@@ -20,49 +20,6 @@ import numpy as np
 
 from deepspeed_tpu.accelerator.abstract_accelerator import DeepSpeedAccelerator
 
-# Peak dense bf16 matmul FLOP/s per chip, by TPU generation. Public numbers:
-# v4: 275e12, v5e: 197e12, v5p: 459e12, v6e (Trillium): 918e12.
-_PEAK_FLOPS = {
-    "v2": 45e12,
-    "v3": 123e12,
-    "v4": 275e12,
-    "v5lite": 197e12,
-    "v5e": 197e12,
-    "v5": 459e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
-    "v6": 918e12,
-    "cpu": 1e12,  # nominal, keeps MFU math finite in CPU tests
-}
-
-# HBM bandwidth per chip, bytes/s (published TPU specs) — the denominator
-# for bandwidth-bound metrics (batched decode MBU in bench.py's serving
-# line, the autotuner's HBM cost model)
-_PEAK_HBM_BW = {
-    "v2": 700e9,
-    "v3": 900e9,
-    "v4": 1228e9,
-    "v5lite": 819e9,
-    "v5e": 819e9,
-    "v5": 2765e9,
-    "v5p": 2765e9,
-    "v6e": 1640e9,
-    "v6": 1640e9,
-    "cpu": 100e9,  # nominal, keeps MBU math finite in CPU tests
-}
-
-
-def _detect_generation(device) -> str:
-    kind = getattr(device, "device_kind", "") or ""
-    kind = kind.lower().replace(" ", "")
-    for key in ("v6e", "v6", "v5p", "v5lite", "v5e", "v5", "v4", "v3", "v2"):
-        if key in kind:
-            return key
-    if device.platform == "cpu":
-        return "cpu"
-    return "v5e"
-
-
 class TPU_Accelerator(DeepSpeedAccelerator):
     def __init__(self):
         super().__init__()
@@ -175,17 +132,31 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return self._communication_backend_name
 
     # ----------------------------------------------------------------- perf
+    def chip_spec(self):
+        """This device's row of THE peak table (``analysis/chips.py``, keyed
+        by ``device_kind``). A device the table does not list raises
+        ``KeyError`` — there is no default chip; the ``cpu`` platform gets
+        the nominal ``cpu-sim`` row."""
+        from deepspeed_tpu.analysis import chips
+
+        dev = jax.local_devices()[0]
+        return chips.resolve_chip(
+            chips.detect_chip_name(dev.device_kind, dev.platform))
+
     def peak_flops(self, dtype: Any = None) -> float:
-        gen = _detect_generation(jax.local_devices()[0])
-        peak = _PEAK_FLOPS.get(gen, 197e12)
+        peak = self.chip_spec().peak_flops
         if dtype in (jnp.float32, np.float32, "float32", "fp32"):
             peak = peak / 2.0
         return peak
 
     def memory_bandwidth(self) -> float:
         """Peak HBM bandwidth per chip, bytes/s."""
-        gen = _detect_generation(jax.local_devices()[0])
-        return _PEAK_HBM_BW.get(gen, 819e9)
+        return self.chip_spec().hbm_bytes_per_s
+
+    def hbm_bytes(self) -> int:
+        """Device memory per chip: the limit the runtime reports, else (a
+        backend without ``memory_stats``) the peak table's capacity."""
+        return self.total_memory() or self.chip_spec().hbm_bytes
 
     # ------------------------------------------------------------- op builder
     def create_op_builder(self, op_name: str):
@@ -237,9 +208,3 @@ def get_accelerator() -> TPU_Accelerator:
     if forced == "cpu":
         jax.config.update("jax_platforms", "cpu")
     return TPU_Accelerator()
-
-
-def set_accelerator_visible(local_rank: int, local_size: int) -> None:
-    """Restrict this process to a subset of local chips (launcher helper)."""
-    os.environ.setdefault("TPU_PROCESS_BOUNDS", "1,1,1")
-    os.environ["TPU_VISIBLE_CHIPS"] = str(local_rank)

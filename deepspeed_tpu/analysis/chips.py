@@ -1,18 +1,22 @@
-"""Per-chip peak tables for the analytic roofline (``ds_roofline``).
+"""THE per-chip peak table, keyed by jax ``device_kind``.
 
-One frozen :class:`ChipSpec` per TPU generation — peak matmul FLOP/s
-(bf16 systolic-array number; fp32 halves, same convention as
-``accelerator/tpu_accelerator.py``) and peak HBM bytes/s — plus a
-``cpu-sim`` entry so the simulated CPU meshes every tier-1 test runs on
-get finite MFU/MBU math. The NUMBERS ARE THE SAME DICTS as
-``tpu_accelerator._PEAK_FLOPS`` / ``_PEAK_HBM_BW`` restated without the
-jax import: this module must stay pure stdlib so ``bin/ds_roofline``
-can price a saved ``.hlo`` dump on a machine with no jax at all (the
-``ds_prof`` contract).
+One frozen :class:`ChipSpec` per TPU generation — peak bf16 matmul FLOP/s
+(fp32 halves), peak HBM bytes/s and HBM capacity — shared by the live
+accelerator (``accelerator/tpu_accelerator.py`` reads its peaks from here)
+and the analytic roofline (``ds_roofline``). Pure stdlib: ``bin/ds_roofline``
+prices a saved ``.hlo`` dump on a machine with no jax at all (the ``ds_prof``
+contract).
 
-Adding a chip = adding one ``ChipSpec`` line here (plus, for live
-detection, the matching entry in ``tpu_accelerator``'s dicts). Keep the
-two in sync — ``tests/unit/test_roofline.py`` cross-checks them.
+Sources: the per-generation pages of the Google Cloud TPU documentation
+("TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s; likewise "TPU v2" …
+"TPU v6e"), and for the ``device_kind`` spellings the installed jax's own
+list (``jax/_src/pallas/mosaic/tpu_info.py``). A device that is in neither
+the table nor the ``cpu`` platform is an ERROR (:func:`detect_chip_name`
+raises): an MFU against a guessed peak is a wrong number, not a rough one.
+The ``cpu-sim`` row is NOMINAL — it keeps MFU/MBU math finite on the
+simulated CPU meshes tier-1 runs on and describes no hardware.
+
+Adding a chip = adding one ``ChipSpec`` line here.
 """
 
 from __future__ import annotations
@@ -33,6 +37,8 @@ class ChipSpec:
     hbm_bytes_per_s: float
     hbm_bytes: int        # HBM capacity, bytes
     note: str = ""
+    # the jax ``device.device_kind`` strings this row answers to
+    device_kinds: Tuple[str, ...] = ()
 
     def peak_flops_for(self, dtype: Optional[str] = None) -> float:
         """Peak for a dtype string — fp32 runs the MXU at half rate
@@ -51,18 +57,21 @@ class ChipSpec:
 
 _GIB = 1024 ** 3
 
-# Canonical table. FLOPs/BW numbers mirror tpu_accelerator.py exactly.
 CHIPS: Dict[str, ChipSpec] = {
-    "v2": ChipSpec("v2", 45e12, 700e9, 8 * _GIB, "TPU v2 core"),
-    "v3": ChipSpec("v3", 123e12, 900e9, 16 * _GIB, "TPU v3 core"),
-    "v4": ChipSpec("v4", 275e12, 1228e9, 32 * _GIB, "TPU v4"),
-    "v5e": ChipSpec("v5e", 197e12, 819e9, 16 * _GIB, "TPU v5e (lite)"),
-    "v5p": ChipSpec("v5p", 459e12, 2765e9, 95 * _GIB, "TPU v5p"),
-    "v6e": ChipSpec("v6e", 918e12, 1640e9, 32 * _GIB, "TPU v6e (Trillium)"),
-    # nominal envelope for the simulated CPU meshes of tier-1 tests —
-    # keeps MFU/MBU finite, matches tpu_accelerator's "cpu" entry
+    "v2": ChipSpec("v2", 45e12, 700e9, 8 * _GIB, "TPU v2 core",
+                   ("TPU v2",)),
+    "v3": ChipSpec("v3", 123e12, 900e9, 16 * _GIB, "TPU v3 core",
+                   ("TPU v3",)),
+    "v4": ChipSpec("v4", 275e12, 1228e9, 32 * _GIB, "TPU v4",
+                   ("TPU v4",)),
+    "v5e": ChipSpec("v5e", 197e12, 819e9, 16 * _GIB, "TPU v5e (lite)",
+                    ("TPU v5 lite", "TPU v5e")),
+    "v5p": ChipSpec("v5p", 459e12, 2765e9, 95 * _GIB, "TPU v5p",
+                    ("TPU v5", "TPU v5p")),
+    "v6e": ChipSpec("v6e", 918e12, 1640e9, 32 * _GIB, "TPU v6e (Trillium)",
+                    ("TPU v6 lite", "TPU v6e")),
     "cpu-sim": ChipSpec("cpu-sim", 1e12, 100e9, 64 * _GIB,
-                        "simulated CPU mesh (nominal)"),
+                        "simulated CPU mesh (NOMINAL, not a measurement)"),
 }
 
 ALIASES: Dict[str, str] = {
@@ -74,6 +83,9 @@ ALIASES: Dict[str, str] = {
     "cpu_sim": "cpu-sim",
     "host": "cpu-sim",
 }
+
+_BY_DEVICE_KIND: Dict[str, str] = {
+    kind: spec.name for spec in CHIPS.values() for kind in spec.device_kinds}
 
 
 def known_chips() -> Tuple[str, ...]:
@@ -94,14 +106,16 @@ def resolve_chip(name: str) -> ChipSpec:
 
 
 def detect_chip_name(device_kind: str, platform: str = "") -> str:
-    """Best-effort chip name from a jax ``device.device_kind`` string
-    (e.g. ``"TPU v5 lite"``) — same matching order as
-    ``tpu_accelerator._detect_generation``, but on plain strings so
-    callers need no jax. Falls back to ``cpu-sim``."""
-    kind = (device_kind or "").lower().replace(" ", "")
-    for key in ("v6e", "v6", "v5p", "v5lite", "v5e", "v5", "v4", "v3", "v2"):
-        if key in kind:
-            return ALIASES.get(key, key)
-    if platform and platform.lower() != "cpu":
-        return "v5e"  # unknown TPU-ish platform: the conservative guess
-    return "cpu-sim"
+    """Chip name for a jax ``device.device_kind`` string (e.g. ``"TPU v5
+    lite"``), on plain strings so callers need no jax. The ``cpu`` platform
+    is the nominal ``cpu-sim`` row; any other device the table does not
+    list raises ``KeyError`` — there is no default chip."""
+    if (platform or "").lower() == "cpu":
+        return "cpu-sim"
+    name = _BY_DEVICE_KIND.get((device_kind or "").strip())
+    if name is None:
+        raise KeyError(
+            f"device_kind {device_kind!r} (platform {platform!r}) is not in "
+            f"the peak table; known: {', '.join(sorted(_BY_DEVICE_KIND))}. "
+            "Add its published peaks to deepspeed_tpu/analysis/chips.py")
+    return name

@@ -34,6 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.extend import core as jex_core
 
 from deepspeed_tpu.analysis.findings import Finding
 
@@ -64,9 +65,9 @@ def _sub_jaxprs(eqn):
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 yield item.jaxpr
-            elif isinstance(item, jax.core.Jaxpr):
+            elif isinstance(item, jex_core.Jaxpr):
                 yield item
 
 

@@ -247,19 +247,16 @@ def decode_mbu_ceiling(useful_bytes: float, flops: float = 0.0,
 # --------------------------------------------------------------- live paths
 def chip_for_engine(engine) -> str:
     """The chip to price against: the config's explicit choice, else
-    detected from the live device kind (``cpu-sim`` on CPU meshes)."""
+    detected from the live device kind (``cpu-sim`` on CPU meshes; a
+    device the peak table does not list raises)."""
     cfg = getattr(getattr(engine, "_config", None), "roofline", None)
     explicit = getattr(cfg, "chip", "") or ""
     if explicit and explicit != "auto":
         return _chips.resolve_chip(explicit).name
-    try:
-        import jax
+    import jax
 
-        dev = jax.local_devices()[0]
-        return _chips.detect_chip_name(
-            getattr(dev, "device_kind", ""), getattr(dev, "platform", ""))
-    except Exception:
-        return "cpu-sim"
+    dev = jax.local_devices()[0]
+    return _chips.detect_chip_name(dev.device_kind, dev.platform)
 
 
 def roofline_program(record, chip: str = "cpu-sim") -> Optional[RooflineReport]:

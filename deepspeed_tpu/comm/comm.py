@@ -42,10 +42,6 @@ from deepspeed_tpu.parallel.topology import (ALL_AXES, DP_AXES, build_mesh)
 from deepspeed_tpu.utils import locks as _locks
 from deepspeed_tpu.utils.logging import log_dist, logger
 
-# jax.shard_map graduated from jax.experimental in 0.5; the shared compat
-# shim (utils.shard_map_compat) maps the modern spelling back on old jax
-from deepspeed_tpu.utils import shard_map_compat as _shard_map
-
 
 class ReduceOp:
     """cf. reference comm/comm.py:33."""
@@ -274,14 +270,7 @@ def init_distributed(dist_backend: str = "xccl",
     # jax.process_count() initializes the XLA backend, after which
     # jax.distributed.initialize refuses to run. Whether the distributed
     # client already exists is read from jax's own state, not the backend.
-    try:
-        from jax._src import distributed as _jax_distributed
-
-        _dist_client_up = getattr(_jax_distributed.global_state, "client",
-                                  None) is not None
-    except ImportError:    # private module moved: fall back to trying anyway
-        _dist_client_up = False
-    if not _dist_client_up and (os.environ.get("DSTPU_NUM_PROCESSES") or
+    if _dist_client() is None and (os.environ.get("DSTPU_NUM_PROCESSES") or
                                 os.environ.get("COORDINATOR_ADDRESS") or
                                 os.environ.get("JAX_COORDINATOR_ADDRESS")):
         coord = (os.environ.get("JAX_COORDINATOR_ADDRESS") or os.environ.get("COORDINATOR_ADDRESS")
@@ -305,12 +294,13 @@ def init_distributed(dist_backend: str = "xccl",
             (_env_int("DSTPU_NUM_PROCESSES", "JAX_NUM_PROCESSES", "WORLD_SIZE") or 1)
         pid = rank if rank >= 0 else \
             (_env_int("DSTPU_PROCESS_ID", "JAX_PROCESS_ID", "RANK") or 0)
-        try:
+        # a world of one has nobody to rendezvous with (the launcher's
+        # single-host path exports exactly that). For more, a failed
+        # rendezvous raises: carrying on would run N independent jobs
+        if nproc > 1:
             jax.distributed.initialize(**_jax_init_kwargs(coord, nproc, pid, timeout))
             if verbose:
                 log_dist(f"jax.distributed initialized: {nproc} processes via {coord}", ranks=[0])
-        except Exception as e:  # already initialized or single-host
-            logger.warning(f"jax.distributed.initialize skipped: {e}")
 
     if mesh is None:
         # THE mesh: built once per topology and cached process-globally, so
@@ -332,23 +322,10 @@ def _jax_init_kwargs(coord: str, nproc: int, pid: int, timeout=None) -> dict:
     """kwargs for ``jax.distributed.initialize``: the rendezvous triple plus
     ``initialization_timeout`` when the caller set one (the reference passes
     its ``timeout`` into the NCCL rendezvous, torch.py:84 — here it bounds
-    the coordinator handshake). Omitted on a jax too old to accept it."""
+    the coordinator handshake)."""
     kwargs = dict(coordinator_address=coord, num_processes=nproc, process_id=pid)
     if timeout is not None:
-        import inspect as _inspect
-
-        try:
-            params = _inspect.signature(jax.distributed.initialize).parameters
-        except (TypeError, ValueError):
-            params = {}
-        if "initialization_timeout" in params:
-            kwargs["initialization_timeout"] = max(1, int(timeout))
-        else:
-            logger.warning("init_distributed: this jax has no "
-                           "initialization_timeout — the rendezvous timeout "
-                           "is dropped (barrier deadlines come from "
-                           "watchdog.barrier_timeout / monitored_barrier's "
-                           "own timeout arg, not from here)")
+        kwargs["initialization_timeout"] = max(1, int(timeout))
     return kwargs
 
 
@@ -696,8 +673,8 @@ def _eager_shard_map(fn, group, x, extra_leading_out: bool = False,
     in_spec = P(axes, *([None] * (x.ndim - 1)))
     out_first = axes if extra_leading_out else None
     out_spec = P(out_first, *([None] * (x.ndim - 1)))
-    shard_fn = _shard_map(fn, mesh=mesh, in_specs=in_spec,
-                          out_specs=out_spec)
+    shard_fn = jax.shard_map(fn, mesh=mesh, in_specs=in_spec,
+                             out_specs=out_spec)
     from deepspeed_tpu.sharding import sharded_jit
 
     # label by the COLLECTIVE name, not the closure's (__name__ is '_k' for
@@ -884,12 +861,9 @@ def clear_config_barrier_timeout() -> None:
 
 def _dist_client():
     """The jax coordination-service client (None single-host / pre-init)."""
-    try:
-        from jax._src import distributed as _jax_distributed
+    from jax._src import distributed as _jax_distributed
 
-        return getattr(_jax_distributed.global_state, "client", None)
-    except ImportError:      # private module moved
-        return None
+    return _jax_distributed.global_state.client
 
 
 def monitored_barrier(group=None, timeout=None, wait_all_ranks=False,

@@ -210,24 +210,13 @@ class InferenceEngine:
         # a2a dispatch inside the compiled prefill/decode programs
         ep = int(self._config.moe.ep_size) if self._config.moe.enabled else 1
         if mesh is None:
-            if dist.is_initialized():
-                mesh = dist.get_mesh()
-                mesh_tp = mesh.shape.get("tensor", 1)
-                if tp != 1 and mesh_tp != tp:
-                    from deepspeed_tpu.utils.logging import logger
-
-                    logger.warning(
-                        f"init_inference: configured tp_size={tp} but the existing mesh "
-                        f"has tensor={mesh_tp}; using the mesh (pass mesh=None after "
-                        "tearing down comm, or build the mesh with the desired tp)")
-                mesh_ep = mesh.shape.get("expert", 1)
-                if ep != 1 and mesh_ep != ep:
-                    from deepspeed_tpu.utils.logging import logger
-
-                    logger.warning(
-                        f"init_inference: configured moe.ep_size={ep} but the existing "
-                        f"mesh has expert={mesh_ep}; using the mesh")
-            else:
+            mesh = dist.get_mesh() if dist.is_initialized() else None
+            # the installed (training) mesh serves only while it agrees with
+            # what this config asks for; an explicit tp_size / ep_size it
+            # does not carry gets the mesh it names, not a warning
+            if mesh is None \
+                    or (tp != 1 and mesh.shape.get("tensor", 1) != tp) \
+                    or (ep != 1 and mesh.shape.get("expert", 1) != ep):
                 n = jax.device_count()
                 if n % (tp * ep):
                     raise ValueError(f"tp_size {tp} x moe.ep_size {ep} does "
@@ -246,11 +235,11 @@ class InferenceEngine:
         specs = None
         if hasattr(model, "param_partition_specs"):
             specs = model.param_partition_specs()
+        shapes = (jax.eval_shape(lambda: params) if params is not None
+                  else jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
         if specs is None or self._config.injection_policy is not None:
             from deepspeed_tpu.module_inject.auto_tp import AutoTP
 
-            shapes = (jax.eval_shape(lambda: params) if params is not None
-                      else jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
             # a policy refines the model's own specs where given; only without
             # model specs does AutoTP name-pattern inference take over fully
             specs = AutoTP.infer_specs(shapes, policy=self._config.injection_policy,
@@ -264,6 +253,7 @@ class InferenceEngine:
         # split prefill/decode pair and the fused generate read placements
         # from (params here; the KV cache lazily via cache_shardings)
         self.sharding = ShardingRegistry(mesh)
+        specs = self.sharding.fit(specs, shapes)
         self.sharding.register("params", specs)
         shardings = self.sharding.shardings("params")
         with mesh:
@@ -281,7 +271,6 @@ class InferenceEngine:
                 from deepspeed_tpu.runtime.checkpoint_engine.engine import \
                     load_inference_params
 
-                shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
                 abstract = jax.tree.map(
                     lambda x, s: jax.ShapeDtypeStruct(
                         x.shape,

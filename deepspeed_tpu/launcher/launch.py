@@ -99,8 +99,6 @@ def build_env(world_info: dict, node_rank: int, master_addr: str, master_port: i
     env["WORLD_SIZE"] = str(num_hosts)
     env["MASTER_ADDR"] = master_addr
     env["MASTER_PORT"] = str(master_port)
-    chips_host = hosts[node_rank] if node_rank < len(hosts) else hosts[-1]
-    env["DS_TPU_CHIPS"] = ",".join(str(c) for c in world_info[chips_host])
     return env
 
 

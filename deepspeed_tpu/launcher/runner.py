@@ -164,12 +164,16 @@ def parse_inclusion_exclusion(resource_pool: Dict[str, int],
 
 
 def build_resource_pool(args) -> "OrderedDict[str, List[int]]":
-    """hostfile + filters + --num_nodes/--num_gpus → final {host: chips}."""
+    """hostfile + filters + --num_nodes/--num_gpus → final {host: chips}.
+
+    No hostfile: a localhost-only job whose one worker process owns every
+    local chip. The launcher does NOT count them — counting means
+    initialising a jax backend, and a parent that holds the chip starves
+    the child it is about to spawn — so the chip list is ``--num_gpus``
+    when given and empty ("whatever the child finds") otherwise."""
     pool = fetch_hostfile(args.hostfile)
     if not pool:
-        # no hostfile: localhost-only job; chips = visible devices (or num_gpus)
-        n = args.num_gpus if args.num_gpus > 0 else _local_chip_count()
-        return OrderedDict([("localhost", list(range(n)))])
+        return OrderedDict([("localhost", list(range(max(args.num_gpus, 0))))])
     active = parse_inclusion_exclusion(pool, args.include, args.exclude)
     if args.num_nodes > 0:
         active = OrderedDict(list(active.items())[:args.num_nodes])
@@ -178,15 +182,6 @@ def build_resource_pool(args) -> "OrderedDict[str, List[int]]":
     if not active:
         raise ValueError("no hosts left after filtering")
     return active
-
-
-def _local_chip_count() -> int:
-    try:
-        import jax
-
-        return max(1, len(jax.local_devices()))
-    except Exception:
-        return 1
 
 
 def _ssh_reachable(host: str) -> bool:
@@ -228,6 +223,12 @@ def main(args=None):
 
     master_addr = args.master_addr or hosts[0]
     env = os.environ.copy()
+    # the workers import the deepspeed_tpu this launcher runs from, whether
+    # or not it is pip-installed (a checkout launched from another cwd)
+    pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env["PYTHONPATH"] = os.pathsep.join(
+        filter(None, [pkg_root, env.get("PYTHONPATH")]))
 
     if not multi_node:
         # single host: exec through launch.py in-place (reference :475-486)

@@ -13,14 +13,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 # logits-buffer budget: chunk length chosen so the (B, chunk, V) fp32 buffer
 # stays around 256MB
 _CHUNK_ELEMS = 64 * 1024 * 1024
 
 NEG_INF_ATTN = -1e30
-
-_warned_flash_fallback = [False]
 
 # ---------------------------------------------------------------------------
 # layer-scan indirection (overlap engine hook)
@@ -123,12 +122,59 @@ def apply_rope(x, cos, sin, interleaved: bool = False):
     return out.astype(x.dtype)
 
 
+def _kernel_target():
+    """``(mesh, on_tpu)`` for the program under trace: the ambient ``with
+    mesh:`` every engine traces inside and whether its devices are TPUs —
+    which is what decides if a Mosaic kernel can be in the program, also
+    when lowering ahead of time for a chip this process does not hold.
+    Outside any mesh context it is the default backend."""
+    from deepspeed_tpu.sharding.mesh import ambient_mesh
+
+    mesh = ambient_mesh()
+    if mesh is None:
+        return None, jax.default_backend() == "tpu"
+    return mesh, mesh.devices.flat[0].platform == "tpu"
+
+
+def _attn_axes(mesh, batch: int, n_heads: int):
+    """Mesh axes attention is embarrassingly parallel over, as PartitionSpec
+    entries ``(batch_entry, head_entry)``: the dp axes when they divide the
+    batch, 'tensor' when it divides the heads — the placement the
+    surrounding GSPMD program already uses — else replicated (None)."""
+    from deepspeed_tpu.parallel.topology import DP_AXES, TENSOR_AXIS
+
+    if mesh is None:
+        return None, None
+    dp = tuple(a for a in DP_AXES if mesh.shape.get(a, 1) > 1)
+    world = math.prod(mesh.shape[a] for a in dp)
+    tp = mesh.shape.get(TENSOR_AXIS, 1)
+    return (dp if dp and batch % world == 0 else None,
+            TENSOR_AXIS if tp > 1 and n_heads % tp == 0 else None)
+
+
+def _kernel_on_mesh(kernel, mesh, args, in_specs, out_specs):
+    """Call a Pallas kernel from a program compiled over ``mesh``. XLA
+    cannot partition a Mosaic call (jax refuses to lower one under GSPMD
+    on more than one device), so on a multi-device mesh the call sits in a
+    ``shard_map`` manual over EVERY mesh axis; on one device, or already
+    inside a fully-manual region (Ulysses), it is called directly."""
+    if mesh is None or mesh.size == 1:
+        return kernel(*args)
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty and set(ctx.manual_axes) == set(ctx.axis_names):
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
 def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                            causal: bool = True, key_padding_mask=None,
                            flash_block=None, window=None):
     """Self-attention on local (unsharded-sequence) q, k, v with equal head
-    counts (B, T, H, Dh): Pallas flash kernel when available, XLA einsum
-    otherwise (CPU tests, unsupported shapes). Causal by default;
+    counts (B, T, H, Dh): the Pallas flash kernel on TPU, XLA einsum for
+    what the kernel does not carry (below) and off-TPU (the CPU tests).
+    The path is chosen by what the call needs, never by a failure: a kernel
+    that does not trace, lower or compile is an error. Causal by default;
     ``causal=False`` is the encoder (BERT) path.
 
     ``alibi``: optional (H,) per-head slopes; the bias added is
@@ -136,30 +182,29 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     ``slopes * (j - i)`` because per-row constants cancel in softmax, and
     exactly HF BLOOM's ``build_alibi_tensor`` under a full attention mask.
     ``key_padding_mask``: optional (B, T) True=attend. Biased or masked
-    attention takes the einsum path (the flash kernel carries neither).
+    attention takes the einsum path (the flash kernel carries neither), and
+    so does a non-causal length the kernel cannot tile.
     ``window``: optional sliding window (GPT-Neo local attention, reference
     containers/gptneo.py): position i attends to j with 0 <= i-j < window.
     May be a TRACED scalar so one scanned layer loop can mix global and
     local layers; <=0 means global. Windowed attention takes the einsum
     path.
     """
-    # the backend gate matters: off-TPU the Mosaic kernel fails at LOWERING
-    # time (inside jit compilation), where no try/except here could catch it
     if use_flash and alibi is None and key_padding_mask is None \
-            and window is None and jax.default_backend() == "tpu":
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+            and window is None:
+        mesh, on_tpu = _kernel_target()
+        if on_tpu:
+            from deepspeed_tpu.ops.pallas import flash_attention as fa
 
             kw = ({"block_q": int(flash_block), "block_k": int(flash_block)}
                   if flash_block else {})
-            return flash_attention(q, k, v, causal=causal, **kw)
-        except Exception as e:
-            if not _warned_flash_fallback[0]:
-                _warned_flash_fallback[0] = True
-                from deepspeed_tpu.utils.logging import logger
-
-                logger.warning(f"flash attention unavailable ({e}); "
-                               "using XLA einsum attention")
+            if fa.flash_supports(q.shape[1], k.shape[1], causal, **kw):
+                batch, heads = _attn_axes(mesh, q.shape[0], q.shape[2])
+                spec = P(batch, None, heads, None)
+                return _kernel_on_mesh(
+                    lambda q, k, v: fa.flash_attention(q, k, v, causal=causal,
+                                                       **kw),
+                    mesh, (q, k, v), (spec, spec, spec), spec)
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     T = q.shape[1]
@@ -182,7 +227,6 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-_warned_decode_fallback = [False]
 _warned_decode_alibi = [False]
 
 
@@ -194,12 +238,14 @@ def cached_decode_attention(q, k_cache, v_cache, pos, use_flash_decode=False,
     (H,) slopes (key-position bias; einsum path only). → (B, H, Dh).
 
     ``use_flash_decode`` selects the Pallas streaming kernel
-    (ops/pallas/decode_attention.py). Measured on v5e: the kernel reads only
-    the valid cache prefix, so it wins when the cache is preallocated longer
-    than the current length (microbench B=8, S=4096, H=KV=16, Dh=64 bf16:
-    822us vs 933us einsum at 1/8 fill; engine-level generate() of 64 tokens
-    on a 4-layer model: 79ms vs 113ms) but loses ~2× to XLA's fused einsum
-    when the cache is exactly full — hence opt-in.
+    (ops/pallas/decode_attention.py) — a TPU kernel: asking for it where it
+    cannot lower is an error, not a reason to run something else. Round-5
+    v5e measurements: the kernel reads only the valid cache prefix, so it
+    wins when the cache is preallocated longer than the current length
+    (microbench B=8, S=4096, H=KV=16, Dh=64 bf16: 822us vs 933us einsum at
+    1/8 fill; engine-level generate() of 64 tokens on a 4-layer model: 79ms
+    vs 113ms) but loses ~2× to XLA's fused einsum when the cache is exactly
+    full — hence opt-in.
     """
     if use_flash_decode and alibi is not None and not _warned_decode_alibi[0]:
         _warned_decode_alibi[0] = True
@@ -209,17 +255,15 @@ def cached_decode_attention(q, k_cache, v_cache, pos, use_flash_decode=False,
                        "decode kernel has no bias input — using XLA einsum "
                        "decode for this model")
     if use_flash_decode and alibi is None and window is None:
-        try:
-            from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+        from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
 
-            return decode_attention(q, k_cache, v_cache, pos)
-        except Exception as e:
-            if not _warned_decode_fallback[0]:
-                _warned_decode_fallback[0] = True
-                from deepspeed_tpu.utils.logging import logger
-
-                logger.warning(f"decode-attention kernel unavailable ({e}); "
-                               "using XLA einsum decode")
+        mesh, _ = _kernel_target()
+        batch, heads = _attn_axes(mesh, q.shape[0], k_cache.shape[2])
+        cache_spec = P(batch, None, heads, None)
+        return _kernel_on_mesh(
+            decode_attention, mesh, (q, k_cache, v_cache, pos),
+            (P(batch, heads, None), cache_spec, cache_spec, P()),
+            P(batch, heads, None))
     B, H, Dh = q.shape
     S, KV = k_cache.shape[1], k_cache.shape[2]
     qg = q.reshape(B, KV, H // KV, Dh)

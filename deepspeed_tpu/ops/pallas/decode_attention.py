@@ -6,7 +6,7 @@ one new query token per sequence attends over the whole KV cache. This step is
 HBM-bandwidth bound (the cache read dominates), so the kernel:
 
 * streams the cache ONCE with an online softmax — no (B, H, S) score tensor
-  is ever written back to HBM (the einsum fallback materializes it in fp32);
+  is ever written back to HBM (the einsum path materializes it in fp32);
 * is GQA-native: queries arrive grouped per KV head, the cache is read at KV
   (not H) heads — no repeated K/V copies;
 * clamps the k-block index to the cache's valid length (scalar-prefetched
@@ -153,18 +153,14 @@ def decode_attention(q, k_cache, v_cache, pos, block_k: int = DEFAULT_BLOCK_K):
             flops=int(4 * B * H * S * Dh),
             bytes_accessed=int(k_cache.size + v_cache.size) * k_cache.dtype.itemsize,
             transcendentals=int(B * H * S)),
-        # Mosaic lowering is TPU-only, and under jit a lowering failure
-        # escapes any try/except around the call — so off-TPU the kernel
-        # interprets itself (slow but exact; CPU decode is not a perf target)
-        interpret=jax.default_backend() != "tpu",
     )(pos_arr, qg, k_cache, v_cache)
     return out[:, :, :rep].reshape(B, H, Dh)
 
 
 def decode_reference(q, k_cache, v_cache, pos):
-    """Grouped-einsum reference — the exact XLA path the models fall back to
-    (one shared implementation in models/common.py, so kernel tests compare
-    against what production actually runs)."""
+    """Grouped-einsum reference — the exact XLA path the models run without
+    ``use_flash_decode`` (one shared implementation in models/common.py, so
+    kernel tests compare against what production actually runs)."""
     from deepspeed_tpu.models.common import cached_decode_attention
 
     return cached_decode_attention(q, k_cache, v_cache, pos,

@@ -50,11 +50,46 @@ DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
 
+# sequence lengths one block cannot hold are padded to a multiple of this
+# (the MXU tile edge), so the halving in _pick_block stops at >= 128 rows
+_PAD_MULTIPLE = 128
+
+
 def _pick_block(t: int, preferred: int) -> int:
     b = min(preferred, t)
     while t % b:
         b //= 2
     return max(b, 1)
+
+
+def _tiles(t: int, preferred: int) -> bool:
+    """Whether Mosaic takes ``_pick_block``'s block for length ``t``: its
+    row count must be a multiple of 8 or the whole array."""
+    b = _pick_block(t, preferred)
+    return b == t or b % 8 == 0
+
+
+def _padded_len(t: int, preferred: int) -> int:
+    """The length causal self-attention runs the kernels at: ``t`` while it
+    tiles in blocks of >= 128 rows (or one block holds it), else the next
+    multiple of 128 — any prompt length lowers, and none degrades to the
+    8-row blocks an odd multiple of 8 (T=1000) would halve down to."""
+    if t <= preferred or _pick_block(t, preferred) >= _PAD_MULTIPLE:
+        return t
+    return -(-t // _PAD_MULTIPLE) * _PAD_MULTIPLE
+
+
+def flash_supports(t_q: int, t_k: int, causal: bool,
+                   block_q: int = None, block_k: int = None) -> bool:
+    """Whether :func:`flash_attention` can tile these lengths. Causal
+    self-attention always can (it pads; see ``_padded_len``); the other
+    forms — which have no mask to hide pad keys behind — only at lengths
+    that tile as they are."""
+    block_q = block_q or DEFAULT_BLOCK_Q
+    block_k = block_k or DEFAULT_BLOCK_K
+    if causal and t_q == t_k:
+        return True
+    return _tiles(t_q, block_q) and _tiles(t_k, block_k)
 
 
 def _causal_pairs(nq: int):
@@ -534,9 +569,26 @@ _flash_bhtd.defvjp(_flash_bhtd_fwd, _flash_bhtd_bwd)
 
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
-    """q, k, v: (B, T, H, D) → (B, T, H, D). Differentiable; bf16-friendly."""
-    b, t_q, h, d = q.shape
-    t_k = k.shape[1]
+    """q, k, v: (B, T, H, D) → (B, T, H, D). Differentiable; bf16-friendly.
+
+    Causal self-attention at a length the kernels cannot tile is padded at
+    the END of the sequence and the pad rows sliced off the output: under
+    the causal mask a pad key is visible only to pad queries, so no kept
+    row changes, and the pad rows' cotangents are zero. Other forms raise
+    on an untileable length (``flash_supports`` tells callers beforehand).
+    """
+    b, t, h, d = q.shape
+    if not flash_supports(t, k.shape[1], causal, block_q, block_k):
+        raise ValueError(
+            f"flash_attention: lengths ({t}, {k.shape[1]}) with causal="
+            f"{causal} do not tile in blocks ({block_q}, {block_k}) — only "
+            "causal self-attention is padded")
+    if causal and t == k.shape[1]:
+        pad = _padded_len(t, min(block_q, block_k)) - t
+        if pad:
+            q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                       for x in (q, k, v))
+    t_q, t_k = q.shape[1], k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     # fold the softmax scale into q OUTSIDE the kernels: one multiply over
@@ -546,7 +598,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     to_bhtd = lambda x, t: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     o = _flash_bhtd(to_bhtd(q, t_q), to_bhtd(k, t_k), to_bhtd(v, t_k),
                     1.0, bool(causal), int(block_q), int(block_k))
-    return o.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)[:, :t]
 
 
 # ------------------------------------------------------------ block-sparse

@@ -23,10 +23,10 @@ the ppermute/all_to_all into the reverse-direction gradient comms. Fully
 manual (every mesh axis, with in_specs naming the batch/seq/head layout the
 surrounding GSPMD program already uses) rather than manual-over-'seq'-only:
 attention is embarrassingly parallel over batch AND heads, so no cross-dp or
-cross-tp collective is needed inside — and the partial-manual mode the old
-wrapper asked for hard-aborts the SPMD partitioner on the jax 0.4.x this
-repo pins (``Check failed: target.IsManualSubgroup()``, rc=134 — one of the
-failure classes behind the red MULTICHIP gate).
+cross-tp collective is needed inside — and the flash kernel Ulysses calls
+inside must be manual over every axis anyway (XLA cannot partition a Mosaic
+call). Partial-manual mode hard-aborted the SPMD partitioner on jax 0.4.x;
+not re-tested on 0.9 (ROADMAP D10).
 """
 
 from __future__ import annotations
@@ -43,7 +43,6 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.parallel.topology import (DATA_AXIS, EXPERT_AXIS,
                                              ICI_AXIS, MICS_AXIS, SEQ_AXIS,
                                              TENSOR_AXIS)
-from deepspeed_tpu.utils import shard_map_compat
 
 NEG_INF = -1e30
 
@@ -86,9 +85,9 @@ def ulysses_attention(attn_fn: Callable, q, k, v, mesh, seq_axis: str = SEQ_AXIS
         return gather_heads(o)
 
     spec = _qkv_spec(mesh, seq_axis, q.shape[2], head_groups=S)
-    sm = shard_map_compat(inner, mesh=mesh,
-                          in_specs=(spec, spec, spec), out_specs=spec,
-                          check_vma=False)
+    sm = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return sm(q, k, v)
 
 
@@ -176,7 +175,7 @@ def ring_attention(q, k, v, mesh, causal: bool = True, scale: Optional[float] = 
         return (acc / l_safe.transpose(0, 2, 1)[..., None].astype(acc.dtype))
 
     spec = _qkv_spec(mesh, seq_axis, q.shape[2])
-    sm = shard_map_compat(inner, mesh=mesh,
-                          in_specs=(spec, spec, spec), out_specs=spec,
-                          check_vma=False)
+    sm = jax.shard_map(inner, mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return sm(q, k, v)

@@ -167,11 +167,25 @@ def build_mesh(mesh_config=None, devices=None, axis_dims: Optional[Dict[str, int
         axis_dims = _resolve_mesh_dims(mesh_config, len(devices))
     names = [a for a in ALL_AXES if a in axis_dims]
     shape = [axis_dims[a] for a in names]
-    try:
-        from jax.experimental import mesh_utils
+    from jax.experimental import mesh_utils
 
+    try:
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    except (ValueError, NotImplementedError, AssertionError) as e:
+        # Warn, not raise: the enumeration-order mesh below is still a
+        # CORRECT mesh — what is lost is placement (an inner axis may no
+        # longer sit on neighbouring chips, so its per-layer collectives
+        # take more hops), and legitimate callers land here: a survivor
+        # mesh over 6 of 8 chips has no torus assignment at all. Whoever
+        # reads a slow collective in a trace must be able to find this.
+        from deepspeed_tpu.utils.logging import logger
+
+        logger.warning(
+            f"build_mesh: no topology-aware device assignment for mesh "
+            f"{dict(zip(names, shape))} over {len(devices)} "
+            f"{devices[0].device_kind} device(s) ({type(e).__name__}: {e}); "
+            "using enumeration order — logical axes may not be "
+            "ICI-contiguous")
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, axis_names=tuple(names))
 

@@ -608,6 +608,14 @@ def load_engine_checkpoint(engine, load_dir: str, tag: Optional[str] = None,
     restore_s}``.
     """
     wait_for_pending_saves()              # an async save may still be writing
+    if jax.process_count() > 1:
+        # the manifest and 'latest' land from PROCESS 0's finalize; every
+        # other host's wait above returns at once (it has no finalize of its
+        # own) and would verify the tag before they exist, skip it, and
+        # leave process 0 alone in the collective restore
+        from deepspeed_tpu.comm import comm as _comm
+
+        _comm.barrier(log_name="ds_ckpt_load")
     import time as _time
 
     import orbax.checkpoint as ocp

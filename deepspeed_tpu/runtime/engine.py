@@ -354,8 +354,15 @@ class DeepSpeedEngine:
         self._host_offload_param = bool(off_param and off_param.device == "cpu")
         self._nvme_offload = bool(off_opt and off_opt.device == "nvme")
         if (self._host_offload_opt or self._host_offload_param) and not on_tpu:
-            log_dist("offload to host memory requires the TPU backend; running "
-                     "without offload (CPU backend has one memory space)", ranks=[0])
+            # chosen by what the backend can compile, up front: XLA:CPU has
+            # the pinned_host memory kind but its SPMD partitioner rejects
+            # the placement annotations the streamed step carries (jax
+            # 0.9.0: "Side-effect HLO must have sharding"), so the CPU
+            # tests train the same config with the state left in place
+            logger.warning(
+                "offload to host memory needs the TPU backend: this "
+                f"{jax.default_backend()} run keeps optimizer state/params "
+                "in device memory (same numerics, no offload)")
             self._host_offload_opt = self._host_offload_param = False
         # Moments-only offload: when the fp32 MASTER fits HBM next to the
         # bf16 params + grads (+ remat activations), keep it resident and
@@ -375,10 +382,7 @@ class DeepSpeedEngine:
                         for l in jax.tree.leaves(param_shapes))
                 shards = max(1, int(np.prod([mesh.shape[a]
                                              for a in self.plan.dp_axes] or [1])))
-                try:
-                    hbm = int(jax.local_devices()[0].memory_stats()["bytes_limit"])
-                except Exception:
-                    hbm = 16 << 30
+                hbm = get_accelerator().hbm_bytes()
                 # resident set with master in HBM ≈ fp32 master (4n,
                 # dp-sharded at stage>=1) + bf16 params (2n, sharded only at
                 # stage 3) + bf16 grads (2n, sharded at stage>=2) + the
@@ -877,12 +881,18 @@ class DeepSpeedEngine:
             self._nvme_optimizer.init_from_params(named)
 
         repl = NamedSharding(mesh, P())
-        scaler_state = self.loss_scaler.initial_state() if self.loss_scaler else None
-        state = TrainState(step=jnp.int32(0), params=params, master=master,
-                           opt_state=opt_state,
+        # the scalars are COMMITTED to the mesh like every other field: an
+        # uncommitted host scalar carries no mesh in its type, the step's own
+        # outputs do, and that difference alone made the second train_batch
+        # re-trace and re-compile the whole step (jax 0.9 types shardings)
+        on_mesh = lambda x: jax.device_put(x, repl)
+        scaler_state = jax.tree.map(on_mesh, self.loss_scaler.initial_state()) \
+            if self.loss_scaler else None
+        state = TrainState(step=on_mesh(jnp.int32(0)), params=params,
+                           master=master, opt_state=opt_state,
                            scaler=scaler_state,
-                           rng=seed_key,
-                           skipped_steps=jnp.int32(0))
+                           rng=on_mesh(seed_key),
+                           skipped_steps=on_mesh(jnp.int32(0)))
         shardings = TrainState(
             step=repl,
             params=param_sh,
@@ -1073,10 +1083,7 @@ class DeepSpeedEngine:
         # stream-in is PER-DEVICE bytes, not global
         shards = max(1, int(np.prod([self.mesh.shape[a]
                                      for a in self.plan.dp_axes] or [1])))
-        try:
-            hbm = int(jax.local_devices()[0].memory_stats()["bytes_limit"])
-        except Exception:
-            hbm = 16 << 30
+        hbm = get_accelerator().hbm_bytes()
         # host-resident fp32 streamed in at once: master+mu+nu = 12
         # bytes/param, or mu+nu = 8 when the master stays in HBM (which also
         # shrinks the budget the stream-in must fit into)
@@ -1358,8 +1365,9 @@ class DeepSpeedEngine:
         if batch is None:
             return None
         flat, treedef = jax.tree_util.tree_flatten(batch)
-        return (treedef, tuple(len(getattr(x, "shape", np.asarray(x).shape))
-                               for x in flat))
+        # np.ndim reads .ndim where there is one: no host copy of a
+        # pre-placed leaf, and a multi-host global array has no host view
+        return (treedef, tuple(np.ndim(x) for x in flat))
 
     def _batch_in_shardings(self, batch):
         """THE batch in_shardings policy for every compiled step variant:
@@ -1469,12 +1477,10 @@ class DeepSpeedEngine:
             batch_specs = jax.tree.map(lambda x: P(batch_axis, *([None] * (x.ndim - 1))), batch)
             repl = jax.tree.map(lambda _: P(), jax.eval_shape(lambda: StepMetrics(
                 jnp.float32(0), jnp.float32(0), jnp.float32(0), jnp.float32(0), jnp.bool_(False))))
-            from deepspeed_tpu.utils import shard_map_compat
-
-            return shard_map_compat(local_step, mesh=mesh,
-                                    in_specs=(state_specs, batch_specs),
-                                    out_specs=(state_specs, repl),
-                                    check_vma=False)(state, batch)
+            return jax.shard_map(local_step, mesh=mesh,
+                                 in_specs=(state_specs, batch_specs),
+                                 out_specs=(state_specs, repl),
+                                 check_vma=False)(state, batch)
 
         return step_fn
 
@@ -1851,9 +1857,8 @@ class DeepSpeedEngine:
         multihost = jax.process_count() > 1
 
         def put(x):
-            ndim = np.asarray(x).ndim
             # ONE source for batch placement: the registry (clamped per rank)
-            sh = self.sharding.batch_sharding(ndim)
+            sh = self.sharding.batch_sharding(np.ndim(x))
             if hasattr(x, "sharding") and x.sharding == sh:
                 return x
             x = np.asarray(x)

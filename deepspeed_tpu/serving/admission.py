@@ -21,10 +21,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from deepspeed_tpu.utils.logging import logger
 
-# fallback HBM budget when the backend reports no memory_stats (CPU mesh,
-# some TPU runtimes): one v5e chip's worth, documented in docs/CONFIG.md
-DEFAULT_HBM_BYTES = 16 << 30
-
 
 class ShedError(RuntimeError):
     """Structured admission rejection. Not a failure of the server — the
@@ -128,19 +124,6 @@ def kv_bytes_per_request(module, max_total_len: int) -> int:
     return total
 
 
-def _device_hbm_bytes(engine) -> Tuple[int, str]:
-    """(HBM bytes, source) for the engine's first device; falls back to
-    ``DEFAULT_HBM_BYTES`` when the backend exposes no memory_stats (CPU)."""
-    try:
-        dev = next(iter(engine.mesh.devices.flat))
-        stats = dev.memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"]), "memory_stats"
-    except Exception:       # backend without the API — the fallback is the point
-        pass
-    return DEFAULT_HBM_BYTES, "fallback"
-
-
 def resolve_capacity(engine, cfg) -> Tuple[int, Dict[str, Any]]:
     """The admission bound (queued + in-flight requests) and how it was
     derived. An explicit ``max_queue_depth`` wins; otherwise the bound is
@@ -159,7 +142,11 @@ def resolve_capacity(engine, cfg) -> Tuple[int, Dict[str, Any]]:
     if cfg.hbm_bytes > 0:
         hbm, src = int(cfg.hbm_bytes), "config"
     else:
-        hbm, src = _device_hbm_bytes(engine)
+        # the runtime's memory_stats limit, else the peak table's capacity
+        # for this device_kind (an unknown device raises — no guessed budget)
+        from deepspeed_tpu.accelerator import get_accelerator
+
+        hbm, src = get_accelerator().hbm_bytes(), "device"
     params_bytes = sum(int(x.nbytes) for x in jax.tree.leaves(engine.params))
     budget = max(0, hbm - params_bytes) * float(cfg.kv_budget_fraction)
     cap = max(1, int(budget // max(1, per_req)))

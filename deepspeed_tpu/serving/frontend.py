@@ -469,13 +469,12 @@ class ServingFrontEnd:
         if remaining <= 0:
             raise _RequestDeadline()
         # a tick is "warm" only once its exact jit SPECIALIZATION has run:
-        # prefill specializes per prompt length, and the decode chunk
-        # specializes twice — call #1 takes prefill outputs + a fresh
-        # PRNGKey, call #2+ takes its OWN outputs, whose layouts differ
-        # (the hybrid-engine two-compile effect) — so the two call
-        # positions carry distinct warm keys. Until a specialization has
-        # run, the startup cap applies; a compile must never read as a
-        # hang.
+        # prefill specializes per prompt length; the decode chunk's call #1
+        # takes prefill outputs, call #2+ its OWN outputs — XLA may hand
+        # those back in another layout and specialize again — so the two
+        # call positions carry distinct warm keys. Until a specialization
+        # has run, the startup cap applies; a compile must never read as
+        # a hang.
         cold = not self._warm.get(warm_key)
         cap = float(self.cfg.startup_tick_timeout_s) if cold \
             else float(self.cfg.decode_tick_timeout_s)
@@ -543,7 +542,11 @@ class ServingFrontEnd:
             logits, cache, done = self._tick(
                 req, lambda: prefill(self.engine.params, ids),
                 warm_key=("prefill", pkey, ids.shape[1]))
-            rng = jax.random.PRNGKey(req.seed)
+            # committed to the mesh, like the carry the chunk hands back:
+            # an uncommitted key types differently from the program's own
+            # output and would compile the decode chunk a second time
+            rng = jax.device_put(jax.random.PRNGKey(req.seed),
+                                 self.engine.sharding.replicated())
             chunk_i = 0
             while len(req.tokens) < req.max_new_tokens:
                 self._poll_preempt()
@@ -599,8 +602,11 @@ class ServingFrontEnd:
         except Exception as e:      # noqa: BLE001 - resolved, never dropped
             self.breaker.record_failure()
             self._count("failed")
+            # the server keeps serving (this is its boundary) but the
+            # failure keeps its traceback; a caller that must not pass on a
+            # dead engine checks req.status — chip_smoke.py does
             logger.error(f"serving: request {req.id} failed: "
-                         f"{type(e).__name__}: {e}")
+                         f"{type(e).__name__}: {e}", exc_info=True)
             self._resolve(req, "partial" if req.tokens else "failed",
                           f"error: {type(e).__name__}: {e}")
         finally:

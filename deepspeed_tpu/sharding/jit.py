@@ -112,6 +112,24 @@ def reset_program_table() -> None:
         _PROGRAMS.clear()
 
 
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def _place_compile_cache() -> None:
+    """Give jax's persistent compilation cache a home unless it has one.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax has already read it into
+    its config and nothing is set here — the operator (or the chip tool)
+    placed the cache. Otherwise it goes to ``<checkout>/.jax_cache``: a
+    FIXED path, because the directory is where a later process looks, so
+    one built from a pid, the time or ``tempfile`` never hits. This is the
+    only site in the package that names a cache directory."""
+    if jax.config.jax_compilation_cache_dir is None:
+        jax.config.update("jax_compilation_cache_dir", _REPO_CACHE_DIR)
+
+
 def _resolve(tree):
     """INHERIT → None (jax.jit's 'infer from operand'), recursively.
     Returns (resolved, saw_inherit)."""
@@ -255,6 +273,7 @@ def sharded_jit(fn, *, label: str, in_shardings, out_shardings,
     """
     if not label:
         raise ValueError("sharded_jit: a non-empty program label is required")
+    _place_compile_cache()
     if in_shardings is None or out_shardings is None:
         raise TypeError(
             f"sharded_jit({label!r}): in_shardings/out_shardings must be "
@@ -292,14 +311,7 @@ def sharded_jit(fn, *, label: str, in_shardings, out_shardings,
     if out_resolved is not None:
         kwargs["out_shardings"] = out_resolved
     jitted = jax.jit(fn, **kwargs)
-    try:
-        jitted.program_record = record   # introspection hook (ds_report/tests)
-    except (AttributeError, TypeError):
-        pass
-    try:
-        record.jitted_ref = weakref.ref(jitted)
-    except TypeError:
-        record.jitted_ref = (lambda j=jitted: j)   # unlikely; stay analyzable
+    record.jitted_ref = weakref.ref(jitted)
     return _ShardedProgram(jitted, record)
 
 

@@ -34,35 +34,46 @@ _RNG_PINNED = False
 def _enable_sharding_invariant_rng() -> None:
     """Force partitionable threefry ON (one-time, with the first mesh).
 
-    On jax 0.4.x the flag defaults to False, and non-partitionable
-    threefry is NOT sharding-invariant: the same ``jax.random.normal``
-    compiled with dp/pipe-sharded ``out_shardings`` yields DIFFERENT
-    values than the unsharded draw (measured: 0.09 abs diff on a 0.02-std
-    init). That silently made a model's initialization depend on its
-    topology — a pp=2 engine trained from different weights than the
-    pp=1 engine with the same seed. One mesh, one RNG semantics: every
-    placement decision flows through this package, so the invariance
-    knob lives here too.
+    Non-partitionable threefry is NOT sharding-invariant: the same
+    ``jax.random.normal`` compiled with dp/pipe-sharded ``out_shardings``
+    yields DIFFERENT values than the unsharded draw (measured: 0.09 abs
+    diff on a 0.02-std init), which makes a model's initialization depend
+    on its topology. The installed jax defaults the flag to True; the pin
+    covers a process that turned it off (``JAX_THREEFRY_PARTITIONABLE=0``).
+    One mesh, one RNG semantics: every placement decision flows through
+    this package, so the invariance knob lives here too.
     """
     global _RNG_PINNED
     if _RNG_PINNED:
         return
     import jax
 
-    try:
-        if not jax.config.jax_threefry_partitionable:
-            jax.config.update("jax_threefry_partitionable", True)
-            logger.info("jax_threefry_partitionable enabled: random inits "
-                        "are now sharding-invariant (a sharded draw equals "
-                        "the unsharded draw for the same key)")
-    except AttributeError:
-        pass     # newer jax: always-on, flag removed
+    if not jax.config.jax_threefry_partitionable:
+        jax.config.update("jax_threefry_partitionable", True)
+        logger.info("jax_threefry_partitionable enabled: random inits "
+                    "are now sharding-invariant (a sharded draw equals "
+                    "the unsharded draw for the same key)")
     _RNG_PINNED = True
 
 
 def global_mesh() -> Optional[Mesh]:
     """The current process-global mesh, or None before the first build."""
     return _GLOBAL_MESH
+
+
+def ambient_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing ``with mesh:`` block, or None outside one.
+
+    Every engine traces its programs inside ``with self.mesh:``, so at
+    trace time this is the mesh the program is being compiled FOR — which
+    the process-global mesh is not when a caller handed an engine its own
+    (``init_inference(mesh=...)``, AOT lowering against a compile-only
+    topology). Model code asks here which devices a kernel will run on.
+    The one read of jax's thread-local mesh context in the package."""
+    from jax._src.mesh import thread_resources
+
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 def mesh_generation() -> int:

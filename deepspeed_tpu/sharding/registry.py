@@ -15,6 +15,7 @@ their in/out shardings from here.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -75,6 +76,32 @@ class ShardingRegistry:
     def replicated(self) -> NamedSharding:
         return self.named(P())
 
+    def fit(self, specs: Any, shapes: Any) -> Any:
+        """``specs`` with every dim entry the mesh does not divide evenly
+        set to None (jax refuses uneven shardings): GPT-2's published
+        vocab of 50257 over ``tensor=4`` stays whole on each chip instead
+        of failing the placement. Each dropped entry is logged."""
+        from deepspeed_tpu.parallel.topology import spec_axes
+        from deepspeed_tpu.utils.logging import logger
+
+        def leaf(path, spec, shape):
+            if spec is None:
+                return spec
+            entries = list(tuple(spec)[:len(shape.shape)])
+            for d, e in enumerate(entries):
+                ways = math.prod(self.mesh.shape[a]
+                                 for a in spec_axes(P(e), 1))
+                if shape.shape[d] % ways:
+                    logger.warning(
+                        f"sharding: {jax.tree_util.keystr(path)} dim {d} "
+                        f"({shape.shape[d]}) is not divisible by {e}="
+                        f"{ways}; that dim stays unsharded")
+                    entries[d] = None
+            return P(*entries)
+
+        return jax.tree_util.tree_map_with_path(leaf, specs, shapes,
+                                                is_leaf=_is_spec)
+
     # -------------------------------------------------------------- batches
     def batch_axes(self) -> Tuple[str, ...]:
         """Mesh axes the batch (leading) dim shards over."""
@@ -101,11 +128,7 @@ class ShardingRegistry:
 
     def batch_shardings(self, batch: Any) -> Any:
         """Per-leaf NamedShardings for a host/device batch pytree."""
-        def leaf(x):
-            ndim = len(getattr(x, "shape", np.asarray(x).shape))
-            return self.batch_sharding(ndim)
-
-        return jax.tree.map(leaf, batch)
+        return jax.tree.map(lambda x: self.batch_sharding(np.ndim(x)), batch)
 
     def ids_sharding(self, batch_size: Optional[int] = None) -> NamedSharding:
         """Token-id arrays of generation programs — (B, T) with B over the

@@ -1,39 +1,6 @@
 import os
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check_vma=None,
-                     axis_names=None):
-    """``jax.shard_map`` across jax versions: it graduated from
-    ``jax.experimental.shard_map`` in 0.5 with renamed knobs
-    (``check_rep``→``check_vma``; ``auto`` complement → ``axis_names``).
-    Callers use the MODERN spelling; this maps it back on old jax. The one
-    shim every production shard_map call site goes through — a second copy
-    of this mapping is a bug."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    kw = {}
-    if sm is not None:
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as esm
-
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    if axis_names is not None and set(axis_names) != set(mesh.axis_names):
-        # partial-manual mode: old jax's `auto=` spelling ABORTS the process
-        # in the SPMD partitioner (XLA CHECK failure, not a catchable
-        # exception) — refuse cleanly instead of taking down the run
-        raise NotImplementedError(
-            f"shard_map over a subset of mesh axes ({sorted(axis_names)} of "
-            f"{list(mesh.axis_names)}) requires jax>=0.5 (jax.shard_map "
-            "axis_names); this jax only supports fully-manual shard_map")
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
 def env_flag(name: str) -> bool:
     """Boolean env knob: unset, empty, "0", "false", "no", and "off" are OFF —
     so the natural ways a user spells a disable (FLAG=0, FLAG=no, FLAG=off)

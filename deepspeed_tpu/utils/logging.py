@@ -43,13 +43,16 @@ logger = _create_logger("DeepSpeedTPU", _default_level())
 
 
 def _process_index() -> int:
-    """Current global rank. Safe to call before jax.distributed is initialized."""
-    try:
-        import jax
+    """Current global rank, WITHOUT initialising a jax backend: before one is
+    up the launcher's env answers. A log line must not be what makes a later
+    ``jax.distributed.initialize`` impossible, nor what claims the chip in a
+    process that only meant to spawn the one that needs it."""
+    import jax
+    from jax._src import xla_bridge
 
+    if xla_bridge.backends_are_initialized():
         return jax.process_index()
-    except Exception:
-        return int(os.environ.get("JAX_PROCESS_ID", os.environ.get("RANK", 0)))
+    return int(os.environ.get("JAX_PROCESS_ID") or os.environ.get("RANK") or 0)
 
 
 def log_dist(message: str, ranks=None, level: int = logging.INFO) -> None:

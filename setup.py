@@ -26,7 +26,7 @@ setup(
     version=_version(),
     description="TPU-native training/inference framework with DeepSpeed's capabilities",
     packages=find_packages(include=["deepspeed_tpu", "deepspeed_tpu.*"]),
-    python_requires=">=3.10",
-    install_requires=["jax", "flax", "optax", "orbax-checkpoint", "numpy", "pydantic>=2"],
+    python_requires=">=3.12",
+    install_requires=["jax>=0.9", "flax", "optax", "orbax-checkpoint", "numpy", "pydantic>=2"],
     scripts=["bin/deepspeed_tpu", "bin/ds_report", "bin/ds_bench", "bin/ds_elastic", "bin/ds_doctor"],
 )

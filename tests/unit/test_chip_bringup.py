@@ -1,0 +1,371 @@
+"""Chip bring-up rules, checked without a chip.
+
+Two kinds of test. (1) REHEARSAL against a compile-only v5e topology: the
+installed libtpu can describe a ``v5e:2x2`` slice inside a CPU-pinned
+process, and lowering/compiling against its devices runs the real Mosaic
+and XLA:TPU compilers — so "does the kernel lower at this shape, does the
+sharded step keep the kernel" is answered here, before chip time is spent.
+It says nothing about numerics or speed. (2) The RULES the bring-up set:
+no process holds a chip and then spawns a child that needs it, no fallback
+hides the device, one cache directory placed from outside.
+"""
+
+import dataclasses
+import functools
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from deepspeed_tpu.models import common
+from deepspeed_tpu.models.gpt2 import PRESETS, GPT2Config, GPT2Model
+from deepspeed_tpu.ops.pallas import decode_attention as da
+from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+# ------------------------------------------------------------- rehearsal
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a compile-only v5e 2x2 slice."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu / no topology support here
+        pytest.skip(f"get_topology_desc raised: {type(e).__name__}: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo.devices
+
+
+def _mesh(devices, **dims):
+    from deepspeed_tpu.parallel.topology import ALL_AXES
+
+    shape = [dims.get(a, 1) for a in ALL_AXES]
+    return Mesh(np.array(devices[:int(np.prod(shape))]).reshape(shape),
+                ALL_AXES)
+
+
+def _abstract(shape, dtype, mesh, spec=P()):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, spec))
+
+
+@pytest.mark.parametrize("heads,dim", [(16, 96), (12, 64), (16, 128)])
+def test_flash_fwd_bwd_compiles_for_v5e(v5e, heads, dim):
+    mesh = _mesh(v5e)
+    x = _abstract((1, 1024, heads, dim), jnp.bfloat16, mesh)
+    loss = lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v).astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3      # fwd, dq, dkv
+
+
+def test_decode_kernel_compiles_for_v5e_uninterpreted(v5e):
+    mesh = _mesh(v5e)
+    q = _abstract((2, 4, 96), jnp.bfloat16, mesh)
+    cache = _abstract((2, 1024, 4, 96), jnp.bfloat16, mesh)
+    pos = _abstract((), jnp.int32, mesh)
+    text = jax.jit(da.decode_attention).lower(
+        q, cache, cache, pos).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("t", [601, 1001])
+def test_prefill_at_untileable_prompt_length_compiles(v5e, t):
+    """Mosaic refuses a block whose row count is neither a multiple of 8
+    nor the whole array; T=601/1001 used to halve down to a 1-row block."""
+    mesh = _mesh(v5e)
+    model = GPT2Model(dataclasses.replace(PRESETS["gpt2-760m"], n_layer=2))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: _abstract(s.shape, jnp.bfloat16, mesh), shapes)
+
+    def prefill(p, ids):
+        return model.prefill(p, ids, model.init_cache(1, 1024))
+
+    with mesh:
+        text = jax.jit(prefill).lower(
+            params, _abstract((1, t), jnp.int32, mesh)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
+    """Bare GSPMD cannot partition a Mosaic call (jax raises at lowering on
+    more than one device): the kernel must sit in a shard_map manual over
+    every mesh axis. 16x96 heads, 24 layers, batch over data=4."""
+    mesh = _mesh(v5e, data=4)
+    model = GPT2Model(dataclasses.replace(PRESETS["gpt2-760m"], remat="attn"))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: _abstract(s.shape, jnp.bfloat16, mesh), shapes)
+    ids = _abstract((16, 1024), jnp.int32, mesh, P("data"))
+    with mesh:
+        lowered = jax.jit(jax.value_and_grad(
+            lambda p, b: model.loss(p, {"input_ids": b}))).lower(params, ids)
+    assert lowered.as_text().count("tpu_custom_call") == 4
+
+
+# ------------------------------------------------------- kernel dispatch
+@pytest.fixture
+def interpreted(monkeypatch):
+    """Run the Pallas kernels in interpret mode and make model code believe
+    its mesh is a TPU mesh — the kernel path on the CPU test mesh."""
+    from jax.experimental import pallas as pl
+
+    call = functools.partial(pl.pallas_call, interpret=True)
+    monkeypatch.setattr(fa.pl, "pallas_call", call)
+    monkeypatch.setattr(da.pl, "pallas_call", call)
+    real = common._kernel_target
+    monkeypatch.setattr(common, "_kernel_target", lambda: (real()[0], True))
+
+
+def _qkv(b, t, h, d, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(k, (b, t, h, d), jnp.float32) for k in keys)
+
+
+def test_flash_under_a_mesh_runs_in_shard_map_and_matches_einsum(interpreted):
+    """Batch over data=2, heads over tensor=2: the shard_map-wrapped kernel
+    equals the einsum path, values and gradients."""
+    mesh = _mesh(jax.devices(), data=2, tensor=2)
+    q, k, v = _qkv(4, 128, 4, 32)
+    sh = NamedSharding(mesh, P("data", None, "tensor", None))
+    q, k, v = (jax.device_put(x, sh) for x in (q, k, v))
+
+    def loss(use_flash):
+        return lambda q, k, v: jnp.sum(jnp.sin(
+            common.local_causal_attention(q, k, v, use_flash=use_flash)))
+
+    with mesh:
+        ker = jax.jit(jax.value_and_grad(loss(True), argnums=(0, 1, 2)))
+        assert "shard_map" in str(jax.make_jaxpr(loss(True))(q, k, v))
+        (lk, gk), (le, ge) = ker(q, k, v), jax.jit(jax.value_and_grad(
+            loss(False), argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(float(lk), float(le), rtol=1e-4)
+    for a, b in zip(gk, ge):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-3)
+
+
+def test_padded_causal_flash_matches_reference(interpreted):
+    """T > one block and not tileable: padded inside the wrapper, pad rows
+    sliced off — forward and backward equal the reference at length T."""
+    q, k, v = _qkv(1, 601, 2, 32, seed=1)
+    # 601 has no usable divisor; 520 = 8 x 65 would tile in 8-row blocks
+    assert fa._padded_len(601, 512) == fa._padded_len(520, 512) == 640
+    assert fa._padded_len(1024, 512) == 1024 and fa._padded_len(37, 512) == 37
+    loss = lambda f: lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+    out = fa.flash_attention(q, k, v)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(fa.mha_reference(q, k, v)),
+                               atol=2e-2, rtol=2e-2)
+    gk = jax.grad(loss(fa.flash_attention), argnums=(0, 1, 2))(q, k, v)
+    ge = jax.grad(loss(fa.mha_reference), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gk, ge):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-2, rtol=5e-2)
+
+
+def test_untileable_noncausal_takes_einsum_by_choice_and_kernel_refuses():
+    assert fa.flash_supports(601, 601, causal=True)
+    assert not fa.flash_supports(601, 601, causal=False)
+    assert fa.flash_supports(512, 512, causal=False)
+    q, k, v = _qkv(1, 601, 2, 32)
+    with pytest.raises(ValueError, match="do not tile"):
+        fa.flash_attention(q, k, v, causal=False)
+
+
+def test_kernel_failure_on_tpu_backend_propagates(monkeypatch):
+    """On a TPU backend a flash kernel that fails is an error — it must not
+    be caught and answered by the einsum path."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def boom(*a, **kw):
+        raise RuntimeError("mosaic says no")
+
+    monkeypatch.setattr(fa, "flash_attention", boom)
+    q, k, v = _qkv(1, 64, 2, 32)
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        common.local_causal_attention(q, k, v)
+    # and what the kernel does not carry is chosen up front, not on failure
+    out = common.local_causal_attention(q, k, v, window=8)
+    assert out.shape == q.shape
+
+
+def test_decode_kernel_never_interprets_itself():
+    """Off-TPU the decode kernel fails to lower instead of quietly running
+    in the interpreter; a test that wants the interpreter asks for it."""
+    q = jnp.zeros((1, 4, 64))
+    cache = jnp.zeros((1, 128, 4, 64))
+    with pytest.raises(Exception, match="[Ii]nterpret|TPU|tpu"):
+        da.decode_attention(q, cache, cache, jnp.int32(3))
+
+
+# ----------------------------------------------------------- engine rules
+def test_second_train_batch_does_not_compile_again():
+    """The TrainState scalars are committed to the mesh at init: typed like
+    the step's own outputs, so step 2 hits the program step 1 compiled."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import synthetic_lm_batch
+
+    cfg = GPT2Config(vocab_size=256, n_positions=32, n_embd=32, n_layer=1,
+                     n_head=2)
+    engine, *_ = deepspeed_tpu.initialize(model=GPT2Model(cfg), config={
+        "train_batch_size": 8, "bf16": {"enabled": True},
+        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+        "zero_optimization": {"stage": 1}, "steps_per_print": 0})
+    for seed in range(3):
+        engine.train_batch(synthetic_lm_batch(8, 16, cfg.vocab_size, seed))
+    (prog,) = engine._compiled_train_batch.values()
+    assert prog._cache_size() == 1
+
+
+def test_inference_tp_leaves_an_indivisible_vocab_whole():
+    """GPT-2's published vocab (50257) does not divide tp=4; the vocab dim
+    stays unsharded instead of failing the placement, and the tokens are
+    those of tp=1."""
+    import deepspeed_tpu
+    from deepspeed_tpu.comm import comm
+
+    cfg = GPT2Config(vocab_size=251, n_positions=32, n_embd=32, n_layer=1,
+                     n_head=4)
+    model = GPT2Model(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    prompt = np.arange(8, dtype=np.int32)[None] % cfg.vocab_size
+    outs = []
+    for tp in (1, 4):
+        comm.cdb = None
+        eng = deepspeed_tpu.init_inference(
+            model, dtype="float32", max_out_tokens=32, params=params,
+            tensor_parallel={"tp_size": tp})
+        assert eng.mesh.shape["tensor"] == tp
+        outs.append(np.asarray(eng.generate(prompt, max_new_tokens=8)))
+    assert eng.params["wte"].sharding.spec == P(None, None)
+    assert eng.params["blocks"]["qkv_w"].sharding.spec == P(None, None, "tensor")
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
+def test_explicit_tp_size_is_not_overridden_by_the_training_mesh():
+    """init_inference after a data=8 training run, asked for tp_size=2,
+    builds the tensor=2 mesh — it does not warn and serve on data=8."""
+    import deepspeed_tpu
+
+    cfg = GPT2Config(vocab_size=64, n_positions=32, n_embd=16, n_layer=1,
+                     n_head=2, use_flash_attention=False)
+    train, *_ = deepspeed_tpu.initialize(model=GPT2Model(cfg), config={
+        "train_batch_size": 8, "steps_per_print": 0,
+        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}})
+    assert train.mesh.shape["data"] == 8
+    eng = deepspeed_tpu.init_inference(GPT2Model(cfg), dtype="float32",
+                                       tensor_parallel={"tp_size": 2})
+    assert eng.mesh.shape["tensor"] == 2 and eng.mesh.shape["data"] == 4
+    same = deepspeed_tpu.init_inference(GPT2Model(cfg), dtype="float32")
+    assert same.mesh is eng.mesh        # no tp asked: the installed mesh
+
+
+# --------------------------------------------------------- process rules
+def _run(code, env=None, timeout=120):
+    e = {k: v for k, v in os.environ.items()
+         if k != "JAX_COMPILATION_CACHE_DIR" and not k.startswith("BENCH_")}
+    e.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO, **(env or {}))
+    return subprocess.run([sys.executable, "-c", code], env=e, cwd=REPO,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+def test_chip_smoke_without_a_chip_fails_at_the_device_check():
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert "no accelerator" in r.stderr and "'cpu'" in r.stderr
+    assert r.stdout.strip() == ""                # no result, no phase ran
+
+
+_CACHE_PROBE = """
+import jax
+from deepspeed_tpu.sharding import INHERIT, sharded_jit
+sharded_jit(lambda x: x, label="t/probe", in_shardings=INHERIT,
+            out_shardings=INHERIT, donate_argnums=())
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+def test_compile_cache_dir_is_placed_from_outside_or_fixed(tmp_path):
+    outside = _run(_CACHE_PROBE, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert outside.stdout.split() == [str(tmp_path)], outside.stderr[-2000:]
+    first, second = _run(_CACHE_PROBE), _run(_CACHE_PROBE)
+    assert first.stdout.split() == second.stdout.split() \
+        == [os.path.join(REPO, ".jax_cache")], first.stderr[-2000:]
+    sites = subprocess.run(
+        ["grep", "-rln", "--include=*.py", "compilation_cache",
+         "deepspeed_tpu"], cwd=REPO,
+        capture_output=True, text=True).stdout.split()
+    assert sites == ["deepspeed_tpu/sharding/jit.py"]
+
+
+def test_launcher_parent_counts_no_chips():
+    """build_resource_pool with no hostfile must not initialise a jax
+    backend: the parent would hold the chip its child needs."""
+    r = _run("""
+import argparse
+from deepspeed_tpu.launcher import runner
+pool = runner.build_resource_pool(argparse.Namespace(
+    hostfile="/nonexistent", include="", exclude="", num_nodes=-1, num_gpus=-1))
+from jax._src import xla_bridge
+assert not xla_bridge._backends, list(xla_bridge._backends)
+print(dict(pool))
+""")
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == "{'localhost': []}"
+
+
+def test_bench_ladder_parent_stays_off_jax_and_fails_on_a_failed_line(tmp_path):
+    """The ladder parent spawns every line (headline included) without ever
+    initialising a backend, and any FAILED line makes the exit non-zero."""
+    code = """
+import json, subprocess, sys
+sys.argv = ["bench.py"]
+import bench
+from jax._src import xla_bridge
+spawned = []
+def fake_run(cmd, env=None, **kw):
+    assert not xla_bridge._backends, list(xla_bridge._backends)
+    spawned.append(env.get("BENCH_MODEL") or "special")
+    ok = env.get("BENCH_MODEL") != "gpt2-xl"
+    out = json.dumps({"metric": "m", "value": 1.0, "unit": "MFU"}) if ok else ""
+    return subprocess.CompletedProcess(cmd, 0 if ok else 1, out, "boom")
+bench.subprocess = subprocess
+subprocess.run = fake_run
+bench.time.sleep = lambda s: None
+bench.EXPECTED.clear()
+try:
+    bench.main()
+except SystemExit as e:
+    print("SPAWNED", spawned[0], len(spawned), "EXIT", e.code)
+"""
+    r = _run(code, {"BENCH_LEDGER": str(tmp_path / "L.jsonl")})
+    assert r.returncode == 0, r.stderr[-3000:]
+    tail = r.stdout.strip().splitlines()[-1]
+    assert tail.startswith("SPAWNED gpt2-760m"), r.stdout[-2000:]
+    assert "gpt2-xl" in tail.split("EXIT", 1)[1]     # named, non-zero
+
+
+def test_bench_without_a_chip_exits_nonzero():
+    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
+                       env={k: v for k, v in dict(
+                           os.environ, JAX_PLATFORMS="cpu").items()
+                           if not k.startswith("BENCH_")},
+                       cwd=str(REPO), capture_output=True, text=True,
+                       timeout=300)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert "{" not in r.stdout                   # no line was measured

@@ -67,10 +67,8 @@ def test_traced_collectives_inside_shard_map(mesh_dp4_tp2):
         return s, g
 
     x = np.ones(8, np.float32)
-    from deepspeed_tpu.comm.comm import _shard_map
-
-    s, g = jax.jit(_shard_map(f, mesh=mesh, in_specs=P(("data", "tensor")),
-                              out_specs=(P(), P(("data", "tensor")))))(x)
+    s, g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(("data", "tensor")),
+                                 out_specs=(P(), P(("data", "tensor")))))(x)
     np.testing.assert_allclose(np.asarray(s), 8.0)
 
 

@@ -106,7 +106,6 @@ class TestLaunchEnv:
         assert env["JAX_NUM_PROCESSES"] == "2"
         assert env["JAX_PROCESS_ID"] == "1"
         assert env["RANK"] == "1" and env["WORLD_SIZE"] == "2"
-        assert env["DS_TPU_CHIPS"] == "0,1,2,3"
 
 
 class TestElasticity:

@@ -239,17 +239,35 @@ condition=%cond, body=%body
 # ----------------------------------------------------------------- chips
 @pytest.mark.analysis
 class TestChips:
-    def test_table_pinned_to_accelerator_peaks(self):
-        """chips.py restates tpu_accelerator's dicts without the jax
-        import — the two tables must never drift."""
-        from deepspeed_tpu.accelerator.tpu_accelerator import (_PEAK_FLOPS,
-                                                               _PEAK_HBM_BW)
+    def test_accelerator_reads_the_one_table(self, monkeypatch):
+        """The live accelerator has no peak numbers of its own: it reads
+        its device's row of chips.py, and a device_kind the table does not
+        list raises instead of defaulting to some chip."""
+        import jax
+
+        from deepspeed_tpu.accelerator import get_accelerator
         from deepspeed_tpu.analysis.chips import resolve_chip
 
-        for gen, flops in _PEAK_FLOPS.items():
-            spec = resolve_chip(gen if gen != "cpu" else "cpu-sim")
-            assert spec.peak_flops == flops, gen
-            assert spec.hbm_bytes_per_s == _PEAK_HBM_BW[gen], gen
+        acc = get_accelerator()
+        cpu = resolve_chip("cpu-sim")
+        assert acc.peak_flops() == cpu.peak_flops
+        assert acc.peak_flops("fp32") == cpu.peak_flops / 2
+        assert acc.memory_bandwidth() == cpu.hbm_bytes_per_s
+        assert acc.hbm_bytes() == cpu.hbm_bytes     # CPU: no memory_stats
+
+        class Dev:
+            platform, device_kind = "tpu", "TPU v5 lite"
+
+            def memory_stats(self):
+                return None
+
+        monkeypatch.setattr(jax, "local_devices", lambda: [Dev()])
+        assert acc.peak_flops() == resolve_chip("v5e").peak_flops == 197e12
+        assert acc.hbm_bytes() == 16 * 1024 ** 3
+        Dev.device_kind = "TPU v9 mystery"
+        for read in (acc.peak_flops, acc.memory_bandwidth, acc.hbm_bytes):
+            with pytest.raises(KeyError, match="TPU v9 mystery"):
+                read()
 
     def test_aliases_and_unknown(self):
         from deepspeed_tpu.analysis.chips import resolve_chip
@@ -265,7 +283,10 @@ class TestChips:
                                                   resolve_chip)
 
         assert detect_chip_name("TPU v5 lite", "tpu") == "v5e"
+        assert detect_chip_name("TPU v6 lite", "tpu") == "v6e"
         assert detect_chip_name("", "cpu") == "cpu-sim"
+        with pytest.raises(KeyError, match="not in the peak table"):
+            detect_chip_name("NVIDIA H100", "gpu")
         spec = resolve_chip("v4")
         assert spec.peak_flops_for("float32") == spec.peak_flops / 2
         assert spec.peak_flops_for("bf16") == spec.peak_flops

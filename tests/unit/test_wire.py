@@ -181,7 +181,6 @@ class TestQGZNumerics:
     def test_hierarchical_matches_flat_and_exact_mean(self):
         from deepspeed_tpu.runtime.wire import (
             hierarchical_quantized_allreduce, qgz_state_shapes)
-        from deepspeed_tpu.utils import shard_map_compat
 
         mesh = _qgz_mesh()
         n, W = 1000, 8
@@ -203,7 +202,7 @@ class TestQGZNumerics:
                     group_size=64)
                 return out[None], nwe[None], nse[None]
 
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 k, mesh=mesh,
                 in_specs=(P(("data", "ici")), P(("data", "ici")),
                           P(("data", "ici"))),
@@ -229,7 +228,6 @@ class TestQGZNumerics:
         its bias."""
         from deepspeed_tpu.runtime.wire import (
             hierarchical_quantized_allreduce, qgz_state_shapes)
-        from deepspeed_tpu.utils import shard_map_compat
 
         mesh = _qgz_mesh()
         n, W, steps = 256, 8, 24
@@ -244,7 +242,7 @@ class TestQGZNumerics:
                 bits=4, group_size=64)
             return out[None], nwe[None], nse[None]
 
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             k, mesh=mesh,
             in_specs=(P(("data", "ici")),) * 3,
             out_specs=(P(("data", "ici")),) * 3, check_vma=False)

@@ -1,0 +1,2 @@
+"""``train.flash_fwd_s_per_step``: read by ``benchmark/program_spans.py``."""
+from benchmark.program_spans import kernel_seconds_per_step as read  # noqa: F401

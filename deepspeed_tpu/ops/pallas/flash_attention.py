@@ -31,6 +31,13 @@ Causal execution (the perf-critical path for LM training):
 
 Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
 (B·H, T, D).
+
+Every ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv``; ``sparse_`` before each for the block-sparse kernels).
+That name is the last scope of the Mosaic call's ``op_name`` and so the name
+of its HLO instruction (``%flash_fwd.3 = ... custom-call(...)``), which is
+what an op event in a device profile is called — whatever wraps the call
+(``checkpoint``, ``shard_map``, the forward run again under remat).
 """
 
 from __future__ import annotations
@@ -249,6 +256,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
         qi_arr, ki_arr = _causal_pairs(nq)
         o, lse = pl.pallas_call(
             functools.partial(_fwd_tri_kernel, scale=scale, block=bq),
+            name="flash_fwd",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, len(qi_arr)),
@@ -277,6 +285,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
                                block_q=bq, block_k=bk, num_k=nk)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -455,6 +464,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
         # dq: iterate (qi, ki≤qi) row-major; first prefetch array indexes q/dq
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_tri_kernel, scale=scale, block=bq),
+            name="flash_bwd_dq",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, len(qi_arr)),
@@ -478,6 +488,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
         ki2, qi2 = _causal_pairs_colmajor(nq)
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_tri_kernel, scale=scale, block=bq, num_q=nq),
+            name="flash_bwd_dkv",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, len(ki2)),
@@ -506,6 +517,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, num_k=nk),
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -525,6 +537,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, num_q=nq),
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
@@ -751,6 +764,7 @@ def _sparse_forward(q, k, v, scale, causal, layout):
     o, lse = pl.pallas_call(
         functools.partial(_sparse_fwd_kernel, scale=scale, block=block,
                           causal=causal),
+        name="sparse_flash_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(bh, len(pf[0])),
@@ -797,6 +811,7 @@ def _sparse_backward(res, g, scale, causal, layout):
     dq = pl.pallas_call(
         functools.partial(_sparse_bwd_dq_kernel, scale=scale, block=block,
                           causal=causal),
+        name="sparse_flash_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(bh, len(pf_row[0])),
@@ -814,6 +829,7 @@ def _sparse_backward(res, g, scale, causal, layout):
     dk, dv = pl.pallas_call(
         functools.partial(_sparse_bwd_dkv_kernel, scale=scale, block=block,
                           causal=causal),
+        name="sparse_flash_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(bh, len(pf_col[0])),

@@ -33,6 +33,7 @@ and writes no heartbeat without it).
 
 from __future__ import annotations
 
+import contextvars
 import faulthandler
 import math
 import os
@@ -180,10 +181,13 @@ def run_with_deadline(fn: Callable, timeout: float, name: str = "op",
         raise ValueError(f"run_with_deadline({name!r}): timeout must be positive, got {timeout!r}")
     result: dict = {}
     done = threading.Event()
+    # the caller's context variables (the tracer's open span) go with the
+    # work, so what ``fn`` records hangs under the span that asked for it
+    ctx = contextvars.copy_context()
 
     def worker():
         try:
-            result["value"] = fn()
+            result["value"] = ctx.run(fn)
         except BaseException as e:  # noqa: BLE001 - re-raised in the caller
             result["error"] = e
         finally:

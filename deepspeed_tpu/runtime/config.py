@@ -500,8 +500,9 @@ class TelemetryConfig(DeepSpeedConfigModel):
     """Unified telemetry (deepspeed_tpu/telemetry/): process-wide metrics
     registry (counters / gauges / p50-p90-p99 histograms) + Chrome-trace
     step spans, exported to JSONL (``bin/ds_metrics`` renders it),
-    Prometheus text exposition, and the MonitorMaster fan-out. Zero
-    overhead when disabled (no-op registry); file exporters write from
+    Prometheus text exposition, and the MonitorMaster fan-out. Disabled,
+    the registry is a no-op and nothing is written; the span recorder
+    stays on as a bounded in-memory ring. File exporters write from
     process 0 only. See docs/CONFIG.md 'telemetry' section."""
     enabled: bool = Field(False, description="install the telemetry session at engine init")
     output_dir: str = Field("./ds_telemetry", description="rank-0 output directory for metrics.jsonl / metrics.prom / trace.json")

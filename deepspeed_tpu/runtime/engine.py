@@ -603,7 +603,7 @@ class DeepSpeedEngine:
                 # hook per-span peak deltas into the live tracer; sessions
                 # re-fetch through get_tracer(), so wrapping the session's
                 # tracer covers every instrumentation point
-                if session is not None and session.tracer is not _telemetry.NOOP_TRACER \
+                if session is not None and session.tracer is not None \
                         and not isinstance(session.tracer, SpanMemoryTracer):
                     session.tracer = SpanMemoryTracer(session.tracer)
         # ---- perf ledger recorder ----------------------------------------
@@ -1626,7 +1626,9 @@ class DeepSpeedEngine:
 
     def _train_batch_outer(self, batch, data_iter):
         gas = self._config.gradient_accumulation_steps
-        with _telemetry.get_tracer().span("data", step=getattr(self, "_host_step", 0)):
+        step = getattr(self, "_host_step", 0)
+        # fetching the batch and placing it on the mesh
+        with _telemetry.get_tracer().span("data", trace=step, step=step):
             if batch is None:
                 assert data_iter is not None, "train_batch needs a batch or data_iter"
                 batch = next(data_iter)
@@ -1640,20 +1642,6 @@ class DeepSpeedEngine:
             batch = self._shard_batch(batch)
         self.timers(TRAIN_BATCH_TIMER).start()
         self.tput_timer.start()
-        trace_dir = os.environ.get("DS_TPU_TRACE_DIR")
-        if trace_dir and getattr(self, "_host_step", 0) == 2:
-            # offload-path diagnosis knob (r4: llama collapsed to 40% of its
-            # recorded MFU under the driver with no way to see WHERE the step
-            # went): capture one post-warmup step as an XLA profiler trace —
-            # the streamed pull/update/write-back DMAs are in-trace ops, so
-            # host wall-clocks cannot attribute them; the trace can
-            import jax.profiler as _prof
-
-            with _prof.trace(trace_dir):
-                loss = self._train_batch_inner(batch, gas)
-            log_dist(f"profiler trace for step 3 written to {trace_dir}",
-                     ranks=[0])
-            return loss
         return self._train_batch_inner(batch, gas)
 
     def _train_batch_inner(self, batch, gas):
@@ -1736,69 +1724,91 @@ class DeepSpeedEngine:
                 log_dist(report.render("ds_doctor: batch shape changed"),
                          ranks=[0])
 
+    def _step_reads_host(self) -> bool:
+        """True where this step's own bookkeeping reads the step's outputs
+        on the host, and so waits for the device: a log line, the monitor,
+        a telemetry session, synchronized timers. Otherwise the step has no
+        host sync of its own and the caller's read of the loss ends it."""
+        every = self._config.steps_per_print
+        return bool(self.wall_clock_breakdown or self.monitor.enabled
+                    or _telemetry.get_session() is not None
+                    or (every and (getattr(self, "_host_step", 0) + 1)
+                        % every == 0))
+
     def _train_batch_instrumented(self, batch, gas):
-        with _telemetry.get_tracer().span("train_batch",
-                                          step=getattr(self, "_host_step", 0)):
+        tracer = _telemetry.get_tracer()
+        step = getattr(self, "_host_step", 0)
+        with tracer.span("train_batch", trace=step, step=step):
             if self._sdc is not None:
                 # audit-interval steps stash a device-side copy of the
                 # pre-step state + batch so after_step can replay the
                 # exact step against the same compiled program
-                self._sdc.maybe_stash(
-                    getattr(self, "_host_step", 0) + 1, batch, gas)
-            if self._nvme_optimizer is not None:
-                metrics = self._train_batch_nvme(batch, gas)
-            elif self._onebit:
-                phase = self.optimizer.phase_for_step(getattr(self, "_host_step", 0))
-                with self.mesh:
-                    self.state, metrics = self._get_compiled_onebit(
-                        gas, phase, batch)(self.state, batch)
-            elif self._overlap is not None and self._overlap.schedule == "serial":
-                # the measured un-overlapped ZeRO-3 schedule: a blocking,
-                # span-timed all-gather phase, then the compute program —
-                # what `overlap.schedule: "overlapped"` removes from the
-                # host timeline (runtime/overlap.py module docstring)
-                self.state, metrics = self._overlap.serial_step(
-                    self.state, batch, gas)
-            else:
-                with self.mesh:
-                    self.state, metrics = self._get_compiled_train_batch(
-                        gas, batch)(self.state, batch)
-            self._last_metrics = metrics
-            self.micro_steps += gas
-            self.global_samples += self.train_batch_size()
-            self._post_step(metrics)
-            if self._bad_step_sentinel is not None:
-                self._check_bad_step(metrics)
-            from deepspeed_tpu.resilience import chaos as _chaos_mod
+                self._sdc.maybe_stash(step + 1, batch, gas)
+            # the compiled step's call returns (the device runs on)
+            with tracer.span("dispatch", step=step):
+                if self._nvme_optimizer is not None:
+                    metrics = self._train_batch_nvme(batch, gas)
+                elif self._onebit:
+                    phase = self.optimizer.phase_for_step(step)
+                    with self.mesh:
+                        self.state, metrics = self._get_compiled_onebit(
+                            gas, phase, batch)(self.state, batch)
+                elif self._overlap is not None and \
+                        self._overlap.schedule == "serial":
+                    # the measured un-overlapped ZeRO-3 schedule: a
+                    # blocking, span-timed all-gather phase, then the
+                    # compute program — what `overlap.schedule:
+                    # "overlapped"` removes from the host timeline
+                    # (runtime/overlap.py module docstring)
+                    self.state, metrics = self._overlap.serial_step(
+                        self.state, batch, gas)
+                else:
+                    with self.mesh:
+                        self.state, metrics = self._get_compiled_train_batch(
+                            gas, batch)(self.state, batch)
+            if self._step_reads_host():
+                # the step's first host sync, in one named place: what
+                # follows would block on its first read anyway
+                with tracer.span("wait", step=step):
+                    jax.block_until_ready(metrics.loss)
+            # all host work after it
+            with tracer.span("post_step", step=step):
+                self._last_metrics = metrics
+                self.micro_steps += gas
+                self.global_samples += self.train_batch_size()
+                self._post_step(metrics)
+                if self._bad_step_sentinel is not None:
+                    self._check_bad_step(metrics)
+                from deepspeed_tpu.resilience import chaos as _chaos_mod
 
-            _inj = _chaos_mod.active_injector()
-            if _inj is not None and _inj.bitflip_armed():
-                # chaos `bitflip` fault class: corrupt the post-step state
-                # BEFORE the sdc audit looks at it — exactly the window a
-                # real cosmic-ray flip lands in
-                _flipped = _inj.perturb_state(self.state, self._host_step)
-                if _flipped is not None:
-                    self.state = _flipped
-            if self._sdc is not None:
-                # replay audit + blame; may raise FleetResizeEvent
-                # (quarantine-and-evict) or rewind the engine in place
-                self._sdc.after_step(self._host_step, metrics)
-            if self._gray is not None:
-                # fail-slow evidence fusion + microprobe; may raise
-                # FleetResizeEvent (quarantine-and-evict) or GrayError
-                self._gray.after_step(self._host_step, metrics)
-            if self._rewind is not None:
-                # AFTER the sentinel: a step the sentinel flagged (or a
-                # rewound-to step) must not enter the tier-0 ring
-                self._rewind.maybe_snapshot(self._host_step, metrics)
-            if self._blackbox is not None:
-                # flight-recorder heartbeat: one locked deque append — the
-                # rolling step tail every incident bundle ships
-                self._blackbox.on_step(self._host_step)
-            # the timer stop syncs on the loss, so the enclosing span's
-            # duration covers the device step, not just its dispatch
-            self.timers(TRAIN_BATCH_TIMER).stop(sync_obj=metrics.loss)
-            self.tput_timer.stop(global_step=True, sync_obj=metrics.loss)
+                _inj = _chaos_mod.active_injector()
+                if _inj is not None and _inj.bitflip_armed():
+                    # chaos `bitflip` fault class: corrupt the post-step state
+                    # BEFORE the sdc audit looks at it — exactly the window a
+                    # real cosmic-ray flip lands in
+                    _flipped = _inj.perturb_state(self.state, self._host_step)
+                    if _flipped is not None:
+                        self.state = _flipped
+                if self._sdc is not None:
+                    # replay audit + blame; may raise FleetResizeEvent
+                    # (quarantine-and-evict) or rewind the engine in place
+                    self._sdc.after_step(self._host_step, metrics)
+                if self._gray is not None:
+                    # fail-slow evidence fusion + microprobe; may raise
+                    # FleetResizeEvent (quarantine-and-evict) or GrayError
+                    self._gray.after_step(self._host_step, metrics)
+                if self._rewind is not None:
+                    # AFTER the sentinel: a step the sentinel flagged (or a
+                    # rewound-to step) must not enter the tier-0 ring
+                    self._rewind.maybe_snapshot(self._host_step, metrics)
+                if self._blackbox is not None:
+                    # flight-recorder heartbeat: one locked deque append — the
+                    # rolling step tail every incident bundle ships
+                    self._blackbox.on_step(self._host_step)
+                # the timer stop syncs on the loss, so the enclosing span's
+                # duration covers the device step, not just its dispatch
+                self.timers(TRAIN_BATCH_TIMER).stop(sync_obj=metrics.loss)
+                self.tput_timer.stop(global_step=True, sync_obj=metrics.loss)
         if self.eigenvalue is not None:
             # OUTSIDE the TRAIN_BATCH_TIMER/tput window AND the
             # train_batch span: the power-iteration estimate used to

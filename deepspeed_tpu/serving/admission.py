@@ -71,8 +71,10 @@ class Request:
     tokens: List[int] = dataclasses.field(default_factory=list)
     submitted_at: float = 0.0
     started_at: Optional[float] = None
+    prefill_done_at: Optional[float] = None   # the prefill tick returned
+    first_tokens_at: Optional[float] = None   # first chunk's tokens on the host
     finished_at: Optional[float] = None
-    ttft_s: Optional[float] = None
+    ttft_s: Optional[float] = None    # first_tokens_at - submitted_at
     _done: threading.Event = dataclasses.field(default_factory=threading.Event,
                                                repr=False)
 

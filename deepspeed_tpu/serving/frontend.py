@@ -394,7 +394,12 @@ class ServingFrontEnd:
                             self._resolve(req, "failed", "worker_dead")
                         self._in_flight = None
                         self._set_queue_gauge()
-                self._write_status()
+                # outside the request's span (its client has its answer
+                # already); ``request`` says whose resolution it follows
+                with _telemetry.get_tracer().span("status_write",
+                                                  cat="serving",
+                                                  request=req.id):
+                    self._write_status()
         except BaseException as e:      # noqa: BLE001 - last line of defense
             logger.error(f"serving worker died: {type(e).__name__}: {e}")
             with self._lock:
@@ -459,81 +464,122 @@ class ServingFrontEnd:
         deadline. The chaos ``decode_step`` hook runs INSIDE the deadline,
         so an injected hang trips it exactly like a real device wedge.
         Raises WatchdogTimeout (tick cap / hung step) or
-        _RequestDeadline (the request's own budget, drain cap)."""
+        _RequestDeadline (the request's own budget, drain cap).
+
+        The tick is one span (``prefill`` | ``decode``) from entry to
+        return, tiled by three children: ``tick_launch`` (entry until
+        ``fn()`` has returned in the deadline worker), ``tick_wait``
+        (``block_until_ready``) and ``tick_return`` (until this method
+        returns). The worker is a new thread per tick, so it only takes
+        the two inner stamps; the children are recorded from here."""
         import jax
 
-        now = time.monotonic()
-        remaining = req.deadline_at - now
-        if self._draining and self._drain_deadline is not None:
-            remaining = min(remaining, self._drain_deadline - now)
-        if remaining <= 0:
-            raise _RequestDeadline()
-        # a tick is "warm" only once its exact jit SPECIALIZATION has run:
-        # prefill specializes per prompt length; the decode chunk's call #1
-        # takes prefill outputs, call #2+ its OWN outputs — XLA may hand
-        # those back in another layout and specialize again — so the two
-        # call positions carry distinct warm keys. Until a specialization
-        # has run, the startup cap applies; a compile must never read as
-        # a hang.
-        cold = not self._warm.get(warm_key)
-        cap = float(self.cfg.startup_tick_timeout_s) if cold \
-            else float(self.cfg.decode_tick_timeout_s)
-        budget = max(0.01, min(cap, remaining))
-
-        def run():
-            from deepspeed_tpu.resilience.chaos import active_injector
-
-            inj = active_injector()
-            if inj is not None and inj.targets("decode_step"):
-                inj.before("decode_step", req.id)
-            with self.engine.mesh:
-                out = fn()
-                jax.block_until_ready(out)
-            return out
-
         phase = str(warm_key[0])        # "prefill" | "decode"
-        t_tick = time.monotonic()
-        try:
-            # request-scoped span: with the admission_wait span this lets
-            # ds_metrics --serving decompose TTFT into queue-wait vs
-            # compute, and a merged trace show WHICH request a tick served
-            with _telemetry.get_tracer().span(phase, cat="serving",
-                                              request=req.id):
+        tracer = _telemetry.get_tracer()
+        stamps: List[float] = []        # worker: fn() returned, outputs ready
+        # request-scoped span: with the admission_wait span this lets
+        # ds_metrics --serving decompose TTFT into queue-wait vs compute,
+        # and a device profile show WHICH request a tick served
+        with tracer.span(
+                phase, cat="serving", request=req.id,
+                context=int(req.prompt.shape[1]) + len(req.tokens),
+                index=len(req.tokens) // int(self.cfg.decode_tick_tokens)
+                ) as tick:
+            now = tick.t0
+            remaining = req.deadline_at - now
+            if self._draining and self._drain_deadline is not None:
+                remaining = min(remaining, self._drain_deadline - now)
+            if remaining <= 0:
+                raise _RequestDeadline()
+            # a tick is "warm" only once its exact jit SPECIALIZATION has
+            # run: prefill specializes per prompt length; the decode
+            # chunk's call #1 takes prefill outputs, call #2+ its OWN
+            # outputs — XLA may hand those back in another layout and
+            # specialize again — so the two call positions carry distinct
+            # warm keys. Until a specialization has run, the startup cap
+            # applies; a compile must never read as a hang.
+            cold = not self._warm.get(warm_key)
+            cap = float(self.cfg.startup_tick_timeout_s) if cold \
+                else float(self.cfg.decode_tick_timeout_s)
+            budget = max(0.01, min(cap, remaining))
+
+            def run():
+                from deepspeed_tpu.resilience.chaos import active_injector
+
+                inj = active_injector()
+                if inj is not None and inj.targets("decode_step"):
+                    inj.before("decode_step", req.id)
+                with self.engine.mesh:
+                    out = fn()
+                    stamps.append(time.monotonic())
+                    jax.block_until_ready(out)
+                    stamps.append(time.monotonic())
+                return out
+
+            try:
                 # a tick bound by the REQUEST's budget (budget < cap) that
                 # expires is a deadline over healthy compute, not a hang —
                 # it must not stamp a goodput watchdog_stall span
                 out = run_with_deadline(run, timeout=budget,
                                         name=f"serve-tick[{req.id}]",
                                         stall_span=budget >= cap)
-        except WatchdogTimeout:
-            if budget < cap:
-                # the request's own budget (or the drain cap) was the
-                # binding constraint — that is a deadline, not a hang
-                raise _RequestDeadline() from None
-            raise
+            except WatchdogTimeout:
+                if budget < cap:
+                    # the request's own budget (or the drain cap) was the
+                    # binding constraint — that is a deadline, not a hang
+                    raise _RequestDeadline() from None
+                raise
+            self._warm[warm_key] = self._warm.get(warm_key, 0) + 1
+            # "K consecutive decode-step failures" is TICK-granular: every
+            # healthy tick resets the streak (a deadline-partial request
+            # full of good ticks is not evidence of a sick engine), and a
+            # working tick is what closes a half-open circuit
+            self.breaker.record_success()
+        launched, ready = stamps
+        for name, t0, t1 in (("tick_launch", tick.t0, launched),
+                             ("tick_wait", launched, ready),
+                             ("tick_return", ready, tick.t1)):
+            tracer.record(name, t0, t1, cat="serving", parent=tick,
+                          request=req.id)
+        if phase == "prefill":
+            req.prefill_done_at = tick.t1
         self._reg().histogram(
             f"serving/{'prefill' if phase == 'prefill' else 'decode_chunk'}"
-            "_seconds").observe(time.monotonic() - t_tick)
-        self._warm[warm_key] = self._warm.get(warm_key, 0) + 1
-        # "K consecutive decode-step failures" is TICK-granular: every
-        # healthy tick resets the streak (a deadline-partial request full
-        # of good ticks is not evidence of a sick engine), and a working
-        # tick is what closes a half-open circuit
-        self.breaker.record_success()
+            "_seconds").observe(tick.dur)
         return out
 
     def _process(self, req: Request) -> None:
+        tracer = _telemetry.get_tracer()
+        # the request's own span, from here to its resolution; every span
+        # below hangs under it and shares its ``trace`` (the request id)
+        with tracer.span("request", cat="serving", trace=req.id,
+                         request=req.id) as span:
+            req.started_at = span.t0
+            try:
+                self._serve(req, tracer)
+            finally:
+                # a probe that ended with NO tick verdict (expired in
+                # queue, drain-capped before its first tick) must hand the
+                # half-open slot back, or the breaker wedges in half_open
+                self.breaker.release_probe()
+                # whoever reads the spans keeps no handle to the Request
+                span.args.update(
+                    prompt_len=int(req.prompt.shape[1]),
+                    new_tokens=len(req.tokens), status=req.status,
+                    prefill_done_at=req.prefill_done_at,
+                    first_tokens_at=req.first_tokens_at)
+
+    def _serve(self, req: Request, tracer) -> None:
         import jax
 
-        req.started_at = time.monotonic()
         req.status = "running"
         reg = self._reg()
         wait_s = req.started_at - req.submitted_at
         reg.histogram("serving/queue_wait_seconds").observe(wait_s)
-        # the admission wait as a complete span ending NOW: the first leg
-        # of the request-scoped admission_wait -> prefill -> decode chain
-        _telemetry.get_tracer().complete("admission_wait", wait_s * 1e6,
-                                         cat="serving", request=req.id)
+        # the first leg of the request-scoped admission_wait -> prefill ->
+        # decode chain, from the two stamps the request already carries
+        tracer.record("admission_wait", req.submitted_at, req.started_at,
+                      cat="serving", request=req.id)
         eos = 0 if req.eos_token_id is None else max(int(req.eos_token_id), 0)
         pkey = self._program_key(req)
         try:
@@ -556,23 +602,30 @@ class ServingFrontEnd:
                     warm_key=("decode", pkey, min(chunk_i, 1)))
                 chunk_i += 1
                 logits, cache, done, rng, toks = out
-                fresh = np.asarray(toks)[0].tolist()
-                take = min(len(fresh), req.max_new_tokens - len(req.tokens))
-                fresh = fresh[:take]
-                req.tokens.extend(fresh)
-                self._count("tokens_streamed", n=len(fresh))
-                if req.ttft_s is None:
-                    req.ttft_s = time.monotonic() - req.submitted_at
-                    reg.histogram("serving/ttft_seconds").observe(req.ttft_s)
-                    reg.histogram("serving/ttft_deadline_fraction").observe(
-                        req.ttft_s / req.deadline_s)
-                self._flush_stream(req, fresh)
-                if bool(np.asarray(done).all()):
-                    # parity with generate(): post-EOS positions hold EOS
-                    pad = req.max_new_tokens - len(req.tokens)
-                    if pad > 0:
-                        req.tokens.extend([eos] * pad)
-                        self._flush_stream(req, [eos] * pad)
+                # the chunk's tokens come to the host and go to the client
+                with tracer.span("deliver", cat="serving", request=req.id):
+                    fresh = np.asarray(toks)[0].tolist()
+                    take = min(len(fresh),
+                               req.max_new_tokens - len(req.tokens))
+                    fresh = fresh[:take]
+                    req.tokens.extend(fresh)
+                    self._count("tokens_streamed", n=len(fresh))
+                    if req.first_tokens_at is None:
+                        req.first_tokens_at = time.monotonic()
+                        req.ttft_s = req.first_tokens_at - req.submitted_at
+                        reg.histogram("serving/ttft_seconds").observe(
+                            req.ttft_s)
+                        reg.histogram("serving/ttft_deadline_fraction"
+                                      ).observe(req.ttft_s / req.deadline_s)
+                    self._flush_stream(req, fresh)
+                    finished = bool(np.asarray(done).all())
+                    if finished:
+                        # parity with generate(): post-EOS positions hold EOS
+                        pad = req.max_new_tokens - len(req.tokens)
+                        if pad > 0:
+                            req.tokens.extend([eos] * pad)
+                            self._flush_stream(req, [eos] * pad)
+                if finished:
                     break
             self._observe_service(req)
             self._count("completed")
@@ -609,11 +662,6 @@ class ServingFrontEnd:
                          f"{type(e).__name__}: {e}", exc_info=True)
             self._resolve(req, "partial" if req.tokens else "failed",
                           f"error: {type(e).__name__}: {e}")
-        finally:
-            # a probe that ended with NO tick verdict (expired in queue,
-            # drain-capped before its first tick) must hand the half-open
-            # slot back, or the breaker wedges in half_open forever
-            self.breaker.release_probe()
 
     def _flush_stream(self, req: Request, toks: List[int]) -> None:
         if req.stream is None or not toks:

@@ -15,6 +15,10 @@ Wraps ``jax.jit`` with three obligations the bare call lets you skip:
   ``(label, call site, mesh axes, in/out spec summary, donation)`` —
   which ``ds_report mesh`` renders and the ds_doctor
   ``sharding/unspecified-jit`` lint audits.
+* every call that makes a program specialize (a new shape, dtype,
+  placement or committed-ness of an operand: a trace and, unless a cache
+  has it, a compile) is counted at the door: :func:`door_events` says
+  WHICH label went through and when.
 
 The wrapper is intentionally thin: it resolves :data:`INHERIT` to the
 ``None`` jax.jit spells inference with, registers the record, and returns
@@ -26,14 +30,15 @@ from __future__ import annotations
 import dataclasses
 import inspect
 import os
+import time
 import weakref
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
 __all__ = ["INHERIT", "ProgramRecord", "program_table", "sharded_jit",
            "render_program_table", "reset_program_table",
-           "describe_shardings"]
+           "describe_shardings", "door_events"]
 
 
 class _Inherit:
@@ -110,6 +115,18 @@ def program_table() -> Dict[str, ProgramRecord]:
 def reset_program_table() -> None:
     with _LOCK:
         _PROGRAMS.clear()
+
+
+# (time.monotonic(), label, specializations of that program so far), one
+# per call that added a specialization; appends are atomic, nothing is
+# removed (the count is bounded by programs x shapes)
+_DOOR_EVENTS: List[Tuple[float, str, int]] = []
+
+
+def door_events() -> List[Tuple[float, str, int]]:
+    """Every specialization this process's programs went through, in
+    order: ``(time.monotonic(), label, specializations so far)``."""
+    return list(_DOOR_EVENTS)
 
 
 _REPO_CACHE_DIR = os.path.join(
@@ -219,14 +236,21 @@ class _ShardedProgram:
     record — that snapshot is what lets ``ds_doctor xray`` AOT
     lower+compile the exact program later, with no engine in hand.
     Snapshot cost is paid once; afterwards ``__call__`` is one flag
-    check on top of the pjit fast path."""
+    check and one read of the callable's specialization count
+    (``_cache_size()``, ~0.06 µs) on top of the pjit fast path. A call
+    after which that count has grown went through trace and compile (or
+    a cache load): it is noted as a door event and as an instant
+    ``door_compile`` in the tracer."""
 
-    __slots__ = ("_jitted", "program_record", "_captured")
+    __slots__ = ("_jitted", "program_record", "_captured", "_cache_size",
+                 "_specializations")
 
     def __init__(self, jitted, record: ProgramRecord):
         self._jitted = jitted
         self.program_record = record
         self._captured = False
+        self._cache_size = jitted._cache_size
+        self._specializations = 0
 
     def _capture(self, args, kwargs):
         self._captured = True
@@ -242,7 +266,24 @@ class _ShardedProgram:
     def __call__(self, *args, **kwargs):
         if not self._captured:
             self._capture(args, kwargs)
-        return self._jitted(*args, **kwargs)
+        out = self._jitted(*args, **kwargs)
+        n = self._cache_size()
+        if n != self._specializations:
+            self._note_specialization(n)
+        return out
+
+    def _note_specialization(self, n: int) -> None:
+        grew = n > self._specializations    # not: jax dropped its caches
+        self._specializations = n
+        if not grew:
+            return
+        from deepspeed_tpu import telemetry
+
+        label = self.program_record.label
+        _DOOR_EVENTS.append((time.monotonic(), label, n))
+        # not named "compile": goodput books that as compile badput
+        telemetry.get_tracer().instant("door_compile", cat="door",
+                                       label=label, specializations=n)
 
     def __getattr__(self, name):
         return getattr(self._jitted, name)

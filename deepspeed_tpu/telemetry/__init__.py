@@ -14,11 +14,11 @@ monitor fan-out). Here one process-wide *session* owns:
   (TensorBoard/W&B/CSV get the series for free).
 
 Enabled by the ``telemetry`` ds_config block (engine init calls
-:func:`configure`); when off, :func:`get_registry` / :func:`get_tracer`
-return shared no-op singletons so every instrumentation point in the
-codebase costs one call into a ``pass`` (the ``NoopTimer`` pattern).
-Instrumented layers NEVER hold the registry across a reconfigure — they
-re-fetch through the module functions.
+:func:`configure`); when off, :func:`get_registry` returns a shared no-op
+singleton (every metric update costs one call into a ``pass``) and
+:func:`get_tracer` the process-wide span ring (``tracing.RING``: the last
+``RING_SPANS`` spans, always recorded). Instrumented layers NEVER hold
+either across a reconfigure — they re-fetch through the module functions.
 """
 
 from __future__ import annotations
@@ -32,12 +32,12 @@ from deepspeed_tpu.telemetry.exporters import (JSONLExporter, MonitorExporter,
 from deepspeed_tpu.telemetry.registry import (NOOP_REGISTRY, Counter, Gauge,
                                               Histogram, MetricsRegistry,
                                               NoopRegistry)
-from deepspeed_tpu.telemetry.tracing import NOOP_TRACER, NoopTracer, StepTracer
+from deepspeed_tpu.telemetry.tracing import RING, Span, StepTracer
 from deepspeed_tpu.utils.logging import logger
 
 __all__ = [
     "MetricsRegistry", "NoopRegistry", "Counter", "Gauge", "Histogram",
-    "StepTracer", "NoopTracer", "TelemetrySession", "JSONLExporter",
+    "StepTracer", "Span", "RING", "TelemetrySession", "JSONLExporter",
     "PrometheusExporter", "MonitorExporter", "configure", "install_session",
     "deconfigure", "get_session", "get_registry", "get_tracer", "flush",
     "METRICS_FILE", "PROMETHEUS_FILE", "TRACE_FILE",
@@ -66,8 +66,9 @@ class TelemetrySession:
             default_max_samples=cfg.histogram_max_samples,
             default_bounds=cfg.histogram_buckets or None)
         rank = jax.process_index()
+        # None with ``trace: false``: get_tracer() then hands out the ring
         self.tracer = (StepTracer(max_events=cfg.max_trace_events, pid=rank)
-                       if cfg.trace else NOOP_TRACER)
+                       if cfg.trace else None)
         # new session = new trace file + clock: restart the comm layer's
         # per-(op, group) collective seq counters with it, so every rank's
         # (op, seq, group) trace identities stay alignable by ds_prof even
@@ -207,7 +208,9 @@ def get_registry():
 
 
 def get_tracer():
-    return _session.tracer if _session is not None else NOOP_TRACER
+    """The live session's tracer, or the always-there span ring."""
+    s = _session
+    return s.tracer if s is not None and s.tracer is not None else RING
 
 
 def flush() -> None:

@@ -13,6 +13,7 @@ hides the device, one cache directory placed from outside.
 import dataclasses
 import functools
 import os
+import re
 import subprocess
 import sys
 
@@ -67,6 +68,13 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, heads, dim):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
     assert text.count("tpu_custom_call") == 3      # fwd, dq, dkv
+    # each Mosaic call is an HLO instruction named after the kernel's own
+    # ``name`` (here inside the transform's: %jvp_flash_fwd_.1): that name
+    # is what an op event of a device profile carries
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert len(re.findall(
+            rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", text)) == 1, \
+            kernel
 
 
 def test_decode_kernel_compiles_for_v5e_uninterpreted(v5e):

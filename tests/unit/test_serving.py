@@ -231,6 +231,159 @@ class TestAdmission:
 
 
 @pytest.mark.serving
+class TestSpans:
+    """The front-end's own spans and stamps (the names are a contract with
+    benchmark/program_spans.py and docs/CONFIG.md)."""
+
+    @staticmethod
+    def _serve_one(engine, new_tokens=12, status="completed"):
+        from deepspeed_tpu import telemetry
+
+        tracer = telemetry.get_tracer()
+        before = {s.id for s in tracer.snapshot()}
+        fe = _frontend(engine)
+        try:
+            r = fe.submit(_prompt(), max_new_tokens=new_tokens)
+            r.result(timeout=300)
+            assert r.status == status
+        finally:
+            fe.close()      # joins the worker: status_write is recorded
+        return r, [s for s in tracer.snapshot() if s.id not in before
+                   and s.args.get("request") == r.id]
+
+    def test_no_session_records_into_the_ring(self, engine):
+        from deepspeed_tpu import telemetry
+        from deepspeed_tpu.telemetry import tracing
+
+        assert telemetry.get_session() is None
+        assert telemetry.get_tracer() is tracing.RING
+        _, spans = self._serve_one(engine, new_tokens=4)
+        assert {s.name for s in spans} == {
+            "request", "admission_wait", "prefill", "decode", "tick_launch",
+            "tick_wait", "tick_return", "deliver", "status_write"}
+
+    def test_stamps_are_ordered_and_in_the_request_span(self, engine):
+        r, spans = self._serve_one(engine)
+        assert r.submitted_at <= r.started_at <= r.prefill_done_at \
+            <= r.first_tokens_at <= r.finished_at
+        assert r.ttft_s == r.first_tokens_at - r.submitted_at
+        (req,) = [s for s in spans if s.name == "request"]
+        assert req.t0 == r.started_at and req.t1 >= r.finished_at
+        assert req.args == {
+            "request": r.id, "prompt_len": 8, "new_tokens": 12,
+            "status": "completed", "prefill_done_at": r.prefill_done_at,
+            "first_tokens_at": r.first_tokens_at}
+        (wait,) = [s for s in spans if s.name == "admission_wait"]
+        assert (wait.t0, wait.t1) == (r.submitted_at, r.started_at)
+        (prefill,) = [s for s in spans if s.name == "prefill"]
+        assert prefill.t1 == r.prefill_done_at
+
+    def test_spans_of_a_request_form_one_tree(self, engine):
+        r, spans = self._serve_one(engine)      # 12 tokens: 3 decode ticks
+        assert len({s.id for s in spans}) == len(spans)
+        by_id = {s.id: s for s in spans}
+        (req,) = [s for s in spans if s.name == "request"]
+        assert req.parent is None
+        tree = [s for s in spans if s.name != "status_write"]
+        assert all(s.trace == r.id for s in tree)
+        for s in tree:
+            if s is not req:
+                want = ("request",) if s.name in (
+                    "admission_wait", "prefill", "decode", "deliver") \
+                    else ("prefill", "decode")
+                assert by_id[s.parent].name in want, (s.name, s.parent)
+        decodes = [s for s in spans if s.name == "decode"]
+        assert [s.args["index"] for s in decodes] == [0, 1, 2]
+        assert [s.args["context"] for s in decodes] == [8, 12, 16]
+        assert len([s for s in spans if s.name == "deliver"]) == 3
+        # the status write follows the request and is not part of its tree
+        (status,) = [s for s in spans if s.name == "status_write"]
+        assert status.parent is None and status.trace is None
+        assert status.t0 >= req.t1
+
+    def test_three_children_tile_the_tick(self, engine):
+        _, spans = self._serve_one(engine)
+        ticks = [s for s in spans if s.name in ("prefill", "decode")]
+        assert len(ticks) == 4
+        for tick in ticks:
+            kids = sorted((s for s in spans if s.parent == tick.id),
+                          key=lambda s: s.t0)
+            assert [k.name for k in kids] == [
+                "tick_launch", "tick_wait", "tick_return"]
+            assert kids[0].t0 == tick.t0 and kids[2].t1 == tick.t1
+            assert kids[0].t1 == kids[1].t0 and kids[1].t1 == kids[2].t0
+            assert abs(sum(k.dur for k in kids) - tick.dur) < 50e-6
+        # a decode tick and its deliver follow each other at once
+        decodes = [s for s in spans if s.name == "decode"]
+        delivers = [s for s in spans if s.name == "deliver"]
+        for tick, deliver in zip(decodes, delivers):
+            assert 0 <= deliver.t0 - tick.t1 < 1e-3
+
+    def test_a_tick_that_dies_records_no_children(self, engine):
+        from deepspeed_tpu.resilience.chaos import (ChaosInjector,
+                                                    install_chaos)
+
+        install_chaos(ChaosInjector(fail_at={"decode_step": [2]}))
+        r, spans = self._serve_one(engine, new_tokens=8, status="failed")
+        (req,) = [s for s in spans if s.name == "request"]
+        assert req.args["status"] == "failed"
+        assert req.args["first_tokens_at"] is None
+        (dead,) = [s for s in spans if s.name == "decode"]
+        assert not [s for s in spans if s.parent == dead.id]
+
+    def test_every_span_is_in_a_device_profile_as_ds(self, engine, tmp_path):
+        """The with-spans enter a TraceAnnotation ``ds/serving/<name>``
+        while a profile is being taken; the three children of a tick are
+        stamped in a short-lived worker thread and live in the ring only;
+        no program span may pose as the benchmark's (``bench/``)."""
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            r, _ = self._serve_one(engine, new_tokens=4)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        events = [e for plane in jax.profiler.ProfileData.from_file(
+                      str(path)).planes if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith(("ds/", "bench/"))]
+        assert {e.name for e in events} == {
+            "ds/serving/request", "ds/serving/prefill", "ds/serving/decode",
+            "ds/serving/deliver", "ds/serving/status_write"}
+        decode = next(e for e in events if e.name == "ds/serving/decode")
+        stats = {k: str(v) for k, v in decode.stats}
+        assert stats["request"] == r.id and stats["trace"] == r.id
+        assert stats["context"] == "8" and stats["index"] == "0"
+
+    def test_histograms_observe_the_spans_own_durations(self, engine,
+                                                        tmp_path):
+        from deepspeed_tpu import telemetry
+        from deepspeed_tpu.runtime.config import TelemetryConfig
+
+        tel = telemetry.configure(TelemetryConfig(
+            enabled=True, output_dir=str(tmp_path / "t"),
+            flush_interval=1000, prometheus=False))
+        try:
+            r, spans = self._serve_one(engine, new_tokens=4)
+            assert spans and telemetry.get_tracer() is tel.tracer
+            hist = {rec["name"]: rec for rec in tel.registry.snapshot()
+                    if rec["name"].startswith("serving/")}
+            one = lambda name: next(s for s in spans if s.name == name).dur
+            for series, want in (
+                    ("serving/prefill_seconds", one("prefill")),
+                    ("serving/decode_chunk_seconds", one("decode")),
+                    ("serving/queue_wait_seconds", one("admission_wait")),
+                    ("serving/ttft_seconds", r.ttft_s)):
+                assert hist[series]["count"] == 1
+                assert hist[series]["max"] == pytest.approx(want, abs=1e-9)
+        finally:
+            telemetry.deconfigure()
+
+
+@pytest.mark.serving
 @pytest.mark.chaos
 class TestFailurePaths:
     def test_request_deadline_caps_decode(self, engine):

@@ -11,6 +11,8 @@ import os
 import re
 import subprocess
 import sys
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -25,7 +27,7 @@ from deepspeed_tpu.resilience import ChaosInjector, install_chaos, uninstall_cha
 from deepspeed_tpu.runtime.config import DeepSpeedConfig, TelemetryConfig
 from deepspeed_tpu.telemetry import (MetricsRegistry, NoopRegistry,
                                      PrometheusExporter, StepTracer,
-                                     TelemetrySession)
+                                     TelemetrySession, tracing)
 from deepspeed_tpu.telemetry.registry import NOOP_REGISTRY
 
 HIDDEN = 16
@@ -159,6 +161,149 @@ class TestTracer:
                 pass
         assert len(tr.events) == 3
         assert tr.dropped == 7
+        assert [s.name for s in tr.snapshot()] == ["s0", "s1", "s2"]
+
+    def test_ring_is_bounded_and_overwrites_the_oldest(self):
+        tr = StepTracer(max_events=3, ring=True)
+        assert not tr.wrapped
+        for i in range(10):
+            with tr.span(f"s{i}"):
+                pass
+        assert [s.name for s in tr.snapshot()] == ["s7", "s8", "s9"]
+        assert [e["name"] for e in tr.events] == ["s7", "s8", "s9"]
+        assert tr.dropped == 0 and tr.wrapped
+        assert tracing.RING.ring and tracing.RING.max_events == \
+            tracing.RING_SPANS >= 16_000
+
+    def test_span_records_absolute_monotonic_ids_parents_and_trace(self):
+        tr = StepTracer()
+        t_before = time.monotonic()
+        with tr.span("train_batch", trace=7, step=7) as outer:
+            with tr.span("dispatch", step=7) as inner:
+                pass
+            late = tr.record("wait", inner.t1, inner.t1 + 0.5, step=7)
+            tr.instant("door_compile", cat="door", label="x")
+        other = tr.record("data", 1.0, 2.0, trace=8)
+        t_after = time.monotonic()
+        spans = tr.snapshot()
+        assert [s.name for s in spans] == [
+            "dispatch", "wait", "door_compile", "train_batch", "data"]
+        assert len({s.id for s in spans}) == 5
+        assert t_before <= outer.t0 <= inner.t0 <= inner.t1 <= outer.t1 \
+            <= t_after
+        assert outer.parent is None and other.parent is None
+        assert inner.parent == late.parent == spans[2].parent == outer.id
+        # children take their trace from the span above; an explicit one wins
+        assert [s.trace for s in spans] == [7, 7, 7, 7, 8]
+        assert (late.t0, late.t1, late.dur) == (inner.t1, inner.t1 + 0.5, 0.5)
+        assert spans[2].t1 is None and spans[2].dur == 0.0     # an instant
+        assert inner.args == {"step": 7}
+
+    def test_explicit_parent_and_the_deadline_worker(self):
+        """Parents cross threads two ways: ``record(parent=...)`` after the
+        fact, and ``run_with_deadline`` carrying the open span into its
+        worker."""
+        from deepspeed_tpu.resilience.watchdog import run_with_deadline
+
+        tr = StepTracer()
+        with tr.span("decode", cat="serving", trace="req-1") as tick:
+            run_with_deadline(
+                lambda: tr.instant("door_compile", cat="door"), timeout=30)
+        child = tr.record("tick_wait", tick.t0, tick.t1, cat="serving",
+                          parent=tick)
+        (door,) = [s for s in tr.snapshot() if s.name == "door_compile"]
+        assert door.parent == child.parent == tick.id
+        assert door.trace == child.trace == "req-1"
+        # a thread started any other way begins with no span above it
+        t = threading.Thread(target=lambda: tr.instant("lone"))
+        with tr.span("outer"):
+            t.start()
+            t.join(30)
+        (lone,) = [s for s in tr.snapshot() if s.name == "lone"]
+        assert lone.parent is None
+
+    def test_chrome_events_keep_their_keys(self):
+        tr = StepTracer(pid=2)
+        with tr.span("train_batch", trace=4, step=4) as sp:
+            tr.complete("comm:all_reduce", 250.0, cat="comm", op="all_reduce")
+            tr.instant("sentinel_rewind", cat="resilience")
+        comm_ev, instant, span = tr.to_chrome_trace()["traceEvents"][1:]
+        for ev in (comm_ev, span):
+            assert set(ev) == {"name", "cat", "ph", "ts", "dur", "pid", "tid",
+                               "args"}
+            assert ev["ph"] == "X" and ev["pid"] == 2 and ev["tid"] == 0
+        assert set(instant) == {"name", "cat", "ph", "s", "ts", "pid", "tid",
+                                "args"} and instant["ph"] == "i"
+        assert span["args"] == {"step": 4, "id": sp.id, "parent": None,
+                                "trace": 4}
+        assert comm_ev["args"]["parent"] == sp.id
+        assert comm_ev["dur"] == pytest.approx(250.0)
+        # µs since the tracer's start, derived from the absolute stamps
+        assert span["ts"] == pytest.approx((sp.t0 - tr._t0) * 1e6)
+        assert span["dur"] == pytest.approx(sp.dur * 1e6)
+        # a session's list is converted once and then only appended to
+        first = tr.events
+        with tr.span("step"):
+            pass
+        assert tr.events is first and len(first) == 4
+
+    def test_span_is_in_a_profile_as_ds_and_never_as_bench(self, tmp_path):
+        import jax
+
+        tr = StepTracer()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with tr.span("train_batch", trace=3, step=3):
+                with tr.span("save_checkpoint", cat="checkpoint"):
+                    pass
+            tr.record("wait", 1.0, 2.0)         # stamps only: ring only
+        finally:
+            jax.profiler.stop_trace()
+        with tr.span("after"):                  # no profile: no annotation
+            pass
+        (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        events = {e.name: {k: str(v) for k, v in e.stats}
+                  for plane in jax.profiler.ProfileData.from_file(
+                      str(path)).planes if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith(("ds/", "bench/"))}
+        assert set(events) == {"ds/train/train_batch",
+                               "ds/checkpoint/save_checkpoint"}
+        assert events["ds/train/train_batch"] == {"trace": "3", "step": "3"}
+
+
+class TestProgramDoor:
+    def test_one_event_per_new_specialization_none_on_a_warm_call(self):
+        from deepspeed_tpu.sharding import INHERIT, sharded_jit
+        from deepspeed_tpu.sharding.jit import door_events
+
+        label = "test/door_double"
+        double = sharded_jit(lambda x: x * 2, label=label,
+                             in_shardings=INHERIT, out_shardings=INHERIT,
+                             donate_argnums=())
+        mine = lambda: [e for e in door_events() if e[1] == label]
+        ring = telemetry.get_tracer()
+        instants = lambda: [s for s in ring.snapshot()
+                            if s.name == "door_compile"
+                            and s.args["label"] == label]
+        assert mine() == [] and instants() == []
+        t0 = time.monotonic()
+        double(jnp.ones(4))                     # first shape: through the door
+        assert [(l, n) for _, l, n in mine()] == [(label, 1)]
+        assert t0 <= mine()[0][0] <= time.monotonic()
+        for _ in range(3):                      # warm: nothing
+            double(jnp.ones(4))
+        assert len(mine()) == 1
+        double(jnp.ones(5))                     # a new shape: once more
+        double(jnp.ones(4))
+        double(jnp.ones(5))
+        assert [(l, n) for _, l, n in mine()] == [(label, 1), (label, 2)]
+        # each is also an instant in the tracer, under a name goodput does
+        # not book as compile badput
+        assert [s.args["specializations"] for s in instants()] == [1, 2]
+        assert all(s.cat == "door" and s.t1 is None for s in instants())
 
 
 # ----------------------------------------------------- prometheus exposition
@@ -192,7 +337,7 @@ class TestPrometheusFormat:
 
 # --------------------------------------------------------- disabled = no-op
 class TestDisabledNoop:
-    def test_module_defaults_are_noop(self):
+    def test_module_defaults_registry_noop_tracer_ring(self):
         assert telemetry.get_session() is None
         reg = telemetry.get_registry()
         assert isinstance(reg, NoopRegistry) and not reg.enabled
@@ -200,9 +345,27 @@ class TestDisabledNoop:
         reg.gauge("x").set(1)
         reg.histogram("x").observe(1)
         assert len(reg) == 0 and reg.snapshot() == []
-        with telemetry.get_tracer().span("fwd"):
+        # the span recorder is always there: the process-wide ring
+        tracer = telemetry.get_tracer()
+        assert tracer is tracing.RING
+        with tracer.span("fwd", step=41) as sp:
             pass
-        assert telemetry.get_tracer().to_chrome_trace()["traceEvents"] == []
+        assert tracer.snapshot()[-1] is sp
+        last = tracer.to_chrome_trace()["traceEvents"][-1]
+        assert last["name"] == "fwd" and last["args"]["id"] == sp.id
+
+    def test_session_without_trace_leaves_the_ring_in_place(self, tmp_path):
+        cfg = TelemetryConfig(enabled=True, trace=False,
+                              output_dir=str(tmp_path / "t"))
+        session = telemetry.configure(cfg)
+        assert session.tracer is None and session.trace_path is None
+        assert telemetry.get_tracer() is tracing.RING
+        session.flush()
+        assert not (tmp_path / "t" / "trace.json").exists()
+        traced = telemetry.configure(TelemetryConfig(
+            enabled=True, output_dir=str(tmp_path / "t")))
+        assert telemetry.get_tracer() is traced.tracer is not tracing.RING
+        assert not traced.tracer.ring
 
     def test_configure_disabled_removes_config_session(self, tmp_path):
         cfg = TelemetryConfig(enabled=True, output_dir=str(tmp_path / "t"))
@@ -226,6 +389,39 @@ class TestDisabledNoop:
         assert telemetry.get_registry().snapshot() == []
         assert not os.path.exists(str(tmp_path / "ds_telemetry"))
         assert os.listdir(tmp_path) == []
+
+    def test_engine_without_a_session_leaves_its_spans_in_the_ring(self):
+        """The step's own spans exist with no ``telemetry`` block: data,
+        then train_batch over dispatch and post_step, sharing the step as
+        their ``trace``; ``wait`` only where the step itself reads its
+        outputs on the host (here: never)."""
+        engine = _engine()
+        ring = telemetry.get_tracer()
+        assert ring is tracing.RING
+        before = {s.id for s in ring.snapshot()}
+        for i in range(2):
+            engine.train_batch(_batch(i))
+        spans = [s for s in ring.snapshot() if s.id not in before
+                 and s.cat == "train"]
+        assert [s.name for s in spans] == [
+            "data", "dispatch", "post_step", "train_batch"] * 2
+        assert [s.trace for s in spans] == [0] * 4 + [1] * 4
+        assert all(s.args["step"] == s.trace for s in spans)
+        data, dispatch, post, batch = spans[:4]
+        assert data.parent is None and batch.parent is None
+        assert dispatch.parent == post.parent == batch.id
+        assert data.t1 <= batch.t0 <= dispatch.t0 <= dispatch.t1 \
+            <= post.t0 <= post.t1 <= batch.t1
+
+    def test_wait_span_only_where_the_step_reads_its_outputs(self, tmp_path):
+        engine = _engine(telemetry_cfg={"enabled": True,
+                                        "output_dir": str(tmp_path / "t")})
+        engine.train_batch(_batch())
+        spans = telemetry.get_tracer().snapshot()
+        (batch,) = [s for s in spans if s.name == "train_batch"]
+        kids = [s.name for s in spans if s.parent == batch.id
+                and s.cat == "train"]
+        assert kids == ["dispatch", "wait", "post_step"]
 
 
 # ------------------------------------------------- resilience counters

@@ -117,13 +117,10 @@ sweet spots on one v5e chip:
   B=32 (MBU 0.70), 13.7k at B=128 (MBU 0.83) after moving the stacked KV
   cache into the decode scan's carry (the xs/ys layout copied the whole
   cache every token: 2.2k tok/s). int8 weights measured no change
-  (decode is cache+weight-stream bound, not weight-only);
-  use_flash_decode measured slower at 256-token AND ~4k tight caches
-  (llama 4096+64: 409 vs 720 tok/s) — generate() tight-allocates the
-  cache per (prompt, gen) shape, so the kernel's length-clamped-DMA win
-  case (long preallocated, mostly-empty cache) never arises there; it
-  stays opt-in for external cache-reusing callers. llama3.2-1b GQA
-  decode: 6.3k tok/s at B=32/128/128 (MBU 0.66).
+  (decode is cache+weight-stream bound, not weight-only). llama3.2-1b GQA
+  decode: 6.3k tok/s at B=32/128/128 (MBU 0.66). (These predate the
+  decode kernel and folded cache of PR 25, which every TPU program now
+  takes: PERF.md.)
 """
 
 import json
@@ -782,8 +779,6 @@ def serving_line(on_tpu: bool, n_dev: int) -> dict:
     if gen < 2:
         raise ValueError("BENCH_GEN must be >= 2 (prefill is solved out of "
                          "the two-point measurement)")
-    if os.environ.get("BENCH_FLASH_DECODE", "0") == "1":
-        config = dataclasses.replace(config, use_flash_decode=True)
 
     model = model_cls(config)
     params = model.init_params(jax.random.PRNGKey(0))

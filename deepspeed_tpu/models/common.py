@@ -8,6 +8,7 @@ is never materialized — at V≈50k that is multiple GB per microbatch.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -227,50 +228,124 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-_warned_decode_alibi = [False]
+# ------------------------------------------------------------------ KV cache
+# One layout for every family that decodes through ``cached_decode_attention``
+# (gpt2, gpt2_moe, llama): ``(L, B, S, W)`` with the KV heads folded into
+# the row — KV head ``g`` in columns ``[g * Dh, (g + 1) * Dh)`` — and ``W`` =
+# ``KV * Dh`` rounded up to whole 128-lane tiles (zeros in the pad columns).
+# A TPU lays an array out with the minor dimension that pads least: at
+# ``(.., S, 25, 64)`` that is S, a transposed layout every decode chunk paid
+# two relayouts of the whole cache for (PERF.md, PR 25); at ``(.., S, 1664)``
+# it is the row itself, which is what the decode kernel's blocks, prefill's
+# writes and the per-token update all want.
+KV_LANES = 128
 
 
-def cached_decode_attention(q, k_cache, v_cache, pos, use_flash_decode=False,
+def kv_cache_width(n_kv: int, head_dim: int) -> int:
+    return -(-n_kv * head_dim // KV_LANES) * KV_LANES
+
+
+def init_kv_cache(n_layer: int, batch_size: int, max_len: int, n_kv: int,
+                  head_dim: int, dtype):
+    shape = (n_layer, batch_size, max_len, kv_cache_width(n_kv, head_dim))
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            "pos": jnp.zeros((), jnp.int32)}
+
+
+def kv_cache_partition_specs(n_kv: int, head_dim: int):
+    """Heads over 'tensor' where the rows carry no pad columns (a padded row
+    cut into equal shards would cut through heads); replicated otherwise."""
+    from deepspeed_tpu.parallel.topology import TENSOR_AXIS
+
+    heads = TENSOR_AXIS if (n_kv * head_dim) % KV_LANES == 0 else None
+    return {"k": P(None, None, None, heads), "v": P(None, None, None, heads),
+            "pos": P()}
+
+
+def kv_cache_rows(t, max_len: int):
+    """Prefill's k or v (B, T, KV, Dh) as one layer of the cache:
+    (B, max_len, W), zeros past T and in the pad columns."""
+    B, T, KV, Dh = t.shape
+    return jnp.pad(t.reshape(B, T, KV * Dh),
+                   ((0, 0), (0, max_len - T),
+                    (0, kv_cache_width(KV, Dh) - KV * Dh)))
+
+
+def kv_cache_write(cache, t, layer, pos):
+    """The new token's k or v (B, 1, KV, Dh) into slot ``pos`` of ``layer``
+    of the stacked cache, in place when the cache is a loop carry."""
+    B, _, KV, Dh = t.shape
+    return jax.lax.dynamic_update_slice(
+        cache, t.reshape(1, B, 1, KV * Dh).astype(cache.dtype),
+        (layer, 0, pos, 0))
+
+
+def read_as_stored(w):
+    """A (K, N) layer slice of a stacked weight, inside a decode loop, kept
+    in the layout its parameter has on the device. A TPU stores an array
+    with the minor dimension that pads (8, 128) tiles least: a stacked
+    (L, 6400, 1600) sits K-minor, the loop's one-row matmul asks for it
+    N-minor, and XLA relays the WHOLE stack out ahead of the loop on every
+    call (983 MB a decode chunk at gpt2-xl; PERF.md, PR 25). Pinning the
+    slice makes the contraction read what is stored. Only where that
+    storage is certain: an unsharded weight whose K fills whole lane tiles
+    and whose N does not; anything else is left to XLA."""
+    K, N = w.shape
+    mesh, on_tpu = _kernel_target()
+    if not on_tpu or K % KV_LANES or not N % KV_LANES:
+        return w
+    from deepspeed_tpu.parallel.topology import TENSOR_AXIS
+
+    if mesh is not None and mesh.shape.get(TENSOR_AXIS, 1) > 1:
+        return w
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(w, Layout(major_to_minor=(1, 0)))
+
+
+def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
                             alibi=None, window=None):
-    """Single-token decode attention over a KV cache, shared by the model
-    families. q: (B, H, Dh) — the new token's queries; caches (B, S, KV, Dh)
-    valid through index ``pos``; KV may divide H (GQA); ``alibi``: optional
-    (H,) slopes (key-position bias; einsum path only). → (B, H, Dh).
+    """Single-token decode attention over the stacked KV cache
+    (``init_kv_cache``), shared by the model families. q: (B, H, Dh) — the
+    new token's queries; caches (L, B, S, W), ``layer`` of them valid
+    through slot ``pos`` (both traced scalars); ``n_kv`` may divide H
+    (GQA); ``alibi``: optional (H,) slopes (key-position bias); ``window``:
+    optional traced sliding window (GPT-Neo). → (B, H, Dh).
 
-    ``use_flash_decode`` selects the Pallas streaming kernel
-    (ops/pallas/decode_attention.py) — a TPU kernel: asking for it where it
-    cannot lower is an error, not a reason to run something else. Round-5
-    v5e measurements: the kernel reads only the valid cache prefix, so it
-    wins when the cache is preallocated longer than the current length
-    (microbench B=8, S=4096, H=KV=16, Dh=64 bf16: 822us vs 933us einsum at
-    1/8 fill; engine-level generate() of 64 tokens on a 4-layer model: 79ms
-    vs 113ms) but loses ~2× to XLA's fused einsum when the cache is exactly
-    full — hence opt-in.
+    The path is chosen the way ``local_causal_attention`` chooses flash:
+    the Pallas streaming kernel (ops/pallas/decode_attention.py), which
+    reads only slots ``0..pos``, where the program is for a TPU and the
+    call carries neither a bias nor a window; the XLA einsum over the whole
+    allocation otherwise — and as the reference the kernel is tested
+    against. A kernel that does not lower is an error, not a reason to run
+    something else.
     """
-    if use_flash_decode and alibi is not None and not _warned_decode_alibi[0]:
-        _warned_decode_alibi[0] = True
-        from deepspeed_tpu.utils.logging import logger
-
-        logger.warning("use_flash_decode is set but ALiBi is active; the "
-                       "decode kernel has no bias input — using XLA einsum "
-                       "decode for this model")
-    if use_flash_decode and alibi is None and window is None:
-        from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
-
-        mesh, _ = _kernel_target()
-        batch, heads = _attn_axes(mesh, q.shape[0], k_cache.shape[2])
-        cache_spec = P(batch, None, heads, None)
-        return _kernel_on_mesh(
-            decode_attention, mesh, (q, k_cache, v_cache, pos),
-            (P(batch, heads, None), cache_spec, cache_spec, P()),
-            P(batch, heads, None))
     B, H, Dh = q.shape
-    S, KV = k_cache.shape[1], k_cache.shape[2]
-    qg = q.reshape(B, KV, H // KV, Dh)
+    if alibi is None and window is None:
+        mesh, on_tpu = _kernel_target()
+        if on_tpu:
+            from deepspeed_tpu.ops.pallas.decode_attention import \
+                decode_attention
+
+            batch, heads = _attn_axes(mesh, B, n_kv)
+            if (n_kv * Dh) % KV_LANES:
+                heads = None        # padded rows stay whole (see the specs)
+            local_kv = n_kv // (mesh.shape[heads] if heads else 1)
+            cache_spec = P(None, batch, None, heads)
+            return _kernel_on_mesh(
+                functools.partial(decode_attention, n_kv=local_kv), mesh,
+                (q, k_cache, v_cache, layer, pos),
+                (P(batch, heads, None), cache_spec, cache_spec, P(), P()),
+                P(batch, heads, None))
+    S = k_cache.shape[2]
+    layer_of = lambda c: jax.lax.dynamic_index_in_dim(
+        c, layer, 0, keepdims=False)[..., :n_kv * Dh].reshape(B, S, n_kv, Dh)
+    k_l, v_l = layer_of(k_cache), layer_of(v_cache)
+    qg = q.reshape(B, n_kv, H // n_kv, Dh)
     scale = 1.0 / math.sqrt(Dh)
-    s = jnp.einsum("bgrd,bkgd->bgrk", qg, k_cache).astype(jnp.float32) * scale
+    s = jnp.einsum("bgrd,bkgd->bgrk", qg, k_l).astype(jnp.float32) * scale
     if alibi is not None:
-        s = s + (alibi.reshape(KV, H // KV)[None, :, :, None]
+        s = s + (alibi.reshape(n_kv, H // n_kv)[None, :, :, None]
                  * jnp.arange(S, dtype=jnp.float32)[None, None, None, :])
     valid = (jnp.arange(S) <= pos)[None, None, None]
     if window is not None:
@@ -280,7 +355,7 @@ def cached_decode_attention(q, k_cache, v_cache, pos, use_flash_decode=False,
         valid = valid & (((jnp.arange(S) > pos - w) | (w <= 0))[None, None, None])
     s = jnp.where(valid, s, NEG_INF_ATTN)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrk,bkgd->bgrd", p, v_cache).reshape(B, H, Dh)
+    return jnp.einsum("bgrk,bkgd->bgrd", p, v_l).reshape(B, H, Dh)
 
 
 def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,

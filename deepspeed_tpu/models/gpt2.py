@@ -57,10 +57,6 @@ class GPT2Config:
     # (512). An autotuner axis: smaller tiles fit tighter VMEM at long
     # head_dim, larger amortize the grid
     flash_block: Optional[int] = None
-    # Pallas streaming decode kernel for generate(); opt-in — wins when the
-    # KV cache is preallocated longer than the generated length (see
-    # models/common.py cached_decode_attention for measured numbers)
-    use_flash_decode: bool = False
     tie_embeddings: bool = True
     lm_head_bias: bool = False       # GPT-J style bias on the (untied) head
     # BLOOM-style variant switches: ALiBi replaces the learned position table
@@ -485,9 +481,12 @@ class GPT2Model:
 
     # ------------------------------------------------------------- inference
     def init_cache(self, batch_size: int, max_len: int):
-        """KV cache: (L, B, max_len, H, Dh) per k/v, plus current length.
-        The TPU counterpart of the reference's InferenceContext KV workspace
-        (csrc/transformer/inference/includes/inference_context.h:287)."""
+        """KV cache: (L, B, max_len, W) per k/v — the heads folded into
+        lane-dense rows (models/common.py ``init_kv_cache``) — plus current
+        length. The TPU counterpart of the reference's InferenceContext KV
+        workspace (csrc/transformer/inference/includes/inference_context.h:287)."""
+        from deepspeed_tpu.models.common import init_kv_cache
+
         c = self.config
         if c.sparse_attention is not None:
             # prefill/decode attend densely over the cache; serving a
@@ -497,14 +496,14 @@ class GPT2Model:
                 "KV-cache generation does not apply sparse_attention "
                 "layouts; serve with sparse_attention=None only if the "
                 "model was also trained dense")
-        shape = (c.n_layer, batch_size, max_len, c.n_head, c.head_dim)
-        return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype),
-                "pos": jnp.zeros((), jnp.int32)}
+        return init_kv_cache(c.n_layer, batch_size, max_len, c.n_head,
+                             c.head_dim, c.dtype)
 
     def cache_partition_specs(self):
-        return {"k": P(None, None, None, "tensor", None),
-                "v": P(None, None, None, "tensor", None),
-                "pos": P()}
+        from deepspeed_tpu.models.common import kv_cache_partition_specs
+
+        return kv_cache_partition_specs(self.config.n_head,
+                                        self.config.head_dim)
 
     def _rope_tables(self, positions):
         """cos/sin for the rotary fraction of each head, or None."""
@@ -575,6 +574,8 @@ class GPT2Model:
 
     def prefill(self, params, input_ids, cache):
         """Process the prompt, fill the cache, return last-position logits."""
+        from deepspeed_tpu.models.common import kv_cache_rows
+
         c = self.config
         B, T = input_ids.shape
         max_len = cache["k"].shape[2]
@@ -589,11 +590,8 @@ class GPT2Model:
             q, k, v = self._block_kv(x, blk, rope)
             attn = self._attention_local(q, k, v, window=w)
             x = self._block_finish(x, blk, attn)
-            k_pad = jnp.zeros((B, max_len, c.n_head, c.head_dim), c.dtype)
-            k_pad = jax.lax.dynamic_update_slice(k_pad, k, (0, 0, 0, 0))
-            v_pad = jnp.zeros((B, max_len, c.n_head, c.head_dim), c.dtype)
-            v_pad = jax.lax.dynamic_update_slice(v_pad, v, (0, 0, 0, 0))
-            return x, (k_pad, v_pad)
+            return x, (kv_cache_rows(k, max_len),
+                       kv_cache_rows(v, max_len))
 
         x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], windows))
         x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
@@ -621,31 +619,29 @@ class GPT2Model:
         pos = cache["pos"]
         x = self._decode_embed(params, token, pos)
 
-        from deepspeed_tpu.models.common import cached_decode_attention
+        from deepspeed_tpu.models.common import (cached_decode_attention,
+                                                 kv_cache_write,
+                                                 read_as_stored)
 
         rope = self._rope_tables(pos[None])
 
         windows = self._layer_windows()
 
-        # The stacked (L, B, T, H, D) cache rides the scan CARRY, updated in
-        # place with a per-layer DUS. The previous layout passed it as
-        # xs/ys, which makes lax.scan assemble a brand-new stacked output
-        # buffer every decode step — a full cache copy per token (measured
-        # 13ms/step at B=32 on gpt2-760m v5e, the dominant serving cost;
-        # the carry aliases instead of copying).
+        # The stacked (L, B, S, W) cache rides the scan CARRY, updated in
+        # place with a per-layer DUS, and attention reads layer l of it in
+        # place. The previous layout passed it as xs/ys, which makes
+        # lax.scan assemble a brand-new stacked output buffer every decode
+        # step — a full cache copy per token (measured 13ms/step at B=32 on
+        # gpt2-760m v5e; the carry aliases instead of copying).
         def body(carry, xs):
             x, cache_k, cache_v = carry
             blk, w, l = xs
+            blk = dict(blk, fc2_w=read_as_stored(blk["fc2_w"]))
             q, k, v = self._block_kv(x, blk, rope)     # (B, 1, H, Dh)
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, k[None].astype(cache_k.dtype), (l, 0, pos, 0, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, v[None].astype(cache_v.dtype), (l, 0, pos, 0, 0))
-            k_l = jax.lax.dynamic_index_in_dim(cache_k, l, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(cache_v, l, 0, keepdims=False)
-            attn = cached_decode_attention(q[:, 0], k_l, v_l, pos,
-                                           c.use_flash_decode,
-                                           alibi=self._alibi(),
+            cache_k = kv_cache_write(cache_k, k, l, pos)
+            cache_v = kv_cache_write(cache_v, v, l, pos)
+            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, l, pos,
+                                           c.n_head, alibi=self._alibi(),
                                            window=w)[:, None]
             x = self._block_finish(x, blk, attn)
             return (x, cache_k, cache_v), None

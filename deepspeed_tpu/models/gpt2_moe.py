@@ -145,7 +145,7 @@ class MoEGPT2(GPT2Model):
         return x + attn @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
 
     # ------------------------------------------------------------- inference
-    # Same cache layout/protocol as the dense GPT-2 ((L, B, max_len, H, Dh)
+    # Same cache layout/protocol as the dense GPT-2 ((L, B, max_len, W)
     # per k/v — init_cache and cache_partition_specs inherit), but the layer
     # walk must be the PAIRED one: the inherited prefill/decode would run the
     # odd blocks' untrained dense MLPs instead of the expert bank. This is
@@ -154,6 +154,8 @@ class MoEGPT2(GPT2Model):
     # gated dispatch inside the scan compiles to a2a on the expert axis.
 
     def prefill(self, params, input_ids, cache):
+        from deepspeed_tpu.models.common import kv_cache_rows
+
         c = self.config
         B, T = input_ids.shape
         max_len = cache["k"].shape[2]
@@ -161,9 +163,7 @@ class MoEGPT2(GPT2Model):
         rope = self._rope_tables(jnp.arange(T))
         _, paired = self._paired_blocks(params)
 
-        def pad_kv(k):
-            z = jnp.zeros((B, max_len, c.n_head, c.head_dim), c.dtype)
-            return jax.lax.dynamic_update_slice(z, k, (0, 0, 0, 0))
+        pad_kv = lambda k: kv_cache_rows(k, max_len)
 
         def body(x, xs):
             pair_blocks, moe_p = xs
@@ -188,7 +188,8 @@ class MoEGPT2(GPT2Model):
                         "pos": jnp.int32(T)}
 
     def decode_step(self, params, token, cache):
-        from deepspeed_tpu.models.common import cached_decode_attention
+        from deepspeed_tpu.models.common import (cached_decode_attention,
+                                                 kv_cache_write)
 
         c = self.config
         pos = cache["pos"]
@@ -201,14 +202,10 @@ class MoEGPT2(GPT2Model):
         # the whole cache every decode step)
         def attend(x, blk, cache_k, cache_v, l):
             q, k, v = self._block_kv(x, blk, rope)          # (B, 1, H, Dh)
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, k[None].astype(cache_k.dtype), (l, 0, pos, 0, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, v[None].astype(cache_v.dtype), (l, 0, pos, 0, 0))
-            k_l = jax.lax.dynamic_index_in_dim(cache_k, l, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(cache_v, l, 0, keepdims=False)
-            attn = cached_decode_attention(q[:, 0], k_l, v_l, pos,
-                                           c.use_flash_decode,
+            cache_k = kv_cache_write(cache_k, k, l, pos)
+            cache_v = kv_cache_write(cache_v, v, l, pos)
+            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, l, pos,
+                                           c.n_head,
                                            alibi=self._alibi())[:, None]
             return attn, cache_k, cache_v
 

@@ -58,10 +58,6 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     remat: Any = True                # False | True/'full' | 'dots' | 'attn'
     use_flash_attention: bool = True
-    # Pallas streaming decode kernel for generate(); opt-in — wins when the
-    # KV cache is preallocated longer than the generated length (see
-    # models/common.py cached_decode_attention for measured numbers)
-    use_flash_decode: bool = False
     sequence_parallel: Any = False   # False | 'ring' | 'ulysses'
 
     VALID_REMAT = (False, None, "none", True, "full", "dots", "attn")
@@ -307,22 +303,26 @@ class LlamaModel:
 
     # ------------------------------------------------------------- inference
     def init_cache(self, batch_size: int, max_len: int):
-        """KV cache holds only the KV heads: (L, B, max_len, KV, Dh) — the GQA
+        """KV cache holds only the KV heads, folded into lane-dense rows:
+        (L, B, max_len, W) (models/common.py ``init_kv_cache``) — the GQA
         memory win over the reference's full-head InferenceContext workspace
         (csrc/transformer/inference/includes/inference_context.h:287)."""
+        from deepspeed_tpu.models.common import init_kv_cache
+
         c = self.config
-        shape = (c.n_layer, batch_size, max_len, c.n_kv_head, c.head_dim)
-        return {"k": jnp.zeros(shape, c.dtype), "v": jnp.zeros(shape, c.dtype),
-                "pos": jnp.zeros((), jnp.int32)}
+        return init_kv_cache(c.n_layer, batch_size, max_len, c.n_kv_head,
+                             c.head_dim, c.dtype)
 
     def cache_partition_specs(self):
-        return {"k": P(None, None, None, "tensor", None),
-                "v": P(None, None, None, "tensor", None),
-                "pos": P()}
+        from deepspeed_tpu.models.common import kv_cache_partition_specs
+
+        return kv_cache_partition_specs(self.config.n_kv_head,
+                                        self.config.head_dim)
 
     def prefill(self, params, input_ids, cache):
         """Process the prompt, fill the cache, return last-position logits."""
-        from deepspeed_tpu.models.common import local_causal_attention
+        from deepspeed_tpu.models.common import (kv_cache_rows,
+                                                 local_causal_attention)
 
         c = self.config
         B, T = input_ids.shape
@@ -337,10 +337,8 @@ class LlamaModel:
                                           self._repeat_kv(v),
                                           c.use_flash_attention)
             x = self._block_finish(x, blk, attn)
-            pad = lambda t: jax.lax.dynamic_update_slice(
-                jnp.zeros((B, max_len, c.n_kv_head, c.head_dim), c.dtype),
-                t, (0, 0, 0, 0))
-            return x, (pad(k), pad(v))
+            return x, (kv_cache_rows(k, max_len),
+                       kv_cache_rows(v, max_len))
 
         x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])
         x = self._rms_norm(x, params["norm_g"])
@@ -355,7 +353,8 @@ class LlamaModel:
         x = params["wte"].astype(c.dtype)[token][:, None]   # (B, 1, D)
         cos, sin = _rope_cos_sin(pos[None], c.head_dim, c.rope_theta, c.rope_scaling)
 
-        from deepspeed_tpu.models.common import cached_decode_attention
+        from deepspeed_tpu.models.common import (cached_decode_attention,
+                                                 kv_cache_write)
 
         # stacked cache rides the scan CARRY (in-place per-layer DUS); the
         # xs/ys layout made lax.scan assemble a fresh stacked cache buffer
@@ -364,16 +363,12 @@ class LlamaModel:
             x, cache_k, cache_v = carry
             blk, l = xs
             q, k, v = self._block_qkv(x, blk, cos, sin)     # q (B,1,H,Dh)
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, k[None].astype(cache_k.dtype), (l, 0, pos, 0, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, v[None].astype(cache_v.dtype), (l, 0, pos, 0, 0))
-            k_l = jax.lax.dynamic_index_in_dim(cache_k, l, 0, keepdims=False)
-            v_l = jax.lax.dynamic_index_in_dim(cache_v, l, 0, keepdims=False)
+            cache_k = kv_cache_write(cache_k, k, l, pos)
+            cache_v = kv_cache_write(cache_v, v, l, pos)
             # GQA decode against the KV-head cache — repeated K/V are never
             # materialized (grouped einsum or the Pallas streaming kernel)
-            attn = cached_decode_attention(q[:, 0], k_l, v_l, pos,
-                                           c.use_flash_decode)[:, None]
+            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, l, pos,
+                                           c.n_kv_head)[:, None]
             x = self._block_finish(x, blk, attn)
             return (x, cache_k, cache_v), None
 

@@ -77,14 +77,89 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, heads, dim):
             kernel
 
 
-def test_decode_kernel_compiles_for_v5e_uninterpreted(v5e):
+# B, S, H, KV, Dh: the serving cells' gpt2-xl rows (25 x 64 in 1664 lanes),
+# gpt2-760m's, GQA, and a generate()-sized cache that does not tile
+@pytest.mark.parametrize("shape", [(1, 1024, 25, 25, 64), (2, 1024, 16, 16, 96),
+                                   (2, 1024, 32, 8, 64), (8, 1001, 16, 16, 96)])
+def test_decode_kernel_compiles_for_v5e_uninterpreted(v5e, shape):
+    B, S, H, KV, Dh = shape
     mesh = _mesh(v5e)
-    q = _abstract((2, 4, 96), jnp.bfloat16, mesh)
-    cache = _abstract((2, 1024, 4, 96), jnp.bfloat16, mesh)
-    pos = _abstract((), jnp.int32, mesh)
-    text = jax.jit(da.decode_attention).lower(
-        q, cache, cache, pos).compile().as_text()
-    assert "tpu_custom_call" in text
+    q = _abstract((B, H, Dh), jnp.bfloat16, mesh)
+    cache = _abstract((2, B, S, common.kv_cache_width(KV, Dh)), jnp.bfloat16,
+                      mesh)
+    scalar = _abstract((), jnp.int32, mesh)
+    text = jax.jit(functools.partial(da.decode_attention, n_kv=KV)).lower(
+        q, cache, cache, scalar, scalar).compile().as_text()
+    # the kernel's ``name`` is the HLO instruction, hence the op's name in a
+    # device profile
+    assert len(re.findall(r"%[\w.]*decode_attn[\w.]* = [^\n]*tpu_custom_call",
+                          text)) == 1
+
+
+def test_decode_chunk_for_v5e_reads_cache_and_weights_in_place(v5e):
+    """The serving decode chunk at gpt2-xl widths (2 layers), compiled as
+    the chip compiles it — entry layouts the TPU's own (``fc2_w`` K-minor,
+    the cache row-major): the kernel is in it, no stacked weight is relaid
+    out, and the only cache-shaped copies are at most the two an undonated
+    input costs (k and v)."""
+    from deepspeed_tpu.inference.engine import build_serving_programs
+
+    mesh = _mesh(v5e)
+    model = GPT2Model(dataclasses.replace(PRESETS["gpt2-xl"], n_layer=2))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: _abstract(s.shape, jnp.bfloat16, mesh), shapes)
+    _, chunk = build_serving_programs(model, 1024, 16, False, 1.0, 0, 1.0, None)
+    cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
+                         jax.eval_shape(lambda: model.init_cache(1, 1024)))
+    with mesh:
+        text = jax.jit(chunk).lower(
+            params, _abstract((1, 50257), jnp.float32, mesh), cache,
+            _abstract((1,), jnp.bool_, mesh),
+            _abstract((2,), jnp.uint32, mesh)).compile().as_text()
+    assert re.search(r"%[\w.]*decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
+    assert re.search(r"bf16\[2,6400,1600\]\{1,2,0", text)   # as stored, K-minor
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    as_hlo = lambda x: "bf16[" + ",".join(map(str, x.shape)) + "]"
+    stacked = {as_hlo(x) for x in jax.tree.leaves(shapes["blocks"])}
+    assert not stacked & set(copies), copies
+    # (the tied wte and wpe, stored V-minor, are still relaid out: PERF.md)
+    assert copies.count(as_hlo(cache["k"])) <= 2, copies
+
+
+def test_decode_chunk_over_tensor_4_keeps_the_kernel(v5e):
+    """gpt2-760m served at tp=4 (what chip_smoke.py runs on four chips):
+    the cache rows (16 x 96 = 1536, no pad columns) are cut over 'tensor'
+    with the heads, and the kernel sits in a shard_map over them."""
+    from deepspeed_tpu.inference.engine import build_serving_programs
+
+    mesh = _mesh(v5e, tensor=4)
+    named = lambda spec: NamedSharding(mesh, spec)
+    model = GPT2Model(dataclasses.replace(PRESETS["gpt2-760m"], n_layer=2))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    from deepspeed_tpu.sharding import ShardingRegistry
+
+    # as InferenceEngine fits them: the 50257-row wte stays whole
+    specs = ShardingRegistry(mesh).fit(model.param_partition_specs(), shapes)
+    params = jax.tree.map(
+        lambda s, spec: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                             sharding=named(spec)),
+        shapes, specs)
+    cache_sh = jax.tree.map(named, model.cache_partition_specs(),
+                            is_leaf=lambda x: isinstance(x, P))
+    assert cache_sh["k"].spec == P(None, None, None, "tensor")
+    _, chunk = build_serving_programs(model, 1024, 16, False, 1.0, 0, 1.0,
+                                      None, cache_shardings=cache_sh)
+    cache = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(lambda: model.init_cache(1, 1024)), cache_sh)
+    with mesh:
+        text = jax.jit(chunk).lower(
+            params, _abstract((1, 50257), jnp.float32, mesh), cache,
+            _abstract((1,), jnp.bool_, mesh),
+            _abstract((2,), jnp.uint32, mesh)).compile().as_text()
+    assert re.search(r"%[\w.]*decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
+    assert "bf16[2,1,1024,384]" in text         # a shard of the cache
 
 
 @pytest.mark.parametrize("t", [601, 1001])
@@ -213,9 +288,10 @@ def test_decode_kernel_never_interprets_itself():
     """Off-TPU the decode kernel fails to lower instead of quietly running
     in the interpreter; a test that wants the interpreter asks for it."""
     q = jnp.zeros((1, 4, 64))
-    cache = jnp.zeros((1, 128, 4, 64))
+    cache = jnp.zeros((1, 1, 128, 256))
     with pytest.raises(Exception, match="[Ii]nterpret|TPU|tpu"):
-        da.decode_attention(q, cache, cache, jnp.int32(3))
+        da.decode_attention(q, cache, cache, jnp.int32(0), jnp.int32(3),
+                            n_kv=4)
 
 
 # ----------------------------------------------------------- engine rules

@@ -127,16 +127,20 @@ class TestRegistry:
         plan.map_opt_state_specs(opt_shapes, shapes)
         assert plan.registry.has("opt_state")
 
-    def test_cache_shardings_one_source(self):
+    # the cache folds the heads into its rows (L, B, S, W): heads go over
+    # 'tensor' where the rows carry no pad columns, and stay whole where
+    # equal shards of a padded row would cut through heads
+    @pytest.mark.parametrize("n_embd,heads", [(256, "tensor"), (16, None)])
+    def test_cache_shardings_one_source(self, n_embd, heads):
         from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 
         mesh = ensure_global_mesh(axis_dims=_dims(data=4, tensor=2))
         reg = ShardingRegistry(mesh)
-        m = GPT2Model(GPT2Config(vocab_size=64, n_positions=32, n_embd=16,
+        m = GPT2Model(GPT2Config(vocab_size=64, n_positions=32, n_embd=n_embd,
                                  n_layer=1, n_head=2,
                                  use_flash_attention=False))
         sh = reg.cache_shardings(m)
-        assert sh["k"].spec == P(None, None, None, "tensor", None)
+        assert sh["k"].spec == sh["v"].spec == P(None, None, None, heads)
         assert reg.has("kv_cache")
 
 

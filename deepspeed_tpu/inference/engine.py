@@ -191,6 +191,21 @@ def build_serving_programs(module, max_total_len: int, chunk_tokens: int,
     return prefill, decode_chunk
 
 
+def _served_as_given(params, shardings, dtype) -> bool:
+    """True where every leaf of ``params`` is a device array that the cast
+    of its floating leaves to ``dtype`` and the placement onto ``shardings``
+    would leave as it is."""
+    def same(x, sh):
+        return isinstance(x, jax.Array) and not x.is_deleted() \
+            and (x.dtype == dtype or not jnp.issubdtype(x.dtype, jnp.floating)) \
+            and x.sharding.is_equivalent_to(sh, x.ndim)
+
+    try:
+        return all(jax.tree.leaves(jax.tree.map(same, params, shardings)))
+    except ValueError:          # another tree than the specs describe
+        return False
+
+
 class InferenceEngine:
     def __init__(self, model, config: Optional[DeepSpeedInferenceConfig] = None,
                  params: Any = None, mesh=None):
@@ -257,7 +272,13 @@ class InferenceEngine:
         self.sharding.register("params", specs)
         shardings = self.sharding.shardings("params")
         with mesh:
-            if params is not None:
+            if params is not None and _served_as_given(params, shardings,
+                                                       self.dtype):
+                # already in the served type and placement: the engine holds
+                # the caller's buffers, not a second copy of the weights (a
+                # 13.84 GB model on a 16 GB chip has room for one)
+                self.params = params
+            elif params is not None:
                 self.params = sharded_jit(
                     lambda p: jax.tree.map(to_dtype, p),
                     label="inference/cast_params", donate_argnums=(),

@@ -46,7 +46,7 @@ class PipelinedLlama(PipelinedDecoderMixin, LlamaModel):
                                 c.rope_theta, c.rope_scaling)
 
         def body(carry, blk):
-            return self._block(carry, blk, cos_sin), None
+            return self._block(carry, blk, cos_sin)    # (x, None): dense
 
         out, _ = jax.lax.scan(body, x, stage_params)
         return out

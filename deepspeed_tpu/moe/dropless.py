@@ -1,0 +1,104 @@
+"""Dropless top-k routing and the routed SwiGLU expert MLP.
+
+``sharded_moe.py`` is the reference's GShard form: top-1 or top-2, a
+capacity per expert, overflow dropped, tokens scattered into an ``(E, C, D)``
+buffer and every expert run over its whole buffer. Today's open MoE models
+(OLMoE, Qwen-MoE, DeepSeek, Moonlight) route top-k for k up to 8 with NO
+capacity: every (token, expert) pair is computed. Here the pairs are sorted
+by expert and the experts see ragged groups — group sizes instead of
+padding — so nothing is dropped and no slot is wasted:
+
+* ``route_topk``: router matmul, softmax over ALL experts and top-k in
+  float32; weights are the chosen probabilities, renormalised only where
+  the model says so (OLMoE: ``norm_topk_prob`` false);
+* ``routed_mlp``: ``sum_j w[t, j] * down_e(silu(gate_e(x_t)) * up_e(x_t))``
+  over the k chosen experts ``e = experts[t, j]``. Where the program is for
+  one TPU device and the widths tile, the three contractions are the Pallas
+  grouped matmul (``ops/pallas/grouped_matmul.py``) reading the stacked
+  ``(L, E, ...)`` leaves in place; otherwise — the CPU, a multi-device mesh,
+  training — ``jax.lax.ragged_dot`` over the sorted rows, which is also the
+  reference the kernel is tested against. The path is chosen by what the
+  code observes, never by an option and never by a failure;
+* ``load_balancing_loss``: the Switch / Hugging Face auxiliary loss from
+  per-layer sums, so a scanned trunk can carry them.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def route_topk(x, router_w, k: int, renormalize: bool = False):
+    """x (T, D), router_w (D, E) -> ``probs`` (T, E) float32 softmax over all
+    experts, ``weights`` (T, k) float32, ``experts`` (T, k) int32."""
+    # "highest": a TPU's default float32 matmul is one bf16 pass
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return probs, weights, experts.astype(jnp.int32)
+
+
+def load_balancing_loss(expert_tokens, prob_sums, n_tokens):
+    """``E * sum_e f_e * P_e`` (Fedus et al. 2021 eq. 4, as Hugging Face's
+    ``load_balancing_loss_func`` computes it over all layers at once):
+    ``expert_tokens`` (L, E) pairs routed to each expert, ``prob_sums``
+    (L, E) the router probabilities summed over the tokens, ``n_tokens`` the
+    tokens a layer saw. f_e counts every one of a token's k choices; the
+    gradient flows through P_e only."""
+    L, E = prob_sums.shape
+    denom = jnp.float32(L * n_tokens)
+    f = jnp.sum(expert_tokens.astype(jnp.float32), axis=0) / denom
+    p = jnp.sum(prob_sums.astype(jnp.float32), axis=0) / denom
+    return E * jnp.sum(jax.lax.stop_gradient(f) * p)
+
+
+def _use_kernel(x, w) -> bool:
+    """The kernel where the program is for ONE TPU device (GSPMD cannot
+    partition a Mosaic call; experts over chips are open), the stacked
+    weights have the rows' type and both widths tile."""
+    from deepspeed_tpu.models.common import _kernel_target
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
+
+    mesh, on_tpu = _kernel_target()
+    return on_tpu and (mesh is None or mesh.size == 1) \
+        and w.dtype == x.dtype and gmm.supports(*w.shape[2:])
+
+
+def _layer_of(w, layer):
+    return w if layer is None else jax.lax.dynamic_index_in_dim(
+        w, layer, 0, keepdims=False)
+
+
+def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None):
+    """x (T, D); ``weights`` / ``experts`` (T, k) from ``route_topk``;
+    ``gate_w`` / ``up_w`` (E, D, F) and ``down_w`` (E, F, D), or the stacked
+    (L, E, ...) leaves with the traced ``layer`` to take.
+    -> (out (T, D) in x's type, pairs routed to each expert (E,) int32)."""
+    T, D = x.shape
+    k = experts.shape[1]
+    E = gate_w.shape[-3]
+    flat = experts.reshape(-1)                       # pair p = token p // k
+    if layer is not None and _use_kernel(x, gate_w):
+        from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
+
+        tm = gmm.row_tile(T * k)
+        sizes, tile_group, n_active, src, pos = gmm.group_layout(flat, E, tm)
+        call = lambda rows, w, **kw: gmm.grouped_matmul(
+            rows, w, layer, tile_group, n_active, tm=tm, **kw)
+        h = call(x[src // k], (gate_w, up_w), swiglu=True)
+        y = call(h, down_w)[pos]
+    else:
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        dot = lambda rows, w: jax.lax.ragged_dot(
+            rows, _layer_of(w, layer).astype(rows.dtype), sizes)
+        rows = x[order // k]
+        h = jax.nn.silu(dot(rows, gate_w)) * dot(rows, up_w)
+        y = dot(h, down_w)[jnp.argsort(order)]
+    out = jnp.einsum("tk,tkd->td", weights, y.reshape(T, k, D).astype(
+        jnp.float32))
+    return out.astype(x.dtype), sizes

@@ -627,6 +627,7 @@ class ServingFrontEnd:
                             self._flush_stream(req, [eos] * pad)
                 if finished:
                     break
+            self._count_expert_tokens(req, cache, tracer)
             self._observe_service(req)
             self._count("completed")
             self._resolve(req, "completed", "")
@@ -662,6 +663,20 @@ class ServingFrontEnd:
                          f"{type(e).__name__}: {e}", exc_info=True)
             self._resolve(req, "partial" if req.tokens else "failed",
                           f"error: {type(e).__name__}: {e}")
+
+    def _count_expert_tokens(self, req: Request, cache, tracer) -> None:
+        """A routed (MoE) model's programs sum, in the cache they hand from
+        tick to tick, the (token, expert) pairs every expert of every layer
+        was given (``expert_tokens`` (L, E)). One read when the request has
+        its tokens, not one a tick: into the counter ``moe/expert_tokens``
+        and, whole, into an instant of that name in the tracer."""
+        routed = cache.get("expert_tokens") if isinstance(cache, dict) else None
+        if routed is None:
+            return
+        counts = np.asarray(routed)
+        self._reg().counter("moe/expert_tokens").inc(float(counts.sum()))
+        tracer.instant("moe/expert_tokens", cat="moe", trace=req.id,
+                       request=req.id, counts=counts.tolist())
 
     def _flush_stream(self, req: Request, toks: List[int]) -> None:
         if req.stream is None or not toks:

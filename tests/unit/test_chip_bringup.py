@@ -197,6 +197,116 @@ def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
     assert lowered.as_text().count("tpu_custom_call") == 4
 
 
+# ------------------------------------ OLMoE-1B-7B at its published widths
+GIB = 1 << 30
+
+
+@pytest.fixture(scope="module")
+def olmoe(v5e):
+    """(mesh, model, abstract bf16 params, abstract cache, the two serving
+    programs) of the whole published model on ONE chip: 16 layers, 64
+    experts, top-8, a 4,096-slot cache."""
+    from deepspeed_tpu.inference.engine import build_serving_programs
+    from deepspeed_tpu.models.llama import PRESETS as LLAMA, LlamaModel
+
+    mesh = _mesh(v5e)
+    model = LlamaModel(dataclasses.replace(LLAMA["olmoe-1b-7b"],
+                                           param_dtype=jnp.bfloat16))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh), shapes)
+    cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
+                         jax.eval_shape(lambda: model.init_cache(1, 4096)))
+    return (mesh, model, params, cache) + build_serving_programs(
+        model, 4096, 16, False, 1.0, 0, 1.0, None)
+
+
+def _footprint(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes, m.argument_size_in_bytes
+            + m.output_size_in_bytes + m.temp_size_in_bytes
+            - m.alias_size_in_bytes)
+
+
+def _expert_matrix_moves(text):
+    """Results of copy / gather / dynamic-slice that end in an expert's
+    (2048, 1024) or (1024, 2048): a per-layer slice of a stacked expert leaf
+    (805 MB), a gather of the chosen experts (33 MB), a relayout."""
+    return [m for m in re.findall(
+        r"= (bf16\[[\d,]*\])\S* (?:copy|gather|dynamic-slice)\(", text)
+        if re.search(r"(2048,1024|1024,2048)\]$", m)]
+
+
+def test_olmoe_weights_are_drawn_in_bf16_with_no_float32_leaf(olmoe):
+    """What ``benchmark/systems.py::ServeSystem`` runs: the whole draw in one
+    jitted call. 13.84 GB out and next to nothing beside it — a float32
+    copy of one expert leaf would be 8.6 GB."""
+    mesh, model, params, *_ = olmoe
+    with mesh:
+        compiled = jax.jit(lambda key: jax.tree.map(
+            lambda x: x.astype(jnp.bfloat16), model.init_params(key))).lower(
+                _abstract((2,), jnp.uint32, mesh)).compile()
+    m = compiled.memory_analysis()
+    # (small leaves are padded to tiles: a few hundred bytes over)
+    assert 0 <= m.output_size_in_bytes - 2 * model.config.num_params() < 1 << 20
+    assert m.temp_size_in_bytes < 0.5 * GIB, m.temp_size_in_bytes
+
+
+def test_olmoe_decode_chunk_for_v5e_reads_the_chosen_experts_in_place(olmoe):
+    mesh, model, params, cache, _, chunk = olmoe
+    with mesh:
+        compiled = jax.jit(chunk).lower(
+            params, _abstract((1, 50304), jnp.float32, mesh), cache,
+            _abstract((1,), jnp.bool_, mesh),
+            _abstract((2,), jnp.uint32, mesh)).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin", "decode_attn"):
+        assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
+            kernel
+    assert not _expert_matrix_moves(text)
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    assert copies.count("bf16[16,1,4096,2048]") <= 2, copies   # undonated k, v
+    # the weights counted once (13.84 GB) + the cache in and out: it fits
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.6 * GIB
+    assert total < 15.75 * GIB, total / GIB
+
+
+def test_olmoe_prefill_for_v5e_runs_ragged_groups_on_the_stacked_leaves(olmoe):
+    mesh, model, params, _, prefill, _ = olmoe
+    with mesh:
+        compiled = jax.jit(prefill).lower(
+            params, _abstract((1, 2048), jnp.int32, mesh)).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_swiglu_full", "moe_gmm_full", "flash_fwd"):
+        assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
+            kernel
+    assert not _expert_matrix_moves(text)
+    # 2048 x 8 rows in at most 128 + 64 tiles of 128
+    assert "bf16[24576,2048]" in text
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.1 * GIB
+    assert total < 15.75 * GIB, total / GIB
+
+
+def test_routed_experts_on_a_mesh_of_several_chips_take_the_xla_form(v5e):
+    """GSPMD cannot partition a Mosaic call: over tensor=4 the experts run
+    as ``ragged_dot`` (no kernel, and it compiles); experts over chips are
+    open (ROADMAP R1)."""
+    from deepspeed_tpu.models.llama import PRESETS as LLAMA, LlamaModel
+
+    mesh = _mesh(v5e, tensor=4)
+    model = LlamaModel(dataclasses.replace(
+        LLAMA["olmoe-1b-7b"], n_layer=1, n_experts=8, vocab_size=1024))
+    params = jax.tree.map(
+        lambda s: _abstract(s.shape, jnp.bfloat16, mesh),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    with mesh:
+        text = jax.jit(lambda p, ids: model.prefill(
+            p, ids, model.init_cache(1, 512))).lower(
+                params, _abstract((1, 256), jnp.int32, mesh)).compile().as_text()
+    assert "moe_gmm" not in text
+
+
 # ------------------------------------------------------- kernel dispatch
 @pytest.fixture
 def interpreted(monkeypatch):

@@ -16,8 +16,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# logits-buffer budget: chunk length chosen so the (B, chunk, V) fp32 buffer
-# stays around 256MB
+# logits-buffer budget of chunked_lm_loss: the chunk length is the largest
+# divisor of T that keeps ONE CHIP's (B, chunk, V) fp32 logits at or under
+# 256MB, B being the rows that chip computes (all of them on one device, its
+# own share where the scan runs per chip)
 _CHUNK_ELEMS = 64 * 1024 * 1024
 
 NEG_INF_ATTN = -1e30
@@ -394,6 +396,24 @@ def parse_lm_batch(batch):
     return batch, batch, None
 
 
+def _batch_shard_axes(batch: int):
+    """``(mesh, axes)`` when the loss head's scan should run per chip: the
+    program is traced under a mesh whose devices are ALL on the batch (dp)
+    axes, more than one of them, and their number divides the batch.
+    ``(None, None)`` otherwise: one device or no mesh, a mesh that also
+    shards the sequence ('seq'), the vocabulary ('tensor') or the layers
+    ('pipe'), or a region that is already manual."""
+    from deepspeed_tpu.sharding.mesh import ambient_mesh
+
+    mesh = ambient_mesh()
+    axes, _ = _attn_axes(mesh, batch, 1)
+    if axes is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return None, None
+    if math.prod(mesh.shape[a] for a in axes) != mesh.size:
+        return None, None
+    return mesh, axes
+
+
 def chunked_lm_loss(x, head, targets, loss_mask=None, bias=None, remat=True):
     """Mean next-token NLL with the vocab projection computed in sequence
     chunks.
@@ -401,9 +421,54 @@ def chunked_lm_loss(x, head, targets, loss_mask=None, bias=None, remat=True):
     x: (B, T, D) final hidden states already shifted to align with
     ``targets`` (B, T); ``head``: (D, V) in compute dtype; ``loss_mask``:
     optional (B, T) weighting. ``remat``: see the scan note below; False
-    trades the ~2.4G peak (saved per-chunk fp32 logits) back for ~1% step
-    time — only sensible when the model fits HBM with slack.
+    trades the peak of the saved per-chunk fp32 logits (B*T*V*4 bytes: 1.6G
+    at gpt2-760m's 8 x 1023 x 50257) back for ~1% step time — only sensible
+    when the model fits HBM with slack.
+
+    Where the rows are computed follows the mesh the program is traced
+    under (no option):
+
+    * one device, or no mesh: one scan over all ``B`` rows.
+    * a mesh whose devices are all on the batch axes (ZeRO over ``data``,
+      with ``mics`` / ``ici`` / ``expert`` where they are there): the scan
+      runs per chip, inside a ``shard_map`` over those axes, on the chip's
+      OWN ``B / world`` rows — so that is the ``B`` the chunk length is
+      sized from. ``head`` (and ``bias``) enter it WHOLE: a ZeRO-3 head is
+      all-gathered once a step at the door, in the compute dtype the
+      caller cast it to, and a head that is replicated anyway (ZeRO 0-2)
+      is not touched. Its cotangent accumulates locally over the chunks,
+      in that dtype, and leaves through ONE all-reduce a step (the
+      ``shard_map``'s transpose of an unsharded operand), of which ZeRO
+      keeps its slice. Left to the SPMD partitioner a ZeRO-3 head is
+      gathered inside the scan body, once a chunk forward and once a chunk
+      backward (gpt2-xl over data=4: 186 gathers of 160 MB a step), and a
+      head pinned replicated ahead of the scan has its WHOLE gradient
+      all-reduced once a chunk. The (B, T) losses come out sharded by
+      rows, so the sum and the mask's count below are one all-reduce of a
+      scalar each, the partitioner's.
+    * a mesh that shards the sequence ('seq'), the vocabulary ('tensor')
+      or the layers ('pipe'), a batch the batch axes do not divide, or a
+      region that is already manual: the first form, placed by the
+      partitioner. No benchmark cell runs one.
     """
+    mesh, axes = _batch_shard_axes(x.shape[0])
+    if mesh is None:
+        nll = _chunked_nll(x, head, targets, bias, remat)
+    else:
+        rows = P(axes)
+        nll = jax.shard_map(
+            functools.partial(_chunked_nll, remat=remat), mesh=mesh,
+            in_specs=(rows, P(), rows, P()), out_specs=rows,
+            check_vma=False)(x, head, targets, bias)
+    if loss_mask is not None:
+        m = loss_mask.astype(jnp.float32)
+        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
+    return jnp.mean(nll)
+
+
+def _chunked_nll(x, head, targets, bias, remat):
+    """(B, T) float32 ``logsumexp - target logit`` of the rows handed in,
+    the (B, chunk, V) logits of one chunk of positions at a time."""
     B, T, D = x.shape
     vocab = head.shape[1]
     chunk = max(1, min(T, _CHUNK_ELEMS // max(1, B * vocab)))
@@ -429,8 +494,4 @@ def chunked_lm_loss(x, head, targets, loss_mask=None, bias=None, remat=True):
     # the 760m headline, so small-model benches opt out via remat=False.
     body = jax.checkpoint(chunk_nll) if remat else chunk_nll
     _, nll = jax.lax.scan(body, 0.0, (xs, ts))                    # (n, B, C)
-    nll = nll.swapaxes(0, 1).reshape(B, T)
-    if loss_mask is not None:
-        m = loss_mask.astype(jnp.float32)
-        return jnp.sum(nll * m) / jnp.maximum(jnp.sum(m), 1.0)
-    return jnp.mean(nll)
+    return nll.swapaxes(0, 1).reshape(B, T)

@@ -12,6 +12,7 @@ hides the device, one cache directory placed from outside.
 
 import dataclasses
 import functools
+import math
 import os
 import re
 import subprocess
@@ -195,6 +196,121 @@ def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
         lowered = jax.jit(jax.value_and_grad(
             lambda p, b: model.loss(p, {"input_ids": b}))).lower(params, ids)
     assert lowered.as_text().count("tpu_custom_call") == 4
+
+
+# ---------------------- gpt2-xl's ZeRO-3 step over four chips (train.z3x4)
+HEAD_ELEMS = 50257 * 1600
+HBM_PER_CHIP = 16.91e9          # 15.75 GiB: what a v5e chip offers a program
+
+
+@pytest.fixture(scope="module")
+def xl_z3_step(v5e):
+    """The engine's REAL train step of ``gpt2-xl.train.z3x4`` (the cell's
+    own configuration and traffic files: ZeRO-3 over data=4, micro-batch 16 a
+    chip, bf16, AdamW, clipping, remat 'attn'), compiled for the four
+    described chips. Nothing can be placed on a described device, so the two
+    places where the engine materializes state hand back shapes instead."""
+    import json
+    import types
+
+    import deepspeed_tpu
+    from benchmark import families
+    from deepspeed_tpu.runtime import engine as engine_mod
+
+    mesh = _mesh(v5e, data=4)
+    cfg, traffic = (json.load(open(os.path.join(REPO, "benchmark", d, f)))
+                    for d, f in (("configs", "gpt2-xl.json"),
+                                 ("traffic", "train.z3x4.json")))
+    micro = traffic["engine"]["micro_batch_per_chip"]
+    ds = dict(cfg["train"]["ds_config"],
+              train_micro_batch_size_per_gpu=micro,
+              gradient_accumulation_steps=1, steps_per_print=0,
+              zero_optimization={"stage": traffic["engine"]["zero_stage"]})
+    real_jit, real_put = engine_mod.sharded_jit, jax.device_put
+
+    def abstract(shape, sharding):
+        return jax.ShapeDtypeStruct(shape.shape, shape.dtype, sharding=sharding)
+
+    def jit_or_shapes(fn, *, label, out_shardings, **kw):
+        if label != "engine/init_state":
+            return real_jit(fn, label=label, out_shardings=out_shardings, **kw)
+        return lambda: jax.tree.map(abstract, jax.eval_shape(fn), out_shardings)
+
+    def put_or_shape(x, sharding=None, **kw):
+        if getattr(sharding, "mesh", None) is mesh:
+            return abstract(jax.eval_shape(lambda: x), sharding)
+        return real_put(x, sharding, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(engine_mod, "sharded_jit", jit_or_shapes)
+        mp.setattr(jax, "device_put", put_or_shape)
+        engine, *_ = deepspeed_tpu.initialize(
+            model=families.get(cfg["family"]).build_model(cfg, "train"),
+            config=ds, mpu=types.SimpleNamespace(mesh=mesh))
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (4 * micro, traffic["seq_len"]), jnp.int32,
+        sharding=engine.sharding.batch_sharding(2))}
+    with mesh:
+        return engine._get_compiled_train_batch(1, batch).lower(
+            engine.state, batch).compile()
+
+
+def _head_collectives(text):
+    """[(op, dtype, inside a while body?)] of every all-gather / all-reduce /
+    reduce-scatter of the compiled text that moves the tied head's 50257 x
+    1600 elements: the result of a gather or an all-reduce, four times the
+    result of a reduce-scatter. XLA:TPU writes most reduce-scatters as a
+    fusion that calls a computation holding the whole all-reduce, which is
+    counted there, in a loop if its caller is."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name:
+            comps[name].append(line)
+    called = lambda keys, lines: [
+        c for l in lines for c in re.findall(rf"(?:{keys})=%?([\w.\-]+)", l)]
+    inside, todo = set(), [c for lines in comps.values()
+                           for c in called("body|condition", lines)]
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in inside:
+            inside.add(c)
+            todo += called("to_apply|calls|body|condition", comps[c])
+    found = []
+    for name, lines in comps.items():
+        for l in lines:
+            m = re.search(r"= (.*?) (all-gather|all-reduce|reduce-scatter)"
+                          r"(?:-start)?\(", l)
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]+)\]", m.group(1)) if m else ():
+                if math.prod(map(int, dims.split(","))) * (
+                        4 if m.group(2) == "reduce-scatter" else 1) == HEAD_ELEMS:
+                    found.append((m.group(2), dtype, name in inside))
+    return found
+
+
+def test_xl_z3_step_gathers_the_head_once_and_reduces_its_gradient_once(xl_z3_step):
+    """Left to the partitioner the chunked loss gathered the ZeRO-3 head in
+    the scan's body: 93 chunks forward and 93 backward, 186 gathers of 160
+    MB a step (ledger, PR 27: the largest device op of the cell), and an
+    all-reduce of the head's whole gradient a chunk beside them."""
+    found = _head_collectives(xl_z3_step.as_text())
+    assert [op for op, _, in_loop in found if in_loop] == [], found
+    assert 1 <= sum(op == "all-gather" for op, _, _ in found) <= 2, found
+    assert sum(op != "all-gather" for op, _, _ in found) == 1, found
+    # cast, then gather: 160 MB of bf16 and not 321 MB of float32
+    assert {dtype for _, dtype, _ in found} == {"bf16"}, found
+
+
+def test_xl_z3_step_at_micro_batch_16_fits_a_chip(xl_z3_step):
+    _, total = _footprint(xl_z3_step)
+    assert total < HBM_PER_CHIP, (
+        f"gpt2-xl ZeRO-3 step at micro-batch 16: {total / 1e9:.2f} GB of "
+        f"{HBM_PER_CHIP / 1e9:.2f} GB a chip")
+    # what XLA rematerializes to make a step fit is on no idle share (PR 26)
+    assert ".remat" not in xl_z3_step.as_text()
 
 
 # ------------------------------------ OLMoE-1B-7B at its published widths

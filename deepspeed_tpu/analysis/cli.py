@@ -47,7 +47,7 @@ Subcommand::
     ds_doctor race [--witness FILE ...] [--allow RULE ...]
 
 host-side concurrency analysis: the static lock-order / blocking-under-
-lock / signal-safety lint over the package (and bin/* + bench.py), plus
+lock / signal-safety lint over the package (and bin/*), plus
 offline analysis of runtime lock-witness logs (``utils.locks
 .save_witness``) — acquisition-order inversions are reported with both
 call sites even when no deadlock ever manifested. Needs no --config.
@@ -177,7 +177,7 @@ def race_cli(argv) -> int:
                     help="package root to analyze (default: the installed "
                          "deepspeed_tpu package)")
     ap.add_argument("--no-scripts", action="store_true",
-                    help="skip bin/* + bench.py (package modules only — "
+                    help="skip bin/* (package modules only — "
                          "the scope the engine-init pass uses)")
     ap.add_argument("--witness", action="append", default=[],
                     help="witness JSON from utils.locks.save_witness(); "

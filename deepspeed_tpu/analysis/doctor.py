@@ -71,9 +71,9 @@ def engine_init_analysis(engine, param_shapes) -> AnalysisReport:
             "sharding")
         # the unspecified-jit lint: no engine program may enter jax.jit
         # outside sharded_jit (AST over the package, memoized per process).
-        # Package only here: the repo-script scan (bin/*, bench.py) is a CI
-        # concern — a job vendoring this package next to its own bench.py
-        # must not die at engine init over scripts that never run
+        # Package only here: the repo-script scan (bin/*) is a CI concern —
+        # a job vendoring this package next to its own bin/ must not die
+        # at engine init over scripts that never run
         report.extend(lint_unspecified_jit(include_scripts=False),
                       "sharding")
     if _wants(acfg, "race"):

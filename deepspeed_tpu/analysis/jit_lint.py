@@ -117,15 +117,11 @@ _AST_CACHE = {}
 
 def repo_script_paths(root: str) -> List[str]:
     """The repo-level entry scripts the lint also covers: ``bin/*``
-    (extensionless python launchers) and ``bench.py``. These dispatch
-    real programs — bench.py compiles the whole ladder — so a bare
-    ``jax.jit`` there is exactly as deadlock-capable as one in the
-    package; package-only coverage left them a blind spot."""
+    (extensionless python launchers). These dispatch real programs, so
+    a bare ``jax.jit`` there is exactly as deadlock-capable as one in
+    the package; package-only coverage left them a blind spot."""
     repo = os.path.dirname(root)
     out: List[str] = []
-    bench = os.path.join(repo, "bench.py")
-    if os.path.isfile(bench):
-        out.append(bench)
     bindir = os.path.join(repo, "bin")
     if os.path.isdir(bindir):
         for fn in sorted(os.listdir(bindir)):
@@ -147,9 +143,9 @@ def lint_unspecified_jit(root: Optional[str] = None,
                          skip_dirs: Sequence[str] = ("__pycache__",),
                          include_scripts: bool = True) -> List[Finding]:
     """AST lint of every .py file of the deepspeed_tpu package, plus the
-    repo's entry scripts (``bin/*``, ``bench.py``) when they sit next to
-    it. Memoized per root: the source tree does not change mid-process,
-    and the engine runs this at every init."""
+    repo's entry scripts (``bin/*``) when they sit next to it. Memoized
+    per root: the source tree does not change mid-process, and the engine
+    runs this at every init."""
     if root is None:
         import deepspeed_tpu
 

@@ -893,7 +893,7 @@ _LINT_CACHE: Dict[Tuple[str, bool], List[Finding]] = {}
 def lint_race(root: Optional[str] = None, include_scripts: bool = True,
               allowlist: Sequence[str] = ()) -> List[Finding]:
     """The three static rules over the package (and, by default, the repo
-    entry scripts ``bin/*`` + ``bench.py``). Memoized per root like the
+    entry scripts ``bin/*``). Memoized per root like the
     unspecified-jit lint — the source tree does not change mid-process.
     ``allowlist`` entries (``analysis.race_allowlist``) are
     ``"race/<rule>[:substr]"``; matching findings are filtered, unknown

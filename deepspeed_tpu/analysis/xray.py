@@ -721,10 +721,10 @@ def static_comm_for_engine(engine) -> Optional[Dict[str, Any]]:
     global and may hold train programs of other engines or earlier gas
     configurations. Single-device meshes short-circuit to zero bytes
     WITHOUT paying the AOT compile (no partitions ⇒ no collectives by
-    construction) — this keeps ``bench.py --smoke`` fast while still
-    stamping the key. The bill is deterministic per compiled program, so
-    it is memoized on the record: a loop recording N perf entries pays
-    the AOT compile once, not N times."""
+    construction), so a one-device entry still carries the key. The
+    bill is deterministic per compiled program, so it is memoized on the
+    record: a loop recording N perf entries pays the AOT compile once,
+    not N times."""
     from deepspeed_tpu.sharding import program_table
     from deepspeed_tpu.sharding.mesh import mesh_axes_string
 

@@ -64,12 +64,6 @@ class AutotuningConfig:
     # candidates that offload.
     offload_overlap_list: Optional[List[bool]] = None
     flash_block_list: Optional[List[Optional[int]]] = None  # kernel tile edges
-    # head-count variants at fixed n_embd (param/flop-invariant relayout —
-    # a DIFFERENT architecture, reported as such): the r5 sweeps measured
-    # fewer/fatter heads beating head_dim=128 for pretrain (gpt2-760m 4x384
-    # 0.569 vs 12x128 0.536) with per-model sweet spots, so the axis is
-    # worth tuning per model. None entries keep the factory's own layout.
-    heads_list: Optional[List[Optional[int]]] = None
     # first-order HBM model: candidates predicted over this fraction of HBM
     # are pruned BEFORE compiling; 0 disables. Default 1.5 (= only prune
     # candidates 50% past HBM) because the model omits real contributors
@@ -179,17 +173,10 @@ class Autotuner:
         off_list = t.offload_list or [False]
         ov_list = t.offload_overlap_list or [False]
         fb_list = t.flash_block_list or [None]
-        heads_list = t.heads_list or [None]
-        if t.heads_list and not self._factory_accepts("n_head"):
-            # otherwise the axis multiplies the space with IDENTICAL models
-            # and the reported winner carries a knob that was never applied
-            logger.warning("autotuner: heads_list set but the model factory "
-                           "does not accept n_head; axis dropped")
-            heads_list = [None]
         out = []
-        for mbs, stage, remat, gas, tp, off, ov, fb, nh in itertools.product(
+        for mbs, stage, remat, gas, tp, off, ov, fb in itertools.product(
                 mbs_list, zero_list, remat_list, gas_list, tp_list, off_list,
-                ov_list, fb_list, heads_list):
+                ov_list, fb_list):
             if ov and not off:
                 continue   # overlap only exists on the offload path
             cfg = json.loads(json.dumps(self.base_config))   # deep copy
@@ -211,20 +198,9 @@ class Autotuner:
             # (convergence-affecting); pass it in base_config to tune with it
             cfg["_tune"] = {"remat": remat, "micro_batch": mbs, "zero": stage,
                             "gas": gas, "tp": tp, "offload": off,
-                            "offload_overlap": ov, "flash_block": fb,
-                            "n_head": nh}
+                            "offload_overlap": ov, "flash_block": fb}
             out.append(cfg)
         return out
-
-    def _factory_accepts(self, name: str) -> bool:
-        import inspect
-
-        try:
-            sig = inspect.signature(self.model_factory).parameters
-            return name in sig or any(
-                p.kind is inspect.Parameter.VAR_KEYWORD for p in sig.values())
-        except (TypeError, ValueError):
-            return False
 
     # --------------------------------------------------------- HBM cost model
     def estimate_hbm_bytes(self, tune: Dict[str, Any],
@@ -328,15 +304,13 @@ class Autotuner:
                 accepted = set(sig)
                 if any(p.kind is inspect.Parameter.VAR_KEYWORD
                        for p in sig.values()):
-                    accepted |= {"remat", "flash_block", "n_head"}
+                    accepted |= {"remat", "flash_block"}
             except (TypeError, ValueError):
                 accepted = {"remat"}
             if "remat" in tune and "remat" in accepted:
                 kw["remat"] = tune["remat"]
             if tune.get("flash_block") and "flash_block" in accepted:
                 kw["flash_block"] = tune["flash_block"]
-            if tune.get("n_head") and "n_head" in accepted:
-                kw["n_head"] = tune["n_head"]
             model = self.model_factory(**kw)
             refs["model"] = model
             engine, *_ = deepspeed_tpu.initialize(model=model, config=cfg)

@@ -129,7 +129,7 @@ def _env_report() -> Dict[str, Any]:
     except Exception as e:  # noqa: BLE001
         out["error"] = str(e)
     env_keys = ("JAX_PLATFORMS", "XLA_FLAGS", "TPU_CHIPS_PER_HOST_BOUNDS",
-                "LIBTPU_INIT_ARGS", "DS_BENCH_PRESET")
+                "LIBTPU_INIT_ARGS")
     out["env"] = {k: os.environ[k] for k in env_keys if k in os.environ}
     return out
 

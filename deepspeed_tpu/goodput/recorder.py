@@ -169,8 +169,8 @@ class GoodputMeter:
         """The ``goodput`` block of a perf-ledger entry: per-step ledgers
         of the timed window (last ``timed_steps`` complete steps), the
         summed buckets, and the window's goodput fraction. Buckets sum to
-        each step's measured wall window exactly (asserted by the bench
-        --smoke acceptance test at 5% against the train span samples)."""
+        each step's measured wall window exactly (asserted at 5% against
+        the train span samples by tests/unit/test_goodput.py)."""
         if events is None:
             session = _telemetry.get_session()
             events = list(getattr(session.tracer, "events", []) or []) \

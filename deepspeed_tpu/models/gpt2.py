@@ -159,14 +159,6 @@ PRESETS = {
     "gpt2-tiny": GPT2Config(vocab_size=2048, n_positions=256, n_embd=128, n_layer=2, n_head=4),
     "gpt2-125m": GPT2Config(n_embd=768, n_layer=12, n_head=12),
     "gpt2-350m": GPT2Config(n_embd=1024, n_layer=24, n_head=16),
-    # 12 heads, not the GPT-2-paper-style 16: head_dim 128 = the MXU lane
-    # width, so QK^T/PV tiles carry no K-dim padding (16 heads -> head_dim 96
-    # pads every MXU pass 96->128; measured 0.512 -> 0.533-0.536 MFU on v5e).
-    # Param count and flops_per_token are head-count invariant.
-    # canonical 16-head layout (param shapes are head-count invariant, but the
-    # grouping is architecture: checkpoints must keep their meaning). The TPU
-    # bench/tuner relayout to 12x128 heads via registry.tpu_native_layout —
-    # never by editing this preset.
     "gpt2-760m": GPT2Config(n_embd=1536, n_layer=24, n_head=16),
     "gpt2-1.3b": GPT2Config(n_embd=2048, n_layer=24, n_head=16, n_positions=2048),
     "gpt2-xl": GPT2Config(n_embd=1600, n_layer=48, n_head=25, n_positions=1024),

@@ -1,8 +1,9 @@
-"""Model-family registry shared by the bench and autotuner entry points.
+"""Model-family registry shared by the entry points that take a preset name.
 
 One place maps a preset name (``gpt2-*``, ``gpt2-moe-*``, ``llama-*``,
 ``bert-*``) to (model class, synthetic-batch builder, preset table) so
-``bench.py`` and ``bin/ds_tune`` cannot drift apart on family dispatch.
+``bin/ds_tune``, ``ds_doctor``, ``ds_roofline`` and ``ds_serve`` cannot
+drift apart on family dispatch.
 """
 
 from __future__ import annotations
@@ -36,62 +37,3 @@ def resolve_family(model_name: str, moe_experts: int = 8
         return cls, synthetic_lm_batch, {
             model_name: GPT2_PRESETS[model_name.replace("-moe", "")]}
     return GPT2Model, synthetic_lm_batch, GPT2_PRESETS
-
-
-def mxu_aligned(config):
-    """TPU-native pretrain head layout: head_dim = 128 (the MXU lane width).
-
-    Param- and flop-count invariant for plain multi-head attention (gpt2/bert
-    families — do NOT use for llama GQA, where kv_dim follows n_kv_head).
-    Applied by bench.py and bin/ds_tune through this one helper so the tuner
-    sweeps the same model the bench measures. No-op when n_embd is not a
-    multiple of 128 (e.g. gpt2-xl's 1600) or the layout is already aligned.
-    """
-    import dataclasses
-
-    if config.n_embd % 128 == 0 and config.n_head != config.n_embd // 128:
-        return dataclasses.replace(config, n_head=config.n_embd // 128)
-    return config
-
-
-# Measured TPU head layouts per preset (v5e). head_dim=128 (the MXU lane
-# width) was round-4's lever; round 5 measured that FEWER, FATTER heads go
-# further — per-head grid iterations drop and the contraction stays
-# tile-aligned — up to a per-model sweet spot (beyond it the flash kernel's
-# vmem scratch or HBM gives out):
-#   gpt2-760m (1536): 12x128 0.536 < 6x256 0.545 < 3x512 0.549 < 4x384 0.569
-#     (2x768 OOM)
-#   bert-large (1024): 8x128 0.568 < 4x256 0.568 < 2x512 0.576 @seq512;
-#     2x512 lifts the seq128 record config 0.614 -> 0.694
-#   gpt2-xl (1600): 25x64 0.429 < 20x80 < 10x160 < 8x200 ~= 5x320 0.50
-#     (4x400 exceeds the kernel vmem stack)
-#   gpt2-moe-125m: no change beyond 6x128 (dispatch-bound, stays mxu_aligned)
-#   gpt2-1.3b: 8x256 within noise of 16x128 (offload-bound, stays aligned)
-# Param/flop-invariant, but a DIFFERENT architecture — every consumer must
-# log the relayout (see tpu_native_layout).
-TPU_HEAD_OVERRIDES = {"gpt2-xl": 5, "gpt2-760m": 4, "bert-large": 2}
-
-
-def tpu_native_layout(config, model_name: str = "", log=None):
-    """The layout bench.py and bin/ds_tune measure on TPU: the measured
-    per-preset override when one exists, else ``mxu_aligned`` (head_dim=128).
-    ``log``: callable fed a one-line notice whenever the head count actually
-    changes — the knob that keeps reported configs reproducible (a result
-    measured on a relayout must SAY so)."""
-    import dataclasses
-
-    heads = TPU_HEAD_OVERRIDES.get(model_name)
-    if heads and config.n_embd % heads == 0:
-        # idempotent: a config already at the override layout passes through
-        # (falling through to mxu_aligned would oscillate 4 -> 12 -> 4)
-        out = config if config.n_head == heads \
-            else dataclasses.replace(config, n_head=heads)
-    else:
-        out = mxu_aligned(config)
-    if log is not None and out is not config:
-        log(f"TPU-native head relayout: {model_name or 'model'} "
-            f"n_head {config.n_head} -> {out.n_head} (head_dim "
-            f"{config.n_embd // config.n_head} -> {out.n_embd // out.n_head}; "
-            f"param/flop-invariant, architecture differs — reproduce with "
-            f"n_head={out.n_head})")
-    return out

@@ -20,9 +20,9 @@ regression fail loudly:
 * :mod:`~deepspeed_tpu.perf.cli` — ``bin/ds_perf`` (show / diff / gate /
   calibration), pure stdlib so it runs far from any TPU.
 
-``bench.py`` runs every ladder line under a telemetry session and records
-through this package; ``ds_perf gate --baseline BENCH_r05.json`` is the
-CI tooth that fails a PR regressing a headline metric.
+A training script records through ``engine.perf_record(...)``;
+``ds_perf gate --baseline A.jsonl --candidate B.jsonl`` over two ledgers
+written that way fails a change that regresses a gated series.
 """
 
 from deepspeed_tpu.perf.ledger import (SCHEMA_VERSION, append_entry, compare,

@@ -29,7 +29,7 @@ from typing import Any, Dict, List, Optional, Sequence
 # remat policy → fraction of peak the fwd+bwd step can plausibly reach
 # (the recompute tax: 'full' recomputes the whole fwd in bwd, 'attn' only
 # the cheap matmul chain, 'none' recomputes nothing). Ballparked from the
-# measured v5e family sweet spots in bench.py's docstring; calibration
+# v5e sweeps of the rounds before PR 1 (never re-measured); calibration
 # exists precisely because these decay.
 _REMAT_EFFICIENCY = {"none": 0.55, False: 0.55, "attn": 0.50,
                      "dots": 0.42, "full": 0.38}

@@ -8,16 +8,16 @@ Subcommands (all pure stdlib — run them on a laptop, in CI, anywhere):
   of every series two ledgers share, with noise bounds: a delta only
   counts as regression/improvement when the per-step samples clear a
   Welch-style t gate (entries without samples fall back to the plain
-  threshold). ``A``/``B`` may be perf ledgers (JSONL) or historical
-  ``BENCH_rNN.json`` driver files.
-* ``ds_perf gate --baseline BENCH_r05.json [--candidate perf_ledger.jsonl]``
+  threshold).
+* ``ds_perf gate --baseline base.jsonl [--candidate perf_ledger.jsonl]``
   — CI teeth: exit 2 when a gated series regresses OR its newest
   candidate entry is a failure line (a crashed headline bench fails the
   gate even when an older success sits in the append-only ledger), exit
   3 when a gated series was never measured (``--allow-missing``
   downgrades that to a warning). Default gate set = the baseline's
-  headline entry (the driver format marks it); ``--metric SUBSTR`` gates
-  matching series instead, ``--all`` gates every shared series.
+  entries marked ``"headline": true`` (every series when none is);
+  ``--metric SUBSTR`` gates matching series instead, ``--all`` gates
+  every shared series.
 * ``ds_perf calibration <ledger|results_dir>`` — predicted-vs-measured
   cost-model error over the autotuner's ``tune_candidate`` entries.
 """
@@ -41,7 +41,11 @@ def _load(path: str):
     if not os.path.exists(path):
         print(f"ds_perf: no such file: {path}", file=sys.stderr)
         raise SystemExit(1)
-    return led.load_baseline(path)
+    try:
+        return led.load_baseline(path)
+    except ValueError as e:
+        print(f"ds_perf: {e}", file=sys.stderr)
+        raise SystemExit(1)
 
 
 def _cmd_show(args) -> int:
@@ -353,7 +357,7 @@ def main(argv=None) -> int:
     sub = p.add_subparsers(dest="cmd")
 
     s = sub.add_parser("show", help="latest entry per benchmark series")
-    s.add_argument("ledger", help="perf ledger JSONL (or BENCH_rNN.json)")
+    s.add_argument("ledger", help="perf ledger JSONL")
 
     d = sub.add_parser("diff", help="compare two ledgers with noise bounds")
     d.add_argument("old")
@@ -368,7 +372,7 @@ def main(argv=None) -> int:
 
     g = sub.add_parser("gate", help="exit 2 on a gated-series regression")
     g.add_argument("--baseline", required=True,
-                   help="baseline ledger / BENCH_rNN.json")
+                   help="baseline ledger")
     g.add_argument("--candidate", default="perf_ledger.jsonl",
                    help="candidate ledger (default ./perf_ledger.jsonl)")
     g.add_argument("--rel-tol", type=float, default=0.08,

@@ -1,6 +1,6 @@
 """Append-only perf ledger: every benchmark number, with attribution.
 
-The BENCH_r0*.json history taught two lessons the hard way: a metric line
+Two lessons from the records that came before it: a metric line
 that is just ``{"metric", "value"}`` cannot be diffed against anything
 (the config is crammed into the metric STRING), and a regression found
 five PRs later cannot be attributed to anything (the line carries no
@@ -21,12 +21,6 @@ fingerprint, no environment, no breakdown). The ledger fixes both:
 
 Everything here is pure stdlib: ``bin/ds_perf`` diffs ledgers on a laptop
 with no jax installed, exactly like ``bin/ds_prof`` merges traces.
-
-Baseline compatibility: :func:`load_baseline` also reads the historical
-driver format (``BENCH_rNN.json``: ``{"cmd", "rc", "tail", "parsed"}``
-where ``tail`` is the benched JSON lines) and bare JSON-lines text, so
-``ds_perf gate --baseline BENCH_r05.json`` works against the existing
-record without converting anything.
 """
 
 from __future__ import annotations
@@ -46,7 +40,7 @@ _GIT_REV_CACHE: Dict[str, str] = {}
 
 def git_rev(cwd: Optional[str] = None) -> str:
     """Short git revision of ``cwd`` (or this file's repo); "" when not a
-    checkout. Cached — bench ladders call this once per line."""
+    checkout. Cached — a run asks once per entry it records."""
     key = cwd or os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     if key not in _GIT_REV_CACHE:
@@ -66,9 +60,7 @@ def series_key(entry: Dict[str, Any]) -> str:
     metric string is ``"<label> FAILED: ..."``, which must still land in
     the same series as the measurement it failed to produce), else the
     metric string's config-free prefix (everything before " (") plus the
-    unit. Works for both ledger entries and the historical bench lines,
-    whose metric strings share the same ``"<name> <what> (knobs...)"``
-    shape."""
+    unit: metric strings have the shape ``"<name> <what> (knobs...)"``."""
     series = entry.get("series")
     if series:
         return f"{series} [{entry.get('unit', '')}]"
@@ -111,11 +103,10 @@ def load_entries(path: str) -> List[Dict[str, Any]]:
 
 
 def load_baseline(path: str) -> List[Dict[str, Any]]:
-    """Entries from ANY of the three formats a baseline can live in:
-    a perf ledger (JSONL), the driver's ``BENCH_rNN.json`` wrapper
-    (``tail`` = benched JSON lines, ``parsed`` = the headline), or bare
-    JSON-lines text. The driver format marks its ``parsed`` headline with
-    ``"headline": True`` so ``gate`` can default to it."""
+    """Entries from either format a baseline can live in: a perf ledger
+    (JSON lines, as ``engine.perf_record`` appends them) or one JSON
+    document holding an entry or a list of entries. Any other document
+    is rejected, not guessed at."""
     if path.endswith((".jsonl", ".ndjson")):
         # a perf ledger BY EXTENSION: parse line-wise natively instead of
         # relying on the whole-text json.loads to fail first — a
@@ -127,34 +118,18 @@ def load_baseline(path: str) -> List[Dict[str, Any]]:
     try:
         data = json.loads(text)
     except ValueError:
-        data = None
-    if isinstance(data, dict) and "tail" in data and "parsed" in data:
-        entries = []
-        for line in str(data.get("tail", "")).splitlines():
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                entries.append(json.loads(line))
-            except ValueError:
-                continue
-        parsed = data.get("parsed")
-        if isinstance(parsed, dict):
-            pk = series_key(parsed)
-            matched = False
-            for e in entries:
-                if series_key(e) == pk:
-                    e["headline"] = True
-                    matched = True
-            if not matched:
-                parsed = dict(parsed, headline=True)
-                entries.append(parsed)
-        return entries
-    if isinstance(data, dict):
-        return [data]
-    if isinstance(data, list):
-        return [e for e in data if isinstance(e, dict)]
-    return load_entries(path)
+        return load_entries(path)
+    entries = data if isinstance(data, list) else [data]
+    bad = next((e for e in entries
+                if not isinstance(e, dict) or "metric" not in e), None)
+    if bad is not None:
+        what = (f"an object with keys {sorted(bad)}" if isinstance(bad, dict)
+                else type(bad).__name__)
+        raise ValueError(
+            f"{path}: not a perf ledger. ds_perf reads (1) JSON lines, one "
+            "entry per line, and (2) one JSON document holding an entry or "
+            f"a list of entries, each with a \"metric\"; found {what}")
+    return entries
 
 
 def is_nonmeasurement(entry: Dict[str, Any]) -> bool:

@@ -12,10 +12,10 @@ ledger entries from what the run already knows:
   live telemetry session + engine profiling hooks;
 * the caller's headline (metric string / value / unit / model / knobs).
 
-``bench.py`` calls :meth:`PerfRecorder.record` once per ladder line; any
-training script can do the same through ``engine.perf_record(...)``.
-Entries append to ``perf.ledger_path`` (rank 0 only) and are returned to
-the caller either way.
+A training script calls ``engine.perf_record(...)`` (which lands in
+:meth:`PerfRecorder.record`) once per number it wants kept. Entries
+append to ``perf.ledger_path`` (rank 0 only) and are returned to the
+caller either way.
 """
 
 from __future__ import annotations

@@ -267,7 +267,7 @@ class GrayManager:
 
     def should_probe(self, step: int) -> bool:
         """Probe when an unconditional cadence says so (``probe_every``,
-        the bench/CI pricing mode), or when suspicion clears the blame
+        the CI pricing mode), or when suspicion clears the blame
         threshold with the evidence floor met and the probe rate limit
         open."""
         pe = int(self.cfg.probe_every)

@@ -121,7 +121,7 @@ RAW_BLOCK_KEYS = {
         "tuner_type", "tuner_early_stopping", "tuner_num_trials",
         "results_dir", "exps_dir", "fast", "mbs_list", "zero_stage_list",
         "remat_list", "gas_list", "tp_list", "offload_list",
-        "offload_overlap_list", "flash_block_list", "heads_list",
+        "offload_overlap_list", "flash_block_list",
         "hbm_prune_fraction", "exact_memory_check", "exact_memory_fraction",
         "assume_hbm_bytes", "ledger_path"}),
     "data_efficiency": frozenset({"enabled", "seed", "data_sampling",
@@ -615,11 +615,11 @@ class PerfConfig(DeepSpeedConfigModel):
     as the comparison key, per-step samples for ``ds_perf diff``'s noise
     bounds, and attribution from the live telemetry session (span
     p50/p99, memory-census buckets, flops, exposed-comm µs/step).
-    ``bench.py`` drives it for every ladder line; ``bin/ds_perf``
-    diffs/gates the resulting ledgers. STRICT no-op when the block is
-    absent: the perf package is never imported and the engine records
-    nothing (same contract as ``analysis`` / ``profiling``). See
-    docs/BENCH.md for the ledger schema and gate semantics."""
+    ``bin/ds_perf`` diffs/gates the resulting ledgers. STRICT no-op when
+    the block is absent: the perf package is never imported and the
+    engine records nothing (same contract as ``analysis`` /
+    ``profiling``). ``perf/ledger.py`` documents the entry schema and
+    ``perf/cli.py`` the gate semantics."""
     enabled: bool = Field(True, description="arm the perf recorder (the block being present opts in; set false to keep the block but skip the work)")
     ledger_path: str = Field("", description="append each perf_record() entry to this JSONL ledger (process 0 only); empty = entries are returned to the caller but not persisted")
     attribution: bool = Field(True, description="embed the telemetry/profiling attribution (span p50/p99, memory census, flops, exposed comm) in each entry; false = headline + identity fields only")
@@ -869,7 +869,7 @@ class GrayConfig(DeepSpeedConfigModel):
     hysteresis: float = Field(0.85, gt=0.0, lt=1.0, description="EWMA decay per step — suspicion s' = h*s + (1-h)*evidence; higher = slower to accuse AND slower to forgive (the false-positive floor)")
     min_evidence: int = Field(3, ge=1, description="distinct evidence-bearing steps required before any probe — a single recompile spike or GC pause can never reach a probe, let alone a verdict")
     probe_interval: int = Field(10, gt=0, description="minimum steps between suspicion-triggered microprobes — bounds probe badput even under sustained suspicion")
-    probe_every: int = Field(0, ge=0, description="ALSO probe unconditionally every N steps (0 = suspicion-only) — the bench/CI cadence that prices gray_overhead deterministically")
+    probe_every: int = Field(0, ge=0, description="ALSO probe unconditionally every N steps (0 = suspicion-only) — the CI cadence that prices gray_overhead deterministically")
     probe_confirmations: int = Field(2, ge=1, description="consecutive probes that must name the SAME device before a verdict — one noisy probe never evicts")
     probe_size: int = Field(256, ge=8, description="square matmul dimension / transfer payload rows of the microprobe (tiny by design: the probe must cost microseconds)")
     evict: bool = Field(True, description="on a confirmed verdict, quarantine the culprit and raise the TBS-stepped FleetResizeEvent shrink (needs elasticity.resize armed); false = report-only (verdicts land in telemetry/restart_log but the fleet keeps its drag)")

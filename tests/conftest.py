@@ -142,3 +142,78 @@ def mesh8():
     from deepspeed_tpu.parallel.topology import build_mesh
 
     return build_mesh(axis_dims={"pipe": 1, "data": 8, "expert": 1, "seq": 1, "tensor": 1})
+
+
+@pytest.fixture(scope="session")
+def tiny_ledger_run():
+    """``run(out_dir, extra=None, devices=1, seq=128) -> (engine, entry)``:
+    gpt2-tiny through ``deepspeed_tpu.initialize`` with the ``telemetry``,
+    ``perf`` and ``goodput`` blocks (plus whatever ``extra`` arms), two
+    warm-up steps, three timed ones (the fewest the ledger's t gate has
+    power on), then one ``engine.perf_record``. ``devices`` is the width
+    of the data-parallel mesh (ZeRO-3 past one device); ``None`` leaves
+    the mesh to the config. The entry is also the one line of
+    ``out_dir/ledger.jsonl``. Process globals the run armed are reset
+    before it returns."""
+    import sys
+    import time
+    import types
+
+    steps = 3
+
+    def run(out_dir, extra=None, devices=1, seq=128):
+        import deepspeed_tpu
+        from deepspeed_tpu import telemetry
+        from deepspeed_tpu.models.gpt2 import (PRESETS, GPT2Model,
+                                               synthetic_lm_batch)
+        from deepspeed_tpu.parallel.topology import build_mesh
+
+        n_dev = devices or len(jax.devices())
+        mcfg = PRESETS["gpt2-tiny"]
+        cfg = {
+            "train_batch_size": 2 * n_dev,
+            "optimizer": {"type": "AdamW",
+                          "params": {"lr": 1e-4, "weight_decay": 0.01}},
+            "bf16": {"enabled": True},
+            "zero_optimization": {"stage": 3 if n_dev > 1 else 1},
+            "gradient_clipping": 1.0,
+            "steps_per_print": 0,
+            "telemetry": {"enabled": True, "prometheus": False,
+                          "output_dir": str(out_dir / "telemetry"),
+                          "flush_interval": 1_000_000},
+            "profiling": {"sample_interval": 1_000_000},
+            "perf": {"ledger_path": str(out_dir / "ledger.jsonl")},
+            "goodput": {},
+        }
+        cfg.update(extra or {})
+        mpu = None
+        if devices:
+            mpu = types.SimpleNamespace(mesh=build_mesh(
+                axis_dims={"pipe": 1, "data": devices, "expert": 1, "seq": 1,
+                           "tensor": 1}, devices=jax.devices()[:devices]))
+        try:
+            engine, *_ = deepspeed_tpu.initialize(
+                model=GPT2Model(mcfg), config=cfg, mpu=mpu)
+            batch = engine._shard_batch(synthetic_lm_batch(
+                cfg["train_batch_size"], seq, mcfg.vocab_size, seed=0))
+            for _ in range(2):
+                loss = engine.train_batch(batch)
+            float(loss)
+            t0 = time.perf_counter()
+            for _ in range(steps):
+                loss = engine.train_batch(batch)
+            float(loss)
+            tok_s = cfg["train_batch_size"] * seq * steps / (
+                time.perf_counter() - t0)
+            entry = engine.perf_record(
+                f"gpt2-tiny pretrain tok/s (seq={seq}, {n_dev} device(s))",
+                round(tok_s, 1), "tok/s", model="gpt2-tiny", seed=0,
+                timed_steps=steps, config={"seq": seq, "steps": steps})
+            telemetry.flush()
+            return engine, entry
+        finally:
+            telemetry.deconfigure()
+            if "deepspeed_tpu.blackbox" in sys.modules:
+                sys.modules["deepspeed_tpu.blackbox"].deconfigure()
+
+    return run

@@ -175,38 +175,32 @@ class TestDsTuneCLI:
         assert res["status"] == "ok"
         assert res["tuned"]["micro_batch"] == 2
 
+    def test_report_describes_the_preset_it_was_given(self, tmp_path, capsys,
+                                                      monkeypatch):
+        """The tuner tunes the system, never the model: every candidate is
+        built at the preset's own published n_head and the report carries
+        no head_relayout marker."""
+        import runpy
+        import sys
 
-def test_heads_axis_reaches_factory(tmp_path):
-    """The r5 fat-head axis: heads_list expands the space and the winning
-    candidate's n_head reaches the model factory (and the reported config)."""
-    import jax
+        from deepspeed_tpu.models.gpt2 import PRESETS, GPT2Model
 
-    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+        built = []
+        init = GPT2Model.__init__
 
-    seen = []
+        def spy(self, config, *a, **kw):
+            built.append(config.n_head)
+            init(self, config, *a, **kw)
 
-    def factory(remat="none", n_head=None):
-        cfg = GPT2Config(vocab_size=128, n_positions=32, n_embd=16,
-                         n_layer=1, n_head=n_head or 2, remat=False,
-                         use_flash_attention=False)
-        seen.append(cfg.n_head)
-        return GPT2Model(cfg)
-
-    def batches(bs):
-        rng = np.random.RandomState(0)
-        return {"input_ids": rng.randint(0, 128, size=(bs, 16)).astype(np.int32)}
-
-    t = AutotuningConfig(enabled=True, start_profile_step=1, end_profile_step=2,
-                         results_dir=str(tmp_path / "r"),
-                         exps_dir=str(tmp_path / "e"),
-                         mbs_list=[1], zero_stage_list=[0],
-                         remat_list=["none"], heads_list=[2, 4],
-                         tuner_type="gridsearch")
-    at = Autotuner(factory, batches,
-                   {"optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-                    "steps_per_print": 0}, t)
-    cands = at.candidate_space()
-    assert {c["_tune"]["n_head"] for c in cands} == {2, 4}
-    best = at.tune()
-    assert best is not None and best["_tuned"]["n_head"] in (2, 4)
-    assert set(seen) >= {2, 4}
+        monkeypatch.setattr(GPT2Model, "__init__", spy)
+        monkeypatch.setattr(sys, "argv", [
+            "ds_tune", "--model", "gpt2-tiny", "--seq", "64",
+            "--mbs", "2", "--remat", "none", "--steps", "1",
+            "--output", str(tmp_path)])
+        runpy.run_path(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.dirname(os.path.abspath(__file__)))), "bin", "ds_tune"),
+            run_name="__main__")
+        res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert res["status"] == "ok" and "head_relayout" not in res
+        assert "n_head" not in res["tuned"]
+        assert built and set(built) == {PRESETS["gpt2-tiny"].n_head}

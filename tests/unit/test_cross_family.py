@@ -186,43 +186,25 @@ def test_autotp_classifies_raw_bert_tree():
     assert tuple(mlp_out) == ("tensor", None)
 
 
-def test_mxu_aligned_is_param_and_flop_invariant():
-    """registry.mxu_aligned must only relayout heads: same n_embd, same
-    num_params, same flops_per_token — and no-op when n_embd % 128 != 0
-    (gpt2-xl's 1600) or the layout is already aligned."""
-    from deepspeed_tpu.models.bert import PRESETS as BERT_PRESETS
-    from deepspeed_tpu.models.gpt2 import PRESETS as GPT2_PRESETS
-    from deepspeed_tpu.models.registry import mxu_aligned
+@pytest.mark.parametrize("name,n_head,head_dim", [
+    ("gpt2-760m", 16, 96),      # GPT-3 Table 2.1 "Large"
+    ("gpt2-xl", 25, 64),        # GPT-2 1.5B
+    ("gpt2-1.3b", 16, 128),     # GPT-3 Table 2.1 "XL" (the repo's 16-head cut)
+    ("bert-large", 16, 64),     # BERT-large
+])
+def test_preset_keeps_its_published_heads(name, n_head, head_dim, monkeypatch):
+    """A preset is the published architecture on every backend: what
+    resolve_family hands back, and the model built from it, carry the
+    published head count and width — nothing swaps them on a TPU."""
+    from deepspeed_tpu.models.registry import resolve_family
 
-    bl = BERT_PRESETS["bert-large"]
-    al = mxu_aligned(bl)
-    assert al.n_head == bl.n_embd // 128 and al.n_embd == bl.n_embd
-    assert al.num_params() == bl.num_params()
-    assert al.flops_per_token(512) == bl.flops_per_token(512)
-
-    xl = GPT2_PRESETS["gpt2-xl"]          # 1600 % 128 != 0: untouched
-    assert mxu_aligned(xl) is xl
-    m760 = GPT2_PRESETS["gpt2-760m"]      # canonical 16 heads -> 12 x 128
-    a760 = mxu_aligned(m760)
-    assert a760.n_head == 12 and a760.num_params() == m760.num_params()
-
-    # per-preset override where head_dim=128 is unreachable (gpt2-xl 1600):
-    # measured 5 x 320 (see registry.TPU_HEAD_OVERRIDES); logged via callback
-    from deepspeed_tpu.models.registry import tpu_native_layout
-
-    notes = []
-    nxl = tpu_native_layout(xl, "gpt2-xl", log=notes.append)
-    assert nxl.n_head == 5 and nxl.num_params() == xl.num_params()
-    assert nxl.flops_per_token(1024) == xl.flops_per_token(1024)
-    assert notes and "n_head 25 -> 5" in notes[0]
-    # unknown preset name: falls back to mxu_aligned only, no log
-    assert tpu_native_layout(xl, "not-a-preset", log=notes.append) is xl
-    assert len(notes) == 1
-    # measured fat-head overrides take precedence over mxu_aligned
-    n760 = tpu_native_layout(m760, "gpt2-760m")
-    assert n760.n_head == 4 and n760.num_params() == m760.num_params()
-    bl2 = tpu_native_layout(bl, "bert-large")
-    assert bl2.n_head == 2 and bl2.num_params() == bl.num_params()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model_cls, _, presets = resolve_family(name)
+    cfg = presets[name]
+    assert (cfg.n_head, cfg.head_dim) == (n_head, head_dim)
+    assert cfg.n_head * cfg.head_dim == cfg.n_embd
+    built = model_cls(cfg).config
+    assert (built.n_head, built.head_dim) == (n_head, head_dim)
 
 
 def test_llama32_1b_preset_matches_hf_shape():

@@ -8,7 +8,7 @@ fixture with an injected elastic restart must attribute the downtime to
 the ``restart`` bucket), the tail-follower shared by ``ds_metrics
 --follow`` and ``bin/ds_top``, the ``ds_prof merge`` degradation cases
 (missing ranks, a restart mid-trace, empty/truncated files), the serving
-request-span TTFT decomposition, and the bench --smoke goodput chain.
+request-span TTFT decomposition, and the perf_record goodput chain.
 """
 
 import importlib.util
@@ -786,30 +786,22 @@ class TestSchemaAndGate:
 
 
 @pytest.mark.goodput
-class TestBenchSmokeGoodput:
-    """The --smoke acceptance: every ledger entry carries a per-step
-    goodput breakdown whose buckets sum to within 5% of the measured
-    step wall time, and the hoisted goodput_fraction is gateable."""
+class TestLedgerEntryGoodput:
+    """The acceptance of ``engine.perf_record`` under the ``goodput``
+    block: the entry carries a per-step goodput breakdown whose buckets
+    sum to within 5% of the measured step wall time, and the hoisted
+    goodput_fraction is gateable."""
 
     @pytest.fixture(scope="class")
-    def smoke(self, tmp_path_factory):
+    def smoke(self, tmp_path_factory, tiny_ledger_run):
         tmp = tmp_path_factory.mktemp("goodput_smoke")
-        ledger = str(tmp / "ledger.jsonl")
-        env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_SEQ="64",
-                   BENCH_TELEMETRY_DIR=str(tmp / "telemetry"))
-        env.pop("XLA_FLAGS", None)
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-             "--ledger", ledger],
-            capture_output=True, text=True, timeout=420, env=env, cwd=tmp)
-        return proc, ledger
+        tiny_ledger_run(tmp, seq=64)
+        return str(tmp / "ledger.jsonl")
 
     def test_entry_carries_closed_goodput_breakdown(self, smoke):
         from deepspeed_tpu.perf import ledger as led
 
-        proc, ledger = smoke
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        [entry] = led.load_entries(ledger)
+        [entry] = led.load_entries(smoke)
         gp = entry["attribution"]["goodput"]
         assert gp["per_step"], "every entry must carry per-step ledgers"
         for step in gp["per_step"]:
@@ -821,15 +813,12 @@ class TestBenchSmokeGoodput:
         # train_batch samples (seconds) to the acceptance tolerance plus
         # the data-wait the window includes
         assert len(entry["samples"]) >= len(gp["per_step"])
-        # the stderr note is the human surface bench prints
-        assert "# goodput:" in proc.stderr
 
     def test_goodput_fraction_gates(self, smoke, tmp_path):
         from deepspeed_tpu.perf import ledger as led
         from deepspeed_tpu.perf.cli import main
 
-        proc, ledger = smoke
-        assert proc.returncode == 0, proc.stderr[-2000:]
+        ledger = smoke
         assert main(["gate", "--baseline", ledger,
                      "--candidate", ledger]) == 0
         [entry] = led.load_entries(ledger)

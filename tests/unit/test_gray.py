@@ -25,7 +25,7 @@ matrix the acceptance criteria name:
   ``ds_metrics``;
 * the report-only + escalation drill (``evict: false`` records verdicts
   without touching the fleet; past ``max_verdicts`` a GrayError);
-* the randomized slow-device sweep and the ``bench.py --smoke --gray``
+* the randomized slow-device sweep and the probe / flight-recorder
   pricing run (both in tests/slow_tests.txt).
 """
 
@@ -440,23 +440,27 @@ class TestEvictDrill:
         # ---- the collapse: dragged-8 steps (slow active, pre-verdict)
         # vs post-evict survivor steps, from the step_callback clock.
         # Callback steps are the agent's PRE-increment counter (callback
-        # s = host step s+1). Consecutive-pair walls only, and the pair
+        # s = host step s+1), so wall[s] is host step s+1: the drag is
+        # in wall[10] (chaos step 11) through wall[verdict-2]; the pair
         # straddling the restart (callback verdict-1 carries the whole
-        # restore + recompile) stays out of both windows.
+        # restore + recompile) stays out of both windows. The earliest
+        # verdict the ladder allows (step 12, when a loaded host has
+        # raised suspicion before the drag) still leaves wall[10].
         walls = {}
         for (s0, t0), (s1, t1) in zip(ticks, ticks[1:]):
             if s1 == s0 + 1:
                 walls.setdefault(s1, t1 - t0)
-        dragged = [walls[s] for s in range(11, verdict_step - 2)
+        dragged = [walls[s] for s in range(10, verdict_step - 1)
                    if s in walls]
         post = [walls[s] for s in range(20, 24) if s in walls]
         assert dragged and len(post) >= 3
-        dragged_mean = sum(dragged) / len(dragged)
-        post_mean = sum(post) / len(post)
-        # >= 5x step-wall collapse; equivalently the 6 survivors push
-        # more samples/sec than the dragged 8 ever did
-        assert dragged_mean >= 5.0 * post_mean, (dragged, post)
-        assert TBS / post_mean > TBS / dragged_mean
+        # what the chaos GUARANTEES, whatever the host's load: every
+        # dragged step slept the 0.1 s floor inside its gather phase.
+        # Load only ever ADDS time to a step, so the collapse compares
+        # the fastest step of each phase: >= 5x, i.e. the 6 survivors
+        # push more samples/sec than the dragged 8 ever did
+        assert min(dragged) >= 0.1, dragged
+        assert min(dragged) >= 5.0 * min(post), (dragged, post)
 
         # ---- PRICED: the goodput report carries the probe and
         # straggler_wait badput and annotates the shrink
@@ -687,29 +691,37 @@ def test_randomized_slow_sweep():
         assert dict(engine.mesh.shape)["data"] == 8, ctx
 
 
-# ------------------------------------------------------ bench --gray smoke
-def test_bench_smoke_gray(tmp_path):
-    """`bench.py --smoke --gray` runs gpt2-tiny with unconditional
-    probes every 2 steps; the ledger entry prices them as the `probe`
-    goodput bucket and the `gray_overhead` attribution, asserted under
-    the cadence-scaled 2%-of-wall contract."""
-    ledger = tmp_path / "led.jsonl"
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("BENCH_")}
-    env.pop("XLA_FLAGS", None)
-    env["BENCH_TELEMETRY_DIR"] = str(tmp_path / "tel")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-         "--gray", "--ledger", str(ledger)],
-        capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = json.loads([l for l in proc.stdout.splitlines()
-                       if l.startswith("{")][-1])
-    assert line["config"]["gray"] == 2
-    assert "gray@2" in line["metric"]
-    att = line.get("attribution") or {}
+# ------------------------------------- probe and flight-recorder pricing
+def test_probe_and_blackbox_overhead_under_budget(tmp_path, tiny_ledger_run):
+    """gpt2-tiny with unconditional probes every 2 steps (the three timed
+    steps must hold a probe) and the flight recorder armed: the ledger
+    entry PRICES both defences. The gray contract is <= 2% of wall at the
+    DEFAULT cadence (a suspicion-gated probe at most every
+    probe_interval=10 steps), so the budget scales by the cadence ratio —
+    same per-probe cost, more probes per wall. probe_confirmations sits
+    out of reach: this run prices the defence, it must never verdict on
+    CPU-sim probe noise. The always-on recorder must cost under 0.5% of
+    wall and write ZERO bundles on a clean run."""
+    every = 2
+    engine, entry = tiny_ledger_run(tmp_path, extra={
+        "gray": {"probe_every": every, "probe_confirmations": 1_000_000,
+                 "evict": False},
+        "blackbox": {}})
+    att = entry["attribution"]
     go = att.get("gray_overhead")
-    assert go is not None
-    assert 0.0 < go < 0.1          # 2% contract scaled to probe_every=2
-    assert (att["goodput"]["buckets_us"]).get("probe", 0.0) > 0.0
-    assert "# gray: probe overhead" in proc.stderr
+    assert go is not None, "gray armed but the entry carries no gray_overhead"
+    assert att["goodput"]["buckets_us"].get("probe", 0.0) > 0.0, \
+        "gray armed but no probe bucket landed in the timed window"
+    budget = 0.02 * (10.0 / every)
+    assert 0.0 < go < budget, (
+        f"gray_overhead {go:.4f} exceeds {budget:.3f} (2%-of-wall contract "
+        f"scaled from probe_interval=10 to probe_every={every})")
+    bo = att.get("blackbox_overhead")
+    assert bo is not None, \
+        "blackbox armed but the entry carries no blackbox_overhead"
+    assert bo < 0.005, (
+        f"blackbox_overhead {bo:.5f} exceeds the 0.5%-of-wall budget")
+    assert engine._blackbox is not None \
+        and engine._blackbox.bundles_written == 0, (
+        "clean run wrote incident bundle(s): a severity>=error event "
+        "fired with no fault injected")

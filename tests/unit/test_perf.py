@@ -1,6 +1,6 @@
 """Perf-ledger tests: structured entries, noise-bound diff/gate, exposed
 comm, autotuner exact-memory pruning + calibration, zero-overhead-when-off,
-and the bench --smoke end-to-end acceptance chain."""
+and the engine.perf_record end-to-end acceptance chain."""
 
 import json
 import os
@@ -72,21 +72,25 @@ class TestLedger:
         latest = led.latest_by_series(entries)
         assert latest[led.series_key(entries[0])]["value"] == 0.5
 
-    def test_load_baseline_driver_format_marks_headline(self, tmp_path):
-        tail = "\n".join([
-            json.dumps(_entry("a pretrain MFU (x)", 0.5)),
-            json.dumps(_entry("b serving decode (y)", 6000,
-                              unit="decode-tok/s/chip")),
-            json.dumps(_entry("a pretrain MFU (x)", 0.5)),
-        ])
+    def test_load_baseline_rejects_a_document_that_is_no_ledger(
+            self, tmp_path):
+        """Input from outside the program is rejected, not guessed at: the
+        pre-PR-1 ``{"cmd","rc","tail","parsed"}`` wrapper is no ledger,
+        and the error names the two formats that are."""
         p = str(tmp_path / "BENCH_r99.json")
         with open(p, "w") as f:
-            json.dump({"n": 1, "cmd": "bench", "rc": 0, "tail": tail,
-                       "parsed": _entry("a pretrain MFU (x)", 0.5)}, f)
-        entries = led.load_baseline(p)
-        heads = [e for e in entries if e.get("headline")]
-        assert heads and all(
-            led.series_key(h) == "a pretrain MFU [MFU]" for h in heads)
+            json.dump({"n": 1, "cmd": "bench", "rc": 0,
+                       "tail": json.dumps(_entry()), "parsed": _entry()}, f)
+        with pytest.raises(ValueError) as e:
+            led.load_baseline(p)
+        msg = str(e.value)
+        assert p in msg and "'tail'" in msg
+        assert "JSON lines" in msg and "one JSON document" in msg
+        from deepspeed_tpu.perf.cli import main as perf_main
+
+        with pytest.raises(SystemExit) as ex:
+            perf_main(["show", p])
+        assert ex.value.code == 1
 
     def test_load_baseline_jsonl_passthrough(self, tmp_path):
         p = str(tmp_path / "l.jsonl")
@@ -112,21 +116,14 @@ class TestLedger:
         assert [e["value"] for e in led.load_baseline(p)] == [0.5, 0.6]
 
     def test_gate_accepts_jsonl_baseline(self, tmp_path):
-        """ds_perf gate --baseline ledger.jsonl — the bench.py smoke
-        recipe verbatim."""
+        """ds_perf gate --baseline ledger.jsonl --candidate ledger.jsonl:
+        a ledger gates against itself."""
         from deepspeed_tpu.perf.cli import main as perf_main
 
         p = str(tmp_path / "ledger.jsonl")
         led.append_entry(p, _entry(samples=[0.5, 0.5, 0.5],
                                    headline=True, fingerprint="f"))
         assert perf_main(["gate", "--baseline", p, "--candidate", p]) == 0
-
-    def test_real_bench_r05_parses(self):
-        entries = led.load_baseline(os.path.join(REPO, "BENCH_r05.json"))
-        assert len(entries) >= 8
-        keys = {led.series_key(e) for e in entries}
-        assert "gpt2-760m pretrain MFU [MFU]" in keys
-        assert any(e.get("headline") for e in entries)
 
     def test_git_rev_of_this_repo(self):
         rev = led.git_rev(REPO)
@@ -902,29 +899,21 @@ class TestZeroOverheadWhenOff:
 
 
 @pytest.mark.perf
-class TestBenchSmoke:
-    """The --smoke acceptance chain: bench.py on CPU produces ledger
-    entries with span breakdown, memory buckets and fingerprints; ds_perf
-    diff/gate work on them; gate fails a synthetic regression."""
+class TestEngineLedgerEntry:
+    """The acceptance chain of ``engine.perf_record``: a gpt2-tiny run on
+    the CPU produces a ledger entry with span breakdown, memory buckets
+    and fingerprint; ds_perf gate works on it and fails a synthetic
+    regression."""
 
     @pytest.fixture(scope="class")
-    def smoke(self, tmp_path_factory):
-        tmp = tmp_path_factory.mktemp("bench_smoke")
-        ledger = str(tmp / "ledger.jsonl")
-        env = dict(os.environ, JAX_PLATFORMS="cpu", BENCH_SEQ="64",
-                   BENCH_TELEMETRY_DIR=str(tmp / "telemetry"))
-        env.pop("XLA_FLAGS", None)      # 1 CPU device is enough and faster
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py"), "--smoke",
-             "--ledger", ledger],
-            capture_output=True, text=True, timeout=420, env=env, cwd=tmp)
-        return proc, ledger
+    def smoke(self, tmp_path_factory, tiny_ledger_run):
+        tmp = tmp_path_factory.mktemp("ledger_smoke")
+        _, entry = tiny_ledger_run(tmp, seq=64)
+        return entry, str(tmp / "ledger.jsonl")
 
     def test_smoke_emits_attributed_ledger_entry(self, smoke):
-        proc, ledger = smoke
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        line = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert line["unit"] == "MFU" and line["value"] > 0
+        returned, ledger = smoke
+        assert returned["unit"] == "tok/s" and returned["value"] > 0
         [entry] = led.load_entries(ledger)
         assert entry["model"] == "gpt2-tiny"
         assert entry["fingerprint"] and entry["git_rev"]
@@ -933,15 +922,14 @@ class TestBenchSmoke:
         assert entry["samples"]
         assert "train_batch" in entry["attribution"]["spans"]
         assert entry["attribution"]["memory"]["bucket_bytes"]["params"] > 0
-        # the printed line IS the ledger entry (tail parsers see a superset)
-        assert line["fingerprint"] == entry["fingerprint"]
+        # what perf_record hands back IS the ledger entry
+        assert returned["fingerprint"] == entry["fingerprint"]
 
     def test_gate_passes_against_own_run_and_fails_synthetic_regression(
             self, smoke, tmp_path):
         from deepspeed_tpu.perf.cli import main
 
-        proc, ledger = smoke
-        assert proc.returncode == 0, proc.stderr[-2000:]
+        _, ledger = smoke
         [entry] = led.load_entries(ledger)
         # same-run baseline: must pass
         assert main(["gate", "--baseline", ledger,
@@ -955,45 +943,3 @@ class TestBenchSmoke:
         led.append_entry(base, synthetic)
         assert main(["gate", "--baseline", base,
                      "--candidate", ledger]) == 2
-
-    def test_fail_line_carries_traceback_and_lands_in_ledger(
-            self, tmp_path, monkeypatch):
-        """A ladder line that dies mid-run is diagnosable from the ledger
-        alone: traceback + error type in the structured record."""
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-
-        ledger = str(tmp_path / "ledger.jsonl")
-        monkeypatch.setattr(bench, "PERF", True)
-        monkeypatch.setattr(bench, "LEDGER", ledger)
-        # BENCH_HEADS=5 does not divide gpt2-tiny's n_embd=128: run_one
-        # raises before any engine exists, like a real config-error line
-        monkeypatch.setenv("BENCH_HEADS", "5")
-        line = None
-        try:
-            bench.run_one("gpt2-tiny", False, 1)
-        except ValueError as e:
-            line = bench._fail_line("gpt2-tiny", e)
-        assert line is not None, "BENCH_HEADS=5 must not divide n_embd=128"
-        assert line["failed"] is True and line["value"] == 0.0
-        assert "FAILED" in line["metric"] and "ValueError" in line["metric"]
-        assert line["error_type"] == "ValueError"
-        assert "Traceback" in line["traceback"]
-        assert "run_one" in line["traceback"]
-        # gateable: the fail line names the series it failed to measure
-        assert line["series"] == "gpt2-tiny pretrain MFU"
-        entries = led.load_entries(ledger)
-        assert entries and entries[-1].get("failed") is True
-
-    def test_fail_line_without_live_traceback_still_structured(
-            self, monkeypatch):
-        if REPO not in sys.path:
-            sys.path.insert(0, REPO)
-        import bench
-
-        monkeypatch.setattr(bench, "PERF", False)   # no ledger side effects
-        line = bench._fail_line("gpt2-xl", TimeoutError("deadline"), "MFU")
-        assert line["failed"] is True
-        assert line["error_type"] == "TimeoutError"
-        assert "deadline" in line["traceback"]

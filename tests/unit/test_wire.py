@@ -9,7 +9,6 @@ stability, the chaos ``collective`` drill on the quantized serial gather,
 and the perf-ledger ``wire_mode`` identity."""
 
 import json
-import os
 import sys
 
 import jax
@@ -551,35 +550,20 @@ class TestQGZEngine:
 
 
 # ---------------------------------------------------------------------------
-# bench --wire e2e (the satellite's smoke ledger line)
+# the full wire mode's ledger entry, end to end
 # ---------------------------------------------------------------------------
 @pytest.mark.wire
 @pytest.mark.perf
-def test_bench_smoke_devices_wire(tmp_path):
-    """`bench.py --smoke --devices 8 --wire full` runs gpt2-tiny as a real
-    simulated 8-dev ZeRO-3 job on the ici-factored mesh; the ledger entry
-    stamps wire_mode + the host-split static comm."""
-    import subprocess
-
-    ledger = tmp_path / "led.jsonl"
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("BENCH_")}
-    env.pop("XLA_FLAGS", None)
-    env["BENCH_TELEMETRY_DIR"] = str(tmp_path / "tel")
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, "bench.py"), "--smoke",
-         "--devices", "8", "--wire", "full", "--ledger", str(ledger)],
-        capture_output=True, text=True, timeout=600, env=env, cwd=repo)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = json.loads([l for l in proc.stdout.splitlines()
-                       if l.startswith("{")][-1])
-    assert line["config"]["n_dev"] == 8
-    assert line["config"]["wire"] == "full"
-    assert "wire=full" in line["metric"]
-    assert line["wire_mode"] == "qwz+hpz+qgz"
-    assert line["mesh_axes"] == "data=2×ici=4"
-    att = line.get("attribution") or {}
-    by_kind = (att.get("static_comm") or {}).get("by_kind") or {}
+def test_wire_full_entry_splits_intra_host_bytes(tmp_path, tiny_ledger_run):
+    """gpt2-tiny as a real ZeRO-3 job over 8 simulated devices on the
+    ici-factored mesh with qwZ + hpZ + qgZ armed: the engine's ledger
+    entry stamps wire_mode and the host-split static comm."""
+    _, entry = tiny_ledger_run(tmp_path, devices=None, extra={
+        "tpu": {"data": -1, "ici": 4},
+        "overlap": {},
+        "wire": {"weight_quant_bits": 8, "secondary_partition": True,
+                 "secondary_size": 4, "grad_quant_bits": 4}})
+    assert entry["wire_mode"] == "qwz+hpz+qgz"
+    assert entry["mesh_axes"] == "data=2×ici=4"
+    by_kind = entry["attribution"]["static_comm"]["by_kind"]
     assert any(k.endswith("/intra") for k in by_kind)

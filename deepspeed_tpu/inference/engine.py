@@ -36,10 +36,8 @@ from deepspeed_tpu.parallel.topology import build_mesh
 from deepspeed_tpu.utils.logging import log_dist
 
 
-def _sample(logits, rng, temperature: float, top_k: int, top_p: float, greedy: bool):
-    """Sampling head: greedy / temperature / top-k / nucleus."""
-    if greedy:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+def _sample(logits, rng, temperature: float, top_k: int, top_p: float):
+    """Sampling head: temperature / top-k / nucleus."""
     logits = logits / jnp.maximum(temperature, 1e-6)
     if top_k > 0:
         kth = jnp.sort(logits, axis=-1)[..., -top_k][..., None]
@@ -74,8 +72,8 @@ def build_generate_fn(module, max_new_tokens: int, do_sample: bool,
     def gen(params, ids, rng):
         if param_transform is not None:
             params = param_transform(params)
-        logits, cache = prefill(params, ids)
-        return decode(params, ids, logits, cache, rng)
+        tok, cache, done, rng = prefill(params, ids, rng)
+        return decode(params, ids, tok, cache, done, rng)
 
     return gen
 
@@ -92,25 +90,73 @@ def _resolve_cache_shardings(module, cache_shardings):
     return None
 
 
-def _decode_scan_step(module, params, do_sample: bool, temperature: float,
-                      top_k: int, top_p: float, eos: int):
-    """One token of the decode loop (sample → mask finished rows → one
-    ``module.decode_step``) as a ``lax.scan`` body. The SINGLE source of the
-    per-token logic, shared by the fused/observed generate paths and the
-    serving front-end's chunked decode (serving/frontend.py) — the three
-    consumers cannot diverge numerically."""
+def _sampling(do_sample: bool, temperature: float, top_k: int, top_p: float,
+              eos_token_id: Optional[int]) -> tuple:
+    """How a program chooses tokens, as :func:`_next_token` takes it
+    (``eos`` -1 = none: no token equals it)."""
+    return (do_sample, temperature, top_k, top_p,
+            -1 if eos_token_id is None else int(eos_token_id))
+
+
+def _next_token(logits, done, rng, do_sample: bool, temperature: float,
+                top_k: int, top_p: float, eos: int):
+    """Choose each row's next token from ``logits``: the largest, or with
+    ``do_sample`` one ``split`` of the carried key and the sampling head;
+    rows past their EOS hold the EOS token. What ends the prefill and every
+    decode step — a token is chosen by the program that computed its
+    logits. Greedy programs carry the key untouched: nothing reads it, and
+    lowering a threefry split costs each of them 0.2-0.5 s of set-up on
+    the chip's host (PERF.md, PR 30). -> (tok, done, rng)."""
+    if do_sample:
+        rng, sub = jax.random.split(rng)
+        tok = _sample(logits, sub, temperature, top_k, top_p)
+    else:
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    tok = jnp.where(done, jnp.int32(max(eos, 0)), tok)
+    return tok, done | (tok == eos), rng
+
+
+def _decode_scan_step(module, params, sampling):
+    """One token of the decode loop as a ``lax.scan`` body over the carry
+    ``(tok, cache, done, rng)``: one ``module.decode_step`` on the token the
+    carry holds, then :func:`_next_token` from the new logits, emitted.
+    Step, then sample: what passes from program to program is a TOKEN,
+    never the ``(B, vocab)`` logits, and no step's logits go unsampled. The
+    SINGLE source of the per-token logic, shared by the fused/observed
+    generate paths and the serving front-end's chunked decode
+    (serving/frontend.py) — the three consumers cannot diverge
+    numerically. ``sampling``: :func:`_sampling`'s."""
 
     def step(carry, _):
-        logits, cache, done, rng = carry
-        rng, sub = jax.random.split(rng)
-        nxt = _sample(logits, sub, temperature, top_k, top_p,
-                      greedy=not do_sample)
-        nxt = jnp.where(done, jnp.int32(max(eos, 0)), nxt)
-        done = done | (nxt == eos)
-        logits, cache = module.decode_step(params, nxt, cache)
-        return (logits, cache, done, rng), nxt
+        tok, cache, done, rng = carry
+        logits, cache = module.decode_step(params, tok, cache)
+        tok, done, rng = _next_token(logits, done, rng, *sampling)
+        return (tok, cache, done, rng), tok
 
     return step
+
+
+def _prefill_program(module, cache_len, sampling, param_transform,
+                     cache_shardings):
+    """``prefill(params, ids, rng) -> (tok, cache, done, rng)`` over a cache
+    of ``cache_len(T)`` slots: the prompt's pass and the FIRST token
+    (:func:`_next_token`, as every decode step), so whoever runs it holds a
+    token when it returns. ``sampling``: :func:`_sampling`'s."""
+
+    def prefill(params, ids, rng):
+        if param_transform is not None:
+            params = param_transform(params)
+        B, T = ids.shape
+        cache = module.init_cache(B, cache_len(T))
+        cc = _resolve_cache_shardings(module, cache_shardings)
+        if cc is not None:
+            cache = jax.lax.with_sharding_constraint(cache, cc)
+        logits, cache = module.prefill(params, ids, cache)
+        tok, done, rng = _next_token(logits, jnp.zeros((B,), jnp.bool_), rng,
+                                     *sampling)
+        return tok, cache, done, rng
+
+    return prefill
 
 
 def build_generate_parts(module, max_new_tokens: int, do_sample: bool,
@@ -122,33 +168,29 @@ def build_generate_parts(module, max_new_tokens: int, do_sample: bool,
     the two numbers that define serving latency. Used directly when
     telemetry or ``profile_model_time`` is active; ``build_generate_fn``
     composes the same two pieces into the fused single-program fast path.
-    ``param_transform`` (dequant / offload stream-in) runs inside each
-    program, so numerics match the fused path exactly."""
-    eos = -1 if eos_token_id is None else int(eos_token_id)
+    ``prefill(params, ids, rng) -> (tok, cache, done, rng)`` hands over the
+    first token; ``decode(params, ids, tok, cache, done, rng)`` scans the
+    ``max_new_tokens - 1`` steps the other tokens need and returns the ids
+    with all of them appended. ``param_transform`` (dequant / offload
+    stream-in) runs inside each program, so numerics match the fused path
+    exactly."""
+    if max_new_tokens < 1:
+        raise ValueError(f"max_new_tokens {max_new_tokens}: the prefill "
+                         "already chooses the first token")
+    sampling = _sampling(do_sample, temperature, top_k, top_p, eos_token_id)
 
-    def prefill(params, ids):
+    def decode(params, ids, tok, cache, done, rng):
         if param_transform is not None:
             params = param_transform(params)
-        B, T = ids.shape
-        cache = module.init_cache(B, T + max_new_tokens)
-        cc = _resolve_cache_shardings(module, cache_shardings)
-        if cc is not None:
-            cache = jax.lax.with_sharding_constraint(cache, cc)
-        logits, cache = module.prefill(params, ids, cache)
-        return logits, cache
+        _, toks = jax.lax.scan(
+            _decode_scan_step(module, params, sampling),
+            (tok, cache, done, rng), None, length=max_new_tokens - 1)
+        return jnp.concatenate(
+            [ids, tok[:, None].astype(ids.dtype), toks.T.astype(ids.dtype)],
+            axis=1)
 
-    def decode(params, ids, logits, cache, rng):
-        if param_transform is not None:
-            params = param_transform(params)
-        B = ids.shape[0]
-        step = _decode_scan_step(module, params, do_sample, temperature,
-                                 top_k, top_p, eos)
-        done0 = jnp.zeros((B,), jnp.bool_)
-        _, toks = jax.lax.scan(step, (logits, cache, done0, rng),
-                               None, length=max_new_tokens)
-        return jnp.concatenate([ids, toks.T.astype(ids.dtype)], axis=1)
-
-    return prefill, decode
+    return _prefill_program(module, lambda T: T + max_new_tokens, sampling,
+                            param_transform, cache_shardings), decode
 
 
 def build_serving_programs(module, max_total_len: int, chunk_tokens: int,
@@ -156,39 +198,32 @@ def build_serving_programs(module, max_total_len: int, chunk_tokens: int,
                            top_p: float, eos_token_id: Optional[int],
                            param_transform=None, cache_shardings=None):
     """``(prefill, decode_chunk)`` for the serving front-end's tick loop
-    (serving/frontend.py): the cache is sized once at ``max_total_len`` and
-    decode advances ``chunk_tokens`` per call, returning the full carry so
-    the HOST can check deadlines / cancellation / drain between chunks —
-    the price of interruptibility is one dispatch gap per chunk instead of
-    one per request. Per-token logic is :func:`_decode_scan_step`, the same
-    scan body ``generate()`` compiles, so a request served through the
-    front-end emits exactly the tokens ``generate()`` would."""
-    eos = -1 if eos_token_id is None else int(eos_token_id)
+    (serving/frontend.py). ``prefill(params, ids, rng) -> (tok, cache, done,
+    rng)`` sizes the cache once at ``max_total_len`` and returns the FIRST
+    token, so the front-end delivers it when the prefill tick returns;
+    ``decode_chunk(params, tok, cache, done, rng) -> (tok, cache, done, rng,
+    toks)`` advances ``chunk_tokens`` steps from the last token and returns
+    the full carry, with ``toks`` (B, chunk) the chunk's NEW tokens, so the
+    HOST can check deadlines / cancellation / drain between chunks — the
+    price of interruptibility is one dispatch gap per chunk instead of one
+    per request. Both end in :func:`_next_token`, in ``generate()``'s order
+    of key splits (one for the first token, then one a step), so a request
+    served through the front-end emits exactly the tokens ``generate()``
+    would."""
+    sampling = _sampling(do_sample, temperature, top_k, top_p, eos_token_id)
 
-    def prefill(params, ids):
+    def decode_chunk(params, tok, cache, done, rng):
         if param_transform is not None:
             params = param_transform(params)
-        B, _ = ids.shape
-        cache = module.init_cache(B, max_total_len)
-        cc = _resolve_cache_shardings(module, cache_shardings)
-        if cc is not None:
-            cache = jax.lax.with_sharding_constraint(cache, cc)
-        logits, cache = module.prefill(params, ids, cache)
-        done = jnp.zeros((B,), jnp.bool_)
-        return logits, cache, done
-
-    def decode_chunk(params, logits, cache, done, rng):
-        if param_transform is not None:
-            params = param_transform(params)
-        step = _decode_scan_step(module, params, do_sample, temperature,
-                                 top_k, top_p, eos)
-        (logits, cache, done, rng), toks = jax.lax.scan(
-            step, (logits, cache, done, rng), None, length=chunk_tokens)
+        (tok, cache, done, rng), toks = jax.lax.scan(
+            _decode_scan_step(module, params, sampling),
+            (tok, cache, done, rng), None, length=chunk_tokens)
         # (B, chunk) int32 — rows past their EOS hold the EOS token, same
         # post-EOS convention as generate()
-        return logits, cache, done, rng, toks.T
+        return tok, cache, done, rng, toks.T
 
-    return prefill, decode_chunk
+    return _prefill_program(module, lambda T: max_total_len, sampling,
+                            param_transform, cache_shardings), decode_chunk
 
 
 def _served_as_given(params, shardings, dtype) -> bool:
@@ -467,22 +502,20 @@ class InferenceEngine:
                 top_p, eos_token_id, param_transform=self._dequant,
                 cache_shardings=cache_sh)
             params_in = self._params_in_shardings()
+            cache_io = cache_sh if cache_sh is not None else INHERIT
             repl = self.sharding.replicated()
             self._compiled[key] = (
                 sharded_jit(pf, label=f"inference/prefill[new={max_new_tokens}]",
                             donate_argnums=(), mesh=self.mesh,
-                            in_shardings=(params_in, ids_sh),
-                            out_shardings=(INHERIT,
-                                           cache_sh if cache_sh is not None
-                                           else INHERIT),
+                            in_shardings=(params_in, ids_sh, repl),
+                            out_shardings=(INHERIT, cache_io, INHERIT, repl),
                             meta={"params_argnum": 0}),
                 sharded_jit(df, label=f"inference/decode[new={max_new_tokens}]",
                             # the cache is dead after the decode consumes it —
                             # donating it avoids a second live KV buffer
                             donate_argnums=(3,), mesh=self.mesh,
                             in_shardings=(params_in, ids_sh, INHERIT,
-                                          cache_sh if cache_sh is not None
-                                          else INHERIT, repl),
+                                          cache_io, INHERIT, repl),
                             out_shardings=ids_sh,
                             meta={"params_argnum": 0, "cache_argnum": 3}))
         pf, df = self._compiled[key]
@@ -491,12 +524,14 @@ class InferenceEngine:
         t0 = time.perf_counter()
         with self.mesh:
             with tracer.span("prefill", cat="inference", tokens=int(ids.shape[1])):
-                logits, cache = pf(self.params, ids)
-                jax.block_until_ready(logits)
+                tok, cache, done, rng = pf(self.params, ids, rng)
+                jax.block_until_ready(tok)
             ttft = time.perf_counter() - t0
             t1 = time.perf_counter()
-            with tracer.span("decode", cat="inference", tokens=int(max_new_tokens)):
-                out = df(self.params, ids, logits, cache, rng)
+            # the first token came with the prefill: the scan makes the rest
+            with tracer.span("decode", cat="inference",
+                             tokens=int(max_new_tokens) - 1):
+                out = df(self.params, ids, tok, cache, done, rng)
                 jax.block_until_ready(out)
             decode_s = time.perf_counter() - t1
         total = time.perf_counter() - t0
@@ -507,7 +542,7 @@ class InferenceEngine:
             reg.counter("inference/generated_tokens").inc(B * int(max_new_tokens))
             reg.histogram("inference/ttft_seconds").observe(ttft)
             reg.histogram("inference/decode_per_token_seconds").observe(
-                decode_s / max(1, int(max_new_tokens)))
+                decode_s / max(1, int(max_new_tokens) - 1))
             reg.histogram("inference/request_seconds").observe(total)
         if self._model_profile_enabled:
             self._model_times.append(total)

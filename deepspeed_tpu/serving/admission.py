@@ -72,7 +72,8 @@ class Request:
     submitted_at: float = 0.0
     started_at: Optional[float] = None
     prefill_done_at: Optional[float] = None   # the prefill tick returned
-    first_tokens_at: Optional[float] = None   # first chunk's tokens on the host
+    first_tokens_at: Optional[float] = None   # the first token is on the host
+    decode_ticks: int = 0             # decode chunks that ran to their end
     finished_at: Optional[float] = None
     ttft_s: Optional[float] = None    # first_tokens_at - submitted_at
     _done: threading.Event = dataclasses.field(default_factory=threading.Event,

@@ -3,11 +3,13 @@
 One worker thread pulls admitted requests off a bounded queue and drives
 each through ``prefill`` + chunked ``decode`` ticks (the programs come
 from :func:`~deepspeed_tpu.inference.engine.build_serving_programs`, the
-same scan body ``generate()`` compiles). Every tick runs under the
-watchdog's ``run_with_deadline``, so a hung device step — or an injected
-chaos ``decode_step`` hang — surfaces as a clean per-request timeout
-instead of a wedged server, and the host checks the request deadline,
-the drain flag, and the elastic agent's preemption flag between ticks.
+same scan body ``generate()`` compiles), delivering what a tick chose when
+it returns: the prefill tick the first token, a decode tick its chunk.
+Every tick runs under the watchdog's ``run_with_deadline``, so a hung
+device step — or an injected chaos ``decode_step`` hang — surfaces as a
+clean per-request timeout instead of a wedged server, and the host checks
+the request deadline, the drain flag, and the elastic agent's preemption
+flag between ticks.
 
 The invariant everything here serves: **an admitted request reaches
 exactly one terminal status** (completed / partial / shed / failed), and
@@ -192,7 +194,12 @@ class ServingFrontEnd:
         """Admit a request or raise :class:`ShedError`. Admission is where
         load shedding happens EARLY — a request whose estimated TTFT
         already blows its deadline is refused now, not decoded into a
-        guaranteed timeout later."""
+        guaranteed timeout later.
+
+        ``stream`` is called with each new list of tokens as a tick
+        delivers it: the first call carries the first token alone (the
+        prefill tick chose it), later calls up to ``decode_tick_tokens``,
+        the last one cut to what is owed (or the EOS padding)."""
         ids = np.asarray(prompt, dtype=np.int32)
         if ids.ndim == 1:
             ids = ids[None, :]
@@ -439,14 +446,15 @@ class ServingFrontEnd:
             params_in = eng._params_in_shardings()
             cache_io = cache_sh if cache_sh is not None else INHERIT
             # serving batches are ragged (whatever requests are in flight),
-            # so ids/logits/done explicitly INHERIT; the KV cache — the one
+            # so ids/tok/done/rng explicitly INHERIT; the KV cache — the one
             # big buffer that cycles program-to-program across ticks — is
             # pinned to the registry's placement both ways
             self._programs[key] = (
                 sharded_jit(pf, label="serving/prefill", donate_argnums=(),
                             mesh=eng.mesh,
-                            in_shardings=(params_in, INHERIT),
-                            out_shardings=(INHERIT, cache_io, INHERIT)),
+                            in_shardings=(params_in, INHERIT, INHERIT),
+                            out_shardings=(INHERIT, cache_io, INHERIT,
+                                           INHERIT)),
                 sharded_jit(dc, label="serving/decode_chunk",
                             # NO donation: a tick that dies on its deadline
                             # leaves the request's last-good cache intact for
@@ -482,9 +490,10 @@ class ServingFrontEnd:
         # and a device profile show WHICH request a tick served
         with tracer.span(
                 phase, cat="serving", request=req.id,
-                context=int(req.prompt.shape[1]) + len(req.tokens),
-                index=len(req.tokens) // int(self.cfg.decode_tick_tokens)
-                ) as tick:
+                # positions in the cache when the tick starts: the prompt
+                # and every token but the last, which this tick steps on
+                context=int(req.prompt.shape[1]) + max(len(req.tokens) - 1, 0),
+                index=req.decode_ticks) as tick:
             now = tick.t0
             remaining = req.deadline_at - now
             if self._draining and self._drain_deadline is not None:
@@ -566,6 +575,7 @@ class ServingFrontEnd:
                 span.args.update(
                     prompt_len=int(req.prompt.shape[1]),
                     new_tokens=len(req.tokens), status=req.status,
+                    decode_ticks=req.decode_ticks,
                     prefill_done_at=req.prefill_done_at,
                     first_tokens_at=req.first_tokens_at)
 
@@ -580,53 +590,28 @@ class ServingFrontEnd:
         # decode chain, from the two stamps the request already carries
         tracer.record("admission_wait", req.submitted_at, req.started_at,
                       cat="serving", request=req.id)
-        eos = 0 if req.eos_token_id is None else max(int(req.eos_token_id), 0)
         pkey = self._program_key(req)
         try:
             prefill, decode_chunk = self._get_programs(req)
             ids = np.asarray(req.prompt, dtype=np.int32)
-            logits, cache, done = self._tick(
-                req, lambda: prefill(self.engine.params, ids),
-                warm_key=("prefill", pkey, ids.shape[1]))
-            # committed to the mesh, like the carry the chunk hands back:
-            # an uncommitted key types differently from the program's own
+            # committed to the mesh, like the carry the programs hand back:
+            # an uncommitted key types differently from a program's own
             # output and would compile the decode chunk a second time
             rng = jax.device_put(jax.random.PRNGKey(req.seed),
                                  self.engine.sharding.replicated())
-            chunk_i = 0
-            while len(req.tokens) < req.max_new_tokens:
+            tok, cache, done, rng = self._tick(
+                req, lambda: prefill(self.engine.params, ids, rng),
+                warm_key=("prefill", pkey, ids.shape[1]))
+            # prefill chose the first token: it leaves now, alone
+            finished = self._deliver(req, tok, done, tracer)
+            while not finished and len(req.tokens) < req.max_new_tokens:
                 self._poll_preempt()
-                out = self._tick(
-                    req, lambda: decode_chunk(self.engine.params, logits,
-                                              cache, done, rng),
-                    warm_key=("decode", pkey, min(chunk_i, 1)))
-                chunk_i += 1
-                logits, cache, done, rng, toks = out
-                # the chunk's tokens come to the host and go to the client
-                with tracer.span("deliver", cat="serving", request=req.id):
-                    fresh = np.asarray(toks)[0].tolist()
-                    take = min(len(fresh),
-                               req.max_new_tokens - len(req.tokens))
-                    fresh = fresh[:take]
-                    req.tokens.extend(fresh)
-                    self._count("tokens_streamed", n=len(fresh))
-                    if req.first_tokens_at is None:
-                        req.first_tokens_at = time.monotonic()
-                        req.ttft_s = req.first_tokens_at - req.submitted_at
-                        reg.histogram("serving/ttft_seconds").observe(
-                            req.ttft_s)
-                        reg.histogram("serving/ttft_deadline_fraction"
-                                      ).observe(req.ttft_s / req.deadline_s)
-                    self._flush_stream(req, fresh)
-                    finished = bool(np.asarray(done).all())
-                    if finished:
-                        # parity with generate(): post-EOS positions hold EOS
-                        pad = req.max_new_tokens - len(req.tokens)
-                        if pad > 0:
-                            req.tokens.extend([eos] * pad)
-                            self._flush_stream(req, [eos] * pad)
-                if finished:
-                    break
+                tok, cache, done, rng, toks = self._tick(
+                    req, lambda: decode_chunk(self.engine.params, tok, cache,
+                                              done, rng),
+                    warm_key=("decode", pkey, min(req.decode_ticks, 1)))
+                req.decode_ticks += 1
+                finished = self._deliver(req, toks, done, tracer)
             self._count_expert_tokens(req, cache, tracer)
             self._observe_service(req)
             self._count("completed")
@@ -663,6 +648,34 @@ class ServingFrontEnd:
                          f"{type(e).__name__}: {e}", exc_info=True)
             self._resolve(req, "partial" if req.tokens else "failed",
                           f"error: {type(e).__name__}: {e}")
+
+    def _deliver(self, req: Request, toks, done, tracer) -> bool:
+        """A tick's new tokens come to the host and go to the client, cut
+        to what the request is still owed: the one token of the prefill
+        tick, up to ``decode_tick_tokens`` of a decode tick. -> whether
+        every row has passed its EOS (the rest is then padded with it and
+        no further tick runs)."""
+        with tracer.span("deliver", cat="serving", request=req.id):
+            fresh = np.asarray(toks).reshape(-1).tolist()
+            fresh = fresh[:req.max_new_tokens - len(req.tokens)]
+            req.tokens.extend(fresh)
+            self._count("tokens_streamed", n=len(fresh))
+            if req.first_tokens_at is None:
+                req.first_tokens_at = time.monotonic()
+                req.ttft_s = req.first_tokens_at - req.submitted_at
+                reg = self._reg()
+                reg.histogram("serving/ttft_seconds").observe(req.ttft_s)
+                reg.histogram("serving/ttft_deadline_fraction").observe(
+                    req.ttft_s / req.deadline_s)
+            self._flush_stream(req, fresh)
+            finished = bool(np.asarray(done).all())
+            if finished:
+                # parity with generate(): post-EOS positions hold EOS
+                eos = max(int(req.eos_token_id or 0), 0)
+                pad = [eos] * (req.max_new_tokens - len(req.tokens))
+                req.tokens.extend(pad)
+                self._flush_stream(req, pad)
+        return finished
 
     def _count_expert_tokens(self, req: Request, cache, tracer) -> None:
         """A routed (MoE) model's programs sum, in the cache they hand from

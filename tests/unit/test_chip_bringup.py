@@ -97,6 +97,30 @@ def test_decode_kernel_compiles_for_v5e_uninterpreted(v5e, shape):
                           text)) == 1
 
 
+def _chunk_carry(cache, mesh, batch=1):
+    """What a decode chunk takes after the weights: the last token, the
+    cache, ``done`` and the key."""
+    return (_abstract((batch,), jnp.int32, mesh), cache,
+            _abstract((batch,), jnp.bool_, mesh),
+            _abstract((2,), jnp.uint32, mesh))
+
+
+def _token_in_token_out(prefill, chunk, params, cache, mesh, prompt, vocab):
+    """The two serving programs hand each other a TOKEN: prefill's outputs
+    are the chunk's carry, the chunk's own outputs are its carry again (plus
+    the chunk's tokens), and no ``(B, vocab)`` float32 leaf is among them."""
+    key = _abstract((2,), jnp.uint32, mesh)
+    first = jax.eval_shape(prefill, params,
+                           _abstract((1, prompt), jnp.int32, mesh), key)
+    carry = _chunk_carry(cache, mesh)
+    again = jax.eval_shape(chunk, params, *carry)
+    shape = lambda tree: jax.tree.map(lambda x: (x.shape, x.dtype), tree)
+    assert shape(first) == shape(carry) == shape(again[:4])
+    assert shape(again[4]) == ((1, 16), jnp.int32)
+    for leaf in jax.tree.leaves((first, again)):
+        assert not (leaf.dtype == jnp.float32 and leaf.shape[-1:] == (vocab,))
+
+
 def test_decode_chunk_for_v5e_reads_cache_and_weights_in_place(v5e):
     """The serving decode chunk at gpt2-xl widths (2 layers), compiled as
     the chip compiles it — entry layouts the TPU's own (``fc2_w`` K-minor,
@@ -110,14 +134,20 @@ def test_decode_chunk_for_v5e_reads_cache_and_weights_in_place(v5e):
     shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     params = jax.tree.map(
         lambda s: _abstract(s.shape, jnp.bfloat16, mesh), shapes)
-    _, chunk = build_serving_programs(model, 1024, 16, False, 1.0, 0, 1.0, None)
+    prefill, chunk = build_serving_programs(model, 1024, 16, False, 1.0, 0,
+                                            1.0, None)
     cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
                          jax.eval_shape(lambda: model.init_cache(1, 1024)))
     with mesh:
-        text = jax.jit(chunk).lower(
-            params, _abstract((1, 50257), jnp.float32, mesh), cache,
-            _abstract((1,), jnp.bool_, mesh),
+        _token_in_token_out(prefill, chunk, params, cache, mesh, 512, 50257)
+        # prefill ends in the first token's argmax, after the flash kernel
+        first = jax.jit(prefill).lower(
+            params, _abstract((1, 512), jnp.int32, mesh),
             _abstract((2,), jnp.uint32, mesh)).compile().as_text()
+        assert re.search(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call",
+                         first)
+        text = jax.jit(chunk).lower(
+            params, *_chunk_carry(cache, mesh)).compile().as_text()
     assert re.search(r"%[\w.]*decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
     assert re.search(r"bf16\[2,6400,1600\]\{1,2,0", text)   # as stored, K-minor
     copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
@@ -156,9 +186,7 @@ def test_decode_chunk_over_tensor_4_keeps_the_kernel(v5e):
         jax.eval_shape(lambda: model.init_cache(1, 1024)), cache_sh)
     with mesh:
         text = jax.jit(chunk).lower(
-            params, _abstract((1, 50257), jnp.float32, mesh), cache,
-            _abstract((1,), jnp.bool_, mesh),
-            _abstract((2,), jnp.uint32, mesh)).compile().as_text()
+            params, *_chunk_carry(cache, mesh)).compile().as_text()
     assert re.search(r"%[\w.]*decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
     assert "bf16[2,1,1024,384]" in text         # a shard of the cache
 
@@ -371,9 +399,7 @@ def test_olmoe_decode_chunk_for_v5e_reads_the_chosen_experts_in_place(olmoe):
     mesh, model, params, cache, _, chunk = olmoe
     with mesh:
         compiled = jax.jit(chunk).lower(
-            params, _abstract((1, 50304), jnp.float32, mesh), cache,
-            _abstract((1,), jnp.bool_, mesh),
-            _abstract((2,), jnp.uint32, mesh)).compile()
+            params, *_chunk_carry(cache, mesh)).compile()
     text = compiled.as_text()
     for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin", "decode_attn"):
         assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
@@ -387,11 +413,18 @@ def test_olmoe_decode_chunk_for_v5e_reads_the_chosen_experts_in_place(olmoe):
     assert total < 15.75 * GIB, total / GIB
 
 
+def test_olmoe_programs_hand_each_other_a_token(olmoe):
+    mesh, model, params, cache, prefill, chunk = olmoe
+    with mesh:
+        _token_in_token_out(prefill, chunk, params, cache, mesh, 2048, 50304)
+
+
 def test_olmoe_prefill_for_v5e_runs_ragged_groups_on_the_stacked_leaves(olmoe):
     mesh, model, params, _, prefill, _ = olmoe
     with mesh:
         compiled = jax.jit(prefill).lower(
-            params, _abstract((1, 2048), jnp.int32, mesh)).compile()
+            params, _abstract((1, 2048), jnp.int32, mesh),
+            _abstract((2,), jnp.uint32, mesh)).compile()
     text = compiled.as_text()
     for kernel in ("moe_gmm_swiglu_full", "moe_gmm_full", "flash_fwd"):
         assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \

@@ -309,8 +309,9 @@ def test_the_front_end_reads_the_expert_counter_once_a_request(served):
                 if s.name == "moe/expert_tokens" and s.args["request"] == req.id]
         assert len(mine) == 1 and mine[0].cat == "moe"
         counts = np.asarray(mine[0].args["counts"])
-        # two 16-token ticks ran: 20 prompt + 32 decoded positions (a tick
-        # feeds every token it samples back, to have the next tick's logits)
+        # the prefill's token + ceil(19 / 16) = two 16-step ticks: 20 prompt
+        # + 32 decoded positions (a tick steps on the token it is handed,
+        # then on each it samples but the last)
         assert counts.shape == (2, 8)
         assert (counts.sum(axis=1) == 3 * (20 + 32)).all()
     finally:

@@ -271,7 +271,8 @@ class TestSpans:
         assert req.t0 == r.started_at and req.t1 >= r.finished_at
         assert req.args == {
             "request": r.id, "prompt_len": 8, "new_tokens": 12,
-            "status": "completed", "prefill_done_at": r.prefill_done_at,
+            "status": "completed", "decode_ticks": 3,
+            "prefill_done_at": r.prefill_done_at,
             "first_tokens_at": r.first_tokens_at}
         (wait,) = [s for s in spans if s.name == "admission_wait"]
         assert (wait.t0, wait.t1) == (r.submitted_at, r.started_at)
@@ -279,7 +280,8 @@ class TestSpans:
         assert prefill.t1 == r.prefill_done_at
 
     def test_spans_of_a_request_form_one_tree(self, engine):
-        r, spans = self._serve_one(engine)      # 12 tokens: 3 decode ticks
+        # 12 tokens: the prefill's one, then ceil(11 / 4) = 3 decode ticks
+        r, spans = self._serve_one(engine)
         assert len({s.id for s in spans}) == len(spans)
         by_id = {s.id: s for s in spans}
         (req,) = [s for s in spans if s.name == "request"]
@@ -294,8 +296,11 @@ class TestSpans:
                 assert by_id[s.parent].name in want, (s.name, s.parent)
         decodes = [s for s in spans if s.name == "decode"]
         assert [s.args["index"] for s in decodes] == [0, 1, 2]
+        # positions in the cache when the tick starts: the token a tick
+        # steps on is in ``req.tokens`` already, and not yet in the cache
         assert [s.args["context"] for s in decodes] == [8, 12, 16]
-        assert len([s for s in spans if s.name == "deliver"]) == 3
+        # one deliver after the prefill tick and one after every decode tick
+        assert len([s for s in spans if s.name == "deliver"]) == 4
         # the status write follows the request and is not part of its tree
         (status,) = [s for s in spans if s.name == "status_write"]
         assert status.parent is None and status.trace is None
@@ -313,21 +318,25 @@ class TestSpans:
             assert kids[0].t0 == tick.t0 and kids[2].t1 == tick.t1
             assert kids[0].t1 == kids[1].t0 and kids[1].t1 == kids[2].t0
             assert abs(sum(k.dur for k in kids) - tick.dur) < 50e-6
-        # a decode tick and its deliver follow each other at once
-        decodes = [s for s in spans if s.name == "decode"]
+        # a tick (the prefill too) and its deliver follow each other at once
         delivers = [s for s in spans if s.name == "deliver"]
-        for tick, deliver in zip(decodes, delivers):
+        assert len(delivers) == len(ticks)
+        for tick, deliver in zip(ticks, delivers):
             assert 0 <= deliver.t0 - tick.t1 < 1e-3
 
     def test_a_tick_that_dies_records_no_children(self, engine):
         from deepspeed_tpu.resilience.chaos import (ChaosInjector,
                                                     install_chaos)
 
+        # the hook's call #1 is the prefill tick, #2 the first decode tick:
+        # the prefill's token was delivered, so the request ends partial
         install_chaos(ChaosInjector(fail_at={"decode_step": [2]}))
-        r, spans = self._serve_one(engine, new_tokens=8, status="failed")
+        r, spans = self._serve_one(engine, new_tokens=8, status="partial")
+        assert len(r.tokens) == 1
         (req,) = [s for s in spans if s.name == "request"]
-        assert req.args["status"] == "failed"
-        assert req.args["first_tokens_at"] is None
+        assert req.args["status"] == "partial"
+        assert req.args["decode_ticks"] == 0
+        assert req.args["first_tokens_at"] == r.first_tokens_at
         (dead,) = [s for s in spans if s.name == "decode"]
         assert not [s for s in spans if s.parent == dead.id]
 

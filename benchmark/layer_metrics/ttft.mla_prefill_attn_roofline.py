@@ -1,0 +1,2 @@
+"""``ttft.mla_prefill_attn_roofline``: read by ``benchmark/mla_metrics.py``."""
+from benchmark.mla_metrics import prefill_attn_roofline as read  # noqa: F401

@@ -174,7 +174,9 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                            causal: bool = True, key_padding_mask=None,
                            flash_block=None, window=None):
     """Self-attention on local (unsharded-sequence) q, k, v with equal head
-    counts (B, T, H, Dh): the Pallas flash kernel on TPU, XLA einsum for
+    counts (B, T, H, Dh) — v's head size may differ from q's and k's (latent
+    attention: q.k at 192 columns, v at 128): the Pallas flash kernel on
+    TPU, XLA einsum for
     what the kernel does not carry (below) and off-TPU (the CPU tests).
     The path is chosen by what the call needs, never by a failure: a kernel
     that does not trace, lower or compile is an error. Causal by default;
@@ -248,19 +250,23 @@ def kv_cache_width(n_kv: int, head_dim: int) -> int:
 
 
 def init_kv_cache(n_layer: int, batch_size: int, max_len: int, n_kv: int,
-                  head_dim: int, dtype):
+                  head_dim: int, dtype, rows=("k", "v")):
+    """``rows``: the row arrays the cache holds. A latent-attention model
+    holds ONE, a position's ``[c_kv | k_rope]`` row for all heads (``n_kv``
+    1, ``head_dim`` its width: 576 values in 640 lanes where K/V of 128
+    heads would be 40,960): the same layout, writes and in-place reads."""
     shape = (n_layer, batch_size, max_len, kv_cache_width(n_kv, head_dim))
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+    return {**{name: jnp.zeros(shape, dtype) for name in rows},
             "pos": jnp.zeros((), jnp.int32)}
 
 
-def kv_cache_partition_specs(n_kv: int, head_dim: int):
+def kv_cache_partition_specs(n_kv: int, head_dim: int, rows=("k", "v")):
     """Heads over 'tensor' where the rows carry no pad columns (a padded row
     cut into equal shards would cut through heads); replicated otherwise."""
     from deepspeed_tpu.parallel.topology import TENSOR_AXIS
 
     heads = TENSOR_AXIS if (n_kv * head_dim) % KV_LANES == 0 else None
-    return {"k": P(None, None, None, heads), "v": P(None, None, None, heads),
+    return {**{name: P(None, None, None, heads) for name in rows},
             "pos": P()}
 
 
@@ -274,11 +280,12 @@ def kv_cache_rows(t, max_len: int):
 
 
 def kv_cache_write(cache, t, layer, pos):
-    """The new token's k or v (B, 1, KV, Dh) into slot ``pos`` of ``layer``
-    of the stacked cache, in place when the cache is a loop carry."""
-    B, _, KV, Dh = t.shape
+    """k or v (B, T, KV, Dh) — the new token's, or a prompt's — into slots
+    ``pos .. pos + T - 1`` of ``layer`` of the stacked cache, in place when
+    the cache is a loop carry."""
+    B, T, KV, Dh = t.shape
     return jax.lax.dynamic_update_slice(
-        cache, t.reshape(1, B, 1, KV * Dh).astype(cache.dtype),
+        cache, t.reshape(1, B, T, KV * Dh).astype(cache.dtype),
         (layer, 0, pos, 0))
 
 
@@ -358,6 +365,40 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     s = jnp.where(valid, s, NEG_INF_ATTN)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrk,bkgd->bgrd", p, v_l).reshape(B, H, Dh)
+
+
+def latent_decode_attention(q, cache, layer, pos, v_width: int, scale: float):
+    """Single-token decode attention of latent attention (MLA), absorbed:
+    the scores AND the weighted sum are taken over the cached latent rows
+    themselves. q: (B, H, C) — every head's query already multiplied into
+    the row's columns (``[q_nope W_UK^T | q_rope]``); ``cache`` (L, B, S, W)
+    the stacked latent cache (``init_kv_cache`` with one row array),
+    ``layer`` of it valid through slot ``pos``; the value of a position is
+    the first ``v_width`` columns of the SAME row (``c_kv``). -> (B, H,
+    v_width). The path is chosen as ``cached_decode_attention`` chooses:
+    the Pallas kernel (``latent_decode_attn``: the row read from HBM once
+    for all heads, slots ``0..pos`` only) where the program is for a TPU,
+    the einsum over the whole allocation otherwise, and as the reference
+    the kernel is tested against."""
+    B, H, C = q.shape
+    mesh, on_tpu = _kernel_target()
+    if on_tpu:
+        from deepspeed_tpu.ops.pallas.decode_attention import \
+            latent_decode_attention as kernel
+
+        batch, _ = _attn_axes(mesh, B, 1)
+        return _kernel_on_mesh(
+            functools.partial(kernel, v_width=v_width, scale=scale), mesh,
+            (q, cache, layer, pos),
+            (P(batch, None, None), P(None, batch, None, None), P(), P()),
+            P(batch, None, None))
+    S = cache.shape[2]
+    rows = jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+    s = jnp.einsum("bhc,bkc->bhk", q, rows[..., :C]).astype(jnp.float32) \
+        * scale
+    s = jnp.where((jnp.arange(S) <= pos)[None, None], s, NEG_INF_ATTN)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhk,bkc->bhc", p, rows[..., :v_width])
 
 
 def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,

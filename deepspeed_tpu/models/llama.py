@@ -24,7 +24,36 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
   stay one set: the serving programs keep the expert leaves OUT of the layer
   scan's sliced operands (a per-layer slice of a stacked expert leaf is an
   805 MB copy at OLMoE-1B-7B) and hand the grouped-matmul kernel the whole
-  leaf with the layer as an index.
+  leaf with the layer as an index;
+* the further variations today's large routed models make, each again
+  chosen by the leaves a block holds (ROADMAP D4: one block that takes its
+  mixer, its norms and its MLP per layer):
+  - **latent attention (MLA)**, the mixer of a block that holds ``kv_a_w``:
+    queries through a low-rank bottleneck (``q_a_w``, ``q_a_norm_g``,
+    ``q_b_w``) into heads of ``[nope | rope]`` columns; keys and values
+    through ONE latent row a position, ``[c_kv | k_rope]`` (``kv_a_w``,
+    ``kv_a_norm_g``), from which ``kv_b_k_w (L, H, nope, C)`` makes every
+    head's un-rotated key part and ``kv_b_v_w (L, H, C, v)`` its value.
+    The trunk and ``prefill`` expand the row into per-head keys and values
+    (q.k at nope + rope columns, v at its own width); ``decode_step``
+    ABSORBS the two up-projections into the query and the output, attends
+    over the cached latent rows themselves (``common
+    .latent_decode_attention``) and caches nothing per head;
+  - **sandwich norm**: a block that holds ``post_attn_norm_g`` /
+    ``post_mlp_norm_g`` normalises each branch's OUTPUT too, before the
+    residual add (four gains a layer);
+  - **a shared expert** beside the routed ones (``shared_gate_w`` ...),
+    which every token meets; a router that scores with a sigmoid and
+    scales the chosen weights (``moe/dropless.py::route_topk``);
+  - **a chip's share of the experts**: ``experts_held = (first, count)``.
+    The router keeps its ``n_experts`` outputs and its top-k; the expert
+    leaves are ``(L, count, ...)``; a pair routed to an expert outside the
+    share adds nothing here (another chip adds it);
+  - **two stacks**: ``n_dense_layers`` leading layers with a dense SwiGLU
+    of width ``dense_intermediate_size`` in ``params["dense_blocks"]``,
+    the routed layers in ``params["blocks"]``; the trunk, ``prefill`` and
+    ``decode_step`` scan one after the other, and the cache's layer axis
+    runs over both.
 
 Implements the same model protocol as GPT2Model (init_params, loss, apply,
 prefill/decode_step, partition specs), so ``initialize()``,
@@ -73,6 +102,26 @@ class LlamaConfig:
     n_experts_per_tok: int = 0
     norm_topk_prob: bool = False     # renormalise the k chosen probabilities
     router_aux_loss_coef: float = 0.0   # x the load-balancing loss, in loss()
+    # a routed model's other MLPs: ``n_dense_layers`` leading layers keep a
+    # dense SwiGLU of width ``dense_intermediate_size``; every routed layer
+    # adds ``n_shared_experts`` experts (one SwiGLU of n_shared x
+    # intermediate_size) that every token meets
+    n_dense_layers: int = 0
+    dense_intermediate_size: Optional[int] = None
+    n_shared_experts: int = 0
+    router_scoring: str = "softmax"     # | "sigmoid" (route_topk)
+    routed_scaling_factor: float = 1.0  # x the chosen weights
+    # (first, count): the experts, of the router's ``n_experts``, whose
+    # weights this chip holds; None = all of them
+    experts_held: Optional[tuple] = None
+    # latent attention (MLA) where kv_lora_rank > 0: the widths of the two
+    # bottlenecks, of a head's un-rotated and rotated q.k columns, of its v
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    sandwich_norm: bool = False         # RMSNorm each branch's output too
     dtype: Any = jnp.bfloat16
     # what init_params draws in: a server that holds bf16 weights asks for
     # them as such, so no float32 copy of a 8.6 GB expert leaf ever exists
@@ -84,6 +133,8 @@ class LlamaConfig:
     VALID_REMAT = (False, None, "none", True, "full", "dots", "attn")
 
     VALID_ROPE_TYPES = ("default", "linear", "llama3")
+
+    VALID_ROUTER_SCORING = ("softmax", "sigmoid")
 
     def __post_init__(self):
         if self.remat not in self.VALID_REMAT:
@@ -104,6 +155,33 @@ class LlamaConfig:
         if self.n_experts and not 0 < self.n_experts_per_tok <= self.n_experts:
             raise ValueError(f"n_experts_per_tok={self.n_experts_per_tok} "
                              f"of n_experts={self.n_experts}")
+        if self.router_scoring not in self.VALID_ROUTER_SCORING:
+            raise ValueError(f"router_scoring={self.router_scoring!r} not in "
+                             f"{self.VALID_ROUTER_SCORING}")
+        if not 0 <= self.n_dense_layers < max(self.n_layer, 1) or \
+                (self.n_dense_layers and not self.n_experts):
+            raise ValueError(f"n_dense_layers={self.n_dense_layers}: leading "
+                             "dense layers of a ROUTED model, fewer than "
+                             f"n_layer={self.n_layer}")
+        if self.dense_intermediate_size is None:
+            self.dense_intermediate_size = self.intermediate_size
+        if self.experts_held is not None:
+            first, count = self.experts_held = tuple(self.experts_held)
+            if not (0 <= first and 0 < count
+                    and first + count <= self.n_experts):
+                raise ValueError(f"experts_held={self.experts_held} of "
+                                 f"n_experts={self.n_experts}")
+            if count < self.n_experts and self.router_aux_loss_coef:
+                raise ValueError("the load-balancing loss needs every "
+                                 "expert's count: a share of the experts "
+                                 "takes router_aux_loss_coef=0")
+        if self.mla and not (self.q_lora_rank and self.qk_nope_head_dim
+                             and self.qk_rope_head_dim and self.v_head_dim
+                             and self.n_kv_head == self.n_head
+                             and not self.qk_norm):
+            raise ValueError("latent attention takes q_lora_rank, "
+                             "qk_nope_head_dim, qk_rope_head_dim and "
+                             "v_head_dim, n_kv_head = n_head and no qk_norm")
 
     @property
     def head_dim(self) -> int:
@@ -113,17 +191,54 @@ class LlamaConfig:
     def kv_dim(self) -> int:
         return self.n_kv_head * self.head_dim
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def rope_dim(self) -> int:
+        """Columns of a head the rotary embedding turns."""
+        return self.qk_rope_head_dim if self.mla else self.head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Values of one cached latent row: ``[c_kv | k_rope]``."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights are held here."""
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layer - self.n_dense_layers if self.n_experts else 0
+
     def num_params(self, active: bool = False) -> int:
-        """``active``: count only the experts a token is routed to."""
+        """Parameters HELD (a share of the experts counts its own);
+        ``active``: count only the experts a token is routed to."""
         c = self
-        d, i, l, v = c.n_embd, c.intermediate_size, c.n_layer, c.vocab_size
-        mlps = (c.n_experts_per_tok if active else c.n_experts) or 1
-        per_layer = d * d + 2 * d * c.kv_dim + d * d + mlps * 3 * d * i \
-            + 2 * d + d * c.n_experts
-        if c.qk_norm:
-            per_layer += d + c.kv_dim
+        d, i, v = c.n_embd, c.intermediate_size, c.vocab_size
+        if c.mla:
+            qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+            attn = d * c.q_lora_rank + c.q_lora_rank \
+                + c.q_lora_rank * c.n_head * qk \
+                + d * c.latent_dim + c.kv_lora_rank \
+                + c.kv_lora_rank * c.n_head * (c.qk_nope_head_dim
+                                               + c.v_head_dim) \
+                + c.n_head * c.v_head_dim * d
+        else:
+            attn = d * d + 2 * d * c.kv_dim + d * d
+            if c.qk_norm:
+                attn += d + c.kv_dim
+        norms = (4 if c.sandwich_norm else 2) * d
+        mlps = (c.n_experts_per_tok if active else c.n_held) or 1
+        routed = attn + norms + d * c.n_experts \
+            + (mlps + c.n_shared_experts) * 3 * d * i
+        dense = attn + norms + 3 * d * c.dense_intermediate_size
         embeds = v * d if c.tie_embeddings else 2 * v * d
-        return embeds + l * per_layer + d
+        return embeds + c.n_dense_layers * dense \
+            + (c.n_layer - c.n_dense_layers) * routed + d
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Megatron accounting (6N + 12·l·d·s), as in GPT2Config: GQA does not
@@ -185,74 +300,131 @@ class LlamaModel:
         self.config = config
 
     # ---------------------------------------------------------------- params
-    def init_params(self, rng) -> Dict[str, Any]:
+    def _init_stack(self, keys, l: int, routed: bool) -> Dict[str, Any]:
+        """``l`` layers of one kind, stacked: the mixer's leaves, the norms'
+        gains, then a dense MLP or the router, the held experts and the
+        shared expert. ``keys``: ``init_params``' eight (a leaf that came
+        later folds a number into one of them, so the older leaves draw
+        what they always drew)."""
         c = self.config
-        d, i, l = c.n_embd, c.intermediate_size, c.n_layer
-        keys = jax.random.split(rng, 8)
+        d = c.n_embd
+        fold = jax.random.fold_in
         s = 0.02
-        proj_scale = s / math.sqrt(2 * l)   # residual-scaled, as in GPT-2 init
+        proj_scale = s / math.sqrt(2 * c.n_layer)   # residual-scaled (GPT-2)
         norm = lambda key, shape, scale: \
             jax.random.normal(key, shape, c.param_dtype) * scale
         ones = lambda *shape: jnp.ones(shape, c.param_dtype)
-        blocks = {
-            "attn_norm_g": ones(l, d),
-            "q_w": norm(keys[1], (l, d, d), s),
-            "k_w": norm(keys[2], (l, d, c.kv_dim), s),
-            "v_w": norm(keys[3], (l, d, c.kv_dim), s),
-            "o_w": norm(keys[4], (l, d, d), proj_scale),
-            "mlp_norm_g": ones(l, d),
-        }
+        blocks = {"attn_norm_g": ones(l, d), "mlp_norm_g": ones(l, d)}
+        if c.mla:
+            h, n, r = c.n_head, c.qk_nope_head_dim, c.qk_rope_head_dim
+            blocks.update(
+                q_a_w=norm(keys[1], (l, d, c.q_lora_rank), s),
+                q_a_norm_g=ones(l, c.q_lora_rank),
+                q_b_w=norm(keys[2], (l, c.q_lora_rank, h * (n + r)), s),
+                kv_a_w=norm(keys[3], (l, d, c.latent_dim), s),
+                kv_a_norm_g=ones(l, c.kv_lora_rank),
+                kv_b_k_w=norm(fold(keys[3], 1), (l, h, n, c.kv_lora_rank), s),
+                kv_b_v_w=norm(fold(keys[3], 2),
+                              (l, h, c.kv_lora_rank, c.v_head_dim), s),
+                o_w=norm(keys[4], (l, h * c.v_head_dim, d), proj_scale))
+        else:
+            blocks.update(q_w=norm(keys[1], (l, d, d), s),
+                          k_w=norm(keys[2], (l, d, c.kv_dim), s),
+                          v_w=norm(keys[3], (l, d, c.kv_dim), s),
+                          o_w=norm(keys[4], (l, d, d), proj_scale))
         if c.qk_norm:
             blocks.update(q_norm_g=ones(l, d), k_norm_g=ones(l, c.kv_dim))
-        if c.n_experts:
-            # an expert leaf one layer at a time: the generator's temporaries
-            # are a layer's, not the 2.1 G elements of the whole leaf
-            e = c.n_experts
-            per_layer = lambda key, shape, scale: jax.lax.map(
-                lambda k: norm(k, shape, scale), jax.random.split(key, l))
-            blocks.update(
-                router_w=norm(jax.random.fold_in(keys[5], 1), (l, d, e), s),
-                expert_gate_w=per_layer(keys[5], (e, d, i), s),
-                expert_up_w=per_layer(keys[6], (e, d, i), s),
-                expert_down_w=per_layer(keys[7], (e, i, d), proj_scale))
-        else:
+        if c.sandwich_norm:
+            blocks.update(post_attn_norm_g=ones(l, d),
+                          post_mlp_norm_g=ones(l, d))
+        if not routed:
+            i = c.dense_intermediate_size if c.n_experts \
+                else c.intermediate_size
             blocks.update(gate_w=norm(keys[5], (l, d, i), s),
                           up_w=norm(keys[6], (l, d, i), s),
                           down_w=norm(keys[7], (l, i, d), proj_scale))
-        params = {"wte": norm(keys[0], (c.vocab_size, d), s),
-                  "blocks": blocks, "norm_g": ones(d)}
+            return blocks
+        # an expert leaf one layer at a time: the generator's temporaries
+        # are a layer's, not the 2.1 G elements of the whole leaf
+        i, e = c.intermediate_size, c.n_held
+        per_layer = lambda key, shape, scale: jax.lax.map(
+            lambda k: norm(k, shape, scale), jax.random.split(key, l))
+        blocks.update(
+            router_w=norm(fold(keys[5], 1), (l, d, c.n_experts), s),
+            expert_gate_w=per_layer(keys[5], (e, d, i), s),
+            expert_up_w=per_layer(keys[6], (e, d, i), s),
+            expert_down_w=per_layer(keys[7], (e, i, d), proj_scale))
+        if c.n_shared_experts:
+            si = c.n_shared_experts * i
+            blocks.update(shared_gate_w=norm(fold(keys[5], 2), (l, d, si), s),
+                          shared_up_w=norm(fold(keys[6], 2), (l, d, si), s),
+                          shared_down_w=norm(fold(keys[7], 2), (l, si, d),
+                                             proj_scale))
+        return blocks
+
+    def init_params(self, rng) -> Dict[str, Any]:
+        c = self.config
+        keys = jax.random.split(rng, 8)
+        norm = lambda key, shape: \
+            jax.random.normal(key, shape, c.param_dtype) * 0.02
+        params = {"wte": norm(keys[0], (c.vocab_size, c.n_embd)),
+                  "blocks": self._init_stack(
+                      keys, c.n_layer - c.n_dense_layers,
+                      routed=bool(c.n_experts)),
+                  "norm_g": jnp.ones((c.n_embd,), c.param_dtype)}
+        if c.n_dense_layers:
+            params["dense_blocks"] = self._init_stack(
+                jax.random.split(jax.random.fold_in(rng, 1), 8),
+                c.n_dense_layers, routed=False)
         if not c.tie_embeddings:
             params["lm_head"] = norm(jax.random.fold_in(keys[0], 1),
-                                     (d, c.vocab_size), s)
+                                     (c.n_embd, c.vocab_size))
         return params
+
+    def _stack_specs(self, routed: bool) -> Dict[str, Any]:
+        c = self.config
+        rep = lambda rank: P(*([None] * rank))
+        blocks = {"attn_norm_g": rep(2), "mlp_norm_g": rep(2)}
+        if c.mla:
+            # replicated: latent attention under tensor parallelism is open
+            # (one latent row a position cannot be split by head)
+            blocks.update(q_a_w=rep(3), q_a_norm_g=rep(2), q_b_w=rep(3),
+                          kv_a_w=rep(3), kv_a_norm_g=rep(2), kv_b_k_w=rep(4),
+                          kv_b_v_w=rep(4), o_w=rep(3))
+        else:
+            blocks.update(q_w=P(None, None, "tensor"),
+                          k_w=P(None, None, "tensor"),
+                          v_w=P(None, None, "tensor"),
+                          o_w=P(None, "tensor", None))
+        if c.qk_norm:
+            blocks.update(q_norm_g=rep(2), k_norm_g=rep(2))
+        if c.sandwich_norm:
+            blocks.update(post_attn_norm_g=rep(2), post_mlp_norm_g=rep(2))
+        if not routed:
+            blocks.update(gate_w=P(None, None, "tensor"),
+                          up_w=P(None, None, "tensor"),
+                          down_w=P(None, "tensor", None))
+            return blocks
+        blocks.update(router_w=rep(3), expert_gate_w=rep(4),
+                      expert_up_w=rep(4), expert_down_w=rep(4))
+        if c.n_shared_experts:
+            blocks.update(shared_gate_w=P(None, None, "tensor"),
+                          shared_up_w=P(None, None, "tensor"),
+                          shared_down_w=P(None, "tensor", None))
+        return blocks
 
     def param_partition_specs(self) -> Dict[str, Any]:
         """Megatron TP over the 'tensor' mesh axis: q/k/v/gate/up column
         parallel, o/down row parallel, vocab-sharded embedding. The routed
-        experts are replicated: the one-chip server is what runs today, and
-        experts over chips are ROADMAP R1's open half."""
+        experts are replicated (``experts_held`` says which of the router's
+        experts a chip's leaves hold; the exchange of rows between chips is
+        ROADMAP R1's open half), and so is a latent-attention mixer."""
         c = self.config
-        blocks = {
-            "attn_norm_g": P(None, None),
-            "q_w": P(None, None, "tensor"),
-            "k_w": P(None, None, "tensor"),
-            "v_w": P(None, None, "tensor"),
-            "o_w": P(None, "tensor", None),
-            "mlp_norm_g": P(None, None),
-        }
-        if c.qk_norm:
-            blocks.update(q_norm_g=P(None, None), k_norm_g=P(None, None))
-        if c.n_experts:
-            blocks.update(router_w=P(None, None, None),
-                          expert_gate_w=P(None, None, None, None),
-                          expert_up_w=P(None, None, None, None),
-                          expert_down_w=P(None, None, None, None))
-        else:
-            blocks.update(gate_w=P(None, None, "tensor"),
-                          up_w=P(None, None, "tensor"),
-                          down_w=P(None, "tensor", None))
-        specs = {"wte": P("tensor", None), "blocks": blocks,
+        specs = {"wte": P("tensor", None),
+                 "blocks": self._stack_specs(bool(c.n_experts)),
                  "norm_g": P(None)}
+        if c.n_dense_layers:
+            specs["dense_blocks"] = self._stack_specs(False)
         if not c.tie_embeddings:
             specs["lm_head"] = P(None, "tensor")
         return specs
@@ -273,19 +445,31 @@ class LlamaModel:
         rep = self.config.n_head // self.config.n_kv_head
         return t if rep == 1 else jnp.repeat(t, rep, axis=2)
 
-    def _attention(self, q, k, v):
-        """q: (B,T,H,Dh); k,v: (B,T,KV,Dh). Causal self-attention with GQA:
-        KV heads are repeated to the query head count, then the shared
-        dispatch (models/common.py: sequence-parallel → flash → einsum)."""
+    def _causal(self, q, k, v):
+        """The trunk's attention on full-head q, k, v: the shared dispatch
+        (models/common.py: sequence-parallel → flash → einsum)."""
         from deepspeed_tpu.models.common import causal_attention
 
         c = self.config
-        return causal_attention(q, self._repeat_kv(k), self._repeat_kv(v),
-                                use_flash=c.use_flash_attention,
+        return causal_attention(q, k, v, use_flash=c.use_flash_attention,
                                 sequence_parallel=c.sequence_parallel)
 
+    def _stacks(self, params):
+        """The trunk's stacks in order: (stacked blocks, index of the
+        stack's first layer in the model and in the cache)."""
+        c = self.config
+        stacks = [(params["blocks"], c.n_dense_layers)]
+        if c.n_dense_layers:
+            stacks.insert(0, (params["dense_blocks"], 0))
+        return stacks
+
+    def _rope(self, positions):
+        c = self.config
+        return _rope_cos_sin(positions, c.rope_dim, c.rope_theta,
+                             c.rope_scaling)
+
     def _block_qkv(self, x, blk, cos, sin):
-        """One block's RoPE'd q, k, v for the current x."""
+        """One GQA block's RoPE'd q, k, v for the current x."""
         c = self.config
         B, T, D = x.shape
         h = self._rms_norm(x, blk["attn_norm_g"])
@@ -301,7 +485,89 @@ class LlamaModel:
         v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, c.n_kv_head, c.head_dim)
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
+    def _block_latent(self, x, blk, cos, sin):
+        """A latent-attention block's queries and its ONE cached row a
+        position: ``q_nope`` (B, T, H, nope), ``q_rope`` (B, T, H, rope)
+        rotated, ``latent`` (B, T, 1, C + rope) = ``[RMSNorm(c_kv) |
+        RoPE(k_rope)]`` — the rotary key is one for all heads."""
+        c = self.config
+        B, T, _ = x.shape
+        n, C = c.qk_nope_head_dim, c.kv_lora_rank
+        hd = self._rms_norm(x, blk["attn_norm_g"]).astype(c.dtype)
+        cq = self._rms_norm(hd @ blk["q_a_w"].astype(hd.dtype),
+                            blk["q_a_norm_g"])
+        q = (cq @ blk["q_b_w"].astype(hd.dtype)).reshape(
+            B, T, c.n_head, n + c.qk_rope_head_dim)
+        kv = hd @ blk["kv_a_w"].astype(hd.dtype)             # (B, T, C + rope)
+        latent = jnp.concatenate(
+            [self._rms_norm(kv[..., None, :C], blk["kv_a_norm_g"]),
+             apply_rope(kv[..., None, C:], cos, sin)], axis=-1)
+        return q[..., :n], apply_rope(q[..., n:], cos, sin), latent
+
+    def _attend(self, x, blk, cos_sin, attention):
+        """A block's causal self-attention over the whole of x (the trunk,
+        prefill), by the mixer whose leaves it holds -> (attn (B, T, H,
+        Dv), the rows a cache keeps of it: GQA (k, v) at the KV heads,
+        latent attention (latent,)). ``attention`` takes full-head q, k, v."""
+        c = self.config
+        if "kv_a_w" not in blk:
+            q, k, v = self._block_qkv(x, blk, *cos_sin)
+            return attention(q, self._repeat_kv(k), self._repeat_kv(v)), (k, v)
+        # un-absorbed: every head's key and value expanded from the latent
+        # row (q.k at nope + rope columns, v at its own width); absorbing
+        # here would cost (C + rope + C) / (nope + rope + v) = 3.4 x the FLOPs
+        q_nope, q_rope, latent = self._block_latent(x, blk, *cos_sin)
+        c_kv, k_rope = latent[:, :, 0, :c.kv_lora_rank], \
+            latent[..., c.kv_lora_rank:]
+        k_nope = jnp.einsum("btc,hnc->bthn", c_kv,
+                            blk["kv_b_k_w"].astype(c_kv.dtype))
+        v = jnp.einsum("btc,hcd->bthd", c_kv,
+                       blk["kv_b_v_w"].astype(c_kv.dtype))
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        return attention(q, k, v), (latent,)
+
+    def _attend_cached(self, x, blk, cos_sin, caches, layer, pos):
+        """The new token's attention over the cache (decode): its rows are
+        written into slot ``pos`` of ``layer``, then attended with the rest.
+        -> (attn (B, 1, H, Dv), the caches)."""
+        from deepspeed_tpu.models.common import (cached_decode_attention,
+                                                 kv_cache_write,
+                                                 latent_decode_attention)
+
+        c = self.config
+        if "kv_a_w" not in blk:
+            q, k, v = self._block_qkv(x, blk, *cos_sin)     # q (B,1,H,Dh)
+            cache_k = kv_cache_write(caches[0], k, layer, pos)
+            cache_v = kv_cache_write(caches[1], v, layer, pos)
+            # GQA decode against the KV-head cache — repeated K/V are never
+            # materialized (grouped einsum or the Pallas streaming kernel)
+            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, layer,
+                                           pos, c.n_kv_head)
+            return attn[:, None], (cache_k, cache_v)
+        # absorbed: q.k_nope = (q_nope W_UK^T).c_kv and p.v = (p.c_kv) W_UV,
+        # so the scores and the weighted sum are over the latent rows
+        # themselves, read once for all heads
+        q_nope, q_rope, latent = self._block_latent(x, blk, *cos_sin)
+        cache = kv_cache_write(caches[0], latent, layer, pos)
+        q_lat = jnp.einsum("bhn,hnc->bhc", q_nope[:, 0],
+                           blk["kv_b_k_w"].astype(q_nope.dtype))
+        o_lat = latent_decode_attention(
+            jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1), cache, layer,
+            pos, v_width=c.kv_lora_rank,
+            scale=1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim))
+        attn = jnp.einsum("bhc,hcd->bhd", o_lat,
+                          blk["kv_b_v_w"].astype(o_lat.dtype))
+        return attn[:, None], (cache,)
+
     EXPERT_LEAVES = ("expert_gate_w", "expert_up_w", "expert_down_w")
+
+    @staticmethod
+    def _swiglu(h, gate_w, up_w, down_w):
+        gate = h @ gate_w.astype(h.dtype)
+        up = h @ up_w.astype(h.dtype)
+        return (jax.nn.silu(gate) * up) @ down_w.astype(h.dtype)
 
     def _mlp(self, h, blk, stacked=None, layer=None):
         """The block's MLP on the normed h (B, T, D) -> (out, router
@@ -309,28 +575,42 @@ class LlamaModel:
         routed experts where it holds ``router_w``: the expert leaves are the
         block's own (E, ...) slices, or ``stacked`` (L, E, ...) leaves with
         the traced ``layer`` (the serving programs: see the module's
-        docstring). Statistics: pairs routed to each expert (E,) int32 and
-        the router's probabilities summed over the tokens (E,) float32."""
+        docstring), E the experts held here; plus the shared expert where
+        it holds ``shared_gate_w``. Statistics: pairs routed to each held
+        expert (E,) int32 and the router's scores summed over the tokens
+        (n_experts,) float32."""
         if "router_w" not in blk:
-            gate = h @ blk["gate_w"].astype(h.dtype)
-            up = h @ blk["up_w"].astype(h.dtype)
-            return (jax.nn.silu(gate) * up) @ blk["down_w"].astype(h.dtype), None
+            return self._swiglu(h, blk["gate_w"], blk["up_w"],
+                                blk["down_w"]), None
         from deepspeed_tpu.moe.dropless import route_topk, routed_mlp
 
         c = self.config
         B, T, D = h.shape
         tokens = h.reshape(B * T, D)
+        # a softmax router is called with the four arguments it always had:
+        # tests/benchmark/test_olmoe_family.py lays a wrapper of exactly that
+        # signature over route_topk, and a benchmark file is not this
+        # change's to edit
+        scored = {} if (c.router_scoring, c.routed_scaling_factor) == \
+            ("softmax", 1.0) else {"scoring": c.router_scoring,
+                                   "scale": c.routed_scaling_factor}
         probs, weights, experts = route_topk(
-            tokens, blk["router_w"], c.n_experts_per_tok, c.norm_topk_prob)
+            tokens, blk["router_w"], c.n_experts_per_tok, c.norm_topk_prob,
+            **scored)
         leaves = stacked if stacked is not None else blk
         out, sizes = routed_mlp(
             tokens, weights, experts,
-            *(leaves[n] for n in self.EXPERT_LEAVES), layer=layer)
-        return out.reshape(B, T, D), (sizes, jnp.sum(probs, axis=0))
+            *(leaves[n] for n in self.EXPERT_LEAVES), layer=layer,
+            first=c.experts_held[0] if c.experts_held else None)
+        out = out.reshape(B, T, D)
+        if "shared_gate_w" in blk:
+            out = out + self._swiglu(h, blk["shared_gate_w"],
+                                     blk["shared_up_w"], blk["shared_down_w"])
+        return out, (sizes, jnp.sum(probs, axis=0))
 
     def _split_experts(self, blocks):
         """(the leaves a layer scan may slice, the stacked expert leaves it
-        must not — None for a dense model)."""
+        must not — None for a dense stack)."""
         if "router_w" not in blocks:
             return blocks, None
         return ({n: v for n, v in blocks.items()
@@ -339,18 +619,21 @@ class LlamaModel:
 
     def _block_finish(self, x, blk, attn, stacked=None, layer=None):
         """-> (x after the attention output and the MLP, router statistics
-        or None)."""
-        B, T, D = x.shape
-        a = attn.reshape(B, T, D) @ blk["o_w"].astype(x.dtype)
+        or None). Pre-norm; sandwich norm (each branch's output normalised
+        too) where the block holds the two post-norm gains."""
+        B, T, _ = x.shape
+        a = attn.reshape(B, T, -1) @ blk["o_w"].astype(x.dtype)
+        if "post_attn_norm_g" in blk:
+            a = self._rms_norm(a, blk["post_attn_norm_g"])
         x = x + a
         h = self._rms_norm(x, blk["mlp_norm_g"])
         out, stats = self._mlp(h, blk, stacked, layer)
+        if "post_mlp_norm_g" in blk:
+            out = self._rms_norm(out, blk["post_mlp_norm_g"])
         return x + out, stats
 
     def _block(self, x, blk, cos_sin):
-        cos, sin = cos_sin
-        q, k, v = self._block_qkv(x, blk, cos, sin)
-        attn = self._attention(q, k, v)
+        attn, _ = self._attend(x, blk, cos_sin, self._causal)
         attn = checkpoint_name(attn, "attn_out")
         return self._block_finish(x, blk, attn)
 
@@ -358,7 +641,7 @@ class LlamaModel:
         c = self.config
         B, T = input_ids.shape
         x = params["wte"].astype(c.dtype)[input_ids]
-        cos, sin = _rope_cos_sin(jnp.arange(T), c.head_dim, c.rope_theta, c.rope_scaling)
+        cos_sin = self._rope(jnp.arange(T))
 
         block_fn = self._block
         if c.remat in (True, "full"):
@@ -374,13 +657,14 @@ class LlamaModel:
                 policy=jax.checkpoint_policies.save_only_these_names("attn_out"))
 
         def scan_body(carry, blk):
-            return block_fn(carry, blk, (cos, sin))
+            return block_fn(carry, blk, cos_sin)
 
         # overridable layer scan (overlap engine's ZeRO-3 gather prefetch;
         # a plain lax.scan when nothing is installed)
         from deepspeed_tpu.models.common import layer_scan
 
-        x, stats = layer_scan(scan_body, x, params["blocks"])
+        for blocks, _ in self._stacks(params):
+            x, stats = layer_scan(scan_body, x, blocks)     # the last: routed
         x = self._rms_norm(x, params["norm_g"])
         return (x, stats) if with_router_stats else x
 
@@ -395,7 +679,7 @@ class LlamaModel:
     def loss(self, params, batch, rng=None):
         """Next-token cross entropy with the chunked vocab projection
         (models/common.py); a routed model adds ``router_aux_loss_coef`` x
-        the load-balancing loss over every layer and position."""
+        the load-balancing loss over every routed layer and position."""
         from deepspeed_tpu.models.common import chunked_lm_loss, parse_lm_batch
 
         c = self.config
@@ -414,100 +698,113 @@ class LlamaModel:
         return loss
 
     # ------------------------------------------------------------- inference
+    def _cache_layout(self):
+        """(heads folded into a row, values a head, the cache's row arrays in
+        ``_attend``'s order): K and V at the KV heads, or ONE latent row a
+        position, ``[c_kv | k_rope]``, for all heads."""
+        c = self.config
+        return (1, c.latent_dim, ("kv",)) if c.mla \
+            else (c.n_kv_head, c.head_dim, ("k", "v"))
+
     def init_cache(self, batch_size: int, max_len: int):
         """KV cache holds only the KV heads, folded into lane-dense rows:
         (L, B, max_len, W) (models/common.py ``init_kv_cache``) — the GQA
         memory win over the reference's full-head InferenceContext workspace
         (csrc/transformer/inference/includes/inference_context.h:287). A
-        routed model's cache also carries ``expert_tokens`` (L, E) int32: the
-        (token, expert) pairs each expert has been given since the prompt's
-        first token, summed by the compiled programs themselves (the
-        front-end reads it back when a request resolves)."""
+        latent-attention model caches ONE array ``kv`` of that form, nothing
+        per head. A routed model's cache also carries ``expert_tokens``
+        (L_routed, E held) int32: the (token, expert) pairs each held expert
+        has been given since the prompt's first token — summed by the
+        compiled programs themselves (the front-end reads them back when a
+        request resolves)."""
         from deepspeed_tpu.models.common import init_kv_cache
 
         c = self.config
-        cache = init_kv_cache(c.n_layer, batch_size, max_len, c.n_kv_head,
-                              c.head_dim, c.dtype)
+        n_kv, dim, rows = self._cache_layout()
+        cache = init_kv_cache(c.n_layer, batch_size, max_len, n_kv, dim,
+                              c.dtype, rows=rows)
         if c.n_experts:
-            cache["expert_tokens"] = jnp.zeros((c.n_layer, c.n_experts),
+            cache["expert_tokens"] = jnp.zeros((c.n_moe_layers, c.n_held),
                                                jnp.int32)
         return cache
 
     def cache_partition_specs(self):
         from deepspeed_tpu.models.common import kv_cache_partition_specs
 
-        specs = kv_cache_partition_specs(self.config.n_kv_head,
-                                         self.config.head_dim)
+        n_kv, dim, rows = self._cache_layout()
+        specs = kv_cache_partition_specs(n_kv, dim, rows=rows)
         if self.config.n_experts:
             specs["expert_tokens"] = P()
         return specs
 
     def prefill(self, params, input_ids, cache):
         """Process the prompt, fill the cache, return last-position logits."""
-        from deepspeed_tpu.models.common import (kv_cache_rows,
+        from deepspeed_tpu.models.common import (kv_cache_write,
                                                  local_causal_attention)
 
         c = self.config
         B, T = input_ids.shape
-        max_len = cache["k"].shape[2]
+        names = self._cache_layout()[2]
         x = params["wte"].astype(c.dtype)[input_ids]
-        cos, sin = _rope_cos_sin(jnp.arange(T), c.head_dim, c.rope_theta, c.rope_scaling)
-        blocks, experts = self._split_experts(params["blocks"])
+        cos_sin = self._rope(jnp.arange(T))
+        attention = lambda q, k, v: local_causal_attention(
+            q, k, v, c.use_flash_attention)
 
-        def body(carry, xs):
-            x = carry
-            blk, l = xs
-            q, k, v = self._block_qkv(x, blk, cos, sin)
-            attn = local_causal_attention(q, self._repeat_kv(k),
-                                          self._repeat_kv(v),
-                                          c.use_flash_attention)
-            x, stats = self._block_finish(x, blk, attn, experts, l)
-            return x, (kv_cache_rows(k, max_len), kv_cache_rows(v, max_len),
-                       None if stats is None else stats[0])
+        # as in decode_step: the stacked cache rides the scan CARRY and each
+        # layer writes its T rows into it in place, whichever stack it is of
+        caches, routed = tuple(cache[n] for n in names), None
+        for stack, first in self._stacks(params):
+            blocks, experts = self._split_experts(stack)
 
-        x, (ks, vs, routed) = jax.lax.scan(
-            body, x, (blocks, jnp.arange(c.n_layer)))
+            def body(carry, xs):
+                x, caches = carry
+                blk, l = xs
+                attn, kept = self._attend(x, blk, cos_sin, attention)
+                caches = tuple(kv_cache_write(held, t, first + l, 0)
+                               for held, t in zip(caches, kept))
+                x, stats = self._block_finish(x, blk, attn, experts, l)
+                return (x, caches), None if stats is None else stats[0]
+
+            n = next(iter(blocks.values())).shape[0]
+            (x, caches), routed = jax.lax.scan(
+                body, (x, caches), (blocks, jnp.arange(n)))
         x = self._rms_norm(x, params["norm_g"])
         logits = (x[:, -1] @ self._head(params, x.dtype)).astype(jnp.float32)
-        cache = {"k": ks, "v": vs, "pos": jnp.int32(T)}
+        out = dict(zip(names, caches), pos=jnp.int32(T))
         if routed is not None:
-            cache["expert_tokens"] = routed
-        return logits, cache
+            out["expert_tokens"] = routed
+        return logits, out
 
     def decode_step(self, params, token, cache):
         """One token for every sequence: (B,) → logits (B, V), cache advanced."""
         c = self.config
         B = token.shape[0]
         pos = cache["pos"]
+        names = self._cache_layout()[2]
         x = params["wte"].astype(c.dtype)[token][:, None]   # (B, 1, D)
-        cos, sin = _rope_cos_sin(pos[None], c.head_dim, c.rope_theta, c.rope_scaling)
-        blocks, experts = self._split_experts(params["blocks"])
-
-        from deepspeed_tpu.models.common import (cached_decode_attention,
-                                                 kv_cache_write)
+        cos_sin = self._rope(pos[None])
 
         # stacked cache rides the scan CARRY (in-place per-layer DUS); the
         # xs/ys layout made lax.scan assemble a fresh stacked cache buffer
         # every decode step — see gpt2.decode_step for the measured cost
-        def body(carry, xs):
-            x, cache_k, cache_v = carry
-            blk, l = xs
-            q, k, v = self._block_qkv(x, blk, cos, sin)     # q (B,1,H,Dh)
-            cache_k = kv_cache_write(cache_k, k, l, pos)
-            cache_v = kv_cache_write(cache_v, v, l, pos)
-            # GQA decode against the KV-head cache — repeated K/V are never
-            # materialized (grouped einsum or the Pallas streaming kernel)
-            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, l, pos,
-                                           c.n_kv_head)[:, None]
-            x, stats = self._block_finish(x, blk, attn, experts, l)
-            return (x, cache_k, cache_v), None if stats is None else stats[0]
+        caches, routed = tuple(cache[n] for n in names), None
+        for stack, first in self._stacks(params):
+            blocks, experts = self._split_experts(stack)
 
-        (x, ks, vs), routed = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (blocks, jnp.arange(c.n_layer)))
+            def body(carry, xs):
+                x, caches = carry
+                blk, l = xs
+                attn, caches = self._attend_cached(x, blk, cos_sin, caches,
+                                                   first + l, pos)
+                x, stats = self._block_finish(x, blk, attn, experts, l)
+                return (x, caches), None if stats is None else stats[0]
+
+            n = next(iter(blocks.values())).shape[0]
+            (x, caches), routed = jax.lax.scan(
+                body, (x, caches), (blocks, jnp.arange(n)))
         x = self._rms_norm(x, params["norm_g"])
         logits = (x[:, 0] @ self._head(params, x.dtype)).astype(jnp.float32)
-        out = {"k": ks, "v": vs, "pos": pos + 1}
+        out = dict(zip(names, caches), pos=pos + 1)
         if routed is not None:
             out["expert_tokens"] = cache["expert_tokens"] + routed
         return logits, out

@@ -8,11 +8,19 @@ capacity: every (token, expert) pair is computed. Here the pairs are sorted
 by expert and the experts see ragged groups — group sizes instead of
 padding — so nothing is dropped and no slot is wasted:
 
-* ``route_topk``: router matmul, softmax over ALL experts and top-k in
-  float32; weights are the chosen probabilities, renormalised only where
-  the model says so (OLMoE: ``norm_topk_prob`` false);
+* ``route_topk``: router matmul, a score for ALL experts (their softmax,
+  or each one's sigmoid — the DeepSeek-V3 convention) and top-k of the
+  scores in float32; weights are the chosen scores, renormalised only where
+  the model says so (OLMoE: ``norm_topk_prob`` false) and scaled where it
+  says so (``routed_scaling_factor``);
 * ``routed_mlp``: ``sum_j w[t, j] * down_e(silu(gate_e(x_t)) * up_e(x_t))``
-  over the k chosen experts ``e = experts[t, j]``. Where the program is for
+  over the k chosen experts ``e = experts[t, j]`` — or, told that the leaves
+  hold a chip's SHARE of the experts (``first``: the router's number of the
+  first one held), over those of the k that are held here: the router still
+  chooses among all, a pair that fell on another chip's expert takes no row
+  tile and adds exactly zero, and a call none of whose pairs is held (a
+  decode step, most of the time) runs no expert kernel at all. Nothing
+  stands in for the other chips or their traffic. Where the program is for
   one TPU device and the widths tile, the three contractions are the Pallas
   grouped matmul (``ops/pallas/grouped_matmul.py``) reading the stacked
   ``(L, E, ...)`` leaves in place; otherwise — the CPU, a multi-device mesh,
@@ -29,16 +37,22 @@ import jax
 import jax.numpy as jnp
 
 
-def route_topk(x, router_w, k: int, renormalize: bool = False):
-    """x (T, D), router_w (D, E) -> ``probs`` (T, E) float32 softmax over all
-    experts, ``weights`` (T, k) float32, ``experts`` (T, k) int32."""
+def route_topk(x, router_w, k: int, renormalize: bool = False,
+               scoring: str = "softmax", scale: float = 1.0):
+    """x (T, D), router_w (D, E) -> ``probs`` (T, E) float32 scores of all
+    experts (``scoring``: their ``softmax``, or each one's ``sigmoid``),
+    ``weights`` (T, k) float32 = the k largest scores, divided by their sum
+    with ``renormalize``, times ``scale``; ``experts`` (T, k) int32."""
     # "highest": a TPU's default float32 matmul is one bf16 pass
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     weights, experts = jax.lax.top_k(probs, k)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
     return probs, weights, experts.astype(jnp.int32)
 
 
@@ -73,15 +87,22 @@ def _layer_of(w, layer):
         w, layer, 0, keepdims=False)
 
 
-def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None):
+def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None,
+               first=None):
     """x (T, D); ``weights`` / ``experts`` (T, k) from ``route_topk``;
     ``gate_w`` / ``up_w`` (E, D, F) and ``down_w`` (E, F, D), or the stacked
-    (L, E, ...) leaves with the traced ``layer`` to take.
-    -> (out (T, D) in x's type, pairs routed to each expert (E,) int32)."""
+    (L, E, ...) leaves with the traced ``layer`` to take. ``first`` None: the
+    leaves hold every expert the router can choose. An int: they hold the E
+    experts ``first .. first + E - 1`` of the router's, and a pair whose
+    expert is not among them adds exactly zero.
+    -> (out (T, D) in x's type, pairs routed to each held expert (E,) int32)."""
     T, D = x.shape
     k = experts.shape[1]
     E = gate_w.shape[-3]
     flat = experts.reshape(-1)                       # pair p = token p // k
+    if first is not None:
+        held = (flat >= first) & (flat < first + E)
+        flat = jnp.where(held, flat - first, E)      # E: no group, sorts last
     if layer is not None and _use_kernel(x, gate_w):
         from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
 
@@ -89,8 +110,15 @@ def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None):
         sizes, tile_group, n_active, src, pos = gmm.group_layout(flat, E, tm)
         call = lambda rows, w, **kw: gmm.grouped_matmul(
             rows, w, layer, tile_group, n_active, tm=tm, **kw)
-        h = call(x[src // k], (gate_w, up_w), swiglu=True)
-        y = call(h, down_w)[pos]
+
+        def computed():
+            h = call(x[src // k], (gate_w, up_w), swiglu=True)
+            return call(h, down_w)[pos]
+
+        # a share may hold none of this call's pairs: then no kernel runs
+        # and no expert's weights leave HBM
+        y = computed() if first is None else jax.lax.cond(
+            n_active > 0, computed, lambda: jnp.zeros((T * k, D), x.dtype))
     else:
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
@@ -99,6 +127,8 @@ def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None):
         rows = x[order // k]
         h = jax.nn.silu(dot(rows, gate_w)) * dot(rows, up_w)
         y = dot(h, down_w)[jnp.argsort(order)]
+    if first is not None:       # whatever row an unheld pair was handed
+        y = jnp.where(held[:, None], y, jnp.zeros_like(y))
     out = jnp.einsum("tk,tkd->td", weights, y.reshape(T, k, D).astype(
         jnp.float32))
     return out.astype(x.dtype), sizes

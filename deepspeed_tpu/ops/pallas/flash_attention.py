@@ -30,7 +30,11 @@ Causal execution (the perf-critical path for LM training):
   O(T²·D) on the MXU), so shaving VPU passes is worth more than it looks.
 
 Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
-(B·H, T, D).
+(B·H, T, D). v may have a head size of its own (latent attention: q.k at 192
+columns, v at 128): the FORWARD kernels take the value width from v — the
+accumulator, the output and the P @ V pass are that wide, nothing is padded.
+The backward kernels take one width and refuse another for v: no model
+trains through latent attention here yet.
 
 Every ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``; ``sparse_`` before each for the block-sparse kernels).
@@ -239,16 +243,16 @@ def _use_tri(causal, t_q, t_k, bq, bk) -> bool:
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k):
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
+    t_k, dv = k.shape[1], v.shape[2]
     bq = _pick_block(t_q, block_q)
     bk = _pick_block(t_k, block_k)
     if causal and t_q == t_k and bq == bk and t_q // bq < _tri_min_blocks():
         bk = _pick_block(t_k, 2 * bq)       # short-seq rect: wider k blocks
     nq, nk = t_q // bq, t_k // bk
 
-    out_shapes = (jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
+    out_shapes = (jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
                   jax.ShapeDtypeStruct((bh, t_q, 1), jnp.float32))
-    scratch = [pltpu.VMEM((bq, d), jnp.float32),
+    scratch = [pltpu.VMEM((bq, dv), jnp.float32),
                pltpu.VMEM((bq, 128), jnp.float32),
                pltpu.VMEM((bq, 128), jnp.float32)]
 
@@ -263,10 +267,10 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
                 in_specs=[
                     pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
                     pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
+                    pl.BlockSpec((1, bk, dv), lambda b, f, qa, ka: (b, ka[f], 0)),
                 ],
                 out_specs=(
-                    pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
+                    pl.BlockSpec((1, bq, dv), lambda b, f, qa, ka: (b, qa[f], 0)),
                     pl.BlockSpec((1, bq, 1), lambda b, f, qa, ka: (b, qa[f], 0)),
                 ),
                 scratch_shapes=scratch,
@@ -275,8 +279,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             cost_estimate=pl.CostEstimate(
-                flops=int(2 * bh * t_q * t_k * d),   # causal: half the blocks run
-                bytes_accessed=int((q.size + k.size + v.size + q.size) * q.dtype.itemsize),
+                flops=int(bh * t_q * t_k * (d + dv)),   # causal: half the blocks run
+                bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
                 transcendentals=int(bh * t_q * t_k // 2)),
         )(jnp.asarray(qi_arr), jnp.asarray(ki_arr), q, k, v)
         return o, lse
@@ -290,10 +294,10 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
         ),
         out_shape=out_shapes,
@@ -301,8 +305,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * bh * t_q * t_k * d * (0.5 if causal else 1.0)),
-            bytes_accessed=int((q.size + k.size + v.size + q.size) * q.dtype.itemsize),
+            flops=int(2 * bh * t_q * t_k * (d + dv) * (0.5 if causal else 1.0)),
+            bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
             transcendentals=int(bh * t_q * t_k)),
     )(q, k, v)
     return o, lse
@@ -574,6 +578,10 @@ def _flash_bhtd_fwd(q, k, v, scale, causal, block_q, block_k):
 
 
 def _flash_bhtd_bwd(scale, causal, block_q, block_k, res, g):
+    if res[2].shape[-1] != res[0].shape[-1]:
+        raise NotImplementedError(
+            "flash_attention: the backward takes v at the q.k width "
+            f"({res[0].shape[-1]}), not {res[2].shape[-1]}")
     return _flash_backward(res, g, scale, causal, block_q, block_k)
 
 
@@ -582,7 +590,8 @@ _flash_bhtd.defvjp(_flash_bhtd_fwd, _flash_bhtd_bwd)
 
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
-    """q, k, v: (B, T, H, D) → (B, T, H, D). Differentiable; bf16-friendly.
+    """q, k: (B, T, H, D), v: (B, T, H, Dv) → (B, T, H, Dv); Dv <= D, the
+    softmax scale is D's. Differentiable; bf16-friendly.
 
     Causal self-attention at a length the kernels cannot tile is padded at
     the END of the sequence and the pad rows sliced off the output: under
@@ -608,10 +617,11 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     # (T, D) instead of a VPU pass over every (T², causal-half) score element
     # in the forward and in both backward kernels; autodiff scales dq back
     q = q * jnp.asarray(scale, q.dtype)
-    to_bhtd = lambda x, t: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    to_bhtd = lambda x, t: x.transpose(0, 2, 1, 3).reshape(
+        b * h, t, x.shape[-1])
     o = _flash_bhtd(to_bhtd(q, t_q), to_bhtd(k, t_k), to_bhtd(v, t_k),
                     1.0, bool(causal), int(block_q), int(block_k))
-    return o.reshape(b, h, t_q, d).transpose(0, 2, 1, 3)[:, :t]
+    return o.reshape(b, h, t_q, v.shape[-1]).transpose(0, 2, 1, 3)[:, :t]
 
 
 # ------------------------------------------------------------ block-sparse

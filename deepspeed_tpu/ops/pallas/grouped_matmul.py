@@ -21,7 +21,10 @@ expert it belongs to:
   be non-empty); how many of them hold rows is data (``n_active``, scalar
   prefetched): the tiles past it re-present the last active tile's block
   indices, so the pipeline issues no DMA for them, and ``pl.when`` skips
-  their compute;
+  their compute. A row may belong to NO group (group number = the number
+  of groups: a pair routed to an expert another chip holds): it sorts
+  last, takes no tile and is computed by no call; with no row in any group
+  ``n_active`` is 0 and every tile is skipped;
 * consecutive tiles of one expert present the same weight block, so each
   expert's weights cross HBM -> VMEM once per call, in blocks of up to
   ``RHS_BLOCK_BYTES``;
@@ -79,7 +82,9 @@ def supports(k: int, n: int) -> bool:
 
 def group_layout(group_of_row, n_groups: int, tm: int):
     """Where every row goes when rows are sorted by group and every group is
-    padded to whole tiles of ``tm`` rows. ``group_of_row``: (M,) int32.
+    padded to whole tiles of ``tm`` rows. ``group_of_row``: (M,) int32 in
+    ``[0, n_groups]``; ``n_groups`` itself = the row belongs to no group and
+    goes to no tile (its ``pos`` means nothing).
 
     -> ``sizes`` (G,) rows per group; ``tile_group`` (R,) the group of every
     row tile (tiles past ``n_active`` repeat the last active tile's);
@@ -98,10 +103,14 @@ def group_layout(group_of_row, n_groups: int, tm: int):
     # sorted position of every row, then its rank within its group
     sorted_pos = jnp.zeros((M,), jnp.int32).at[order].set(
         jnp.arange(M, dtype=jnp.int32))
-    pos = padded_starts[group_of_row] + sorted_pos - starts[group_of_row]
-    tile = jnp.minimum(jnp.arange(R, dtype=jnp.int32), n_active - 1)
-    tile_group = jnp.searchsorted(tile_ends, tile, side="right").astype(
-        jnp.int32)
+    of_row = jnp.minimum(group_of_row, n_groups - 1)
+    pos = jnp.minimum(padded_starts[of_row] + sorted_pos - starts[of_row],
+                      R * tm - 1)
+    tile = jnp.clip(jnp.arange(R, dtype=jnp.int32), 0,
+                    jnp.maximum(n_active - 1, 0))
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_ends, tile, side="right"),
+        n_groups - 1).astype(jnp.int32)
     row = jnp.arange(R * tm, dtype=jnp.int32)
     g = jnp.repeat(tile_group, tm)
     rank = jnp.minimum(row - padded_starts[g], sizes[g] - 1)
@@ -131,7 +140,8 @@ def grouped_matmul(x, weights, layer, tile_group, n_active, *, tm: int,
     """``x``: (R * tm, K) rows in tile-aligned groups (``group_layout``);
     ``weights``: one stacked (L, E, K, N) leaf, or with ``swiglu`` the pair
     (gate, up); ``layer``: traced int32 scalar; ``tile_group`` (R,) and
-    ``n_active`` () as ``group_layout`` gives them. -> (R * tm, N): tile r is
+    ``n_active`` () as ``group_layout`` gives them (``n_active`` may be 0:
+    nothing is computed). -> (R * tm, N): tile r is
     ``x[tile r] @ w[layer, tile_group[r]]`` (``swiglu``: ``silu(x @ gate) *
     (x @ up)``) for r < n_active, and undefined past it."""
     weights = tuple(weights) if swiglu else (weights,)
@@ -155,7 +165,7 @@ def grouped_matmul(x, weights, layer, tile_group, n_active, *, tm: int,
                       jnp.asarray(n_active, jnp.int32).reshape(())])
     # index maps: grid indices first, then the scalar-prefetch refs. A tile
     # past the active ones presents the last active tile's blocks again
-    live = lambda r, meta: jnp.minimum(r, meta[1] - 1)
+    live = lambda r, meta: jnp.maximum(jnp.minimum(r, meta[1] - 1), 0)
     xmap = lambda n, r, meta, tg: (live(r, meta), 0)
     wmap = lambda n, r, meta, tg: (meta[0], tg[r], 0, n)
     omap = lambda n, r, meta, tg: (live(r, meta), n)

@@ -572,12 +572,27 @@ class ServingFrontEnd:
                 # half-open slot back, or the breaker wedges in half_open
                 self.breaker.release_probe()
                 # whoever reads the spans keeps no handle to the Request
+                positions = self._cache_positions(req)
                 span.args.update(
                     prompt_len=int(req.prompt.shape[1]),
                     new_tokens=len(req.tokens), status=req.status,
                     decode_ticks=req.decode_ticks,
                     prefill_done_at=req.prefill_done_at,
-                    first_tokens_at=req.first_tokens_at)
+                    first_tokens_at=req.first_tokens_at,
+                    # what the request's context cost the cache: positions
+                    # its programs wrote (a sequence) and the bytes the
+                    # cache's own arrays hold for them
+                    cache_positions=positions,
+                    cache_bytes=positions * int(req.prompt.shape[0])
+                    * req.cache_position_bytes)
+
+    def _cache_positions(self, req: Request) -> int:
+        """Positions of a sequence the request's programs have run: the
+        prompt and every step of every decode chunk."""
+        if req.prefill_done_at is None:
+            return 0
+        return int(req.prompt.shape[1]) + req.decode_ticks * int(
+            self.cfg.decode_tick_tokens)
 
     def _serve(self, req: Request, tracer) -> None:
         import jax
@@ -602,6 +617,9 @@ class ServingFrontEnd:
             tok, cache, done, rng = self._tick(
                 req, lambda: prefill(self.engine.params, ids, rng),
                 warm_key=("prefill", pkey, ids.shape[1]))
+            req.cache_position_bytes = sum(
+                x.shape[0] * x.shape[3] * x.dtype.itemsize
+                for x in jax.tree.leaves(cache) if x.ndim == 4)
             # prefill chose the first token: it leaves now, alone
             finished = self._deliver(req, tok, done, tracer)
             while not finished and len(req.tokens) < req.max_new_tokens:
@@ -679,17 +697,26 @@ class ServingFrontEnd:
 
     def _count_expert_tokens(self, req: Request, cache, tracer) -> None:
         """A routed (MoE) model's programs sum, in the cache they hand from
-        tick to tick, the (token, expert) pairs every expert of every layer
-        was given (``expert_tokens`` (L, E)). One read when the request has
-        its tokens, not one a tick: into the counter ``moe/expert_tokens``
-        and, whole, into an instant of that name in the tracer."""
+        tick to tick, the (token, expert) pairs every expert HELD here was
+        given in every routed layer (``expert_tokens`` (L, E held)). One
+        read when the request has its tokens, not one a tick: into the
+        counter ``moe/expert_tokens`` and, whole, into an instant of that
+        name in the tracer, with the share the counts are of (``held_first``
+        of the router's experts, ``held`` of them) and ``routed_pairs``: all
+        the pairs the routers made of the positions run, held here or not."""
         routed = cache.get("expert_tokens") if isinstance(cache, dict) else None
         if routed is None:
             return
         counts = np.asarray(routed)
+        config = getattr(self.engine.module, "config", None)
+        held = getattr(config, "experts_held", None) or (0, counts.shape[-1])
+        pairs = counts.shape[0] * int(req.prompt.shape[0]) \
+            * self._cache_positions(req) * getattr(config, "n_experts_per_tok", 0)
         self._reg().counter("moe/expert_tokens").inc(float(counts.sum()))
         tracer.instant("moe/expert_tokens", cat="moe", trace=req.id,
-                       request=req.id, counts=counts.tolist())
+                       request=req.id, counts=counts.tolist(),
+                       held_first=int(held[0]), held=int(held[1]),
+                       routed_pairs=int(pairs or counts.sum()))
 
     def _flush_stream(self, req: Request, toks: List[int]) -> None:
         if req.stream is None or not toks:

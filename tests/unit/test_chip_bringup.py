@@ -437,6 +437,120 @@ def test_olmoe_prefill_for_v5e_runs_ragged_groups_on_the_stacked_leaves(olmoe):
     assert total < 15.75 * GIB, total / GIB
 
 
+# --------- openPangu-Ultra-MoE-718B: one chip's share, at the published widths
+def test_latent_decode_kernel_compiles_for_v5e_uninterpreted(v5e):
+    """128 query rows of 576 columns against 640-lane latent rows, the value
+    the first 512 lanes of the same block; the cell's 32,768-slot cache."""
+    mesh = _mesh(v5e)
+    q = _abstract((1, 128, 576), jnp.bfloat16, mesh)
+    cache = _abstract((5, 1, 32768, 640), jnp.bfloat16, mesh)
+    scalar = _abstract((), jnp.int32, mesh)
+    text = jax.jit(functools.partial(
+        da.latent_decode_attention, v_width=512, scale=192 ** -0.5)).lower(
+            q, cache, scalar, scalar).compile().as_text()
+    assert len(re.findall(
+        r"%[\w.]*latent_decode_attn[\w.]* = [^\n]*tpu_custom_call", text)) == 1
+
+
+def test_flash_fwd_at_192_and_128_columns_compiles_for_v5e(v5e):
+    """q.k at 192 columns (1.5 lane tiles), v and the output at 128: the
+    forward takes v's width, nothing is padded."""
+    mesh = _mesh(v5e)
+    qk = _abstract((1, 4096, 8, 192), jnp.bfloat16, mesh)
+    v = _abstract((1, 4096, 8, 128), jnp.bfloat16, mesh)
+    text = jax.jit(fa.flash_attention).lower(qk, qk, v).compile().as_text()
+    call, = re.findall(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call", text)
+    assert "(bf16[8,4096,128]" in call                  # the output: v's width
+    assert "bf16[8,4096,192]" in text and "bf16[8,4096,256]" not in text
+
+
+@pytest.fixture(scope="module")
+def pangu(v5e):
+    """(mesh, model, abstract bf16 params, abstract cache, the two serving
+    programs) of the benchmark's configuration on ONE chip: 1 dense + 4
+    routed layers, 16 of 256 experts, a 32,768-slot latent cache."""
+    from benchmark import manifest as mf
+    from benchmark.families import pangu_ultra_moe as family
+    from deepspeed_tpu.inference.engine import build_serving_programs
+
+    mesh = _mesh(v5e)
+    model = family.build_model(mf.load_json(
+        mf.BENCH_DIR / "configs" / "openpangu-ultra-moe-718b.json"), "serve")
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh), shapes)
+    cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
+                         jax.eval_shape(lambda: model.init_cache(1, 32768)))
+    return (mesh, model, params, cache) + build_serving_programs(
+        model, 32768, 16, False, 1.0, 0, 1.0, None)
+
+
+def _moves(text, shapes, ops="copy|gather|dynamic-slice|transpose"):
+    """Results of ``ops`` with one of ``shapes``."""
+    return [m for m in re.findall(
+        rf"= (bf16\[[\d,]*\])\S* (?:{ops})\(", text) if m in shapes]
+
+
+def test_pangu_decode_chunk_for_v5e_reads_the_latent_rows_once(pangu):
+    """One ``latent_decode_attn`` in the routed stack's loop and one in the
+    dense stack's, the thin grouped matmuls under a conditional (a step with
+    no held pair runs none), and nothing cache-shaped or ``W_kvb``-shaped is
+    copied, sliced out or relaid: the stacked cache and the two up-projection
+    leaves are read in place."""
+    mesh, model, params, cache, _, chunk = pangu
+    with mesh:
+        compiled = jax.jit(chunk).lower(
+            params, *_chunk_carry(cache, mesh)).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin", "latent_decode_attn"):
+        assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
+            kernel
+    assert len(re.findall(
+        r"%latent_decode_attn[\w.]* = [^\n]*tpu_custom_call", text)) == 2
+    assert "decode_attn" not in re.sub("latent_decode_attn", "", text)
+    assert " conditional(" in text
+    # the up-projections (4 or 1 layers x 128 heads x (128, 512) / (512, 128))
+    # feed XLA's own matmuls, which slice a layer out inside their fusion:
+    # no copy, no relayout; an expert's matrices feed a custom call, where a
+    # slice would be a copy too
+    up = {f"bf16[{lead}128,{a},{b}]" for lead in ("", "1,", "4,")
+          for a, b in ((128, 512), (512, 128))}
+    assert not _moves(text, up, "copy|transpose")
+    assert not _moves(text, {"bf16[7680,2048]", "bf16[2048,7680]",
+                             "bf16[16,7680,2048]", "bf16[16,2048,7680]"})
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    assert copies.count("bf16[5,1,32768,640]") <= 1, copies    # undonated
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.3 * GIB
+    assert total < 15.75 * GIB, total / GIB
+
+
+def test_pangu_programs_hand_each_other_a_token(pangu):
+    mesh, model, params, cache, prefill, chunk = pangu
+    with mesh:
+        _token_in_token_out(prefill, chunk, params, cache, mesh, 2048, 19200)
+
+
+def test_pangu_prefill_for_v5e_keeps_no_square_of_scores(pangu):
+    """Flash at 192 / 128 columns and the full-tile grouped matmuls over the
+    stacked share; no (T, T) array anywhere in the program; it fits."""
+    mesh, model, params, _, prefill, _ = pangu
+    with mesh:
+        compiled = jax.jit(prefill).lower(
+            params, _abstract((1, 3072), jnp.int32, mesh),
+            _abstract((2,), jnp.uint32, mesh)).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_swiglu_full", "moe_gmm_full", "flash_fwd"):
+        assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
+            kernel
+    assert "bf16[128,3072,192]" in text                 # flash's q, by head
+    assert not re.search(r"\[[\d,]*3072,3072\]", text)
+    assert not _moves(text, {"bf16[7680,2048]", "bf16[2048,7680]",
+                             "bf16[16,7680,2048]", "bf16[16,2048,7680]"})
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.1 * GIB
+    assert total < 15.75 * GIB, total / GIB
+
+
 def test_routed_experts_on_a_mesh_of_several_chips_take_the_xla_form(v5e):
     """GSPMD cannot partition a Mosaic call: over tensor=4 the experts run
     as ``ragged_dot`` (no kernel, and it compiles); experts over chips are

@@ -63,7 +63,8 @@ class AutotuningConfig:
     # real TPU-VM PCIe) — a MEASURED axis, not a baked default. Only expands
     # candidates that offload.
     offload_overlap_list: Optional[List[bool]] = None
-    flash_block_list: Optional[List[Optional[int]]] = None  # kernel tile edges
+    # flash kernel tile edges: multiples of 128 (the model configs refuse another)
+    flash_block_list: Optional[List[Optional[int]]] = None
     # first-order HBM model: candidates predicted over this fraction of HBM
     # are pruned BEFORE compiling; 0 disables. Default 1.5 (= only prune
     # candidates 50% past HBM) because the model omits real contributors

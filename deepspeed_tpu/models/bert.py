@@ -28,7 +28,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 IGNORE_INDEX = -100
@@ -61,15 +60,17 @@ class BertConfig:
     activation: str = "gelu"                  # BERT uses exact-erf gelu
     dtype: Any = jnp.bfloat16
     # False/'none' | True/'full' | 'dots' | 'attn' — same policy ladder as the
-    # decoders; 'attn' saves only the flash-attention outputs so the backward
-    # never re-runs the kernel (the policy behind gpt2's headline MFU)
+    # decoders (models/common.py remat_wrap): 'attn' keeps attention's output
+    # and the flash kernel's log-sum-exp, so the backward never re-runs it
     remat: Any = False
     # remat the chunked-CE loss scan (see gpt2.GPT2Config.remat_loss_chunks)
     remat_loss_chunks: bool = True
     use_flash_attention: bool = True
-    # flash kernel tile edge (block_q == block_k); None = kernel default.
-    # The bidirectional grid has no triangular skip, so the full-sequence
-    # tile (= seq_len) removes all tiling overhead at BERT's short seqs
+    # flash kernel tile edge (block_q == block_k), a multiple of 128; None =
+    # kernel default. The bidirectional grid has no triangular skip, so the
+    # full-sequence tile (= seq_len) removes all tiling overhead at BERT's
+    # short seqs; a length that does not tile in whole 128s (576) takes the
+    # einsum path
     flash_block: Optional[int] = None
     # lax.scan unroll factor for the layer loop: >1 trades compile time for
     # schedule freedom (fewer while-loop iterations and less saved-activation
@@ -91,12 +92,15 @@ class BertConfig:
     VALID_REMAT = (False, None, "none", True, "full", "dots", "attn")
 
     def __post_init__(self):
+        from deepspeed_tpu.models.common import check_flash_block
+
         if self.intermediate_size is None:
             self.intermediate_size = 4 * self.n_embd
         if self.activation not in ("gelu", "gelu_new", "relu"):
             raise ValueError(f"activation {self.activation!r} unknown")
         if self.remat not in self.VALID_REMAT:
             raise ValueError(f"remat={self.remat!r} not in {self.VALID_REMAT}")
+        check_flash_block(self.flash_block)
 
     @property
     def head_dim(self) -> int:
@@ -232,9 +236,7 @@ class BertModel:
         to_heads = lambda t: t.reshape(B, T, c.n_head, c.head_dim)
         attn = self._attention(to_heads(q), to_heads(k), to_heads(v),
                                attention_mask)
-        # named so remat='attn' can save exactly this tensor (the only one
-        # whose recompute re-runs the flash kernel)
-        attn = checkpoint_name(attn, "attn_out").reshape(B, T, D)
+        attn = attn.reshape(B, T, D)
         attn = attn @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
         x = self._layer_norm(x + attn, blk["attn_ln_g"], blk["attn_ln_b"])
         h = x @ blk["fc_w"].astype(x.dtype) + blk["fc_b"].astype(x.dtype)
@@ -251,18 +253,9 @@ class BertModel:
                 jnp.zeros_like(input_ids) if token_type_ids is None else token_type_ids]
         x = self._layer_norm(x, params["emb_ln_g"], params["emb_ln_b"])
 
-        block_fn = self._block
-        if c.remat in (True, "full"):
-            block_fn = jax.checkpoint(
-                block_fn, policy=jax.checkpoint_policies.nothing_saveable)
-        elif c.remat == "dots":
-            block_fn = jax.checkpoint(
-                block_fn,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        elif c.remat == "attn":
-            block_fn = jax.checkpoint(
-                block_fn,
-                policy=jax.checkpoint_policies.save_only_these_names("attn_out"))
+        from deepspeed_tpu.models.common import layer_scan, remat_wrap
+
+        block_fn = remat_wrap(self._block, c.remat)
 
         # Progressive Layer Drop gate (same design as models/gpt2.py _trunk:
         # depth-scaled keep probs, inverted 1/p scaling, θ traced) — PLD's
@@ -287,8 +280,6 @@ class BertModel:
 
         # overridable layer scan (overlap engine's ZeRO-3 gather prefetch;
         # a plain lax.scan when nothing is installed)
-        from deepspeed_tpu.models.common import layer_scan
-
         x, _ = layer_scan(scan_body, x, (params["blocks"], keep_p, pld_rngs),
                           unroll=c.scan_unroll)
         return x

@@ -14,7 +14,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.ops.pallas import SAVED_LSE, SAVED_O
 
 # logits-buffer budget of chunked_lm_loss: the chunk length is the largest
 # divisor of T that keeps ONE CHIP's (B, chunk, V) fp32 logits at or under
@@ -170,6 +173,47 @@ def _kernel_on_mesh(kernel, mesh, args, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)(*args)
 
 
+def remat_wrap(fn, remat):
+    """``fn`` (a per-layer function) under the activation-checkpoint policy a
+    model's ``remat`` names (reference activation_checkpointing/
+    checkpointing.py role); each config's ``VALID_REMAT`` says which it takes.
+
+    ``'attn'`` keeps what attention's backward reads and only its forward can
+    make: the output (~1 x d a token) and, where a flash kernel ran, its
+    log-sum-exp (one float32 a head a token). Each is named where it is made
+    — the kernel's forward rule (``ops/pallas/flash_attention.py``), the
+    einsum and ring paths below — so the backward re-runs the qkv and MLP
+    matmuls and never attention: the best FLOPs / HBM trade when ``'dots'``
+    does not fit. ``'attn_mlp'`` also keeps the MLP's activation
+    (``mlp_act``): neither attention nor the two fat MLP matmuls are re-run,
+    ~8 d^2 of the 12 d^2 a layer recomputed go for 4 d a token more HBM."""
+    policies = jax.checkpoint_policies
+    if remat in (True, "full"):
+        return jax.checkpoint(fn, policy=policies.nothing_saveable)
+    if remat == "dots":
+        return jax.checkpoint(
+            fn, policy=policies.dots_with_no_batch_dims_saveable)
+    if remat == "attn":
+        return jax.checkpoint(
+            fn, policy=policies.save_only_these_names(SAVED_O, SAVED_LSE))
+    if remat == "attn_mlp":
+        return jax.checkpoint(fn, policy=policies.save_only_these_names(
+            SAVED_O, SAVED_LSE, "mlp_act"))
+    return fn
+
+
+def check_flash_block(block):
+    """A model config's ``flash_block``: None (the kernel's default) or a
+    multiple of 128. A q block is the lane dimension of the blocks the flash
+    kernels pass the log-sum-exp in (``ops/pallas/flash_attention.py``): at
+    64 no length over one block tiles, and ``local_causal_attention`` would
+    take the einsum path for a config that asked for the kernel."""
+    if block is not None and (block <= 0 or block % 128):
+        raise ValueError(
+            f"flash_block={block}: not a multiple of 128 (the flash kernels' "
+            "q block is a lane dimension)")
+
+
 def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                            causal: bool = True, key_padding_mask=None,
                            flash_block=None, window=None):
@@ -188,7 +232,8 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     exactly HF BLOOM's ``build_alibi_tensor`` under a full attention mask.
     ``key_padding_mask``: optional (B, T) True=attend. Biased or masked
     attention takes the einsum path (the flash kernel carries neither), and
-    so does a non-causal length the kernel cannot tile.
+    so does a non-causal length the kernel cannot tile (q in whole 128s or
+    one block: 576 = 9 x 64 is not).
     ``window``: optional sliding window (GPT-Neo local attention, reference
     containers/gptneo.py): position i attends to j with 0 <= i-j < window.
     May be a TRACED scalar so one scanned layer loop can mix global and
@@ -229,7 +274,7 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
         keep = jnp.asarray(key_padding_mask).astype(jnp.bool_)
         logits = jnp.where(keep[:, None, None, :], logits, NEG_INF_ATTN)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return checkpoint_name(jnp.einsum("bhqk,bkhd->bqhd", probs, v), SAVED_O)
 
 
 # ------------------------------------------------------------------ KV cache
@@ -423,7 +468,8 @@ def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,
                     q, k, v, mesh)
             # ring attention schedules its own per-shard blocks; the flash
             # tile knob does not apply there
-            return seq_par.ring_attention(q, k, v, mesh, causal=True)
+            return checkpoint_name(
+                seq_par.ring_attention(q, k, v, mesh, causal=True), SAVED_O)
     return local_causal_attention(q, k, v, use_flash, alibi=alibi,
                                   flash_block=flash_block, window=window)
 

@@ -53,9 +53,9 @@ class GPT2Config:
     # with slack (the bench sets it for the small-model presets)
     remat_loss_chunks: bool = True
     use_flash_attention: bool = True
-    # flash kernel tile edge (block_q == block_k); None = kernel default
-    # (512). An autotuner axis: smaller tiles fit tighter VMEM at long
-    # head_dim, larger amortize the grid
+    # flash kernel tile edge (block_q == block_k), a multiple of 128; None =
+    # kernel default (512). An autotuner axis: smaller tiles fit tighter VMEM
+    # at long head_dim, larger amortize the grid
     flash_block: Optional[int] = None
     tie_embeddings: bool = True
     lm_head_bias: bool = False       # GPT-J style bias on the (untied) head
@@ -95,8 +95,11 @@ class GPT2Config:
                    "attn_mlp")
 
     def __post_init__(self):
+        from deepspeed_tpu.models.common import check_flash_block
+
         if self.remat not in self.VALID_REMAT:
             raise ValueError(f"remat={self.remat!r} not in {self.VALID_REMAT}")
+        check_flash_block(self.flash_block)
         if self.activation not in ("gelu", "gelu_new", "relu", "quick_gelu"):
             raise ValueError(f"activation {self.activation!r} not in "
                              "('gelu', 'gelu_new', 'relu', 'quick_gelu')")
@@ -204,11 +207,13 @@ class GPT2Model:
             # the dense token-level oracle is orders of magnitude faster than
             # Pallas interpret mode; DS_TPU_SPARSE_INTERPRET=1 forces the real
             # kernel off-TPU (CI exercises it via the interpret monkeypatch)
+            from deepspeed_tpu.ops.pallas import SAVED_O
             from deepspeed_tpu.ops.pallas.flash_attention import sparse_mha_reference
 
-            return sparse_mha_reference(q, k, v,
-                                        self._sparse.get_layout(q.shape[1]),
-                                        causal=True)
+            return checkpoint_name(
+                sparse_mha_reference(q, k, v,
+                                     self._sparse.get_layout(q.shape[1]),
+                                     causal=True), SAVED_O)
         return self._sparse(q, k, v, causal=True)
 
     # ---------------------------------------------------------------- params
@@ -345,11 +350,9 @@ class GPT2Model:
 
     def _block(self, x, blk, rng, rope=None, window=None):
         q, k, v = self._block_kv(x, blk, rope)
+        # what remat='attn' saves of attention is named where it is made
+        # (common.remat_wrap): the rest of the block is recomputed
         attn = self._attention(q, k, v, window=window)
-        # named so remat='attn' can save exactly this tensor (the only one
-        # whose recompute re-runs the flash kernel) while rematerializing
-        # the cheap-to-recompute matmul/elementwise chain
-        attn = checkpoint_name(attn, "attn_out")
         return self._block_finish(x, blk, attn, rng)
 
     def _lm_logits(self, params, x):
@@ -366,32 +369,6 @@ class GPT2Model:
         """input_ids (B, T) int32 → logits (B, T, V) fp32."""
         return self._lm_logits(params, self._trunk(params, input_ids, rng))
 
-    def _remat_wrap(self, fn):
-        """Apply the configured activation-checkpoint policy to a per-layer
-        function (reference activation_checkpointing/checkpointing.py role).
-        'attn' saves per-layer attention outputs only (~1×d per token): the
-        backward re-runs the qkv/mlp matmuls but never the flash attention
-        kernel — the best flops/HBM trade when full 'dots' saving doesn't
-        fit."""
-        c = self.config
-        if c.remat in (True, "full"):
-            return jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
-        if c.remat == "dots":
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        if c.remat == "attn":
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.save_only_these_names("attn_out"))
-        if c.remat == "attn_mlp":
-            # middle rung between 'attn' (5d/token saved vs 3d): also save
-            # the gelu output, so the backward re-runs neither the flash
-            # kernel nor the two fat MLP matmuls — ~8d² of the 12d² per-layer
-            # recompute disappears for 4d/token more HBM
-            return jax.checkpoint(
-                fn, policy=jax.checkpoint_policies.save_only_these_names(
-                    "attn_out", "mlp_act"))
-        return fn
-
     def _trunk(self, params, input_ids, rng=None, pld_theta=None):
         c = self.config
         B, T = input_ids.shape
@@ -400,7 +377,9 @@ class GPT2Model:
             rng, emb_key = jax.random.split(rng)
             x = self._dropout(x, emb_key)
 
-        block_fn = self._remat_wrap(self._block)
+        from deepspeed_tpu.models.common import layer_scan, remat_wrap
+
+        block_fn = remat_wrap(self._block, c.remat)
 
         layer_rngs = jax.random.split(rng, c.n_layer) if (rng is not None and c.dropout > 0.0) else None
         rope = self._rope_tables(jnp.arange(T))
@@ -434,8 +413,6 @@ class GPT2Model:
         # layer_scan = lax.scan unless the overlap engine installed its
         # double-buffered ZeRO-3 gather-prefetch implementation (trace-time
         # indirection; identical trace when nothing is installed)
-        from deepspeed_tpu.models.common import layer_scan
-
         x, _ = layer_scan(scan_body, x,
                           (params["blocks"], layer_rngs, windows,
                            keep_p, pld_rngs),

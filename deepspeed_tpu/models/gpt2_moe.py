@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.models.common import remat_wrap
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.moe.layer import MoE
 
@@ -96,7 +97,7 @@ class MoEGPT2(GPT2Model):
         # the configured remat policy applies per PAIR (dense block + MoE
         # half-block): without it every expert hidden and dispatch buffer is
         # saved for backward and an E=8 bank blows a 16G chip at bench shapes
-        pair_fn = self._remat_wrap(pair_fn)
+        pair_fn = remat_wrap(pair_fn, self.config.remat)
 
         def pair_body(carry, xs):
             x, aux = carry
@@ -134,14 +135,9 @@ class MoEGPT2(GPT2Model):
         return ce + self.aux_loss_coef * aux
 
     def _attn_sublayer(self, x, blk, rope=None):
-        from jax.ad_checkpoint import checkpoint_name
-
         B, T, D = x.shape
         q, k, v = self._block_kv(x, blk, rope)
-        # named like _block's attention so remat='attn' saves it and the
-        # backward never re-runs the flash kernel on the MoE half-blocks
-        attn = checkpoint_name(self._attention(q, k, v), "attn_out")
-        attn = attn.reshape(B, T, D)
+        attn = self._attention(q, k, v).reshape(B, T, D)
         return x + attn @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
 
     # ------------------------------------------------------------- inference

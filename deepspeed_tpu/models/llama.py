@@ -70,10 +70,10 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.models.common import _rope_cos_sin, apply_rope
+from deepspeed_tpu.models.common import (_rope_cos_sin, apply_rope,
+                                         remat_wrap)
 
 
 @dataclasses.dataclass
@@ -634,7 +634,6 @@ class LlamaModel:
 
     def _block(self, x, blk, cos_sin):
         attn, _ = self._attend(x, blk, cos_sin, self._causal)
-        attn = checkpoint_name(attn, "attn_out")
         return self._block_finish(x, blk, attn)
 
     def _trunk(self, params, input_ids, rng=None, with_router_stats=False):
@@ -643,18 +642,7 @@ class LlamaModel:
         x = params["wte"].astype(c.dtype)[input_ids]
         cos_sin = self._rope(jnp.arange(T))
 
-        block_fn = self._block
-        if c.remat in (True, "full"):
-            block_fn = jax.checkpoint(
-                block_fn, policy=jax.checkpoint_policies.nothing_saveable)
-        elif c.remat == "dots":
-            block_fn = jax.checkpoint(
-                block_fn,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        elif c.remat == "attn":
-            block_fn = jax.checkpoint(
-                block_fn,
-                policy=jax.checkpoint_policies.save_only_these_names("attn_out"))
+        block_fn = remat_wrap(self._block, c.remat)
 
         def scan_body(carry, blk):
             return block_fn(carry, blk, cos_sin)

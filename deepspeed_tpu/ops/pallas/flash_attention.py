@@ -34,14 +34,29 @@ Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
 columns, v at 128): the FORWARD kernels take the value width from v — the
 accumulator, the output and the P @ V pass are that wide, nothing is padded.
 The backward kernels take one width and refuse another for v: no model
-trains through latent attention here yet.
+trains through latent attention here yet. The per-row statistics that pass
+between the kernels, the log-sum-exp and the backward's delta, are (B·H, 1,
+T) float32, lane-dense: a kernel transposes a query block's column in VMEM
+(``_row``, ``_col``), since a (B·H, T, 1) array pads the 1 to 128 lanes in
+HBM — 128 x the bytes for the kernel to write, for XLA to copy and keep.
+So a q block is a multiple of 128 or the whole length (``flash_supports``;
+a caller takes its einsum path for another, as ``local_causal_attention``
+does). The block-sparse kernels, whose tile is the layout's, keep a row a
+block instead: (B·H·n, 1, block).
 
 Every ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``; ``sparse_`` before each for the block-sparse kernels).
 That name is the last scope of the Mosaic call's ``op_name`` and so the name
 of its HLO instruction (``%flash_fwd.3 = ... custom-call(...)``), which is
 what an op event in a device profile is called — whatever wraps the call
-(``checkpoint``, ``shard_map``, the forward run again under remat).
+(``checkpoint``, ``shard_map``).
+
+Under activation checkpointing the forward kernel runs ONCE a call site:
+the custom VJP's forward rule (``_attention_vjp``) names the two residuals
+only the kernel can produce, ``o`` and the log-sum-exp, and remat ``'attn'``
+(``models/common.py::remat_wrap``) saves those names, so the recompute
+inside the backward holds q, k, v (the caller's matmuls) and no
+``flash_fwd``. ``'full'`` saves nothing and runs the forward again.
 """
 
 from __future__ import annotations
@@ -53,17 +68,22 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.pallas import SAVED_LSE, SAVED_O
 
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
 
-# sequence lengths one block cannot hold are padded to a multiple of this
-# (the MXU tile edge), so the halving in _pick_block stops at >= 128 rows
-_PAD_MULTIPLE = 128
+# a q block is also the LANE dimension of the blocks the log-sum-exp and
+# delta pass between the kernels in, (1, 1, block) of (B*H, 1, T): Mosaic
+# takes a multiple of 128 there, or the whole length. Causal lengths one
+# block cannot hold are padded to a multiple of it.
+_LANES = 128
 
 
 def _pick_block(t: int, preferred: int) -> int:
@@ -73,34 +93,37 @@ def _pick_block(t: int, preferred: int) -> int:
     return max(b, 1)
 
 
-def _tiles(t: int, preferred: int) -> bool:
-    """Whether Mosaic takes ``_pick_block``'s block for length ``t``: its
-    row count must be a multiple of 8 or the whole array."""
+def _tiles(t: int, preferred: int, multiple: int) -> bool:
+    """Whether Mosaic takes ``_pick_block``'s block for length ``t``: a
+    multiple of ``multiple`` or the whole array. 8 for a k/v block, the rows
+    of a (block, D) tile; ``_LANES`` for a q block (above)."""
     b = _pick_block(t, preferred)
-    return b == t or b % 8 == 0
+    return b == t or b % multiple == 0
 
 
 def _padded_len(t: int, preferred: int) -> int:
-    """The length causal self-attention runs the kernels at: ``t`` while it
-    tiles in blocks of >= 128 rows (or one block holds it), else the next
+    """The length causal self-attention runs the kernels at: ``t`` while one
+    block holds it or it tiles in blocks of whole 128s, else the next
     multiple of 128 — any prompt length lowers, and none degrades to the
     8-row blocks an odd multiple of 8 (T=1000) would halve down to."""
-    if t <= preferred or _pick_block(t, preferred) >= _PAD_MULTIPLE:
+    if _tiles(t, preferred, _LANES):
         return t
-    return -(-t // _PAD_MULTIPLE) * _PAD_MULTIPLE
+    return -(-t // _LANES) * _LANES
 
 
 def flash_supports(t_q: int, t_k: int, causal: bool,
                    block_q: int = None, block_k: int = None) -> bool:
     """Whether :func:`flash_attention` can tile these lengths. Causal
-    self-attention always can (it pads; see ``_padded_len``); the other
-    forms — which have no mask to hide pad keys behind — only at lengths
-    that tile as they are."""
+    self-attention can at the default blocks, whatever the length (it pads;
+    see ``_padded_len``); the other forms — which have no mask to hide pad
+    keys behind — only at lengths that tile as they are: q in blocks of
+    whole 128s (or one block), k in whole 8s. 576 = 9 x 64 does not, nor
+    does any longer length in blocks of 64."""
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
     if causal and t_q == t_k:
-        return True
-    return _tiles(t_q, block_q) and _tiles(t_k, block_k)
+        t_q = t_k = _padded_len(t_q, min(block_q, block_k))
+    return _tiles(t_q, block_q, _LANES) and _tiles(t_k, block_k, 8)
 
 
 def _causal_pairs(nq: int):
@@ -148,6 +171,28 @@ def _block_iotas(block_q, block_k, qi, ki):
     return rows, cols
 
 
+def _row(x):
+    """(n, 128) with every lane of a row equal -> (1, n), lane-dense: how a
+    per-row statistic (the log-sum-exp) leaves a kernel. A (n, 1) output
+    pads the 1 to 128 lanes in HBM: 128 x the bytes, written by the kernel
+    and read by whatever takes it next."""
+    return x.T[:1]
+
+
+def _col(row):
+    """(1, n) lane-dense -> (n, 1): a per-row statistic (log-sum-exp, delta)
+    as the column a (n, block_k) score block subtracts."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
+
+
+def _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc):
+    """Write a query block's output and log-sum-exp from the running
+    accumulator, max and sum (the last two lane-broadcast, (rows, 128))."""
+    l_safe = jnp.where(l_sc[:] == 0.0, 1.0, l_sc[:])
+    o_ref[0] = (acc_sc[:] / l_safe[:, :1]).astype(o_ref.dtype)
+    lse_ref[0] = _row(m_sc[:] + jnp.log(l_safe))
+
+
 def _causal_dispatch(qi, ki, block_q, block_k, compute):
     """Rectangular-grid causal dispatch shared by fwd/dq/dkv kernels:
     run ``compute(mask_rc)`` mask-free on blocks fully below the diagonal,
@@ -189,10 +234,7 @@ def _fwd_tri_kernel(qi_arr, ki_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
                               acc_sc, m_sc, l_sc, scale,
                               mask_rc=_block_iotas(block, block, qi, ki))
         # last block of this row: write out
-        l = l_sc[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_sc[:, :1] + jnp.log(l_safe)).astype(jnp.float32)
+        _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc)
 
 
 # --------------------------------------------- forward (rectangular fallback)
@@ -218,10 +260,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
 
     @pl.when(ki == num_k - 1)
     def _finalize():
-        l = l_sc[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_sc[:, :1] + jnp.log(l_safe)).astype(jnp.float32)
+        _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc)
 
 
 def _tri_min_blocks() -> int:
@@ -251,7 +290,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
     nq, nk = t_q // bq, t_k // bk
 
     out_shapes = (jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
-                  jax.ShapeDtypeStruct((bh, t_q, 1), jnp.float32))
+                  jax.ShapeDtypeStruct((bh, 1, t_q), jnp.float32))
     scratch = [pltpu.VMEM((bq, dv), jnp.float32),
                pltpu.VMEM((bq, 128), jnp.float32),
                pltpu.VMEM((bq, 128), jnp.float32)]
@@ -271,7 +310,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
                 ],
                 out_specs=(
                     pl.BlockSpec((1, bq, dv), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bq, 1), lambda b, f, qa, ka: (b, qa[f], 0)),
+                    pl.BlockSpec((1, 1, bq), lambda b, f, qa, ka: (b, 0, qa[f])),
                 ),
                 scratch_shapes=scratch,
             ),
@@ -298,7 +337,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
         ],
         out_specs=(
             pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ),
         out_shape=out_shapes,
         scratch_shapes=scratch,
@@ -314,7 +353,8 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
 
 # -------------------------------------------------------------------- backward
 def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None):
-    """Recompute P and dS for one block (shared by dq and dkv kernels)."""
+    """Recompute P and dS for one block (shared by dq and dkv kernels).
+    ``lse`` and ``delta`` arrive as the query block's lane-dense rows."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if scale != 1.0:
@@ -322,10 +362,10 @@ def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None):
     if mask_rc is not None:
         rows, cols = mask_rc
         s = jnp.where(rows >= cols, s, NEG_INF)
-    p = jnp.exp(s - lse)
+    p = jnp.exp(s - _col(lse))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
+    ds = p * (dp - _col(delta))
     if scale != 1.0:
         ds = ds * scale
     ds = ds.astype(k.dtype)
@@ -456,8 +496,8 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     bk = _pick_block(t_k, block_k)
     nq, nk = t_q // bq, t_k // bk
     do = g
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)  # (bh, t_q, 1)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, None]        # (bh, 1, t_q), as lse
 
     if causal and t_q == t_k and bq == bk and t_q // bq < _tri_min_blocks():
         bk = _pick_block(t_k, 2 * bq)       # mirror the forward's block choice
@@ -477,8 +517,8 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
                     pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
                     pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
                     pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bq, 1), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bq, 1), lambda b, f, qa, ka: (b, qa[f], 0)),
+                    pl.BlockSpec((1, 1, bq), lambda b, f, qa, ka: (b, 0, qa[f])),
+                    pl.BlockSpec((1, 1, bq), lambda b, f, qa, ka: (b, 0, qa[f])),
                 ],
                 out_specs=pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
                 scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -501,8 +541,8 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
                     pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
                     pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
                     pl.BlockSpec((1, bq, d), lambda b, f, ka, qa: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bq, 1), lambda b, f, ka, qa: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bq, 1), lambda b, f, ka, qa: (b, qa[f], 0)),
+                    pl.BlockSpec((1, 1, bq), lambda b, f, ka, qa: (b, 0, qa[f])),
+                    pl.BlockSpec((1, 1, bq), lambda b, f, ka, qa: (b, 0, qa[f])),
                 ],
                 out_specs=(
                     pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
@@ -528,8 +568,8 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
@@ -548,8 +588,8 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i)),
         ],
         out_specs=(
             pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
@@ -566,26 +606,61 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
 
 
 # ------------------------------------------------------------------ public api
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bhtd(q, k, v, scale, causal, block_q, block_k):
-    o, _ = _flash_forward(q, k, v, scale, causal, block_q, block_k)
-    return o
+def _to_bhtd(x):
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
-def _flash_bhtd_fwd(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _flash_forward(q, k, v, scale, causal, block_q, block_k)
-    return o, (q, k, v, o, lse)
+def _to_bthd(x, b):
+    bh, t, d = x.shape
+    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
 
 
-def _flash_bhtd_bwd(scale, causal, block_q, block_k, res, g):
-    if res[2].shape[-1] != res[0].shape[-1]:
-        raise NotImplementedError(
-            "flash_attention: the backward takes v at the q.k width "
-            f"({res[0].shape[-1]}), not {res[2].shape[-1]}")
-    return _flash_backward(res, g, scale, causal, block_q, block_k)
+def _attention_vjp(forward, backward):
+    """``f(q, k, v, static) -> o`` over (B, T, H, D) arrays, differentiable,
+    from a kernel pair over (B*H, T, D): ``forward(q, k, v, *static) -> (o,
+    lse)`` and ``backward((q, k, v, o, lse), do, *static) -> (dq, dk, dv)``.
+
+    The forward RULE names what it keeps of the kernel's outputs, in the
+    form worth keeping: ``o`` as the model's lane-dense (B, T, H*Dv) tensor
+    (the kernel's (B*H, T, Dv) pads a 64- or 96-wide head to 128 lanes in
+    HBM), the log-sum-exp as the kernels' rows, (B*H, T) float32 ((B*H*n,
+    block) from the block-sparse pair). The backward rule takes the
+    kernels' views from them. A name on the function's OUTPUT would name
+    another variable than the residual, and a policy that saved only that
+    would throw ``o`` and ``lse`` away and run the forward kernel again to
+    get them."""
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+    def attend(q, k, v, static):
+        o, _ = forward(_to_bhtd(q), _to_bhtd(k), _to_bhtd(v), *static)
+        return _to_bthd(o, q.shape[0])
+
+    def fwd(q, k, v, static):
+        b, t, h, _ = q.shape
+        o, lse = forward(_to_bhtd(q), _to_bhtd(k), _to_bhtd(v), *static)
+        o = checkpoint_name(_to_bthd(o, b).reshape(b, t, -1), SAVED_O)
+        lse = checkpoint_name(lse[:, 0], SAVED_LSE)
+        return o.reshape(b, t, h, -1), (q, k, v, o, lse)
+
+    def bwd(static, res, g):
+        q, k, v, o, lse = res
+        b, t, h, d = q.shape
+        if v.shape[-1] != d:
+            raise NotImplementedError(
+                "flash_attention: the backward takes v at the q.k width "
+                f"({d}), not {v.shape[-1]}")
+        grads = backward(
+            (_to_bhtd(q), _to_bhtd(k), _to_bhtd(v),
+             _to_bhtd(o.reshape(b, t, h, d)), lse[:, None]),
+            _to_bhtd(g), *static)
+        return tuple(_to_bthd(x, b) for x in grads)
+
+    attend.defvjp(fwd, bwd)
+    return attend
 
 
-_flash_bhtd.defvjp(_flash_bhtd_fwd, _flash_bhtd_bwd)
+_flash_bthd = _attention_vjp(_flash_forward, _flash_backward)
 
 
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
@@ -599,29 +674,26 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     row changes, and the pad rows' cotangents are zero. Other forms raise
     on an untileable length (``flash_supports`` tells callers beforehand).
     """
-    b, t, h, d = q.shape
+    t, d = q.shape[1], q.shape[-1]
     if not flash_supports(t, k.shape[1], causal, block_q, block_k):
         raise ValueError(
             f"flash_attention: lengths ({t}, {k.shape[1]}) with causal="
-            f"{causal} do not tile in blocks ({block_q}, {block_k}) — only "
-            "causal self-attention is padded")
+            f"{causal} do not tile in blocks ({block_q}, {block_k}): q in "
+            "whole 128s or one block, k in whole 8s — only causal "
+            "self-attention is padded, to a multiple of 128")
     if causal and t == k.shape[1]:
         pad = _padded_len(t, min(block_q, block_k)) - t
         if pad:
             q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
                        for x in (q, k, v))
-    t_q, t_k = q.shape[1], k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     # fold the softmax scale into q OUTSIDE the kernels: one multiply over
     # (T, D) instead of a VPU pass over every (T², causal-half) score element
     # in the forward and in both backward kernels; autodiff scales dq back
     q = q * jnp.asarray(scale, q.dtype)
-    to_bhtd = lambda x, t: x.transpose(0, 2, 1, 3).reshape(
-        b * h, t, x.shape[-1])
-    o = _flash_bhtd(to_bhtd(q, t_q), to_bhtd(k, t_k), to_bhtd(v, t_k),
-                    1.0, bool(causal), int(block_q), int(block_k))
-    return o.reshape(b, h, t_q, v.shape[-1]).transpose(0, 2, 1, 3)[:, :t]
+    o = _flash_bthd(q, k, v, (1.0, bool(causal), int(block_q), int(block_k)))
+    return o[:, :t]
 
 
 # ------------------------------------------------------------ block-sparse
@@ -706,10 +778,7 @@ def _sparse_fwd_kernel(qi_arr, ki_arr, first_arr, last_arr, valid_arr,
 
     @pl.when(last_arr[f] == 1)
     def _finalize():
-        l = l_sc[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_sc[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = (m_sc[:, :1] + jnp.log(l_safe)).astype(jnp.float32)
+        _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc)
 
 
 def _sparse_bwd_dq_kernel(qi_arr, ki_arr, first_arr, last_arr, valid_arr,
@@ -765,8 +834,13 @@ def _sparse_bwd_dkv_kernel(qi_arr, ki_arr, first_arr, last_arr, valid_arr,
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _sparse_forward(q, k, v, scale, causal, layout):
+def _sparse_forward(q, k, v, scale, causal, hlayout):
+    """The log-sum-exp (and the backward's delta) are (B*H*n, 1, block), a
+    row a query block: the tile is the LAYOUT's block, any multiple of 8,
+    and a (1, 1, block) block of a (B*H, 1, T) array would have to be a
+    multiple of 128 lanes. From 128 up the bytes are the same, lane-dense."""
     bh, t, d = q.shape
+    layout = hlayout.arr
     n = layout.shape[0]
     block = t // n
     row_pairs, _ = _sparse_pairs(layout, causal)
@@ -785,37 +859,38 @@ def _sparse_forward(q, k, v, scale, causal, layout):
             ],
             out_specs=(
                 pl.BlockSpec((1, block, d), lambda b, f, qa, ka, fa, la, va: (b, qa[f], 0)),
-                pl.BlockSpec((1, block, 1), lambda b, f, qa, ka, fa, la, va: (b, qa[f], 0)),
+                pl.BlockSpec((1, 1, block), lambda b, f, qa, ka, fa, la, va: (b * n + qa[f], 0, 0)),
             ),
             scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
                             pltpu.VMEM((block, 128), jnp.float32),
                             pltpu.VMEM((block, 128), jnp.float32)],
         ),
         out_shape=(jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-                   jax.ShapeDtypeStruct((bh, t, 1), jnp.float32)),
+                   jax.ShapeDtypeStruct((bh * n, 1, block), jnp.float32)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(*pf, q, k, v)
     return o, lse
 
 
-def _sparse_backward(res, g, scale, causal, layout):
+def _sparse_backward(res, g, scale, causal, hlayout):
     q, k, v, o, lse = res
+    layout = hlayout.arr
     bh, t, d = q.shape
     n = layout.shape[0]
     block = t // n
     row_pairs, col_pairs = _sparse_pairs(layout, causal)
     do = g
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
-                    keepdims=True)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).reshape(lse.shape)
 
     in_specs = [
         pl.BlockSpec((1, block, d), lambda b, f, qa, ka, fa, la, va: (b, qa[f], 0)),
         pl.BlockSpec((1, block, d), lambda b, f, qa, ka, fa, la, va: (b, ka[f], 0)),
         pl.BlockSpec((1, block, d), lambda b, f, qa, ka, fa, la, va: (b, ka[f], 0)),
         pl.BlockSpec((1, block, d), lambda b, f, qa, ka, fa, la, va: (b, qa[f], 0)),
-        pl.BlockSpec((1, block, 1), lambda b, f, qa, ka, fa, la, va: (b, qa[f], 0)),
-        pl.BlockSpec((1, block, 1), lambda b, f, qa, ka, fa, la, va: (b, qa[f], 0)),
+        pl.BlockSpec((1, 1, block), lambda b, f, qa, ka, fa, la, va: (b * n + qa[f], 0, 0)),
+        pl.BlockSpec((1, 1, block), lambda b, f, qa, ka, fa, la, va: (b * n + qa[f], 0, 0)),
     ]
     pf_row = [jnp.asarray(x) for x in row_pairs]
     dq = pl.pallas_call(
@@ -873,22 +948,7 @@ class _HashableLayout:
         return isinstance(other, _HashableLayout) and self._key == other._key
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _sparse_bhtd(q, k, v, scale, causal, hlayout):
-    o, _ = _sparse_forward(q, k, v, scale, causal, hlayout.arr)
-    return o
-
-
-def _sparse_bhtd_fwd(q, k, v, scale, causal, hlayout):
-    o, lse = _sparse_forward(q, k, v, scale, causal, hlayout.arr)
-    return o, (q, k, v, o, lse)
-
-
-def _sparse_bhtd_bwd(scale, causal, hlayout, res, g):
-    return _sparse_backward(res, g, scale, causal, hlayout.arr)
-
-
-_sparse_bhtd.defvjp(_sparse_bhtd_fwd, _sparse_bhtd_bwd)
+_sparse_bthd = _attention_vjp(_sparse_forward, _sparse_backward)
 
 
 def flash_attention_sparse(q, k, v, layout, causal: bool = True,
@@ -902,17 +962,14 @@ def flash_attention_sparse(q, k, v, layout, causal: bool = True,
     MXU/VPU waste most of the hardware and multiply grid overhead. The
     reference's Triton default of block=16 is a GPU-warp granularity that
     does not transfer."""
-    b, t, h, d = q.shape
+    t, d = q.shape[1], q.shape[-1]
     n = np.asarray(layout).shape[0]
     if t % n:
         raise ValueError(f"seq {t} not divisible by layout blocks {n}")
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     q = q * jnp.asarray(scale, q.dtype)
-    to_bhtd = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    o = _sparse_bhtd(to_bhtd(q), to_bhtd(k), to_bhtd(v), 1.0, bool(causal),
-                     _HashableLayout(layout))
-    return o.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return _sparse_bthd(q, k, v, (1.0, bool(causal), _HashableLayout(layout)))
 
 
 def sparse_mha_reference(q, k, v, layout, causal: bool = True,

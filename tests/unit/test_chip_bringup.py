@@ -78,6 +78,77 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, heads, dim):
             kernel
 
 
+def _attention_grad_text(attend, mesh, *shapes):
+    """The compiled gradient of ``sum(attend(q, k, v))`` for the chip."""
+    args = [_abstract(s, jnp.bfloat16, mesh) for s in shapes]
+    loss = lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32))
+    with mesh:
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            *args).compile().as_text()
+
+
+# 576 = 9 x 64 is a 24 x 24 latent (models/diffusion.py::_mha) and 520 = 65
+# x 8: under a whole 128 a q block is not a lane dimension Mosaic takes, so
+# these take the einsum path by choice; 640, 384 (one block) and a cross-
+# attention over 77 keys keep the kernel
+@pytest.mark.parametrize("t_q,t_k,kernel", [
+    (576, 576, False), (520, 520, False), (640, 640, True), (384, 384, True),
+    (1024, 77, True)])
+def test_non_causal_attention_compiles_for_v5e_at_any_length(v5e, t_q, t_k,
+                                                             kernel):
+    """The log-sum-exp and delta pass between the kernels as (B*H, 1, T) in
+    blocks (1, 1, block_q): the q block is a multiple of 128 or the whole
+    length. ``flash_supports`` knows, so the dispatcher never hands Mosaic a
+    block it refuses (interpret mode, which the CPU tests run the kernels
+    in, enforces no tiling: only a compile for the chip sees this)."""
+    assert fa.flash_supports(t_q, t_k, False) == kernel
+    text = _attention_grad_text(
+        functools.partial(common.local_causal_attention, causal=False),
+        _mesh(v5e), (2, t_q, 8, 64), (2, t_k, 8, 64), (2, t_k, 8, 64))
+    assert text.count("tpu_custom_call") == (3 if kernel else 0)
+
+
+def test_a_flash_block_under_128_is_refused_before_mosaic_sees_it(v5e):
+    """64-row blocks were legal while the log-sum-exp was a (.., T, 1)
+    column; as a lane dimension they are not. A config says so when it is
+    made; the kernel's wrapper, handed one directly, raises its own error
+    and not the compiler's; 128 and 256 compile."""
+    from deepspeed_tpu.models.bert import BertConfig
+
+    for make in (GPT2Config, BertConfig):
+        with pytest.raises(ValueError, match="multiple of 128"):
+            make(flash_block=64)
+        assert make(flash_block=256).flash_block == 256
+    assert not fa.flash_supports(1024, 1024, True, 64, 64)
+    x = _abstract((1, 1024, 8, 64), jnp.bfloat16, _mesh(v5e))
+    with pytest.raises(ValueError, match="whole 128s"):
+        jax.jit(functools.partial(fa.flash_attention, block_q=64,
+                                  block_k=64)).lower(x, x, x)
+    for block in (128, 256):
+        text = _attention_grad_text(
+            functools.partial(common.local_causal_attention,
+                              flash_block=block),
+            _mesh(v5e), *[(1, 1024, 8, 64)] * 3)
+        assert text.count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("block", [16, 64, 128, 512])
+def test_sparse_flash_compiles_for_v5e_at_the_layouts_block(v5e, block):
+    """The block-sparse kernels' tile is the layout's block (16 is the
+    reference's default), so their log-sum-exp is a row a block, (B*H*n, 1,
+    block): any block the parent's kernels took still lowers."""
+    n = 1024 // block
+    layout = np.tril(np.ones((n, n), bool)) & ~np.tril(np.ones((n, n), bool), -3)
+    text = _attention_grad_text(
+        lambda q, k, v: fa.flash_attention_sparse(q, k, v, layout),
+        _mesh(v5e), *[(1, 1024, 4, 64)] * 3)
+    for kernel in ("sparse_flash_fwd", "sparse_flash_bwd_dq",
+                   "sparse_flash_bwd_dkv"):
+        assert len(re.findall(
+            rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", text)) == 1, \
+            kernel
+
+
 # B, S, H, KV, Dh: the serving cells' gpt2-xl rows (25 x 64 in 1664 lanes),
 # gpt2-760m's, GQA, and a generate()-sized cache that does not tile
 @pytest.mark.parametrize("shape", [(1, 1024, 25, 25, 64), (2, 1024, 16, 16, 96),
@@ -213,7 +284,11 @@ def test_prefill_at_untileable_prompt_length_compiles(v5e, t):
 def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
     """Bare GSPMD cannot partition a Mosaic call (jax raises at lowering on
     more than one device): the kernel must sit in a shard_map manual over
-    every mesh axis. 16x96 heads, 24 layers, batch over data=4."""
+    every mesh axis. 16x96 heads, 24 layers, batch over data=4. THREE Mosaic
+    calls: forward, dq, dkv. Remat 'attn' saves the forward's ``o`` and
+    log-sum-exp, named inside that shard_map by the custom VJP's forward
+    rule, so the recompute holds no second forward (four until PR 32: the
+    name sat on the VJP's output, the residuals were thrown away)."""
     mesh = _mesh(v5e, data=4)
     model = GPT2Model(dataclasses.replace(PRESETS["gpt2-760m"], remat="attn"))
     shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
@@ -223,7 +298,7 @@ def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
     with mesh:
         lowered = jax.jit(jax.value_and_grad(
             lambda p, b: model.loss(p, {"input_ids": b}))).lower(params, ids)
-    assert lowered.as_text().count("tpu_custom_call") == 4
+    assert lowered.as_text().count("tpu_custom_call") == 3
 
 
 # ---------------------- gpt2-xl's ZeRO-3 step over four chips (train.z3x4)
@@ -231,13 +306,13 @@ HEAD_ELEMS = 50257 * 1600
 HBM_PER_CHIP = 16.91e9          # 15.75 GiB: what a v5e chip offers a program
 
 
-@pytest.fixture(scope="module")
-def xl_z3_step(v5e):
-    """The engine's REAL train step of ``gpt2-xl.train.z3x4`` (the cell's
-    own configuration and traffic files: ZeRO-3 over data=4, micro-batch 16 a
-    chip, bf16, AdamW, clipping, remat 'attn'), compiled for the four
-    described chips. Nothing can be placed on a described device, so the two
-    places where the engine materializes state hand back shapes instead."""
+def _compiled_train_step(devices, config, traffic_file, chips):
+    """The engine's REAL train step of a train cell (the cell's own
+    configuration and traffic files: ZeRO stage, micro-batch a chip,
+    accumulation steps, bf16, AdamW, clipping, remat 'attn'), compiled for
+    ``chips`` described chips over 'data'. Nothing can be placed on a
+    described device, so the two places where the engine materializes state
+    hand back shapes instead."""
     import json
     import types
 
@@ -245,14 +320,14 @@ def xl_z3_step(v5e):
     from benchmark import families
     from deepspeed_tpu.runtime import engine as engine_mod
 
-    mesh = _mesh(v5e, data=4)
+    mesh = _mesh(devices, data=chips)
     cfg, traffic = (json.load(open(os.path.join(REPO, "benchmark", d, f)))
-                    for d, f in (("configs", "gpt2-xl.json"),
-                                 ("traffic", "train.z3x4.json")))
+                    for d, f in (("configs", config), ("traffic", traffic_file)))
     micro = traffic["engine"]["micro_batch_per_chip"]
+    gas = traffic["engine"]["gradient_accumulation_steps"]
     ds = dict(cfg["train"]["ds_config"],
               train_micro_batch_size_per_gpu=micro,
-              gradient_accumulation_steps=1, steps_per_print=0,
+              gradient_accumulation_steps=gas, steps_per_print=0,
               zero_optimization={"stage": traffic["engine"]["zero_stage"]})
     real_jit, real_put = engine_mod.sharded_jit, jax.device_put
 
@@ -276,11 +351,83 @@ def xl_z3_step(v5e):
             model=families.get(cfg["family"]).build_model(cfg, "train"),
             config=ds, mpu=types.SimpleNamespace(mesh=mesh))
     batch = {"input_ids": jax.ShapeDtypeStruct(
-        (4 * micro, traffic["seq_len"]), jnp.int32,
+        (gas * chips * micro, traffic["seq_len"]), jnp.int32,
         sharding=engine.sharding.batch_sharding(2))}
     with mesh:
-        return engine._get_compiled_train_batch(1, batch).lower(
+        return engine._get_compiled_train_batch(gas, batch).lower(
             engine.state, batch).compile()
+
+
+@pytest.fixture(scope="module")
+def xl_z3_step(v5e):
+    """``gpt2-xl.train.z3x4``: ZeRO-3 over data=4, micro-batch 16 a chip."""
+    return _compiled_train_step(v5e, "gpt2-xl.json", "train.z3x4.json", 4)
+
+
+@pytest.fixture(scope="module")
+def gas4_step(v5e):
+    """``gpt2-760m.train.z1.gas4``: ZeRO-1 on one chip, 4 micro-batches of 6
+    accumulated in float32 by the engine's scan."""
+    return _compiled_train_step(v5e, "gpt2-760m.json", "train.z1.gas4.json", 1)
+
+
+def _computations(text):
+    """{computation: its lines} of a compiled module's text, and ``reach``:
+    the computations a computation calls, itself included."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name:
+            comps[name].append(line)
+
+    def called(keys, lines):
+        return [c for l in lines
+                for c in re.findall(rf"(?:{keys})=%?([\w.\-]+)", l)]
+
+    def reach(root, keys="to_apply|calls|body|condition"):
+        seen, todo = set(), [root]
+        while todo:
+            c = todo.pop()
+            if c in comps and c not in seen:
+                seen.add(c)
+                todo += called(keys, comps[c])
+        return seen
+
+    return comps, called, reach
+
+
+def _flash_kernels_by_loop(text):
+    """[{kernel: Mosaic calls}] for every ``while`` body of the compiled text
+    that holds a flash kernel (directly or in what it calls), the smallest
+    body first: the layer scans come before a scan that holds them."""
+    comps, called, reach = _computations(text)
+    found = []
+    for body in {c for lines in comps.values() for c in called("body", lines)}:
+        inside = reach(body)
+        n = {k: sum(bool(re.search(
+            rf"%[\w.]*{k}[\w.]* = [^\n]*tpu_custom_call", l))
+            for c in inside for l in comps[c])
+            for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+        if any(n.values()):
+            found.append((len(inside), n))
+    return [n for _, n in sorted(found, key=lambda x: x[0])]
+
+
+def _assert_the_forward_kernel_runs_once_a_layer(text):
+    """The layer scan of the forward holds ``flash_fwd``; the backward's
+    holds ``flash_bwd_dq`` and ``flash_bwd_dkv`` and NO forward kernel: what
+    remat 'attn' saved (``o``, the log-sum-exp) is what the backward reads."""
+    loops = _flash_kernels_by_loop(text)
+    assert loops[:2] in (
+        [{"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkv": 0},
+         {"flash_fwd": 0, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}],
+        [{"flash_fwd": 0, "flash_bwd_dq": 1, "flash_bwd_dkv": 1},
+         {"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkv": 0}]), loops
+    assert len(re.findall(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call",
+                          text)) == 1
 
 
 def _head_collectives(text):
@@ -290,23 +437,9 @@ def _head_collectives(text):
     result of a reduce-scatter. XLA:TPU writes most reduce-scatters as a
     fusion that calls a computation holding the whole all-reduce, which is
     counted there, in a loop if its caller is."""
-    comps, name = {}, None
-    for line in text.splitlines():
-        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
-        if m:
-            name = m.group(1)
-            comps[name] = []
-        elif name:
-            comps[name].append(line)
-    called = lambda keys, lines: [
-        c for l in lines for c in re.findall(rf"(?:{keys})=%?([\w.\-]+)", l)]
-    inside, todo = set(), [c for lines in comps.values()
-                           for c in called("body|condition", lines)]
-    while todo:
-        c = todo.pop()
-        if c in comps and c not in inside:
-            inside.add(c)
-            todo += called("to_apply|calls|body|condition", comps[c])
+    comps, called, reach = _computations(text)
+    inside = set().union(*(reach(c) for lines in comps.values()
+                           for c in called("body|condition", lines)))
     found = []
     for name, lines in comps.items():
         for l in lines:
@@ -339,6 +472,52 @@ def test_xl_z3_step_at_micro_batch_16_fits_a_chip(xl_z3_step):
         f"{HBM_PER_CHIP / 1e9:.2f} GB a chip")
     # what XLA rematerializes to make a step fit is on no idle share (PR 26)
     assert ".remat" not in xl_z3_step.as_text()
+
+
+def _stacked(text, dtype, *dims):
+    return re.search(rf"{dtype}\[{','.join(map(str, dims))}\]", text)
+
+
+def test_xl_z3_step_runs_the_flash_forward_once_a_layer(xl_z3_step):
+    """Inside the shard_map a mesh puts the kernel in, the names reach the
+    policy: the backward's layer scan holds no ``flash_fwd`` (it did until
+    PR 32: 202 ms of a 2,433 ms step). Kept a layer: the block's input and
+    ``o``, each ONE lane-dense (16, 1024, 1600) bf16, and 400 x 1024 float32
+    of log-sum-exp; no lane-padded copy of either (the kernel's own (400,
+    1024, 64) pads to 128 lanes: +2.5 GB; a (400, 1024, 1) log-sum-exp x
+    128: +10 GB, which is why the kernels pass it as (400, 1, 1024)), which
+    ``test_xl_z3_step_at_micro_batch_16_fits_a_chip`` would see too."""
+    text = xl_z3_step.as_text()
+    _assert_the_forward_kernel_runs_once_a_layer(text)
+    assert _stacked(text, "bf16", 48, 16, 1024, 1600)
+    assert _stacked(text, "f32", 48, 400, 1024)
+    assert not _stacked(text, "bf16", 48, 400, 1024, 64)
+    assert not _stacked(text, "bf16", 48, 16, 1024, 25, 64)
+    assert not _stacked(text, "f32", 48, 400, 1024, 1)
+
+
+# what XLA's own rematerialization pass puts into the gas-4 step to make it
+# fit: 14 ops before PR 32 (8.59 ms a sequence on the chip, PR 26); 15 with
+# o and an XLA-squeezed log-sum-exp among the residuals, which LOST 0.5% on
+# the chip (the pass re-ran one more matmul a layer); 10 with the
+# log-sum-exp and delta lane-dense from and to the kernels (+2.3%)
+GAS4_REMAT_OPS = 10
+
+
+def test_gas4_step_at_micro_batch_6_compiles_and_runs_the_forward_once(gas4_step):
+    """``gpt2-760m.train.z1.gas4`` stands at the compiler's limit (micro-batch
+    8 is refused: "Used 15.81G of 15.75G"), so the compiler's verdict on the
+    real step is the test: it compiled. What it rematerialized to get there
+    is device time on no idle share: a change to what a layer keeps moves
+    that count, and is seen here before it is on the chip."""
+    text = gas4_step.as_text()
+    _assert_the_forward_kernel_runs_once_a_layer(text)
+    assert _stacked(text, "bf16", 24, 6, 1024, 1536)
+    assert _stacked(text, "f32", 24, 96, 1024)
+    assert not _stacked(text, "f32", 24, 96, 1024, 1)
+    remat = len(re.findall(r"%[\w.\-]*\.remat[\w.\-]* = ", text))
+    assert remat <= GAS4_REMAT_OPS, (
+        f"{remat} ops rematerialized by XLA (PR 32: {GAS4_REMAT_OPS})")
 
 
 # ------------------------------------ OLMoE-1B-7B at its published widths

@@ -67,3 +67,120 @@ def test_uneven_blocks():
     out = fa.flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
     ref = fa.mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2, rtol=2e-2)
+
+
+# ----------------------------- remat 'attn' keeps what the backward reads
+def _remat_cases():
+    """(head_dim, T, where, remat, forward kernels in the gradient): every
+    head size the cells train at x a length that tiles and one that pads
+    (1000 -> 1024) x the kernel called directly (one chip) and inside the
+    ``shard_map`` a mesh puts it in; then the two neighbours of 'attn'."""
+    cases = [(d, t, where, "attn", 1)
+             for d in (64, 96, 128) for t in (256, 1000)
+             for where in ("direct", "shard_map")]
+    return cases + [(64, 256, "direct", "full", 2),
+                    (64, 256, "direct", "attn_mlp", 1)]
+
+
+@pytest.mark.parametrize(
+    "head_dim,T,where,remat,forwards", _remat_cases(),
+    ids=lambda x: str(x))
+def test_remat_attn_saves_o_and_lse_so_the_forward_runs_once(
+        head_dim, T, where, remat, forwards, capsys):
+    """A layer under ``remat_wrap(.., 'attn')`` keeps the flash forward's
+    ``o`` and log-sum-exp (named INSIDE the custom VJP's forward rule: the
+    residuals themselves), so the gradient holds ``flash_fwd`` once a call
+    site, not once more in the recompute, and is exactly the un-rematted
+    gradient: same kernels, same inputs. 'full' saves nothing and holds two."""
+    from jax.ad_checkpoint import print_saved_residuals
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from deepspeed_tpu.models import common
+
+    B, H = 2, 2
+    keys = jax.random.split(jax.random.PRNGKey(head_dim + T), 4)
+    x, wq, wk, wv = (jax.random.normal(k, s, jnp.float32) for k, s in zip(
+        keys, [(B, T, H * head_dim)] + [(H * head_dim, H * head_dim)] * 3))
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",)) \
+        if where == "shard_map" else None
+    spec = P("data")
+
+    def layer(x, wq, wk, wv):
+        # q, k, v come from the layer's input, as a block's c_attn makes them
+        q, k, v = ((x @ w * 0.05).reshape(B, T, H, head_dim)
+                   for w in (wq, wk, wv))
+        o = common._kernel_on_mesh(fa.flash_attention, mesh, (q, k, v),
+                                   (spec, spec, spec), spec)
+        return jnp.sin(o.reshape(B, T, -1)) @ wq
+
+    def grads(fn):
+        loss = lambda *a: jnp.sum(fn(*a))
+        g = jax.grad(loss, argnums=(0, 1, 2, 3))
+        n = str(jax.make_jaxpr(g)(x, wq, wk, wv)).count("name=flash_fwd")
+        return jax.jit(g)(x, wq, wk, wv), n
+
+    plain, n_plain = grads(layer)
+    kept, n_kept = grads(common.remat_wrap(layer, remat))
+    assert (n_plain, n_kept) == (1, forwards)
+    for a, b in zip(kept, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if where == "direct" and remat != "full":
+        # besides the layer's arguments: o ONCE, in the model's lane-dense
+        # (B, T, H*D) layout, and the log-sum-exp at B*H*T float32
+        t_run = fa._padded_len(T, fa.DEFAULT_BLOCK_Q)
+        capsys.readouterr()
+        print_saved_residuals(common.remat_wrap(layer, remat), x, wq, wk, wv)
+        saved = sorted(l.split(" ", 1)[0]
+                       for l in capsys.readouterr().out.splitlines()
+                       if "from the argument" not in l)
+        assert saved == sorted([f"f32[{B},{t_run},{H * head_dim}]",
+                                f"f32[{B * H},{t_run}]"]), saved
+
+
+@pytest.mark.parametrize("producer", ["einsum", "ring", "sparse_stand_in"])
+def test_an_attention_no_kernel_ran_names_its_output_for_remat_attn(
+        producer, capsys, monkeypatch):
+    """What 'attn' saves is named where it is made. Off the kernel that is
+    three places: the einsum path, the ring, gpt2's off-TPU stand-in for the
+    block-sparse kernel. Each keeps ONE tensor of B*T*H*D a layer besides
+    the layer's arguments, so the backward re-runs no attention; a new path
+    that forgot the name would keep nothing here, and say nothing."""
+    from jax.ad_checkpoint import print_saved_residuals
+    from jax.sharding import Mesh
+
+    from deepspeed_tpu.comm import comm
+    from deepspeed_tpu.models import common
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from deepspeed_tpu.parallel.topology import ALL_AXES
+
+    B, T, H, D = 2, 64, 2, 16
+    if producer == "einsum":
+        attend = common.local_causal_attention
+    elif producer == "ring":
+        shape = [2 if a == "seq" else 1 for a in ALL_AXES]
+        mesh = Mesh(np.array(jax.devices()[:2]).reshape(shape), ALL_AXES)
+        monkeypatch.setattr(comm, "get_mesh", lambda: mesh)
+        attend = functools.partial(common.causal_attention,
+                                   sequence_parallel="ring")
+    else:
+        attend = GPT2Model(GPT2Config(
+            vocab_size=64, n_positions=T, n_embd=H * D, n_layer=1, n_head=H,
+            sparse_attention={"mode": "fixed", "block": 16,
+                              "num_local_blocks": 2}))._sparse_attention
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    x = jax.random.normal(keys[0], (B, T, H * D), jnp.float32)
+    w = jax.random.normal(keys[1], (H * D, H * D), jnp.float32)
+
+    def layer(x, w):
+        q, k, v = ((x @ w * s).reshape(B, T, H, D) for s in (0.1, 0.2, 0.3))
+        return jnp.sin(attend(q, k, v).reshape(B, T, -1)) @ w
+
+    def saved(remat):
+        capsys.readouterr()
+        print_saved_residuals(common.remat_wrap(layer, remat), x, w)
+        # (the stand-in also holds its layout's mask, a bool constant)
+        return [l.split(" ", 1)[0] for l in capsys.readouterr().out.splitlines()
+                if "from the argument" not in l and l.startswith("f32")]
+
+    assert saved("attn") == [f"f32[{B},{T},{H},{D}]"]
+    assert saved("full") == []

@@ -129,7 +129,7 @@ def test_flash_backward_refuses_a_value_width_of_its_own(interpreted):
     v = jax.random.normal(keys[2], (1, 128, 2, 32))
     with pytest.raises(NotImplementedError, match="q.k width"):
         jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
-            q, k, v, block_q=64, block_k=64) ** 2))(q, k, v)
+            q, k, v, block_q=128, block_k=128) ** 2))(q, k, v)
 
 
 # ------------------------------------------------------------------ the router

@@ -299,7 +299,11 @@ def init_kv_cache(n_layer: int, batch_size: int, max_len: int, n_kv: int,
     """``rows``: the row arrays the cache holds. A latent-attention model
     holds ONE, a position's ``[c_kv | k_rope]`` row for all heads (``n_kv``
     1, ``head_dim`` its width: 576 values in 640 lanes where K/V of 128
-    heads would be 40,960): the same layout, writes and in-place reads."""
+    heads would be 40,960): the same layout, writes and in-place reads.
+    ``n_layer``: the layers that HAVE such rows — of a hybrid model its
+    softmax layers only; what its other layers keep of a sequence is not a
+    row a position and lies beside these arrays in the same dict
+    (``models/kda.py::STATE_LEAVES``, ``cache_footprint``)."""
     shape = (n_layer, batch_size, max_len, kv_cache_width(n_kv, head_dim))
     return {**{name: jnp.zeros(shape, dtype) for name in rows},
             "pos": jnp.zeros((), jnp.int32)}
@@ -313,6 +317,26 @@ def kv_cache_partition_specs(n_kv: int, head_dim: int, rows=("k", "v")):
     heads = TENSOR_AXIS if (n_kv * head_dim) % KV_LANES == 0 else None
     return {**{name: P(None, None, None, heads) for name in rows},
             "pos": P()}
+
+
+# The row arrays ``init_kv_cache`` makes, by the names the models give them:
+# rows a POSITION, (L, B, S, W). What a sequence keeps whatever its length
+# is ``models/kda.py::STATE_LEAVES``, (L, B, ...). Anything else in a cache
+# dict (``pos``, a counter) is neither.
+CACHE_POSITION_ROWS = ("k", "v", "kv")
+
+
+def cache_footprint(cache):
+    """(bytes ONE position of one sequence holds across the layers, pad lanes
+    and all; bytes one sequence holds whatever its length) of a cache dict,
+    from the shapes of its own leaves."""
+    from deepspeed_tpu.models.kda import STATE_LEAVES
+
+    held = lambda names: [cache[n] for n in names if n in cache]
+    return (sum(x.shape[0] * x.shape[3] * x.dtype.itemsize
+                for x in held(CACHE_POSITION_ROWS)),
+            sum(x.size // x.shape[1] * x.dtype.itemsize
+                for x in held(STATE_LEAVES)))
 
 
 def kv_cache_rows(t, max_len: int):
@@ -444,6 +468,29 @@ def latent_decode_attention(q, cache, layer, pos, v_width: int, scale: float):
     s = jnp.where((jnp.arange(S) <= pos)[None, None], s, NEG_INF_ATTN)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhk,bkc->bhc", p, rows[..., :v_width])
+
+
+def kda_attention(q, k, v, g, beta, state, differentiable: bool = False):
+    """The gated delta rule (KDA) over T > 1 positions continuing ``state``:
+    the chunked form of ``ops/pallas/kda.py``. q, k, g (B, T, H, dk), v (B,
+    T, H, dv), beta (B, T, H), state (B, H, dk, dv) float32 -> (o (B, T, H,
+    dv), the state after the last position). The path is chosen as
+    ``cached_decode_attention`` chooses: the state pass is the Pallas kernel
+    (``kda_chunk_fwd``) where the program is for a TPU, its ``jnp`` form
+    otherwise, which is also what the kernel is tested against — and what
+    ``differentiable`` asks for (the trunk under ``loss``: the kernel has no
+    backward)."""
+    from deepspeed_tpu.ops.pallas.kda import chunked_kda
+
+    mesh, on_tpu = _kernel_target()
+    if not on_tpu or differentiable:
+        return chunked_kda(q, k, v, g, beta, state)
+    batch, heads = _attn_axes(mesh, q.shape[0], q.shape[2])
+    rows, held = P(batch, None, heads, None), P(batch, heads, None, None)
+    return _kernel_on_mesh(
+        functools.partial(chunked_kda, kernel=True), mesh,
+        (q, k, v, g, beta, state),
+        (rows, rows, rows, rows, P(batch, None, heads), held), (rows, held))
 
 
 def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,

@@ -53,7 +53,28 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
     of width ``dense_intermediate_size`` in ``params["dense_blocks"]``,
     the routed layers in ``params["blocks"]``; the trunk, ``prefill`` and
     ``decode_step`` scan one after the other, and the cache's layer axis
-    runs over both.
+    runs over both;
+  - **a head size of the configuration's own** (``head_dim``; None:
+    ``n_embd // n_head``), **no rotary embedding** (``use_rope`` false) and
+    **an output gate** on softmax attention (a block that holds
+    ``attn_gate_w``: ``o_w [attn * sigmoid(x attn_gate_w)]``);
+  - **a layer pattern** (``gqa_layers``: the layers that mix with softmax
+    attention; the others with KDA, ``models/kda.py``, a gated delta rule
+    whose memory is a fixed-size state and not a row a position).
+    ``LlamaConfig.pattern`` is one period of it. ``params["blocks"]`` then
+    holds what EVERY layer has (norms, router, experts) stacked over all
+    layers, and each kind of mixer lies in a stack of its own over the
+    layers that have it (``attn_blocks``, ``kda_blocks``); the trunk,
+    ``prefill`` and ``decode_step`` scan over PERIODS with the period's
+    layers in the body — a run of consecutive layers of one kind an inner
+    scan that indexes the period's leaves by layer — so each layer's
+    weights are read in place, once, and the stacked expert leaves still go
+    whole to the grouped-matmul kernel with the layer as an index. The
+    cache holds two kinds of state in one dict: ``k``, ``v`` over the
+    SOFTMAX layers only, and ``kda_state`` / ``kda_conv`` over the KDA
+    layers (``models/common.py::cache_footprint`` tells them apart by
+    name). ``loss`` runs KDA through the chunked form's ``jnp`` path (the
+    kernel has no backward).
 
 Implements the same model protocol as GPT2Model (init_params, loss, apply,
 prefill/decode_step, partition specs), so ``initialize()``,
@@ -64,6 +85,7 @@ Weights convert from HF ``LlamaForCausalLM`` via module_inject/hf.py.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Any, Dict, Optional
 
@@ -84,6 +106,7 @@ class LlamaConfig:
     n_layer: int = 32
     n_head: int = 32
     n_kv_head: Optional[int] = None  # None → n_head (no GQA)
+    head_dim: Optional[int] = None   # None → n_embd // n_head
     intermediate_size: Optional[int] = None  # None → LLaMA's 8/3·d rounded to 256
     rope_theta: float = 10000.0
     # None | {"rope_type": "linear", "factor": f}
@@ -122,6 +145,17 @@ class LlamaConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     sandwich_norm: bool = False         # RMSNorm each branch's output too
+    use_rope: bool = True               # False: no positional embedding (NoPE)
+    attn_gate: bool = False             # softmax output x sigmoid(x attn_gate_w)
+    # the layer pattern of a hybrid model: the layers whose mixer is softmax
+    # attention (None: every layer); the others mix with KDA (models/kda.py),
+    # ``kda_heads`` heads of ``kda_head_dim`` behind a causal depthwise
+    # convolution over ``kda_conv`` positions. The pattern must repeat with a
+    # period that divides n_layer (``pattern``)
+    gqa_layers: Optional[tuple] = None
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
     dtype: Any = jnp.bfloat16
     # what init_params draws in: a server that holds bf16 weights asks for
     # them as such, so no float32 copy of a 8.6 GB expert leaf ever exists
@@ -147,6 +181,8 @@ class LlamaConfig:
                                  f"(have: {self.VALID_ROPE_TYPES})")
         if self.n_kv_head is None:
             self.n_kv_head = self.n_head
+        if self.head_dim is None:
+            self.head_dim = self.n_embd // self.n_head
         if self.n_head % self.n_kv_head:
             raise ValueError(f"n_head={self.n_head} not divisible by "
                              f"n_kv_head={self.n_kv_head}")
@@ -182,10 +218,25 @@ class LlamaConfig:
             raise ValueError("latent attention takes q_lora_rank, "
                              "qk_nope_head_dim, qk_rope_head_dim and "
                              "v_head_dim, n_kv_head = n_head and no qk_norm")
-
-    @property
-    def head_dim(self) -> int:
-        return self.n_embd // self.n_head
+        if self.attn_gate and self.mla:
+            raise ValueError("attn_gate: the output gate of a GQA mixer, not "
+                             "of latent attention")
+        if self.gqa_layers is not None:
+            self.gqa_layers = tuple(sorted(set(self.gqa_layers)))
+            if not self.gqa_layers or self.gqa_layers[0] < 0 \
+                    or self.gqa_layers[-1] >= self.n_layer \
+                    or not (self.kda_heads > 0 and self.kda_head_dim > 0
+                            and self.kda_conv > 1):
+                raise ValueError(
+                    f"gqa_layers={self.gqa_layers}: softmax layers among "
+                    f"n_layer={self.n_layer}, the others KDA layers of "
+                    "kda_heads x kda_head_dim behind a convolution of "
+                    "kda_conv > 1 positions")
+            if self.mla or self.n_dense_layers or self.sequence_parallel:
+                raise ValueError(
+                    "a layer pattern (gqa_layers) with latent attention, "
+                    "leading dense layers or sequence parallelism is not "
+                    "built: KDA layers carry a state along the sequence")
 
     @property
     def kv_dim(self) -> int:
@@ -204,6 +255,26 @@ class LlamaConfig:
     def latent_dim(self) -> int:
         """Values of one cached latent row: ``[c_kv | k_rope]``."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pattern(self) -> tuple:
+        """One period of the layer pattern, a mixer's kind a layer:
+        ``("attn",)`` where every layer is softmax attention, ``("attn",
+        "kda", "kda", "kda")`` for one softmax layer in four."""
+        if self.gqa_layers is None:
+            return ("attn",)
+        kinds = ["attn" if l in self.gqa_layers else "kda"
+                 for l in range(self.n_layer)]
+        period = next(p for p in range(1, self.n_layer + 1)
+                      if self.n_layer % p == 0
+                      and kinds == kinds[:p] * (self.n_layer // p))
+        return tuple(kinds[:period])
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that keep rows a position in the cache."""
+        return self.n_layer if self.gqa_layers is None \
+            else len(self.gqa_layers)
 
     @property
     def n_held(self) -> int:
@@ -228,25 +299,34 @@ class LlamaConfig:
                                                + c.v_head_dim) \
                 + c.n_head * c.v_head_dim * d
         else:
-            attn = d * d + 2 * d * c.kv_dim + d * d
+            heads = c.n_head * c.head_dim
+            attn = (2 + c.attn_gate) * d * heads + 2 * d * c.kv_dim
             if c.qk_norm:
-                attn += d + c.kv_dim
+                attn += heads + c.kv_dim
         norms = (4 if c.sandwich_norm else 2) * d
         mlps = (c.n_experts_per_tok if active else c.n_held) or 1
         routed = attn + norms + d * c.n_experts \
             + (mlps + c.n_shared_experts) * 3 * d * i
         dense = attn + norms + 3 * d * c.dense_intermediate_size
         embeds = v * d if c.tie_embeddings else 2 * v * d
-        return embeds + c.n_dense_layers * dense \
+        total = embeds + c.n_dense_layers * dense \
             + (c.n_layer - c.n_dense_layers) * routed + d
+        if c.gqa_layers is not None:    # the KDA layers' mixer for softmax's
+            from deepspeed_tpu.models import kda
+
+            total += (c.n_layer - c.n_attn_layers) * (kda.num_params(c) - attn)
+        return total
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Megatron accounting (6N + 12·l·d·s), as in GPT2Config: GQA does not
         change the attention score/value FLOPs, only the KV projection (already
         inside N). N counts the experts a token meets, not all of them."""
         s = seq_len or self.n_positions
+        # scores and values over the context: the softmax layers only, at the
+        # heads' own width (a KDA layer's state costs the same at any length:
+        # 6 dk dv a head a token forward, in N's order of magnitude, left out)
         return 6 * self.num_params(active=True) \
-            + 12 * self.n_layer * self.n_embd * s
+            + 12 * self.n_attn_layers * self.n_head * self.head_dim * s
 
 
 PRESETS = {
@@ -300,10 +380,45 @@ class LlamaModel:
         self.config = config
 
     # ---------------------------------------------------------------- params
-    def _init_stack(self, keys, l: int, routed: bool) -> Dict[str, Any]:
-        """``l`` layers of one kind, stacked: the mixer's leaves, the norms'
-        gains, then a dense MLP or the router, the held experts and the
-        shared expert. ``keys``: ``init_params``' eight (a leaf that came
+    def _init_mixer(self, keys, l: int) -> Dict[str, Any]:
+        """``l`` softmax mixers, stacked: GQA (with its q/k norm and output
+        gate where the configuration has them) or latent attention."""
+        c = self.config
+        d, s = c.n_embd, 0.02
+        fold = jax.random.fold_in
+        proj_scale = s / math.sqrt(2 * c.n_layer)   # residual-scaled (GPT-2)
+        norm = lambda key, shape, scale: \
+            jax.random.normal(key, shape, c.param_dtype) * scale
+        ones = lambda *shape: jnp.ones(shape, c.param_dtype)
+        if c.mla:
+            h, n, r = c.n_head, c.qk_nope_head_dim, c.qk_rope_head_dim
+            return dict(
+                q_a_w=norm(keys[1], (l, d, c.q_lora_rank), s),
+                q_a_norm_g=ones(l, c.q_lora_rank),
+                q_b_w=norm(keys[2], (l, c.q_lora_rank, h * (n + r)), s),
+                kv_a_w=norm(keys[3], (l, d, c.latent_dim), s),
+                kv_a_norm_g=ones(l, c.kv_lora_rank),
+                kv_b_k_w=norm(fold(keys[3], 1), (l, h, n, c.kv_lora_rank), s),
+                kv_b_v_w=norm(fold(keys[3], 2),
+                              (l, h, c.kv_lora_rank, c.v_head_dim), s),
+                o_w=norm(keys[4], (l, h * c.v_head_dim, d), proj_scale))
+        heads = c.n_head * c.head_dim
+        mixer = dict(q_w=norm(keys[1], (l, d, heads), s),
+                     k_w=norm(keys[2], (l, d, c.kv_dim), s),
+                     v_w=norm(keys[3], (l, d, c.kv_dim), s),
+                     o_w=norm(keys[4], (l, heads, d), proj_scale))
+        if c.qk_norm:
+            mixer.update(q_norm_g=ones(l, heads), k_norm_g=ones(l, c.kv_dim))
+        if c.attn_gate:
+            mixer.update(attn_gate_w=norm(fold(keys[1], 1), (l, d, heads), s))
+        return mixer
+
+    def _init_stack(self, keys, l: int, routed: bool,
+                    mixer: bool = True) -> Dict[str, Any]:
+        """``l`` layers of one kind, stacked: the mixer's leaves (unless the
+        model keeps its mixers in stacks of their own: a layer pattern), the
+        norms' gains, then a dense MLP or the router, the held experts and
+        the shared expert. ``keys``: ``init_params``' eight (a leaf that came
         later folds a number into one of them, so the older leaves draw
         what they always drew)."""
         c = self.config
@@ -315,25 +430,8 @@ class LlamaModel:
             jax.random.normal(key, shape, c.param_dtype) * scale
         ones = lambda *shape: jnp.ones(shape, c.param_dtype)
         blocks = {"attn_norm_g": ones(l, d), "mlp_norm_g": ones(l, d)}
-        if c.mla:
-            h, n, r = c.n_head, c.qk_nope_head_dim, c.qk_rope_head_dim
-            blocks.update(
-                q_a_w=norm(keys[1], (l, d, c.q_lora_rank), s),
-                q_a_norm_g=ones(l, c.q_lora_rank),
-                q_b_w=norm(keys[2], (l, c.q_lora_rank, h * (n + r)), s),
-                kv_a_w=norm(keys[3], (l, d, c.latent_dim), s),
-                kv_a_norm_g=ones(l, c.kv_lora_rank),
-                kv_b_k_w=norm(fold(keys[3], 1), (l, h, n, c.kv_lora_rank), s),
-                kv_b_v_w=norm(fold(keys[3], 2),
-                              (l, h, c.kv_lora_rank, c.v_head_dim), s),
-                o_w=norm(keys[4], (l, h * c.v_head_dim, d), proj_scale))
-        else:
-            blocks.update(q_w=norm(keys[1], (l, d, d), s),
-                          k_w=norm(keys[2], (l, d, c.kv_dim), s),
-                          v_w=norm(keys[3], (l, d, c.kv_dim), s),
-                          o_w=norm(keys[4], (l, d, d), proj_scale))
-        if c.qk_norm:
-            blocks.update(q_norm_g=ones(l, d), k_norm_g=ones(l, c.kv_dim))
+        if mixer:
+            blocks.update(self._init_mixer(keys, l))
         if c.sandwich_norm:
             blocks.update(post_attn_norm_g=ones(l, d),
                           post_mlp_norm_g=ones(l, d))
@@ -363,15 +461,29 @@ class LlamaModel:
         return blocks
 
     def init_params(self, rng) -> Dict[str, Any]:
+        """``blocks``: the layers' leaves, stacked over the layers (with
+        ``dense_blocks`` ahead of them where leading layers are dense). A
+        model with a layer pattern keeps in ``blocks`` what every layer has
+        (norms, router, experts), over ALL layers, and each kind of mixer in
+        a stack of its own over the layers that have it: ``attn_blocks``
+        (softmax) and ``kda_blocks`` (``models/kda.py``)."""
         c = self.config
         keys = jax.random.split(rng, 8)
         norm = lambda key, shape: \
             jax.random.normal(key, shape, c.param_dtype) * 0.02
+        hybrid = c.gqa_layers is not None
         params = {"wte": norm(keys[0], (c.vocab_size, c.n_embd)),
                   "blocks": self._init_stack(
                       keys, c.n_layer - c.n_dense_layers,
-                      routed=bool(c.n_experts)),
+                      routed=bool(c.n_experts), mixer=not hybrid),
                   "norm_g": jnp.ones((c.n_embd,), c.param_dtype)}
+        if hybrid:
+            from deepspeed_tpu.models import kda
+
+            params["attn_blocks"] = self._init_mixer(keys, c.n_attn_layers)
+            params["kda_blocks"] = kda.init_leaves(
+                c, jax.random.fold_in(rng, 2), c.n_layer - c.n_attn_layers,
+                0.02 / math.sqrt(2 * c.n_layer))
         if c.n_dense_layers:
             params["dense_blocks"] = self._init_stack(
                 jax.random.split(jax.random.fold_in(rng, 1), 8),
@@ -381,23 +493,29 @@ class LlamaModel:
                                      (c.n_embd, c.vocab_size))
         return params
 
-    def _stack_specs(self, routed: bool) -> Dict[str, Any]:
+    def _mixer_specs(self) -> Dict[str, Any]:
         c = self.config
         rep = lambda rank: P(*([None] * rank))
-        blocks = {"attn_norm_g": rep(2), "mlp_norm_g": rep(2)}
         if c.mla:
             # replicated: latent attention under tensor parallelism is open
             # (one latent row a position cannot be split by head)
-            blocks.update(q_a_w=rep(3), q_a_norm_g=rep(2), q_b_w=rep(3),
-                          kv_a_w=rep(3), kv_a_norm_g=rep(2), kv_b_k_w=rep(4),
-                          kv_b_v_w=rep(4), o_w=rep(3))
-        else:
-            blocks.update(q_w=P(None, None, "tensor"),
-                          k_w=P(None, None, "tensor"),
-                          v_w=P(None, None, "tensor"),
-                          o_w=P(None, "tensor", None))
+            return dict(q_a_w=rep(3), q_a_norm_g=rep(2), q_b_w=rep(3),
+                        kv_a_w=rep(3), kv_a_norm_g=rep(2), kv_b_k_w=rep(4),
+                        kv_b_v_w=rep(4), o_w=rep(3))
+        specs = dict(q_w=P(None, None, "tensor"), k_w=P(None, None, "tensor"),
+                     v_w=P(None, None, "tensor"), o_w=P(None, "tensor", None))
         if c.qk_norm:
-            blocks.update(q_norm_g=rep(2), k_norm_g=rep(2))
+            specs.update(q_norm_g=rep(2), k_norm_g=rep(2))
+        if c.attn_gate:
+            specs.update(attn_gate_w=P(None, None, "tensor"))
+        return specs
+
+    def _stack_specs(self, routed: bool, mixer: bool = True) -> Dict[str, Any]:
+        c = self.config
+        rep = lambda rank: P(*([None] * rank))
+        blocks = {"attn_norm_g": rep(2), "mlp_norm_g": rep(2)}
+        if mixer:
+            blocks.update(self._mixer_specs())
         if c.sandwich_norm:
             blocks.update(post_attn_norm_g=rep(2), post_mlp_norm_g=rep(2))
         if not routed:
@@ -418,11 +536,19 @@ class LlamaModel:
         parallel, o/down row parallel, vocab-sharded embedding. The routed
         experts are replicated (``experts_held`` says which of the router's
         experts a chip's leaves hold; the exchange of rows between chips is
-        ROADMAP R1's open half), and so is a latent-attention mixer."""
+        ROADMAP R1's open half), and so are a latent-attention mixer and a
+        KDA mixer (``models/kda.py::leaf_specs``)."""
         c = self.config
+        hybrid = c.gqa_layers is not None
         specs = {"wte": P("tensor", None),
-                 "blocks": self._stack_specs(bool(c.n_experts)),
+                 "blocks": self._stack_specs(bool(c.n_experts),
+                                             mixer=not hybrid),
                  "norm_g": P(None)}
+        if hybrid:
+            from deepspeed_tpu.models import kda
+
+            specs["attn_blocks"] = self._mixer_specs()
+            specs["kda_blocks"] = kda.leaf_specs()
         if c.n_dense_layers:
             specs["dense_blocks"] = self._stack_specs(False)
         if not c.tie_embeddings:
@@ -454,22 +580,66 @@ class LlamaModel:
         return causal_attention(q, k, v, use_flash=c.use_flash_attention,
                                 sequence_parallel=c.sequence_parallel)
 
-    def _stacks(self, params):
-        """The trunk's stacks in order: (stacked blocks, index of the
-        stack's first layer in the model and in the cache)."""
+    def _stacks(self, params, split_experts=True):
+        """The trunk's stacks in order, each as ``(xs, experts, first, view)``:
+        ``xs`` is what a scan over the stack's PERIODS of the layer pattern
+        slices, ``experts`` the stacked ``(L, E, ...)`` expert leaves it must
+        not (None for a dense stack, and where ``split_experts`` is false:
+        the trunk under ``loss`` slices every leaf a layer), ``first`` the index of the stack's
+        first layer in the model and in the cache, and ``view(per, j, i)`` the
+        block — a dict of one layer's leaves — of layer j + i of a period
+        ``per`` (j static, i a traced offset inside a run of one kind). With one kind of mixer a period is a layer and ``xs`` the
+        stacked blocks themselves. With a layer pattern ``xs`` holds the
+        leaves every layer has regrouped ``(L / p, p, ...)`` (a reshape of
+        the leading axis: no copy) and each kind of mixer's own stack
+        regrouped by what a period holds of it."""
         c = self.config
-        stacks = [(params["blocks"], c.n_dense_layers)]
-        if c.n_dense_layers:
-            stacks.insert(0, (params["dense_blocks"], 0))
-        return stacks
+        blocks, experts = self._split_experts(params["blocks"]) \
+            if split_experts else (params["blocks"], None)
+        if c.gqa_layers is None:
+            stacks = [(blocks, experts, c.n_dense_layers,
+                       lambda per, j, i=0: per)]
+            if c.n_dense_layers:
+                stacks.insert(0, (params["dense_blocks"], None, 0,
+                                  lambda per, j, i=0: per))
+            return stacks
+        pattern = c.pattern
+        group = lambda tree, each: jax.tree.map(
+            lambda a: a.reshape(a.shape[0] // each, each, *a.shape[1:]), tree)
+        xs = {"all": group(blocks, len(pattern)),
+              "attn": group(params["attn_blocks"], pattern.count("attn")),
+              "kda": group(params["kda_blocks"], pattern.count("kda"))}
+
+        def view(per, j, i=0):
+            kind = pattern[j]
+            mine = pattern[:j].count(kind)
+            return {**jax.tree.map(lambda a: a[j + i], per["all"]),
+                    **jax.tree.map(lambda a: a[mine + i], per[kind])}
+
+        return [(xs, experts, 0, view)]
+
+    def _layer_at(self, n, j, i=0):
+        """For layer j + i of period ``n`` (n, i traced) of a stack: (its
+        index in the stack, its index among the stack's layers of ITS kind —
+        the layer axis of that kind's cache arrays)."""
+        pattern = self.config.pattern
+        if len(pattern) == 1:
+            return n, n
+        return n * len(pattern) + j + i, \
+            n * pattern.count(pattern[j]) + pattern[:j].count(pattern[j]) + i
 
     def _rope(self, positions):
+        """(cos, sin), or (None, None) for a model without a positional
+        embedding (``use_rope`` false)."""
         c = self.config
+        if not c.use_rope:
+            return None, None
         return _rope_cos_sin(positions, c.rope_dim, c.rope_theta,
                              c.rope_scaling)
 
     def _block_qkv(self, x, blk, cos, sin):
-        """One GQA block's RoPE'd q, k, v for the current x."""
+        """One GQA block's q, k, v for the current x, rotated where the
+        model has a rotary embedding."""
         c = self.config
         B, T, D = x.shape
         h = self._rms_norm(x, blk["attn_norm_g"])
@@ -483,7 +653,20 @@ class LlamaModel:
         q = q.reshape(B, T, c.n_head, c.head_dim)
         k = k.reshape(B, T, c.n_kv_head, c.head_dim)
         v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, c.n_kv_head, c.head_dim)
+        if cos is None:
+            return q, k, v
         return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+
+    def _gated(self, attn, x, blk):
+        """The softmax output (B, T, H, Dh) times ``sigmoid(h attn_gate_w)``,
+        one gate a head channel, from the block's normed input h (the one
+        q, k and v came from) — where the block holds that leaf."""
+        if "attn_gate_w" not in blk:
+            return attn
+        hd = self._rms_norm(x, blk["attn_norm_g"]).astype(self.config.dtype)
+        gate = jax.nn.sigmoid((hd @ blk["attn_gate_w"].astype(hd.dtype)
+                               ).astype(jnp.float32)).reshape(attn.shape)
+        return (attn * gate).astype(attn.dtype)
 
     def _block_latent(self, x, blk, cos, sin):
         """A latent-attention block's queries and its ONE cached row a
@@ -512,7 +695,8 @@ class LlamaModel:
         c = self.config
         if "kv_a_w" not in blk:
             q, k, v = self._block_qkv(x, blk, *cos_sin)
-            return attention(q, self._repeat_kv(k), self._repeat_kv(v)), (k, v)
+            attn = attention(q, self._repeat_kv(k), self._repeat_kv(v))
+            return self._gated(attn, x, blk), (k, v)
         # un-absorbed: every head's key and value expanded from the latent
         # row (q.k at nope + rope columns, v at its own width); absorbing
         # here would cost (C + rope + C) / (nope + rope + v) = 3.4 x the FLOPs
@@ -545,7 +729,7 @@ class LlamaModel:
             # materialized (grouped einsum or the Pallas streaming kernel)
             attn = cached_decode_attention(q[:, 0], cache_k, cache_v, layer,
                                            pos, c.n_kv_head)
-            return attn[:, None], (cache_k, cache_v)
+            return self._gated(attn[:, None], x, blk), (cache_k, cache_v)
         # absorbed: q.k_nope = (q_nope W_UK^T).c_kv and p.v = (p.c_kv) W_UV,
         # so the scores and the weighted sum are over the latent rows
         # themselves, read once for all heads
@@ -633,7 +817,18 @@ class LlamaModel:
         return x + out, stats
 
     def _block(self, x, blk, cos_sin):
-        attn, _ = self._attend(x, blk, cos_sin, self._causal)
+        """One layer of the trunk: a new sequence, nothing kept of it."""
+        if "kda_qkv_w" in blk:
+            from deepspeed_tpu.models import kda
+
+            c = self.config
+            fresh = kda.init_state(c, 1, x.shape[0])
+            attn, _, _ = kda.mix(
+                c, self._rms_norm(x, blk["attn_norm_g"]), blk,
+                fresh["kda_conv"][0], fresh["kda_state"][0],
+                differentiable=True)
+        else:
+            attn, _ = self._attend(x, blk, cos_sin, self._causal)
         return self._block_finish(x, blk, attn)
 
     def _trunk(self, params, input_ids, rng=None, with_router_stats=False):
@@ -641,18 +836,30 @@ class LlamaModel:
         B, T = input_ids.shape
         x = params["wte"].astype(c.dtype)[input_ids]
         cos_sin = self._rope(jnp.arange(T))
+        pattern = c.pattern
 
         block_fn = remat_wrap(self._block, c.remat)
-
-        def scan_body(carry, blk):
-            return block_fn(carry, blk, cos_sin)
 
         # overridable layer scan (overlap engine's ZeRO-3 gather prefetch;
         # a plain lax.scan when nothing is installed)
         from deepspeed_tpu.models.common import layer_scan
 
-        for blocks, _ in self._stacks(params):
-            x, stats = layer_scan(scan_body, x, blocks)     # the last: routed
+        for xs, _, _, view in self._stacks(params, split_experts=False):
+
+            def scan_body(carry, per):
+                if len(pattern) == 1:
+                    return block_fn(carry, per, cos_sin)
+                stats = []
+                for j in range(len(pattern)):
+                    carry, st = block_fn(carry, view(per, j), cos_sin)
+                    stats.append(st)
+                return carry, None if stats[0] is None else \
+                    jax.tree.map(lambda *a: jnp.stack(a), *stats)
+
+            x, stats = layer_scan(scan_body, x, xs)     # the last: routed
+        if stats is not None and len(pattern) > 1:      # (L / p, p, ..) -> L
+            stats = jax.tree.map(
+                lambda a: a.reshape(-1, *a.shape[2:]), stats)
         x = self._rms_norm(x, params["norm_g"])
         return (x, stats) if with_router_stats else x
 
@@ -694,13 +901,27 @@ class LlamaModel:
         return (1, c.latent_dim, ("kv",)) if c.mla \
             else (c.n_kv_head, c.head_dim, ("k", "v"))
 
+    def _cache_names(self):
+        """The cache's arrays that ride the layer scan's carry."""
+        rows = self._cache_layout()[2]
+        if self.config.gqa_layers is None:
+            return rows
+        from deepspeed_tpu.models import kda
+
+        return rows + kda.STATE_LEAVES
+
     def init_cache(self, batch_size: int, max_len: int):
         """KV cache holds only the KV heads, folded into lane-dense rows:
         (L, B, max_len, W) (models/common.py ``init_kv_cache``) — the GQA
         memory win over the reference's full-head InferenceContext workspace
         (csrc/transformer/inference/includes/inference_context.h:287). A
         latent-attention model caches ONE array ``kv`` of that form, nothing
-        per head. A routed model's cache also carries ``expert_tokens``
+        per head. A model with a layer pattern holds TWO kinds of cache in
+        the one dict: those rows over its SOFTMAX layers only, ``(L_softmax,
+        B, max_len, W)``, and what each KDA layer keeps of a sequence
+        whatever its length (``models/kda.py::init_state``): ``kda_state``
+        (L_kda, B, H, dk, dv) float32 and ``kda_conv`` (L_kda, B, taps - 1,
+        3 H dk). A routed model's cache also carries ``expert_tokens``
         (L_routed, E held) int32: the (token, expert) pairs each held expert
         has been given since the prompt's first token — summed by the
         compiled programs themselves (the front-end reads them back when a
@@ -709,8 +930,13 @@ class LlamaModel:
 
         c = self.config
         n_kv, dim, rows = self._cache_layout()
-        cache = init_kv_cache(c.n_layer, batch_size, max_len, n_kv, dim,
-                              c.dtype, rows=rows)
+        cache = init_kv_cache(c.n_attn_layers, batch_size, max_len, n_kv,
+                              dim, c.dtype, rows=rows)
+        if c.gqa_layers is not None:
+            from deepspeed_tpu.models import kda
+
+            cache.update(kda.init_state(c, c.n_layer - c.n_attn_layers,
+                                        batch_size))
         if c.n_experts:
             cache["expert_tokens"] = jnp.zeros((c.n_moe_layers, c.n_held),
                                                jnp.int32)
@@ -721,44 +947,122 @@ class LlamaModel:
 
         n_kv, dim, rows = self._cache_layout()
         specs = kv_cache_partition_specs(n_kv, dim, rows=rows)
+        if self.config.gqa_layers is not None:
+            from deepspeed_tpu.models import kda
+
+            specs.update(kda.state_specs())
         if self.config.n_experts:
             specs["expert_tokens"] = P()
         return specs
 
+    def _mix_cached(self, x, blk, cos_sin, caches, at, pos, attention=None):
+        """A layer's mixer on x against the cache, by the leaves the block
+        holds -> (what ``_block_finish`` takes, the caches). ``caches``: the
+        arrays of ``_cache_names`` (the rows a position first); ``at``: the
+        layer's index in its own kind's arrays. ``attention`` (prefill): x is
+        a whole prompt, written from slot 0 on; None (decode): x is the one
+        new position ``pos``. A KDA layer continues the window and the state
+        the cache holds for it — zeros for a new sequence — and puts back
+        what the last position left."""
+        n_rows = len(self._cache_layout()[2])
+        if "kda_qkv_w" not in blk:
+            if attention is None:
+                attn, rows = self._attend_cached(x, blk, cos_sin,
+                                                 caches[:n_rows], at, pos)
+            else:
+                from deepspeed_tpu.models.common import kv_cache_write
+
+                attn, kept = self._attend(x, blk, cos_sin, attention)
+                rows = tuple(kv_cache_write(held, t, at, 0)
+                             for held, t in zip(caches, kept))
+            return attn, rows + caches[n_rows:]
+        from deepspeed_tpu.models import kda
+
+        states, tails = caches[n_rows:]
+        layer_of = lambda a: jax.lax.dynamic_index_in_dim(a, at, 0,
+                                                          keepdims=False)
+        attn, tail, state = kda.mix(
+            self.config, self._rms_norm(x, blk["attn_norm_g"]), blk,
+            layer_of(tails), layer_of(states))
+        put = lambda a, new: jax.lax.dynamic_update_index_in_dim(
+            a, new.astype(a.dtype), at, 0)
+        return attn, caches[:n_rows] + (put(states, state), put(tails, tail))
+
+    def _run_cached(self, params, x, cache, cos_sin, pos, attention=None):
+        """x through every layer against the cache (``_mix_cached``) -> (x,
+        the cache's carried arrays by name, the pairs each held expert was
+        given (L_routed, E held) or None). As in gpt2.decode_step the
+        stacked cache rides the scan CARRY and each layer updates its own
+        part in place, whichever stack and kind it is of; the stacked expert
+        leaves stay out of the scan's sliced operands. A period's consecutive
+        layers of ONE kind (three KDA layers) are an inner scan whose body
+        indexes the period's leaves by layer, so a kind's layer is compiled
+        once and not once a layer (a prompt length's program must compile
+        inside a request's deadline: 32 s -> 24 s for an 8,192-token
+        prefill). The compiled decode step then shows a ``dynamic-slice`` a
+        stacked leaf a layer where the unrolled form shows none; on the chip
+        it is no copy (a tick of 16 steps: 39.7 ms on the device with the
+        run as a loop, 42.5 ms unrolled; PERF.md, PR 33)."""
+        names = self._cache_names()
+        pattern = self.config.pattern
+        # (first layer, layers) of each run of one kind in a period
+        sizes = [len(list(same)) for _, same in itertools.groupby(pattern)]
+        runs = list(zip(itertools.accumulate([0] + sizes), sizes))
+        caches, routed = tuple(cache[n] for n in names), None
+        for xs, experts, first, view in self._stacks(params):
+
+            def layer(x, caches, per, n, j, i=0):
+                blk = view(per, j, i)
+                at, mine = self._layer_at(n, j, i)
+                attn, caches = self._mix_cached(
+                    x, blk, cos_sin, caches, first + mine, pos, attention)
+                x, stats = self._block_finish(x, blk, attn, experts, at)
+                return x, caches, None if stats is None else stats[0]
+
+            def body(carry, at):
+                x, caches = carry
+                per, n = at
+                if len(pattern) == 1:
+                    x, caches, given = layer(x, caches, per, n, 0)
+                    return (x, caches), given
+                given = []
+                for j, count in runs:
+                    if count == 1:
+                        x, caches, g = layer(x, caches, per, n, j)
+                        given.append(None if g is None else g[None])
+                        continue
+
+                    def one_of_run(carry, i):
+                        x, caches, g = layer(*carry, per, n, j, i)
+                        return (x, caches), g
+
+                    (x, caches), g = jax.lax.scan(
+                        one_of_run, (x, caches), jnp.arange(count))
+                    given.append(g)
+                return (x, caches), None if given[0] is None \
+                    else jnp.concatenate(given)
+
+            n = next(iter(jax.tree.leaves(xs))).shape[0]
+            (x, caches), routed = jax.lax.scan(
+                body, (x, caches), (xs, jnp.arange(n)))
+        if routed is not None and len(pattern) > 1:
+            routed = routed.reshape(-1, routed.shape[-1])
+        return x, dict(zip(names, caches)), routed
+
     def prefill(self, params, input_ids, cache):
         """Process the prompt, fill the cache, return last-position logits."""
-        from deepspeed_tpu.models.common import (kv_cache_write,
-                                                 local_causal_attention)
+        from deepspeed_tpu.models.common import local_causal_attention
 
         c = self.config
         B, T = input_ids.shape
-        names = self._cache_layout()[2]
         x = params["wte"].astype(c.dtype)[input_ids]
-        cos_sin = self._rope(jnp.arange(T))
         attention = lambda q, k, v: local_causal_attention(
             q, k, v, c.use_flash_attention)
-
-        # as in decode_step: the stacked cache rides the scan CARRY and each
-        # layer writes its T rows into it in place, whichever stack it is of
-        caches, routed = tuple(cache[n] for n in names), None
-        for stack, first in self._stacks(params):
-            blocks, experts = self._split_experts(stack)
-
-            def body(carry, xs):
-                x, caches = carry
-                blk, l = xs
-                attn, kept = self._attend(x, blk, cos_sin, attention)
-                caches = tuple(kv_cache_write(held, t, first + l, 0)
-                               for held, t in zip(caches, kept))
-                x, stats = self._block_finish(x, blk, attn, experts, l)
-                return (x, caches), None if stats is None else stats[0]
-
-            n = next(iter(blocks.values())).shape[0]
-            (x, caches), routed = jax.lax.scan(
-                body, (x, caches), (blocks, jnp.arange(n)))
+        x, out, routed = self._run_cached(
+            params, x, cache, self._rope(jnp.arange(T)), 0, attention)
         x = self._rms_norm(x, params["norm_g"])
         logits = (x[:, -1] @ self._head(params, x.dtype)).astype(jnp.float32)
-        out = dict(zip(names, caches), pos=jnp.int32(T))
+        out["pos"] = jnp.int32(T)
         if routed is not None:
             out["expert_tokens"] = routed
         return logits, out
@@ -766,33 +1070,13 @@ class LlamaModel:
     def decode_step(self, params, token, cache):
         """One token for every sequence: (B,) → logits (B, V), cache advanced."""
         c = self.config
-        B = token.shape[0]
         pos = cache["pos"]
-        names = self._cache_layout()[2]
         x = params["wte"].astype(c.dtype)[token][:, None]   # (B, 1, D)
-        cos_sin = self._rope(pos[None])
-
-        # stacked cache rides the scan CARRY (in-place per-layer DUS); the
-        # xs/ys layout made lax.scan assemble a fresh stacked cache buffer
-        # every decode step — see gpt2.decode_step for the measured cost
-        caches, routed = tuple(cache[n] for n in names), None
-        for stack, first in self._stacks(params):
-            blocks, experts = self._split_experts(stack)
-
-            def body(carry, xs):
-                x, caches = carry
-                blk, l = xs
-                attn, caches = self._attend_cached(x, blk, cos_sin, caches,
-                                                   first + l, pos)
-                x, stats = self._block_finish(x, blk, attn, experts, l)
-                return (x, caches), None if stats is None else stats[0]
-
-            n = next(iter(blocks.values())).shape[0]
-            (x, caches), routed = jax.lax.scan(
-                body, (x, caches), (blocks, jnp.arange(n)))
+        x, out, routed = self._run_cached(params, x, cache,
+                                          self._rope(pos[None]), pos)
         x = self._rms_norm(x, params["norm_g"])
         logits = (x[:, 0] @ self._head(params, x.dtype)).astype(jnp.float32)
-        out = dict(zip(names, caches), pos=pos + 1)
+        out["pos"] = pos + 1
         if routed is not None:
             out["expert_tokens"] = cache["expert_tokens"] + routed
         return logits, out

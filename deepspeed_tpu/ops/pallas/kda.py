@@ -1,0 +1,311 @@
+"""Kimi Delta Attention (KDA): the gated delta rule with a PER-CHANNEL decay,
+as a recurrence (decode), as its chunked form (prefill) and as the Pallas TPU
+kernel that carries the state from chunk to chunk.
+
+One head holds a state ``S`` (dk, dv), float32. A position ``t`` brings a
+query and key (dk,), L2-normalised by the caller, a value (dv,), a log-decay
+``g_t <= 0`` per channel of dk and a step ``beta_t`` (up to 2: the
+eigenvalues of ``I - beta k k^T`` then lie in [-1, 1])::
+
+    S' = Diag(exp(g_t)) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T        o_t = S_t^T q_t
+
+``recurrent_kda`` is that, token by token (``lax.scan``): the definition,
+what a decode step runs (``kda_step``) and what the chunked form is tested
+against. A prompt of 4k-32k tokens is that many dependent steps, so prefill
+takes the chunked form (chunks of ``CHUNK`` = 64 positions, the family's
+convention: the state pass below is sequential over T / 64 chunks and a
+chunk's quadratic part is 64 x 64; 128 would halve the steps and cost 5x
+the operations of the in-chunk inverse). With ``G_t`` the cumulative
+log-decay inside a chunk and ``S_0`` the state it starts from::
+
+    delta = (I + A)^-1 (beta (V - (K . e^G) S_0))   A_ti = beta_t sum_c k_tc k_ic e^(G_tc - G_ic), i < t
+    O = (Q . e^G) S_0 + Aqk delta                   Aqk_ti = sum_c q_tc k_ic e^(G_tc - G_ic), i <= t
+    S_C = Diag(e^G_C) S_0 + (K . e^(G_C - G))^T delta
+
+``chunk_operands`` computes everything that does not depend on ``S_0`` for
+all chunks at once (matmuls, parallel over chunks; plain XLA): ``U = T beta
+V``, ``W = T beta (K . e^G)`` with ``T = (I + A)^-1``, ``Qg``, ``Kend``,
+``Aqk`` and the chunk's total decay. ``kda_chunk_fwd`` (the kernel; off the
+TPU ``_state_pass_jnp``, the same three lines in ``jnp``, which the kernel
+is tested against) then walks the chunks: grid parallel over batch x head,
+sequential over chunks, the state in a float32 VMEM scratch, emitting every
+position's output and the state after the last one.
+
+**A per-channel decay cannot be factored naively.** ``e^(G_t - G_i)`` as
+``e^G_t x e^-G_i`` overflows float32 inside one chunk (the cumulative
+log-decay reaches -100 and below over 64 steps where a head decays fast;
+``e^88`` is the limit). Every exponent here is a DIFFERENCE of cumulative
+log-decays that is <= 0: the pairs (t, i) of a chunk are split by halving
+(blocks of 64, 32, ... 2 positions; a pair belongs to the level at which t
+falls in the later and i in the earlier half of one block) and a level's
+pairs are taken relative to the boundary between its halves, ``e^(G_t - r)
+x e^(r - G_i)``, both factors <= 1, as ONE matmul a level. Never a
+reciprocal of a decay.
+
+``(I + A)^-1`` is exact in finitely many matmuls, A being strictly lower
+triangular: within diagonal blocks of 16 by the product form of the Neumann
+series, ``(I - D)(I + D^2)(I + D^4)(I + D^8)``, and across the four blocks
+by the same form of the block-nilpotent rest, in float32 (three bf16
+passes a matmul on the MXU).
+
+A prompt that is no whole number of chunks is padded at its tail with
+``beta = 0``, ``g = 0``: the identity, the state does not move. No backward:
+``jax.grad`` works through the ``jnp`` forms (``kernel=False``) and not
+through the kernel.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+CHUNK = 64
+INVERSE_BLOCK = 16      # diagonal blocks of the in-chunk inverse
+# chunks a grid step of the kernel walks: the per-step overhead is paid once
+# for 8 x 64 rows (six operand blocks of <= 128 KB each, twice buffered)
+CHUNKS_PER_STEP = 8
+# float32 operands on the MXU: three bf16 passes (one is the default)
+_PRECISE = jax.lax.Precision.HIGH
+
+
+# --------------------------------------------------------------- recurrence
+def kda_step(q, k, v, g, beta, state):
+    """One position of the recurrence for every row and head. q, k, g
+    (B, H, dk), v (B, H, dv), beta (B, H), state (B, H, dk, dv) float32 ->
+    (o (B, H, dv) float32, the new state). A rank-1 update, in float32."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (t.astype(f32) for t in (q, k, v, g, beta))
+    state = state * jnp.exp(g)[..., None]
+    delta = beta[..., None] * (v - jnp.einsum("bhkv,bhk->bhv", state, k))
+    state = state + k[..., None] * delta[..., None, :]
+    return jnp.einsum("bhkv,bhk->bhv", state, q), state
+
+
+def recurrent_kda(q, k, v, g, beta, state=None):
+    """The definition, token by token. q, k, g (B, T, H, dk), v (B, T, H,
+    dv), beta (B, T, H); ``state`` (B, H, dk, dv) float32, None = zeros.
+    -> (o (B, T, H, dv) float32, the state after the last position)."""
+    B, T, H, dk = q.shape
+    if state is None:
+        state = jnp.zeros((B, H, dk, v.shape[-1]), jnp.float32)
+
+    def step(state, at):
+        o, state = kda_step(*at, state)
+        return state, o
+
+    state, o = jax.lax.scan(
+        step, state, tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1), state
+
+
+# ------------------------------------------------ a chunk without its state
+def _decayed_scores(q, k, G, dtype):
+    """q, k, G (..., C, dk) float32, G the cumulative log-decay of the chunk
+    -> (``sum_c q_tc k_ic e^(G_tc - G_ic)`` for i <= t, the same of k with k
+    for i < t; zeros elsewhere), (..., C, C) float32 each. By halving: see
+    the module's docstring. The matmuls take their operands in ``dtype``."""
+    C = q.shape[-2]
+    at = jnp.arange(C)
+    rows = jnp.concatenate([q, k], axis=-2)                 # (..., 2C, dk)
+    scores = jnp.zeros((*q.shape[:-2], 2 * C, C), jnp.float32)
+    half = C // 2
+    while half >= 1:
+        block = 2 * half
+        # the boundary: G at the last position of each block's earlier half
+        ref = jnp.repeat(G[..., half - 1::block, :], block, axis=-2)
+        late = ((at % block) >= half)[:, None]
+        row_decay = jnp.exp(jnp.where(late, G - ref, -jnp.inf))
+        col_decay = jnp.exp(jnp.where(late, -jnp.inf, ref - G))
+        level = jnp.einsum(
+            "...td,...id->...ti",
+            (rows * jnp.concatenate([row_decay] * 2, axis=-2)).astype(dtype),
+            (k * col_decay).astype(dtype),
+            preferred_element_type=jnp.float32)
+        same = at[:, None] // block == at[None, :] // block
+        scores = scores + jnp.where(jnp.tile(same, (2, 1)), level, 0.0)
+        half //= 2
+    own = jnp.sum(q.astype(dtype).astype(jnp.float32)
+                  * k.astype(dtype).astype(jnp.float32), axis=-1)
+    return scores[..., :C, :] + own[..., None] * jnp.eye(C), scores[..., C:, :]
+
+
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` (..., C, C) strictly lower triangular, in
+    float32 (three bf16 passes a matmul on a TPU: 2^-16, far below the bf16
+    operands around it): exact in finitely many matmuls (the module's
+    docstring)."""
+    C = a.shape[-1]
+    mm = functools.partial(jnp.matmul, precision=_PRECISE)
+    eye = jnp.eye(C, dtype=jnp.float32)
+    at = jnp.arange(C) // min(INVERSE_BLOCK, C)
+    diag = jnp.where(at[:, None] == at[None, :], a, 0.0)
+
+    def neumann(x, order):
+        # sum_{n < order} (-x)^n = (I - x)(I + x^2)(I + x^4) ... : the
+        # squarings are one loop body, compiled once
+        steps = max(0, math.ceil(math.log2(order)) - 1)
+
+        def square(_, held):
+            inv, power = held
+            power = mm(power, power)
+            return mm(inv, eye + power), power
+
+        return jax.lax.fori_loop(0, steps, square, (eye - x, x))[0]
+
+    inv_diag = neumann(diag, min(INVERSE_BLOCK, C))
+    # I + a = (I + diag)(I + rest), rest block-strictly-lower
+    rest = mm(inv_diag, a - diag)
+    return mm(neumann(rest, -(-C // INVERSE_BLOCK)), inv_diag)
+
+
+def chunk_operands(q, k, v, g, beta, chunk=CHUNK):
+    """What the state pass needs of every chunk, computed for all chunks at
+    once. q, k, g (B, T, H, dk), v (B, T, H, dv), beta (B, T, H); T is
+    padded to whole groups of ``CHUNKS_PER_STEP`` chunks, or to whole chunks
+    where it is shorter than one group (``beta`` = 0, ``g`` = 0: identity
+    chunks). -> ``u`` (dv), ``w``,
+    ``qg``, ``kend`` (dk) as (B * H, T', .) and ``aqk`` (B * H, T', chunk)
+    in v's type, ``decay`` (B * H, T' / chunk, dk) float32."""
+    B, T, H, dk = q.shape
+    dtype, f32 = v.dtype, jnp.float32
+    n = -(-T // chunk)
+    n = -(-n // min(CHUNKS_PER_STEP, n)) * min(CHUNKS_PER_STEP, n)
+    pad = lambda t: jnp.pad(t.astype(f32), ((0, 0), (0, n * chunk - T))
+                            + ((0, 0),) * (t.ndim - 2))
+    # (B, H, n, chunk, .)
+    cut = lambda t: jnp.moveaxis(pad(t), 2, 1).reshape(
+        B, H, n, chunk, *t.shape[3:])
+    q, k, v, g, beta = (cut(t) for t in (q, k, v, g, beta))
+    G = jnp.cumsum(g, axis=-2)
+    aqk, akk = _decayed_scores(q, k, G, dtype)
+    t_inv = _unit_lower_inverse(beta[..., None] * akk)
+    grown = jnp.exp(G)
+    solved = jnp.matmul(t_inv, jnp.concatenate(
+        [beta[..., None] * k * grown, beta[..., None] * v], axis=-1),
+        precision=_PRECISE)
+    total = G[..., -1:, :]
+    flat = lambda t: t.astype(dtype).reshape(B * H, n * chunk, t.shape[-1])
+    return {"u": flat(solved[..., dk:]), "w": flat(solved[..., :dk]),
+            "qg": flat(q * grown), "kend": flat(k * jnp.exp(total - G)),
+            "aqk": flat(aqk),
+            "decay": jnp.exp(total[..., 0, :]).reshape(B * H, n, dk)}
+
+
+# ------------------------------------------------------------ the state pass
+def _chunk_update(u, w, qg, kend, aqk, decay, state_t):
+    """One chunk given the state it starts from, TRANSPOSED: ``state_t``
+    (dv, dk) float32 (the decay then runs along the lanes). -> (o (chunk,
+    dv) float32, the transposed state after the chunk). Matmuls take the
+    operands' type (the state rounded to it), sums are float32."""
+    f32 = jnp.float32
+    nt = (((1,), (1,)), ((), ()))
+    held = state_t.astype(w.dtype)
+    delta = u.astype(f32) - jax.lax.dot_general(
+        w, held, nt, preferred_element_type=f32)
+    rounded = delta.astype(w.dtype)
+    o = jax.lax.dot_general(qg, held, nt, preferred_element_type=f32) \
+        + jnp.dot(aqk, rounded, preferred_element_type=f32)
+    state_t = state_t * decay + jax.lax.dot_general(
+        rounded, kend, (((0,), (0,)), ((), ())), preferred_element_type=f32)
+    return o, state_t
+
+
+def _state_pass_jnp(ops, state, chunk):
+    """``lax.scan`` over the chunks: the plain form of ``kda_chunk_fwd``."""
+    BH, Tp, _ = ops["u"].shape
+    n = Tp // chunk
+    chunks = lambda t: jnp.moveaxis(
+        t.reshape(BH, n, chunk, t.shape[-1]), 1, 0)
+    xs = tuple(chunks(ops[name]) for name in ("u", "w", "qg", "kend", "aqk")) \
+        + (jnp.moveaxis(ops["decay"], 1, 0)[:, :, None, :],)
+
+    def step(state_t, at):
+        o, state_t = jax.vmap(_chunk_update)(*at, state_t)
+        return state_t, o
+
+    state_t, o = jax.lax.scan(step, jnp.swapaxes(state, -1, -2), xs)
+    return jnp.moveaxis(o, 0, 1).reshape(BH, Tp, -1).astype(ops["u"].dtype), \
+        jnp.swapaxes(state_t, -1, -2)
+
+
+def _kda_chunk_kernel(u_ref, w_ref, qg_ref, kend_ref, aqk_ref, decay_ref,
+                      s0_ref, o_ref, s_ref, state_sc, *, chunk: int,
+                      chunks: int):
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _start():
+        state_sc[:] = s0_ref[0]
+
+    for c in range(chunks):                 # static: a step's chunks in turn
+        rows = pl.ds(c * chunk, chunk)
+        o, state_t = _chunk_update(
+            u_ref[0, rows, :], w_ref[0, rows, :], qg_ref[0, rows, :],
+            kend_ref[0, rows, :], aqk_ref[0, rows, :],
+            decay_ref[0, pl.ds(c, 1), :], state_sc[:])
+        o_ref[0, rows, :] = o.astype(o_ref.dtype)
+        state_sc[:] = state_t
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _end():
+        s_ref[0] = state_sc[:]
+
+
+def _state_pass_kernel(ops, state, chunk):
+    """``kda_chunk_fwd``: the grid is (batch x head, groups of chunks), the
+    second axis sequential; the state lives transposed, (dv, dk) float32, in
+    VMEM from a head's first chunk to its last."""
+    BH, Tp, dv = ops["u"].shape
+    dk = ops["w"].shape[-1]
+    n = Tp // chunk
+    per = min(CHUNKS_PER_STEP, n)           # chunk_operands padded to it
+    rows = per * chunk
+    block = lambda width: pl.BlockSpec((1, rows, width), lambda b, j: (b, j, 0))
+    whole = lambda *shape: pl.BlockSpec((1, *shape), lambda b, j: (b, 0, 0))
+    item = ops["u"].dtype.itemsize
+    o, state_t = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, chunk=chunk, chunks=per),
+        grid=(BH, n // per),
+        in_specs=[block(dv), block(dk), block(dk), block(dk), block(chunk),
+                  pl.BlockSpec((1, per, dk), lambda b, j: (b, j, 0)),
+                  whole(dv, dk)],
+        out_specs=[block(dv), whole(dv, dk)],
+        out_shape=[jax.ShapeDtypeStruct((BH, n * chunk, dv), ops["u"].dtype),
+                   jax.ShapeDtypeStruct((BH, dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(BH * n * chunk * (6 * dk * dv + 2 * chunk * dv)),
+            bytes_accessed=int(BH * n * chunk * item
+                               * (2 * dv + 3 * dk + chunk)),
+            transcendentals=0),
+        name="kda_chunk_fwd",
+    )(ops["u"], ops["w"], ops["qg"], ops["kend"], ops["aqk"], ops["decay"],
+      jnp.swapaxes(state, -1, -2))
+    return o, jnp.swapaxes(state_t, -1, -2)
+
+
+def chunked_kda(q, k, v, g, beta, state=None, chunk=CHUNK, kernel=False):
+    """The chunked form over T positions. Shapes as ``recurrent_kda``; ->
+    (o (B, T, H, dv) in v's type, the state (B, H, dk, dv) float32 after
+    position T - 1). ``kernel``: the state pass as the Pallas kernel (a
+    program for a TPU, or the interpreter in a test) or as its ``jnp`` form
+    (everywhere else; differentiable). The chunks' operands exist for all T
+    positions at once, ~45 float32 values a channel a position (3 GB at
+    4,096 positions of 64 heads x 128): a caller with a long prompt walks it
+    in segments and hands the state on (``models/kda.py::mix``)."""
+    B, T, H, dk = q.shape
+    dv = v.shape[-1]
+    state = jnp.zeros((B * H, dk, dv), jnp.float32) if state is None \
+        else state.astype(jnp.float32).reshape(B * H, dk, dv)
+    o, state = (_state_pass_kernel if kernel else _state_pass_jnp)(
+        chunk_operands(q, k, v, g, beta, chunk), state, chunk)
+    return jnp.moveaxis(o[:, :T].reshape(B, H, T, dv), 1, 2), \
+        state.reshape(B, H, dk, dv)

@@ -74,9 +74,13 @@ class Request:
     prefill_done_at: Optional[float] = None   # the prefill tick returned
     first_tokens_at: Optional[float] = None   # the first token is on the host
     decode_ticks: int = 0             # decode chunks that ran to their end
+    compile_s: float = 0.0            # spent in ticks that compiled their program
     # bytes one position of one sequence holds in the cache the prefill
-    # handed back, all layers, pad lanes and all (its (L, B, S, W) arrays)
+    # handed back, all layers, pad lanes and all (its (L, B, S, W) arrays),
+    # and bytes one sequence holds there whatever its length (a KDA layer's
+    # state): ``models/common.py::cache_footprint``
     cache_position_bytes: int = 0
+    cache_state_bytes: int = 0
     finished_at: Optional[float] = None
     ttft_s: Optional[float] = None    # first_tokens_at - submitted_at
     _done: threading.Event = dataclasses.field(default_factory=threading.Event,

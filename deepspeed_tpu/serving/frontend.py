@@ -550,6 +550,8 @@ class ServingFrontEnd:
                              ("tick_return", ready, tick.t1)):
             tracer.record(name, t0, t1, cat="serving", parent=tick,
                           request=req.id)
+        if cold:
+            req.compile_s += tick.dur
         if phase == "prefill":
             req.prefill_done_at = tick.t1
         self._reg().histogram(
@@ -581,10 +583,14 @@ class ServingFrontEnd:
                     first_tokens_at=req.first_tokens_at,
                     # what the request's context cost the cache: positions
                     # its programs wrote (a sequence) and the bytes the
-                    # cache's own arrays hold for them
+                    # cache's own arrays hold for them; beside them what a
+                    # sequence keeps WHATEVER its length (a KDA layer's
+                    # state)
                     cache_positions=positions,
                     cache_bytes=positions * int(req.prompt.shape[0])
-                    * req.cache_position_bytes)
+                    * req.cache_position_bytes,
+                    state_bytes=int(req.prompt.shape[0])
+                    * req.cache_state_bytes)
 
     def _cache_positions(self, req: Request) -> int:
         """Positions of a sequence the request's programs have run: the
@@ -596,6 +602,8 @@ class ServingFrontEnd:
 
     def _serve(self, req: Request, tracer) -> None:
         import jax
+
+        from deepspeed_tpu.models.common import cache_footprint
 
         req.status = "running"
         reg = self._reg()
@@ -617,9 +625,10 @@ class ServingFrontEnd:
             tok, cache, done, rng = self._tick(
                 req, lambda: prefill(self.engine.params, ids, rng),
                 warm_key=("prefill", pkey, ids.shape[1]))
-            req.cache_position_bytes = sum(
-                x.shape[0] * x.shape[3] * x.dtype.itemsize
-                for x in jax.tree.leaves(cache) if x.ndim == 4)
+            # told apart by what the model's cache says of itself (the
+            # names of its leaves), not by the number of dimensions
+            req.cache_position_bytes, req.cache_state_bytes = \
+                cache_footprint(cache)
             # prefill chose the first token: it leaves now, alone
             finished = self._deliver(req, tok, done, tracer)
             while not finished and len(req.tokens) < req.max_new_tokens:
@@ -727,7 +736,14 @@ class ServingFrontEnd:
             logger.warning(f"serving: stream consumer for {req.id} raised: {e}")
 
     def _observe_service(self, req: Request) -> None:
-        dur = time.monotonic() - req.started_at
+        # admission estimates the wait from what a request's SERVICE takes.
+        # A tick that compiled its program says what a compile takes (a 20 s
+        # compile read as load sheds the next request of a cold start as
+        # "deadline_unreachable"), so such a tick is left out WHOLE — its
+        # run cannot be told from its compile — and the rest of the request
+        # still feeds the estimate: a server whose every prompt length is
+        # new estimates low by a prefill's run, never by nothing
+        dur = time.monotonic() - req.started_at - req.compile_s
         self._service_ema = dur if self._service_ema is None \
             else 0.8 * self._service_ema + 0.2 * dur
         reg = self._reg()

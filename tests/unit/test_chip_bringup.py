@@ -730,6 +730,111 @@ def test_pangu_prefill_for_v5e_keeps_no_square_of_scores(pangu):
     assert total < 15.75 * GIB, total / GIB
 
 
+# ------ Solar-Open2-250B: one chip's share of 8, at the published widths
+def test_kda_chunk_kernel_compiles_for_v5e_uninterpreted(v5e):
+    """The state pass of the chunked form at 64 heads x 128, a segment of
+    2,048 positions and one that is no whole number of chunks: one Mosaic
+    call, the state float32 out."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    mesh = _mesh(v5e)
+    for T in (2048, 64 * 3 + 17):
+        qk = _abstract((1, T, 64, 128), jnp.bfloat16, mesh)
+        g = _abstract((1, T, 64, 128), jnp.float32, mesh)
+        beta = _abstract((1, T, 64), jnp.float32, mesh)
+        state = _abstract((1, 64, 128, 128), jnp.float32, mesh)
+        text = jax.jit(functools.partial(kda.chunked_kda, kernel=True)).lower(
+            qk, qk, qk, g, beta, state).compile().as_text()
+        call, = re.findall(
+            r"%[\w.]*kda_chunk_fwd[\w.]* = [^\n]*tpu_custom_call", text)
+        assert "f32[64,128,128]" in call
+
+
+@pytest.fixture(scope="module")
+def solar(v5e):
+    """(mesh, model, abstract bf16 params, abstract cache, the two serving
+    programs) of the benchmark's configuration on ONE chip: 1 softmax + 3
+    KDA layers, 40 of 320 experts, a 36,864-slot K/V cache of one layer."""
+    from benchmark import manifest as mf
+    from benchmark.families import solar_open2 as family
+    from deepspeed_tpu.inference.engine import build_serving_programs
+
+    mesh = _mesh(v5e)
+    model = family.build_model(mf.load_json(
+        mf.BENCH_DIR / "configs" / "solar-open2-250b.json"), "serve")
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh), shapes)
+    cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
+                         jax.eval_shape(lambda: model.init_cache(1, 36864)))
+    return (mesh, model, params, cache) + build_serving_programs(
+        model, 36864, 16, False, 1.0, 0, 1.0, None)
+
+
+def test_solar_decode_chunk_for_v5e_reads_each_layers_weights_in_place(solar):
+    """One ``decode_attn`` (the ONE softmax layer), the thin grouped matmuls
+    twice each — the softmax layer's, and ONE body for the three KDA layers
+    (a loop over the run) — under a conditional, no KDA kernel (one position
+    is the recurrence itself), and nothing weight-shaped, state-shaped or
+    expert-shaped copied or relaid: no branch runs both mixers, and the
+    (4, 40, ...) expert leaves go whole to the kernels. The loop over the
+    run indexes the three mixer stacks by layer (a ``dynamic-slice`` a leaf
+    in the text; measured on the chip as no copy: PERF.md, PR 33)."""
+    mesh, model, params, cache, _, chunk = solar
+    with mesh:
+        compiled = jax.jit(chunk).lower(
+            params, *_chunk_carry(cache, mesh)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(
+        r"%decode_attn[\w.]* = [^\n]*tpu_custom_call", text)) == 1
+    for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin"):
+        assert len(re.findall(
+            rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text)) == 2, kernel
+    assert "kda_chunk_fwd" not in text and "flash_fwd" not in text
+    assert " conditional(" in text
+    weights = {"bf16[4096,24576]", "bf16[3,4096,24576]", "bf16[8192,4096]",
+               "bf16[3,8192,4096]", "bf16[4096,8192]", "bf16[1,4096,8192]",
+               "bf16[4096,1280]", "bf16[1280,4096]", "bf16[40,4096,1280]",
+               "bf16[40,1280,4096]", "bf16[4,40,4096,1280]"}
+    assert not _moves(text, weights, "copy|transpose|gather")
+    assert not _moves(text, {"bf16[40,4096,1280]", "bf16[40,1280,4096]",
+                             "bf16[4,40,4096,1280]", "bf16[4,40,1280,4096]"})
+    copies = re.findall(r"= (\w+\[[\d,]*\])\S* copy\(", text)
+    assert copies.count("bf16[1,1,36864,1024]") <= 2, copies    # undonated
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.3 * GIB
+    assert total < 15.75 * GIB, total / GIB
+
+
+def test_solar_programs_hand_each_other_a_token(solar):
+    mesh, model, params, cache, prefill, chunk = solar
+    with mesh:
+        _token_in_token_out(prefill, chunk, params, cache, mesh, 4096, 24576)
+
+
+def test_solar_prefill_for_v5e_at_the_longest_prompt(solar):
+    """32,768 tokens: ``kda_chunk_fwd`` in the segment loop, ``flash_fwd`` at
+    head_dim 128, the full-tile grouped matmuls over the stacked share; no
+    (T, T) array and no per-chunk pairwise (64, 64, 128) array anywhere;
+    it fits beside the 6.6 GB of weights."""
+    mesh, model, params, _, prefill, _ = solar
+    with mesh:
+        compiled = jax.jit(prefill).lower(
+            params, _abstract((1, 32768), jnp.int32, mesh),
+            _abstract((2,), jnp.uint32, mesh)).compile()
+    text = compiled.as_text()
+    for kernel in ("kda_chunk_fwd", "moe_gmm_swiglu_full", "moe_gmm_full",
+                   "flash_fwd"):
+        assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
+            kernel
+    assert not re.search(r"\[[\d,]*32768,32768\]", text)
+    assert not re.search(r"\[[\d,]*64,64,128\]", text)
+    assert not _moves(text, {"bf16[40,4096,1280]", "bf16[40,1280,4096]",
+                             "bf16[4,40,4096,1280]", "bf16[4096,1280]"})
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.1 * GIB
+    assert total < 15.75 * GIB, total / GIB
+
+
 def test_routed_experts_on_a_mesh_of_several_chips_take_the_xla_form(v5e):
     """GSPMD cannot partition a Mosaic call: over tensor=4 the experts run
     as ``ragged_dot`` (no kernel, and it compiles); experts over chips are

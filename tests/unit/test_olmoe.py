@@ -306,7 +306,8 @@ def test_the_front_end_reads_the_expert_counter_once_a_request(served):
         want = np.asarray(engine.generate(prompt[None], max_new_tokens=20))
         assert req.tokens == want[0, 20:].tolist()
         mine = [s for s in telemetry.get_tracer().snapshot()
-                if s.name == "moe/expert_tokens" and s.args["request"] == req.id]
+                if s.name == "moe/expert_tokens"
+                and s.args.get("request") == req.id]
         assert len(mine) == 1 and mine[0].cat == "moe"
         counts = np.asarray(mine[0].args["counts"])
         # the prefill's token + ceil(19 / 16) = two 16-step ticks: 20 prompt

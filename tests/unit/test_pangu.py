@@ -423,7 +423,7 @@ def test_the_front_end_reports_the_cache_and_the_share(monkeypatch):
         assert req.tokens == want[0, 20:].tolist()
         spans = telemetry.get_tracer().snapshot()
         mine = [s for s in spans if s.name == "moe/expert_tokens"
-                and s.args["request"] == req.id]
+                and s.args.get("request") == req.id]
         assert len(mine) == 1
         args = mine[0].args
         # 20 prompt + two 16-step ticks = 52 positions, 2 routed layers, top-4

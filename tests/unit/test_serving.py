@@ -272,18 +272,19 @@ class TestSpans:
         import jax
 
         # the cache its positions hold: 8 prompt + 3 ticks of 4 steps, each
-        # the bytes of one row of every (L, B, S, W) array of the cache
-        rows = [x for x in jax.tree.leaves(jax.eval_shape(
-            lambda: engine.module.init_cache(1, 8))) if x.ndim == 4]
-        a_position = sum(x.shape[0] * x.shape[3] * x.dtype.itemsize
-                         for x in rows)
+        # the bytes of one row of the cache's k and v, (L, B, S, W) each;
+        # a model all of whose layers cache rows keeps no state a sequence
+        cache = jax.eval_shape(lambda: engine.module.init_cache(1, 8))
+        a_position = sum(cache[n].shape[0] * cache[n].shape[3]
+                         * cache[n].dtype.itemsize for n in ("k", "v"))
         assert a_position > 0
         assert req.args == {
             "request": r.id, "prompt_len": 8, "new_tokens": 12,
             "status": "completed", "decode_ticks": 3,
             "prefill_done_at": r.prefill_done_at,
             "first_tokens_at": r.first_tokens_at,
-            "cache_positions": 20, "cache_bytes": 20 * a_position}
+            "cache_positions": 20, "cache_bytes": 20 * a_position,
+            "state_bytes": 0}
         (wait,) = [s for s in spans if s.name == "admission_wait"]
         assert (wait.t0, wait.t1) == (r.submitted_at, r.started_at)
         (prefill,) = [s for s in spans if s.name == "prefill"]

@@ -14,20 +14,38 @@ Backward recomputes P from the saved logsumexp:
   P = exp(S - lse); dV = Pᵀ dO; dS = P ∘ (dO Vᵀ - Δ); dQ = dS K; dK = dSᵀ Q
 with Δ = rowsum(dO ∘ O) computed outside the kernel.
 
-Causal execution (the perf-critical path for LM training):
+Causal execution (self-attention; the path every prefill and every train
+step takes). ONE forward kernel for every length, its shapes from
+``flash_forward_plan(t, d, dv, dtype)`` and nothing else:
 
-* **Triangular grid** — when ``block_q == block_k``, the (qi, ki) iteration
-  space is the lower block-triangle ONLY, flattened to a 1-D grid whose
-  block coordinates are looked up from scalar-prefetch arrays
-  (``pltpu.PrefetchScalarGridSpec``). Above-diagonal blocks are never
-  fetched or executed, so causal costs ~half of non-causal in both DMA and
-  grid steps — a ``pl.when`` skip alone saves neither (the pipeline still
-  pays the block DMA).
-* **Diagonal-only masking** — interior blocks (entirely below the diagonal)
-  run a mask-free softmax block; only blocks crossing the diagonal pay the
-  iota/compare/select VPU passes. Flash attention at small head_dim is
-  VPU-bound on TPU (softmax ops ~O(T²) on the 8×128 VPU vs matmul flops
-  O(T²·D) on the MXU), so shaving VPU passes is worth more than it looks.
+* **A grid step is a q block against a SPAN of keys** — up to four square
+  sub-blocks wide (2,048 columns at the default 512; the whole length
+  while that fits). The (q block, span) pairs are a 1-D grid looked up from
+  scalar-prefetch arrays (``pltpu.PrefetchScalarGridSpec``) that list only
+  the spans which begin at or under the q block's last row: a span above the
+  diagonal is never fetched — a ``pl.when`` skip alone would still pay its
+  DMA. What a grid step costs whatever it holds (the pipeline's step, its
+  DMA waits, the branches) is paid once a span, not once a 512 x 512 tile.
+* **Inside a step the span is walked up to the diagonal and no further.**
+  Every walk is unrolled into one basic block, two sub-blocks to a softmax
+  update (one q.k matmul 1,024 columns wide; the max, the rescale and the
+  accumulator's pass at half the rate), so the scheduler lays the next
+  update's matmul beside this one's softmax; the running max, sum and
+  accumulator are values inside a walk. A span wholly under the diagonal is
+  one walk. The span that holds the diagonal walks what lies under it by
+  the binary digits of its count (a walk of 2, a walk of 1: the count is
+  dynamic, each walk's length static), then takes the ONE sub-block the
+  diagonal crosses — the only one to pay the iota / compare / select —
+  with its left neighbour, and writes the q block out. Nothing above the
+  diagonal is run: ``ForwardPlan.sub_blocks_run`` is the causal count,
+  T = 1,024 in 512s runs 3 sub-blocks, 2 of them masked.
+* **Per-row statistics stay lane-broadcast**, (rows, 128) with every lane
+  equal, and meet a (rows, n) operand tiled along the lanes (``_lanes``):
+  a (rows, 1) column pays an XLU broadcast a row group at every use, which
+  measured 2 x the whole kernel's time at T = 16,384.
+* The backward kernels keep their own two grids (``_use_tri``: triangular
+  from four row blocks, else rectangular with a double-width k block under
+  the mask); they read the log-sum-exp by row, whatever block wrote it.
 
 Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
 (B·H, T, D). v may have a head size of its own (latent attention: q.k at 192
@@ -63,7 +81,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -113,24 +131,34 @@ def _padded_len(t: int, preferred: int) -> int:
 
 def flash_supports(t_q: int, t_k: int, causal: bool,
                    block_q: int = None, block_k: int = None) -> bool:
-    """Whether :func:`flash_attention` can tile these lengths. Causal
-    self-attention can at the default blocks, whatever the length (it pads;
-    see ``_padded_len``); the other forms — which have no mask to hide pad
-    keys behind — only at lengths that tile as they are: q in blocks of
-    whole 128s (or one block), k in whole 8s. 576 = 9 x 64 does not, nor
-    does any longer length in blocks of 64."""
+    """Whether :func:`flash_attention` can tile these lengths. Causal means
+    self-attention (one length), and that can at the default blocks,
+    whatever the length (it pads; see ``_padded_len``); the other forms —
+    which have no mask to hide pad keys behind — only at lengths that tile
+    as they are: q in blocks of whole 128s (or one block), k in whole 8s.
+    576 = 9 x 64 does not, nor does any longer length in blocks of 64."""
     block_q = block_q or DEFAULT_BLOCK_Q
     block_k = block_k or DEFAULT_BLOCK_K
-    if causal and t_q == t_k:
+    if causal:
+        if t_q != t_k:
+            return False
         t_q = t_k = _padded_len(t_q, min(block_q, block_k))
     return _tiles(t_q, block_q, _LANES) and _tiles(t_k, block_k, 8)
 
 
+def _causal_spans(rows: int, n_sub: int):
+    """(q block, span) pairs, row-major: for q block i the spans of ``n_sub``
+    sub-blocks that begin at or under its last row, the diagonal's last."""
+    qi = np.concatenate([np.full(i // n_sub + 1, i, np.int32)
+                         for i in range(rows)])
+    si = np.concatenate([np.arange(i // n_sub + 1, dtype=np.int32)
+                         for i in range(rows)])
+    return qi, si
+
+
 def _causal_pairs(nq: int):
     """Lower-triangle block pairs, row-major (ki ascending within each qi)."""
-    qi = np.concatenate([np.full(i + 1, i, np.int32) for i in range(nq)])
-    ki = np.concatenate([np.arange(i + 1, dtype=np.int32) for i in range(nq)])
-    return qi, ki
+    return _causal_spans(nq, 1)
 
 
 def _causal_pairs_colmajor(nq: int):
@@ -141,10 +169,38 @@ def _causal_pairs_colmajor(nq: int):
     return ki, qi
 
 
-def _online_softmax_block(q, k, v, acc_sc, m_sc, l_sc, scale, mask_rc=None):
-    """One FA2 streaming-softmax block update. ``mask_rc`` = (rows, cols)
-    global index iotas when the block crosses the diagonal, else None
-    (interior blocks skip the mask's VPU passes entirely)."""
+def _lanes(x, n: int):
+    """A per-row statistic kept lane-broadcast, (rows, 128) with every lane
+    of a row equal, as (rows, n): itself tiled along the lanes where n is
+    whole 128s (no cross-lane move: a (rows, 1) column would pay an XLU
+    broadcast a row group wherever it meets a (rows, n) operand), a lane
+    slice under 128, a column broadcast for the rest."""
+    if n % _LANES == 0:
+        return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _softmax_update(s, v, m, l, acc):
+    """One FA2 streaming-softmax update in VALUES: scores ``s`` (rows, cols)
+    float32, already masked where the diagonal crosses them, the running max
+    ``m`` and sum ``l`` lane-broadcast (rows, 128), ``acc`` (rows, Dv)
+    float32. ``p`` goes to the MXU in v's dtype."""
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+    corr = jnp.exp(m - m_new)
+    l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+    acc_new = acc * _lanes(corr, acc.shape[1]) + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _scores(q, k, scale, mask_rc=None):
+    """q . k^T in float32; ``mask_rc`` = (rows, cols) index iotas where the
+    block crosses the diagonal, else None (an interior block pays none of
+    the mask's VPU passes)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if scale != 1.0:
@@ -152,17 +208,15 @@ def _online_softmax_block(q, k, v, acc_sc, m_sc, l_sc, scale, mask_rc=None):
     if mask_rc is not None:
         rows, cols = mask_rc
         s = jnp.where(rows >= cols, s, NEG_INF)
-    m_prev = m_sc[:, :1]                       # (bq, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)             # (bq, 1)
-    l_sc[:] = jnp.broadcast_to(l_sc[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-                               l_sc.shape)
-    acc_sc[:] = acc_sc[:] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
+    return s
+
+
+def _online_softmax_block(q, k, v, acc_sc, m_sc, l_sc, scale, mask_rc=None):
+    """``_softmax_update`` on a grid step's own blocks and (rows, 128)
+    scratch: the non-causal and the block-sparse forward, one k block a
+    grid step."""
+    m_sc[:], l_sc[:], acc_sc[:] = _softmax_update(
+        _scores(q, k, scale, mask_rc), v, m_sc[:], l_sc[:], acc_sc[:])
 
 
 def _block_iotas(block_q, block_k, qi, ki):
@@ -175,8 +229,19 @@ def _row(x):
     """(n, 128) with every lane of a row equal -> (1, n), lane-dense: how a
     per-row statistic (the log-sum-exp) leaves a kernel. A (n, 1) output
     pads the 1 to 128 lanes in HBM: 128 x the bytes, written by the kernel
-    and read by whatever takes it next."""
-    return x.T[:1]
+    and read by whatever takes it next. In whole 128s the row is the
+    diagonal of each (128, 128) group, summed down its zeros (exact; a
+    select and fifteen adds a group where the transpose moves every vreg
+    through the XLU)."""
+    n = x.shape[0]
+    if n % _LANES:
+        return x.T[:1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    diagonal = jnp.where((rows & (_LANES - 1)) == cols, x, 0.0)
+    groups = jnp.sum(diagonal.reshape(n // _LANES, _LANES, _LANES), axis=1,
+                     keepdims=True)
+    return jnp.concatenate(list(groups), axis=1)
 
 
 def _col(row):
@@ -185,12 +250,12 @@ def _col(row):
     return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
 
 
-def _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc):
-    """Write a query block's output and log-sum-exp from the running
-    accumulator, max and sum (the last two lane-broadcast, (rows, 128))."""
-    l_safe = jnp.where(l_sc[:] == 0.0, 1.0, l_sc[:])
-    o_ref[0] = (acc_sc[:] / l_safe[:, :1]).astype(o_ref.dtype)
-    lse_ref[0] = _row(m_sc[:] + jnp.log(l_safe))
+def _write_out(o_ref, lse_ref, m, l, acc):
+    """Write a query block's output and log-sum-exp from the running max
+    and sum (lane-broadcast, (rows, 128)) and the accumulator."""
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc / _lanes(l, acc.shape[1])).astype(o_ref.dtype)
+    lse_ref[0] = _row(m + jnp.log(l))
 
 
 def _causal_dispatch(qi, ki, block_q, block_k, compute):
@@ -210,37 +275,170 @@ def _causal_dispatch(qi, ki, block_q, block_k, compute):
         compute(_block_iotas(block_q, block_k, qi, ki))
 
 
-# ------------------------------------------------- forward (causal, tri-grid)
-def _fwd_tri_kernel(qi_arr, ki_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                    acc_sc, m_sc, l_sc, *, scale: float, block: int):
-    f = pl.program_id(1)
-    qi = qi_arr[f]
-    ki = ki_arr[f]
+# ------------------------------------------------------ forward (causal)
+class ForwardPlan(NamedTuple):
+    """The shapes of the causal forward for one (T, D, Dv, dtype), and what
+    its static grid will run; the counts are of ONE (batch x head) row."""
+    block_q: int            # query rows of a grid step
+    span: int               # keys and values a grid step holds
+    sub_block: int          # columns of one pass of the in-step loop
+    grid_steps: int
+    sub_blocks_run: int     # at or under the diagonal: none above it
+    sub_blocks_masked: int  # those the diagonal crosses: one a row of them
 
-    @pl.when(ki == 0)
+
+# a span is at most this many sub-blocks (every walk of it is unrolled: code
+# size and the kernel's compile time grow with it, 1 s at one sub-block a
+# step, 2.5 s at four, 5 s at eight, and a prompt length's prefill compiles
+# inside its first request's deadline; eight measured no faster) and this
+# many bytes of K and V (each double-buffered in VMEM beside the float32
+# score tiles, which at two sub-blocks an update pass the 16 MiB default)
+_SPAN_SUB_BLOCKS = 4
+_SPAN_BYTES = 4 << 20
+_FWD_VMEM_BYTES = 32 << 20
+
+
+def flash_forward_plan(t: int, d: int, dv: int, dtype,
+                       block_q: int = DEFAULT_BLOCK_Q,
+                       block_k: int = DEFAULT_BLOCK_K) -> ForwardPlan:
+    """What the causal forward runs for a call of length ``t`` (padded as
+    ``flash_attention`` pads it), q.k width ``d``, v width ``dv``: the one
+    place the kernel takes its shapes from, a pure function of what the call
+    can see. The sub-block is the square matmul tile (``_pick_block``: the
+    caller's block or the largest halving of it that divides the length),
+    a q block is one row of them, and a span is as many of them as fit the
+    two limits above, evened out over the length (T = 3,072 in 512s: two
+    spans of three, not four and two). The last span of an awkward length
+    may end past the keys: the loop stops at the diagonal, before them."""
+    t = _padded_len(t, min(block_q, block_k))
+    sub = _pick_block(t, min(block_q, block_k))
+    rows = t // sub
+    widest = max(1, min(_SPAN_SUB_BLOCKS, _SPAN_BYTES // (
+        sub * (d + dv) * jnp.dtype(dtype).itemsize)))
+    n_sub = -(-rows // -(-rows // widest))
+    return ForwardPlan(
+        block_q=sub, span=n_sub * sub, sub_block=sub,
+        grid_steps=sum(i // n_sub + 1 for i in range(rows)),
+        sub_blocks_run=rows * (rows + 1) // 2, sub_blocks_masked=rows)
+
+
+def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       acc_sc, m_sc, l_sc, *, scale: float, sub: int,
+                       n_sub: int):
+    """A q block (one row of square sub-blocks) against a span of ``n_sub``
+    of them. Every walk is unrolled, two sub-blocks a softmax update (one
+    q.k matmul 2 x sub columns wide: the max, the rescale and the
+    accumulator's pass are paid half as often), and one basic block, so the
+    scheduler lays the next update's matmul beside this one's softmax. The
+    running max, sum and accumulator are values inside a walk and touch
+    their scratch between walks."""
+    f = pl.program_id(1)
+    qi, si = qi_arr[f], si_arr[f]
+    q = q_ref[0]
+    # sub-blocks of this span wholly under the diagonal: all of them, or, in
+    # the q block's last span, those before the one the diagonal crosses
+    under = jnp.minimum(qi - si * n_sub, n_sub)
+
+    @pl.when(si == 0)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    @pl.when(ki < qi)
-    def _interior():                               # fully below diagonal
-        _online_softmax_block(q_ref[0], k_ref[0], v_ref[0],
-                              acc_sc, m_sc, l_sc, scale)
+    def update(carry, j, width, diagonal=False):
+        """``width`` sub-blocks from the span's j-th on, the last of them the
+        diagonal's (square, so the mask is of local indices) if so told."""
+        at = j * sub if isinstance(j, int) else pl.multiple_of(j * sub, sub)
+        s = _scores(q, k_ref[0, pl.ds(at, width * sub), :], scale)
+        if diagonal:
+            rows, cols = _block_iotas(sub, sub, 0, 0)
+            last = jnp.where(rows >= cols, s[:, -sub:], NEG_INF)
+            s = last if width == 1 else jnp.concatenate(
+                [s[:, :-sub], last], axis=1)
+        return _softmax_update(s, v_ref[0, pl.ds(at, width * sub), :], *carry)
 
-    @pl.when(ki == qi)
-    def _diagonal():                               # crosses the diagonal
-        _online_softmax_block(q_ref[0], k_ref[0], v_ref[0],
-                              acc_sc, m_sc, l_sc, scale,
-                              mask_rc=_block_iotas(block, block, qi, ki))
-        # last block of this row: write out
-        _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc)
+    def walk(j, n):
+        """``n`` sub-blocks under the diagonal from the j-th, scratch to
+        scratch."""
+        carry = m_sc[:], l_sc[:], acc_sc[:]
+        for i in range(0, n - 1, 2):
+            carry = update(carry, j + i, 2)
+        if n % 2:
+            carry = update(carry, j + n - 1, 1)
+        m_sc[:], l_sc[:], acc_sc[:] = carry
+
+    @pl.when(under == n_sub)
+    def _under():
+        walk(0, n_sub)
+
+    @pl.when(under < n_sub)
+    def _diagonal():
+        # the last update takes the diagonal's sub-block with the one before
+        # it; what lies before those is walked by the binary digits of its
+        # count, most significant first: each digit a walk of its own size
+        before = jnp.maximum(under - 1, 0)
+        j = 0
+        for bit in reversed(range(max(n_sub - 2, 0).bit_length())):
+            digit = before & (1 << bit)
+            pl.when(digit != 0)(functools.partial(walk, j, 1 << bit))
+            j = j + digit
+
+        @pl.when(under == 0)
+        def _first():
+            _write_out(o_ref, lse_ref, *update(
+                (m_sc[:], l_sc[:], acc_sc[:]), 0, 1, diagonal=True))
+
+        if n_sub > 1:
+            @pl.when(under > 0)
+            def _pair():
+                _write_out(o_ref, lse_ref, *update(
+                    (m_sc[:], l_sc[:], acc_sc[:]), under - 1, 2,
+                    diagonal=True))
 
 
-# --------------------------------------------- forward (rectangular fallback)
+def _causal_forward(q, k, v, scale, block_q, block_k):
+    bh, t, d = q.shape
+    dv = v.shape[2]
+    plan = flash_forward_plan(t, d, dv, q.dtype, block_q, block_k)
+    sub, n_sub = plan.sub_block, plan.span // plan.sub_block
+    qi_arr, si_arr = _causal_spans(t // sub, n_sub)
+    return pl.pallas_call(
+        functools.partial(_fwd_causal_kernel, scale=scale, sub=sub,
+                          n_sub=n_sub),
+        name="flash_fwd",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, len(qi_arr)),
+            in_specs=[
+                pl.BlockSpec((1, sub, d), lambda b, f, qa, sa: (b, qa[f], 0)),
+                pl.BlockSpec((1, plan.span, d),
+                             lambda b, f, qa, sa: (b, sa[f], 0)),
+                pl.BlockSpec((1, plan.span, dv),
+                             lambda b, f, qa, sa: (b, sa[f], 0)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, sub, dv), lambda b, f, qa, sa: (b, qa[f], 0)),
+                pl.BlockSpec((1, 1, sub), lambda b, f, qa, sa: (b, 0, qa[f])),
+            ),
+            scratch_shapes=[pltpu.VMEM((sub, dv), jnp.float32),
+                            pltpu.VMEM((sub, _LANES), jnp.float32),
+                            pltpu.VMEM((sub, _LANES), jnp.float32)],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_FWD_VMEM_BYTES),
+        cost_estimate=pl.CostEstimate(
+            flops=int(bh * t * t * (d + dv)),       # the causal half
+            bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
+            transcendentals=int(bh * t * t // 2)),
+    )(jnp.asarray(qi_arr), jnp.asarray(si_arr), q, k, v)
+
+
+# ------------------------------------- forward (non-causal, t_q and t_k apart)
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
-                *, scale: float, causal: bool, block_q: int, block_k: int, num_k: int):
-    qi = pl.program_id(1)
+                *, scale: float, num_k: int):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -249,85 +447,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    if causal:
-        _causal_dispatch(qi, ki, block_q, block_k,
-                         lambda mask_rc: _online_softmax_block(
-                             q_ref[0], k_ref[0], v_ref[0],
-                             acc_sc, m_sc, l_sc, scale, mask_rc=mask_rc))
-    else:
-        _online_softmax_block(q_ref[0], k_ref[0], v_ref[0],
-                              acc_sc, m_sc, l_sc, scale)
+    _online_softmax_block(q_ref[0], k_ref[0], v_ref[0],
+                          acc_sc, m_sc, l_sc, scale)
 
     @pl.when(ki == num_k - 1)
     def _finalize():
-        _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc)
-
-
-def _tri_min_blocks() -> int:
-    """Min row blocks before the triangular grid pays for its bookkeeping
-    (default 4 = 37.5%+ of blocks skipped; DS_TPU_FLASH_TRI_MIN=2 enables
-    it at nq=2 for experiments — measured slower on v5e at GPT-2 shapes)."""
-    import os
-
-    return int(os.environ.get("DS_TPU_FLASH_TRI_MIN", "4"))
-
-
-def _use_tri(causal, t_q, t_k, bq, bk) -> bool:
-    """The triangular grid skips (nq-1)/2nq of the blocks — worth its
-    bookkeeping only with ≥_tri_min_blocks() row blocks. Below that a
-    rectangular grid with a double-width k block measures faster (fewer,
-    larger cells)."""
-    return causal and t_q == t_k and bq == bk and t_q // bq >= _tri_min_blocks()
+        _write_out(o_ref, lse_ref, m_sc[:], l_sc[:], acc_sc[:])
 
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k):
+    if causal:              # self-attention: ``flash_attention`` saw to that
+        return _causal_forward(q, k, v, scale, block_q, block_k)
     bh, t_q, d = q.shape
     t_k, dv = k.shape[1], v.shape[2]
     bq = _pick_block(t_q, block_q)
     bk = _pick_block(t_k, block_k)
-    if causal and t_q == t_k and bq == bk and t_q // bq < _tri_min_blocks():
-        bk = _pick_block(t_k, 2 * bq)       # short-seq rect: wider k blocks
     nq, nk = t_q // bq, t_k // bk
-
-    out_shapes = (jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
-                  jax.ShapeDtypeStruct((bh, 1, t_q), jnp.float32))
-    scratch = [pltpu.VMEM((bq, dv), jnp.float32),
-               pltpu.VMEM((bq, 128), jnp.float32),
-               pltpu.VMEM((bq, 128), jnp.float32)]
-
-    if _use_tri(causal, t_q, t_k, bq, bk):
-        qi_arr, ki_arr = _causal_pairs(nq)
-        o, lse = pl.pallas_call(
-            functools.partial(_fwd_tri_kernel, scale=scale, block=bq),
-            name="flash_fwd",
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(bh, len(qi_arr)),
-                in_specs=[
-                    pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bk, dv), lambda b, f, qa, ka: (b, ka[f], 0)),
-                ],
-                out_specs=(
-                    pl.BlockSpec((1, bq, dv), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, 1, bq), lambda b, f, qa, ka: (b, 0, qa[f])),
-                ),
-                scratch_shapes=scratch,
-            ),
-            out_shape=out_shapes,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            cost_estimate=pl.CostEstimate(
-                flops=int(bh * t_q * t_k * (d + dv)),   # causal: half the blocks run
-                bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
-                transcendentals=int(bh * t_q * t_k // 2)),
-        )(jnp.asarray(qi_arr), jnp.asarray(ki_arr), q, k, v)
-        return o, lse
-
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=bq, block_k=bk, num_k=nk)
-    o, lse = pl.pallas_call(
-        kernel,
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, num_k=nk),
         name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=[
@@ -339,30 +476,36 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
             pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ),
-        out_shape=out_shapes,
-        scratch_shapes=scratch,
+        out_shape=(jax.ShapeDtypeStruct((bh, t_q, dv), q.dtype),
+                   jax.ShapeDtypeStruct((bh, 1, t_q), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((bq, dv), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=int(2 * bh * t_q * t_k * (d + dv) * (0.5 if causal else 1.0)),
+            flops=int(2 * bh * t_q * t_k * (d + dv)),
             bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
             transcendentals=int(bh * t_q * t_k)),
     )(q, k, v)
-    return o, lse
+
+
+# the backward's triangular grid skips (nq-1)/2nq of the blocks: worth its
+# bookkeeping from this many row blocks; below, a rectangular grid with a
+# double-width k block
+_BWD_TRI_MIN_BLOCKS = 4
+
+
+def _use_tri(causal, t_q, t_k, bq, bk) -> bool:
+    return (causal and t_q == t_k and bq == bk
+            and t_q // bq >= _BWD_TRI_MIN_BLOCKS)
 
 
 # -------------------------------------------------------------------- backward
 def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None):
     """Recompute P and dS for one block (shared by dq and dkv kernels).
     ``lse`` and ``delta`` arrive as the query block's lane-dense rows."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if scale != 1.0:
-        s = s * scale
-    if mask_rc is not None:
-        rows, cols = mask_rc
-        s = jnp.where(rows >= cols, s, NEG_INF)
-    p = jnp.exp(s - _col(lse))
+    p = jnp.exp(_scores(q, k, scale, mask_rc) - _col(lse))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     ds = p * (dp - _col(delta))
@@ -499,8 +642,8 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None]        # (bh, 1, t_q), as lse
 
-    if causal and t_q == t_k and bq == bk and t_q // bq < _tri_min_blocks():
-        bk = _pick_block(t_k, 2 * bq)       # mirror the forward's block choice
+    if causal and t_q == t_k and bq == bk and t_q // bq < _BWD_TRI_MIN_BLOCKS:
+        bk = _pick_block(t_k, 2 * bq)       # short sequences: wider k blocks
         nk = t_k // bk
     tri = _use_tri(causal, t_q, t_k, bq, bk)
     if tri:
@@ -679,9 +822,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
         raise ValueError(
             f"flash_attention: lengths ({t}, {k.shape[1]}) with causal="
             f"{causal} do not tile in blocks ({block_q}, {block_k}): q in "
-            "whole 128s or one block, k in whole 8s — only causal "
-            "self-attention is padded, to a multiple of 128")
-    if causal and t == k.shape[1]:
+            "whole 128s or one block, k in whole 8s — causal is "
+            "self-attention (one length), and only that is padded, to a "
+            "multiple of 128")
+    if causal:
         pad = _padded_len(t, min(block_q, block_k)) - t
         if pad:
             q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
@@ -778,7 +922,7 @@ def _sparse_fwd_kernel(qi_arr, ki_arr, first_arr, last_arr, valid_arr,
 
     @pl.when(last_arr[f] == 1)
     def _finalize():
-        _write_out(o_ref, lse_ref, acc_sc, m_sc, l_sc)
+        _write_out(o_ref, lse_ref, m_sc[:], l_sc[:], acc_sc[:])
 
 
 def _sparse_bwd_dq_kernel(qi_arr, ki_arr, first_arr, last_arr, valid_arr,

@@ -78,6 +78,29 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, heads, dim):
             kernel
 
 
+# every length a cell runs the causal forward at, heads cut to a few: the
+# train cells, doc.c1's awkward 768 and 896, doc4k.c1, openPangu's q.k 192 /
+# v 128 at its median prompt, Solar's median and longest
+@pytest.mark.parametrize("t,d,dv", [
+    (1024, 96, 96), (1024, 64, 64), (768, 64, 64), (896, 64, 64),
+    (2048, 128, 128), (4096, 192, 128), (16384, 128, 128),
+    (32768, 128, 128)], ids=lambda x: str(x))
+def test_causal_flash_forward_compiles_for_v5e_at_the_cells_shapes(v5e, t, d,
+                                                                    dv):
+    """The one causal forward at ``flash_forward_plan``'s shapes: a span of
+    K and V double-buffered in VMEM beside the unrolled walk's score tiles
+    is what Mosaic may refuse (a 4,096-key span at 192 / 128 did, under the
+    16 MiB default of scoped VMEM), and only a compile for the chip says.
+    A prompt length's prefill compiles inside its first request's deadline,
+    so the kernel's own compile is held to a few seconds too."""
+    mesh = _mesh(v5e)
+    qk = _abstract((1, t, 4, d), jnp.bfloat16, mesh)
+    v = _abstract((1, t, 4, dv), jnp.bfloat16, mesh)
+    text = jax.jit(fa.flash_attention).lower(qk, qk, v).compile().as_text()
+    call, = re.findall(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call", text)
+    assert f"(bf16[4,{t},{dv}]" in call and f"f32[4,1,{t}]" in call
+
+
 def _attention_grad_text(attend, mesh, *shapes):
     """The compiled gradient of ``sum(attend(q, k, v))`` for the chip."""
     args = [_abstract(s, jnp.bfloat16, mesh) for s in shapes]

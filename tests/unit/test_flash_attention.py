@@ -69,6 +69,116 @@ def test_uneven_blocks():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-2, rtol=2e-2)
 
 
+# ------------------------------- the causal forward: one kernel, wide steps
+def _walk(rows, n_sub):
+    """The sub-blocks the forward's grid runs, by the kernel's own rule: a
+    step (q block i, span s) runs min(i - s * n_sub, n_sub) sub-blocks of
+    its span without a mask and, where that is under n_sub, one more
+    masked."""
+    run, masked = [], []
+    for i, s in zip(*fa._causal_spans(rows, n_sub)):
+        interior = min(i - s * n_sub, n_sub)
+        run += [(i, s * n_sub + j) for j in range(interior)]
+        if interior < n_sub:
+            masked.append((i, s * n_sub + interior))
+    return run, masked
+
+
+# the STRUCTURE of every length a cell runs, met in 128-row blocks (the
+# least Mosaic takes; interpret mode would take less, flash_supports not):
+# 1, 2, 3, 4, 7 and 64 q blocks; 12 = three spans of four, so a span ends
+# mid-length; 9 = three spans of three, 7 = two of four, the second ending
+# past the keys; 1,000 padded to 1,024; q.k at 192 with v at 128 (latent
+# attention), 96 / 96
+@pytest.mark.parametrize("T,D,Dv", [
+    (128, 32, 32), (256, 32, 32), (384, 32, 32), (512, 32, 32),
+    (896, 32, 32), (8192, 16, 16), (1536, 32, 32), (1152, 32, 32),
+    (1000, 32, 32), (512, 192, 128), (512, 96, 96)],
+    ids=lambda x: str(x))
+def test_causal_forward_in_wide_steps_matches_reference(T, D, Dv):
+    """Output and log-sum-exp of the one causal forward against the plain
+    softmax, gradients through it and the unchanged backward, and the
+    plan's counts against a walk of the grid it makes."""
+    B, H = 1, (1 if T > 2048 else 2)
+    keys = jax.random.split(jax.random.PRNGKey(T + D), 4)
+    q, k, v, g = (jax.random.normal(key, (B, T, H, w), jnp.float32)
+                  for key, w in zip(keys, (D, D, Dv, Dv)))
+    plan = fa.flash_forward_plan(T, D, Dv, q.dtype, 128, 128)
+    rows = -(-T // 128)
+    assert plan[:3] == (128, -(-rows // -(-rows // 4)) * 128, 128)
+    run, masked = _walk(rows, plan.span // plan.sub_block)
+    assert sorted(run + masked) == [(i, j) for i in range(rows)
+                                    for j in range(i + 1)]
+    assert masked == [(i, i) for i in range(rows)]
+    assert (plan.sub_blocks_run, plan.sub_blocks_masked) == (
+        len(run) + len(masked), len(masked))
+    assert plan.grid_steps == len(fa._causal_spans(
+        rows, plan.span // plan.sub_block)[0])
+
+    attend = jax.jit(functools.partial(fa.flash_attention, block_q=128,
+                                       block_k=128))
+    scale = D ** -0.5
+
+    @jax.jit
+    def plain(q, k, v):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+        logits = jnp.where(jnp.tril(jnp.ones((T, T), bool)), logits, -jnp.inf)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits), v),
+                jax.nn.logsumexp(logits, axis=-1))
+
+    o_ref, lse_ref = plain(q, k, v)
+    if T % 128:
+        o = attend(q, k, v)
+    else:
+        o, lse = jax.jit(lambda q, k, v: fa._flash_forward(
+            fa._to_bhtd(q * scale), fa._to_bhtd(k), fa._to_bhtd(v),
+            1.0, True, 128, 128))(q, k, v)
+        o = fa._to_bthd(o, B)
+        np.testing.assert_allclose(np.asarray(lse).reshape(B, H, T),
+                                   np.asarray(lse_ref), atol=1e-3, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               atol=2e-2, rtol=2e-2)
+    if D == Dv and T <= 2048:
+        loss = lambda f: lambda *a: jnp.sum(f(*a) * g)
+        for a, b in zip(jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v),
+                        jax.grad(loss(fa.mha_reference),
+                                 argnums=(0, 1, 2))(q, k, v)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("t,d,dv,plan", [
+    # (block_q, span, sub_block, grid_steps, sub_blocks_run, .._masked)
+    (1024, 96, 96, (512, 1024, 512, 2, 3, 2)),         # gpt2-760m.train.*
+    (1024, 64, 64, (512, 1024, 512, 2, 3, 2)),         # gpt2-xl.train.z3x4
+    (768, 64, 64, (256, 768, 256, 3, 6, 3)),           # gpt2-xl.serve.doc.c1
+    (896, 64, 64, (128, 512, 128, 10, 28, 7)),
+    (2048, 128, 128, (512, 2048, 512, 4, 10, 4)),      # olmoe doc4k.c1
+    (4096, 192, 128, (512, 2048, 512, 12, 36, 8)),     # openPangu doc8k.c1
+    (16384, 128, 128, (512, 2048, 512, 144, 528, 32)),  # Solar doc32k.c1
+    (32768, 128, 128, (512, 2048, 512, 544, 2080, 64)),
+], ids=lambda x: str(x))
+def test_flash_forward_plan_at_the_cells_shapes(t, d, dv, plan):
+    """The static grid at the real shapes: every sub-block at or under the
+    diagonal once, one masked a row of them, none above."""
+    got = fa.flash_forward_plan(t, d, dv, jnp.bfloat16)
+    assert tuple(got) == plan
+    run, masked = _walk(t // got.sub_block, got.span // got.sub_block)
+    rows = t // got.sub_block
+    assert len(run) + len(masked) == rows * (rows + 1) // 2 == got.sub_blocks_run
+    assert all(j < i for i, j in run) and all(j == i for i, j in masked)
+
+
+def test_causal_means_one_length():
+    """The causal forward is self-attention's: a causal call at two lengths
+    is refused by ``flash_supports`` (so a dispatcher takes its einsum
+    path) and by the kernel's wrapper, not masked by some convention."""
+    assert not fa.flash_supports(256, 128, True)
+    q = jnp.zeros((1, 256, 1, 32))
+    with pytest.raises(ValueError, match="one length"):
+        fa.flash_attention(q, q[:, :128], q[:, :128], causal=True)
+
+
 # ----------------------------- remat 'attn' keeps what the backward reads
 def _remat_cases():
     """(head_dim, T, where, remat, forward kernels in the gradient): every

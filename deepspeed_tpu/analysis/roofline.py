@@ -264,17 +264,10 @@ def roofline_program(record, chip: str = "cpu-sim") -> Optional[RooflineReport]:
     mesh context, same abstract args) and price it — with the
     ``cost_analysis()`` cross-check stamped in. None when the record
     cannot be lowered."""
-    import contextlib
-
-    if not record.can_lower():
-        return None
     try:
-        ctx = (record.mesh if record.mesh is not None
-               else contextlib.nullcontext())
-        with ctx:
-            lowered = record.jitted.lower(*record.abstract_args,
-                                          **(record.abstract_kwargs or {}))
-            compiled = lowered.compile()
+        compiled = record.compiled()        # the door's one lower-and-compile
+        if compiled is None:
+            return None
         text = compiled.as_text()
     except Exception:
         return None

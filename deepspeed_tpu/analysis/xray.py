@@ -248,13 +248,9 @@ def xray_program(record) -> Tuple[Optional[ProgramXray], List[Finding]]:
     try:
         import contextlib
 
-        # traces that constrain with bare PartitionSpecs need the mesh
-        # context at lower time, exactly like the original dispatch
-        ctx = record.mesh if record.mesh is not None else contextlib.nullcontext()
-        with ctx:
-            lowered = record.jitted.lower(*record.abstract_args,
-                                          **(record.abstract_kwargs or {}))
-            compiled = lowered.compile()
+        compiled = record.compiled()        # the door's one lower-and-compile
+        with record.mesh if record.mesh is not None \
+                else contextlib.nullcontext():
             try:
                 out_tree = jax.eval_shape(record.jitted,
                                           *record.abstract_args,

@@ -30,6 +30,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.telemetry.scopes import scope
+
 
 @dataclasses.dataclass
 class GPT2Config:
@@ -352,18 +354,20 @@ class GPT2Model:
         q, k, v = self._block_kv(x, blk, rope)
         # what remat='attn' saves of attention is named where it is made
         # (common.remat_wrap): the rest of the block is recomputed
-        attn = self._attention(q, k, v, window=window)
+        with scope("attn/core"):
+            attn = self._attention(q, k, v, window=window)
         return self._block_finish(x, blk, attn, rng)
 
     def _lm_logits(self, params, x):
         """Final hidden → fp32 logits (tied or untied head, optional GPT-J
         style head bias)."""
         c = self.config
-        head = (params["wte"].T if c.tie_embeddings else params["lm_head"]).astype(x.dtype)
-        logits = (x @ head).astype(jnp.float32)
-        if "lm_head_b" in params:
-            logits = logits + params["lm_head_b"].astype(jnp.float32)
-        return logits
+        with scope("head"):
+            head = (params["wte"].T if c.tie_embeddings else params["lm_head"]).astype(x.dtype)
+            logits = (x @ head).astype(jnp.float32)
+            if "lm_head_b" in params:
+                logits = logits + params["lm_head_b"].astype(jnp.float32)
+            return logits
 
     def apply(self, params, input_ids, rng=None):
         """input_ids (B, T) int32 → logits (B, T, V) fp32."""
@@ -372,10 +376,11 @@ class GPT2Model:
     def _trunk(self, params, input_ids, rng=None, pld_theta=None):
         c = self.config
         B, T = input_ids.shape
-        x = self._embed(params, input_ids)
-        if rng is not None and c.dropout > 0.0:
-            rng, emb_key = jax.random.split(rng)
-            x = self._dropout(x, emb_key)
+        with scope("embed"):
+            x = self._embed(params, input_ids)
+            if rng is not None and c.dropout > 0.0:
+                rng, emb_key = jax.random.split(rng)
+                x = self._dropout(x, emb_key)
 
         from deepspeed_tpu.models.common import layer_scan, remat_wrap
 
@@ -413,11 +418,13 @@ class GPT2Model:
         # layer_scan = lax.scan unless the overlap engine installed its
         # double-buffered ZeRO-3 gather-prefetch implementation (trace-time
         # indirection; identical trace when nothing is installed)
-        x, _ = layer_scan(scan_body, x,
-                          (params["blocks"], layer_rngs, windows,
-                           keep_p, pld_rngs),
-                          unroll=max(1, int(c.scan_unroll)))
-        return self._layer_norm(x, params["lnf_g"], params["lnf_b"])
+        with scope("layers"):
+            x, _ = layer_scan(scan_body, x,
+                              (params["blocks"], layer_rngs, windows,
+                               keep_p, pld_rngs),
+                              unroll=max(1, int(c.scan_unroll)))
+        with scope("head"):
+            return self._layer_norm(x, params["lnf_g"], params["lnf_b"])
 
     def hidden_states(self, params, input_ids, rng=None):
         """Transformer trunk only: (B, T) → final hidden (B, T, D)."""
@@ -440,12 +447,14 @@ class GPT2Model:
 
         ids, labels, mask = parse_lm_batch(batch)
         c = self.config
-        x = self._trunk(params, ids, rng, pld_theta=pld_theta)[:, :-1]  # (B, T-1, D)
-        head = (params["wte"].T if c.tie_embeddings else params["lm_head"]).astype(x.dtype)
-        return chunked_lm_loss(x, head, labels[:, 1:],
-                               mask[:, 1:] if mask is not None else None,
-                               bias=params.get("lm_head_b"),
-                               remat=c.remat_loss_chunks)
+        x = self._trunk(params, ids, rng, pld_theta=pld_theta)
+        with scope("head"):
+            x = x[:, :-1]  # (B, T-1, D)
+            head = (params["wte"].T if c.tie_embeddings else params["lm_head"]).astype(x.dtype)
+            return chunked_lm_loss(x, head, labels[:, 1:],
+                                   mask[:, 1:] if mask is not None else None,
+                                   bias=params.get("lm_head_b"),
+                                   remat=c.remat_loss_chunks)
 
 
     # ------------------------------------------------------------- inference
@@ -484,8 +493,9 @@ class GPT2Model:
         # round, not int(): converted ratios like 32/96 reconstruct exactly
         rot = round(c.head_dim * c.rotary_pct)
         rot -= rot % 2
-        return _rope_cos_sin(positions, rot, c.rotary_theta,
-                             interleaved=c.rotary_interleaved)
+        with scope("attn/qkv"):
+            return _rope_cos_sin(positions, rot, c.rotary_theta,
+                                 interleaved=c.rotary_interleaved)
 
     def _apply_partial_rope(self, q, k, rope):
         """Partial rotary: rotate the first rotary_pct of each head's dims
@@ -507,39 +517,45 @@ class GPT2Model:
         """One block's q,k,v for the current x (no attention yet)."""
         c = self.config
         B, T, D = x.shape
-        h = self._layer_norm(x, blk["ln1_g"], blk["ln1_b"])
-        qkv = h @ blk["qkv_w"].astype(h.dtype) + blk["qkv_b"].astype(h.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        to_heads = lambda t: t.reshape(B, T, c.n_head, c.head_dim)
-        q, k = self._apply_partial_rope(to_heads(q), to_heads(k), rope)
-        return q, k, to_heads(v)
+        with scope("attn/qkv"):
+            h = self._layer_norm(x, blk["ln1_g"], blk["ln1_b"])
+            qkv = h @ blk["qkv_w"].astype(h.dtype) + blk["qkv_b"].astype(h.dtype)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            to_heads = lambda t: t.reshape(B, T, c.n_head, c.head_dim)
+            q, k = self._apply_partial_rope(to_heads(q), to_heads(k), rope)
+            return q, k, to_heads(v)
 
     def _mlp(self, h_in, blk):
-        h = h_in @ blk["fc_w"].astype(h_in.dtype) + blk["fc_b"].astype(h_in.dtype)
-        act = self.config.activation
-        if act == "relu":
-            h = jax.nn.relu(h)
-        elif act == "quick_gelu":      # CLIP text encoder: x·sigmoid(1.702x)
-            h = h * jax.nn.sigmoid(1.702 * h)
-        else:
-            h = jax.nn.gelu(h, approximate=(act == "gelu_new"))
-        # named so remat='attn_mlp' can save the activation and skip the
-        # fc/fc2 matmul recompute in backward
-        h = checkpoint_name(h, "mlp_act")
-        return h @ blk["fc2_w"].astype(h.dtype) + blk["fc2_b"].astype(h.dtype)
+        with scope("mlp/up"):
+            h = h_in @ blk["fc_w"].astype(h_in.dtype) + blk["fc_b"].astype(h_in.dtype)
+            act = self.config.activation
+            if act == "relu":
+                h = jax.nn.relu(h)
+            elif act == "quick_gelu":  # CLIP text encoder: x·sigmoid(1.702x)
+                h = h * jax.nn.sigmoid(1.702 * h)
+            else:
+                h = jax.nn.gelu(h, approximate=(act == "gelu_new"))
+            # named so remat='attn_mlp' can save the activation and skip the
+            # fc/fc2 matmul recompute in backward
+            h = checkpoint_name(h, "mlp_act")
+        with scope("mlp/down"):
+            return h @ blk["fc2_w"].astype(h.dtype) + blk["fc2_b"].astype(h.dtype)
 
     def _block_finish(self, x, blk, attn, rng=None):
         B, T, D = x.shape
         dk = (lambda i: jax.random.fold_in(rng, i)) if rng is not None else (lambda i: None)
-        a = attn.reshape(B, T, D) @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
-        if self.config.parallel_residual:
-            # NeoX: x + attn(ln1(x)) + mlp(ln2(x)) — both branches read the
-            # block input, so the MLP does not wait on the attention residual
+        with scope("attn/out"):
+            a = attn.reshape(B, T, D) @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
+            a = self._dropout(a, dk(0))
+            if not self.config.parallel_residual:
+                x = x + a
+        # NeoX (parallel_residual): x + attn(ln1(x)) + mlp(ln2(x)) — both
+        # branches read the block input, so the MLP does not wait on the
+        # attention residual
+        with scope("mlp"):
             h = self._layer_norm(x, blk["ln2_g"], blk["ln2_b"])
-            return x + self._dropout(a, dk(0)) + self._dropout(self._mlp(h, blk), dk(1))
-        x = x + self._dropout(a, dk(0))
-        h = self._layer_norm(x, blk["ln2_g"], blk["ln2_b"])
-        return x + self._dropout(self._mlp(h, blk), dk(1))
+            m = self._dropout(self._mlp(h, blk), dk(1))
+            return x + a + m if self.config.parallel_residual else x + m
 
     def prefill(self, params, input_ids, cache):
         """Process the prompt, fill the cache, return last-position logits."""
@@ -548,7 +564,8 @@ class GPT2Model:
         c = self.config
         B, T = input_ids.shape
         max_len = cache["k"].shape[2]
-        x = self._embed(params, input_ids)
+        with scope("embed"):
+            x = self._embed(params, input_ids)
         rope = self._rope_tables(jnp.arange(T))
 
         windows = self._layer_windows()
@@ -557,13 +574,16 @@ class GPT2Model:
             blk, w = xs
             x = carry
             q, k, v = self._block_kv(x, blk, rope)
-            attn = self._attention_local(q, k, v, window=w)
+            with scope("attn/core"):
+                attn = self._attention_local(q, k, v, window=w)
+                rows = (kv_cache_rows(k, max_len), kv_cache_rows(v, max_len))
             x = self._block_finish(x, blk, attn)
-            return x, (kv_cache_rows(k, max_len),
-                       kv_cache_rows(v, max_len))
+            return x, rows
 
-        x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], windows))
-        x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
+        with scope("layers"):
+            x, (ks, vs) = jax.lax.scan(body, x, (params["blocks"], windows))
+        with scope("head"):
+            x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
         logits = self._lm_logits(params, x[:, -1])
         cache = {"k": ks, "v": vs, "pos": jnp.int32(T)}
         return logits, cache
@@ -586,7 +606,8 @@ class GPT2Model:
         path (csrc/transformer/inference/pt_binding.cpp qkv_gemm_/softmax_context_)."""
         c = self.config
         pos = cache["pos"]
-        x = self._decode_embed(params, token, pos)
+        with scope("embed"):
+            x = self._decode_embed(params, token, pos)
 
         from deepspeed_tpu.models.common import (cached_decode_attention,
                                                  kv_cache_write,
@@ -607,18 +628,21 @@ class GPT2Model:
             blk, w, l = xs
             blk = dict(blk, fc2_w=read_as_stored(blk["fc2_w"]))
             q, k, v = self._block_kv(x, blk, rope)     # (B, 1, H, Dh)
-            cache_k = kv_cache_write(cache_k, k, l, pos)
-            cache_v = kv_cache_write(cache_v, v, l, pos)
-            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, l, pos,
-                                           c.n_head, alibi=self._alibi(),
-                                           window=w)[:, None]
+            with scope("attn/core"):
+                cache_k = kv_cache_write(cache_k, k, l, pos)
+                cache_v = kv_cache_write(cache_v, v, l, pos)
+                attn = cached_decode_attention(
+                    q[:, 0], cache_k, cache_v, l, pos, c.n_head,
+                    alibi=self._alibi(), window=w)[:, None]
             x = self._block_finish(x, blk, attn)
             return (x, cache_k, cache_v), None
 
-        (x, ks, vs), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["blocks"], windows, jnp.arange(c.n_layer)))
-        x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
+        with scope("layers"):
+            (x, ks, vs), _ = jax.lax.scan(
+                body, (x, cache["k"], cache["v"]),
+                (params["blocks"], windows, jnp.arange(c.n_layer)))
+        with scope("head"):
+            x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
         logits = self._lm_logits(params, x[:, 0])
         return logits, {"k": ks, "v": vs, "pos": pos + 1}
 

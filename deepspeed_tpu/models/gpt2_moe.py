@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.models.common import remat_wrap
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.moe.layer import MoE
+from deepspeed_tpu.telemetry.scopes import scope
 
 
 class MoEGPT2(GPT2Model):
@@ -79,7 +80,8 @@ class MoEGPT2(GPT2Model):
         dense part: scans pairs of (dense block, moe layer)."""
         c = self.config
         B, T = ids.shape
-        x = self._embed(params, ids)
+        with scope("embed"):
+            x = self._embed(params, ids)
         rope = self._rope_tables(jnp.arange(T))
         n_pairs, paired = self._paired_blocks(params)
 
@@ -90,9 +92,10 @@ class MoEGPT2(GPT2Model):
             # block 1: attention part of the dense block, MoE as its MLP
             b1 = jax.tree.map(lambda t: t[1], pair_blocks)
             x = self._attn_sublayer(x, b1, rope)
-            h = self._layer_norm(x, b1["ln2_g"], b1["ln2_b"])
-            moe_out, l_aux = self.moe(moe_p, h, rng, train=train)
-            return x + moe_out, l_aux
+            with scope("moe"):
+                h = self._layer_norm(x, b1["ln2_g"], b1["ln2_b"])
+                moe_out, l_aux = self.moe(moe_p, h, rng, train=train)
+                return x + moe_out, l_aux
 
         # the configured remat policy applies per PAIR (dense block + MoE
         # half-block): without it every expert hidden and dispatch buffer is
@@ -105,9 +108,11 @@ class MoEGPT2(GPT2Model):
             x, l_aux = pair_fn(x, pair_blocks, moe_p)
             return (x, aux + l_aux), None
 
-        (x, aux), _ = jax.lax.scan(pair_body, (x, jnp.float32(0.0)),
-                                   (paired, params["moe"]))
-        x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
+        with scope("layers"):
+            (x, aux), _ = jax.lax.scan(pair_body, (x, jnp.float32(0.0)),
+                                       (paired, params["moe"]))
+        with scope("head"):
+            x = self._layer_norm(x, params["lnf_g"], params["lnf_b"])
         return x, aux / n_pairs
 
     def apply(self, params, input_ids, rng=None):
@@ -122,23 +127,26 @@ class MoEGPT2(GPT2Model):
 
         ids, labels, mask = parse_lm_batch(batch)
         x, aux = self._moe_trunk(params, ids, rng, train=True)
-        x = x[:, :-1]
         # chunked vocab projection + CE, same as the dense trunk: the full
         # (B, T, V) fp32 logits tensor (≈2.5G at bs=12/seq=1024/V=50k) never
         # materializes — this is what lets the E=8 bank train on one 16G chip
-        head = (params["wte"].T if self.config.tie_embeddings
-                else params["lm_head"]).astype(x.dtype)
-        ce = chunked_lm_loss(x, head, labels[:, 1:],
-                             mask[:, 1:] if mask is not None else None,
-                             bias=params.get("lm_head_b"),
-                             remat=self.config.remat_loss_chunks)
+        with scope("head"):
+            x = x[:, :-1]
+            head = (params["wte"].T if self.config.tie_embeddings
+                    else params["lm_head"]).astype(x.dtype)
+            ce = chunked_lm_loss(x, head, labels[:, 1:],
+                                 mask[:, 1:] if mask is not None else None,
+                                 bias=params.get("lm_head_b"),
+                                 remat=self.config.remat_loss_chunks)
         return ce + self.aux_loss_coef * aux
 
     def _attn_sublayer(self, x, blk, rope=None):
         B, T, D = x.shape
         q, k, v = self._block_kv(x, blk, rope)
-        attn = self._attention(q, k, v).reshape(B, T, D)
-        return x + attn @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
+        with scope("attn/core"):
+            attn = self._attention(q, k, v).reshape(B, T, D)
+        with scope("attn/out"):
+            return x + attn @ blk["proj_w"].astype(x.dtype) + blk["proj_b"].astype(x.dtype)
 
     # ------------------------------------------------------------- inference
     # Same cache layout/protocol as the dense GPT-2 ((L, B, max_len, W)

@@ -94,6 +94,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.telemetry.scopes import scope
 from deepspeed_tpu.models.common import (_rope_cos_sin, apply_rope,
                                          remat_wrap)
 
@@ -634,28 +635,30 @@ class LlamaModel:
         c = self.config
         if not c.use_rope:
             return None, None
-        return _rope_cos_sin(positions, c.rope_dim, c.rope_theta,
-                             c.rope_scaling)
+        with scope("attn/qkv"):
+            return _rope_cos_sin(positions, c.rope_dim, c.rope_theta,
+                                 c.rope_scaling)
 
     def _block_qkv(self, x, blk, cos, sin):
         """One GQA block's q, k, v for the current x, rotated where the
         model has a rotary embedding."""
         c = self.config
         B, T, D = x.shape
-        h = self._rms_norm(x, blk["attn_norm_g"])
-        hd = h.astype(c.dtype)
-        q = hd @ blk["q_w"].astype(hd.dtype)
-        k = hd @ blk["k_w"].astype(hd.dtype)
-        if "q_norm_g" in blk:
-            # OLMoE: over the whole projection, before the heads are split
-            q = self._rms_norm(q, blk["q_norm_g"])
-            k = self._rms_norm(k, blk["k_norm_g"])
-        q = q.reshape(B, T, c.n_head, c.head_dim)
-        k = k.reshape(B, T, c.n_kv_head, c.head_dim)
-        v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, c.n_kv_head, c.head_dim)
-        if cos is None:
-            return q, k, v
-        return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+        with scope("attn/qkv"):
+            h = self._rms_norm(x, blk["attn_norm_g"])
+            hd = h.astype(c.dtype)
+            q = hd @ blk["q_w"].astype(hd.dtype)
+            k = hd @ blk["k_w"].astype(hd.dtype)
+            if "q_norm_g" in blk:
+                # OLMoE: over the whole projection, before the heads are split
+                q = self._rms_norm(q, blk["q_norm_g"])
+                k = self._rms_norm(k, blk["k_norm_g"])
+            q = q.reshape(B, T, c.n_head, c.head_dim)
+            k = k.reshape(B, T, c.n_kv_head, c.head_dim)
+            v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, c.n_kv_head, c.head_dim)
+            if cos is None:
+                return q, k, v
+            return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
 
     def _gated(self, attn, x, blk):
         """The softmax output (B, T, H, Dh) times ``sigmoid(h attn_gate_w)``,
@@ -663,10 +666,11 @@ class LlamaModel:
         q, k and v came from) — where the block holds that leaf."""
         if "attn_gate_w" not in blk:
             return attn
-        hd = self._rms_norm(x, blk["attn_norm_g"]).astype(self.config.dtype)
-        gate = jax.nn.sigmoid((hd @ blk["attn_gate_w"].astype(hd.dtype)
-                               ).astype(jnp.float32)).reshape(attn.shape)
-        return (attn * gate).astype(attn.dtype)
+        with scope("attn/out"):
+            hd = self._rms_norm(x, blk["attn_norm_g"]).astype(self.config.dtype)
+            gate = jax.nn.sigmoid((hd @ blk["attn_gate_w"].astype(hd.dtype)
+                                   ).astype(jnp.float32)).reshape(attn.shape)
+            return (attn * gate).astype(attn.dtype)
 
     def _block_latent(self, x, blk, cos, sin):
         """A latent-attention block's queries and its ONE cached row a
@@ -676,16 +680,17 @@ class LlamaModel:
         c = self.config
         B, T, _ = x.shape
         n, C = c.qk_nope_head_dim, c.kv_lora_rank
-        hd = self._rms_norm(x, blk["attn_norm_g"]).astype(c.dtype)
-        cq = self._rms_norm(hd @ blk["q_a_w"].astype(hd.dtype),
-                            blk["q_a_norm_g"])
-        q = (cq @ blk["q_b_w"].astype(hd.dtype)).reshape(
-            B, T, c.n_head, n + c.qk_rope_head_dim)
-        kv = hd @ blk["kv_a_w"].astype(hd.dtype)             # (B, T, C + rope)
-        latent = jnp.concatenate(
-            [self._rms_norm(kv[..., None, :C], blk["kv_a_norm_g"]),
-             apply_rope(kv[..., None, C:], cos, sin)], axis=-1)
-        return q[..., :n], apply_rope(q[..., n:], cos, sin), latent
+        with scope("attn/qkv"):
+            hd = self._rms_norm(x, blk["attn_norm_g"]).astype(c.dtype)
+            cq = self._rms_norm(hd @ blk["q_a_w"].astype(hd.dtype),
+                                blk["q_a_norm_g"])
+            q = (cq @ blk["q_b_w"].astype(hd.dtype)).reshape(
+                B, T, c.n_head, n + c.qk_rope_head_dim)
+            kv = hd @ blk["kv_a_w"].astype(hd.dtype)         # (B, T, C + rope)
+            latent = jnp.concatenate(
+                [self._rms_norm(kv[..., None, :C], blk["kv_a_norm_g"]),
+                 apply_rope(kv[..., None, C:], cos, sin)], axis=-1)
+            return q[..., :n], apply_rope(q[..., n:], cos, sin), latent
 
     def _attend(self, x, blk, cos_sin, attention):
         """A block's causal self-attention over the whole of x (the trunk,
@@ -695,22 +700,25 @@ class LlamaModel:
         c = self.config
         if "kv_a_w" not in blk:
             q, k, v = self._block_qkv(x, blk, *cos_sin)
-            attn = attention(q, self._repeat_kv(k), self._repeat_kv(v))
+            with scope("attn/core"):
+                attn = attention(q, self._repeat_kv(k), self._repeat_kv(v))
             return self._gated(attn, x, blk), (k, v)
         # un-absorbed: every head's key and value expanded from the latent
         # row (q.k at nope + rope columns, v at its own width); absorbing
         # here would cost (C + rope + C) / (nope + rope + v) = 3.4 x the FLOPs
         q_nope, q_rope, latent = self._block_latent(x, blk, *cos_sin)
-        c_kv, k_rope = latent[:, :, 0, :c.kv_lora_rank], \
-            latent[..., c.kv_lora_rank:]
-        k_nope = jnp.einsum("btc,hnc->bthn", c_kv,
-                            blk["kv_b_k_w"].astype(c_kv.dtype))
-        v = jnp.einsum("btc,hcd->bthd", c_kv,
-                       blk["kv_b_v_w"].astype(c_kv.dtype))
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
-        q = jnp.concatenate([q_nope, q_rope], axis=-1)
-        return attention(q, k, v), (latent,)
+        with scope("attn/qkv"):
+            c_kv, k_rope = latent[:, :, 0, :c.kv_lora_rank], \
+                latent[..., c.kv_lora_rank:]
+            k_nope = jnp.einsum("btc,hnc->bthn", c_kv,
+                                blk["kv_b_k_w"].astype(c_kv.dtype))
+            v = jnp.einsum("btc,hcd->bthd", c_kv,
+                           blk["kv_b_v_w"].astype(c_kv.dtype))
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        with scope("attn/core"):
+            return attention(q, k, v), (latent,)
 
     def _attend_cached(self, x, blk, cos_sin, caches, layer, pos):
         """The new token's attention over the cache (decode): its rows are
@@ -723,26 +731,32 @@ class LlamaModel:
         c = self.config
         if "kv_a_w" not in blk:
             q, k, v = self._block_qkv(x, blk, *cos_sin)     # q (B,1,H,Dh)
-            cache_k = kv_cache_write(caches[0], k, layer, pos)
-            cache_v = kv_cache_write(caches[1], v, layer, pos)
-            # GQA decode against the KV-head cache — repeated K/V are never
-            # materialized (grouped einsum or the Pallas streaming kernel)
-            attn = cached_decode_attention(q[:, 0], cache_k, cache_v, layer,
-                                           pos, c.n_kv_head)
+            with scope("attn/core"):
+                cache_k = kv_cache_write(caches[0], k, layer, pos)
+                cache_v = kv_cache_write(caches[1], v, layer, pos)
+                # GQA decode against the KV-head cache — repeated K/V are
+                # never materialized (grouped einsum or the Pallas streaming
+                # kernel)
+                attn = cached_decode_attention(q[:, 0], cache_k, cache_v,
+                                               layer, pos, c.n_kv_head)
             return self._gated(attn[:, None], x, blk), (cache_k, cache_v)
         # absorbed: q.k_nope = (q_nope W_UK^T).c_kv and p.v = (p.c_kv) W_UV,
         # so the scores and the weighted sum are over the latent rows
         # themselves, read once for all heads
         q_nope, q_rope, latent = self._block_latent(x, blk, *cos_sin)
-        cache = kv_cache_write(caches[0], latent, layer, pos)
-        q_lat = jnp.einsum("bhn,hnc->bhc", q_nope[:, 0],
-                           blk["kv_b_k_w"].astype(q_nope.dtype))
-        o_lat = latent_decode_attention(
-            jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1), cache, layer,
-            pos, v_width=c.kv_lora_rank,
-            scale=1.0 / math.sqrt(c.qk_nope_head_dim + c.qk_rope_head_dim))
-        attn = jnp.einsum("bhc,hcd->bhd", o_lat,
-                          blk["kv_b_v_w"].astype(o_lat.dtype))
+        with scope("attn/qkv"):
+            q_lat = jnp.einsum("bhn,hnc->bhc", q_nope[:, 0],
+                               blk["kv_b_k_w"].astype(q_nope.dtype))
+        with scope("attn/core"):
+            cache = kv_cache_write(caches[0], latent, layer, pos)
+            o_lat = latent_decode_attention(
+                jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1), cache, layer,
+                pos, v_width=c.kv_lora_rank,
+                scale=1.0 / math.sqrt(c.qk_nope_head_dim
+                                      + c.qk_rope_head_dim))
+        with scope("attn/out"):
+            attn = jnp.einsum("bhc,hcd->bhd", o_lat,
+                              blk["kv_b_v_w"].astype(o_lat.dtype))
         return attn[:, None], (cache,)
 
     EXPERT_LEAVES = ("expert_gate_w", "expert_up_w", "expert_down_w")
@@ -788,8 +802,10 @@ class LlamaModel:
             first=c.experts_held[0] if c.experts_held else None)
         out = out.reshape(B, T, D)
         if "shared_gate_w" in blk:
-            out = out + self._swiglu(h, blk["shared_gate_w"],
-                                     blk["shared_up_w"], blk["shared_down_w"])
+            with scope("moe/shared"):
+                out = out + self._swiglu(
+                    h, blk["shared_gate_w"], blk["shared_up_w"],
+                    blk["shared_down_w"])
         return out, (sizes, jnp.sum(probs, axis=0))
 
     def _split_experts(self, blocks):
@@ -806,15 +822,17 @@ class LlamaModel:
         or None). Pre-norm; sandwich norm (each branch's output normalised
         too) where the block holds the two post-norm gains."""
         B, T, _ = x.shape
-        a = attn.reshape(B, T, -1) @ blk["o_w"].astype(x.dtype)
-        if "post_attn_norm_g" in blk:
-            a = self._rms_norm(a, blk["post_attn_norm_g"])
-        x = x + a
-        h = self._rms_norm(x, blk["mlp_norm_g"])
-        out, stats = self._mlp(h, blk, stacked, layer)
-        if "post_mlp_norm_g" in blk:
-            out = self._rms_norm(out, blk["post_mlp_norm_g"])
-        return x + out, stats
+        with scope("kda/out" if "kda_qkv_w" in blk else "attn/out"):
+            a = attn.reshape(B, T, -1) @ blk["o_w"].astype(x.dtype)
+            if "post_attn_norm_g" in blk:
+                a = self._rms_norm(a, blk["post_attn_norm_g"])
+            x = x + a
+        with scope("moe" if "router_w" in blk else "mlp"):
+            h = self._rms_norm(x, blk["mlp_norm_g"])
+            out, stats = self._mlp(h, blk, stacked, layer)
+            if "post_mlp_norm_g" in blk:
+                out = self._rms_norm(out, blk["post_mlp_norm_g"])
+            return x + out, stats
 
     def _block(self, x, blk, cos_sin):
         """One layer of the trunk: a new sequence, nothing kept of it."""
@@ -822,11 +840,12 @@ class LlamaModel:
             from deepspeed_tpu.models import kda
 
             c = self.config
-            fresh = kda.init_state(c, 1, x.shape[0])
-            attn, _, _ = kda.mix(
-                c, self._rms_norm(x, blk["attn_norm_g"]), blk,
-                fresh["kda_conv"][0], fresh["kda_state"][0],
-                differentiable=True)
+            with scope("kda"):
+                fresh = kda.init_state(c, 1, x.shape[0])
+                attn, _, _ = kda.mix(
+                    c, self._rms_norm(x, blk["attn_norm_g"]), blk,
+                    fresh["kda_conv"][0], fresh["kda_state"][0],
+                    differentiable=True)
         else:
             attn, _ = self._attend(x, blk, cos_sin, self._causal)
         return self._block_finish(x, blk, attn)
@@ -834,7 +853,8 @@ class LlamaModel:
     def _trunk(self, params, input_ids, rng=None, with_router_stats=False):
         c = self.config
         B, T = input_ids.shape
-        x = params["wte"].astype(c.dtype)[input_ids]
+        with scope("embed"):
+            x = params["wte"].astype(c.dtype)[input_ids]
         cos_sin = self._rope(jnp.arange(T))
         pattern = c.pattern
 
@@ -856,11 +876,13 @@ class LlamaModel:
                 return carry, None if stats[0] is None else \
                     jax.tree.map(lambda *a: jnp.stack(a), *stats)
 
-            x, stats = layer_scan(scan_body, x, xs)     # the last: routed
+            with scope("layers"):
+                x, stats = layer_scan(scan_body, x, xs)  # the last: routed
         if stats is not None and len(pattern) > 1:      # (L / p, p, ..) -> L
             stats = jax.tree.map(
                 lambda a: a.reshape(-1, *a.shape[2:]), stats)
-        x = self._rms_norm(x, params["norm_g"])
+        with scope("head"):
+            x = self._rms_norm(x, params["norm_g"])
         return (x, stats) if with_router_stats else x
 
     def hidden_states(self, params, input_ids, rng=None):
@@ -869,7 +891,8 @@ class LlamaModel:
     def apply(self, params, input_ids, rng=None):
         """input_ids (B, T) int32 → logits (B, T, V) fp32."""
         x = self._trunk(params, input_ids, rng)
-        return (x @ self._head(params, x.dtype)).astype(jnp.float32)
+        with scope("head"):
+            return (x @ self._head(params, x.dtype)).astype(jnp.float32)
 
     def loss(self, params, batch, rng=None):
         """Next-token cross entropy with the chunked vocab projection
@@ -880,16 +903,18 @@ class LlamaModel:
         c = self.config
         ids, labels, mask = parse_lm_batch(batch)
         x, stats = self._trunk(params, ids, rng, with_router_stats=True)
-        x = x[:, :-1]
-        head = self._head(params, x.dtype)
-        loss = chunked_lm_loss(x, head, labels[:, 1:],
-                               mask[:, 1:] if mask is not None else None,
-                               remat=c.remat_loss_chunks)
+        with scope("head"):
+            x = x[:, :-1]
+            head = self._head(params, x.dtype)
+            loss = chunked_lm_loss(x, head, labels[:, 1:],
+                                   mask[:, 1:] if mask is not None else None,
+                                   remat=c.remat_loss_chunks)
         if stats is not None and c.router_aux_loss_coef:
             from deepspeed_tpu.moe.dropless import load_balancing_loss
 
-            loss = loss + c.router_aux_loss_coef * load_balancing_loss(
-                *stats, n_tokens=ids.size)
+            with scope("moe/router"):
+                loss = loss + c.router_aux_loss_coef * load_balancing_loss(
+                    *stats, n_tokens=ids.size)
         return loss
 
     # ------------------------------------------------------------- inference
@@ -973,20 +998,23 @@ class LlamaModel:
                 from deepspeed_tpu.models.common import kv_cache_write
 
                 attn, kept = self._attend(x, blk, cos_sin, attention)
-                rows = tuple(kv_cache_write(held, t, at, 0)
-                             for held, t in zip(caches, kept))
+                with scope("attn/core"):
+                    rows = tuple(kv_cache_write(held, t, at, 0)
+                                 for held, t in zip(caches, kept))
             return attn, rows + caches[n_rows:]
         from deepspeed_tpu.models import kda
 
         states, tails = caches[n_rows:]
         layer_of = lambda a: jax.lax.dynamic_index_in_dim(a, at, 0,
                                                           keepdims=False)
-        attn, tail, state = kda.mix(
-            self.config, self._rms_norm(x, blk["attn_norm_g"]), blk,
-            layer_of(tails), layer_of(states))
         put = lambda a, new: jax.lax.dynamic_update_index_in_dim(
             a, new.astype(a.dtype), at, 0)
-        return attn, caches[:n_rows] + (put(states, state), put(tails, tail))
+        with scope("kda"):
+            attn, tail, state = kda.mix(
+                self.config, self._rms_norm(x, blk["attn_norm_g"]), blk,
+                layer_of(tails), layer_of(states))
+            return attn, caches[:n_rows] + (put(states, state),
+                                            put(tails, tail))
 
     def _run_cached(self, params, x, cache, cos_sin, pos, attention=None):
         """x through every layer against the cache (``_mix_cached``) -> (x,
@@ -1043,8 +1071,9 @@ class LlamaModel:
                     else jnp.concatenate(given)
 
             n = next(iter(jax.tree.leaves(xs))).shape[0]
-            (x, caches), routed = jax.lax.scan(
-                body, (x, caches), (xs, jnp.arange(n)))
+            with scope("layers"):
+                (x, caches), routed = jax.lax.scan(
+                    body, (x, caches), (xs, jnp.arange(n)))
         if routed is not None and len(pattern) > 1:
             routed = routed.reshape(-1, routed.shape[-1])
         return x, dict(zip(names, caches)), routed
@@ -1055,13 +1084,16 @@ class LlamaModel:
 
         c = self.config
         B, T = input_ids.shape
-        x = params["wte"].astype(c.dtype)[input_ids]
+        with scope("embed"):
+            x = params["wte"].astype(c.dtype)[input_ids]
         attention = lambda q, k, v: local_causal_attention(
             q, k, v, c.use_flash_attention)
         x, out, routed = self._run_cached(
             params, x, cache, self._rope(jnp.arange(T)), 0, attention)
-        x = self._rms_norm(x, params["norm_g"])
-        logits = (x[:, -1] @ self._head(params, x.dtype)).astype(jnp.float32)
+        with scope("head"):
+            x = self._rms_norm(x, params["norm_g"])
+            logits = (x[:, -1] @ self._head(params, x.dtype)
+                      ).astype(jnp.float32)
         out["pos"] = jnp.int32(T)
         if routed is not None:
             out["expert_tokens"] = routed
@@ -1071,11 +1103,14 @@ class LlamaModel:
         """One token for every sequence: (B,) → logits (B, V), cache advanced."""
         c = self.config
         pos = cache["pos"]
-        x = params["wte"].astype(c.dtype)[token][:, None]   # (B, 1, D)
+        with scope("embed"):
+            x = params["wte"].astype(c.dtype)[token][:, None]   # (B, 1, D)
         x, out, routed = self._run_cached(params, x, cache,
                                           self._rope(pos[None]), pos)
-        x = self._rms_norm(x, params["norm_g"])
-        logits = (x[:, 0] @ self._head(params, x.dtype)).astype(jnp.float32)
+        with scope("head"):
+            x = self._rms_norm(x, params["norm_g"])
+            logits = (x[:, 0] @ self._head(params, x.dtype)
+                      ).astype(jnp.float32)
         out["pos"] = pos + 1
         if routed is not None:
             out["expert_tokens"] = cache["expert_tokens"] + routed

@@ -36,6 +36,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.telemetry.scopes import scope
+
 
 def route_topk(x, router_w, k: int, renormalize: bool = False,
                scoring: str = "softmax", scale: float = 1.0):
@@ -43,17 +45,20 @@ def route_topk(x, router_w, k: int, renormalize: bool = False,
     experts (``scoring``: their ``softmax``, or each one's ``sigmoid``),
     ``weights`` (T, k) float32 = the k largest scores, divided by their sum
     with ``renormalize``, times ``scale``; ``experts`` (T, k) int32."""
-    # "highest": a TPU's default float32 matmul is one bf16 pass
-    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
-        else jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
-    if renormalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    if scale != 1.0:
-        weights = weights * scale
-    return probs, weights, experts.astype(jnp.int32)
+    with scope("moe/router"):
+        # "highest": a TPU's default float32 matmul is one bf16 pass
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            router_w.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, k)
+        if renormalize:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        if scale != 1.0:
+            weights = weights * scale
+        return probs, weights, experts.astype(jnp.int32)
 
 
 def load_balancing_loss(expert_tokens, prob_sums, n_tokens):
@@ -96,6 +101,12 @@ def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None,
     experts ``first .. first + E - 1`` of the router's, and a pair whose
     expert is not among them adds exactly zero.
     -> (out (T, D) in x's type, pairs routed to each held expert (E,) int32)."""
+    with scope("moe/experts"):
+        return _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer,
+                           first)
+
+
+def _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer, first):
     T, D = x.shape
     k = experts.shape[1]
     E = gate_w.shape[-3]

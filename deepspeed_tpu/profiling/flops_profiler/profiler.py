@@ -93,13 +93,16 @@ def _walk_jaxpr(jaxpr, scope: str, acc: Dict[str, int], totals: Dict[str, int],
                 mult: int = 1):
     for eqn in jaxpr.eqns:
         prim = eqn.primitive.name
-        name = scope
-        # named_scope shows up via `name` param on some eqns / pjit names
+        # a jax.named_scope (telemetry/scopes.py: attn, mlp, head ...) is in
+        # the equation's name stack, relative to the jaxpr that holds it; a
+        # pjit's own name in its `name` param
+        name = "/".join(filter(None, (
+            scope, str(eqn.source_info.name_stack))))
         if prim in ("pjit", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
                     "remat", "remat2", "checkpoint", "scan", "while", "cond", "closed_call",
                     "shard_map", "custom_partitioning"):
             sub_name = eqn.params.get("name", "")
-            inner_scope = f"{scope}/{sub_name}" if sub_name else scope
+            inner_scope = f"{name}/{sub_name}" if sub_name else name
             inner_mult = mult * int(eqn.params.get("length", 1)) if prim == "scan" else mult
             for key in ("jaxpr", "call_jaxpr", "branches", "cond_jaxpr", "body_jaxpr", "fun_jaxpr"):
                 sub = eqn.params.get(key)

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu import telemetry as _telemetry
+from deepspeed_tpu.telemetry.scopes import scope
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.ops.optimizers import build_optimizer
 from deepspeed_tpu.parallel.topology import DATA_AXIS, EXPERT_AXIS, ParallelGrid
@@ -976,25 +977,26 @@ class DeepSpeedEngine:
         Mirrors stage3.step (stage3.py:1775): overflow check, unscale_and_clip,
         optimizer update, fp32→bf16/fp16 copy-back — but as one fused XLA
         program over the sharded state."""
-        plan = self.plan
-        scale = state.scaler.scale if state.scaler is not None else jnp.float32(1.0)
+        with scope("optimizer/gnorm"):
+            plan = self.plan
+            scale = state.scaler.scale if state.scaler is not None else jnp.float32(1.0)
 
-        # move grads to their ZeRO placement (stage>=2: reduce-scattered)
-        grads = jax.lax.with_sharding_constraint(grads, plan.grad_specs)
+            # move grads to their ZeRO placement (stage>=2: reduce-scattered)
+            grads = jax.lax.with_sharding_constraint(grads, plan.grad_specs)
 
-        finite = grads_finite(grads) if state.scaler is not None else jnp.bool_(True)
+            finite = grads_finite(grads) if state.scaler is not None else jnp.bool_(True)
 
-        # Unscale + global-norm clip WITHOUT materializing a second fp32 grad
-        # tree (at 1B params that tree is 4GB): norms are fused reductions,
-        # and the per-leaf f32 cast happens inside the (fused) scale op.
-        inv_scale = 1.0 / scale
-        clip = self._config.gradient_clipping
-        sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
-        grad_norm = jnp.sqrt(sq) * inv_scale  # unscaled norm (reference clip_grad_norm_)
-        coef = inv_scale
-        if clip > 0:
-            coef = coef * jnp.minimum(1.0, clip / (grad_norm + 1e-6))
-        grads = jax.tree.map(lambda g: (g.astype(jnp.float32) * coef).astype(g.dtype), grads)
+            # Unscale + global-norm clip WITHOUT materializing a second fp32 grad
+            # tree (at 1B params that tree is 4GB): norms are fused reductions,
+            # and the per-leaf f32 cast happens inside the (fused) scale op.
+            inv_scale = 1.0 / scale
+            clip = self._config.gradient_clipping
+            sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
+            grad_norm = jnp.sqrt(sq) * inv_scale  # unscaled norm (reference clip_grad_norm_)
+            coef = inv_scale
+            if clip > 0:
+                coef = coef * jnp.minimum(1.0, clip / (grad_norm + 1e-6))
+            grads = jax.tree.map(lambda g: (g.astype(jnp.float32) * coef).astype(g.dtype), grads)
 
         masters = state.master if state.master is not None else state.params
         opt_state_in = state.opt_state
@@ -1010,59 +1012,61 @@ class DeepSpeedEngine:
             return self._apply_grads_streamed_adam(state, grads, loss,
                                                    grad_norm, finite)
 
-        # whole-tree stream-in (small models / non-Adam optimizers): XLA
-        # overlaps these DMAs with the grad epilogue. When there is no fp32
-        # master, params ARE the optimizer target, so param offload implies
-        # the same stream-in.
-        if state.master is not None:
+        with scope("optimizer/update"):
+            # whole-tree stream-in (small models / non-Adam optimizers): XLA
+            # overlaps these DMAs with the grad epilogue. When there is no fp32
+            # master, params ARE the optimizer target, so param offload implies
+            # the same stream-in.
+            if state.master is not None:
+                if self._host_offload_opt:
+                    masters = jax.device_put(masters, self._dev_kind(self.state_shardings.master))
+            elif self._host_offload_param:
+                masters = jax.device_put(masters, self._dev_kind(self.state_shardings.params))
             if self._host_offload_opt:
-                masters = jax.device_put(masters, self._dev_kind(self.state_shardings.master))
-        elif self._host_offload_param:
-            masters = jax.device_put(masters, self._dev_kind(self.state_shardings.params))
-        if self._host_offload_opt:
-            opt_state_in = jax.device_put(opt_state_in, self._dev_kind(self.state_shardings.opt_state))
-        lr = self._lr_at(state.step)
-        if self._lr_supports_override:
-            updates, new_opt = self.optimizer.update(grads, opt_state_in, masters, lr_override=lr)
-        else:
-            updates, new_opt = self.optimizer.update(grads, opt_state_in, masters)
-        import optax
+                opt_state_in = jax.device_put(opt_state_in, self._dev_kind(self.state_shardings.opt_state))
+            lr = self._lr_at(state.step)
+            if self._lr_supports_override:
+                updates, new_opt = self.optimizer.update(grads, opt_state_in, masters, lr_override=lr)
+            else:
+                updates, new_opt = self.optimizer.update(grads, opt_state_in, masters)
+            import optax
 
-        new_masters = optax.apply_updates(masters, updates)
-        new_masters = jax.lax.with_sharding_constraint(new_masters, plan.master_specs if state.master is not None else plan.param_specs)
+            new_masters = optax.apply_updates(masters, updates)
+            new_masters = jax.lax.with_sharding_constraint(new_masters, plan.master_specs if state.master is not None else plan.param_specs)
 
-        keep = lambda new, old: jnp.where(finite, new, old)
-        new_masters = jax.tree.map(keep, new_masters, masters)
-        new_opt = jax.tree.map(keep, new_opt, opt_state_in)
+            keep = lambda new, old: jnp.where(finite, new, old)
+            new_masters = jax.tree.map(keep, new_masters, masters)
+            new_opt = jax.tree.map(keep, new_opt, opt_state_in)
 
-        if state.master is not None:
-            new_params = jax.tree.map(
-                lambda m, p: m.astype(p.dtype) if jnp.issubdtype(p.dtype, jnp.floating) else m,
-                new_masters, state.params)
-            new_params = jax.lax.with_sharding_constraint(new_params, plan.param_specs)
-            master_out = new_masters
-        else:
-            new_params = new_masters
-            master_out = None
+        with scope("optimizer/cast"):
+            if state.master is not None:
+                new_params = jax.tree.map(
+                    lambda m, p: m.astype(p.dtype) if jnp.issubdtype(p.dtype, jnp.floating) else m,
+                    new_masters, state.params)
+                new_params = jax.lax.with_sharding_constraint(new_params, plan.param_specs)
+                master_out = new_masters
+            else:
+                new_params = new_masters
+                master_out = None
 
-        if self._host_offload_opt:
-            # stream updated fp32 state back out to host memory
-            if master_out is not None:
-                master_out = jax.device_put(master_out, self.state_shardings.master)
-            new_opt = jax.device_put(new_opt, self.state_shardings.opt_state)
-        if self._host_offload_param:
-            new_params = jax.device_put(new_params, self.state_shardings.params)
+            if self._host_offload_opt:
+                # stream updated fp32 state back out to host memory
+                if master_out is not None:
+                    master_out = jax.device_put(master_out, self.state_shardings.master)
+                new_opt = jax.device_put(new_opt, self.state_shardings.opt_state)
+            if self._host_offload_param:
+                new_params = jax.device_put(new_params, self.state_shardings.params)
 
-        new_scaler = self.loss_scaler.update(state.scaler, finite) if state.scaler is not None else None
-        new_state = TrainState(step=state.step + 1,
-                               params=new_params,
-                               master=master_out,
-                               opt_state=new_opt,
-                               scaler=new_scaler,
-                               rng=jax.random.fold_in(state.rng, state.step),
-                               skipped_steps=state.skipped_steps + (~finite).astype(jnp.int32))
-        metrics = StepMetrics(loss=loss, grad_norm=grad_norm, lr=lr,
-                              loss_scale=scale, overflow=~finite)
+            new_scaler = self.loss_scaler.update(state.scaler, finite) if state.scaler is not None else None
+            new_state = TrainState(step=state.step + 1,
+                                   params=new_params,
+                                   master=master_out,
+                                   opt_state=new_opt,
+                                   scaler=new_scaler,
+                                   rng=jax.random.fold_in(state.rng, state.step),
+                                   skipped_steps=state.skipped_steps + (~finite).astype(jnp.int32))
+            metrics = StepMetrics(loss=loss, grad_norm=grad_norm, lr=lr,
+                                  loss_scale=scale, overflow=~finite)
         return new_state, metrics
 
     def _offload_streamed(self) -> bool:
@@ -1313,16 +1317,22 @@ class DeepSpeedEngine:
             acc = jax.tree.map(lambda a, g: a + g.astype(acc_dtype), acc, grads)
             return (acc, i + 1), loss
 
-        zero_acc = jax.tree.map(lambda s: jnp.zeros(s.shape, acc_dtype),
-                                jax.eval_shape(lambda: params_c))
-        zero_acc = jax.lax.with_sharding_constraint(zero_acc, plan.grad_specs)
-        # NOT unrolled: measured on v5e gpt2-760m/gas=4, unroll=2 OOMs by
-        # 1.9G and unroll=4 by 4.7G — XLA interleaves the unrolled micros,
-        # so each extra body keeps a full live activation set (~1.8G). The
-        # scan's sequencing is what bounds gas>1 memory to one micro.
-        (acc, _), losses = jax.lax.scan(body, (zero_acc, jnp.int32(0)), mbs)
-        return jnp.mean(losses), jax.tree.map(
-            lambda g: (g.astype(jnp.float32) / gas).astype(g.dtype), acc)
+        # scope "accumulate": what the scan itself adds (the accumulator, its
+        # adds, the micro-batch slices); a micro-batch's ops keep the model's
+        with scope("accumulate"):
+            zero_acc = jax.tree.map(lambda s: jnp.zeros(s.shape, acc_dtype),
+                                    jax.eval_shape(lambda: params_c))
+            zero_acc = jax.lax.with_sharding_constraint(zero_acc,
+                                                        plan.grad_specs)
+            # NOT unrolled: measured on v5e gpt2-760m/gas=4, unroll=2 OOMs by
+            # 1.9G and unroll=4 by 4.7G — XLA interleaves the unrolled
+            # micros, so each extra body keeps a full live activation set
+            # (~1.8G). The scan's sequencing is what bounds gas>1 memory to
+            # one micro.
+            (acc, _), losses = jax.lax.scan(body, (zero_acc, jnp.int32(0)),
+                                            mbs)
+            return jnp.mean(losses), jax.tree.map(
+                lambda g: (g.astype(jnp.float32) / gas).astype(g.dtype), acc)
 
     def _build_train_batch_fn(self, gas: int):
         """Fused train step: scan over gradient-accumulation microbatches.

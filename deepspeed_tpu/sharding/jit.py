@@ -27,9 +27,12 @@ the jitted callable unchanged (lower/compile/AOT all still work).
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import inspect
 import os
+import re
 import time
 import weakref
 from typing import Any, Dict, List, Optional, Tuple
@@ -63,7 +66,11 @@ class ProgramRecord:
     the resolved promise trees, and — captured at the first real
     dispatch — abstract argument shapes carrying each COMMITTED
     operand's sharding (so an INHERIT program re-lowers against the
-    same placements it actually compiled with)."""
+    same placements it actually compiled with).
+
+    That is also enough to say what the program IS, on demand and never on
+    the call path: :meth:`compiled` is the one lower-and-compile of a
+    record, :meth:`instruction_scopes` and :meth:`memory` read its result."""
 
     label: str
     call_site: str
@@ -87,6 +94,8 @@ class ProgramRecord:
     jitted_ref: Any = None      # callable -> jitted | None
     abstract_args: Optional[Tuple] = None   # captured at first dispatch
     abstract_kwargs: Optional[Dict[str, Any]] = None
+    _compiled: Any = dataclasses.field(default=None, repr=False,
+                                       compare=False)
 
     @property
     def jitted(self):
@@ -97,6 +106,109 @@ class ProgramRecord:
     def can_lower(self) -> bool:
         """True while a dispatch-captured, re-lowerable program is alive."""
         return self.jitted is not None and self.abstract_args is not None
+
+    def compiled(self):
+        """The program lowered from ``abstract_args`` under ``mesh`` and
+        compiled again: instruction for instruction what the dispatch runs,
+        with THIS tree's metadata; kept while the program lives. None for a
+        record that was never dispatched or whose program was collected;
+        what the lowering or the compiler raises is the caller's."""
+        if not self.can_lower():
+            self._compiled = None
+            return None
+        if self._compiled is None:
+            # Not the dispatch's executable: jax's persistent cache keys a
+            # program WITHOUT its metadata (op_name, source lines), so a hit
+            # may have handed the dispatch what another tree compiled, under
+            # that tree's names, and ``lower()`` hands back that very
+            # lowering with its executable. A compiler option (this one at
+            # XLA's default) makes it compile anew, and this compile keys
+            # on the metadata too: a later process of this tree hits it.
+            flag = "jax_compilation_cache_include_metadata_in_key"
+            keep = getattr(jax.config, flag)
+            jax.config.update(flag, True)
+            try:
+                # traces that constrain with bare PartitionSpecs need the
+                # mesh context at lower time, exactly like the dispatch
+                with self.mesh if self.mesh is not None \
+                        else contextlib.nullcontext():
+                    self._compiled = self.jitted.lower(
+                        *self.abstract_args,
+                        **(self.abstract_kwargs or {})).compile(
+                            compiler_options={
+                                "xla_embed_ir_in_executable": False})
+            finally:
+                jax.config.update(flag, keep)
+        return self._compiled
+
+    def instruction_scopes(self) -> Optional[Dict[str, str]]:
+        """``{HLO instruction name: op_name}`` over every computation of
+        the optimized module (``""`` where there is none):
+        ``telemetry.scopes.classify`` reads a scope and a pass from an
+        ``op_name``. None as :meth:`compiled`."""
+        compiled = self.compiled()
+        return None if compiled is None \
+            else _instruction_scopes(compiled.as_text())
+
+    def memory(self) -> Optional[Dict[str, int]]:
+        """Bytes a device holds for this program, as the compiler counts
+        them (``memory_analysis()``): ``argument``, ``output``, ``alias``
+        (outputs written into donated arguments), ``temp``,
+        ``generated_code``, and ``total`` = argument + output - alias +
+        temp + generated_code. None as :meth:`compiled`."""
+        compiled = self.compiled()
+        if compiled is None:
+            return None
+        m = compiled.memory_analysis()
+        out = {k: int(getattr(m, f"{k}_size_in_bytes")) for k in
+               ("argument", "output", "alias", "temp", "generated_code")}
+        out["total"] = sum(out.values()) - 2 * out["alias"]
+        return out
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"[( ]%([\w.\-]+)")
+
+
+def _instruction_scopes(text: str) -> Dict[str, str]:
+    """One pass over a compiled module's text, which lists a computation
+    before its callers and an operand before its users. An instruction with
+    no metadata of its own takes, if it calls a computation (some fusions),
+    the ``op_name`` of that computation's ROOT, else the commonest among its
+    instructions; if it calls none (what the compiler made itself: a
+    combined collective, the done of a start, a layout copy), that of its
+    first operand that has one."""
+    names: Dict[str, str] = {}
+    roots: Dict[str, str] = {}
+    seen: Dict[str, collections.Counter] = {}
+    comp = ""
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            head = _COMPUTATION.match(line)
+            if head is not None:
+                comp = head.group(1)
+            continue
+        is_root, name = m.groups()
+        op = _OP_NAME.search(line)
+        if op:
+            names[name] = op.group(1)
+            seen.setdefault(comp, collections.Counter())[op.group(1)] += 1
+            if is_root:
+                roots[comp] = op.group(1)
+            continue
+        callee = _CALLS.search(line)
+        if callee:
+            inner = seen.get(callee.group(1))
+            names[name] = roots.get(callee.group(1)) or (
+                inner.most_common(1)[0][0] if inner else "")
+        else:
+            names[name] = next(filter(None, (
+                names.get(o) for o in _OPERAND.findall(line, m.end()))), "")
+    return names
 
 
 from deepspeed_tpu.utils import locks as _locks

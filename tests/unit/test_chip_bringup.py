@@ -10,6 +10,8 @@ no process holds a chip and then spawns a child that needs it, no fallback
 hides the device, one cache directory placed from outside.
 """
 
+import collections
+import contextlib
 import dataclasses
 import functools
 import math
@@ -335,7 +337,9 @@ def _compiled_train_step(devices, config, traffic_file, chips):
     accumulation steps, bf16, AdamW, clipping, remat 'attn'), compiled for
     ``chips`` described chips over 'data'. Nothing can be placed on a
     described device, so the two places where the engine materializes state
-    hand back shapes instead."""
+    hand back shapes instead. -> (the engine, which keeps the program alive,
+    the program's record at the door with these shapes as its arguments):
+    ``record.compiled()`` is the ONE compile the tests below read."""
     import json
     import types
 
@@ -376,22 +380,32 @@ def _compiled_train_step(devices, config, traffic_file, chips):
     batch = {"input_ids": jax.ShapeDtypeStruct(
         (gas * chips * micro, traffic["seq_len"]), jnp.int32,
         sharding=engine.sharding.batch_sharding(2))}
-    with mesh:
-        return engine._get_compiled_train_batch(gas, batch).lower(
-            engine.state, batch).compile()
+    record = engine._get_compiled_train_batch(gas, batch).program_record
+    record.abstract_args, record.abstract_kwargs = (engine.state, batch), {}
+    return engine, record
 
 
 @pytest.fixture(scope="module")
-def xl_z3_step(v5e):
+def xl_z3_record(v5e):
     """``gpt2-xl.train.z3x4``: ZeRO-3 over data=4, micro-batch 16 a chip."""
     return _compiled_train_step(v5e, "gpt2-xl.json", "train.z3x4.json", 4)
 
 
 @pytest.fixture(scope="module")
-def gas4_step(v5e):
+def gas4_record(v5e):
     """``gpt2-760m.train.z1.gas4``: ZeRO-1 on one chip, 4 micro-batches of 6
     accumulated in float32 by the engine's scan."""
     return _compiled_train_step(v5e, "gpt2-760m.json", "train.z1.gas4.json", 1)
+
+
+@pytest.fixture(scope="module")
+def xl_z3_step(xl_z3_record):
+    return xl_z3_record[1].compiled()
+
+
+@pytest.fixture(scope="module")
+def gas4_step(gas4_record):
+    return gas4_record[1].compiled()
 
 
 def _computations(text):
@@ -541,6 +555,131 @@ def test_gas4_step_at_micro_batch_6_compiles_and_runs_the_forward_once(gas4_step
     remat = len(re.findall(r"%[\w.\-]*\.remat[\w.\-]* = ", text))
     assert remat <= GAS4_REMAT_OPS, (
         f"{remat} ops rematerialized by XLA (PR 32: {GAS4_REMAT_OPS})")
+
+
+# ------------- the real steps under the program's own names (scopes, PR 35)
+@pytest.fixture(params=["xl_z3", "gas4"])
+def step_record(request):
+    return request.getfixturevalue(f"{request.param}_record")[1]
+
+
+def _own_instructions(text):
+    """[(name, opcode, the line)] of the non-fused computations."""
+    comps, _, _ = _computations(text)
+    rows = []
+    for comp, lines in comps.items():
+        if "fused_computation" in comp:
+            continue
+        for line in lines:
+            m = re.match(r"\s+(?:ROOT )?%?([\w.\-]+) = .*?\s([\w\-]+)\(", line)
+            if m:
+                rows.append((m.group(1), m.group(2), line))
+    return rows
+
+
+def test_the_flash_kernels_keep_their_instruction_names(step_record):
+    """The ``name=`` of a ``pallas_call`` stays the LAST scope of its
+    ``op_name``, so the Mosaic calls are still the instructions the
+    benchmark's ``train.flash_*`` readers and the ledger's ``breakdown``
+    know, and each now says which scope and pass it runs in."""
+    from deepspeed_tpu.telemetry.scopes import classify
+
+    table = step_record.instruction_scopes()
+    kernels = {n: classify(table[n], n) for n, _, line in _own_instructions(
+        step_record.compiled().as_text()) if "tpu_custom_call" in line}
+    by_kernel = {re.sub(r"[.\d]+$", "", n): v for n, v in kernels.items()}
+    assert by_kernel == {"flash_fwd": ("attn/core", "fwd"),
+                         "flash_bwd_dq": ("attn/core", "bwd"),
+                         "flash_bwd_dkv": ("attn/core", "bwd")}, kernels
+
+
+def test_the_steps_device_work_resolves_to_a_scope(step_record):
+    """Of the instructions that take device time in a profile — fusions,
+    copies, custom calls, collectives of the non-fused computations — at
+    least 90% carry one of the program's scopes. Not counted, because they
+    are the compiler's own and move no data or wait for a copy it scheduled
+    itself: ``AllocateBuffer`` / ``ConcatBitcast`` custom calls and the
+    ``copy-start`` / ``copy-done`` / ``slice-start`` / ``slice-done`` pairs
+    of its memory-space assignment."""
+    from deepspeed_tpu.telemetry.scopes import classify
+
+    table = step_record.instruction_scopes()
+    kinds = re.compile(r"^(fusion|copy|custom-call|convolution|all-gather|"
+                       r"all-reduce|reduce-scatter|all-to-all|"
+                       r"collective-permute)(-start|-done)?$")
+    rows = [(n, kind) for n, kind, line in _own_instructions(
+        step_record.compiled().as_text())
+        if kinds.match(kind) and not kind.startswith("copy-")
+        and not re.search(r'custom_call_target="(AllocateBuffer|'
+                          r'ConcatBitcast)"', line)]
+    scoped = [bool(classify(table[n], n)[0]) for n, _ in rows]
+    assert len(rows) > 150 and sum(scoped) / len(rows) >= 0.9, (
+        sum(scoped), len(rows),
+        collections.Counter(k for (n, k), s in zip(rows, scoped) if not s))
+
+
+def test_the_steps_names_cover_every_phase(step_record):
+    from deepspeed_tpu.telemetry.scopes import classify
+
+    found = collections.defaultdict(set)
+    for name, op_name in step_record.instruction_scopes().items():
+        scope, pass_ = classify(op_name, name)
+        found[scope.split("/")[0]].add(pass_)
+    assert {"embed", "attn", "mlp", "head", "layers", "optimizer"} \
+        <= set(found)
+    for scope in ("attn", "mlp"):
+        assert {"fwd", "bwd", "recompute"} <= found[scope], found
+    assert ("accumulate" in found) == \
+        (step_record.label == "engine/train_batch[gas=4]")
+
+
+def test_the_doors_memory_is_the_footprint(step_record):
+    """``memory()``'s sum is what ``_footprint`` counts plus the program's
+    own code: the figure ``train.step_hbm_frac`` divides by 16 GiB."""
+    compiled = step_record.compiled()
+    arguments, total = _footprint(compiled)
+    m = step_record.memory()
+    assert m["argument"] == arguments
+    assert m["total"] - m["generated_code"] == total
+    assert 0 < m["generated_code"] < 64 << 20
+
+
+def _two_layer_step_text(devices, monkeypatch_scope):
+    from deepspeed_tpu.telemetry import scopes
+
+    mesh = _mesh(devices)
+    model = GPT2Model(dataclasses.replace(PRESETS["gpt2-760m"], remat="attn",
+                                          n_layer=2))
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _abstract(s.shape, jnp.bfloat16, mesh),
+                          shapes)
+    ids = _abstract((8, 1024), jnp.int32, mesh)
+    with pytest.MonkeyPatch.context() as mp:
+        if monkeypatch_scope:
+            mp.setattr(scopes, "_named_scope",
+                       lambda name: contextlib.nullcontext())
+        with mesh:
+            return jax.jit(jax.value_and_grad(
+                lambda p, b: model.loss(p, {"input_ids": b}))).lower(
+                    params, ids).compile().as_text()
+
+
+def test_scopes_are_metadata_and_nothing_else(v5e):
+    """A two-layer step at ``z1``'s widths compiled for one v5e chip with the
+    helper real and with the helper a no-op: the SAME instructions, names,
+    shapes, layouts and schedule, once ``metadata={...}`` is cut (and the
+    module's tables of file names and stack frames, which are metadata
+    too)."""
+    def instructions(text):
+        text = re.sub(r", metadata=\{[^}]*\}", "", text)
+        return [l for l in text.splitlines()
+                if re.match(r"^(\s+(ROOT )?%|ENTRY |%|\}|HloModule)", l)]
+
+    scoped = _two_layer_step_text(v5e, False)
+    plain = _two_layer_step_text(v5e, True)
+    assert "attn/core/flash_fwd" in scoped and "attn/core" not in plain
+    assert instructions(scoped) == instructions(plain)
+    assert len(instructions(scoped)) > 2000
 
 
 # ------------------------------------ OLMoE-1B-7B at its published widths

@@ -8,6 +8,7 @@ is never materialized — at V≈50k that is multiple GB per microbatch.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Optional
@@ -57,6 +58,27 @@ def layer_scan(body, init, xs, unroll: int = 1):
     if impl is None:
         return jax.lax.scan(body, init, xs, unroll=max(1, int(unroll)))
     return impl(body, init, xs, unroll)
+
+
+# The same kind of indirection for what a block is HANDED: under ZeRO-3 on
+# more than one chip the engine installs the placement layer's gather-on-use
+# rule (runtime/zero/partition.py::LayerGathers) around the trace of the
+# loss's gradient, and `remat_wrap` applies it to the block's arguments
+# INSIDE the block's checkpoint. With nothing installed `remat_wrap` traces
+# what it always did.
+_LAYER_LEAVES_HOOK = None
+
+
+@contextlib.contextmanager
+def layer_leaves_hook(hook):
+    """``hook(args) -> args`` over every ``remat_wrap``-ed block traced in
+    the body (None: none)."""
+    global _LAYER_LEAVES_HOOK
+    prev, _LAYER_LEAVES_HOOK = _LAYER_LEAVES_HOOK, hook
+    try:
+        yield
+    finally:
+        _LAYER_LEAVES_HOOK = prev
 
 
 def alibi_slopes(n_head: int):
@@ -186,8 +208,25 @@ def remat_wrap(fn, remat):
     matmuls and never attention: the best FLOPs / HBM trade when ``'dots'``
     does not fit. ``'attn_mlp'`` also keeps the MLP's activation
     (``mlp_act``): neither attention nor the two fat MLP matmuls are re-run,
-    ~8 d^2 of the 12 d^2 a layer recomputed go for 4 d a token more HBM."""
+    ~8 d^2 of the 12 d^2 a layer recomputed go for 4 d a token more HBM.
+
+    Where a hook on the block's leaves is installed (:func:`layer_leaves_hook`:
+    ZeRO-3's gather-on-use), it runs on the arguments inside the checkpoint:
+    a layer then keeps its SHARDED leaves and the backward gathers again. A
+    gather outside it would make the gathered leaves inputs of the
+    checkpoint, which the layer scan stacks over all layers. Without a
+    ``remat`` the block is checkpointed for the gathered leaves alone."""
     policies = jax.checkpoint_policies
+    hook = _LAYER_LEAVES_HOOK
+    if hook is not None:
+        block = fn
+        fn = lambda *args: block(*hook(args))
+        if not remat:
+            from deepspeed_tpu.runtime.zero.partition import GATHERED_NAME
+
+            return jax.checkpoint(
+                fn, policy=policies.save_anything_except_these_names(
+                    GATHERED_NAME))
     if remat in (True, "full"):
         return jax.checkpoint(fn, policy=policies.nothing_saveable)
     if remat == "dots":

@@ -461,6 +461,16 @@ class LlamaModel:
                                              proj_scale))
         return blocks
 
+    @property
+    def stacked_params_key(self):
+        """The layer-stacked subtrees of :meth:`init_params` (ZeRO judges
+        their leaves a layer and states their gather: zero/partition.py);
+        first the one every layer has."""
+        c = self.config
+        return ("blocks",) \
+            + (("attn_blocks", "kda_blocks") if c.gqa_layers is not None else ()) \
+            + (("dense_blocks",) if c.n_dense_layers else ())
+
     def init_params(self, rng) -> Dict[str, Any]:
         """``blocks``: the layers' leaves, stacked over the layers (with
         ``dense_blocks`` ahead of them where leading layers are dense). A

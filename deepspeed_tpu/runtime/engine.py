@@ -12,8 +12,10 @@ The reference's three-call API (``forward`` engine.py:1663, ``backward`` :1804,
 as a ``lax.scan``).
 
 What the reference does with streams/hooks, XLA does in the scheduler: ZeRO-3
-allgather-on-use + prefetch = GSPMD sharded params; overlapped reduce-scatter =
-grad sharding constraints; bucket sizes become advisory (SURVEY §7).
+allgather-on-use + prefetch = GSPMD sharded params, gathered where the program
+says (the layer stack's leaves where the block uses them, the loss head once a
+step: zero/partition.py); overlapped reduce-scatter = grad sharding
+constraints; bucket sizes become advisory (SURVEY §7).
 """
 
 from __future__ import annotations
@@ -40,7 +42,8 @@ from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.fp16.loss_scaler import (CreateLossScaler, DynamicLossScaler,
                                                     LossScaleState, grads_finite)
 from deepspeed_tpu.runtime.lr_schedules import LRSchedule, build_lr_schedule
-from deepspeed_tpu.runtime.zero.partition import ShardingPlan, partition_report, plan_sharding
+from deepspeed_tpu.runtime.zero.partition import (ShardingPlan, partition_report,
+                                                  plan_sharding, stacked_param_keys)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER, NoopTimer,
                                        STEP_GLOBAL_TIMER, SynchronizedWallClockTimer,
@@ -298,7 +301,8 @@ class DeepSpeedEngine:
         if hasattr(model, "param_partition_specs"):
             tp_specs = model.param_partition_specs()
         self.plan: ShardingPlan = plan_sharding(
-            param_shapes, mesh, zero_config=self._config.zero_config, tp_specs=tp_specs)
+            param_shapes, mesh, zero_config=self._config.zero_config, tp_specs=tp_specs,
+            stacked_keys=stacked_param_keys(model))
         # the spec registry the plan is a view over — the ONE source every
         # sharded_jit call site reads its in/out shardings from
         self.sharding = self.plan.registry
@@ -487,6 +491,17 @@ class DeepSpeedEngine:
             from deepspeed_tpu.runtime.overlap import OverlapEngine
 
             self._overlap = OverlapEngine(self, self._config.overlap)
+
+        # ---- ZeRO-3 gather-on-use of the layer stack ---------------------
+        # zero/partition.py: the plan's rule (None on one chip, at stage
+        # 0-2, where no stacked leaf is sharded), applied to what a block is
+        # handed while the loss's gradient is traced. Not where the step is
+        # manual over the data axes (1-bit) or the overlap engine gathers
+        # the layers itself (its ring, its serial phase): no leaf twice.
+        self._layer_gathers = self.plan.layer_gathers
+        if self._onebit or (self._overlap is not None
+                            and self._overlap.gathers_layers):
+            self._layer_gathers = None
 
         # ---- materialize state sharded ----------------------------------
         self.state, self.state_shardings = self._init_state(init_fn, param_shapes, seed_key)
@@ -951,7 +966,14 @@ class DeepSpeedEngine:
             loss = out[0] if isinstance(out, tuple) else out
             return loss.astype(jnp.float32) * scale, loss
 
-        grads, loss = jax.grad(scaled_loss, has_aux=True)(params)
+        grad = jax.grad(scaled_loss, has_aux=True)
+        if self._layer_gathers is None:
+            grads, loss = grad(params)
+        else:
+            from deepspeed_tpu.models.common import layer_leaves_hook
+
+            with layer_leaves_hook(self._layer_gathers):
+                grads, loss = grad(params)
         return loss, grads
 
     def _loss_accepts_rng(self) -> bool:

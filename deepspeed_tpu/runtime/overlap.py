@@ -54,15 +54,17 @@ import os
 import threading
 import time
 from contextlib import contextmanager, nullcontext
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.runtime.zero.partition import (ShardingPlan, _axes_of,
-                                                  _spec_tuple)
+from deepspeed_tpu.runtime.zero.partition import (GATHERED_NAME, ShardingPlan,
+                                                  _axes_of, _spec_tuple,
+                                                  drop_dp_axes, gather_on_use,
+                                                  stacked_param_keys)
 from deepspeed_tpu.utils import locks as _locks
 from deepspeed_tpu.utils.logging import log_dist, logger
 
@@ -84,9 +86,6 @@ SCHEDULER_FLAG_PRESET = (
     "--xla_enable_async_all_gather=true",
     "--xla_enable_async_collective_permute=true",
 )
-
-_GATHERED_NAME = "zero3_gathered"
-
 
 def scheduler_flag_status() -> List[Tuple[str, bool]]:
     """(flag, present-in-XLA_FLAGS) for the preset — what ``ds_report``
@@ -139,16 +138,6 @@ def apply_scheduler_flags() -> List[str]:
 # ---------------------------------------------------------------------------
 # gathered-spec math
 # ---------------------------------------------------------------------------
-def drop_dp_axes(spec: Optional[P], ndim: int, dp_axes: Sequence[str]) -> P:
-    """The GATHERED twin of a ZeRO-sharded spec: same tp placement, dp
-    axes removed (the all-gather GSPMD inserts to honor the change)."""
-    out = []
-    for entry in _spec_tuple(spec, ndim):
-        axes = tuple(a for a in _axes_of(entry) if a not in dp_axes)
-        out.append(axes[0] if len(axes) == 1 else (axes if axes else None))
-    return P(*out)
-
-
 def gathered_param_specs(plan: ShardingPlan, param_shapes: Any) -> Any:
     """plan.param_specs with the dp axes dropped from every leaf — the
     placement of the serial schedule's explicit gather phase."""
@@ -220,29 +209,15 @@ class StackedGatherPlan:
                    for l, s in zip(leaves, self.stacked_shapes))
 
     def _gather_leaf(self, x, gathered: P, sharded: P):
-        """with_sharding_constraint to the gathered layout, with a
-        custom_vjp so the BACKWARD issues the per-block reduce-scatter
-        (cotangent constrained straight back to the sharded layout) —
-        grad_reduce="scan". "post" keeps the plain constraint: cotangents
-        stay gathered through the backward scan and the engine's final
-        grad constraint does one fused reduction."""
-        g_sh = NamedSharding(self.mesh, gathered)
-        if self.grad_reduce != "scan":
-            return jax.lax.with_sharding_constraint(x, g_sh)
-        s_sh = NamedSharding(self.mesh, sharded)
-
-        @jax.custom_vjp
-        def gather(v):
-            return jax.lax.with_sharding_constraint(v, g_sh)
-
-        def fwd(v):
-            return gather(v), None
-
-        def bwd(_, ct):
-            return (jax.lax.with_sharding_constraint(ct, s_sh),)
-
-        gather.defvjp(fwd, bwd)
-        return gather(x)
+        """The placement layer's gather-on-use (zero/partition.py), whose
+        BACKWARD issues the per-block reduce-scatter — grad_reduce="scan".
+        "post" keeps the plain constraint: cotangents stay gathered through
+        the backward scan and the engine's final grad constraint does one
+        fused reduction."""
+        return gather_on_use(
+            x, NamedSharding(self.mesh, gathered),
+            NamedSharding(self.mesh, sharded)
+            if self.grad_reduce == "scan" else None)
 
     def gather_slice(self, sliced_element: Any, sec_slices=None) -> Any:
         """Gather one layer's slice of the stacked subtree (leaves without
@@ -277,7 +252,7 @@ class StackedGatherPlan:
                     "zero3_gather", stacked[1:], getattr(leaf, "dtype", "?"),
                     self.dp_axes)
                 g = self._gather_leaf(leaf, gathered, sharded)
-            out.append(checkpoint_name(g, _GATHERED_NAME))
+            out.append(checkpoint_name(g, GATHERED_NAME))
         return jax.tree_util.tree_unflatten(self.treedef, out)
 
     # ------------------------------------------------- hpZ secondary replica
@@ -330,7 +305,7 @@ def find_stacked_plan(engine, cfg) -> Optional[StackedGatherPlan]:
     """The model's layer-stacked param subtree, as a gather plan — None
     when there is nothing to prefetch (no stacked key, stage < 3, or no
     leaf actually dp-sharded)."""
-    key = getattr(engine.module, "stacked_params_key", "blocks")
+    key = stacked_param_keys(engine.module)[0]   # the ring walks one stack
     shapes = getattr(engine.plan, "_master_shapes", None)
     specs = engine.plan.param_specs
     if not (isinstance(shapes, dict) and key in shapes
@@ -495,6 +470,15 @@ class OverlapEngine:
             return "overlapped"
         return self.cfg.schedule
 
+    @property
+    def gathers_layers(self) -> bool:
+        """This engine gathers the layer stack's ZeRO-3 leaves itself (the
+        serial schedule's phase, the prefetch ring), so the engine's default
+        gather-on-use (zero/partition.py::LayerGathers) stays off."""
+        return self.schedule == "serial" or (
+            self.schedule == "overlapped" and self.cfg.param_prefetch > 0
+            and self._stacked is not None)
+
     def invalidate_compiled(self):
         self._gather_compiled = None
         self._serial_compute = {}
@@ -514,7 +498,7 @@ class OverlapEngine:
                     "overlap: param-gather prefetch inactive — the model "
                     "exposes no dp-sharded layer-stacked param subtree "
                     "(key "
-                    f"{getattr(self.engine.module, 'stacked_params_key', 'blocks')!r}"
+                    f"{stacked_param_keys(self.engine.module)[0]!r}"
                     "); the step compiles unrestructured", ranks=[0])
             return nullcontext()
         depth = self.cfg.param_prefetch

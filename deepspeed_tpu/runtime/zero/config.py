@@ -88,7 +88,9 @@ class DeepSpeedZeroConfig(DeepSpeedConfigModel):
                                  "new_param_fn": lambda v: DeepSpeedZeroOffloadOptimizerConfig(device="cpu") if v else None})
 
     prefetch_bucket_size: int = Field(50_000_000, ge=0, alias="stage3_prefetch_bucket_size")
-    param_persistence_threshold: int = Field(100_000, ge=0, alias="stage3_param_persistence_threshold")
+    param_persistence_threshold: int = Field(
+        100_000, ge=0, alias="stage3_param_persistence_threshold",
+        description="stage 3: a parameter under this many elements stays whole on every chip (its master, moments and gradient stay sharded). A leaf of a layer-stacked subtree (`model.stacked_params_key`, `blocks` by default) is judged by the elements ONE layer holds, as the reference judges one layer's parameter; any other leaf by all of its elements")
     model_persistence_threshold: int = Field(2**63 - 1, ge=0, alias="stage3_model_persistence_threshold")
     max_live_parameters: int = Field(1_000_000_000, ge=0, alias="stage3_max_live_parameters")
     max_reuse_distance: int = Field(1_000_000_000, ge=0, alias="stage3_max_reuse_distance")

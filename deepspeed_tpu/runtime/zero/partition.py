@@ -9,13 +9,40 @@ the *placement* declaratively and lets XLA generate the collectives:
   stage 0  params replicated, grads all-reduced (psum), optimizer replicated
   stage 1  + optimizer state (and fp32 master weights) sharded over the DP axes
   stage 2  + gradients sharded over the DP axes (psum → reduce_scatter)
-  stage 3  + parameters themselves sharded over the DP axes (allgather-on-use,
-             which XLA schedules per-layer and overlaps — the role of the
-             reference's PartitionedParameterCoordinator prefetch machinery)
+  stage 3  + parameters themselves sharded over the DP axes, gathered on use
+
+**Stage 3: who gathers, and where.** A placement at rest says nothing of
+where a sharded weight becomes whole, and the partitioner, left alone, picks
+a different form in each pass (gpt2-xl over data=4: the backward's re-run
+gathered the weight, the forward gathered the ACTIVATIONS of every chip and
+sent the product back through an all-to-all, or ran the matmul windowed over
+the shards: two to three times the re-run's time). So the program states it,
+in two places, and the partitioner keeps the rest (embeddings, final norm):
+
+* **the layer stack** (:class:`LayerGathers`): every leaf of a layer's slice
+  that ZeRO sharded is constrained, where the block uses it, to its spec
+  WITHOUT the DP axes (tensor / expert axes kept: :func:`drop_dp_axes`)
+  under a ``custom_vjp`` whose backward constrains the cotangent to the
+  SHARDED spec (:func:`gather_on_use`): one async all-gather a leaf a pass,
+  the weight gradient leaves as a reduce-scatter. The seat is
+  ``models/common.py::remat_wrap``, INSIDE the block's ``jax.checkpoint``,
+  so what a layer keeps for its backward is the sharded slice and the
+  backward gathers again (a gather outside the checkpoint makes the scan
+  stack L gathered layers). The engine installs the rule around the trace
+  of the loss's gradient when the plan is stage 3 over more than one chip;
+  with the ``overlap`` block its prefetch ring gathers instead
+  (``runtime/overlap.py``, the same pair). One chip, stage 0-2, a leaf
+  without a DP axis, a region that is already manual: nothing is traced.
+* **the loss head**: ``models/common.py::chunked_lm_loss`` (a ``shard_map``
+  over the batch axes that the head enters whole, once a step).
 
 ``param_persistence_threshold`` keeps small params replicated in stage 3 just
 like the reference's "persistent parameters" (stage3.py persistence threshold),
-avoiding per-tiny-tensor allgathers. MiCS-style scoped sharding
+avoiding per-tiny-tensor allgathers. As in the reference it judges ONE
+layer's parameter: a leaf of a layer-stacked subtree (``stacked_keys``:
+``model.stacked_params_key``, ``"blocks"`` by default) by the elements one
+layer holds, any other leaf whole. Masters, moments and gradients of a
+persistent leaf stay sharded. MiCS-style scoped sharding
 (zero/mics.py:31) falls out of restricting ``shard_axes`` to a sub-axis of the
 mesh: params replicate across the remaining DP axes.
 """
@@ -23,10 +50,12 @@ mesh: params replicate across the remaining DP axes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.parallel.topology import (DATA_AXIS, DP_AXES, EXPERT_AXIS,
@@ -49,6 +78,126 @@ def _axes_of(entry) -> Tuple[str, ...]:
     if isinstance(entry, str):
         return (entry,)
     return tuple(entry)
+
+
+def drop_dp_axes(spec: Optional[P], ndim: int, dp_axes: Sequence[str],
+                 own: Optional[P] = None) -> P:
+    """The GATHERED twin of a ZeRO-sharded spec: same tp placement, dp
+    axes removed (the all-gather GSPMD inserts to honor the change).
+    ``own``: the leaf's spec before ZeRO touched it; a dp axis it carries
+    there is the model's (an expert axis) and stays."""
+    out = []
+    for entry, mine in zip(_spec_tuple(spec, ndim), _spec_tuple(own, ndim)):
+        axes = tuple(a for a in _axes_of(entry)
+                     if a not in dp_axes or a in _axes_of(mine))
+        out.append(axes[0] if len(axes) == 1 else (axes if axes else None))
+    return P(*out)
+
+
+def gather_on_use(x, gathered: NamedSharding,
+                  sharded: Optional[NamedSharding] = None):
+    """``x`` constrained to its GATHERED placement; with ``sharded``, under
+    a ``custom_vjp`` whose backward constrains the cotangent straight back
+    to the SHARDED placement, so a weight's gradient leaves the pass that
+    made it as a reduce-scatter. The plain constraint's transpose pins the
+    cotangent GATHERED instead: inside a loop that is an all-reduce of the
+    whole gradient an iteration (measured at the loss head, PR 28)."""
+    if sharded is None:
+        return jax.lax.with_sharding_constraint(x, gathered)
+
+    @jax.custom_vjp
+    def gather(v):
+        return jax.lax.with_sharding_constraint(v, gathered)
+
+    def fwd(v):
+        return gather(v), None
+
+    def bwd(_, ct):
+        return (jax.lax.with_sharding_constraint(ct, sharded),)
+
+    gather.defvjp(fwd, bwd)
+    return gather(x)
+
+
+# checkpoint name of a gathered leaf: a remat policy that saves by name
+# never lists it, so a layer keeps its sharded slice and gathers again
+GATHERED_NAME = "zero3_gathered"
+
+
+def stacked_param_keys(model) -> Tuple[str, ...]:
+    """The layer-stacked subtrees of a model's params: ``"blocks"`` unless
+    the model names its own (``stacked_params_key``: one key or several)."""
+    key = getattr(model, "stacked_params_key", "blocks")
+    return (key,) if isinstance(key, str) else tuple(key)
+
+
+def _stacked_leaves(tree: Any, stacked_keys: Sequence[str]):
+    """The (path from the root, leaf) pairs of every subtree of ``tree``
+    under a ``stacked_keys`` entry whose leaves all lead with one length."""
+    found = []
+    for key in stacked_keys if isinstance(tree, dict) else ():
+        flat = jax.tree_util.tree_flatten_with_path({key: tree.get(key)})[0]
+        if flat and len({l.shape[:1] for _, l in flat}) == 1 \
+                and flat[0][1].shape:
+            found += flat
+    return found
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerGathers:
+    """Stage 3's gather-on-use for the layer stack, as a rule the models'
+    layer walk applies to what a block is handed (``models/common.py::
+    remat_wrap``, inside the block's checkpoint). ``leaves``: {leaf name:
+    [(one layer's shape, gathered spec, sharded spec)]} of every leaf of a
+    stacked subtree that ZeRO sharded; a leaf is recognised by the dict key
+    it is held under and its trailing shape (leading axes — a pair of
+    layers — stay unsharded), anything else passes through."""
+    mesh: Mesh
+    dp_axes: Tuple[str, ...]
+    leaves: dict
+
+    def __call__(self, tree: Any) -> Any:
+        from deepspeed_tpu.comm import comm as _comm
+
+        if jax.sharding.get_abstract_mesh().manual_axes:
+            return tree         # a manual region places its own operands
+
+        def on_use(path, x):
+            name = next((k.key for k in reversed(path)
+                         if isinstance(k, jax.tree_util.DictKey)), None)
+            shape = tuple(getattr(x, "shape", ()))
+            for layer, gathered, sharded in self.leaves.get(name, ()):
+                lead = len(shape) - len(layer)
+                if lead < 0 or shape[lead:] != layer:
+                    continue
+                _comm.record_engine_collective("zero3_gather", layer,
+                                               x.dtype, self.dp_axes)
+                pad = (None,) * lead
+                return checkpoint_name(gather_on_use(
+                    x, NamedSharding(self.mesh, P(*pad, *gathered)),
+                    NamedSharding(self.mesh, P(*pad, *sharded))),
+                    GATHERED_NAME)
+            return x
+
+        return jax.tree_util.tree_map_with_path(on_use, tree)
+
+
+def _layer_gathers(stacked, param_specs, tp_specs, mesh,
+                   dp_axes) -> Optional[LayerGathers]:
+    """The rule over ``stacked`` (:func:`_stacked_leaves` of the shapes)."""
+    at = lambda tree, path: functools.reduce(
+        lambda t, k: t[k.key if hasattr(k, "key") else k.idx], path, tree)
+    leaves = {}
+    for path, sh in stacked:
+        spec, own = at(param_specs, path), at(tp_specs, path)
+        rank = len(sh.shape)
+        sharded = P(*_spec_tuple(spec, rank)[1:])
+        gathered = P(*tuple(drop_dp_axes(spec, rank, dp_axes, own))[1:])
+        name = getattr(path[-1], "key", None)   # held in a dict
+        if name is not None and tuple(gathered) != tuple(sharded):
+            leaves.setdefault(name, []).append(
+                (tuple(sh.shape[1:]), gathered, sharded))
+    return LayerGathers(mesh, tuple(dp_axes), leaves) if leaves else None
 
 
 def _shard_over_dp(shape: Tuple[int, ...], base_spec: Optional[P], dp_axes: Sequence[str],
@@ -122,6 +271,9 @@ class ShardingPlan:
         self.zero_stage = int(zero_stage)
         self.dp_axes = tuple(dp_axes)
         self._master_shapes = None
+        # stage 3 over more than one chip: the gather-on-use rule of the
+        # layer stack (plan_sharding fills it; None: nothing to gather)
+        self.layer_gathers: Optional[LayerGathers] = None
 
     # ------------------------------------------------------- registry views
     @property
@@ -235,7 +387,8 @@ def plan_sharding(param_shapes: Any,
                   zero_config=None,
                   tp_specs: Any = None,
                   dp_axes: Sequence[str] = DP_AXES,
-                  batch_spec: Optional[P] = None) -> ShardingPlan:
+                  batch_spec: Optional[P] = None,
+                  stacked_keys: Sequence[str] = ("blocks",)) -> ShardingPlan:
     """Compute the ZeRO placement plan.
 
     Args:
@@ -243,6 +396,10 @@ def plan_sharding(param_shapes: Any,
       tp_specs: optional pytree of PartitionSpec with tensor/seq-parallel axes
         already assigned (the AutoTP analogue fills this; None = pure DP).
       zero_config: DeepSpeedZeroConfig; stage and thresholds read from it.
+      stacked_keys: the top-level keys of ``param_shapes`` whose leaves are
+        stacked over the layers (:func:`stacked_param_keys`): the persistence
+        threshold judges such a leaf by ONE layer's elements, and at stage 3
+        the plan carries the gather-on-use rule for them (``layer_gathers``).
     """
     from deepspeed_tpu.runtime.zero.config import DeepSpeedZeroConfig
 
@@ -280,10 +437,20 @@ def plan_sharding(param_shapes: Any,
     if tp_specs is None:
         tp_specs = jax.tree.map(lambda s: P(), param_shapes)
 
-    def param_spec(shape_struct, tp_spec):
+    # the layers a leaf is stacked over, by its path: the persistence
+    # threshold is a count of ONE layer's elements (module docstring)
+    stacked = _stacked_leaves(param_shapes, stacked_keys)
+    layers_of = {jax.tree_util.keystr(path): leaf.shape[0]
+                 for path, leaf in stacked}
+
+    def persistent_under(path):
+        return int(zc.param_persistence_threshold) * \
+            layers_of.get(jax.tree_util.keystr(path), 1)
+
+    def param_spec(path, shape_struct, tp_spec):
         if stage >= 3:
             return _shard_over_dp(shape_struct.shape, tp_spec, dp_axes, mesh,
-                                  min_size=zc.param_persistence_threshold)
+                                  min_size=persistent_under(path))
         return tp_spec if tp_spec is not None else P()
 
     def master_spec(shape_struct, tp_spec):
@@ -297,7 +464,8 @@ def plan_sharding(param_shapes: Any,
         return tp_spec if tp_spec is not None else P()
 
     is_p = lambda x: isinstance(x, P) or x is None
-    param_specs = jax.tree.map(param_spec, param_shapes, tp_specs)
+    param_specs = jax.tree_util.tree_map_with_path(param_spec, param_shapes,
+                                                   tp_specs)
     master_specs = jax.tree.map(master_spec, param_shapes, tp_specs)
     grad_specs = jax.tree.map(grad_spec, param_shapes, tp_specs)
 
@@ -308,7 +476,6 @@ def plan_sharding(param_shapes: Any,
     # per offending leaf, threshold = the stage-3 persistence threshold
     # (smaller leaves are intentionally kept whole).
     if dp_axes and stage >= 1:
-        thresh = max(int(zc.param_persistence_threshold), 1)
         # keep None leaves on both sides so the zip can't shift (see
         # map_opt_state_specs)
         shapes_flat = jax.tree_util.tree_flatten_with_path(
@@ -320,7 +487,7 @@ def plan_sharding(param_shapes: Any,
             if sh is None:
                 continue
             n = int(np.prod(sh.shape))
-            if n < thresh:
+            if n < max(persistent_under(path), 1):
                 continue
             axes = set()
             for e in _spec_tuple(sp, len(sh.shape)):
@@ -355,6 +522,9 @@ def plan_sharding(param_shapes: Any,
     registry.register("batch", batch_spec)
     plan = ShardingPlan(registry=registry, zero_stage=stage, dp_axes=dp_axes)
     plan._master_shapes = param_shapes
+    if stage >= 3 and dp_axes:
+        plan.layer_gathers = _layer_gathers(stacked, param_specs, tp_specs,
+                                            mesh, dp_axes)
     return plan
 
 

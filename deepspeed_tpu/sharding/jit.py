@@ -178,9 +178,9 @@ def _instruction_scopes(text: str) -> Dict[str, str]:
     before its callers and an operand before its users. An instruction with
     no metadata of its own takes, if it calls a computation (some fusions),
     the ``op_name`` of that computation's ROOT, else the commonest among its
-    instructions; if it calls none (what the compiler made itself: a
-    combined collective, the done of a start, a layout copy), that of its
-    first operand that has one."""
+    instructions; if it calls none, or one that holds no name either (what
+    the compiler made itself: a combined collective, the done of a start, a
+    layout copy, a bitcast fusion), that of its first operand that has one."""
     names: Dict[str, str] = {}
     roots: Dict[str, str] = {}
     seen: Dict[str, collections.Counter] = {}
@@ -201,13 +201,11 @@ def _instruction_scopes(text: str) -> Dict[str, str]:
                 roots[comp] = op.group(1)
             continue
         callee = _CALLS.search(line)
-        if callee:
-            inner = seen.get(callee.group(1))
-            names[name] = roots.get(callee.group(1)) or (
-                inner.most_common(1)[0][0] if inner else "")
-        else:
-            names[name] = next(filter(None, (
-                names.get(o) for o in _OPERAND.findall(line, m.end()))), "")
+        inner = seen.get(callee.group(1)) if callee else None
+        names[name] = callee and (roots.get(callee.group(1)) or (
+            inner.most_common(1)[0][0] if inner else "")) or next(filter(
+                None, (names.get(o)
+                       for o in _OPERAND.findall(line, m.end()))), "")
     return names
 
 

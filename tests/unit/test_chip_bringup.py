@@ -502,6 +502,92 @@ def test_xl_z3_step_gathers_the_head_once_and_reduces_its_gradient_once(xl_z3_st
     assert {dtype for _, dtype, _ in found} == {"bf16"}, found
 
 
+def _layer_scan_collectives(text):
+    """{"fwd" | "bwd": [(op, result type)]} of the collectives inside the
+    layer scan of the forward (the smallest ``while`` body that holds
+    ``flash_fwd``) and of the backward (``flash_bwd_dq``): what runs once a
+    layer. A reduce-scatter that XLA:TPU writes as an ``all-reduce-scatter``
+    fusion is listed as ``reduce-scatter`` with the FUSION's result."""
+    comps, called, reach = _computations(text)
+    bodies = sorted({c for lines in comps.values() for c in called("body", lines)},
+                    key=lambda b: len(reach(b)))
+    found = {}
+    for key, kernel in (("fwd", "flash_fwd"), ("bwd", "flash_bwd_dq")):
+        body = next(b for b in bodies if any(
+            re.search(rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", l)
+            for c in reach(b) for l in comps[c]))
+        scattered = {c for k in reach(body) for c in called("calls", comps[k])
+                     if "all-reduce-scatter" in c}
+        rows = []
+        for c in reach(body):
+            for l in comps[c]:
+                m = re.search(r"= (.*?) (all-gather|all-reduce|reduce-scatter|"
+                              r"all-to-all|collective-permute)(?:-start)?\(", l)
+                if m and c not in scattered:
+                    rows.append((m.group(2), m.group(1)))
+                m = re.search(r"= (.*?) fusion\(.*calls=%?([\w.\-]+)", l)
+                if m and m.group(2) in scattered:
+                    rows.append(("reduce-scatter", m.group(1)))
+        found[key] = rows
+    return found
+
+
+# one layer's four weights: the leaves of a gpt2-xl block over the
+# persistence threshold, 61.4 MB in bf16
+XL_LAYER_WEIGHTS = ("1600,4800", "1600,1600", "1600,6400", "6400,1600")
+
+
+def test_xl_z3_step_gathers_a_layers_weights_where_the_block_uses_them(xl_z3_step):
+    """ZeRO-3's gather-on-use, stated (runtime/zero/partition.py). Left to
+    the partitioner the forward kept ``qkv_w`` sharded and moved ACTIVATIONS
+    (``all-gather bf16[64,1024,1600]``, 210 MB a layer, and an ``all-to-all
+    bf16[4,16,1024,1200]`` back), and gathered a layer's biases as
+    update-slice + ``all-reduce bf16[6400]``: the forward matmuls cost two
+    to three times their own re-run in the backward, which gathered the
+    weight (PERF.md section 5, PR 35). All three passes now gather the
+    weight; the stacked biases, judged a layer, are whole on every chip."""
+    found = _layer_scan_collectives(xl_z3_step.as_text())
+    for op, result in found["fwd"]:
+        assert op in ("all-gather", "all-reduce"), (op, result)
+        assert not re.search(r",1024,(1600|1200)\]", result), (op, result)
+        assert not re.search(r"bf16\[(6400|4800)\]", result), (op, result)
+    for key in ("fwd", "bwd"):
+        gathered = " ".join(r for op, r in found[key] if op == "all-gather")
+        for dims in XL_LAYER_WEIGHTS:
+            assert re.search(rf"bf16\[(1,)?{dims}\]", gathered), (key, dims)
+    # the backward's reductions are the parent's: three weight gradients
+    # leave as reduce-scatters, fc_w's inside ONE combined all-reduce with
+    # the layer's small gradients (its whole (1600, 6400): the parent's
+    # program too; ROADMAP S7), and no weight gradient is all-reduced alone
+    reduced = [r for op, r in found["bwd"] if op == "all-reduce"]
+    for dims in ("1600,4800", "6400,1600", "1600,1600"):
+        assert not any(f"[{dims}]" in r for r in reduced), (dims, reduced)
+    assert sum("[1600,6400]" in r for r in reduced) <= 1, reduced
+    assert len([1 for op, _ in found["bwd"] if op == "reduce-scatter"]) == 3
+    assert not [r for op, r in found["bwd"] if op == "all-to-all"]
+
+
+def test_the_rule_is_in_the_four_chip_trace_and_not_in_the_one_chip_trace(
+        xl_z3_record, gas4_record):
+    """4 leaves a layer on ``z3x4``; on one chip no DP axis holds more than
+    one device, and the step's trace holds nothing of the rule."""
+    from deepspeed_tpu.runtime.zero.partition import GATHERED_NAME
+
+    def jaxpr_text(fixture):
+        engine, record = fixture
+        with engine.mesh:
+            return engine, str(record.jitted.trace(
+                *record.abstract_args).jaxpr)
+
+    engine, text = jaxpr_text(xl_z3_record)
+    assert sorted(engine._layer_gathers.leaves) == [
+        "fc2_w", "fc_w", "proj_w", "qkv_w"]
+    assert f"name={GATHERED_NAME}" in text
+    engine, text = jaxpr_text(gas4_record)
+    assert engine._layer_gathers is None
+    assert GATHERED_NAME not in text and "custom_vjp" not in text
+
+
 def test_xl_z3_step_at_micro_batch_16_fits_a_chip(xl_z3_step):
     _, total = _footprint(xl_z3_step)
     assert total < HBM_PER_CHIP, (

@@ -215,7 +215,8 @@ class TestEngineSchedules:
     def test_serial_gather_registers_with_doctor(self):
         """PR 4 collective fingerprints cover the overlapped schedule:
         deterministic across engines of the same config, different from
-        the unrestructured step's (which issues no engine collectives)."""
+        a step's that states no gather (ZeRO-2; the unrestructured ZeRO-3
+        step states its own: tests/unit/test_zero3_gather.py)."""
         fps = []
         for _ in range(2):
             e = make_engine(overlap={}, analysis={"fail_on": "error"})
@@ -223,7 +224,8 @@ class TestEngineSchedules:
             assert e._collective_fingerprint is not None
             fps.append(e._collective_fingerprint)
         assert fps[0] == fps[1]
-        e = make_engine(analysis={"fail_on": "error"})
+        e = make_engine(analysis={"fail_on": "error"},
+                        zero_optimization={"stage": 2})
         e.train_batch(lm_batch())
         assert e._collective_fingerprint != fps[0]
 

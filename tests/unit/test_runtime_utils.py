@@ -205,6 +205,51 @@ class TestMiCS:
         assert any("odd" in w and "REPLICATED" in w for w in warnings)
         assert not any("even" in w for w in warnings)
 
+    @pytest.mark.parametrize("path,shape,sharded", [
+        # a layer-stacked leaf is judged by ONE layer's elements (the
+        # reference compares one layer's parameter): 48 x 6,400 = 307,200
+        # stacked, 6,400 a layer: persistent; 1,600 x 1,600 a layer: sharded
+        (("blocks", "fc_b"), (48, 6400), False),
+        (("blocks", "proj_w"), (48, 1600, 1600), True),
+        # exactly the threshold a layer is sharded, one under it is whole
+        (("blocks", "at"), (48, 100_000), True),
+        (("blocks", "under"), (48, 99_996), False),
+        # an unstacked leaf as before: by all its elements
+        (("wpe",), (48, 6400), True),
+        (("lnf_g",), (6400,), False),
+        # a subtree that only looks stacked (leading lengths differ) too
+        (("heads", "a"), (48, 6400), True),
+        (("heads", "b"), (12, 6400), False),
+    ])
+    def test_persistence_threshold_judges_a_stacked_leaf_by_one_layer(
+            self, path, shape, sharded):
+        import functools
+
+        from jax.sharding import Mesh
+
+        from deepspeed_tpu.runtime.zero import plan_sharding
+        from deepspeed_tpu.runtime.zero.config import DeepSpeedZeroConfig
+
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(4), ("data",))
+        leaf = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+        shapes = {"blocks": {"fc_b": leaf(48, 6400),
+                             "proj_w": leaf(48, 1600, 1600),
+                             "at": leaf(48, 100_000),
+                             "under": leaf(48, 99_996)},
+                  "heads": {"a": leaf(48, 6400), "b": leaf(12, 6400)},
+                  "wpe": leaf(48, 6400), "lnf_g": leaf(6400)}
+        at = lambda tree: functools.reduce(lambda t, k: t[k], path, tree)
+        assert at(shapes).shape == shape
+        plan = plan_sharding(shapes, mesh,
+                             zero_config=DeepSpeedZeroConfig(stage=3),
+                             stacked_keys=("blocks", "heads"))
+        assert ("data" in str(at(plan.param_specs))) == sharded
+        # masters, moments and gradients stay sharded either way
+        assert "data" in str(at(plan.master_specs))
+        assert "data" in str(at(plan.grad_specs))
+        if path[0] == "blocks":
+            assert (path[1] in plan.layer_gathers.leaves) == sharded
+
     def test_mics_sub_group_rejected_with_guidance(self):
         import jax
         from deepspeed_tpu.parallel.topology import build_mesh

@@ -274,13 +274,17 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     so does a non-causal length the kernel cannot tile (q in whole 128s or
     one block: 576 = 9 x 64 is not).
     ``window``: optional sliding window (GPT-Neo local attention, reference
-    containers/gptneo.py): position i attends to j with 0 <= i-j < window.
-    May be a TRACED scalar so one scanned layer loop can mix global and
-    local layers; <=0 means global. Windowed attention takes the einsum
-    path.
+    containers/gptneo.py; a model's window layers): position i attends to j
+    with 0 <= i-j < window. A Python int goes to the flash kernel with the
+    rest (``flash_attention(window=)``: the band's blocks only, forward and
+    backward). It may also be a TRACED scalar so one scanned layer loop can
+    mix global and local layers, <=0 meaning global: a kernel's plan is
+    static, so that form takes the einsum path.
     """
+    static_window = window is None or (
+        isinstance(window, int) and window > 0 and causal)
     if use_flash and alibi is None and key_padding_mask is None \
-            and window is None:
+            and static_window:
         mesh, on_tpu = _kernel_target()
         if on_tpu:
             from deepspeed_tpu.ops.pallas import flash_attention as fa
@@ -291,8 +295,8 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                 batch, heads = _attn_axes(mesh, q.shape[0], q.shape[2])
                 spec = P(batch, None, heads, None)
                 return _kernel_on_mesh(
-                    lambda q, k, v: fa.flash_attention(q, k, v, causal=causal,
-                                                       **kw),
+                    lambda q, k, v: fa.flash_attention(
+                        q, k, v, causal=causal, window=window, **kw),
                     mesh, (q, k, v), (spec, spec, spec), spec)
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -639,15 +643,36 @@ def chunked_lm_loss(x, head, targets, loss_mask=None, bias=None, remat=True):
     return jnp.mean(nll)
 
 
+def _chunk_len(T, budget):
+    """-> (chunk, padded T): the largest divisor of ``T`` at or under
+    ``budget`` positions, with ``T`` itself. A length whose divisors are all
+    far under the budget (the 8,191 shifted positions of an 8,192-token
+    sequence are a prime: its only one is 1, a scan of 8,191 one-row matmuls)
+    is padded instead: the fewest chunks the budget admits or up to twice as
+    many, each a multiple of 8 positions, whichever pads least."""
+    budget = max(1, min(T, budget))
+    chunk = next(cc for cc in range(budget, 0, -1) if T % cc == 0)
+    if 8 * chunk >= budget:
+        return chunk, T
+    fewest = -(-T // budget)
+    fits = [(-(-T // (8 * n)) * 8, n) for n in range(fewest, 2 * fewest + 1)]
+    fits = [(c, n) for c, n in fits if c <= budget] or fits[-1:]
+    chunk, n = min(fits, key=lambda cn: (cn[0] * cn[1], cn[1]))
+    return chunk, chunk * n
+
+
 def _chunked_nll(x, head, targets, bias, remat):
     """(B, T) float32 ``logsumexp - target logit`` of the rows handed in,
     the (B, chunk, V) logits of one chunk of positions at a time."""
     B, T, D = x.shape
     vocab = head.shape[1]
-    chunk = max(1, min(T, _CHUNK_ELEMS // max(1, B * vocab)))
-    chunk = next((cc for cc in range(chunk, 0, -1) if T % cc == 0), 1)
-    xs = x.reshape(B, T // chunk, chunk, D).swapaxes(0, 1)        # (n, B, C, D)
-    ts = targets.reshape(B, T // chunk, chunk).swapaxes(0, 1)     # (n, B, C)
+    chunk, padded = _chunk_len(T, _CHUNK_ELEMS // max(1, B * vocab))
+    if padded != T:     # rows of zeros: their losses are cut off below
+        x = jnp.pad(x, ((0, 0), (0, padded - T), (0, 0)))
+        targets = jnp.pad(targets, ((0, 0), (0, padded - T)))
+    n = padded // chunk
+    xs = x.reshape(B, n, chunk, D).swapaxes(0, 1)                 # (n, B, C, D)
+    ts = targets.reshape(B, n, chunk).swapaxes(0, 1)              # (n, B, C)
 
     def chunk_nll(carry, xt):
         xc, tc = xt
@@ -667,4 +692,4 @@ def _chunked_nll(x, head, targets, bias, remat):
     # the 760m headline, so small-model benches opt out via remat=False.
     body = jax.checkpoint(chunk_nll) if remat else chunk_nll
     _, nll = jax.lax.scan(body, 0.0, (xs, ts))                    # (n, B, C)
-    return nll.swapaxes(0, 1).reshape(B, T)
+    return nll.swapaxes(0, 1).reshape(B, padded)[:, :T]

@@ -74,7 +74,26 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
     SOFTMAX layers only, and ``kda_state`` / ``kda_conv`` over the KDA
     layers (``models/common.py::cache_footprint`` tells them apart by
     name). ``loss`` runs KDA through the chunked form's ``jnp`` path (the
-    kernel has no backward).
+    kernel has no backward);
+  - **window and full softmax layers in one pattern** (``layer_types``, a
+    layer's kind by the published key, and ``sliding_window``; afmoe): the
+    two kinds hold the SAME leaves, so they stay in ``blocks`` /
+    ``dense_blocks`` and the kind reaches a block from the pattern
+    (``_block(kind=)``): a window layer's attention is ``causal_attention
+    (window=)`` — the flash kernels' band on a TPU — and, with
+    ``global_rope`` false, it alone is rotated. Leading dense layers go with
+    such a pattern: each stack walks its own phase of it
+    (``LlamaConfig.stack_pattern``). The cached walk keeps the WHOLE context
+    for every layer and gives a window layer's decode step the window's
+    slots (``cached_decode_attention(window=)``: the einsum, the decode
+    kernel carries no window yet; a window-sized cache is ROADMAP R4);
+  - **per-head q/k norm** (``qk_norm="head"``: gains ``(head_dim,)``, told
+    from OLMoE's whole-projection form by the leaf's shape), **an embedding
+    multiplier** (``embed_scale``), and **a selection bias** of the router
+    (a block that holds ``router_bias``: ``route_topk(bias=)``), which no
+    gradient and no optimizer moves: the model names it to the engine
+    (``ruled_leaves``) with the rule that does (``apply_rule``: aux-loss-free
+    balancing from the step's routing counts, ``loss_and_aux``).
 
 Implements the same model protocol as GPT2Model (init_params, loss, apply,
 prefill/decode_step, partition specs), so ``initialize()``,
@@ -85,6 +104,7 @@ Weights convert from HF ``LlamaForCausalLM`` via module_inject/hf.py.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 from typing import Any, Dict, Optional
@@ -121,7 +141,8 @@ class LlamaConfig:
     tie_embeddings: bool = False     # llama3.2-1B/3B style tied lm_head
     # OLMoE's block: RMSNorm over the whole q / k projection; routed experts
     # of width ``intermediate_size`` in place of the dense MLP (0 = dense)
-    qk_norm: bool = False
+    # "head": per HEAD instead, gains (head_dim,), after the split (afmoe)
+    qk_norm: Any = False
     n_experts: int = 0
     n_experts_per_tok: int = 0
     norm_topk_prob: bool = False     # renormalise the k chosen probabilities
@@ -154,6 +175,23 @@ class LlamaConfig:
     # convolution over ``kda_conv`` positions. The pattern must repeat with a
     # period that divides n_layer (``pattern``)
     gqa_layers: Optional[tuple] = None
+    # the OTHER layer pattern, of softmax layers alone: a layer's kind by the
+    # published key, "full_attention" | "sliding_attention" (a causal window
+    # of ``sliding_window`` keys, the row's own among them). Same leaves,
+    # another mask; ``global_rope`` false: the full layers take no rotary
+    # embedding (afmoe rotates where the layer is local). Each stack (the
+    # leading dense layers, the routed ones) walks its own phase of it
+    layer_types: Optional[tuple] = None
+    sliding_window: int = 0
+    global_rope: bool = True
+    # x the embedding's output (muP: n_embd ** 0.5)
+    embed_scale: float = 1.0
+    # a per-expert bias of the router's SELECTION (leaf ``router_bias``,
+    # route_topk(bias=)), which no gradient and no optimizer moves: once a
+    # step ``router_bias_rate`` x the balancing rule (moe/dropless.py::
+    # balance_bias) from the step's routing counts, through the engine
+    router_bias: bool = False
+    router_bias_rate: float = 0.0
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
@@ -170,6 +208,8 @@ class LlamaConfig:
     VALID_ROPE_TYPES = ("default", "linear", "llama3")
 
     VALID_ROUTER_SCORING = ("softmax", "sigmoid")
+
+    LAYER_TYPES = {"full_attention": "attn", "sliding_attention": "win"}
 
     def __post_init__(self):
         if self.remat not in self.VALID_REMAT:
@@ -238,6 +278,24 @@ class LlamaConfig:
                     "a layer pattern (gqa_layers) with latent attention, "
                     "leading dense layers or sequence parallelism is not "
                     "built: KDA layers carry a state along the sequence")
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            if len(self.layer_types) != self.n_layer or self.sliding_window < 1 \
+                    or not set(self.layer_types) <= set(self.LAYER_TYPES):
+                raise ValueError(
+                    f"layer_types: one of {sorted(self.LAYER_TYPES)} a layer "
+                    f"(n_layer={self.n_layer}) and a sliding_window >= 1, "
+                    f"not {self.layer_types} / {self.sliding_window}")
+            if self.gqa_layers is not None or self.mla \
+                    or self.sequence_parallel:
+                raise ValueError(
+                    "layer_types (window and full softmax layers) with "
+                    "gqa_layers, latent attention or sequence parallelism "
+                    "is not built")
+        if self.qk_norm not in (False, True, "head") or \
+                (self.router_bias and not self.n_experts):
+            raise ValueError(f"qk_norm={self.qk_norm!r} (False | True | "
+                             "'head'); router_bias is a routed model's")
 
     @property
     def kv_dim(self) -> int:
@@ -258,18 +316,33 @@ class LlamaConfig:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
-    def pattern(self) -> tuple:
-        """One period of the layer pattern, a mixer's kind a layer:
-        ``("attn",)`` where every layer is softmax attention, ``("attn",
-        "kda", "kda", "kda")`` for one softmax layer in four."""
+    def kinds(self) -> tuple:
+        """Every layer's kind of mixer: ``attn`` (full softmax), ``win``
+        (softmax under a window), ``kda``."""
+        if self.layer_types is not None:
+            return tuple(self.LAYER_TYPES[t] for t in self.layer_types)
         if self.gqa_layers is None:
-            return ("attn",)
-        kinds = ["attn" if l in self.gqa_layers else "kda"
-                 for l in range(self.n_layer)]
-        period = next(p for p in range(1, self.n_layer + 1)
-                      if self.n_layer % p == 0
-                      and kinds == kinds[:p] * (self.n_layer // p))
+            return ("attn",) * self.n_layer
+        return tuple("attn" if l in self.gqa_layers else "kda"
+                     for l in range(self.n_layer))
+
+    def stack_pattern(self, first: int, count: int) -> tuple:
+        """One period of the pattern as the stack of layers ``first .. first
+        + count - 1`` walks it: the shortest run of kinds the stack repeats.
+        ``("attn",)`` where every layer is softmax attention, ``("attn",
+        "kda", "kda", "kda")`` for one softmax layer in four; a stack that
+        begins inside a period begins at that phase."""
+        kinds = list(self.kinds[first:first + count])
+        period = next(p for p in range(1, count + 1)
+                      if count % p == 0 and kinds == kinds[:p] * (count // p))
         return tuple(kinds[:period])
+
+    @property
+    def pattern(self) -> tuple:
+        """One period of the layer pattern of the stack ``params["blocks"]``
+        holds (all layers, or those after the leading dense ones)."""
+        return self.stack_pattern(self.n_dense_layers,
+                                  self.n_layer - self.n_dense_layers)
 
     @property
     def n_attn_layers(self) -> int:
@@ -303,10 +376,12 @@ class LlamaConfig:
             heads = c.n_head * c.head_dim
             attn = (2 + c.attn_gate) * d * heads + 2 * d * c.kv_dim
             if c.qk_norm:
-                attn += heads + c.kv_dim
+                attn += 2 * c.head_dim if c.qk_norm == "head" \
+                    else heads + c.kv_dim
         norms = (4 if c.sandwich_norm else 2) * d
         mlps = (c.n_experts_per_tok if active else c.n_held) or 1
         routed = attn + norms + d * c.n_experts \
+            + (c.n_experts if c.router_bias else 0) \
             + (mlps + c.n_shared_experts) * 3 * d * i
         dense = attn + norms + 3 * d * c.dense_intermediate_size
         embeds = v * d if c.tie_embeddings else 2 * v * d
@@ -408,7 +483,10 @@ class LlamaModel:
                      k_w=norm(keys[2], (l, d, c.kv_dim), s),
                      v_w=norm(keys[3], (l, d, c.kv_dim), s),
                      o_w=norm(keys[4], (l, heads, d), proj_scale))
-        if c.qk_norm:
+        if c.qk_norm == "head":
+            mixer.update(q_norm_g=ones(l, c.head_dim),
+                         k_norm_g=ones(l, c.head_dim))
+        elif c.qk_norm:
             mixer.update(q_norm_g=ones(l, heads), k_norm_g=ones(l, c.kv_dim))
         if c.attn_gate:
             mixer.update(attn_gate_w=norm(fold(keys[1], 1), (l, d, heads), s))
@@ -453,6 +531,11 @@ class LlamaModel:
             expert_gate_w=per_layer(keys[5], (e, d, i), s),
             expert_up_w=per_layer(keys[6], (e, d, i), s),
             expert_down_w=per_layer(keys[7], (e, i, d), proj_scale))
+        if c.router_bias:
+            # not zeros: a selection by s + b then differs from one by s
+            # from the first step on (what a trained model's bias does)
+            blocks.update(router_bias=norm(fold(keys[5], 3),
+                                           (l, c.n_experts), 0.01))
         if c.n_shared_experts:
             si = c.n_shared_experts * i
             blocks.update(shared_gate_w=norm(fold(keys[5], 2), (l, d, si), s),
@@ -536,6 +619,8 @@ class LlamaModel:
             return blocks
         blocks.update(router_w=rep(3), expert_gate_w=rep(4),
                       expert_up_w=rep(4), expert_down_w=rep(4))
+        if c.router_bias:
+            blocks.update(router_bias=rep(2))
         if c.n_shared_experts:
             blocks.update(shared_gate_w=P(None, None, "tensor"),
                           shared_up_w=P(None, None, "tensor"),
@@ -582,17 +667,38 @@ class LlamaModel:
         rep = self.config.n_head // self.config.n_kv_head
         return t if rep == 1 else jnp.repeat(t, rep, axis=2)
 
-    def _causal(self, q, k, v):
+    def _causal(self, q, k, v, window=None):
         """The trunk's attention on full-head q, k, v: the shared dispatch
         (models/common.py: sequence-parallel → flash → einsum)."""
         from deepspeed_tpu.models.common import causal_attention
 
         c = self.config
         return causal_attention(q, k, v, use_flash=c.use_flash_attention,
-                                sequence_parallel=c.sequence_parallel)
+                                sequence_parallel=c.sequence_parallel,
+                                window=window)
+
+    def _window(self, kind):
+        """A layer kind's causal window: ``sliding_window`` keys for a
+        window layer, None for every other."""
+        return self.config.sliding_window if kind == "win" else None
+
+    def _rope_of(self, kind, cos_sin):
+        """A layer kind's rotary tables: the model's, or none for the full
+        layers of a pattern whose window layers alone are rotated."""
+        return (None, None) if kind == "attn" and not self.config.global_rope \
+            else cos_sin
+
+    def _embed(self, params, ids):
+        c = self.config
+        with scope("embed"):
+            x = params["wte"].astype(c.dtype)[ids]
+            if c.embed_scale != 1.0:
+                x = (x.astype(jnp.float32) * c.embed_scale).astype(c.dtype)
+            return x
 
     def _stacks(self, params, split_experts=True):
-        """The trunk's stacks in order, each as ``(xs, experts, first, view)``:
+        """The trunk's stacks in order, each as ``(xs, experts, first, view,
+        pattern)`` (``pattern``: one period of the stack's kinds of layer):
         ``xs`` is what a scan over the stack's PERIODS of the layer pattern
         slices, ``experts`` the stacked ``(L, E, ...)`` expert leaves it must
         not (None for a dense stack, and where ``split_experts`` is false:
@@ -602,21 +708,35 @@ class LlamaModel:
         ``per`` (j static, i a traced offset inside a run of one kind). With one kind of mixer a period is a layer and ``xs`` the
         stacked blocks themselves. With a layer pattern ``xs`` holds the
         leaves every layer has regrouped ``(L / p, p, ...)`` (a reshape of
-        the leading axis: no copy) and each kind of mixer's own stack
-        regrouped by what a period holds of it."""
+        the leading axis: no copy) and, where the kinds of mixer have leaves
+        of their own (KDA beside softmax), each kind's own stack regrouped
+        by what a period holds of it. Window and full softmax layers hold
+        the same leaves: they stay in ``blocks`` / ``dense_blocks``, and
+        each of the two stacks walks its own phase of the pattern."""
         c = self.config
         blocks, experts = self._split_experts(params["blocks"]) \
             if split_experts else (params["blocks"], None)
-        if c.gqa_layers is None:
-            stacks = [(blocks, experts, c.n_dense_layers,
-                       lambda per, j, i=0: per)]
-            if c.n_dense_layers:
-                stacks.insert(0, (params["dense_blocks"], None, 0,
-                                  lambda per, j, i=0: per))
-            return stacks
-        pattern = c.pattern
         group = lambda tree, each: jax.tree.map(
             lambda a: a.reshape(a.shape[0] // each, each, *a.shape[1:]), tree)
+        if c.gqa_layers is None:
+            stacks = []
+            for held, exp, first, count in (
+                    (params.get("dense_blocks"), None, 0, c.n_dense_layers),
+                    (blocks, experts, c.n_dense_layers,
+                     c.n_layer - c.n_dense_layers)):
+                if not count:
+                    continue
+                pattern = c.stack_pattern(first, count)
+                if len(pattern) == 1:
+                    stacks.append((held, exp, first,
+                                   lambda per, j, i=0: per, pattern))
+                else:
+                    stacks.append((
+                        group(held, len(pattern)), exp, first,
+                        lambda per, j, i=0: jax.tree.map(
+                            lambda a: a[j + i], per), pattern))
+            return stacks
+        pattern = c.pattern
         xs = {"all": group(blocks, len(pattern)),
               "attn": group(params["attn_blocks"], pattern.count("attn")),
               "kda": group(params["kda_blocks"], pattern.count("kda"))}
@@ -627,17 +747,20 @@ class LlamaModel:
             return {**jax.tree.map(lambda a: a[j + i], per["all"]),
                     **jax.tree.map(lambda a: a[mine + i], per[kind])}
 
-        return [(xs, experts, 0, view)]
+        return [(xs, experts, 0, view, pattern)]
 
-    def _layer_at(self, n, j, i=0):
-        """For layer j + i of period ``n`` (n, i traced) of a stack: (its
-        index in the stack, its index among the stack's layers of ITS kind —
-        the layer axis of that kind's cache arrays)."""
-        pattern = self.config.pattern
+    @staticmethod
+    def _layer_at(pattern, n, j, i=0):
+        """For layer j + i of period ``n`` (n, i traced) of a stack that
+        walks ``pattern``: (its index in the stack, its index among the
+        stack's layers that keep what it keeps of a sequence — the layer
+        axis of those cache arrays: rows a position for the softmax kinds,
+        full or window, a state for KDA)."""
         if len(pattern) == 1:
             return n, n
+        same = [(k == "kda") == (pattern[j] == "kda") for k in pattern]
         return n * len(pattern) + j + i, \
-            n * pattern.count(pattern[j]) + pattern[:j].count(pattern[j]) + i
+            n * sum(same) + sum(same[:j]) + i
 
     def _rope(self, positions):
         """(cos, sin), or (None, None) for a model without a positional
@@ -659,12 +782,19 @@ class LlamaModel:
             hd = h.astype(c.dtype)
             q = hd @ blk["q_w"].astype(hd.dtype)
             k = hd @ blk["k_w"].astype(hd.dtype)
-            if "q_norm_g" in blk:
+            per_head = "q_norm_g" in blk \
+                and blk["q_norm_g"].shape[-1] == c.head_dim
+            if "q_norm_g" in blk and not per_head:
                 # OLMoE: over the whole projection, before the heads are split
                 q = self._rms_norm(q, blk["q_norm_g"])
                 k = self._rms_norm(k, blk["k_norm_g"])
             q = q.reshape(B, T, c.n_head, c.head_dim)
             k = k.reshape(B, T, c.n_kv_head, c.head_dim)
+            if per_head:
+                # gains (head_dim,): each head's own 128 columns (with ONE
+                # head the two forms are one)
+                q = self._rms_norm(q, blk["q_norm_g"])
+                k = self._rms_norm(k, blk["k_norm_g"])
             v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, c.n_kv_head, c.head_dim)
             if cos is None:
                 return q, k, v
@@ -730,9 +860,13 @@ class LlamaModel:
         with scope("attn/core"):
             return attention(q, k, v), (latent,)
 
-    def _attend_cached(self, x, blk, cos_sin, caches, layer, pos):
+    def _attend_cached(self, x, blk, cos_sin, caches, layer, pos,
+                       window=None):
         """The new token's attention over the cache (decode): its rows are
-        written into slot ``pos`` of ``layer``, then attended with the rest.
+        written into slot ``pos`` of ``layer``, then attended with the rest
+        — under a ``window`` with the last ``window`` slots (the cache holds
+        the whole context for every layer; ``cached_decode_attention`` takes
+        its einsum for a window: the decode kernel carries none yet).
         -> (attn (B, 1, H, Dv), the caches)."""
         from deepspeed_tpu.models.common import (cached_decode_attention,
                                                  kv_cache_write,
@@ -748,7 +882,8 @@ class LlamaModel:
                 # never materialized (grouped einsum or the Pallas streaming
                 # kernel)
                 attn = cached_decode_attention(q[:, 0], cache_k, cache_v,
-                                               layer, pos, c.n_kv_head)
+                                               layer, pos, c.n_kv_head,
+                                               window=window)
             return self._gated(attn[:, None], x, blk), (cache_k, cache_v)
         # absorbed: q.k_nope = (q_nope W_UK^T).c_kv and p.v = (p.c_kv) W_UV,
         # so the scores and the weighted sum are over the latent rows
@@ -786,7 +921,10 @@ class LlamaModel:
         docstring), E the experts held here; plus the shared expert where
         it holds ``shared_gate_w``. Statistics: pairs routed to each held
         expert (E,) int32 and the router's scores summed over the tokens
-        (n_experts,) float32."""
+        (n_experts,) float32; where the block holds a selection bias
+        (``router_bias``) also the pairs routed to EACH of the router's
+        experts (n_experts,) int32, held here or not: what the balancing
+        rule reads."""
         if "router_w" not in blk:
             return self._swiglu(h, blk["gate_w"], blk["up_w"],
                                 blk["down_w"]), None
@@ -802,6 +940,8 @@ class LlamaModel:
         scored = {} if (c.router_scoring, c.routed_scaling_factor) == \
             ("softmax", 1.0) else {"scoring": c.router_scoring,
                                    "scale": c.routed_scaling_factor}
+        if "router_bias" in blk:
+            scored["bias"] = blk["router_bias"]
         probs, weights, experts = route_topk(
             tokens, blk["router_w"], c.n_experts_per_tok, c.norm_topk_prob,
             **scored)
@@ -816,7 +956,12 @@ class LlamaModel:
                 out = out + self._swiglu(
                     h, blk["shared_gate_w"], blk["shared_up_w"],
                     blk["shared_down_w"])
-        return out, (sizes, jnp.sum(probs, axis=0))
+        stats = (sizes, jnp.sum(probs, axis=0))
+        if "router_bias" in blk:
+            with scope("moe/router"):
+                stats += (jnp.bincount(experts.reshape(-1),
+                                       length=c.n_experts).astype(jnp.int32),)
+        return out, stats
 
     def _split_experts(self, blocks):
         """(the leaves a layer scan may slice, the stacked expert leaves it
@@ -844,8 +989,10 @@ class LlamaModel:
                 out = self._rms_norm(out, blk["post_mlp_norm_g"])
             return x + out, stats
 
-    def _block(self, x, blk, cos_sin):
-        """One layer of the trunk: a new sequence, nothing kept of it."""
+    def _block(self, x, blk, cos_sin, kind="attn"):
+        """One layer of the trunk: a new sequence, nothing kept of it.
+        ``kind``: what the layer pattern says of it where its leaves cannot
+        (a window layer holds a full layer's)."""
         if "kda_qkv_w" in blk:
             from deepspeed_tpu.models import kda
 
@@ -857,31 +1004,33 @@ class LlamaModel:
                     fresh["kda_conv"][0], fresh["kda_state"][0],
                     differentiable=True)
         else:
-            attn, _ = self._attend(x, blk, cos_sin, self._causal)
+            attn, _ = self._attend(
+                x, blk, self._rope_of(kind, cos_sin),
+                functools.partial(self._causal, window=self._window(kind)))
         return self._block_finish(x, blk, attn)
 
     def _trunk(self, params, input_ids, rng=None, with_router_stats=False):
         c = self.config
         B, T = input_ids.shape
-        with scope("embed"):
-            x = params["wte"].astype(c.dtype)[input_ids]
+        x = self._embed(params, input_ids)
         cos_sin = self._rope(jnp.arange(T))
-        pattern = c.pattern
-
-        block_fn = remat_wrap(self._block, c.remat)
+        block_fn = {kind: remat_wrap(
+            functools.partial(self._block, kind=kind), c.remat)
+            for kind in set(c.kinds)}
 
         # overridable layer scan (overlap engine's ZeRO-3 gather prefetch;
         # a plain lax.scan when nothing is installed)
         from deepspeed_tpu.models.common import layer_scan
 
-        for xs, _, _, view in self._stacks(params, split_experts=False):
+        for xs, _, _, view, pattern in self._stacks(params,
+                                                    split_experts=False):
 
             def scan_body(carry, per):
                 if len(pattern) == 1:
-                    return block_fn(carry, per, cos_sin)
+                    return block_fn[pattern[0]](carry, per, cos_sin)
                 stats = []
-                for j in range(len(pattern)):
-                    carry, st = block_fn(carry, view(per, j), cos_sin)
+                for j, kind in enumerate(pattern):
+                    carry, st = block_fn[kind](carry, view(per, j), cos_sin)
                     stats.append(st)
                 return carry, None if stats[0] is None else \
                     jax.tree.map(lambda *a: jnp.stack(a), *stats)
@@ -908,6 +1057,14 @@ class LlamaModel:
         """Next-token cross entropy with the chunked vocab projection
         (models/common.py); a routed model adds ``router_aux_loss_coef`` x
         the load-balancing loss over every routed layer and position."""
+        return self.loss_and_aux(params, batch, rng)[0]
+
+    def loss_and_aux(self, params, batch, rng=None):
+        """-> (:meth:`loss`, what the step's routing made beside it: None,
+        or for a model with a selection bias ``expert_pairs`` (L routed,
+        n_experts) — the pairs each of the router's experts was routed,
+        which :meth:`apply_rule` reads — ``held_pairs`` (L routed, E held)
+        and ``bias_abs_max``, for the step's ``moe/expert_tokens`` instant)."""
         from deepspeed_tpu.models.common import chunked_lm_loss, parse_lm_batch
 
         c = self.config
@@ -924,8 +1081,59 @@ class LlamaModel:
 
             with scope("moe/router"):
                 loss = loss + c.router_aux_loss_coef * load_balancing_loss(
-                    *stats, n_tokens=ids.size)
-        return loss
+                    *stats[:2], n_tokens=ids.size)
+        if not c.router_bias:
+            return loss, None
+        with scope("moe/router"):
+            return loss, {
+                "expert_pairs": stats[2], "held_pairs": stats[0],
+                "bias_abs_max": jnp.max(jnp.abs(
+                    params["blocks"]["router_bias"].astype(jnp.float32)))}
+
+    # ------------------------------------- leaves a rule moves (the engine)
+    def ruled_leaves(self, params):
+        """The model protocol's hook for leaves the optimizer leaves alone:
+        None, or a tree like ``params`` of bools, true at a leaf that no
+        gradient, weight decay or moment moves and :meth:`apply_rule` does
+        (the engine: ``runtime/engine.py::_apply_grads``)."""
+        if not self.config.router_bias:
+            return None
+        ruled = jax.tree.map(lambda _: False, params)
+        ruled["blocks"]["router_bias"] = True
+        return ruled
+
+    def apply_rule(self, params, aux):
+        """``params`` (the optimizer's targets: the float32 masters) with the
+        ruled leaves moved by the step's ``aux`` (:meth:`loss_and_aux`): the
+        selection bias by the aux-loss-free balancing rule, once a step, a
+        routed layer a row."""
+        from deepspeed_tpu.moe.dropless import balance_bias
+
+        with scope("optimizer/router_bias"):
+            blocks = dict(params["blocks"])
+            blocks["router_bias"] = balance_bias(
+                blocks["router_bias"], aux["expert_pairs"],
+                self.config.router_bias_rate).astype(
+                    blocks["router_bias"].dtype)
+            return {**params, "blocks": blocks}
+
+    def report_aux(self, step, aux):
+        """The step's routing counts (host values of :meth:`loss_and_aux`'s
+        second result) as the serving front-end reports a request's: the
+        counter ``moe/expert_tokens`` and an instant of that name."""
+        from deepspeed_tpu import telemetry
+
+        c = self.config
+        counts = np.asarray(aux["held_pairs"])
+        held = c.experts_held or (0, c.n_experts)
+        telemetry.get_registry().counter("moe/expert_tokens").inc(
+            float(counts.sum()))
+        telemetry.get_tracer().instant(
+            "moe/expert_tokens", cat="moe", trace=step, step=step,
+            counts=counts.tolist(), held_first=int(held[0]),
+            held=int(held[1]),
+            routed_pairs=int(np.asarray(aux["expert_pairs"]).sum()),
+            bias_abs_max=float(aux["bias_abs_max"]))
 
     # ------------------------------------------------------------- inference
     def _cache_layout(self):
@@ -990,7 +1198,8 @@ class LlamaModel:
             specs["expert_tokens"] = P()
         return specs
 
-    def _mix_cached(self, x, blk, cos_sin, caches, at, pos, attention=None):
+    def _mix_cached(self, x, blk, cos_sin, caches, at, pos, attention=None,
+                    kind="attn"):
         """A layer's mixer on x against the cache, by the leaves the block
         holds -> (what ``_block_finish`` takes, the caches). ``caches``: the
         arrays of ``_cache_names`` (the rows a position first); ``at``: the
@@ -998,16 +1207,21 @@ class LlamaModel:
         a whole prompt, written from slot 0 on; None (decode): x is the one
         new position ``pos``. A KDA layer continues the window and the state
         the cache holds for it — zeros for a new sequence — and puts back
-        what the last position left."""
+        what the last position left. ``kind``: the layer's, where its leaves
+        cannot say it (a window layer: the same rows in the cache, a window
+        over them)."""
         n_rows = len(self._cache_layout()[2])
         if "kda_qkv_w" not in blk:
+            cos_sin, window = self._rope_of(kind, cos_sin), self._window(kind)
             if attention is None:
-                attn, rows = self._attend_cached(x, blk, cos_sin,
-                                                 caches[:n_rows], at, pos)
+                attn, rows = self._attend_cached(
+                    x, blk, cos_sin, caches[:n_rows], at, pos, window)
             else:
                 from deepspeed_tpu.models.common import kv_cache_write
 
-                attn, kept = self._attend(x, blk, cos_sin, attention)
+                attn, kept = self._attend(
+                    x, blk, cos_sin, attention if window is None
+                    else functools.partial(attention, window=window))
                 with scope("attn/core"):
                     rows = tuple(kv_cache_write(held, t, at, 0)
                                  for held, t in zip(caches, kept))
@@ -1042,18 +1256,18 @@ class LlamaModel:
         it is no copy (a tick of 16 steps: 39.7 ms on the device with the
         run as a loop, 42.5 ms unrolled; PERF.md, PR 33)."""
         names = self._cache_names()
-        pattern = self.config.pattern
-        # (first layer, layers) of each run of one kind in a period
-        sizes = [len(list(same)) for _, same in itertools.groupby(pattern)]
-        runs = list(zip(itertools.accumulate([0] + sizes), sizes))
         caches, routed = tuple(cache[n] for n in names), None
-        for xs, experts, first, view in self._stacks(params):
+        for xs, experts, first, view, pattern in self._stacks(params):
+            # (first layer, layers) of each run of one kind in a period
+            sizes = [len(list(same)) for _, same in itertools.groupby(pattern)]
+            runs = list(zip(itertools.accumulate([0] + sizes), sizes))
 
             def layer(x, caches, per, n, j, i=0):
                 blk = view(per, j, i)
-                at, mine = self._layer_at(n, j, i)
+                at, mine = self._layer_at(pattern, n, j, i)
                 attn, caches = self._mix_cached(
-                    x, blk, cos_sin, caches, first + mine, pos, attention)
+                    x, blk, cos_sin, caches, first + mine, pos, attention,
+                    pattern[j])
                 x, stats = self._block_finish(x, blk, attn, experts, at)
                 return x, caches, None if stats is None else stats[0]
 
@@ -1084,7 +1298,7 @@ class LlamaModel:
             with scope("layers"):
                 (x, caches), routed = jax.lax.scan(
                     body, (x, caches), (xs, jnp.arange(n)))
-        if routed is not None and len(pattern) > 1:
+        if routed is not None and routed.ndim > 2:      # (L / p, p, E) -> L
             routed = routed.reshape(-1, routed.shape[-1])
         return x, dict(zip(names, caches)), routed
 
@@ -1094,10 +1308,9 @@ class LlamaModel:
 
         c = self.config
         B, T = input_ids.shape
-        with scope("embed"):
-            x = params["wte"].astype(c.dtype)[input_ids]
-        attention = lambda q, k, v: local_causal_attention(
-            q, k, v, c.use_flash_attention)
+        x = self._embed(params, input_ids)
+        attention = lambda q, k, v, window=None: local_causal_attention(
+            q, k, v, c.use_flash_attention, window=window)
         x, out, routed = self._run_cached(
             params, x, cache, self._rope(jnp.arange(T)), 0, attention)
         with scope("head"):
@@ -1113,8 +1326,7 @@ class LlamaModel:
         """One token for every sequence: (B,) → logits (B, V), cache advanced."""
         c = self.config
         pos = cache["pos"]
-        with scope("embed"):
-            x = params["wte"].astype(c.dtype)[token][:, None]   # (B, 1, D)
+        x = self._embed(params, token)[:, None]                 # (B, 1, D)
         x, out, routed = self._run_cached(params, x, cache,
                                           self._rope(pos[None]), pos)
         with scope("head"):

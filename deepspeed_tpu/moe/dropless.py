@@ -12,7 +12,10 @@ padding — so nothing is dropped and no slot is wasted:
   or each one's sigmoid — the DeepSeek-V3 convention) and top-k of the
   scores in float32; weights are the chosen scores, renormalised only where
   the model says so (OLMoE: ``norm_topk_prob`` false) and scaled where it
-  says so (``routed_scaling_factor``);
+  says so (``routed_scaling_factor``); a per-expert ``bias`` joins the
+  scores for the choice alone;
+* ``balance_bias``: the aux-loss-free rule that moves such a bias once a
+  step from the pairs each expert was routed;
 * ``routed_mlp``: ``sum_j w[t, j] * down_e(silu(gate_e(x_t)) * up_e(x_t))``
   over the k chosen experts ``e = experts[t, j]`` — or, told that the leaves
   hold a chip's SHARE of the experts (``first``: the router's number of the
@@ -40,11 +43,15 @@ from deepspeed_tpu.telemetry.scopes import scope
 
 
 def route_topk(x, router_w, k: int, renormalize: bool = False,
-               scoring: str = "softmax", scale: float = 1.0):
+               scoring: str = "softmax", scale: float = 1.0, bias=None):
     """x (T, D), router_w (D, E) -> ``probs`` (T, E) float32 scores of all
     experts (``scoring``: their ``softmax``, or each one's ``sigmoid``),
     ``weights`` (T, k) float32 = the k largest scores, divided by their sum
-    with ``renormalize``, times ``scale``; ``experts`` (T, k) int32."""
+    with ``renormalize``, times ``scale``; ``experts`` (T, k) int32.
+    ``bias`` (E,): a per-expert bias for the SELECTION only (aux-loss-free
+    balancing, arXiv:2408.15664): the k experts are the largest of ``probs +
+    bias``, their weights ``probs`` at those experts, without it; it takes
+    no gradient."""
     with scope("moe/router"):
         # "highest": a TPU's default float32 matmul is one bf16 pass
         logits = jnp.matmul(x.astype(jnp.float32),
@@ -52,7 +59,12 @@ def route_topk(x, router_w, k: int, renormalize: bool = False,
                             precision=jax.lax.Precision.HIGHEST)
         probs = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
             else jax.nn.softmax(logits, axis=-1)
-        weights, experts = jax.lax.top_k(probs, k)
+        if bias is None:
+            weights, experts = jax.lax.top_k(probs, k)
+        else:
+            _, experts = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(bias.astype(jnp.float32)), k)
+            weights = jnp.take_along_axis(probs, experts, axis=-1)
         if renormalize:
             weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                                  + 1e-20)
@@ -73,6 +85,17 @@ def load_balancing_loss(expert_tokens, prob_sums, n_tokens):
     f = jnp.sum(expert_tokens.astype(jnp.float32), axis=0) / denom
     p = jnp.sum(prob_sums.astype(jnp.float32), axis=0) / denom
     return E * jnp.sum(jax.lax.stop_gradient(f) * p)
+
+
+def balance_bias(bias, expert_pairs, rate: float):
+    """The aux-loss-free balancing rule (arXiv:2408.15664, as torchtitan's
+    MoE applies it), one routed layer a row: ``bias`` (L, E) float32,
+    ``expert_pairs`` (L, E) the pairs each expert was routed in the step ->
+    ``bias + d - mean(d)`` with ``d = rate * sign(mean(n) - n)``: an expert
+    under the mean load becomes likelier to be chosen, one over it less."""
+    n = expert_pairs.astype(jnp.float32)
+    d = rate * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
+    return bias + d - jnp.mean(d, axis=-1, keepdims=True)
 
 
 def _use_kernel(x, w) -> bool:
@@ -136,7 +159,19 @@ def _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer, first):
         dot = lambda rows, w: jax.lax.ragged_dot(
             rows, _layer_of(w, layer).astype(rows.dtype), sizes)
         rows = x[order // k]
-        h = jax.nn.silu(dot(rows, gate_w)) * dot(rows, up_w)
+        if first is None:
+            grouped = lambda a: a
+        else:
+            # A share's unheld pairs sort last and belong to NO group. What
+            # ``ragged_dot`` and its transposes give such a row is whatever
+            # the buffer held (the TPU's grouped kernels never visit it), and
+            # a product's d(rows) would carry that into d(x): zeros go in and
+            # zeros come out, so the cotangents of those rows are zeros too.
+            in_group = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
+            grouped = lambda a: jnp.where(in_group, a, jnp.zeros_like(a))
+        rows = grouped(rows)
+        h = jax.nn.silu(grouped(dot(rows, gate_w))) \
+            * grouped(dot(rows, up_w))
         y = dot(h, down_w)[jnp.argsort(order)]
     if first is not None:       # whatever row an unheld pair was handed
         y = jnp.where(held[:, None], y, jnp.zeros_like(y))

@@ -46,6 +46,20 @@ step takes). ONE forward kernel for every length, its shapes from
 * The backward kernels keep their own two grids (``_use_tri``: triangular
   from four row blocks, else rectangular with a double-width k block under
   the mask); they read the log-sum-exp by row, whatever block wrote it.
+* **A causal WINDOW** (``flash_attention(window=)``: row i sees the keys j
+  with ``0 <= i - j < window``; a model's window layers) is a parameter of
+  the same plans, not another family: a q block's span list starts at the
+  span that holds the first sub-block with a key inside the window
+  (``_causal_spans``: a second bound on the same list), the backward's
+  pair lists keep the pairs of the band alone (``_causal_pairs`` /
+  ``_causal_pairs_colmajor``: a window always takes the triangular grids),
+  and a sub-block pays the mask where the diagonal OR the window's trailing
+  edge crosses it — that edge is a diagonal too (``row - col = window``),
+  which is why no block-sparse layout can express it. The windowed forward
+  (``_fwd_window_kernel``) runs one softmax update a sub-block, scratch to
+  scratch, not yet the wide walk above. Its calls are ``flash_fwd_win`` /
+  ``flash_bwd_dq_win`` / ``flash_bwd_dkv_win``. Without a window every
+  plan, list and traced program is what it was.
 
 Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
 (B·H, T, D). v may have a head size of its own (latent attention: q.k at 192
@@ -146,26 +160,59 @@ def flash_supports(t_q: int, t_k: int, causal: bool,
     return _tiles(t_q, block_q, _LANES) and _tiles(t_k, block_k, 8)
 
 
-def _causal_spans(rows: int, n_sub: int):
+def _first_block(i, block: int, window):
+    """The first block of keys that holds one inside the window of some row
+    of q block ``i`` (``0 <= row - col < window``); 0 without a window. On a
+    Python int or a traced scalar alike."""
+    if window is None:
+        return 0
+    return (jnp.maximum if isinstance(i, jax.Array) else max)(
+        i * block - window + 1, 0) // block
+
+
+def _last_block(k, block: int, window, n: int):
+    """The last q block, of ``n``, a row of which sees a key of block ``k``;
+    ``n - 1`` without a window. On a Python int or a traced scalar alike."""
+    if window is None:
+        return n - 1
+    return (jnp.minimum if isinstance(k, jax.Array) else min)(
+        n - 1, (k * block + block + window - 2) // block)
+
+
+def _inside_window(qi, ki, block: int, window: int):
+    """Whether EVERY (row, col) of the block pair is inside the window: the
+    pair's smallest col against its largest row."""
+    return ki * block >= qi * block + block - window
+
+
+def _causal_spans(rows: int, n_sub: int, sub: int = 0, window=None):
     """(q block, span) pairs, row-major: for q block i the spans of ``n_sub``
-    sub-blocks that begin at or under its last row, the diagonal's last."""
-    qi = np.concatenate([np.full(i // n_sub + 1, i, np.int32)
+    sub-blocks that begin at or under its last row, the diagonal's last —
+    and, with a window, that end at or after the first sub-block which holds
+    a key inside it (a second bound on the same list)."""
+    first = [_first_block(i, sub, window) // n_sub for i in range(rows)]
+    qi = np.concatenate([np.full(i // n_sub + 1 - first[i], i, np.int32)
                          for i in range(rows)])
-    si = np.concatenate([np.arange(i // n_sub + 1, dtype=np.int32)
+    si = np.concatenate([np.arange(first[i], i // n_sub + 1, dtype=np.int32)
                          for i in range(rows)])
     return qi, si
 
 
-def _causal_pairs(nq: int):
-    """Lower-triangle block pairs, row-major (ki ascending within each qi)."""
-    return _causal_spans(nq, 1)
+def _causal_pairs(nq: int, block: int = 0, window=None):
+    """Lower-triangle block pairs, row-major (ki ascending within each qi);
+    with a window, those of the band."""
+    return _causal_spans(nq, 1, block, window)
 
 
-def _causal_pairs_colmajor(nq: int):
+def _causal_pairs_colmajor(nq: int, block: int = 0, window=None):
     """Lower-triangle block pairs, column-major (qi ascending within each ki)
-    — the dkv iteration order: each ki row accumulates over qi = ki..nq-1."""
-    ki = np.concatenate([np.full(nq - i, i, np.int32) for i in range(nq)])
-    qi = np.concatenate([np.arange(i, nq, dtype=np.int32) for i in range(nq)])
+    — the dkv iteration order: each ki row accumulates over qi = ki..nq-1,
+    with a window up to the last q block that sees it."""
+    last = [_last_block(i, block, window, nq) for i in range(nq)]
+    ki = np.concatenate([np.full(last[i] + 1 - i, i, np.int32)
+                         for i in range(nq)])
+    qi = np.concatenate([np.arange(i, last[i] + 1, dtype=np.int32)
+                         for i in range(nq)])
     return ki, qi
 
 
@@ -197,17 +244,20 @@ def _softmax_update(s, v, m, l, acc):
     return m_new, l_new, acc_new
 
 
-def _scores(q, k, scale, mask_rc=None):
+def _scores(q, k, scale, mask_rc=None, window=None):
     """q . k^T in float32; ``mask_rc`` = (rows, cols) index iotas where the
-    block crosses the diagonal, else None (an interior block pays none of
-    the mask's VPU passes)."""
+    block crosses the diagonal or, with a ``window``, its trailing edge, else
+    None (an interior block pays none of the mask's VPU passes)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if scale != 1.0:
         s = s * scale
     if mask_rc is not None:
         rows, cols = mask_rc
-        s = jnp.where(rows >= cols, s, NEG_INF)
+        keep = rows >= cols
+        if window is not None:
+            keep = keep & (rows - cols < window)
+        s = jnp.where(keep, s, NEG_INF)
     return s
 
 
@@ -300,7 +350,8 @@ _FWD_VMEM_BYTES = 32 << 20
 
 def flash_forward_plan(t: int, d: int, dv: int, dtype,
                        block_q: int = DEFAULT_BLOCK_Q,
-                       block_k: int = DEFAULT_BLOCK_K) -> ForwardPlan:
+                       block_k: int = DEFAULT_BLOCK_K,
+                       window=None) -> ForwardPlan:
     """What the causal forward runs for a call of length ``t`` (padded as
     ``flash_attention`` pads it), q.k width ``d``, v width ``dv``: the one
     place the kernel takes its shapes from, a pure function of what the call
@@ -309,17 +360,32 @@ def flash_forward_plan(t: int, d: int, dv: int, dtype,
     a q block is one row of them, and a span is as many of them as fit the
     two limits above, evened out over the length (T = 3,072 in 512s: two
     spans of three, not four and two). The last span of an awkward length
-    may end past the keys: the loop stops at the diagonal, before them."""
+    may end past the keys: the loop stops at the diagonal, before them.
+
+    ``window`` (a causal window shorter than the length: a row sees itself
+    and the ``window - 1`` keys before it) keeps the shapes and bounds the
+    lists from the other side too: a q block's spans start at the one that
+    holds the first sub-block with a key inside the window, nothing before
+    that sub-block is run, and a sub-block pays a mask where the diagonal
+    OR the window's trailing edge crosses it."""
     t = _padded_len(t, min(block_q, block_k))
     sub = _pick_block(t, min(block_q, block_k))
     rows = t // sub
     widest = max(1, min(_SPAN_SUB_BLOCKS, _SPAN_BYTES // (
         sub * (d + dv) * jnp.dtype(dtype).itemsize)))
     n_sub = -(-rows // -(-rows // widest))
+    # without a window: every span from the first, the causal half of the
+    # sub-blocks, one masked a row of them
+    first = [_first_block(i, sub, window) for i in range(rows)]
     return ForwardPlan(
         block_q=sub, span=n_sub * sub, sub_block=sub,
-        grid_steps=sum(i // n_sub + 1 for i in range(rows)),
-        sub_blocks_run=rows * (rows + 1) // 2, sub_blocks_masked=rows)
+        grid_steps=sum(i // n_sub - first[i] // n_sub + 1
+                       for i in range(rows)),
+        sub_blocks_run=sum(i - first[i] + 1 for i in range(rows)),
+        sub_blocks_masked=sum(
+            1 for i in range(rows) for g in range(first[i], i + 1)
+            if g == i or (window is not None
+                          and not _inside_window(i, g, sub, window))))
 
 
 def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -396,16 +462,24 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
                     diagonal=True))
 
 
-def _causal_forward(q, k, v, scale, block_q, block_k):
+def _causal_forward(q, k, v, scale, block_q, block_k, window=None):
     bh, t, d = q.shape
     dv = v.shape[2]
-    plan = flash_forward_plan(t, d, dv, q.dtype, block_q, block_k)
+    plan = flash_forward_plan(t, d, dv, q.dtype, block_q, block_k, window)
     sub, n_sub = plan.sub_block, plan.span // plan.sub_block
-    qi_arr, si_arr = _causal_spans(t // sub, n_sub)
+    qi_arr, si_arr = _causal_spans(t // sub, n_sub, sub, window)
+    if window is None:
+        kernel, name = functools.partial(
+            _fwd_causal_kernel, scale=scale, sub=sub, n_sub=n_sub), "flash_fwd"
+        pairs = t * t // 2                      # the causal half
+    else:
+        kernel, name = functools.partial(
+            _fwd_window_kernel, scale=scale, sub=sub, n_sub=n_sub,
+            window=window), "flash_fwd_win"
+        pairs = plan.sub_blocks_run * sub * sub
     return pl.pallas_call(
-        functools.partial(_fwd_causal_kernel, scale=scale, sub=sub,
-                          n_sub=n_sub),
-        name="flash_fwd",
+        kernel,
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bh, len(qi_arr)),
@@ -430,10 +504,53 @@ def _causal_forward(q, k, v, scale, block_q, block_k):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_FWD_VMEM_BYTES),
         cost_estimate=pl.CostEstimate(
-            flops=int(bh * t * t * (d + dv)),       # the causal half
+            flops=int(2 * bh * pairs * (d + dv)),
             bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
-            transcendentals=int(bh * t * t // 2)),
+            transcendentals=int(bh * pairs)),
     )(jnp.asarray(qi_arr), jnp.asarray(si_arr), q, k, v)
+
+
+def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                       acc_sc, m_sc, l_sc, *, scale: float, sub: int,
+                       n_sub: int, window: int):
+    """The causal forward under a window: a q block against one of the spans
+    that hold a key it sees. Of the span's sub-blocks those from the first
+    with a key inside the window up to the diagonal's are run, one softmax
+    update each, scratch to scratch; the diagonal's and the one(s) the
+    window's trailing edge crosses (a diagonal too: ``row - col = window``)
+    pay the mask, the ones between them none. A row may see nothing of the
+    trailing sub-block: what its update leaves in the running sum is wiped
+    by the next update's rescale (``exp(NEG_INF - m)`` is 0), and the
+    diagonal's sub-block always holds the row's own key."""
+    f = pl.program_id(1)
+    qi, si = qi_arr[f], si_arr[f]
+    q = q_ref[0]
+    first = _first_block(qi, sub, window)
+
+    @pl.when(si == first // n_sub)
+    def _init():
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    def update(j, g, masked):
+        mask_rc = _block_iotas(sub, sub, qi, g) if masked else None
+        s = _scores(q, k_ref[0, pl.ds(j * sub, sub), :], scale, mask_rc,
+                    window)
+        m_sc[:], l_sc[:], acc_sc[:] = _softmax_update(
+            s, v_ref[0, pl.ds(j * sub, sub), :], m_sc[:], l_sc[:], acc_sc[:])
+
+    for j in range(n_sub):
+        g = si * n_sub + j
+        run = (g >= first) & (g <= qi)
+        inside = (g < qi) & _inside_window(qi, g, sub, window)
+        pl.when(run & inside)(functools.partial(update, j, g, False))
+        pl.when(run & jnp.logical_not(inside))(
+            functools.partial(update, j, g, True))
+
+    @pl.when(si == qi // n_sub)
+    def _write():
+        _write_out(o_ref, lse_ref, m_sc[:], l_sc[:], acc_sc[:])
 
 
 # ------------------------------------- forward (non-causal, t_q and t_k apart)
@@ -455,9 +572,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
         _write_out(o_ref, lse_ref, m_sc[:], l_sc[:], acc_sc[:])
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k):
+def _flash_forward(q, k, v, scale, causal, block_q, block_k, window=None):
     if causal:              # self-attention: ``flash_attention`` saw to that
-        return _causal_forward(q, k, v, scale, block_q, block_k)
+        return _causal_forward(q, k, v, scale, block_q, block_k, window)
     bh, t_q, d = q.shape
     t_k, dv = k.shape[1], v.shape[2]
     bq = _pick_block(t_q, block_q)
@@ -496,16 +613,18 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
 _BWD_TRI_MIN_BLOCKS = 4
 
 
-def _use_tri(causal, t_q, t_k, bq, bk) -> bool:
+def _use_tri(causal, t_q, t_k, bq, bk, window=None) -> bool:
+    """A window always takes the pair lists: only they can drop the pairs
+    outside the band."""
     return (causal and t_q == t_k and bq == bk
-            and t_q // bq >= _BWD_TRI_MIN_BLOCKS)
+            and (window is not None or t_q // bq >= _BWD_TRI_MIN_BLOCKS))
 
 
 # -------------------------------------------------------------------- backward
-def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None):
+def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None, window=None):
     """Recompute P and dS for one block (shared by dq and dkv kernels).
     ``lse`` and ``delta`` arrive as the query block's lane-dense rows."""
-    p = jnp.exp(_scores(q, k, scale, mask_rc) - _col(lse))
+    p = jnp.exp(_scores(q, k, scale, mask_rc, window) - _col(lse))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     ds = p * (dp - _col(delta))
@@ -516,22 +635,33 @@ def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None):
 
 
 def _bwd_dq_tri_kernel(qi_arr, ki_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dq_ref, dq_sc, *, scale, block):
+                       delta_ref, dq_ref, dq_sc, *, scale, block, window=None):
+    """A q block's dq over its listed k blocks: those under the diagonal,
+    with a ``window`` those of the band. The mask is paid on the diagonal's
+    block and on the block(s) the window's trailing edge crosses."""
     f = pl.program_id(1)
     qi = qi_arr[f]
     ki = ki_arr[f]
 
-    @pl.when(ki == 0)
+    @pl.when(ki == _first_block(qi, block, window))
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
     def _acc(mask_rc):
         _, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                          delta_ref[0], scale, mask_rc)
+                          delta_ref[0], scale, mask_rc, window)
         dq_sc[:] += jax.lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
 
-    @pl.when(ki < qi)
+    interior = ki < qi
+    if window is not None:
+        interior = interior & _inside_window(qi, ki, block, window)
+
+        @pl.when(jnp.logical_not(interior) & (ki < qi))
+        def _trailing():
+            _acc(_block_iotas(block, block, qi, ki))
+
+    @pl.when(interior)
     def _interior():
         _acc(None)
 
@@ -543,7 +673,9 @@ def _bwd_dq_tri_kernel(qi_arr, ki_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _bwd_dkv_tri_kernel(ki_arr, qi_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
                         delta_ref, dk_ref, dv_ref, dk_sc, dv_sc,
-                        *, scale, block, num_q):
+                        *, scale, block, num_q, window=None):
+    """A k block's dk and dv over its listed q blocks: from the diagonal's
+    down, with a ``window`` as far as the last that sees it."""
     f = pl.program_id(1)
     ki = ki_arr[f]
     qi = qi_arr[f]
@@ -555,7 +687,7 @@ def _bwd_dkv_tri_kernel(ki_arr, qi_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     def _acc(mask_rc):
         p, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                          delta_ref[0], scale, mask_rc)
+                          delta_ref[0], scale, mask_rc, window)
         dv_sc[:] += jax.lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
                                         (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
@@ -566,11 +698,19 @@ def _bwd_dkv_tri_kernel(ki_arr, qi_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def _diagonal():
         _acc(_block_iotas(block, block, qi, ki))
 
-    @pl.when(qi > ki)
+    interior = qi > ki
+    if window is not None:
+        interior = interior & _inside_window(qi, ki, block, window)
+
+        @pl.when(jnp.logical_not(interior) & (qi > ki))
+        def _trailing():
+            _acc(_block_iotas(block, block, qi, ki))
+
+    @pl.when(interior)
     def _interior():
         _acc(None)
 
-    @pl.when(qi == num_q - 1)
+    @pl.when(qi == _last_block(ki, block, window, num_q))
     def _finalize():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
@@ -631,7 +771,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(res, g, scale, causal, block_q, block_k):
+def _flash_backward(res, g, scale, causal, block_q, block_k, window=None):
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
     t_k = k.shape[1]
@@ -642,16 +782,22 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None]        # (bh, 1, t_q), as lse
 
-    if causal and t_q == t_k and bq == bk and t_q // bq < _BWD_TRI_MIN_BLOCKS:
+    if causal and t_q == t_k and bq == bk and window is None \
+            and t_q // bq < _BWD_TRI_MIN_BLOCKS:
         bk = _pick_block(t_k, 2 * bq)       # short sequences: wider k blocks
         nk = t_k // bk
-    tri = _use_tri(causal, t_q, t_k, bq, bk)
+    tri = _use_tri(causal, t_q, t_k, bq, bk, window)
     if tri:
-        qi_arr, ki_arr = _causal_pairs(nq)
+        # the kernels are traced with the window only where there is one:
+        # without it their programs are what they were
+        win = {} if window is None else {"window": window}
+        suffix = "" if window is None else "_win"
+        qi_arr, ki_arr = _causal_pairs(nq, bq, window)
         # dq: iterate (qi, ki≤qi) row-major; first prefetch array indexes q/dq
         dq = pl.pallas_call(
-            functools.partial(_bwd_dq_tri_kernel, scale=scale, block=bq),
-            name="flash_bwd_dq",
+            functools.partial(_bwd_dq_tri_kernel, scale=scale, block=bq,
+                              **win),
+            name="flash_bwd_dq" + suffix,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, len(qi_arr)),
@@ -672,10 +818,11 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
         )(jnp.asarray(qi_arr), jnp.asarray(ki_arr), q, k, v, do, lse, delta)
 
         # dkv: iterate (ki, qi≥ki) — the transposed triangle
-        ki2, qi2 = _causal_pairs_colmajor(nq)
+        ki2, qi2 = _causal_pairs_colmajor(nq, bq, window)
         dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_tri_kernel, scale=scale, block=bq, num_q=nq),
-            name="flash_bwd_dkv",
+            functools.partial(_bwd_dkv_tri_kernel, scale=scale, block=bq,
+                              num_q=nq, **win),
+            name="flash_bwd_dkv" + suffix,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(bh, len(ki2)),
@@ -807,9 +954,17 @@ _flash_bthd = _attention_vjp(_flash_forward, _flash_backward)
 
 
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
-                    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
+                    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+                    window: Optional[int] = None):
     """q, k: (B, T, H, D), v: (B, T, H, Dv) → (B, T, H, Dv); Dv <= D, the
     softmax scale is D's. Differentiable; bf16-friendly.
+
+    ``window`` (a Python int, causal only): row i sees the keys j with ``0 <=
+    i - j < window``. The same kernel family with the window in its plan
+    and pair lists (``flash_forward_plan``); its calls are named
+    ``flash_fwd_win`` / ``flash_bwd_dq_win`` / ``flash_bwd_dkv_win``. A
+    window that reaches the whole length is no window: that call's plans,
+    lists and programs are those of a call without one.
 
     Causal self-attention at a length the kernels cannot tile is padded at
     the END of the sequence and the pad rows sliced off the output: under
@@ -818,6 +973,11 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     on an untileable length (``flash_supports`` tells callers beforehand).
     """
     t, d = q.shape[1], q.shape[-1]
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError(f"flash_attention: window={window} is a causal "
+                             "window of at least the row's own key")
+        window = int(window) if window < t else None
     if not flash_supports(t, k.shape[1], causal, block_q, block_k):
         raise ValueError(
             f"flash_attention: lengths ({t}, {k.shape[1]}) with causal="
@@ -836,7 +996,8 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     # (T, D) instead of a VPU pass over every (T², causal-half) score element
     # in the forward and in both backward kernels; autodiff scales dq back
     q = q * jnp.asarray(scale, q.dtype)
-    o = _flash_bthd(q, k, v, (1.0, bool(causal), int(block_q), int(block_k)))
+    o = _flash_bthd(q, k, v, (1.0, bool(causal), int(block_q), int(block_k),
+                              window))
     return o[:, :t]
 
 

@@ -20,6 +20,7 @@ constraints; bucket sizes become advisory (SURVEY §7).
 
 from __future__ import annotations
 
+import collections
 import inspect
 import math
 import os
@@ -74,6 +75,11 @@ class StepMetrics(NamedTuple):
     # field is an EMPTY pytree node, so the absent-block step program
     # traces and lowers byte-identically
     checksum: Any = None
+    # what the model's loss made beside the loss (``loss_and_aux``), where
+    # the model names leaves a rule moves: a routed model's routing counts.
+    # It leaves the compiled step with the loss, replicated; None (an empty
+    # pytree node) for every other model
+    aux: Any = None
 
 
 def _index_tag(index, shape) -> str:
@@ -297,6 +303,17 @@ class DeepSpeedEngine:
         else:
             raise ValueError("Provide model.init_params(rng) or model_parameters")
 
+        # leaves the optimizer leaves alone and a rule of the model's moves
+        # (the model protocol's ``ruled_leaves`` / ``loss_and_aux`` /
+        # ``apply_rule``: a router's selection bias, balanced from the
+        # step's routing counts). None for a model that names none: the
+        # step then traces what it always did
+        self._ruled = model.ruled_leaves(param_shapes) \
+            if hasattr(model, "ruled_leaves") else None
+        if self._ruled is not None:
+            self._loss_fn = model.loss_and_aux
+        self._aux_pending = collections.deque()
+
         tp_specs = None
         if hasattr(model, "param_partition_specs"):
             tp_specs = model.param_partition_specs()
@@ -491,6 +508,16 @@ class DeepSpeedEngine:
             from deepspeed_tpu.runtime.overlap import OverlapEngine
 
             self._overlap = OverlapEngine(self, self._config.overlap)
+        if self._ruled is not None and (
+                self._onebit or self._nvme_optimizer is not None
+                or self._host_offload_opt or (
+                    self._overlap is not None
+                    and self._overlap.schedule == "serial")):
+            raise NotImplementedError(
+                "a model whose rule moves leaves the optimizer leaves alone "
+                "(ruled_leaves) trains through the fused train_batch step: "
+                "not with a 1-bit or NVMe optimizer, an offloaded optimizer "
+                "state or the overlap engine's serial schedule")
 
         # ---- ZeRO-3 gather-on-use of the layer stack ---------------------
         # zero/partition.py: the plan's rule (None on one chip, at stage
@@ -953,6 +980,11 @@ class DeepSpeedEngine:
     def _micro_loss_and_grads(self, params, batch, rng, scale, step=None):
         """One microbatch: loss (unscaled, for reporting) + scaled grads.
         ``step`` (traced) feeds the PLD θ(t) schedule when enabled."""
+        return self._micro_loss_grads_aux(params, batch, rng, scale, step)[:2]
+
+    def _micro_loss_grads_aux(self, params, batch, rng, scale, step=None):
+        """:meth:`_micro_loss_and_grads` + what the loss made beside itself
+        for the model's rule (None unless the model names ruled leaves)."""
         kw = {}
         if self.progressive_layer_drop is not None and step is not None:
             from deepspeed_tpu.runtime.progressive_layer_drop import theta_at
@@ -964,17 +996,18 @@ class DeepSpeedEngine:
             out = self._loss_fn(p, batch, rng, **kw) if self._loss_accepts_rng() \
                 else self._loss_fn(p, batch, **kw)
             loss = out[0] if isinstance(out, tuple) else out
-            return loss.astype(jnp.float32) * scale, loss
+            aux = out[1] if self._ruled is not None else None
+            return loss.astype(jnp.float32) * scale, (loss, aux)
 
         grad = jax.grad(scaled_loss, has_aux=True)
         if self._layer_gathers is None:
-            grads, loss = grad(params)
+            grads, (loss, aux) = grad(params)
         else:
             from deepspeed_tpu.models.common import layer_leaves_hook
 
             with layer_leaves_hook(self._layer_gathers):
-                grads, loss = grad(params)
-        return loss, grads
+                grads, (loss, aux) = grad(params)
+        return loss, grads, aux
 
     def _loss_accepts_rng(self) -> bool:
         if not hasattr(self, "_rng_ok"):
@@ -993,8 +1026,11 @@ class DeepSpeedEngine:
         except (TypeError, ValueError):
             return False
 
-    def _apply_grads(self, state: TrainState, grads, loss) -> Tuple[TrainState, StepMetrics]:
+    def _apply_grads(self, state: TrainState, grads, loss,
+                     aux=None) -> Tuple[TrainState, StepMetrics]:
         """Shared optimizer phase: unscale→clip→update→cast-back→scale bookkeeping.
+        ``aux``: the loss's second result, for the model's rule on the
+        leaves the optimizer leaves alone (``self._ruled``).
 
         Mirrors stage3.step (stage3.py:1775): overflow check, unscale_and_clip,
         optimizer update, fp32→bf16/fp16 copy-back — but as one fused XLA
@@ -1054,6 +1090,15 @@ class DeepSpeedEngine:
             import optax
 
             new_masters = optax.apply_updates(masters, updates)
+            if self._ruled is not None:
+                # a ruled leaf keeps what it held (its gradient is zero, so
+                # are its moments; the update's weight decay is dropped
+                # here), then the model's rule moves it from the step's aux
+                new_masters = jax.tree.map(
+                    lambda ruled, new, old: old if ruled else new,
+                    self._ruled, new_masters, masters)
+                if aux is not None:
+                    new_masters = self.module.apply_rule(new_masters, aux)
             new_masters = jax.lax.with_sharding_constraint(new_masters, plan.master_specs if state.master is not None else plan.param_specs)
 
             keep = lambda new, old: jnp.where(finite, new, old)
@@ -1088,7 +1133,7 @@ class DeepSpeedEngine:
                                    rng=jax.random.fold_in(state.rng, state.step),
                                    skipped_steps=state.skipped_steps + (~finite).astype(jnp.int32))
             metrics = StepMetrics(loss=loss, grad_norm=grad_norm, lr=lr,
-                                  loss_scale=scale, overflow=~finite)
+                                  loss_scale=scale, overflow=~finite, aux=aux)
         return new_state, metrics
 
     def _offload_streamed(self) -> bool:
@@ -1296,21 +1341,25 @@ class DeepSpeedEngine:
         return new_state, metrics
 
     def _accumulated_loss_grads(self, state: TrainState, batch, gas: int,
-                                scale, fwd_params=None):
+                                scale, fwd_params=None, with_aux=False):
         """Mean loss + mean grads over the accumulation window — shared by the
         fused train step and the NVMe host-step path (gas>1: lax.scan over
         microbatches, reference engine grad-accumulation semantics).
         ``fwd_params`` overrides the forward's params (the overlap engine's
         serial schedule feeds the pre-gathered copy; grads then fall out in
-        the gathered layout and the grad-spec constraint does the reduce)."""
+        the gathered layout and the grad-spec constraint does the reduce).
+        ``with_aux``: -> (loss, grads, aux) with the loss's second result
+        over the window: its integer leaves are counts and add over the
+        micro-batches, the others are the last micro-batch's."""
         plan = self.plan
         params_c = self._compute_params(
             state.params if fwd_params is None else fwd_params,
             step=state.step)
         if gas == 1:
             rng = jax.random.fold_in(state.rng, state.step)
-            return self._micro_loss_and_grads(params_c, batch, rng, scale,
-                                              step=state.step)
+            out = self._micro_loss_grads_aux(params_c, batch, rng, scale,
+                                             step=state.step)
+            return out if with_aux else out[:2]
 
         def split(x):  # microbatch split: leading dim -> (gas, micro)
             return x.reshape((gas, x.shape[0] // gas) + x.shape[1:])
@@ -1333,11 +1382,11 @@ class DeepSpeedEngine:
         def body(carry, mb):
             acc, i = carry
             rng = jax.random.fold_in(jax.random.fold_in(state.rng, state.step), i)
-            loss, grads = self._micro_loss_and_grads(params_c, mb, rng, scale,
-                                                     step=state.step)
+            loss, grads, aux = self._micro_loss_grads_aux(
+                params_c, mb, rng, scale, step=state.step)
             grads = jax.lax.with_sharding_constraint(grads, plan.grad_specs)
             acc = jax.tree.map(lambda a, g: a + g.astype(acc_dtype), acc, grads)
-            return (acc, i + 1), loss
+            return (acc, i + 1), (loss, aux)
 
         # scope "accumulate": what the scan itself adds (the accumulator, its
         # adds, the micro-batch slices); a micro-batch's ops keep the model's
@@ -1351,10 +1400,15 @@ class DeepSpeedEngine:
             # micros, so each extra body keeps a full live activation set
             # (~1.8G). The scan's sequencing is what bounds gas>1 memory to
             # one micro.
-            (acc, _), losses = jax.lax.scan(body, (zero_acc, jnp.int32(0)),
-                                            mbs)
-            return jnp.mean(losses), jax.tree.map(
+            (acc, _), (losses, auxes) = jax.lax.scan(
+                body, (zero_acc, jnp.int32(0)), mbs)
+            grads = jax.tree.map(
                 lambda g: (g.astype(jnp.float32) / gas).astype(g.dtype), acc)
+            if not with_aux:
+                return jnp.mean(losses), grads
+            return jnp.mean(losses), grads, jax.tree.map(
+                lambda a: jnp.sum(a, axis=0)
+                if jnp.issubdtype(a.dtype, jnp.integer) else a[-1], auxes)
 
     def _build_train_batch_fn(self, gas: int):
         """Fused train step: scan over gradient-accumulation microbatches.
@@ -1378,11 +1432,13 @@ class DeepSpeedEngine:
         def step_fn(state: TrainState, batch):
             scale = state.scaler.scale if state.scaler is not None else jnp.float32(1.0)
             if overlap is None:
-                mean_loss, grads = self._accumulated_loss_grads(state, batch, gas, scale)
+                mean_loss, grads, aux = self._accumulated_loss_grads(
+                    state, batch, gas, scale, with_aux=True)
             else:
                 with overlap.scan_context():
-                    mean_loss, grads = self._accumulated_loss_grads(state, batch, gas, scale)
-            new_state, metrics = self._apply_grads(state, grads, mean_loss)
+                    mean_loss, grads, aux = self._accumulated_loss_grads(
+                        state, batch, gas, scale, with_aux=True)
+            new_state, metrics = self._apply_grads(state, grads, mean_loss, aux)
             if sdc_fold is not None:
                 metrics = metrics._replace(checksum=sdc_fold(
                     (new_state.params, new_state.opt_state)))
@@ -1809,6 +1865,7 @@ class DeepSpeedEngine:
                 self.micro_steps += gas
                 self.global_samples += self.train_batch_size()
                 self._post_step(metrics)
+                self._report_aux(metrics.aux)
                 if self._bad_step_sentinel is not None:
                     self._check_bad_step(metrics)
                 from deepspeed_tpu.resilience import chaos as _chaos_mod
@@ -1914,9 +1971,11 @@ class DeepSpeedEngine:
     def forward(self, batch, *args, **kwargs):
         """Compute loss AND stash this microbatch's gradients (fused — same
         cost as the reference's forward+backward pair; see module docstring)."""
-        if self._onebit:
-            raise NotImplementedError("1-bit optimizers use the fused train_batch() "
-                                      "path (grads must stay worker-local)")
+        if self._onebit or self._ruled is not None:
+            raise NotImplementedError(
+                "1-bit optimizers (grads must stay worker-local) and models "
+                "with ruled leaves (the rule reads the step's aux) use the "
+                "fused train_batch() path")
         with _telemetry.get_tracer().span("fwd", step=getattr(self, "_host_step", 0)):
             self.timers(FORWARD_GLOBAL_TIMER).start()
             batch = self._shard_batch(batch)
@@ -2048,6 +2107,25 @@ class DeepSpeedEngine:
                 out_shardings=self.sharding.replicated())
         with self.mesh:
             return self._compiled_eval(self.state, batch)
+
+    def _report_aux(self, aux, wait=False):
+        """Hand the model the host values of its loss's second result
+        (``module.report_aux(step, aux)``: a routed model's counter), with
+        no host sync of this step's own: the copy to the host is started
+        here and read by a LATER step's call, once it has landed; ``wait``
+        (the end of a run) reads what is still pending."""
+        report = getattr(self.module, "report_aux", None)
+        if report is None:
+            return
+        if aux is not None:
+            for leaf in jax.tree.leaves(aux):
+                leaf.copy_to_host_async()
+            self._aux_pending.append((self._host_step, aux))
+        while self._aux_pending and (wait or all(
+                leaf.is_ready()
+                for leaf in jax.tree.leaves(self._aux_pending[0][1]))):
+            step, landed = self._aux_pending.popleft()
+            report(step, jax.tree.map(np.asarray, landed))
 
     def _post_step(self, metrics: StepMetrics):
         if self.lr_scheduler is not None:

@@ -180,7 +180,12 @@ def _instruction_scopes(text: str) -> Dict[str, str]:
     the ``op_name`` of that computation's ROOT, else the commonest among its
     instructions; if it calls none, or one that holds no name either (what
     the compiler made itself: a combined collective, the done of a start, a
-    layout copy, a bitcast fusion), that of its first operand that has one."""
+    layout copy, a bitcast fusion), that of its first operand that has one.
+    So does an instruction whose ``op_name`` is no path but a bare name of
+    the compiler's own (``ragged-dot-none``: a grouped matmul XLA:TPU
+    rewrote into its Mosaic kernel, which keeps none of the traced op's)."""
+    from deepspeed_tpu.telemetry.scopes import classify
+
     names: Dict[str, str] = {}
     roots: Dict[str, str] = {}
     seen: Dict[str, collections.Counter] = {}
@@ -194,6 +199,21 @@ def _instruction_scopes(text: str) -> Dict[str, str]:
             continue
         is_root, name = m.groups()
         op = _OP_NAME.search(line)
+        # every op the program traced carries a PATH (``jit(step_fn)/...``);
+        # a bare name is the compiler's for a call it rewrote itself
+        # (XLA:TPU's ``ragged-dot-none``): named by its operands, as below
+        if op and "/" not in op.group(1):
+            # of its operands' names the one furthest along the step: a
+            # backward product reads a cotangent, a re-run a re-run's rows
+            given = [n for n in (names.get(o) for o in
+                                 _OPERAND.findall(line, m.end())) if n]
+            block = [n for n in given
+                     if classify(n)[0] not in ("", "layers")] or given
+            rerun = [n for n in block if "rematted_computation" in n]
+            names[name] = next(
+                (n for n in block if "transpose(" in n and n not in rerun),
+                (rerun or block or [""])[0])
+            continue
         if op:
             names[name] = op.group(1)
             seen.setdefault(comp, collections.Counter())[op.group(1)] += 1

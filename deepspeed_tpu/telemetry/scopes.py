@@ -21,7 +21,8 @@ ONE vocabulary (:data:`SCOPES`), innermost name wins:
                 slices, residual stacking, carries) is ``layers``, a
                 block's ops inside it are the block's
 ``accumulate``  the engine's scan over micro-batches, by the same rule
-``optimizer``   unscale, norm, clip, update, cast-back
+``optimizer``   unscale, norm, clip, update, cast-back; a model's rule on
+                the leaves the optimizer leaves alone (``/router_bias``)
 
 A finer name may follow a vocabulary name after a ``/`` (:data:`FINER`:
 ``attn/core``, ``mlp/up``): it goes to the notes of a traced run, never to
@@ -43,7 +44,7 @@ __all__ = ["SCOPES", "FINER", "PASSES", "scope", "classify"]
 SCOPES = ("embed", "attn", "kda", "mlp", "moe", "head", "layers",
           "accumulate", "optimizer")
 FINER = ("qkv", "core", "out", "up", "down", "router", "experts", "shared",
-         "gnorm", "update", "cast")
+         "gnorm", "update", "cast", "router_bias")
 PASSES = ("fwd", "bwd", "recompute", "none")
 
 # jax writes a transform around the scope entered outside it:

@@ -103,6 +103,26 @@ def test_causal_flash_forward_compiles_for_v5e_at_the_cells_shapes(v5e, t, d,
     assert f"(bf16[4,{t},{dv}]" in call and f"f32[4,1,{t}]" in call
 
 
+def test_windowed_flash_fwd_bwd_compiles_for_v5e_at_the_cells_shape(v5e):
+    """``trinity-mini.train.z1.s8k``'s window layers: 32 heads x 8,192 x 128
+    under a window of 2,048, the forward and BOTH backward kernels, each an
+    HLO instruction under the name a trace reads (``flash_*_win``: the
+    standing readers' ``flash_fwd`` / ``flash_bwd`` still match)."""
+    mesh = _mesh(v5e)
+    x = _abstract((1, 8192, 32, 128), jnp.bfloat16, mesh)
+    loss = lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, window=2048).astype(jnp.float32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    for kernel in ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win"):
+        assert len(re.findall(
+            rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", text)) == 1, \
+            kernel
+    # no (T, T) square of scores: the band lives in VMEM
+    assert not re.search(r"f32\[(?:\d+,)*8192,8192\]", text)
+
+
 def _attention_grad_text(attend, mesh, *shapes):
     """The compiled gradient of ``sum(attend(q, k, v))`` for the chip."""
     args = [_abstract(s, jnp.bfloat16, mesh) for s in shapes]
@@ -641,6 +661,49 @@ def test_gas4_step_at_micro_batch_6_compiles_and_runs_the_forward_once(gas4_step
     remat = len(re.findall(r"%[\w.\-]*\.remat[\w.\-]* = ", text))
     assert remat <= GAS4_REMAT_OPS, (
         f"{remat} ops rematerialized by XLA (PR 32: {GAS4_REMAT_OPS})")
+
+
+# ----- trinity-mini.train.z1.s8k: the routed, windowed step (PR 37)
+@pytest.fixture(scope="module")
+def trinity_record(v5e):
+    """``trinity-mini.train.z1.s8k``: ZeRO-1 on one chip, the cell's own
+    micro-batch of 8,192-token sequences."""
+    return _compiled_train_step(v5e, "trinity-mini.json",
+                                "train.z1.s8k.json", 1)[1]
+
+
+def test_trinity_step_runs_the_windowed_kernels_and_keeps_no_square(
+        trinity_record):
+    """The cell's real step for one v5e chip: the window layers' three
+    kernels and the full layer's three (the forward ONCE a layer under remat
+    'attn'), XLA:TPU's own grouped-matmul kernels for the routed experts'
+    ``ragged_dot`` (forward, re-run and both transposes), no (T, T) array of
+    scores anywhere, and it fits the chip beside the 9.88 GB of state."""
+    from deepspeed_tpu.telemetry.scopes import classify
+
+    text = trinity_record.compiled().as_text()
+    own = _own_instructions(text)
+    calls = collections.Counter(
+        re.sub(r"[.\d]+$", "", n) for n, _, line in own
+        if "tpu_custom_call" in line)
+    # one dense window layer + a period of [win, attn, win, win]: the
+    # period's layers are unrolled in the scan's body
+    assert calls["flash_fwd_win"] == calls["flash_bwd_dq_win"] == \
+        calls["flash_bwd_dkv_win"] == 4
+    assert calls["flash_fwd"] == calls["flash_bwd_dq"] == \
+        calls["flash_bwd_dkv"] == 1
+    assert calls["ragged-dot-none"] == 4 * 12
+    assert not re.search(r"f32\[(?:\d+,)*8192,8192\]", text)
+    table = trinity_record.instruction_scopes()
+    scopes_of = lambda prefix: {classify(table[n], n)[0] for n, _, line in own
+                                if n.startswith(prefix)}
+    assert scopes_of("ragged-dot-none") == {"moe/experts"}
+    assert scopes_of("flash_") == {"attn/core"}
+    assert "optimizer/router_bias" in {classify(v, n)[0]
+                                       for n, v in table.items()}
+    memory = trinity_record.memory()
+    assert memory["argument"] == pytest.approx(9.88e9, rel=0.01)
+    assert memory["total"] < 15.75 * 2 ** 30
 
 
 # ------------- the real steps under the program's own names (scopes, PR 35)
@@ -1184,9 +1247,14 @@ def test_kernel_failure_on_tpu_backend_propagates(monkeypatch):
     q, k, v = _qkv(1, 64, 2, 32)
     with pytest.raises(RuntimeError, match="mosaic says no"):
         common.local_causal_attention(q, k, v)
-    # and what the kernel does not carry is chosen up front, not on failure
-    out = common.local_causal_attention(q, k, v, window=8)
-    assert out.shape == q.shape
+    # a static window is the kernel's too since PR 37 ...
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        common.local_causal_attention(q, k, v, window=8)
+    # ... and what the kernel does not carry (a bias, a TRACED window) is
+    # chosen up front, not on failure
+    for kw in ({"alibi": jnp.ones((2,))}, {"window": jnp.int32(8)}):
+        out = common.local_causal_attention(q, k, v, **kw)
+        assert out.shape == q.shape
 
 
 def test_decode_kernel_never_interprets_itself():

@@ -294,3 +294,128 @@ def test_an_attention_no_kernel_ran_names_its_output_for_remat_attn(
 
     assert saved("attn") == [f"f32[{B},{T},{H},{D}]"]
     assert saved("full") == []
+
+
+# ------------------------------------------- the causal WINDOW (PR 37)
+def _windowed_reference(q, k, v, window):
+    """Plain softmax under the mask ``0 <= i - j < window``."""
+    T, d = q.shape[1], q.shape[-1]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
+        / np.sqrt(d)
+    dist = jnp.arange(T)[:, None] - jnp.arange(T)[None, :]
+    logits = jnp.where((dist >= 0) & (dist < window), logits, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, axis=-1), v)
+
+
+# lengths and windows that are and are not multiples of a block: a window of
+# whole blocks (the trailing edge cuts ONE block a row, as the cell's 2,048
+# in 512s), of a block and a bit (two), shorter than a block (diagonal and
+# trailing edge in one block), a padded length, one block, three spans
+@pytest.mark.parametrize("T,window,block", [
+    (512, 128, 128), (512, 200, 128), (640, 257, 128), (1024, 256, 128),
+    (1024, 384, 128), (300, 77, 128), (256, 64, 256), (1536, 520, 128)],
+    ids=lambda x: str(x))
+def test_windowed_kernels_match_the_masked_reference(T, window, block):
+    """Forward and all three gradients of ``flash_attention(window=)``
+    against the plain softmax under the window's mask, and the plan's counts
+    against the band it has to cover."""
+    keys = jax.random.split(jax.random.PRNGKey(T + window), 4)
+    q, k, v, g = (jax.random.normal(key, (2, T, 2, 32), jnp.float32)
+                  for key in keys)
+    attend = functools.partial(fa.flash_attention, block_q=block,
+                               block_k=block, window=window)
+    want = functools.partial(_windowed_reference, window=window)
+    np.testing.assert_allclose(np.asarray(attend(q, k, v)),
+                               np.asarray(want(q, k, v)), atol=2e-5, rtol=0)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * g)
+    for a, b in zip(jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(want), argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   rtol=0)
+    plan = fa.flash_forward_plan(T, 32, 32, q.dtype, block, block, window)
+    sub, rows = plan.sub_block, -(-T // plan.sub_block)
+    # a sub-block pair is run iff some (row, col) of it is in the band, and
+    # masked iff not all of them are
+    band = lambda i, j, test: test(
+        0 <= r - c < window for r in (i * sub, i * sub + sub - 1)
+        for c in (j * sub, j * sub + sub - 1))
+    inside = lambda i, j: 0 <= i * sub - (j * sub + sub - 1) \
+        and i * sub + sub - 1 - j * sub < window
+    touched = [(i, j) for i in range(rows) for j in range(i + 1)
+               if i * sub - (j * sub + sub - 1) < window]
+    assert plan.sub_blocks_run == len(touched)
+    assert plan.sub_blocks_masked == sum(not inside(i, j) for i, j in touched)
+    n_sub = plan.span // sub
+    qi, si = fa._causal_spans(rows, n_sub, sub, window)
+    assert plan.grid_steps == len(qi)
+    assert {(i, s) for i, s in zip(qi, si)} == \
+        {(i, j // n_sub) for i, j in touched}
+    # the backward's lists: the band's pairs, each once, either order
+    assert sorted(zip(*fa._causal_pairs(rows, sub, window))) == touched
+    assert sorted((i, j) for j, i in
+                  zip(*fa._causal_pairs_colmajor(rows, sub, window))) == touched
+
+
+def test_the_cells_windowed_plan():
+    """8,192 tokens under a window of 2,048 in 512s (trinity-mini.train.z1
+    .s8k): five sub-blocks a row once the window is full, two of them
+    masked, where full attention runs up to sixteen."""
+    full = fa.flash_forward_plan(8192, 128, 128, jnp.bfloat16)
+    win = fa.flash_forward_plan(8192, 128, 128, jnp.bfloat16, window=2048)
+    assert tuple(full) == (512, 2048, 512, 40, 136, 16)
+    assert tuple(win) == (512, 2048, 512, 28, 70, 28)
+    assert 70 == sum(min(i + 1, 5) for i in range(16))
+
+
+def test_without_a_window_every_plan_list_and_program_is_the_parents():
+    """``window=None`` (and a window that reaches the whole length) gives
+    the lists the kernels always had and traces the same program."""
+    for rows, n_sub in ((1, 1), (4, 4), (7, 4), (16, 4), (9, 3)):
+        qi = np.concatenate([np.full(i // n_sub + 1, i, np.int32)
+                             for i in range(rows)])
+        si = np.concatenate([np.arange(i // n_sub + 1, dtype=np.int32)
+                             for i in range(rows)])
+        got = fa._causal_spans(rows, n_sub)
+        assert (got[0] == qi).all() and (got[1] == si).all()
+    ki, qi = fa._causal_pairs_colmajor(5)
+    assert ki.tolist() == [0] * 5 + [1] * 4 + [2] * 3 + [3] * 2 + [4]
+    assert qi.tolist() == [0, 1, 2, 3, 4, 1, 2, 3, 4, 2, 3, 4, 3, 4, 4]
+    assert fa._use_tri(True, 1024, 1024, 512, 512) is False
+    assert fa._use_tri(True, 1024, 1024, 512, 512, window=300) is True
+    q = jnp.zeros((1, 1024, 2, 32), jnp.float32)
+    grad = lambda **kw: jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, block_q=128, block_k=128, **kw)),
+        argnums=(0, 1, 2)))(q, q, q)
+    plain = str(grad())
+    assert str(grad(window=None)) == plain == str(grad(window=1024)) \
+        == str(grad(window=5000))
+    assert "flash_fwd_win" not in plain and "flash_bwd_dq_win" not in plain
+    windowed = str(grad(window=300))
+    for name in ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win"):
+        assert name in windowed
+    with pytest.raises(ValueError, match="causal window"):
+        fa.flash_attention(q, q, q, causal=False, window=8)
+
+
+def test_a_static_window_goes_to_the_kernel_and_a_traced_one_to_the_einsum(
+        monkeypatch):
+    """``models/common.py``: on a TPU target a Python-int window is the
+    kernel's, a traced scalar (GPT-Neo's mixed scan) the einsum's; on the
+    CPU both are the einsum's, with the same numbers."""
+    from deepspeed_tpu.models import common
+
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    q, k, v = (jax.random.normal(key, (1, 256, 2, 32), jnp.float32)
+               for key in keys)
+    want = np.asarray(_windowed_reference(q, k, v, 100))
+    np.testing.assert_allclose(np.asarray(common.causal_attention(
+        q, k, v, window=100)), want, atol=2e-5, rtol=0)
+    monkeypatch.setattr(common, "_kernel_target", lambda: (None, True))
+    static = jax.make_jaxpr(lambda q, k, v: common.causal_attention(
+        q, k, v, window=100))(q, k, v)
+    assert "flash_fwd_win" in str(static)
+    np.testing.assert_allclose(np.asarray(common.causal_attention(
+        q, k, v, window=100)), want, atol=2e-5, rtol=0)
+    traced = jax.make_jaxpr(lambda q, k, v, w: common.causal_attention(
+        q, k, v, window=w))(q, k, v, jnp.int32(100))
+    assert "pallas_call" not in str(traced)

@@ -218,3 +218,38 @@ def test_a_head_that_is_replicated_anyway_is_not_gathered():
     assert "shard_map" in text          # the per-chip path, by its op_name
     for op in ("all-gather", "all-to-all", "collective-permute"):
         assert f" {op}(" not in text and f" {op}-start(" not in text, op
+
+
+@pytest.mark.parametrize("length,budget,want", [
+    (1023, 166, (93, 1023)),        # gpt2-760m.train.z1: a divisor, as ever
+    (1023, 83, (33, 1023)),         # gpt2-xl.train.z3x4
+    (8191, 1340, (1024, 8192)),     # 8,191 is a prime: one position of padding
+    (8191, 2681, (2048, 8192)),
+    (97, 16, (8, 104)),
+    (127, 127, (127, 127)),
+    (13, 3, (1, 13)),               # a budget of a few positions is a test's
+])
+def test_a_length_without_a_divisor_near_the_budget_is_padded(
+        length, budget, want):
+    assert common._chunk_len(length, budget) == want
+
+
+def test_padded_chunks_change_neither_the_loss_nor_its_gradients(monkeypatch):
+    """97 positions at a budget of 16: thirteen chunks of 8 over 104, the
+    seven rows of padding cut off again. Against one chunk of all 97."""
+    key = jax.random.PRNGKey(0)
+    x = jax.random.normal(key, (2, 97, 16))
+    head = jax.random.normal(jax.random.fold_in(key, 1), (16, VOCAB))
+    targets = jax.random.randint(jax.random.fold_in(key, 2), (2, 97), 0, VOCAB)
+    mask = (jax.random.uniform(jax.random.fold_in(key, 3), (2, 97)) < 0.7)
+    step = jax.value_and_grad(
+        lambda x, head: common.chunked_lm_loss(x, head, targets, mask),
+        argnums=(0, 1))
+    want_loss, want = step(x, head)
+    monkeypatch.setattr(common, "_CHUNK_ELEMS", 16 * 2 * VOCAB)
+    jaxpr = jax.make_jaxpr(step)(x, head).jaxpr
+    assert {eqn.params["length"] for eqn in _primitives(jaxpr)["scan"]} == {13}
+    got_loss, got = step(x, head)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)

@@ -949,7 +949,8 @@ class LlamaModel:
         out, sizes = routed_mlp(
             tokens, weights, experts,
             *(leaves[n] for n in self.EXPERT_LEAVES), layer=layer,
-            first=c.experts_held[0] if c.experts_held else None)
+            first=c.experts_held[0] if c.experts_held else None,
+            n_experts=c.n_experts)
         out = out.reshape(B, T, D)
         if "shared_gate_w" in blk:
             with scope("moe/shared"):
@@ -1063,7 +1064,9 @@ class LlamaModel:
         """-> (:meth:`loss`, what the step's routing made beside it: None,
         or for a model with a selection bias ``expert_pairs`` (L routed,
         n_experts) — the pairs each of the router's experts was routed,
-        which :meth:`apply_rule` reads — ``held_pairs`` (L routed, E held)
+        which :meth:`apply_rule` reads — ``held_pairs`` (L routed, E held),
+        ``overflow_calls`` (of the L routed layers' calls, those that held
+        more pairs than a share's buffer: ``moe/dropless.py::share_capacity``)
         and ``bias_abs_max``, for the step's ``moe/expert_tokens`` instant)."""
         from deepspeed_tpu.models.common import chunked_lm_loss, parse_lm_batch
 
@@ -1084,9 +1087,14 @@ class LlamaModel:
                     *stats[:2], n_tokens=ids.size)
         if not c.router_bias:
             return loss, None
+        from deepspeed_tpu.moe.dropless import share_overflowed
+
         with scope("moe/router"):
             return loss, {
                 "expert_pairs": stats[2], "held_pairs": stats[0],
+                "overflow_calls": jnp.sum(share_overflowed(
+                    stats[0], ids.size * c.n_experts_per_tok, c.n_experts),
+                    dtype=jnp.int32),
                 "bias_abs_max": jnp.max(jnp.abs(
                     params["blocks"]["router_bias"].astype(jnp.float32)))}
 
@@ -1120,19 +1128,25 @@ class LlamaModel:
     def report_aux(self, step, aux):
         """The step's routing counts (host values of :meth:`loss_and_aux`'s
         second result) as the serving front-end reports a request's: the
-        counter ``moe/expert_tokens`` and an instant of that name."""
+        counter ``moe/expert_tokens`` and an instant of that name; beside
+        them the routed-layer calls that held more than a share's buffer
+        (counter ``moe/share_overflow_calls``, the instant's
+        ``overflow_calls``)."""
         from deepspeed_tpu import telemetry
 
         c = self.config
         counts = np.asarray(aux["held_pairs"])
         held = c.experts_held or (0, c.n_experts)
-        telemetry.get_registry().counter("moe/expert_tokens").inc(
-            float(counts.sum()))
+        overflow = int(aux["overflow_calls"])
+        registry = telemetry.get_registry()
+        registry.counter("moe/expert_tokens").inc(float(counts.sum()))
+        registry.counter("moe/share_overflow_calls").inc(float(overflow))
         telemetry.get_tracer().instant(
             "moe/expert_tokens", cat="moe", trace=step, step=step,
             counts=counts.tolist(), held_first=int(held[0]),
             held=int(held[1]),
             routed_pairs=int(np.asarray(aux["expert_pairs"]).sum()),
+            overflow_calls=overflow,
             bias_abs_max=float(aux["bias_abs_max"]))
 
     # ------------------------------------------------------------- inference

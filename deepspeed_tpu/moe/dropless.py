@@ -21,20 +21,25 @@ padding — so nothing is dropped and no slot is wasted:
   hold a chip's SHARE of the experts (``first``: the router's number of the
   first one held), over those of the k that are held here: the router still
   chooses among all, a pair that fell on another chip's expert takes no row
-  tile and adds exactly zero, and a call none of whose pairs is held (a
-  decode step, most of the time) runs no expert kernel at all. Nothing
+  tile and adds exactly zero, and a served call none of whose pairs is held
+  (a decode step, most of the time) runs no expert kernel at all. Nothing
   stands in for the other chips or their traffic. Where the program is for
   one TPU device and the widths tile, the three contractions are the Pallas
   grouped matmul (``ops/pallas/grouped_matmul.py``) reading the stacked
   ``(L, E, ...)`` leaves in place; otherwise — the CPU, a multi-device mesh,
   training — ``jax.lax.ragged_dot`` over the sorted rows, which is also the
-  reference the kernel is tested against. The path is chosen by what the
-  code observes, never by an option and never by a failure;
+  reference the kernel is tested against; there a share moves only the rows
+  of the pairs it holds, in a buffer of ``share_capacity`` rows, and what a
+  call holds beyond that goes through the same code in further chunks of
+  that size. The path is chosen by what the code observes, never by an
+  option and never by a failure;
 * ``load_balancing_loss``: the Switch / Hugging Face auxiliary loss from
   per-layer sums, so a scanned trunk can carry them.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -115,21 +120,45 @@ def _layer_of(w, layer):
         w, layer, 0, keepdims=False)
 
 
+# rows: a share's compact buffer comes in whole tiles of the products' rows
+_ROW_TILE = 128
+
+
+def share_capacity(pairs: int, held: int, n_experts: int) -> int:
+    """Rows of the buffer a SHARE's routed MLP moves its held pairs through:
+    twice the even share of a call's ``pairs`` (token, expert) pairs where the
+    leaves hold ``held`` of the router's ``n_experts``, in whole row tiles, at
+    most every pair. A shape the code works out, never an option: the
+    products run over this many rows whatever the call holds, and a call that
+    holds more (``share_overflowed``) runs as many chunks of them."""
+    even = -(-pairs * held // n_experts)
+    return min(pairs, -(-2 * even // _ROW_TILE) * _ROW_TILE)
+
+
+def share_overflowed(held_pairs, pairs: int, n_experts: int):
+    """``held_pairs`` (..., E) pairs each held expert was given in a call of
+    ``pairs`` -> (...) bool: the call held more than ``share_capacity`` rows
+    (the train step's ``overflow_calls``, ``models/llama.py``)."""
+    return jnp.sum(held_pairs, axis=-1) > share_capacity(
+        pairs, held_pairs.shape[-1], n_experts)
+
+
 def routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer=None,
-               first=None):
+               first=None, n_experts=None):
     """x (T, D); ``weights`` / ``experts`` (T, k) from ``route_topk``;
     ``gate_w`` / ``up_w`` (E, D, F) and ``down_w`` (E, F, D), or the stacked
     (L, E, ...) leaves with the traced ``layer`` to take. ``first`` None: the
     leaves hold every expert the router can choose. An int: they hold the E
-    experts ``first .. first + E - 1`` of the router's, and a pair whose
-    expert is not among them adds exactly zero.
+    experts ``first .. first + E - 1`` of the router's ``n_experts``, and a
+    pair whose expert is not among them adds exactly zero.
     -> (out (T, D) in x's type, pairs routed to each held expert (E,) int32)."""
     with scope("moe/experts"):
         return _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer,
-                           first)
+                           first, n_experts)
 
 
-def _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer, first):
+def _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer, first,
+                n_experts):
     T, D = x.shape
     k = experts.shape[1]
     E = gate_w.shape[-3]
@@ -153,28 +182,115 @@ def _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer, first):
         # and no expert's weights leave HBM
         y = computed() if first is None else jax.lax.cond(
             n_active > 0, computed, lambda: jnp.zeros((T * k, D), x.dtype))
+        if first is not None:   # whatever row an unheld pair was handed
+            y = jnp.where(held[:, None], y, jnp.zeros_like(y))
     else:
         order = jnp.argsort(flat, stable=True)
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        if first is not None:
+            leaves = tuple(_layer_of(w, layer)
+                           for w in (gate_w, up_w, down_w))
+            capacity = share_capacity(T * k, E, n_experts or E)
+            return _share_mlp(x, weights, order, sizes, leaves,
+                              capacity).astype(x.dtype), sizes
         dot = lambda rows, w: jax.lax.ragged_dot(
             rows, _layer_of(w, layer).astype(rows.dtype), sizes)
         rows = x[order // k]
-        if first is None:
-            grouped = lambda a: a
-        else:
-            # A share's unheld pairs sort last and belong to NO group. What
-            # ``ragged_dot`` and its transposes give such a row is whatever
-            # the buffer held (the TPU's grouped kernels never visit it), and
-            # a product's d(rows) would carry that into d(x): zeros go in and
-            # zeros come out, so the cotangents of those rows are zeros too.
-            in_group = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
-            grouped = lambda a: jnp.where(in_group, a, jnp.zeros_like(a))
-        rows = grouped(rows)
-        h = jax.nn.silu(grouped(dot(rows, gate_w))) \
-            * grouped(dot(rows, up_w))
+        h = jax.nn.silu(dot(rows, gate_w)) * dot(rows, up_w)
         y = dot(h, down_w)[jnp.argsort(order)]
-    if first is not None:       # whatever row an unheld pair was handed
-        y = jnp.where(held[:, None], y, jnp.zeros_like(y))
     out = jnp.einsum("tk,tkd->td", weights, y.reshape(T, k, D).astype(
         jnp.float32))
     return out.astype(x.dtype), sizes
+
+
+def _share_mlp(x, weights, order, sizes, leaves, capacity):
+    """A share's pairs through ``ragged_dot``: ``order`` (T*k,) the pairs
+    sorted by held expert, ``sizes`` (E,) a group, the unheld pairs behind
+    the last group -> (T, D) float32. Only the first ``sum(sizes)`` entries
+    of ``order`` are anyone's rows, so the three products and the row buffers
+    around them take ``capacity`` rows and not T*k: the sorted pairs go
+    through ``_grouped_rows_mlp`` a chunk of ``capacity`` at a time, as many
+    chunks as hold a grouped row and never fewer than one (no arm skips a
+    call that holds nothing: the buffer costs its rows whatever the count,
+    as a deployment's balanced call does): ONE trip in nearly every call.
+    Every pair is computed at any count, in the same products and
+    precision."""
+    chunks = -(-order.shape[0] // capacity)
+    order = jnp.pad(order, (0, chunks * capacity - order.shape[0]))
+    return _chunks_mlp(capacity, x, weights, order, sizes, *leaves)
+
+
+def _chunk(order, sizes, capacity, i):
+    """(the i-th ``capacity`` of the sorted pairs, of each group the rows
+    among them): a group that straddles two chunks is split between them."""
+    lo = i * capacity
+    ends = jnp.cumsum(sizes)
+    part = jnp.minimum(ends, lo + capacity) - jnp.maximum(ends - sizes, lo)
+    return jax.lax.dynamic_slice(order, (lo,), (capacity,)), \
+        jnp.clip(part, 0)
+
+
+def _trips(sizes, capacity):
+    return jnp.maximum(-(-jnp.sum(sizes) // capacity), 1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _chunks_mlp(capacity, x, weights, order, sizes, gate_w, up_w, down_w):
+    """The loop of ``_share_mlp``. Its own VJP, because the trip count is
+    the call's and because of what a loop keeps for a backward otherwise
+    (every possible trip's row buffers: T*k rows again): the backward keeps
+    the INPUTS and runs a chunk's rows again before it pulls back through
+    them (the products and what feeds them; the combine is not needed)."""
+    def step(i, out):
+        return out + _grouped_rows_mlp(
+            x, weights, *_chunk(order, sizes, capacity, i),
+            gate_w, up_w, down_w)
+
+    return jax.lax.fori_loop(0, _trips(sizes, capacity), step,
+                             jnp.zeros(x.shape, jnp.float32))
+
+
+def _chunks_fwd(capacity, *operands):
+    return _chunks_mlp(capacity, *operands), operands
+
+
+def _chunks_bwd(capacity, operands, g):
+    x, weights, order, sizes, *leaves = operands
+
+    def step(i, pulled):
+        pairs, part = _chunk(order, sizes, capacity, i)
+        return jax.tree.map(jnp.add, pulled, jax.vjp(
+            lambda x, weights, *leaves: _grouped_rows_mlp(
+                x, weights, pairs, part, *leaves),
+            x, weights, *leaves)[1](g))
+
+    d_x, d_weights, *d_leaves = jax.lax.fori_loop(
+        0, _trips(sizes, capacity), step,
+        tuple(jnp.zeros_like(a) for a in (x, weights, *leaves)))
+    return (d_x, d_weights, None, None, *d_leaves)
+
+
+_chunks_mlp.defvjp(_chunks_fwd, _chunks_bwd)
+
+
+def _grouped_rows_mlp(x, weights, pairs, sizes, gate_w, up_w, down_w):
+    """``pairs`` (C,) of the flattened (token, j) pairs, the first
+    ``sum(sizes)`` sorted into groups of ``sizes`` (E,), the others of no
+    group -> (T, D) float32: each grouped pair's expert output times its
+    weight, summed into its token's row."""
+    k = weights.shape[1]
+    token = pairs // k
+    # What ``ragged_dot`` and its transposes give a row of NO group is
+    # whatever the buffer held (the TPU's grouped kernels never visit it),
+    # and a product's d(rows) would carry that into d(x): zeros go in and
+    # zeros come out, so the cotangents of those rows are zeros too.
+    in_group = (jnp.arange(pairs.shape[0]) < jnp.sum(sizes))[:, None]
+    grouped = lambda a: jnp.where(in_group, a, jnp.zeros_like(a))
+    dot = lambda rows, w: grouped(jax.lax.ragged_dot(
+        rows, w.astype(rows.dtype), sizes))
+    rows = grouped(x.at[token].get(mode="promise_in_bounds"))
+    h = jax.nn.silu(dot(rows, gate_w)) * dot(rows, up_w)
+    y = dot(h, down_w).astype(jnp.float32) \
+        * weights.reshape(-1).at[pairs].get(mode="promise_in_bounds")[:, None]
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(
+        y, mode="promise_in_bounds")

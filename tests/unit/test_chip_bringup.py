@@ -692,7 +692,10 @@ def test_trinity_step_runs_the_windowed_kernels_and_keeps_no_square(
         calls["flash_bwd_dkv_win"] == 4
     assert calls["flash_fwd"] == calls["flash_bwd_dq"] == \
         calls["flash_bwd_dkv"] == 1
-    assert calls["ragged-dot-none"] == 4 * 12
+    # a routed layer's three products over a chunk of the share's rows:
+    # forward, re-run, and in the backward once more before both transposes
+    # (the loop over chunks keeps its inputs, PR 39)
+    assert calls["ragged-dot-none"] == 4 * (3 + 3 + 9)
     assert not re.search(r"f32\[(?:\d+,)*8192,8192\]", text)
     table = trinity_record.instruction_scopes()
     scopes_of = lambda prefix: {classify(table[n], n)[0] for n, _, line in own
@@ -704,6 +707,40 @@ def test_trinity_step_runs_the_windowed_kernels_and_keeps_no_square(
     memory = trinity_record.memory()
     assert memory["argument"] == pytest.approx(9.88e9, rel=0.01)
     assert memory["total"] < 15.75 * 2 ** 30
+
+
+# what the step's ``memory()`` summed to with row buffers of ALL 131,072
+# (token, expert) pairs a routed layer (PR 37; temporaries 5,460,337,664 B)
+TRINITY_FULL_SIZE_STEP_BYTES = 15_452_601_344
+
+
+def test_trinity_step_moves_the_shares_rows_and_not_every_pair(trinity_record):
+    """16,384 tokens x top-8 = 131,072 pairs a routed layer, of which this
+    chip's 16 experts of 128 hold 16,384 if the routing is even: the row
+    buffers around the grouped products have ``share_capacity`` = 32,768
+    rows, and no array of a width has more. The products stand in loops of
+    as many trips as the call's held pairs fill chunks (one, as a rule), so
+    what a call holds beyond the buffer is computed too. And the step needs
+    less of the chip than it did."""
+    from deepspeed_tpu.moe.dropless import share_capacity
+
+    capacity = share_capacity(2 * 8192 * 8, 16, 128)
+    assert capacity == 32768
+    text = trinity_record.compiled().as_text()
+    rows = {int(n) for n in re.findall(
+        r"(?:bf16|f32)\[(\d+),(?:1024|2048)\]", text)}
+    assert max(rows) == capacity, sorted(rows)[-4:]
+    comps, called, reach = _computations(text)
+    kernels = lambda lines: sum(bool(re.search(
+        r"%ragged-dot-none[\w.]* = [^\n]*tpu_custom_call", l)) for l in lines)
+    in_loops = sorted(
+        n for n in (sum(kernels(comps[c]) for c in reach(body))
+                    for body in {c for lines in comps.values()
+                                 for c in called("body", lines)}) if n)
+    # forward and its re-run: 3 products a layer; backward: 3 again + 6
+    assert in_loops == [3] * 8 + [9] * 4
+    assert kernels(text.splitlines()) == sum(in_loops)
+    assert trinity_record.memory()["total"] <= TRINITY_FULL_SIZE_STEP_BYTES
 
 
 # ------------- the real steps under the program's own names (scopes, PR 35)

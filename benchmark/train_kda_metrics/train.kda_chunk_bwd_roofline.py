@@ -1,0 +1,2 @@
+"""``train.kda_chunk_bwd_roofline``: read by ``benchmark/kimi_metrics.py``."""
+from benchmark.kimi_metrics import kda_chunk_roofline as read  # noqa: F401

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.ops.pallas import SAVED_LSE, SAVED_O
+from deepspeed_tpu.ops.pallas import SAVED_KDA_STATES, SAVED_LSE, SAVED_O
 
 # logits-buffer budget of chunked_lm_loss: the chunk length is the largest
 # divisor of T that keeps ONE CHIP's (B, chunk, V) fp32 logits at or under
@@ -195,20 +195,27 @@ def _kernel_on_mesh(kernel, mesh, args, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)(*args)
 
 
+# what remat ``'attn'`` keeps of a block (``remat_wrap``)
+SAVED_BY_ATTN = (SAVED_O, SAVED_LSE, SAVED_KDA_STATES)
+
+
 def remat_wrap(fn, remat):
     """``fn`` (a per-layer function) under the activation-checkpoint policy a
     model's ``remat`` names (reference activation_checkpointing/
     checkpointing.py role); each config's ``VALID_REMAT`` says which it takes.
 
-    ``'attn'`` keeps what attention's backward reads and only its forward can
+    ``'attn'`` keeps what a mixer's backward reads and only its forward can
     make: the output (~1 x d a token) and, where a flash kernel ran, its
-    log-sum-exp (one float32 a head a token). Each is named where it is made
-    — the kernel's forward rule (``ops/pallas/flash_attention.py``), the
+    log-sum-exp (one float32 a head a token); of a KDA layer the state
+    pass's outputs and its state at the end of every group of chunks. Each
+    is named where it is made — the kernels' forward rules
+    (``ops/pallas/flash_attention.py``, ``ops/pallas/kda.py``), the
     einsum and ring paths below — so the backward re-runs the qkv and MLP
-    matmuls and never attention: the best FLOPs / HBM trade when ``'dots'``
-    does not fit. ``'attn_mlp'`` also keeps the MLP's activation
-    (``mlp_act``): neither attention nor the two fat MLP matmuls are re-run,
-    ~8 d^2 of the 12 d^2 a layer recomputed go for 4 d a token more HBM.
+    matmuls and never attention or the state pass: the best FLOPs / HBM
+    trade when ``'dots'`` does not fit. ``'attn_mlp'`` also keeps the MLP's
+    activation (``mlp_act``): neither attention nor the two fat MLP matmuls
+    are re-run, ~8 d^2 of the 12 d^2 a layer recomputed go for 4 d a token
+    more HBM.
 
     Where a hook on the block's leaves is installed (:func:`layer_leaves_hook`:
     ZeRO-3's gather-on-use), it runs on the arguments inside the checkpoint:
@@ -233,11 +240,11 @@ def remat_wrap(fn, remat):
         return jax.checkpoint(
             fn, policy=policies.dots_with_no_batch_dims_saveable)
     if remat == "attn":
-        return jax.checkpoint(
-            fn, policy=policies.save_only_these_names(SAVED_O, SAVED_LSE))
+        return jax.checkpoint(fn, policy=policies.save_only_these_names(
+            *SAVED_BY_ATTN))
     if remat == "attn_mlp":
         return jax.checkpoint(fn, policy=policies.save_only_these_names(
-            SAVED_O, SAVED_LSE, "mlp_act"))
+            *SAVED_BY_ATTN, "mlp_act"))
     return fn
 
 
@@ -520,18 +527,20 @@ def kda_attention(q, k, v, g, beta, state, differentiable: bool = False):
     dv), the state after the last position). The path is chosen as
     ``cached_decode_attention`` chooses: the state pass is the Pallas kernel
     (``kda_chunk_fwd``) where the program is for a TPU, its ``jnp`` form
-    otherwise, which is also what the kernel is tested against — and what
-    ``differentiable`` asks for (the trunk under ``loss``: the kernel has no
-    backward)."""
+    otherwise, which is also what the kernel is tested against.
+    ``differentiable`` (the trunk under ``loss``): the state pass with its
+    own backward (``ops/pallas/kda.py::state_pass``: ``kda_chunk_bwd`` on a
+    TPU, its ``jnp`` form otherwise), which keeps the state of every group
+    of chunks and not of every chunk."""
     from deepspeed_tpu.ops.pallas.kda import chunked_kda
 
     mesh, on_tpu = _kernel_target()
-    if not on_tpu or differentiable:
-        return chunked_kda(q, k, v, g, beta, state)
+    if not on_tpu:
+        return chunked_kda(q, k, v, g, beta, state, vjp=differentiable)
     batch, heads = _attn_axes(mesh, q.shape[0], q.shape[2])
     rows, held = P(batch, None, heads, None), P(batch, heads, None, None)
     return _kernel_on_mesh(
-        functools.partial(chunked_kda, kernel=True), mesh,
+        functools.partial(chunked_kda, kernel=True, vjp=differentiable), mesh,
         (q, k, v, g, beta, state),
         (rows, rows, rows, rows, P(batch, None, heads), held), (rows, held))
 

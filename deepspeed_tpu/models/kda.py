@@ -28,6 +28,8 @@ projection (the convolution's window), whatever the length.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -115,19 +117,30 @@ def mix(c, h, blk, tail, state, differentiable=False):
     sequence whose convolution window is ``tail`` (B, taps - 1, 3 H dk) and
     whose state is ``state`` (B, H, dk, dv) float32 (zeros: a new sequence).
     -> (the gated, normed heads (B, T, H dv) for ``o_w``, the new tail, the
-    new state). A prompt longer than ``SEGMENT`` positions is walked a
+    new state). A sequence longer than ``SEGMENT`` positions is walked a
     segment at a time (``lax.scan``, tail and state handed on, then what is
     left over): the mixer's temporaries — the projection in float32 and the
     chunked form's operands, ~1 MB a position at 64 heads x 128 — are a
-    segment's and not the prompt's, for one more read of its weights a
-    segment."""
+    segment's and not the sequence's, for one more read of its weights a
+    segment. ``differentiable`` (the trunk under ``loss``): the state pass
+    takes its own backward (``common.kda_attention``) and each segment is a
+    ``jax.checkpoint`` that keeps what remat ``'attn'`` keeps of a block, so
+    the backward holds a segment's temporaries too: it makes a segment's
+    operands again from the segment's input, never the state pass."""
     B, T, _ = h.shape
     n = T // SEGMENT
-    if T <= SEGMENT or differentiable:
-        return _mix_segment(c, h, blk, tail, state, differentiable)
+    one = functools.partial(_mix_segment, c, differentiable=differentiable)
+    if T <= SEGMENT:
+        return one(h, blk, tail, state)
+    if differentiable:
+        from deepspeed_tpu.models.common import SAVED_BY_ATTN
+
+        one = jax.checkpoint(
+            one, policy=jax.checkpoint_policies.save_only_these_names(
+                *SAVED_BY_ATTN))
 
     def segment(carry, hs):
-        out, *carry = _mix_segment(c, hs, blk, *carry)
+        out, *carry = one(hs, blk, *carry)
         return tuple(carry), out
 
     (tail, state), out = jax.lax.scan(
@@ -135,8 +148,7 @@ def mix(c, h, blk, tail, state, differentiable=False):
             h[:, :n * SEGMENT].reshape(B, n, SEGMENT, -1), 1, 0))
     out = jnp.moveaxis(out, 0, 1).reshape(B, n * SEGMENT, -1)
     if T % SEGMENT:
-        rest, tail, state = _mix_segment(c, h[:, n * SEGMENT:], blk, tail,
-                                         state)
+        rest, tail, state = one(h[:, n * SEGMENT:], blk, tail, state)
         out = jnp.concatenate([out, rest], axis=1)
     return out, tail, state
 
@@ -144,43 +156,49 @@ def mix(c, h, blk, tail, state, differentiable=False):
 def _mix_segment(c, h, blk, tail, state, differentiable=False):
     """``mix`` over positions that are handled at once. One position
     (decode) runs the recurrence itself; more run the chunked form
-    (``common.kda_attention``: the kernel in a program for a TPU unless
-    ``differentiable``)."""
+    (``common.kda_attention``: the kernels in a program for a TPU, with the
+    state pass's own backward where ``differentiable``)."""
     from deepspeed_tpu.models.common import kda_attention
     from deepspeed_tpu.ops.pallas.kda import kda_step
+    from deepspeed_tpu.telemetry.scopes import scope
 
     H, dk, ch = widths(c)
     B, T, _ = h.shape
     f32 = jnp.float32
     hd = h.astype(c.dtype)
-    window = jnp.concatenate(
-        [tail.astype(c.dtype), hd @ blk["kda_qkv_w"].astype(c.dtype)], axis=1)
-    conv_w = blk["kda_conv_w"].astype(f32)
-    qkv = jax.nn.silu(sum(conv_w[j] * window[:, j:j + T].astype(f32)
-                          for j in range(c.kda_conv))).astype(c.dtype)
-    q, k, v = (t.reshape(B, T, H, dk) for t in jnp.split(qkv, 3, axis=-1))
-
-    def unit(t, scale=1.0):
-        t = t.astype(f32)
-        return (t * (scale * jax.lax.rsqrt(
-            jnp.sum(t * t, axis=-1, keepdims=True) + L2_EPS))).astype(c.dtype)
-
-    q, k = unit(q, dk ** -0.5), unit(k)
     low = lambda a, b: (hd @ blk[a].astype(c.dtype)) @ blk[b].astype(c.dtype)
-    g = -jnp.exp(blk["kda_a_log"].astype(f32))[:, None] * jax.nn.softplus(
-        low("kda_f_a_w", "kda_f_b_w").astype(f32).reshape(B, T, H, dk)
-        + blk["kda_dt_bias"].astype(f32).reshape(H, dk))
-    beta = 2.0 * jax.nn.sigmoid((hd @ blk["kda_b_w"].astype(c.dtype)
-                                 ).astype(f32))
-    if T == 1:
-        o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            state)
-        o = o[:, None]
-    else:
-        o, state = kda_attention(q, k, v, g, beta, state, differentiable)
-    o = o.astype(f32)
-    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                          + c.rms_norm_eps) * blk["kda_o_norm_g"].astype(f32)
-    gate = jax.nn.sigmoid(low("kda_g_a_w", "kda_g_b_w").astype(f32))
-    return (o.reshape(B, T, H * dk) * gate).astype(c.dtype), \
-        window[:, T:], state
+    with scope("kda/qkv"):
+        window = jnp.concatenate(
+            [tail.astype(c.dtype), hd @ blk["kda_qkv_w"].astype(c.dtype)],
+            axis=1)
+        conv_w = blk["kda_conv_w"].astype(f32)
+        qkv = jax.nn.silu(sum(conv_w[j] * window[:, j:j + T].astype(f32)
+                              for j in range(c.kda_conv))).astype(c.dtype)
+        q, k, v = (t.reshape(B, T, H, dk) for t in jnp.split(qkv, 3, axis=-1))
+
+        def unit(t, scale=1.0):
+            t = t.astype(f32)
+            return (t * (scale * jax.lax.rsqrt(jnp.sum(
+                t * t, axis=-1, keepdims=True) + L2_EPS))).astype(c.dtype)
+
+        q, k = unit(q, dk ** -0.5), unit(k)
+        g = -jnp.exp(blk["kda_a_log"].astype(f32))[:, None] * jax.nn.softplus(
+            low("kda_f_a_w", "kda_f_b_w").astype(f32).reshape(B, T, H, dk)
+            + blk["kda_dt_bias"].astype(f32).reshape(H, dk))
+        beta = 2.0 * jax.nn.sigmoid((hd @ blk["kda_b_w"].astype(c.dtype)
+                                     ).astype(f32))
+    with scope("kda/core"):
+        if T == 1:
+            o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                beta[:, 0], state)
+            o = o[:, None]
+        else:
+            o, state = kda_attention(q, k, v, g, beta, state, differentiable)
+    with scope("kda/out"):
+        o = o.astype(f32)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + c.rms_norm_eps) \
+            * blk["kda_o_norm_g"].astype(f32)
+        gate = jax.nn.sigmoid(low("kda_g_a_w", "kda_g_b_w").astype(f32))
+        return (o.reshape(B, T, H * dk) * gate).astype(c.dtype), \
+            window[:, T:], state

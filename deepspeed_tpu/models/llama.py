@@ -30,7 +30,9 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
   mixer, its norms and its MLP per layer):
   - **latent attention (MLA)**, the mixer of a block that holds ``kv_a_w``:
     queries through a low-rank bottleneck (``q_a_w``, ``q_a_norm_g``,
-    ``q_b_w``) into heads of ``[nope | rope]`` columns; keys and values
+    ``q_b_w``; or, ``q_lora_rank`` 0, straight from one ``q_w``) into
+    heads of ``[nope | rope]`` columns (rotated, or with ``use_rope``
+    false carried as they are); keys and values
     through ONE latent row a position, ``[c_kv | k_rope]`` (``kv_a_w``,
     ``kv_a_norm_g``), from which ``kv_b_k_w (L, H, nope, C)`` makes every
     head's un-rotated key part and ``kv_b_v_w (L, H, C, v)`` its value.
@@ -73,8 +75,13 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
     cache holds two kinds of state in one dict: ``k``, ``v`` over the
     SOFTMAX layers only, and ``kda_state`` / ``kda_conv`` over the KDA
     layers (``models/common.py::cache_footprint`` tells them apart by
-    name). ``loss`` runs KDA through the chunked form's ``jnp`` path (the
-    kernel has no backward);
+    name). ``loss`` runs KDA through the chunked form with the state
+    pass's own backward (``ops/pallas/kda.py::state_pass``: both kernels
+    on a TPU), a segment at a time. The softmax kind of such a pattern
+    may be latent attention, and leading dense layers go with it where
+    they are all of ONE kind: ``dense_blocks`` then holds that kind's
+    mixer leaves itself (``LlamaConfig.dense_mixer``), ``attn_blocks`` /
+    ``kda_blocks`` run over the routed layers;
   - **window and full softmax layers in one pattern** (``layer_types``, a
     layer's kind by the published key, and ``sliding_window``; afmoe): the
     two kinds hold the SAME leaves, so they stay in ``blocks`` /
@@ -160,7 +167,9 @@ class LlamaConfig:
     # weights this chip holds; None = all of them
     experts_held: Optional[tuple] = None
     # latent attention (MLA) where kv_lora_rank > 0: the widths of the two
-    # bottlenecks, of a head's un-rotated and rotated q.k columns, of its v
+    # bottlenecks (q_lora_rank 0: no bottleneck, the queries from one
+    # ``q_w``), of a head's un-rotated and rotated q.k columns (with
+    # ``use_rope`` false the second group is carried unrotated), of its v
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -252,13 +261,14 @@ class LlamaConfig:
                 raise ValueError("the load-balancing loss needs every "
                                  "expert's count: a share of the experts "
                                  "takes router_aux_loss_coef=0")
-        if self.mla and not (self.q_lora_rank and self.qk_nope_head_dim
+        if self.mla and not (self.qk_nope_head_dim
                              and self.qk_rope_head_dim and self.v_head_dim
                              and self.n_kv_head == self.n_head
                              and not self.qk_norm):
-            raise ValueError("latent attention takes q_lora_rank, "
-                             "qk_nope_head_dim, qk_rope_head_dim and "
-                             "v_head_dim, n_kv_head = n_head and no qk_norm")
+            raise ValueError("latent attention takes qk_nope_head_dim, "
+                             "qk_rope_head_dim and v_head_dim, n_kv_head = "
+                             "n_head and no qk_norm (q_lora_rank 0: the "
+                             "queries straight from q_w)")
         if self.attn_gate and self.mla:
             raise ValueError("attn_gate: the output gate of a GQA mixer, not "
                              "of latent attention")
@@ -273,11 +283,17 @@ class LlamaConfig:
                     f"n_layer={self.n_layer}, the others KDA layers of "
                     "kda_heads x kda_head_dim behind a convolution of "
                     "kda_conv > 1 positions")
-            if self.mla or self.n_dense_layers or self.sequence_parallel:
+            if self.sequence_parallel:
                 raise ValueError(
-                    "a layer pattern (gqa_layers) with latent attention, "
-                    "leading dense layers or sequence parallelism is not "
-                    "built: KDA layers carry a state along the sequence")
+                    "a layer pattern (gqa_layers) with sequence parallelism "
+                    "is not built: KDA layers carry a state along the "
+                    "sequence")
+            if len(set(self.kinds[:self.n_dense_layers])) > 1:
+                raise ValueError(
+                    f"a layer pattern (gqa_layers={self.gqa_layers}) whose "
+                    f"{self.n_dense_layers} leading dense layers are not of "
+                    "one kind is not built: the dense stack holds ONE kind "
+                    "of mixer's leaves")
         if self.layer_types is not None:
             self.layer_types = tuple(self.layer_types)
             if len(self.layer_types) != self.n_layer or self.sliding_window < 1 \
@@ -345,6 +361,13 @@ class LlamaConfig:
                                   self.n_layer - self.n_dense_layers)
 
     @property
+    def dense_mixer(self):
+        """What the leading dense layers' stack holds of a mixer: true, the
+        model's one kind of softmax mixer; with a layer pattern the ONE kind
+        of those layers (``"attn"`` | ``"kda"``)."""
+        return True if self.gqa_layers is None else self.kinds[0]
+
+    @property
     def n_attn_layers(self) -> int:
         """Layers that keep rows a position in the cache."""
         return self.n_layer if self.gqa_layers is None \
@@ -366,8 +389,9 @@ class LlamaConfig:
         d, i, v = c.n_embd, c.intermediate_size, c.vocab_size
         if c.mla:
             qk = c.qk_nope_head_dim + c.qk_rope_head_dim
-            attn = d * c.q_lora_rank + c.q_lora_rank \
-                + c.q_lora_rank * c.n_head * qk \
+            attn = (d * c.q_lora_rank + c.q_lora_rank
+                    + c.q_lora_rank * c.n_head * qk if c.q_lora_rank
+                    else d * c.n_head * qk) \
                 + d * c.latent_dim + c.kv_lora_rank \
                 + c.kv_lora_rank * c.n_head * (c.qk_nope_head_dim
                                                + c.v_head_dim) \
@@ -468,10 +492,14 @@ class LlamaModel:
         ones = lambda *shape: jnp.ones(shape, c.param_dtype)
         if c.mla:
             h, n, r = c.n_head, c.qk_nope_head_dim, c.qk_rope_head_dim
-            return dict(
+            queries = dict(
                 q_a_w=norm(keys[1], (l, d, c.q_lora_rank), s),
                 q_a_norm_g=ones(l, c.q_lora_rank),
-                q_b_w=norm(keys[2], (l, c.q_lora_rank, h * (n + r)), s),
+                q_b_w=norm(keys[2], (l, c.q_lora_rank, h * (n + r)), s)) \
+                if c.q_lora_rank else dict(
+                    q_w=norm(keys[1], (l, d, h * (n + r)), s))
+            return dict(
+                **queries,
                 kv_a_w=norm(keys[3], (l, d, c.latent_dim), s),
                 kv_a_norm_g=ones(l, c.kv_lora_rank),
                 kv_b_k_w=norm(fold(keys[3], 1), (l, h, n, c.kv_lora_rank), s),
@@ -494,12 +522,13 @@ class LlamaModel:
 
     def _init_stack(self, keys, l: int, routed: bool,
                     mixer: bool = True) -> Dict[str, Any]:
-        """``l`` layers of one kind, stacked: the mixer's leaves (unless the
-        model keeps its mixers in stacks of their own: a layer pattern), the
-        norms' gains, then a dense MLP or the router, the held experts and
-        the shared expert. ``keys``: ``init_params``' eight (a leaf that came
-        later folds a number into one of them, so the older leaves draw
-        what they always drew)."""
+        """``l`` layers of one kind, stacked: the mixer's leaves (``mixer``
+        true or ``"attn"``: softmax; ``"kda"``: ``models/kda.py``'s; false:
+        none, the model keeps its mixers in stacks of their own: a layer
+        pattern), the norms' gains, then a dense MLP or the router, the held
+        experts and the shared expert. ``keys``: ``init_params``' eight (a
+        leaf that came later folds a number into one of them, so the older
+        leaves draw what they always drew)."""
         c = self.config
         d = c.n_embd
         fold = jax.random.fold_in
@@ -509,7 +538,11 @@ class LlamaModel:
             jax.random.normal(key, shape, c.param_dtype) * scale
         ones = lambda *shape: jnp.ones(shape, c.param_dtype)
         blocks = {"attn_norm_g": ones(l, d), "mlp_norm_g": ones(l, d)}
-        if mixer:
+        if mixer == "kda":
+            from deepspeed_tpu.models import kda
+
+            blocks.update(kda.init_leaves(c, fold(keys[1], 7), l, proj_scale))
+        elif mixer:
             blocks.update(self._init_mixer(keys, l))
         if c.sandwich_norm:
             blocks.update(post_attn_norm_g=ones(l, d),
@@ -560,7 +593,8 @@ class LlamaModel:
         model with a layer pattern keeps in ``blocks`` what every layer has
         (norms, router, experts), over ALL layers, and each kind of mixer in
         a stack of its own over the layers that have it: ``attn_blocks``
-        (softmax) and ``kda_blocks`` (``models/kda.py``)."""
+        (softmax) and ``kda_blocks`` (``models/kda.py``); its leading dense
+        layers, all of one kind, hold that kind's leaves themselves."""
         c = self.config
         keys = jax.random.split(rng, 8)
         norm = lambda key, shape: \
@@ -574,14 +608,15 @@ class LlamaModel:
         if hybrid:
             from deepspeed_tpu.models import kda
 
-            params["attn_blocks"] = self._init_mixer(keys, c.n_attn_layers)
+            own = c.kinds[c.n_dense_layers:]
+            params["attn_blocks"] = self._init_mixer(keys, own.count("attn"))
             params["kda_blocks"] = kda.init_leaves(
-                c, jax.random.fold_in(rng, 2), c.n_layer - c.n_attn_layers,
+                c, jax.random.fold_in(rng, 2), own.count("kda"),
                 0.02 / math.sqrt(2 * c.n_layer))
         if c.n_dense_layers:
             params["dense_blocks"] = self._init_stack(
                 jax.random.split(jax.random.fold_in(rng, 1), 8),
-                c.n_dense_layers, routed=False)
+                c.n_dense_layers, routed=False, mixer=c.dense_mixer)
         if not c.tie_embeddings:
             params["lm_head"] = norm(jax.random.fold_in(keys[0], 1),
                                      (c.n_embd, c.vocab_size))
@@ -593,9 +628,10 @@ class LlamaModel:
         if c.mla:
             # replicated: latent attention under tensor parallelism is open
             # (one latent row a position cannot be split by head)
-            return dict(q_a_w=rep(3), q_a_norm_g=rep(2), q_b_w=rep(3),
-                        kv_a_w=rep(3), kv_a_norm_g=rep(2), kv_b_k_w=rep(4),
-                        kv_b_v_w=rep(4), o_w=rep(3))
+            queries = dict(q_a_w=rep(3), q_a_norm_g=rep(2), q_b_w=rep(3)) \
+                if c.q_lora_rank else dict(q_w=rep(3))
+            return dict(**queries, kv_a_w=rep(3), kv_a_norm_g=rep(2),
+                        kv_b_k_w=rep(4), kv_b_v_w=rep(4), o_w=rep(3))
         specs = dict(q_w=P(None, None, "tensor"), k_w=P(None, None, "tensor"),
                      v_w=P(None, None, "tensor"), o_w=P(None, "tensor", None))
         if c.qk_norm:
@@ -608,7 +644,11 @@ class LlamaModel:
         c = self.config
         rep = lambda rank: P(*([None] * rank))
         blocks = {"attn_norm_g": rep(2), "mlp_norm_g": rep(2)}
-        if mixer:
+        if mixer == "kda":
+            from deepspeed_tpu.models import kda
+
+            blocks.update(kda.leaf_specs())
+        elif mixer:
             blocks.update(self._mixer_specs())
         if c.sandwich_norm:
             blocks.update(post_attn_norm_g=rep(2), post_mlp_norm_g=rep(2))
@@ -646,7 +686,8 @@ class LlamaModel:
             specs["attn_blocks"] = self._mixer_specs()
             specs["kda_blocks"] = kda.leaf_specs()
         if c.n_dense_layers:
-            specs["dense_blocks"] = self._stack_specs(False)
+            specs["dense_blocks"] = self._stack_specs(False,
+                                                      mixer=c.dense_mixer)
         if not c.tie_embeddings:
             specs["lm_head"] = P(None, "tensor")
         return specs
@@ -710,7 +751,9 @@ class LlamaModel:
         leaves every layer has regrouped ``(L / p, p, ...)`` (a reshape of
         the leading axis: no copy) and, where the kinds of mixer have leaves
         of their own (KDA beside softmax), each kind's own stack regrouped
-        by what a period holds of it. Window and full softmax layers hold
+        by what a period holds of it (the leading dense layers of such a
+        model are of one kind and hold its leaves themselves: a period is a
+        layer). Window and full softmax layers hold
         the same leaves: they stay in ``blocks`` / ``dense_blocks``, and
         each of the two stacks walks its own phase of the pattern."""
         c = self.config
@@ -718,36 +761,38 @@ class LlamaModel:
             if split_experts else (params["blocks"], None)
         group = lambda tree, each: jax.tree.map(
             lambda a: a.reshape(a.shape[0] // each, each, *a.shape[1:]), tree)
-        if c.gqa_layers is None:
-            stacks = []
-            for held, exp, first, count in (
-                    (params.get("dense_blocks"), None, 0, c.n_dense_layers),
-                    (blocks, experts, c.n_dense_layers,
-                     c.n_layer - c.n_dense_layers)):
-                if not count:
-                    continue
-                pattern = c.stack_pattern(first, count)
-                if len(pattern) == 1:
-                    stacks.append((held, exp, first,
-                                   lambda per, j, i=0: per, pattern))
-                else:
-                    stacks.append((
-                        group(held, len(pattern)), exp, first,
-                        lambda per, j, i=0: jax.tree.map(
-                            lambda a: a[j + i], per), pattern))
-            return stacks
-        pattern = c.pattern
-        xs = {"all": group(blocks, len(pattern)),
-              "attn": group(params["attn_blocks"], pattern.count("attn")),
-              "kda": group(params["kda_blocks"], pattern.count("kda"))}
+        stacks = []
+        # (leaves, expert leaves, first layer, layers, mixers in stacks of
+        # their own: the routed stack of a KDA pattern)
+        for held, exp, first, count, own in (
+                (params.get("dense_blocks"), None, 0, c.n_dense_layers, False),
+                (blocks, experts, c.n_dense_layers,
+                 c.n_layer - c.n_dense_layers, c.gqa_layers is not None)):
+            if not count:
+                continue
+            pattern = c.stack_pattern(first, count)
+            if own:
+                xs = {"all": group(held, len(pattern)),
+                      **{kind: group(params[f"{kind}_blocks"],
+                                     pattern.count(kind))
+                         for kind in sorted(set(pattern))}}
 
-        def view(per, j, i=0):
-            kind = pattern[j]
-            mine = pattern[:j].count(kind)
-            return {**jax.tree.map(lambda a: a[j + i], per["all"]),
-                    **jax.tree.map(lambda a: a[mine + i], per[kind])}
+                def view(per, j, i=0, pattern=pattern):
+                    kind = pattern[j]
+                    mine = pattern[:j].count(kind)
+                    return {**jax.tree.map(lambda a: a[j + i], per["all"]),
+                            **jax.tree.map(lambda a: a[mine + i], per[kind])}
 
-        return [(xs, experts, 0, view, pattern)]
+                stacks.append((xs, exp, first, view, pattern))
+            elif len(pattern) == 1:
+                stacks.append((held, exp, first,
+                               lambda per, j, i=0: per, pattern))
+            else:
+                stacks.append((
+                    group(held, len(pattern)), exp, first,
+                    lambda per, j, i=0: jax.tree.map(
+                        lambda a: a[j + i], per), pattern))
+        return stacks
 
     @staticmethod
     def _layer_at(pattern, n, j, i=0):
@@ -816,21 +861,29 @@ class LlamaModel:
         """A latent-attention block's queries and its ONE cached row a
         position: ``q_nope`` (B, T, H, nope), ``q_rope`` (B, T, H, rope)
         rotated, ``latent`` (B, T, 1, C + rope) = ``[RMSNorm(c_kv) |
-        RoPE(k_rope)]`` — the rotary key is one for all heads."""
+        RoPE(k_rope)]`` — the rotary key is one for all heads. The queries
+        through the low-rank bottleneck, or straight from ``q_w`` where the
+        block holds that; without a rotary embedding (``cos`` None) the
+        ``rope`` columns are carried as they are."""
         c = self.config
         B, T, _ = x.shape
         n, C = c.qk_nope_head_dim, c.kv_lora_rank
+        rotate = (lambda t: t) if cos is None \
+            else (lambda t: apply_rope(t, cos, sin))
         with scope("attn/qkv"):
             hd = self._rms_norm(x, blk["attn_norm_g"]).astype(c.dtype)
-            cq = self._rms_norm(hd @ blk["q_a_w"].astype(hd.dtype),
-                                blk["q_a_norm_g"])
-            q = (cq @ blk["q_b_w"].astype(hd.dtype)).reshape(
-                B, T, c.n_head, n + c.qk_rope_head_dim)
+            if "q_w" in blk:
+                q = hd @ blk["q_w"].astype(hd.dtype)
+            else:
+                cq = self._rms_norm(hd @ blk["q_a_w"].astype(hd.dtype),
+                                    blk["q_a_norm_g"])
+                q = cq @ blk["q_b_w"].astype(hd.dtype)
+            q = q.reshape(B, T, c.n_head, n + c.qk_rope_head_dim)
             kv = hd @ blk["kv_a_w"].astype(hd.dtype)         # (B, T, C + rope)
             latent = jnp.concatenate(
                 [self._rms_norm(kv[..., None, :C], blk["kv_a_norm_g"]),
-                 apply_rope(kv[..., None, C:], cos, sin)], axis=-1)
-            return q[..., :n], apply_rope(q[..., n:], cos, sin), latent
+                 rotate(kv[..., None, C:])], axis=-1)
+            return q[..., :n], rotate(q[..., n:]), latent
 
     def _attend(self, x, blk, cos_sin, attention):
         """A block's causal self-attention over the whole of x (the trunk,
@@ -1276,12 +1329,18 @@ class LlamaModel:
             sizes = [len(list(same)) for _, same in itertools.groupby(pattern)]
             runs = list(zip(itertools.accumulate([0] + sizes), sizes))
 
+            # the stack's first layer in each kind of cache array: the layers
+            # before it that keep rows a position, those that keep a state
+            kda_before = self.config.kinds[:first].count("kda")
+            before = {kind: kda_before if kind == "kda"
+                      else first - kda_before for kind in pattern}
+
             def layer(x, caches, per, n, j, i=0):
                 blk = view(per, j, i)
                 at, mine = self._layer_at(pattern, n, j, i)
                 attn, caches = self._mix_cached(
-                    x, blk, cos_sin, caches, first + mine, pos, attention,
-                    pattern[j])
+                    x, blk, cos_sin, caches, before[pattern[j]] + mine, pos,
+                    attention, pattern[j])
                 x, stats = self._block_finish(x, blk, attn, experts, at)
                 return x, caches, None if stats is None else stats[0]
 

@@ -936,15 +936,19 @@ def _attention_vjp(forward, backward):
     def bwd(static, res, g):
         q, k, v, o, lse = res
         b, t, h, d = q.shape
-        if v.shape[-1] != d:
-            raise NotImplementedError(
-                "flash_attention: the backward takes v at the q.k width "
-                f"({d}), not {v.shape[-1]}")
-        grads = backward(
-            (_to_bhtd(q), _to_bhtd(k), _to_bhtd(v),
-             _to_bhtd(o.reshape(b, t, h, d)), lse[:, None]),
-            _to_bhtd(g), *static)
-        return tuple(_to_bthd(x, b) for x in grads)
+        dv = v.shape[-1]
+        # the backward kernels take ONE width: a narrower v (latent
+        # attention: q.k 192, v 128) goes in with zero columns, and so do o
+        # and its cotangent: P v, dO v^T and rowsum(dO . o) are what they
+        # were, the extra columns of dv come out zero and are dropped
+        wide = lambda x: x if dv == d else jnp.pad(
+            x, ((0, 0),) * (x.ndim - 1) + ((0, d - dv),))
+        dq, dk, dv_wide = backward(
+            (_to_bhtd(q), _to_bhtd(k), _to_bhtd(wide(v)),
+             _to_bhtd(wide(o.reshape(b, t, h, dv))), lse[:, None]),
+            _to_bhtd(wide(g)), *static)
+        return _to_bthd(dq, b), _to_bthd(dk, b), \
+            _to_bthd(dv_wide, b)[..., :dv]
 
     attend.defvjp(fwd, bwd)
     return attend

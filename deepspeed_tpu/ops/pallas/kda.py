@@ -32,6 +32,32 @@ is tested against) then walks the chunks: grid parallel over batch x head,
 sequential over chunks, the state in a float32 VMEM scratch, emitting every
 position's output and the state after the last one.
 
+**The backward** (``state_pass``, a ``custom_vjp`` on the state pass;
+``chunked_kda(vjp=True)``, what a model's trunk under ``loss`` takes). With
+``dO`` and the cotangent ``dS_C`` of the state a chunk leaves::
+
+    d delta = Aqk^T dO + Kend dS_C        dU = d delta        dW = -d delta S_0^T
+    dAqk = dO delta^T    dQg = dO S_0^T   dKend = delta dS_C^T
+    d decay = sum_v (S_0 . dS_C)
+    dS_0 = Diag(decay) dS_C + Qg^T dO - W^T d delta
+
+so a chunk needs the state it STARTED from, and the chunks run in reverse.
+The forward rule keeps the state at the end of every GROUP of
+``CHUNKS_PER_STEP`` chunks and not of every chunk (32 heads x 128 x 128
+float32 x 256 chunks would be 537 MB a layer at 16k positions; a group's
+are 67 MB), under the name ``SAVED_KDA_STATES``, beside the outputs under
+``SAVED_O``: an activation-checkpoint policy that keeps both
+(``models/common.py::remat_wrap``, ``'attn'``) never runs the forward
+kernel a second time. ``kda_chunk_bwd`` (off the TPU
+``_state_pass_bwd_jnp``) walks the groups last to first, batch x head
+parallel: a group's chunk states are made again from its start state into
+VMEM, then its chunks run in reverse with ``dS`` float32 in VMEM, the
+matmuls' operands rounded as the forward rounds them. The gradient of
+``chunk_operands`` (the in-chunk inverse, the level-split decays) is
+autodiff's of the ``jnp`` above: the derivative of ``e^x`` is ``e^x``, so
+every exponent stays a difference <= 0 and no reciprocal of a decay appears
+in the backward either.
+
 **A per-channel decay cannot be factored naively.** ``e^(G_t - G_i)`` as
 ``e^G_t x e^-G_i`` overflows float32 inside one chunk (the cumulative
 log-decay reaches -100 and below over 64 steps where a head decays fast;
@@ -50,9 +76,8 @@ by the same form of the block-nilpotent rest, in float32 (three bf16
 passes a matmul on the MXU).
 
 A prompt that is no whole number of chunks is padded at its tail with
-``beta = 0``, ``g = 0``: the identity, the state does not move. No backward:
-``jax.grad`` works through the ``jnp`` forms (``kernel=False``) and not
-through the kernel.
+``beta = 0``, ``g = 0``: the identity, the state does not move, and a pad
+position's cotangents are zero.
 """
 
 from __future__ import annotations
@@ -62,8 +87,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.pallas import SAVED_KDA_STATES, SAVED_O
 
 CHUNK = 64
 INVERSE_BLOCK = 16      # diagonal blocks of the in-chunk inverse
@@ -198,46 +226,140 @@ def chunk_operands(q, k, v, g, beta, chunk=CHUNK):
 
 
 # ------------------------------------------------------------ the state pass
+_NT = (((1,), (1,)), ((), ()))      # a b^T
+_TN = (((0,), (0,)), ((), ()))      # a^T b
+
+
+def _chunk_delta(u, w, held):
+    """``u - w S_0`` of one chunk, rounded to the operands' type as the
+    matmuls that take it do; ``held`` the transposed state in that type."""
+    return (u.astype(jnp.float32) - jax.lax.dot_general(
+        w, held, _NT, preferred_element_type=jnp.float32)).astype(w.dtype)
+
+
 def _chunk_update(u, w, qg, kend, aqk, decay, state_t):
     """One chunk given the state it starts from, TRANSPOSED: ``state_t``
     (dv, dk) float32 (the decay then runs along the lanes). -> (o (chunk,
     dv) float32, the transposed state after the chunk). Matmuls take the
     operands' type (the state rounded to it), sums are float32."""
     f32 = jnp.float32
-    nt = (((1,), (1,)), ((), ()))
     held = state_t.astype(w.dtype)
-    delta = u.astype(f32) - jax.lax.dot_general(
-        w, held, nt, preferred_element_type=f32)
-    rounded = delta.astype(w.dtype)
-    o = jax.lax.dot_general(qg, held, nt, preferred_element_type=f32) \
+    rounded = _chunk_delta(u, w, held)
+    o = jax.lax.dot_general(qg, held, _NT, preferred_element_type=f32) \
         + jnp.dot(aqk, rounded, preferred_element_type=f32)
     state_t = state_t * decay + jax.lax.dot_general(
-        rounded, kend, (((0,), (0,)), ((), ())), preferred_element_type=f32)
+        rounded, kend, _TN, preferred_element_type=f32)
     return o, state_t
 
 
-def _state_pass_jnp(ops, state, chunk):
-    """``lax.scan`` over the chunks: the plain form of ``kda_chunk_fwd``."""
+def _chunk_state(u, w, kend, decay, state_t):
+    """``_chunk_update`` without the outputs: the state after the chunk."""
+    return state_t * decay + jax.lax.dot_general(
+        _chunk_delta(u, w, state_t.astype(w.dtype)), kend, _TN,
+        preferred_element_type=jnp.float32)
+
+
+def _chunk_backward(u, w, qg, kend, aqk, decay, state_t, do, dstate_t):
+    """The transpose of ``_chunk_update`` (the module's docstring), its
+    roundings taken as the identity. ``state_t`` (dv, dk) float32 the
+    chunk STARTED from, ``do`` (chunk, dv) in the operands' type,
+    ``dstate_t`` (dv, dk) float32 the cotangent of the state it left. ->
+    (du, dw, dqg, dkend, daqk, ddecay (1, dk), the cotangent of ``state_t``),
+    float32. Matmuls take the operands' type as the forward's do (the state
+    and its cotangent rounded to it), sums are float32."""
+    f32 = jnp.float32
+    dot = functools.partial(jax.lax.dot_general, preferred_element_type=f32)
+    held, dheld = state_t.astype(w.dtype), dstate_t.astype(w.dtype)
+    delta = _chunk_delta(u, w, held)
+    ddelta = dot(aqk, do, _TN) + dot(kend, dheld, _NT)
+    rounded = ddelta.astype(w.dtype)
+    dstate0 = dstate_t * decay + dot(do, qg, _TN) - dot(rounded, w, _TN)
+    return (ddelta, -jnp.dot(rounded, held, preferred_element_type=f32),
+            jnp.dot(do, held, preferred_element_type=f32),
+            jnp.dot(delta, dheld, preferred_element_type=f32),
+            dot(do, delta, _NT),
+            jnp.sum(state_t * dstate_t, axis=0, keepdims=True), dstate0)
+
+
+_ROWS = ("u", "w", "qg", "kend", "aqk")     # the operands a position a row
+
+
+def _per_step(n):
+    """Chunks a grid step walks, = a group whose end state the forward rule
+    keeps (``chunk_operands`` padded to whole groups)."""
+    return min(CHUNKS_PER_STEP, n)
+
+
+def _state_pass_jnp(ops, state, chunk, keep=False):
+    """``lax.scan`` over the chunks: the plain form of ``kda_chunk_fwd``.
+    ``keep``: also the transposed state at the end of every group of
+    chunks, (B * H, groups, dv, dk) float32."""
     BH, Tp, _ = ops["u"].shape
     n = Tp // chunk
     chunks = lambda t: jnp.moveaxis(
         t.reshape(BH, n, chunk, t.shape[-1]), 1, 0)
-    xs = tuple(chunks(ops[name]) for name in ("u", "w", "qg", "kend", "aqk")) \
+    xs = tuple(chunks(ops[name]) for name in _ROWS) \
         + (jnp.moveaxis(ops["decay"], 1, 0)[:, :, None, :],)
 
     def step(state_t, at):
         o, state_t = jax.vmap(_chunk_update)(*at, state_t)
-        return state_t, o
+        return state_t, (o, state_t) if keep else o
 
-    state_t, o = jax.lax.scan(step, jnp.swapaxes(state, -1, -2), xs)
-    return jnp.moveaxis(o, 0, 1).reshape(BH, Tp, -1).astype(ops["u"].dtype), \
-        jnp.swapaxes(state_t, -1, -2)
+    state_t, out = jax.lax.scan(step, jnp.swapaxes(state, -1, -2), xs)
+    o, ends = out if keep else (out, None)
+    o = jnp.moveaxis(o, 0, 1).reshape(BH, Tp, -1).astype(ops["u"].dtype)
+    if not keep:
+        return o, jnp.swapaxes(state_t, -1, -2)
+    per = _per_step(n)
+    return o, jnp.swapaxes(state_t, -1, -2), \
+        jnp.moveaxis(ends[per - 1::per], 0, 1)
+
+
+def _state_pass_bwd_jnp(ops, starts, do, dstate_t, chunk):
+    """The plain form of ``kda_chunk_bwd``: the groups last to first
+    (``lax.scan``), a group's chunk states made again from ``starts`` (B * H,
+    groups, dv, dk), then its chunks in reverse. ``do`` (B * H, T', dv),
+    ``dstate_t`` (B * H, dv, dk) float32 -> (the cotangents of the operands
+    as ``ops`` holds them, of the transposed state the pass started from)."""
+    BH, Tp, _ = ops["u"].shape
+    n = Tp // chunk
+    per = _per_step(n)
+    groups = lambda t: jnp.moveaxis(
+        t.reshape(BH, n // per, per, -1, t.shape[-1]), (1, 2), (0, 1))
+    xs = tuple(groups(ops[name]) for name in _ROWS) \
+        + (groups(ops["decay"][:, :, None, :]),)
+    do = groups(do.astype(ops["u"].dtype))
+
+    def group(dstate_t, at):
+        *rows, do, start = at
+        u, w, _, kend, _, decay = rows
+
+        def forward(state_t, at):
+            return jax.vmap(_chunk_state)(*at, state_t), state_t
+
+        _, held = jax.lax.scan(forward, start, (u, w, kend, decay))
+
+        def backward(dstate_t, at):
+            *grads, dstate_t = jax.vmap(_chunk_backward)(*at, dstate_t)
+            return dstate_t, tuple(grads)
+
+        return jax.lax.scan(backward, dstate_t, (*rows, held, do),
+                            reverse=True)
+
+    dstate_t, grads = jax.lax.scan(
+        group, dstate_t, (*xs, do, jnp.moveaxis(starts, 1, 0)), reverse=True)
+    flat = lambda t: jnp.moveaxis(t, (0, 1), (1, 2)).reshape(
+        BH, -1, t.shape[-1])
+    out = {name: flat(g).astype(ops[name].dtype)
+           for name, g in zip(_ROWS, grads)}
+    out["decay"] = flat(grads[5])
+    return out, dstate_t
 
 
 def _kda_chunk_kernel(u_ref, w_ref, qg_ref, kend_ref, aqk_ref, decay_ref,
-                      s0_ref, o_ref, s_ref, state_sc, *, chunk: int,
-                      chunks: int):
+                      s0_ref, o_ref, s_ref, *rest, chunk: int, chunks: int):
     j = pl.program_id(1)
+    *ends_ref, state_sc = rest      # the group's end state, where kept
 
     @pl.when(j == 0)
     def _start():
@@ -252,32 +374,39 @@ def _kda_chunk_kernel(u_ref, w_ref, qg_ref, kend_ref, aqk_ref, decay_ref,
         o_ref[0, rows, :] = o.astype(o_ref.dtype)
         state_sc[:] = state_t
 
+    if ends_ref:
+        ends_ref[0][0, 0] = state_sc[:]
+
     @pl.when(j == pl.num_programs(1) - 1)
     def _end():
         s_ref[0] = state_sc[:]
 
 
-def _state_pass_kernel(ops, state, chunk):
+def _state_pass_kernel(ops, state, chunk, keep=False):
     """``kda_chunk_fwd``: the grid is (batch x head, groups of chunks), the
     second axis sequential; the state lives transposed, (dv, dk) float32, in
-    VMEM from a head's first chunk to its last."""
+    VMEM from a head's first chunk to its last. ``keep``: a third output,
+    the transposed state at the end of every group."""
     BH, Tp, dv = ops["u"].shape
     dk = ops["w"].shape[-1]
     n = Tp // chunk
-    per = min(CHUNKS_PER_STEP, n)           # chunk_operands padded to it
+    per = _per_step(n)                      # chunk_operands padded to it
     rows = per * chunk
     block = lambda width: pl.BlockSpec((1, rows, width), lambda b, j: (b, j, 0))
     whole = lambda *shape: pl.BlockSpec((1, *shape), lambda b, j: (b, 0, 0))
     item = ops["u"].dtype.itemsize
-    o, state_t = pl.pallas_call(
+    kept = ([pl.BlockSpec((1, 1, dv, dk), lambda b, j: (b, j, 0, 0))],
+            [jax.ShapeDtypeStruct((BH, n // per, dv, dk), jnp.float32)]) \
+        if keep else ([], [])
+    o, state_t, *ends = pl.pallas_call(
         functools.partial(_kda_chunk_kernel, chunk=chunk, chunks=per),
         grid=(BH, n // per),
         in_specs=[block(dv), block(dk), block(dk), block(dk), block(chunk),
                   pl.BlockSpec((1, per, dk), lambda b, j: (b, j, 0)),
                   whole(dv, dk)],
-        out_specs=[block(dv), whole(dv, dk)],
+        out_specs=[block(dv), whole(dv, dk)] + kept[0],
         out_shape=[jax.ShapeDtypeStruct((BH, n * chunk, dv), ops["u"].dtype),
-                   jax.ShapeDtypeStruct((BH, dv, dk), jnp.float32)],
+                   jax.ShapeDtypeStruct((BH, dv, dk), jnp.float32)] + kept[1],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -289,23 +418,148 @@ def _state_pass_kernel(ops, state, chunk):
         name="kda_chunk_fwd",
     )(ops["u"], ops["w"], ops["qg"], ops["kend"], ops["aqk"], ops["decay"],
       jnp.swapaxes(state, -1, -2))
-    return o, jnp.swapaxes(state_t, -1, -2)
+    return (o, jnp.swapaxes(state_t, -1, -2), *ends)
 
 
-def chunked_kda(q, k, v, g, beta, state=None, chunk=CHUNK, kernel=False):
+def _kda_chunk_bwd_kernel(u_ref, w_ref, qg_ref, kend_ref, aqk_ref, decay_ref,
+                          start_ref, do_ref, ds_ref, du_ref, dw_ref, dqg_ref,
+                          dkend_ref, daqk_ref, ddecay_ref, ds0_ref, held_sc,
+                          dstate_sc, *, chunk: int, chunks: int):
+    j = pl.program_id(1)                    # the groups, last to first
+
+    @pl.when(j == 0)
+    def _start():
+        dstate_sc[:] = ds_ref[0]
+
+    at = lambda c: (pl.ds(c * chunk, chunk), pl.ds(c, 1))
+    state_t = start_ref[0, 0]
+    for c in range(chunks):         # the states the group's chunks start from
+        rows, one = at(c)
+        held_sc[c] = state_t
+        if c + 1 < chunks:
+            state_t = _chunk_state(u_ref[0, rows, :], w_ref[0, rows, :],
+                                   kend_ref[0, rows, :], decay_ref[0, one, :],
+                                   state_t)
+    for c in reversed(range(chunks)):
+        rows, one = at(c)
+        du, dw, dqg, dkend, daqk, ddecay, dstate_t = _chunk_backward(
+            u_ref[0, rows, :], w_ref[0, rows, :], qg_ref[0, rows, :],
+            kend_ref[0, rows, :], aqk_ref[0, rows, :], decay_ref[0, one, :],
+            held_sc[c], do_ref[0, rows, :], dstate_sc[:])
+        for ref, grad in ((du_ref, du), (dw_ref, dw), (dqg_ref, dqg),
+                          (dkend_ref, dkend), (daqk_ref, daqk)):
+            ref[0, rows, :] = grad.astype(ref.dtype)
+        ddecay_ref[0, one, :] = ddecay
+        dstate_sc[:] = dstate_t
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _end():
+        ds0_ref[0] = dstate_sc[:]
+
+
+def _state_pass_bwd_kernel(ops, starts, do, dstate_t, chunk):
+    """``kda_chunk_bwd``: the grid is (batch x head, groups of chunks LAST
+    TO FIRST), the second axis sequential; ``dS`` lives transposed, (dv,
+    dk) float32, in VMEM from a head's last chunk to its first, beside the
+    states a group's chunks start from, made again from ``starts``."""
+    BH, Tp, dv = ops["u"].shape
+    dk = ops["w"].shape[-1]
+    n = Tp // chunk
+    per = _per_step(n)
+    rows, steps = per * chunk, n // per
+    back = lambda j: steps - 1 - j
+    block = lambda width: pl.BlockSpec((1, rows, width),
+                                       lambda b, j: (b, back(j), 0))
+    whole = pl.BlockSpec((1, dv, dk), lambda b, j: (b, 0, 0))
+    decay = pl.BlockSpec((1, per, dk), lambda b, j: (b, back(j), 0))
+    dtype = ops["u"].dtype
+    like = lambda width: jax.ShapeDtypeStruct((BH, Tp, width), dtype)
+    *grads, ddecay, dstate_t = pl.pallas_call(
+        functools.partial(_kda_chunk_bwd_kernel, chunk=chunk, chunks=per),
+        grid=(BH, steps),
+        in_specs=[block(dv), block(dk), block(dk), block(dk), block(chunk),
+                  decay,
+                  pl.BlockSpec((1, 1, dv, dk),
+                               lambda b, j: (b, back(j), 0, 0)),
+                  block(dv), whole],
+        out_specs=[block(dv), block(dk), block(dk), block(dk), block(chunk),
+                   decay, whole],
+        out_shape=[like(dv), like(dk), like(dk), like(dk), like(chunk),
+                   jax.ShapeDtypeStruct((BH, n, dk), jnp.float32),
+                   jax.ShapeDtypeStruct((BH, dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((per, dv, dk), jnp.float32),
+                        pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(BH * n * chunk * (18 * dk * dv + 4 * chunk * dv)),
+            bytes_accessed=int(BH * n * chunk * dtype.itemsize
+                               * (3 * dv + 6 * dk + 2 * chunk)),
+            transcendentals=0),
+        name="kda_chunk_bwd",
+    )(*(ops[name] for name in _ROWS), ops["decay"], starts,
+      do.astype(dtype), dstate_t)
+    return {**dict(zip(_ROWS, grads)), "decay": ddecay}, dstate_t
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def state_pass(ops, state, chunk, kernel):
+    """The state pass over ``chunk_operands``' ``ops`` from ``state`` (B * H,
+    dk, dv) float32 -> (o (B * H, T', dv), the state after the last chunk),
+    with a backward of its own (the module's docstring): the kernels where
+    ``kernel``, their ``jnp`` forms otherwise."""
+    return (_state_pass_kernel if kernel else _state_pass_jnp)(
+        ops, state, chunk)
+
+
+def _state_pass_fwd(ops, state, chunk, kernel):
+    o, _, ends = (_state_pass_kernel if kernel else _state_pass_jnp)(
+        ops, state, chunk, keep=True)
+    # named HERE, the values the backward rule and the caller are handed: a
+    # policy that keeps both names keeps all this rule makes, the state the
+    # pass ends in being the last group's
+    o, ends = checkpoint_name(o, SAVED_O), \
+        checkpoint_name(ends, SAVED_KDA_STATES)
+    return (o, jnp.swapaxes(ends[:, -1], -1, -2)), (ops, state, ends)
+
+
+def _state_pass_bwd(chunk, kernel, res, cotangents):
+    ops, state, ends = res
+    do, dstate = cotangents
+    starts = jnp.concatenate(
+        [jnp.swapaxes(state, -1, -2)[:, None], ends[:, :-1]], axis=1)
+    grads, dstate_t = (_state_pass_bwd_kernel if kernel
+                       else _state_pass_bwd_jnp)(
+        ops, starts, do, jnp.swapaxes(dstate.astype(jnp.float32), -1, -2),
+        chunk)
+    return grads, jnp.swapaxes(dstate_t, -1, -2)
+
+
+state_pass.defvjp(_state_pass_fwd, _state_pass_bwd)
+
+
+def chunked_kda(q, k, v, g, beta, state=None, chunk=CHUNK, kernel=False,
+                vjp=False):
     """The chunked form over T positions. Shapes as ``recurrent_kda``; ->
     (o (B, T, H, dv) in v's type, the state (B, H, dk, dv) float32 after
-    position T - 1). ``kernel``: the state pass as the Pallas kernel (a
-    program for a TPU, or the interpreter in a test) or as its ``jnp`` form
-    (everywhere else; differentiable). The chunks' operands exist for all T
-    positions at once, ~45 float32 values a channel a position (3 GB at
-    4,096 positions of 64 heads x 128): a caller with a long prompt walks it
-    in segments and hands the state on (``models/kda.py::mix``)."""
+    position T - 1). ``kernel``: the state pass as the Pallas kernels (a
+    program for a TPU, or the interpreter in a test) or as their ``jnp``
+    forms (everywhere else). ``vjp``: the state pass with its own backward
+    (``state_pass``: what a gradient is taken through; without it autodiff
+    walks the ``jnp`` scan and keeps every chunk's state). The chunks'
+    operands exist for all T positions at once, ~45 float32 values a channel
+    a position (3 GB at 4,096 positions of 64 heads x 128): a caller with a
+    long sequence walks it in segments and hands the state on
+    (``models/kda.py::mix``)."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     state = jnp.zeros((B * H, dk, dv), jnp.float32) if state is None \
         else state.astype(jnp.float32).reshape(B * H, dk, dv)
-    o, state = (_state_pass_kernel if kernel else _state_pass_jnp)(
-        chunk_operands(q, k, v, g, beta, chunk), state, chunk)
+    ops = chunk_operands(q, k, v, g, beta, chunk)
+    if vjp:
+        o, state = state_pass(ops, state, chunk, kernel)
+    else:
+        o, state = (_state_pass_kernel if kernel else _state_pass_jnp)(
+            ops, state, chunk)
     return jnp.moveaxis(o[:, :T].reshape(B, H, T, dv), 1, 2), \
         state.reshape(B, H, dk, dv)

@@ -1098,6 +1098,31 @@ def test_kda_chunk_kernel_compiles_for_v5e_uninterpreted(v5e):
         assert "f32[64,128,128]" in call
 
 
+def test_kda_state_pass_compiles_for_v5e_forward_and_backward(v5e):
+    """The state pass with its own backward at Kimi-Linear's 32 heads x 128
+    and a segment of 2,048 positions: ``kda_chunk_fwd`` with the groups'
+    end states as a third output and ``kda_chunk_bwd``, one Mosaic call
+    each, uninterpreted."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    mesh = _mesh(v5e)
+    qk = _abstract((1, 2048, 32, 128), jnp.bfloat16, mesh)
+    g = _abstract((1, 2048, 32, 128), jnp.float32, mesh)
+    beta = _abstract((1, 2048, 32), jnp.float32, mesh)
+    state = _abstract((1, 32, 128, 128), jnp.float32, mesh)
+
+    def loss(*args):
+        o, s = kda.chunked_kda(*args, kernel=True, vjp=True)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(s)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        qk, qk, qk, g, beta, state).compile().as_text()
+    fwd, = re.findall(r"%[\w.]*kda_chunk_fwd[\w.]* = [^\n]*custom-call", text)
+    bwd, = re.findall(r"%[\w.]*kda_chunk_bwd[\w.]* = [^\n]*custom-call", text)
+    assert "f32[32,4,128,128]" in fwd           # a state a group of 8 chunks
+    assert bwd.count("bf16[32,2048,128]") >= 4 and "f32[32,32,128]" in bwd
+
+
 @pytest.fixture(scope="module")
 def solar(v5e):
     """(mesh, model, abstract bf16 params, abstract cache, the two serving

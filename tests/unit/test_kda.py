@@ -120,6 +120,93 @@ def test_chunked_form_continues_a_state(interpreted, kernel):
     assert float(jnp.abs(s2 - want_s).max()) < TOL
 
 
+# the state pass's own backward against ``jax.grad`` of the recurrence,
+# float32: what is left is the order of the sums. Measured 2e-6 - 2e-5 on
+# gradients of size 4 - 38 (q, k, v, g, beta) and 2e-7 on the state's
+# (size 0.4 - 2.2); a missing term moves them by their own size
+VJP_TOL = 1e-4
+VJP_CASES = {**CASES, "segments + chunks + 12": dict(T=64 * 17 + 12)}
+
+
+def _scalar(form, args, state, weights):
+    o, s = form(*args, state)
+    return jnp.sum(o.astype(jnp.float32) * weights[0]) \
+        + jnp.sum(s * weights[1])
+
+
+def _vjp_case(seed, **kw):
+    args = draw(seed, **kw)
+    B, T, H, dk = args[0].shape
+    r = np.random.default_rng(seed + 40)
+    n = lambda *s: jnp.asarray(r.standard_normal(s), jnp.float32)
+    return args, 0.3 * n(B, H, dk, dk), (n(B, T, H, dk), n(B, H, dk, dk))
+
+
+@pytest.mark.parametrize("case", VJP_CASES, ids=VJP_CASES.keys())
+def test_the_state_pass_backward_is_the_gradient_of_the_recurrence(case):
+    """All five operands AND the initial state; lengths that are and are
+    not whole chunks and whole groups of chunks; the fast-decaying head."""
+    args, state, weights = _vjp_case(1, **VJP_CASES[case])
+    want = jax.grad(functools.partial(_scalar, kda.recurrent_kda),
+                    argnums=(0, 1))(args, state, weights)
+    got = jax.jit(jax.grad(functools.partial(
+        _scalar, functools.partial(kda.chunked_kda, vjp=True)),
+        argnums=(0, 1)))(args, state, weights)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(g).all())
+        assert float(jnp.abs(g - w).max()) < VJP_TOL * max(
+            1.0, float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("T", [64 * 3, 64 * 17 + 5], ids=["a group", "groups"])
+def test_both_kernels_are_their_jnp_forms(interpreted, T):
+    """``kda_chunk_fwd`` with the groups' end states and ``kda_chunk_bwd``
+    (interpret mode) against ``_state_pass_jnp`` / ``_state_pass_bwd_jnp``
+    on the same operands: outputs, kept states, every cotangent."""
+    args, state, _ = _vjp_case(2, T=T)
+    ops = kda.chunk_operands(*args)
+    BH = state.shape[0] * state.shape[1]
+    state = state.reshape(BH, *state.shape[2:])
+    o, last, ends = kda._state_pass_kernel(ops, state, kda.CHUNK, keep=True)
+    want = kda._state_pass_jnp(ops, state, kda.CHUNK, keep=True)
+    for got, ref in zip((o, last, ends), want):
+        assert got.shape == ref.shape
+        assert float(jnp.abs(got - ref).max()) < TOL
+    groups = -(-T // (64 * kda.CHUNKS_PER_STEP))
+    assert ends.shape == (BH, groups, 32, 32)
+    assert float(jnp.abs(jnp.swapaxes(ends[:, -1], -1, -2) - last).max()) == 0
+    r = np.random.default_rng(9)
+    do = jnp.asarray(r.standard_normal(o.shape), jnp.float32)
+    ds = jnp.asarray(r.standard_normal((BH, 32, 32)), jnp.float32)
+    starts = jnp.concatenate([jnp.swapaxes(state, -1, -2)[:, None],
+                              ends[:, :-1]], axis=1)
+    got = kda._state_pass_bwd_kernel(ops, starts, do, ds, kda.CHUNK)
+    ref = kda._state_pass_bwd_jnp(ops, starts, do, ds, kda.CHUNK)
+    assert set(got[0]) == set(ops)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert float(jnp.abs(g - w).max()) < TOL * max(
+            1.0, float(jnp.abs(w).max()))
+
+
+def test_the_forward_rule_names_what_remat_attn_keeps():
+    """``remat_wrap('attn')`` keeps the state pass's outputs and its group
+    states by name: under it the backward holds no second forward pass."""
+    from deepspeed_tpu.ops.pallas import SAVED_KDA_STATES, SAVED_O
+
+    assert {SAVED_O, SAVED_KDA_STATES} <= set(common.SAVED_BY_ATTN)
+    args, state, weights = _vjp_case(3, T=200)
+    loss = functools.partial(
+        _scalar, functools.partial(kda.chunked_kda, vjp=True))
+    wrapped = common.remat_wrap(loss, "attn")
+    text = str(jax.make_jaxpr(jax.grad(wrapped))(args, state, weights))
+    assert f"name={SAVED_KDA_STATES}" in text and f"name={SAVED_O}" in text
+    plain = jax.jit(jax.grad(loss))(args, state, weights)
+    again = jax.jit(jax.grad(wrapped))(args, state, weights)
+    for g, w in zip(jax.tree.leaves(again), jax.tree.leaves(plain)):
+        assert float(jnp.abs(g - w).max()) < 1e-5
+
+
 def test_one_step_is_the_recurrence():
     q, k, v, g, beta = draw(3, T=5)
     _, state = kda.recurrent_kda(*(t[:, :4] for t in (q, k, v, g, beta)))
@@ -232,7 +319,8 @@ def test_a_long_prompt_is_walked_in_segments(monkeypatch):
 
 def test_the_kernel_path_matches_the_jnp_form(as_tpu_program):
     """A program "for a TPU" takes ``kda_chunk_fwd`` in prefill (run by the
-    interpreter here); the trunk under ``loss`` does not."""
+    interpreter here), and the trunk under ``loss`` takes it with
+    ``kda_chunk_bwd``."""
     model, params = hybrid(use_flash_attention=False)
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 512, (1, 70)),
                       jnp.int32)
@@ -247,9 +335,18 @@ def test_the_kernel_path_matches_the_jnp_form(as_tpu_program):
     assert "kda_chunk_fwd" in text
     got, _ = model.prefill(params, ids, model.init_cache(1, 80))
     assert float(jnp.abs(got - want).max()) < 2e-5
-    assert "kda_chunk_fwd" not in str(jax.make_jaxpr(model.loss)(params, ids))
-    assert np.isfinite(float(jax.grad(model.loss)(params, ids)[
-        "kda_blocks"]["kda_a_log"].sum()))
+    # the trunk under ``loss`` takes both kernels, through the state pass's
+    # own backward, and its gradient is the jnp forms'
+    text = str(jax.make_jaxpr(jax.grad(model.loss))(params, ids))
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    got = jax.jit(jax.grad(model.loss))(params, ids)
+    common._kernel_target = lambda: (None, False)
+    try:
+        want = jax.jit(jax.grad(model.loss))(params, ids)
+    finally:
+        common._kernel_target = real
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.abs(g - w).max()) < 2e-5
 
 
 def test_every_mechanism_counts():
@@ -319,9 +416,16 @@ def test_config_refuses_what_is_not_built():
     with pytest.raises(ValueError, match="layer pattern"):
         LlamaConfig(**base, gqa_layers=(0,), kda_heads=2, kda_head_dim=16,
                     sequence_parallel="ring")
-    with pytest.raises(ValueError, match="layer pattern"):
+    with pytest.raises(ValueError, match="not of one kind"):
         LlamaConfig(**base, gqa_layers=(0,), kda_heads=2, kda_head_dim=16,
-                    n_experts=4, n_experts_per_tok=2, n_dense_layers=1)
+                    n_experts=4, n_experts_per_tok=2, n_dense_layers=2)
+    # one kind of leading dense layer goes with a pattern, and so does
+    # latent attention without a low-rank q
+    c = LlamaConfig(**base, gqa_layers=(2,), kda_heads=2, kda_head_dim=16,
+                    n_experts=4, n_experts_per_tok=2, n_dense_layers=1,
+                    kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=8,
+                    v_head_dim=8)
+    assert (c.dense_mixer, c.pattern) == ("kda", ("kda", "attn", "kda"))
     with pytest.raises(ValueError, match="attn_gate"):
         LlamaConfig(**base, attn_gate=True, q_lora_rank=8, kv_lora_rank=8,
                     qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8)

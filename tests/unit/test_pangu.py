@@ -121,15 +121,21 @@ def test_flash_forward_takes_a_value_width_of_its_own(interpreted, T):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
 
-def test_flash_backward_refuses_a_value_width_of_its_own(interpreted):
-    """No model trains through latent attention here yet: the backward
-    kernels take one width, and say so."""
+def test_flash_backward_takes_a_value_width_of_its_own(interpreted):
+    """Latent attention trains (Kimi-Linear): the backward kernels take one
+    width, so a narrower v goes in with zero columns beside o and its
+    cotangent, and the gradients are the einsum path's."""
     keys = jax.random.split(jax.random.PRNGKey(5), 3)
     q, k = (jax.random.normal(kk, (1, 128, 2, 48)) for kk in keys[:2])
     v = jax.random.normal(keys[2], (1, 128, 2, 32))
-    with pytest.raises(NotImplementedError, match="q.k width"):
-        jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
-            q, k, v, block_q=128, block_k=128) ** 2))(q, k, v)
+    loss = lambda attend: lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+    got = jax.grad(loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, block_q=128, block_k=128)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: common.local_causal_attention(
+        q, k, v, use_flash=False)), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, atol=5e-5, rtol=1e-4)
 
 
 # ------------------------------------------------------------------ the router
@@ -381,7 +387,11 @@ def test_config_refuses_what_the_block_cannot_be():
     with pytest.raises(ValueError, match="n_dense_layers"):
         dataclasses.replace(TINY, n_dense_layers=3)
     with pytest.raises(ValueError, match="latent attention"):
-        dataclasses.replace(TINY, q_lora_rank=0)
+        dataclasses.replace(TINY, v_head_dim=0)
+    # no low-rank q: the queries straight from ``q_w``
+    direct = LlamaModel(dataclasses.replace(TINY, q_lora_rank=0))
+    leaves = jax.eval_shape(direct.init_params, jax.random.PRNGKey(0))
+    assert "q_w" in leaves["blocks"] and "q_a_w" not in leaves["blocks"]
     with pytest.raises(ValueError, match="load-balancing"):
         dataclasses.replace(TINY, router_aux_loss_coef=0.01)
 

@@ -525,13 +525,17 @@ def kda_attention(q, k, v, g, beta, state, differentiable: bool = False):
     the chunked form of ``ops/pallas/kda.py``. q, k, g (B, T, H, dk), v (B,
     T, H, dv), beta (B, T, H), state (B, H, dk, dv) float32 -> (o (B, T, H,
     dv), the state after the last position). The path is chosen as
-    ``cached_decode_attention`` chooses: the state pass is the Pallas kernel
-    (``kda_chunk_fwd``) where the program is for a TPU, its ``jnp`` form
-    otherwise, which is also what the kernel is tested against.
-    ``differentiable`` (the trunk under ``loss``): the state pass with its
-    own backward (``ops/pallas/kda.py::state_pass``: ``kda_chunk_bwd`` on a
-    TPU, its ``jnp`` form otherwise), which keeps the state of every group
-    of chunks and not of every chunk."""
+    ``cached_decode_attention`` chooses: where the program is for a TPU the
+    chunks' operands and the state pass are the Pallas kernels
+    (``kda_operands_fwd``, which reads q, k, v and g as they are laid out
+    here, and ``kda_chunk_fwd``), otherwise their ``jnp`` forms, which are
+    also what the kernels are tested against. ``differentiable`` (the trunk
+    under ``loss``): both with a backward of their own
+    (``ops/pallas/kda.py::operands`` and ``state_pass``: ``kda_operands_bwd``
+    and ``kda_chunk_bwd`` on a TPU; off it autodiff of the ``jnp`` operands
+    around the state pass's ``jnp`` rule), which keep the operands' inputs
+    and the state of every group of chunks, not what a chunk makes on the
+    way nor the state of every chunk."""
     from deepspeed_tpu.ops.pallas.kda import chunked_kda
 
     mesh, on_tpu = _kernel_target()

@@ -119,14 +119,17 @@ def mix(c, h, blk, tail, state, differentiable=False):
     -> (the gated, normed heads (B, T, H dv) for ``o_w``, the new tail, the
     new state). A sequence longer than ``SEGMENT`` positions is walked a
     segment at a time (``lax.scan``, tail and state handed on, then what is
-    left over): the mixer's temporaries — the projection in float32 and the
-    chunked form's operands, ~1 MB a position at 64 heads x 128 — are a
-    segment's and not the sequence's, for one more read of its weights a
-    segment. ``differentiable`` (the trunk under ``loss``): the state pass
-    takes its own backward (``common.kda_attention``) and each segment is a
-    ``jax.checkpoint`` that keeps what remat ``'attn'`` keeps of a block, so
-    the backward holds a segment's temporaries too: it makes a segment's
-    operands again from the segment's input, never the state pass."""
+    left over): the mixer's temporaries — the projection, convolution and
+    gates in float32 and the chunked form's operands (74 KB a position at 64
+    heads x 128 from ``kda_operands_fwd``; ~1 MB through the ``jnp`` form
+    off the TPU) — are a segment's and not the sequence's, for one more
+    read of its weights a segment. ``differentiable`` (the trunk under
+    ``loss``): the operands and the state pass take their own backward
+    (``common.kda_attention``) and each segment is a ``jax.checkpoint`` that
+    keeps what remat ``'attn'`` keeps of a block, so the backward holds a
+    segment's temporaries too: it makes a segment's operands again from the
+    segment's input (``kda_operands_fwd`` a second time, which keeps
+    nothing but its inputs), never the state pass."""
     B, T, _ = h.shape
     n = T // SEGMENT
     one = functools.partial(_mix_segment, c, differentiable=differentiable)
@@ -156,8 +159,8 @@ def mix(c, h, blk, tail, state, differentiable=False):
 def _mix_segment(c, h, blk, tail, state, differentiable=False):
     """``mix`` over positions that are handled at once. One position
     (decode) runs the recurrence itself; more run the chunked form
-    (``common.kda_attention``: the kernels in a program for a TPU, with the
-    state pass's own backward where ``differentiable``)."""
+    (``common.kda_attention``: the kernels in a program for a TPU, each
+    with its own backward where ``differentiable``)."""
     from deepspeed_tpu.models.common import kda_attention
     from deepspeed_tpu.ops.pallas.kda import kda_step
     from deepspeed_tpu.telemetry.scopes import scope

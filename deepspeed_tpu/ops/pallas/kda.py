@@ -1,6 +1,7 @@
 """Kimi Delta Attention (KDA): the gated delta rule with a PER-CHANNEL decay,
-as a recurrence (decode), as its chunked form (prefill) and as the Pallas TPU
-kernel that carries the state from chunk to chunk.
+as a recurrence (decode), as its chunked form (prefill, training) and as the
+Pallas TPU kernels of that form: one that makes every chunk's operands, one
+that carries the state from chunk to chunk, and a backward for each.
 
 One head holds a state ``S`` (dk, dv), float32. A position ``t`` brings a
 query and key (dk,), L2-normalised by the caller, a value (dv,), a log-decay
@@ -24,11 +25,18 @@ log-decay inside a chunk and ``S_0`` the state it starts from::
     S_C = Diag(e^G_C) S_0 + (K . e^(G_C - G))^T delta
 
 ``chunk_operands`` computes everything that does not depend on ``S_0`` for
-all chunks at once (matmuls, parallel over chunks; plain XLA): ``U = T beta
-V``, ``W = T beta (K . e^G)`` with ``T = (I + A)^-1``, ``Qg``, ``Kend``,
-``Aqk`` and the chunk's total decay. ``kda_chunk_fwd`` (the kernel; off the
-TPU ``_state_pass_jnp``, the same three lines in ``jnp``, which the kernel
-is tested against) then walks the chunks: grid parallel over batch x head,
+all chunks at once: ``U = T beta V``, ``W = T beta (K . e^G)`` with ``T = (I
++ A)^-1``, ``Qg``, ``Kend``, ``Aqk`` and the chunk's total decay. It is the
+definition, in ``jnp``, and what runs off the TPU. In a program for a TPU
+the same comes from ``kda_operands_fwd`` (``_operands_kernel``; its body,
+``_one_chunk_operands``, is tested against the ``jnp``): grid parallel over
+batch x head and over groups of ``CHUNKS_PER_STEP`` chunks, nothing
+carried; q, k, v and g are read as the caller's ``(B, T, H dk)`` rows, a
+head's lanes by block index, and a chunk's cumulative decays, level
+factors, scores and inverse never leave VMEM (through XLA they were ~45
+float32 values a channel a position in HBM). ``kda_chunk_fwd`` (off the TPU
+``_state_pass_jnp``, the same three lines in ``jnp``, which the kernel is
+tested against) then walks the chunks: grid parallel over batch x head,
 sequential over chunks, the state in a float32 VMEM scratch, emitting every
 position's output and the state after the last one.
 
@@ -52,11 +60,20 @@ kernel a second time. ``kda_chunk_bwd`` (off the TPU
 ``_state_pass_bwd_jnp``) walks the groups last to first, batch x head
 parallel: a group's chunk states are made again from its start state into
 VMEM, then its chunks run in reverse with ``dS`` float32 in VMEM, the
-matmuls' operands rounded as the forward rounds them. The gradient of
-``chunk_operands`` (the in-chunk inverse, the level-split decays) is
-autodiff's of the ``jnp`` above: the derivative of ``e^x`` is ``e^x``, so
-every exponent stays a difference <= 0 and no reciprocal of a decay appears
-in the backward either.
+matmuls' operands rounded as the forward rounds them.
+
+The operands have a rule of their own too (``operands``, a ``custom_vjp``
+around ``kda_operands_fwd``) whose residuals are its five INPUTS: a
+checkpointed segment's re-run writes nothing for it. ``kda_operands_bwd``
+reads q, k, v, g, beta and the cotangents ``kda_chunk_bwd`` wrote, makes a
+chunk's intermediates again in VMEM and transposes them there (``jax.vjp``
+of the one-chunk forward, traced inside the kernel; the products through
+rules that keep their operands' types): ``dT = dU (beta V)^T + dW (beta K
+e^G)^T``, ``d(beta Akk) = -T^T dT T^T``, the scores' cotangents back to q, k
+and g level by level through the SAME two factors as the forward. The
+derivative of ``e^x`` is ``e^x``, so every exponent stays a difference <= 0
+and no reciprocal of a decay appears in the backward either. Off the TPU
+the gradient of ``chunk_operands`` is autodiff's of the ``jnp``.
 
 **A per-channel decay cannot be factored naively.** ``e^(G_t - G_i)`` as
 ``e^G_t x e^-G_i`` overflows float32 inside one chunk (the cumulative
@@ -67,13 +84,18 @@ log-decays that is <= 0: the pairs (t, i) of a chunk are split by halving
 falls in the later and i in the earlier half of one block) and a level's
 pairs are taken relative to the boundary between its halves, ``e^(G_t - r)
 x e^(r - G_i)``, both factors <= 1, as ONE matmul a level. Never a
-reciprocal of a decay.
+reciprocal of a decay. (The kernel does not even subtract two cumulative
+sums: ``G_t - r`` IS the sum of g from the boundary to t, so a level scans
+g inside its halves, a log-step rotate-and-add, and takes one exponential
+a position.)
 
 ``(I + A)^-1`` is exact in finitely many matmuls, A being strictly lower
 triangular: within diagonal blocks of 16 by the product form of the Neumann
 series, ``(I - D)(I + D^2)(I + D^4)(I + D^8)``, and across the four blocks
 by the same form of the block-nilpotent rest, in float32 (three bf16
-passes a matmul on the MXU).
+passes a matmul on the MXU; the kernel splits the operands into bf16 high
+and low parts itself, Mosaic knowing one pass or six, and takes all six
+where the caller's own type is float32).
 
 A prompt that is no whole number of chunks is padded at its tail with
 ``beta = 0``, ``g = 0``: the identity, the state does not move, and a pad
@@ -192,6 +214,21 @@ def _unit_lower_inverse(a):
     return mm(neumann(rest, -(-C // INVERSE_BLOCK)), inv_diag)
 
 
+def _whole_groups(T, chunk):
+    """The chunks that hold T positions: whole groups of ``CHUNKS_PER_STEP``
+    chunks (what a grid step walks: ``_per_step``), or the chunks of a T
+    shorter than one group."""
+    n = -(-T // chunk)
+    return -(-n // _per_step(n)) * _per_step(n)
+
+
+def _padded(t, to):
+    """(B, T, ...) with zeros after position T - 1, up to ``to`` positions:
+    ``beta`` = 0, ``g`` = 0 make identity chunks."""
+    return jnp.pad(t, ((0, 0), (0, to - t.shape[1]))
+                   + ((0, 0),) * (t.ndim - 2))
+
+
 def chunk_operands(q, k, v, g, beta, chunk=CHUNK):
     """What the state pass needs of every chunk, computed for all chunks at
     once. q, k, g (B, T, H, dk), v (B, T, H, dv), beta (B, T, H); T is
@@ -202,10 +239,8 @@ def chunk_operands(q, k, v, g, beta, chunk=CHUNK):
     in v's type, ``decay`` (B * H, T' / chunk, dk) float32."""
     B, T, H, dk = q.shape
     dtype, f32 = v.dtype, jnp.float32
-    n = -(-T // chunk)
-    n = -(-n // min(CHUNKS_PER_STEP, n)) * min(CHUNKS_PER_STEP, n)
-    pad = lambda t: jnp.pad(t.astype(f32), ((0, 0), (0, n * chunk - T))
-                            + ((0, 0),) * (t.ndim - 2))
+    n = _whole_groups(T, chunk)
+    pad = lambda t: _padded(t.astype(f32), n * chunk)
     # (B, H, n, chunk, .)
     cut = lambda t: jnp.moveaxis(pad(t), 2, 1).reshape(
         B, H, n, chunk, *t.shape[3:])
@@ -225,7 +260,339 @@ def chunk_operands(q, k, v, g, beta, chunk=CHUNK):
             "decay": jnp.exp(total[..., 0, :]).reshape(B * H, n, dk)}
 
 
+# ------------------------------------------- a chunk's operands as kernels
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rows_down(x, by):
+    """``x`` (rows, lanes) with row t holding row t - by (circular): a
+    sublane rotate in the kernel. Linear, so its transpose is the rotate the
+    other way (``pltpu.roll`` has no rule of its own)."""
+    return pltpu.roll(x, by, 0)
+
+
+_rows_down.defvjp(lambda x, by: (pltpu.roll(x, by, 0), None),
+                  lambda by, _, ct: (pltpu.roll(ct, ct.shape[0] - by, 0),))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _level_scores(q, k, early, dtype):
+    """A level's scores: ``[q; k] early^T`` as ONE matmul (the later rows of
+    q and of k against the earlier rows of k, each already times its decay),
+    operands rounded to ``dtype``, float32 sums -> (of q, of k), (C, C) each.
+    Its transposes take the cotangents rounded the same way (as
+    ``_chunk_backward`` does), never a mixed-type product."""
+    C = q.shape[0]
+    both = jax.lax.dot_general(
+        jnp.concatenate([q, k], axis=0).astype(dtype), early.astype(dtype),
+        _NT, preferred_element_type=jnp.float32)
+    return both[:C], both[C:]
+
+
+def _level_scores_bwd(dtype, res, cts):
+    q, k, early = res
+    C = q.shape[0]
+    dot = functools.partial(jax.lax.dot_general,
+                            preferred_element_type=jnp.float32)
+    ct = jnp.concatenate(cts, axis=0).astype(dtype)
+    rows = dot(ct, early.astype(dtype), _NN)
+    return rows[:C], rows[C:], dot(
+        ct, jnp.concatenate([q, k], axis=0).astype(dtype), _TN)
+
+
+_level_scores.defvjp(
+    lambda q, k, early, dtype: (_level_scores(q, k, early, dtype),
+                                (q, k, early)), _level_scores_bwd)
+
+
+def _three_passes(a, b, dims):
+    """A float32 product as three bf16 passes with float32 sums (what
+    ``Precision.HIGH`` is on the MXU, which Mosaic does not take by name):
+    each operand split into a bf16 high and low part, the low x low term
+    (2^-16 of the product) left out."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    dot = functools.partial(jax.lax.dot_general, dimension_numbers=dims,
+                            preferred_element_type=f32)
+    a_high, b_high = a.astype(bf16), b.astype(bf16)
+    a_low = (a - a_high.astype(f32)).astype(bf16)
+    b_low = (b - b_high.astype(f32)).astype(bf16)
+    return dot(a_high, b_high) + (dot(a_high, b_low) + dot(a_low, b_high))
+
+
+@jax.custom_vjp
+def _precise_bf16(a, b):
+    """``a b`` in float32 at three bf16 passes, forward and transposed."""
+    return _three_passes(a, b, _NN)
+
+
+_precise_bf16.defvjp(
+    lambda a, b: (_three_passes(a, b, _NN), (a, b)),
+    lambda res, ct: (_three_passes(ct, res[1], _NT),
+                     _three_passes(res[0], ct, _TN)))
+
+
+def _one_chunk_operands(q, k, v, g, beta_row, dtype):
+    """``chunk_operands`` for ONE chunk, in the operations a Mosaic kernel
+    has (and whose transposes it has: ``kda_operands_bwd`` is ``jax.vjp`` of
+    this, traced inside the kernel). q, k (C, dk), v (C, dv) in the caller's
+    type, g (C, dk) float32, ``beta_row`` (1, C) float32, C a power of two.
+    -> u (C, dv), w, qg, kend (C, dk), aqk (C, C) in ``dtype``, decay (1,
+    dk) float32. The same mathematics as the ``jnp`` form: every exponent a
+    difference of cumulative log-decays <= 0, by halving; the score matmuls
+    on operands rounded to ``dtype``; the inverse and ``T [beta K e^G, beta
+    V]`` in float32 at three bf16 passes (``_precise_bf16``: Mosaic refuses
+    ``HIGH``) or, for a float32 caller, at ``HIGHEST``.
+
+    No cumulative sum is taken across a level's boundary and subtracted:
+    ``G_t - r`` for a position in the later half of a block IS the sum of g
+    from the boundary to t, ``r - G_i`` in the earlier half minus the sum
+    from i + 1 to the boundary, so each level scans g inside its halves
+    (log-step rotate-and-add, one array: later halves look back, earlier
+    ones ahead) and takes ONE exponential a position."""
+    f32 = jnp.float32
+    C, dk = q.shape
+    assert C & (C - 1) == 0, C
+    q, k, v = (t.astype(f32) for t in (q, k, v))
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+    at = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    to = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = at == to
+    beta = jnp.sum(jnp.where(eye, beta_row, 0.0), axis=1, keepdims=True)
+
+    def scanned(half):
+        """Later halves of blocks of 2 x half rows: the sum of g from the
+        half's first row to this one; earlier halves: from this row to the
+        half's last."""
+        late, inside = (row & half) != 0, row & (half - 1)
+        x, by = g, 1
+        while by < half:
+            x = x + jnp.where(
+                late, jnp.where(inside >= by, _rows_down(x, by), 0.0),
+                jnp.where(inside < half - by, _rows_down(x, C - by), 0.0))
+            by *= 2
+        return late, x
+
+    aqk = jnp.where(eye, jnp.sum(q * k, axis=1, keepdims=True), 0.0)
+    akk = jnp.zeros((C, C), f32)
+    half = C // 2
+    while half >= 1:
+        late, x = scanned(half)
+        # later rows e^(G_t - r), earlier rows e^(r - G_i): both <= 1
+        decay = jnp.exp(jnp.where(late, x, x - g))
+        rows, cols = jnp.where(late, decay, 0.0), jnp.where(late, 0.0, decay)
+        same = (at & -(2 * half)) == (to & -(2 * half))
+        of_q, of_k = _level_scores(q * rows, k * rows, k * cols, dtype)
+        aqk = aqk + jnp.where(same, of_q, 0.0)
+        akk = akk + jnp.where(same, of_k, 0.0)
+        half //= 2
+    # the chunk's own cumulative log-decay, every row looking back, and what
+    # is left of the chunk's after a row, every row looking ahead (summed,
+    # not ``total - G``: two sums of ~-150 would cancel to 1e-5)
+    G, left, by = g, g, 1
+    while by < C:
+        G = G + jnp.where(row >= by, _rows_down(G, by), 0.0)
+        left = left + jnp.where(row < C - by, _rows_down(left, C - by), 0.0)
+        by *= 2
+    grown = jnp.exp(G)
+
+    # float32 products: three bf16 passes beside operands of 8 bits, all
+    # six (Mosaic's ``HIGHEST``) where the caller's own type is float32
+    mm = _precise_bf16 if jnp.dtype(dtype).itemsize < 4 else functools.partial(
+        jnp.dot, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=f32)
+    one = eye.astype(f32)
+    inner = min(INVERSE_BLOCK, C)
+    a = beta * akk
+    diag = jnp.where((at & -inner) == (to & -inner), a, 0.0)
+
+    def neumann(x, order):
+        inv, power = one - x, x
+        for _ in range(max(0, math.ceil(math.log2(order)) - 1)):
+            power = mm(power, power)
+            inv = mm(inv, one + power)
+        return inv
+
+    inv_diag = neumann(diag, inner)
+    rest = mm(inv_diag, a - diag)
+    t_inv = mm(neumann(rest, -(-C // INVERSE_BLOCK)), inv_diag)
+    return (mm(t_inv, beta * v).astype(dtype),
+            mm(t_inv, beta * k * grown).astype(dtype),
+            (q * grown).astype(dtype),
+            (k * jnp.exp(left - g)).astype(dtype), aqk.astype(dtype),
+            jnp.exp(jnp.sum(g, axis=0, keepdims=True)))
+
+
+# chunks of a grid step whose dependent chains of small matmuls the
+# scheduler may interleave: they share one loop body
+_TOGETHER = 2
+
+
+def _chunks_in_turn(chunks, chunk, one):
+    """``one(rows, at)`` for every chunk of a grid step, ``_TOGETHER`` a
+    loop body: ``rows`` the chunk's positions in the step's block, ``at``
+    its row in a (chunks, .) block."""
+    together = _TOGETHER if chunks % _TOGETHER == 0 else 1
+
+    def body(i, _):
+        for c in range(together):
+            c = i * together + c
+            one(pl.ds(pl.multiple_of(c * chunk, chunk), chunk), pl.ds(c, 1))
+
+    jax.lax.fori_loop(0, chunks // together, body, None)
+
+
+def _kda_operands_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, u_ref, w_ref,
+                         qg_ref, kend_ref, aqk_ref, decay_ref, *, chunk: int,
+                         chunks: int):
+    def one(rows, at):
+        *made, decay = _one_chunk_operands(
+            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
+            g_ref[0, rows, :], beta_ref[0, at, :], u_ref.dtype)
+        for ref, value in zip((u_ref, w_ref, qg_ref, kend_ref, aqk_ref),
+                              made):
+            ref[0, rows, :] = value
+        decay_ref[0, at, :] = decay
+
+    _chunks_in_turn(chunks, chunk, one)
+
+
+def _kda_operands_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, du_ref,
+                             dw_ref, dqg_ref, dkend_ref, daqk_ref, ddecay_ref,
+                             dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, *,
+                             chunk: int, chunks: int):
+    def one(rows, at):
+        # the chunk's intermediates are made again here, in VMEM
+        _, transposed = jax.vjp(
+            functools.partial(_one_chunk_operands, dtype=du_ref.dtype),
+            q_ref[0, rows, :], k_ref[0, rows, :], v_ref[0, rows, :],
+            g_ref[0, rows, :], beta_ref[0, at, :])
+        *grads, dbeta = transposed(
+            (du_ref[0, rows, :], dw_ref[0, rows, :], dqg_ref[0, rows, :],
+             dkend_ref[0, rows, :], daqk_ref[0, rows, :],
+             ddecay_ref[0, at, :]))
+        for ref, grad in zip((dq_ref, dk_ref, dv_ref, dg_ref), grads):
+            ref[0, rows, :] = grad
+        dbeta_ref[0, at, :] = dbeta
+
+    _chunks_in_turn(chunks, chunk, one)
+
+
+def _operands_call(kernel, name, q, v, chunk, times, moved):
+    """-> (``pallas_call`` bound to the operand kernels' grid, (batch x
+    head, groups of chunks), both parallel: nothing is carried; how the
+    caller's (B, T', H, width) arrays go in and come out: ``lay``, the
+    block spec and the shape by width, ``unlay``; the block specs of the
+    state pass's (B * H, T', width) and of a value a position or a chunk,
+    (B * H, chunks, width), a chunk a row). The caller's arrays are read
+    and written IN PLACE as (B, T', H x width), a head's lanes by block
+    index, where a head's lanes are whole tiles (no head moves ahead of
+    the positions through HBM); else as (B * H, T', width), moved by XLA.
+    The cost: a chunk-head is six levels of (2 chunk, dk) x (dk, chunk)
+    scores, ten chunk-sized float32 products of the inverse and ``T [beta
+    K e^G, beta V]`` at three or six bf16 passes, eight exponentials a
+    channel a position; ``times`` the forward's, ``moved`` bytes a position
+    a head."""
+    B, Tp, H, dk = q.shape
+    dv = v.shape[-1]
+    n = Tp // chunk
+    per = _per_step(n)
+    rows, passes = per * chunk, 3 if v.dtype.itemsize < 4 else 6
+    by_head = lambda width: pl.BlockSpec((1, rows, width),
+                                         lambda b, j: (b, j, 0))
+    a_chunk = lambda width: pl.BlockSpec((1, per, width),
+                                         lambda b, j: (b, j, 0))
+    if H == 1 or dk % 128 == dv % 128 == 0:
+        lay = lambda t: t.reshape(B, Tp, -1)
+        rows_of = lambda width: pl.BlockSpec(
+            (1, rows, width), lambda b, j: (b // H, j, b % H))
+        shape_of = lambda width: (B, Tp, H * width)
+        unlay = lambda t: t.reshape(B, Tp, H, -1)
+    else:
+        lay = lambda t: jnp.moveaxis(t, 2, 1).reshape(B * H, Tp, -1)
+        rows_of, shape_of = by_head, lambda width: (B * H, Tp, width)
+        unlay = lambda t: jnp.moveaxis(t.reshape(B, H, Tp, -1), 1, 2)
+    call = functools.partial(
+        pl.pallas_call, functools.partial(kernel, chunk=chunk, chunks=per),
+        grid=(B * H, n // per),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(times * B * H * n * 2 * chunk * chunk * (
+                12 * dk + passes * (10 * chunk + dk + dv))),
+            bytes_accessed=int(B * H * Tp * moved),
+            transcendentals=int(times * B * H * Tp * 8 * dk)),
+        name=name)
+    return call, (lay, rows_of, shape_of, unlay), by_head, a_chunk
+
+
+def _a_chunk_a_row(beta, chunk):
+    """(B, T', H) -> (B * H, T' / chunk, chunk)."""
+    return jnp.moveaxis(beta, 2, 1).reshape(-1, beta.shape[1] // chunk, chunk)
+
+
+def _operands_kernel(q, k, v, g, beta, chunk):
+    """``kda_operands_fwd``: ``chunk_operands`` of q, k, v (B, T', H, .) in
+    the caller's type, g and beta float32, T' ``_whole_groups``, as one
+    kernel; a chunk's intermediates never leave VMEM."""
+    B, Tp, H, dk = q.shape
+    dv, dtype = v.shape[-1], v.dtype
+    made = (dv + 3 * dk + chunk) * dtype.itemsize
+    call, (lay, rows_of, _, _), by_head, a_chunk = _operands_call(
+        _kda_operands_kernel, "kda_operands_fwd", q, v, chunk, 1,
+        q.dtype.itemsize * (2 * dk + dv) + 4 * dk + made)
+    like = lambda width: jax.ShapeDtypeStruct((B * H, Tp, width), dtype)
+    out = call(
+        in_specs=[rows_of(dk), rows_of(dk), rows_of(dv), rows_of(dk),
+                  a_chunk(chunk)],
+        out_specs=[by_head(dv), by_head(dk), by_head(dk), by_head(dk),
+                   by_head(chunk), a_chunk(dk)],
+        out_shape=[like(dv), like(dk), like(dk), like(dk), like(chunk),
+                   jax.ShapeDtypeStruct((B * H, Tp // chunk, dk),
+                                        jnp.float32)],
+    )(lay(q), lay(k), lay(v), lay(g), _a_chunk_a_row(beta, chunk))
+    return dict(zip((*_ROWS, "decay"), out))
+
+
+def _operands_bwd_kernel(q, k, v, g, beta, grads, chunk):
+    """``kda_operands_bwd``: the cotangents of q, k, v, g and beta from
+    those of the operands (as ``kda_chunk_bwd`` writes them), on the
+    forward's grid; it reads the forward's INPUTS and makes a chunk's
+    intermediates again in VMEM."""
+    B, Tp, H, dk = q.shape
+    dv = v.shape[-1]
+    made = (dv + 3 * dk + chunk) * grads["u"].dtype.itemsize
+    call, (lay, rows_of, shape_of, unlay), by_head, a_chunk = _operands_call(
+        _kda_operands_bwd_kernel, "kda_operands_bwd", q, v, chunk, 3,
+        2 * q.dtype.itemsize * (2 * dk + dv) + 8 * dk + made)
+    like = lambda t: jax.ShapeDtypeStruct(shape_of(t.shape[-1]), t.dtype)
+    *rows, dbeta = call(
+        in_specs=[rows_of(dk), rows_of(dk), rows_of(dv), rows_of(dk),
+                  a_chunk(chunk), by_head(dv), by_head(dk), by_head(dk),
+                  by_head(dk), by_head(chunk), a_chunk(dk)],
+        out_specs=[rows_of(dk), rows_of(dk), rows_of(dv), rows_of(dk),
+                   a_chunk(chunk)],
+        out_shape=[like(q), like(k), like(v), like(g),
+                   jax.ShapeDtypeStruct((B * H, Tp // chunk, chunk),
+                                        jnp.float32)],
+    )(lay(q), lay(k), lay(v), lay(g), _a_chunk_a_row(beta, chunk),
+      *(grads[name] for name in _ROWS), grads["decay"])
+    return (*(unlay(t) for t in rows),
+            jnp.moveaxis(dbeta.reshape(B, H, Tp), 1, 2))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def operands(q, k, v, g, beta, chunk):
+    """``_operands_kernel`` with a backward of its own, ``kda_operands_bwd``:
+    what the rule keeps is its five inputs."""
+    return _operands_kernel(q, k, v, g, beta, chunk)
+
+
+operands.defvjp(
+    lambda q, k, v, g, beta, chunk: (
+        _operands_kernel(q, k, v, g, beta, chunk), (q, k, v, g, beta)),
+    lambda chunk, res, grads: _operands_bwd_kernel(*res, grads, chunk))
+
+
 # ------------------------------------------------------------ the state pass
+_NN = (((1,), (0,)), ((), ()))      # a b
 _NT = (((1,), (1,)), ((), ()))      # a b^T
 _TN = (((0,), (0,)), ((), ()))      # a^T b
 
@@ -542,20 +909,28 @@ def chunked_kda(q, k, v, g, beta, state=None, chunk=CHUNK, kernel=False,
                 vjp=False):
     """The chunked form over T positions. Shapes as ``recurrent_kda``; ->
     (o (B, T, H, dv) in v's type, the state (B, H, dk, dv) float32 after
-    position T - 1). ``kernel``: the state pass as the Pallas kernels (a
-    program for a TPU, or the interpreter in a test) or as their ``jnp``
-    forms (everywhere else). ``vjp``: the state pass with its own backward
-    (``state_pass``: what a gradient is taken through; without it autodiff
-    walks the ``jnp`` scan and keeps every chunk's state). The chunks'
-    operands exist for all T positions at once, ~45 float32 values a channel
-    a position (3 GB at 4,096 positions of 64 heads x 128): a caller with a
-    long sequence walks it in segments and hands the state on
-    (``models/kda.py::mix``)."""
+    position T - 1). ``kernel``: the chunks' operands and the state pass as
+    the Pallas kernels (a program for a TPU, or the interpreter in a test)
+    or as their ``jnp`` forms (everywhere else). ``vjp``: both with their
+    own backward (``operands``, ``state_pass``: what a gradient is taken
+    through; without it autodiff walks the ``jnp`` scan and keeps every
+    chunk's state). The operands exist for all T positions at once: from
+    the kernel 3 dk + dv + chunk values a position a head in v's type
+    (1.2 KB at 128 in bf16); from the ``jnp`` form ~45 float32 values a
+    channel a position beside them (3 GB at 4,096 positions of 64 heads x
+    128). A caller with a long sequence walks it in segments and hands the
+    state on (``models/kda.py::mix``)."""
     B, T, H, dk = q.shape
     dv = v.shape[-1]
     state = jnp.zeros((B * H, dk, dv), jnp.float32) if state is None \
         else state.astype(jnp.float32).reshape(B * H, dk, dv)
-    ops = chunk_operands(q, k, v, g, beta, chunk)
+    if kernel:
+        to, f32 = _whole_groups(T, chunk) * chunk, jnp.float32
+        ops = (operands if vjp else _operands_kernel)(
+            _padded(q, to), _padded(k, to), _padded(v, to),
+            _padded(g.astype(f32), to), _padded(beta.astype(f32), to), chunk)
+    else:
+        ops = chunk_operands(q, k, v, g, beta, chunk)
     if vjp:
         o, state = state_pass(ops, state, chunk, kernel)
     else:

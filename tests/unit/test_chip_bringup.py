@@ -743,6 +743,40 @@ def test_trinity_step_moves_the_shares_rows_and_not_every_pair(trinity_record):
     assert trinity_record.memory()["total"] <= TRINITY_FULL_SIZE_STEP_BYTES
 
 
+# ----- kimi-linear-48b-a3b.train.z1.s16k: KDA's four kernels in a real step
+# what ``memory()`` of the step summed to with ``chunk_operands`` in XLA and
+# differentiated by autodiff (PR 41: temporaries 5,861.7 MB)
+KIMI_XLA_OPERANDS_STEP_BYTES = 14_646_600_000
+
+
+def test_kimi_step_makes_a_chunks_operands_in_kernels(v5e):
+    """The cell's real step of 1 x 16,384 tokens for one v5e chip, three
+    KDA layers a period behind a dense one: a KDA layer runs
+    ``kda_operands_fwd`` twice (the forward; the segment's re-run under
+    ``mix``'s checkpoint, which hands the state pass's backward its
+    operands) and ``kda_operands_bwd`` once, around ONE ``kda_chunk_fwd``
+    (its outputs and group states are kept) and one ``kda_chunk_bwd``, all
+    under ``kda/core``; nothing of the ``jnp`` ``chunk_operands`` is left;
+    and it needs less of the chip than it did."""
+    from deepspeed_tpu.telemetry.scopes import classify
+
+    record = _compiled_train_step(v5e, "kimi-linear-48b-a3b.json",
+                                  "train.z1.s16k.json", 1)[1]
+    text = record.compiled().as_text()
+    own = _own_instructions(text)
+    calls = collections.Counter(
+        re.sub(r"[.\d]+$", "", n) for n, _, line in own
+        if "tpu_custom_call" in line)
+    assert [calls[k] for k in ("kda_operands_fwd", "kda_chunk_fwd",
+                               "kda_chunk_bwd", "kda_operands_bwd")] \
+        == [8, 4, 4, 4], calls
+    assert not re.search(r"\[(?:1,)?32,32,(?:64|128),(?:64|128)\]", text)
+    table = record.instruction_scopes()
+    assert {classify(table[n], n)[0] for n, _, line in own
+            if n.startswith("kda_")} == {"kda/core"}
+    assert record.memory()["total"] < KIMI_XLA_OPERANDS_STEP_BYTES
+
+
 # ------------- the real steps under the program's own names (scopes, PR 35)
 @pytest.fixture(params=["xl_z3", "gas4"])
 def step_record(request):
@@ -1096,13 +1130,21 @@ def test_kda_chunk_kernel_compiles_for_v5e_uninterpreted(v5e):
         call, = re.findall(
             r"%[\w.]*kda_chunk_fwd[\w.]* = [^\n]*tpu_custom_call", text)
         assert "f32[64,128,128]" in call
+        # its operands are a kernel's too: q, k, v, g read as the caller's
+        # (1, T', heads x 128) rows, a head's lanes by block index
+        made, = re.findall(
+            r"%[\w.]*kda_operands_fwd[\w.]* = [^\n]*tpu_custom_call", text)
+        rows = -(-T // 512) * 512 if T > 512 else -(-T // 64) * 64
+        assert f"bf16[1,{rows},8192]" in text and f"f32[1,{rows},8192]" in text
+        assert made.count(f"bf16[64,{rows},128]") == 4       # u, w, qg, kend
 
 
 def test_kda_state_pass_compiles_for_v5e_forward_and_backward(v5e):
     """The state pass with its own backward at Kimi-Linear's 32 heads x 128
     and a segment of 2,048 positions: ``kda_chunk_fwd`` with the groups'
     end states as a third output and ``kda_chunk_bwd``, one Mosaic call
-    each, uninterpreted."""
+    each, uninterpreted, between ``kda_operands_fwd`` and
+    ``kda_operands_bwd``."""
     from deepspeed_tpu.ops.pallas import kda
 
     mesh = _mesh(v5e)
@@ -1121,6 +1163,40 @@ def test_kda_state_pass_compiles_for_v5e_forward_and_backward(v5e):
     bwd, = re.findall(r"%[\w.]*kda_chunk_bwd[\w.]* = [^\n]*custom-call", text)
     assert "f32[32,4,128,128]" in fwd           # a state a group of 8 chunks
     assert bwd.count("bf16[32,2048,128]") >= 4 and "f32[32,32,128]" in bwd
+    # the operands' own pair around them: one forward (nothing re-runs it
+    # here), and a backward that writes q, k, v and g's cotangents as the
+    # caller's rows and beta's a chunk a row
+    made, = re.findall(
+        r"%[\w.]*kda_operands_fwd[\w.]* = [^\n]*custom-call", text)
+    back, = re.findall(
+        r"%[\w.]*kda_operands_bwd[\w.]* = ([^\n]*?) custom-call\(", text)
+    assert back.count("bf16[1,2048,4096]") == 3 and "f32[1,2048,4096]" in back
+    assert "f32[32,32,64]" in back
+
+
+def test_kda_kernels_compile_for_v5e_at_heads_narrower_than_a_lane_tile(v5e):
+    """4 heads of 16 (the tests' tiny hybrid): a head's lanes are no whole
+    tile, so the operand kernels cannot read ``(B, T, H dk)`` rows by block
+    index; the heads go ahead of the positions through XLA and all four
+    kernels still compile, forward and backward."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    mesh = _mesh(v5e)
+    qk = _abstract((2, 1024, 4, 16), jnp.bfloat16, mesh)
+    g = _abstract((2, 1024, 4, 16), jnp.float32, mesh)
+    beta = _abstract((2, 1024, 4), jnp.float32, mesh)
+    state = _abstract((2, 4, 16, 16), jnp.float32, mesh)
+
+    def loss(*args):
+        o, s = kda.chunked_kda(*args, kernel=True, vjp=True)
+        return jnp.sum(o.astype(jnp.float32)) + jnp.sum(s)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        qk, qk, qk, g, beta, state).compile().as_text()
+    for kernel in ("kda_operands_fwd", "kda_chunk_fwd", "kda_chunk_bwd",
+                   "kda_operands_bwd"):
+        assert len(re.findall(
+            rf"%[\w.]*{kernel}[\w.]* = [^\n]*custom-call", text)) == 1, kernel
 
 
 @pytest.fixture(scope="module")
@@ -1185,7 +1261,8 @@ def test_solar_programs_hand_each_other_a_token(solar):
 
 
 def test_solar_prefill_for_v5e_at_the_longest_prompt(solar):
-    """32,768 tokens: ``kda_chunk_fwd`` in the segment loop, ``flash_fwd`` at
+    """32,768 tokens: ``kda_operands_fwd`` and ``kda_chunk_fwd`` in the segment
+    loop, ``flash_fwd`` at
     head_dim 128, the full-tile grouped matmuls over the stacked share; no
     (T, T) array and no per-chunk pairwise (64, 64, 128) array anywhere;
     it fits beside the 6.6 GB of weights."""
@@ -1195,10 +1272,17 @@ def test_solar_prefill_for_v5e_at_the_longest_prompt(solar):
             params, _abstract((1, 32768), jnp.int32, mesh),
             _abstract((2,), jnp.uint32, mesh)).compile()
     text = compiled.as_text()
-    for kernel in ("kda_chunk_fwd", "moe_gmm_swiglu_full", "moe_gmm_full",
-                   "flash_fwd"):
+    for kernel in ("kda_chunk_fwd", "kda_operands_fwd", "moe_gmm_swiglu_full",
+                   "moe_gmm_full", "flash_fwd"):
         assert re.search(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text), \
             kernel
+    # a chunk's operands come from a kernel wherever the state pass runs,
+    # and nothing of the ``jnp`` ``chunk_operands`` is left: no (heads,
+    # chunks, 64 | 128, 64 | 128) array of level-split scores or decays
+    calls = lambda kernel: len(re.findall(
+        rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call", text))
+    assert calls("kda_operands_fwd") == calls("kda_chunk_fwd") > 0
+    assert not re.search(r"\[(?:1,)?64,32,(?:64|128),(?:64|128)\]", text)
     assert not re.search(r"\[[\d,]*32768,32768\]", text)
     assert not re.search(r"\[[\d,]*64,64,128\]", text)
     assert not _moves(text, {"bf16[40,4096,1280]", "bf16[40,1280,4096]",

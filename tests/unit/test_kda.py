@@ -189,6 +189,150 @@ def test_both_kernels_are_their_jnp_forms(interpreted, T):
             1.0, float(jnp.abs(w).max()))
 
 
+# the operand kernels against the ``jnp`` ``chunk_operands``. float32: what
+# is left is the order of the sums (the kernel scans g inside a level's
+# halves where the ``jnp`` subtracts cumulative sums), measured 1e-8 - 8e-6
+# on operands of size 0.2 - 12. bf16: both round the same products, whose
+# float32 values differ in the last place, so a rounding can fall the other
+# way: one bf16 place (2^-8) of the operand's size
+OPERAND_LENGTHS = {"a whole group": 512, "ragged": 64 * 5 + 17,
+                   "shorter than a group": 23}
+OPERAND_CASES = {
+    **{f"{name}, {H} head{'s'[:H - 1]}": dict(T=T, H=H)
+       for name, T in OPERAND_LENGTHS.items() for H in (1, 2)},
+    # cumulative log-decay under -100 inside a chunk (the case of
+    # ``test_a_naive_reciprocal_overflows_where_the_differences_do_not``)
+    "the fast-decay head": dict(T=192, H=2, a=16.0, dt=0.1),
+    # a head's lanes are whole tiles, as at the published 128: q, k, v, g
+    # are read and their cotangents written IN PLACE, a head by block index
+    # (narrower heads go ahead of the positions first)
+    "heads of 128 lanes": dict(T=64 * 2 + 5, H=2, dk=128)}
+
+
+def _to_groups(args, dtype):
+    """``draw``'s inputs as ``chunked_kda(kernel=True)`` hands them to the
+    operand kernels: q, k, v in ``dtype``, padded to whole groups."""
+    q, k, v, g, beta = args
+    to = kda._whole_groups(q.shape[1], kda.CHUNK) * kda.CHUNK
+    return tuple(kda._padded(t, to) for t in (
+        q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("case", OPERAND_CASES, ids=OPERAND_CASES.keys())
+def test_the_operand_kernel_is_its_jnp_form(interpreted, case, dtype):
+    """``kda_operands_fwd`` (interpret mode) against ``chunk_operands``:
+    every operand, in the layout the state pass reads."""
+    args = draw(4, **OPERAND_CASES[case])
+    if "fast" in case:
+        assert float(jnp.cumsum(args[3][:, :64], axis=1).min()) < -100
+    padded = _to_groups(args, dtype)
+    want = jax.jit(kda.chunk_operands)(*padded)
+    got = jax.jit(functools.partial(kda._operands_kernel, chunk=kda.CHUNK))(
+        *padded)
+    assert set(got) == set(want)
+    for name, ref in want.items():
+        assert got[name].shape == ref.shape and got[name].dtype == ref.dtype
+        assert bool(jnp.isfinite(got[name]).all()), name
+        size = max(1.0, float(jnp.abs(ref).max()))
+        limit = TOL if ref.dtype == jnp.float32 else 2.0 ** -8
+        assert float(jnp.abs(got[name].astype(jnp.float32)
+                             - ref.astype(jnp.float32)).max()) \
+            <= limit * size, name
+
+
+def _operand_cotangents(ops, seed, only=None):
+    r = np.random.default_rng(seed)
+    return {name: jnp.asarray(r.standard_normal(t.shape), jnp.float32)
+            * (only in (None, name)) for name, t in ops.items()}
+
+
+@pytest.mark.parametrize("dtype,only", [
+    (jnp.float32, None), (jnp.float32, "decay"), (jnp.bfloat16, None)],
+    ids=["float32", "float32, decay alone", "bf16"])
+@pytest.mark.parametrize("case", ["ragged, 2 heads", "the fast-decay head",
+                                  "heads of 128 lanes"])
+def test_the_operand_backward_kernel_is_autodiff_of_the_jnp_form(
+        interpreted, case, dtype, only):
+    """``kda_operands_bwd`` (interpret mode) against ``jax.vjp`` of
+    ``chunk_operands`` in float32, for q, k, v, g and beta; with a cotangent
+    on the chunk's total decay ALONE, which only g hears (a rule that
+    dropped it would pass every other check: PR 41's control
+    ``decay_grad_dropped``); and from bf16 operands and cotangents (the
+    inverse at three bf16 passes, the transposed scores on rounded
+    cotangents), where the ``jnp`` form's own autodiff in bf16 is 0.4% of a
+    gradient's size from the float32 one, and so is the kernel: measured
+    0.2 - 0.5%."""
+    padded = _to_groups(draw(5, **OPERAND_CASES[case]), dtype)
+    shapes = jax.eval_shape(kda.chunk_operands, *padded)
+    cts = {name: ct.astype(shapes[name].dtype) for name, ct
+           in _operand_cotangents(shapes, 6, only).items()}
+    f32 = lambda tree: jax.tree.map(lambda t: t.astype(jnp.float32), tree)
+    want = jax.jit(lambda *at: jax.vjp(kda.chunk_operands, *at)[1](f32(cts)))(
+        *f32(padded))
+    got = jax.jit(functools.partial(kda._operands_bwd_kernel,
+                                    chunk=kda.CHUNK))(*padded, cts)
+    limit = VJP_TOL if dtype == jnp.float32 else 2.0 ** -6
+    for name, at, g, w in zip("q k v g beta".split(), padded, got, want):
+        assert g.shape == w.shape and g.dtype == at.dtype
+        assert float(jnp.abs(g.astype(jnp.float32) - w).max()) < limit * max(
+            1.0, float(jnp.abs(w).max())), name
+    if only == "decay":
+        # (the fast-decay head's total decay is e^-150: nothing to hear)
+        assert "fast" in case or float(jnp.abs(got[3]).max()) > 1e-3
+        assert all(float(jnp.abs(got[i]).max()) == 0 for i in (0, 1, 2, 4))
+
+
+@pytest.mark.parametrize("case", ["ragged", "strong decay", "groups",
+                                  "128 lanes"])
+def test_both_kernel_pairs_give_the_gradient_of_the_recurrence(
+        interpreted, case):
+    """``jax.grad`` through ``chunked_kda(kernel=True, vjp=True)`` — the
+    operands' rule around the state pass's, all four kernels in interpret
+    mode — against ``jax.grad`` of ``recurrent_kda``, float32: q, k, v, g,
+    beta and the state the pass starts from."""
+    kw = {"ragged": dict(T=64 * 3 + 12, H=2), "groups": dict(T=64 * 8 + 5, H=1),
+          "128 lanes": dict(T=64 + 7, H=2, dk=128),
+          "strong decay": dict(T=128, H=2, a=16.0, dt=0.1)}[case]
+    args, state, weights = _vjp_case(7, **kw)
+    want = jax.jit(jax.grad(functools.partial(_scalar, kda.recurrent_kda),
+                            argnums=(0, 1)))(args, state, weights)
+    got = jax.jit(jax.grad(functools.partial(_scalar, functools.partial(
+        kda.chunked_kda, kernel=True, vjp=True)), argnums=(0, 1)))(
+            args, state, weights)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.isfinite(g).all())
+        assert float(jnp.abs(g - w).max()) < VJP_TOL * max(
+            1.0, float(jnp.abs(w).max()))
+
+
+def test_a_padded_tail_hears_nothing(interpreted):
+    """Positions past the prompt's end (``beta`` = 0, ``g`` = 0, zero rows)
+    get cotangents that are exactly zero from both backward kernels, g's
+    apart: a pad position's g would move the chunk's total decay, and the
+    pad's own transpose cuts it."""
+    args, state, weights = _vjp_case(8, T=64 * 2 + 9, H=2)
+    T = args[0].shape[1]
+    padded = _to_groups(args, jnp.float32)
+    assert padded[0].shape[1] == 64 * 3
+
+    def loss(*padded):
+        BH = state.shape[0] * state.shape[1]
+        o, s = kda.state_pass(kda.operands(*padded, kda.CHUNK),
+                              state.reshape(BH, *state.shape[2:]),
+                              kda.CHUNK, True)
+        o = jnp.moveaxis(o[:, :T].reshape(*state.shape[:2], T, -1), 1, 2)
+        return jnp.sum(o * weights[0]) + jnp.sum(s.reshape(state.shape)
+                                                 * weights[1])
+
+    grads = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*padded)
+    for name, g in zip("q k v g beta".split(), grads):
+        assert float(jnp.abs(g[:, :T]).max()) > 1e-3, name
+        if name != "g":
+            assert float(jnp.abs(g[:, T:]).max()) == 0, name
+
+
 def test_the_forward_rule_names_what_remat_attn_keeps():
     """``remat_wrap('attn')`` keeps the state pass's outputs and its group
     states by name: under it the backward holds no second forward pass."""
@@ -205,6 +349,21 @@ def test_the_forward_rule_names_what_remat_attn_keeps():
     again = jax.jit(jax.grad(wrapped))(args, state, weights)
     for g, w in zip(jax.tree.leaves(again), jax.tree.leaves(plain)):
         assert float(jnp.abs(g - w).max()) < 1e-5
+    # the operands' rule keeps its five INPUTS and nothing it made ...
+    padded = _to_groups(args, jnp.float32)
+    from jax._src.ad_checkpoint import saved_residuals
+
+    kept = saved_residuals(lambda *a: kda.operands(*a, kda.CHUNK), *padded)
+    assert len(kept) == 5 and all("argument" in why for _, why in kept), kept
+    # ... so a segment under ``mix``'s checkpoint runs the operands' forward
+    # again for the state pass's backward, and no second ``kda_chunk_fwd``
+    kernels = functools.partial(
+        _scalar, functools.partial(kda.chunked_kda, kernel=True, vjp=True))
+    text = str(jax.make_jaxpr(jax.grad(common.remat_wrap(kernels, "attn")))(
+        args, state, weights))
+    assert [text.count(f"name={name}\n") + text.count(f"name={name} ")
+            for name in ("kda_operands_fwd", "kda_chunk_fwd", "kda_chunk_bwd",
+                         "kda_operands_bwd")] == [2, 1, 1, 1]
 
 
 def test_one_step_is_the_recurrence():
@@ -226,7 +385,15 @@ def test_unit_lower_inverse_is_exact():
 
 
 def test_the_kernel_never_interprets_itself():
+    """Off the TPU the kernels fail to lower: none runs in the interpreter
+    unless a test asks (the ``interpreted`` fixture)."""
     assert "interpret=" not in inspect.getsource(kda)
+    padded = _to_groups(draw(1, T=64, H=1), jnp.float32)
+    with pytest.raises(Exception, match="[Ii]nterpret|TPU|tpu"):
+        kda._operands_kernel(*padded, kda.CHUNK)
+    with pytest.raises(Exception, match="[Ii]nterpret|TPU|tpu"):
+        kda._operands_bwd_kernel(*padded, _operand_cotangents(
+            jax.eval_shape(kda.chunk_operands, *padded), 0), kda.CHUNK)
 
 
 # ------------------------------------------------------------- the hybrid
@@ -318,9 +485,9 @@ def test_a_long_prompt_is_walked_in_segments(monkeypatch):
 
 
 def test_the_kernel_path_matches_the_jnp_form(as_tpu_program):
-    """A program "for a TPU" takes ``kda_chunk_fwd`` in prefill (run by the
-    interpreter here), and the trunk under ``loss`` takes it with
-    ``kda_chunk_bwd``."""
+    """A program "for a TPU" takes ``kda_operands_fwd`` and ``kda_chunk_fwd``
+    in prefill (run by the interpreter here), and the trunk under ``loss``
+    takes them with ``kda_chunk_bwd`` and ``kda_operands_bwd``."""
     model, params = hybrid(use_flash_attention=False)
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 512, (1, 70)),
                       jnp.int32)
@@ -332,13 +499,15 @@ def test_the_kernel_path_matches_the_jnp_form(as_tpu_program):
         common._kernel_target = real
     text = str(jax.make_jaxpr(model.prefill)(params, ids,
                                              model.init_cache(1, 80)))
-    assert "kda_chunk_fwd" in text
+    assert "kda_chunk_fwd" in text and "kda_operands_fwd" in text
     got, _ = model.prefill(params, ids, model.init_cache(1, 80))
     assert float(jnp.abs(got - want).max()) < 2e-5
     # the trunk under ``loss`` takes both kernels, through the state pass's
     # own backward, and its gradient is the jnp forms'
     text = str(jax.make_jaxpr(jax.grad(model.loss))(params, ids))
-    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    for kernel in ("kda_operands_fwd", "kda_chunk_fwd", "kda_chunk_bwd",
+                   "kda_operands_bwd"):
+        assert kernel in text, kernel
     got = jax.jit(jax.grad(model.loss))(params, ids)
     common._kernel_target = lambda: (None, False)
     try:

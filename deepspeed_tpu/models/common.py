@@ -549,6 +549,33 @@ def kda_attention(q, k, v, g, beta, state, differentiable: bool = False):
         (rows, rows, rows, rows, P(batch, None, heads), held), (rows, held))
 
 
+def kda_qkv(p, tail, conv_w, heads: int, eps: float,
+            differentiable: bool = False):
+    """q, k, v of a KDA mixer from its q | k | v projection's output ``p``
+    (B, T, 3 H dk), the convolution's window ``tail`` (B, taps - 1, 3 H dk)
+    and taps ``conv_w`` (taps, 3 H dk) float32: convolution, SiLU, unit q
+    (x dk^-1/2) and k, ``ops/pallas/kda.py::prepare_qkv`` -> (q, k, v (B, T,
+    H, dk) in p's type, the next call's ``tail``). The path is chosen as
+    ``kda_attention`` chooses: ONE kernel (``kda_prep_fwd``, which writes
+    the rows ``kda_operands_fwd`` reads in place; ``differentiable``: with
+    ``kda_prep_bwd`` as its backward, which keeps the three inputs and
+    nothing float32) where the program is for a TPU, there is more than one
+    position and a head's lanes are whole tiles; otherwise the ``jnp``
+    form, which is also what the kernels are tested against: a decode
+    step, the CPU, narrow heads."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    mesh, on_tpu = _kernel_target()
+    if not on_tpu or p.shape[1] == 1 or p.shape[-1] // (3 * heads) % 128:
+        return kda.prepare_qkv(p, tail, conv_w, heads, eps)
+    batch, _ = _attn_axes(mesh, p.shape[0], heads)
+    rows, made = P(batch, None, None), P(batch, None, None, None)
+    kernel = kda.prepare if differentiable else kda._prep_kernel
+    return _kernel_on_mesh(
+        lambda *a: kernel(*a, heads, eps), mesh, (p, tail, conv_w),
+        (rows, rows, P(None, None)), (made, made, made, rows))
+
+
 def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,
                      alibi=None, flash_block=None, window=None):
     """The full causal-attention dispatch shared by the model families:

@@ -119,11 +119,13 @@ def mix(c, h, blk, tail, state, differentiable=False):
     -> (the gated, normed heads (B, T, H dv) for ``o_w``, the new tail, the
     new state). A sequence longer than ``SEGMENT`` positions is walked a
     segment at a time (``lax.scan``, tail and state handed on, then what is
-    left over): the mixer's temporaries — the projection, convolution and
-    gates in float32 and the chunked form's operands (74 KB a position at 64
-    heads x 128 from ``kda_operands_fwd``; ~1 MB through the ``jnp`` form
-    off the TPU) — are a segment's and not the sequence's, for one more
-    read of its weights a segment. ``differentiable`` (the trunk under
+    left over): the mixer's temporaries — the projection, the gates in
+    float32 (q, k, v come from ``kda_prep_fwd`` in the caller's type; off
+    the TPU the convolution's float32 passes too) and the chunked form's
+    operands (74 KB a position at 64 heads x 128 from
+    ``kda_operands_fwd``; ~1 MB through the ``jnp`` form off the TPU) —
+    are a segment's and not the sequence's, for one more read of its
+    weights a segment. ``differentiable`` (the trunk under
     ``loss``): the operands and the state pass take their own backward
     (``common.kda_attention``) and each segment is a ``jax.checkpoint`` that
     keeps what remat ``'attn'`` keeps of a block, so the backward holds a
@@ -159,9 +161,10 @@ def mix(c, h, blk, tail, state, differentiable=False):
 def _mix_segment(c, h, blk, tail, state, differentiable=False):
     """``mix`` over positions that are handled at once. One position
     (decode) runs the recurrence itself; more run the chunked form
-    (``common.kda_attention``: the kernels in a program for a TPU, each
-    with its own backward where ``differentiable``)."""
-    from deepspeed_tpu.models.common import kda_attention
+    (``common.kda_attention``) behind ``common.kda_qkv``: the kernels in a
+    program for a TPU, each with its own backward where
+    ``differentiable``."""
+    from deepspeed_tpu.models.common import kda_attention, kda_qkv
     from deepspeed_tpu.ops.pallas.kda import kda_step
     from deepspeed_tpu.telemetry.scopes import scope
 
@@ -171,20 +174,9 @@ def _mix_segment(c, h, blk, tail, state, differentiable=False):
     hd = h.astype(c.dtype)
     low = lambda a, b: (hd @ blk[a].astype(c.dtype)) @ blk[b].astype(c.dtype)
     with scope("kda/qkv"):
-        window = jnp.concatenate(
-            [tail.astype(c.dtype), hd @ blk["kda_qkv_w"].astype(c.dtype)],
-            axis=1)
-        conv_w = blk["kda_conv_w"].astype(f32)
-        qkv = jax.nn.silu(sum(conv_w[j] * window[:, j:j + T].astype(f32)
-                              for j in range(c.kda_conv))).astype(c.dtype)
-        q, k, v = (t.reshape(B, T, H, dk) for t in jnp.split(qkv, 3, axis=-1))
-
-        def unit(t, scale=1.0):
-            t = t.astype(f32)
-            return (t * (scale * jax.lax.rsqrt(jnp.sum(
-                t * t, axis=-1, keepdims=True) + L2_EPS))).astype(c.dtype)
-
-        q, k = unit(q, dk ** -0.5), unit(k)
+        q, k, v, tail = kda_qkv(
+            hd @ blk["kda_qkv_w"].astype(c.dtype), tail.astype(c.dtype),
+            blk["kda_conv_w"].astype(f32), H, L2_EPS, differentiable)
         g = -jnp.exp(blk["kda_a_log"].astype(f32))[:, None] * jax.nn.softplus(
             low("kda_f_a_w", "kda_f_b_w").astype(f32).reshape(B, T, H, dk)
             + blk["kda_dt_bias"].astype(f32).reshape(H, dk))
@@ -203,5 +195,4 @@ def _mix_segment(c, h, blk, tail, state, differentiable=False):
                               + c.rms_norm_eps) \
             * blk["kda_o_norm_g"].astype(f32)
         gate = jax.nn.sigmoid(low("kda_g_a_w", "kda_g_b_w").astype(f32))
-        return (o.reshape(B, T, H * dk) * gate).astype(c.dtype), \
-            window[:, T:], state
+        return (o.reshape(B, T, H * dk) * gate).astype(c.dtype), tail, state

@@ -1,7 +1,8 @@
 """Kimi Delta Attention (KDA): the gated delta rule with a PER-CHANNEL decay,
 as a recurrence (decode), as its chunked form (prefill, training) and as the
 Pallas TPU kernels of that form: one that makes every chunk's operands, one
-that carries the state from chunk to chunk, and a backward for each.
+that carries the state from chunk to chunk, one that makes q, k, v from the
+mixer's projection, and a backward for each.
 
 One head holds a state ``S`` (dk, dv), float32. A position ``t`` brings a
 query and key (dk,), L2-normalised by the caller, a value (dv,), a log-decay
@@ -100,6 +101,21 @@ where the caller's own type is float32).
 A prompt that is no whole number of chunks is padded at its tail with
 ``beta = 0``, ``g = 0``: the identity, the state does not move, and a pad
 position's cotangents are zero.
+
+**What comes before the chunks** (``prepare_qkv``, the definition in
+``jnp``; ``prepare``, the same as a kernel pair under a ``custom_vjp``):
+q, k, v from the mixer's q | k | v projection, a causal depthwise
+convolution over the last ``taps`` positions, SiLU, q and k L2-normalised a
+head. ``kda_prep_fwd`` holds a block of rows x whole heads of each third of
+the projection's output in VMEM, float32, and writes q, k, v as the ``(B,
+T, H dk)`` rows ``kda_operands_fwd`` reads in place; ``kda_prep_bwd`` walks
+the row blocks last to first, makes the pre-activation, SiLU and norms
+again and writes the projection's cotangent in one piece, the tail's, and
+the taps' as partial sums. Their residuals are the rule's three inputs.
+Both are bound by the VPU, so what they spare it decides: the window is
+staged a 128-lane column at a time, where a column's rows are contiguous
+and the convolution's look back is an unaligned load (no rotate, no
+select), and the logistic function is one ``tanh``.
 """
 
 from __future__ import annotations
@@ -938,3 +954,407 @@ def chunked_kda(q, k, v, g, beta, state=None, chunk=CHUNK, kernel=False,
             ops, state, chunk)
     return jnp.moveaxis(o[:, :T].reshape(B, H, T, dv), 1, 2), \
         state.reshape(B, H, dk, dv)
+
+
+# ----------------------------------- q | k | v from the projection's output
+# positions and channels (whole heads) a grid step of the preparation holds,
+# positions a loop body works on (a head at a time), rows kept of what came
+# before a block (one float32 tile; a convolution looks back taps - 1 <= 8)
+PREP_ROWS, PREP_LANES, _PREP_CHUNK, _HALO = 256, 512, 32, 8
+
+
+def prepare_qkv(p, tail, conv_w, heads, eps):
+    """q, k, v of the mixer from the q | k | v projection's output ``p`` (B,
+    T, 3 H dk), the ``taps - 1`` pre-activation rows before it ``tail`` (B,
+    taps - 1, 3 H dk) and the taps ``conv_w`` (taps, 3 H dk): the causal
+    depthwise convolution (the last tap on the current position), SiLU, then
+    q and k L2-normalised a head and q times ``dk ** -0.5``; float32 inside.
+    -> (q, k, v (B, T, H, dk) in p's type, the window's last ``taps - 1``
+    rows: the next call's ``tail``). The definition, in ``jnp``: what runs
+    off the TPU and what ``kda_prep_fwd`` / ``kda_prep_bwd`` are tested
+    against."""
+    B, T, _ = p.shape
+    f32 = jnp.float32
+    window = jnp.concatenate([tail.astype(p.dtype), p], axis=1)
+    conv_w = conv_w.astype(f32)
+    qkv = jax.nn.silu(sum(conv_w[j] * window[:, j:j + T].astype(f32)
+                          for j in range(conv_w.shape[0]))).astype(p.dtype)
+    q, k, v = (t.reshape(B, T, heads, -1) for t in jnp.split(qkv, 3, axis=-1))
+
+    def unit(t, scale=1.0):
+        t = t.astype(f32)
+        return (t * (scale * jax.lax.rsqrt(jnp.sum(
+            t * t, axis=-1, keepdims=True) + eps))).astype(p.dtype)
+
+    return unit(q, q.shape[-1] ** -0.5), unit(k), v, window[:, T:]
+
+
+_LANES = 128      # a vreg's lanes: the staging buffers hold columns of them
+
+
+def _window_into(buf, x_ref, before_ref, tail_ref, first):
+    """``buf`` (columns of 128 lanes, 8 + rows, 128) float32 <- the 8 rows
+    before a block of the projection's output, then the block: the rows are
+    the segment's ``tail`` (its last rows) before the ``first`` block, else
+    the block before's. A column's rows are contiguous in VMEM, so a strip
+    of it is a LOAD from any row: the convolution looks back without a
+    rotate."""
+    f32 = jnp.float32
+    columns = [(n, pl.ds(n * _LANES, _LANES)) for n in range(buf.shape[0])]
+
+    @pl.when(first)
+    def _of_the_tail():
+        buf[:, :_HALO, :] = tail_ref[0]
+
+    @pl.when(jnp.logical_not(first))
+    def _of_the_block_before():
+        for n, lanes in columns:
+            buf[n, :_HALO, :] = before_ref[0, :, lanes].astype(f32)[-_HALO:]
+
+    for n, lanes in columns:
+        buf[n, _HALO:, :] = x_ref[0, :, lanes].astype(f32)
+
+
+def _looked(buf, at, rows, taps, back):
+    """Every column of ``buf``, ``rows`` rows from ``at``, looked back (or
+    ahead: ``back`` -1) by 0 .. taps - 1 rows: (columns, rows, 128) each."""
+    return tuple(buf[:, pl.ds(at - back * s, rows), :] for s in range(taps))
+
+
+def _under_the_taps(looked, w):
+    """``sum_s w[taps - 1 - s] looked[s]``: the convolution (``looked``
+    back: the last tap on the strip itself) or its transpose (ahead); ``w``
+    (columns, taps, 128)."""
+    taps = len(looked)
+    out = w[:, taps - 1:] * looked[0]
+    for s in range(1, taps):
+        out = out + w[:, taps - 1 - s:taps - s] * looked[s]
+    return out
+
+
+def _sigmoid(x):
+    """The logistic function through ONE transcendental (the quotient ``1 /
+    (1 + e^-x)`` costs the VPU a dozen operations a value around its
+    reciprocal)."""
+    return 0.5 * jnp.tanh(0.5 * x) + 0.5
+
+
+def _head_sum(x):
+    """``x`` (heads, columns a head, rows, 128): the sum over a head's
+    channels, (heads, 1, rows, 1)."""
+    return jnp.sum(jnp.sum(x, axis=-1, keepdims=True), axis=1, keepdims=True)
+
+
+# A strip's arithmetic, values in and out, under ``jax.jit(inline=True)``:
+# no program of its own (it is inlined into the kernel's body and never
+# dispatched: the ``sharding/unspecified-jit`` rule is about programs), a
+# trace cache: traced ONCE a variant in a process and replayed into every
+# kernel body after it. A kernel's trace is Python time that a program pays in set-up
+# whatever its compile cache holds (~3 s a program on the chip's host in
+# the first form, which walked a head at a time: PERF.md, PR 43).
+@functools.partial(jax.jit, static_argnames=("wide", "scale", "eps"),
+                   inline=True)
+def _strip_forward(back, w, wide, scale, eps):
+    """A strip of every column of a block, (columns, rows, 128): SiLU of
+    the convolution, L2 normalised over a head (``wide`` columns) and times
+    ``scale`` where that is not None."""
+    pre = _under_the_taps(back, w)
+    a = pre * _sigmoid(pre)
+    if scale is None:
+        return a
+    a = a.reshape(-1, wide, *a.shape[1:])
+    return (a * (scale * jax.lax.rsqrt(_head_sum(a * a) + eps))).reshape(
+        pre.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("wide", "scale", "eps"),
+                   inline=True)
+def _strip_backward(back, w, ct, wide, scale, eps):
+    """The transpose of ``_strip_forward`` up to the pre-activation: ``ct``
+    the strip's cotangent, float32 -> (the pre-activation's cotangent, the
+    taps' as eight partial sums of the rows a tap (columns, 8, 128))."""
+    pre = _under_the_taps(back, w)
+    sig = _sigmoid(pre)
+    if scale is not None:
+        a = (pre * sig).reshape(-1, wide, *pre.shape[1:])
+        r = jax.lax.rsqrt(_head_sum(a * a) + eps)
+        unit, ct = a * r, ct.reshape(a.shape)
+        ct = ((scale * r) * (ct - unit * _head_sum(ct * unit))).reshape(
+            pre.shape)
+    dpre = ct * (sig * (1.0 + pre * (1.0 - sig)))
+    taps, rows = len(back), pre.shape[1]
+    partial = []
+    for tap in range(taps):
+        by = dpre * back[taps - 1 - tap]
+        partial.append(sum(by[:, r:r + _HALO] for r in range(
+            _HALO, rows, _HALO)) + by[:, :_HALO])
+    return dpre, tuple(partial)
+
+
+@functools.partial(jax.jit, inline=True)
+def _transposed_taps(ahead, w):
+    """``_under_the_taps`` of a strip looked AHEAD: the convolution's
+    transpose, traced once."""
+    return _under_the_taps(ahead, w)
+
+
+def _kda_prep_kernel(*refs, chunk: int, dk: int, eps: float):
+    """A block of rows x a block of whole heads of each third of the
+    projection's output -> q, k, v there, a strip of ``chunk`` rows of
+    every column at a time."""
+    xs, befores, tails, ws, outs, bufs = (refs[3 * n:3 * n + 3]
+                                          for n in range(6))
+    rows, lanes = xs[0].shape[1:]
+    taps = ws[0].shape[1]
+
+    for n in range(3):
+        _window_into(bufs[n], xs[n], befores[n], tails[n],
+                     pl.program_id(2) == 0)
+
+    def strips(c, _):
+        at = pl.multiple_of(c * chunk, chunk)
+        for n, scale in enumerate((dk ** -0.5, 1.0, None)):
+            made = _strip_forward(
+                _looked(bufs[n], at + _HALO, chunk, taps, 1), ws[n][...],
+                dk // _LANES, scale, eps).astype(outs[n].dtype)
+            for col in range(lanes // _LANES):
+                outs[n][0, pl.ds(at, chunk), pl.ds(col * _LANES, _LANES)] \
+                    = made[col]
+
+    jax.lax.fori_loop(0, rows // chunk, strips, None)
+
+
+def _kda_prep_bwd_kernel(x_ref, before_ref, tail_ref, w_ref, dq_ref, dk_ref,
+                         dv_ref, dp_ref, dtail_ref, dw_ref, buf, after, *,
+                         chunk: int, dk: int, eps: float, per_third: int):
+    """One block of rows x whole heads of the projection's output, the row
+    blocks LAST TO FIRST: the pre-activation, SiLU and norms again in VMEM,
+    then their transposes. ``after`` (columns, chunk + 8, 128) holds the
+    pre-activation's cotangent of a strip and behind it of the first rows
+    of the strip (and block) that follows, which the convolution's
+    transpose looks ahead to; ``dw_ref`` (columns, 8 taps, 128) gathers
+    the taps' cotangent over the row blocks, eight partial sums a tap."""
+    f32 = jnp.float32
+    j, i = pl.program_id(1), pl.program_id(2)
+    first = i == pl.num_programs(2) - 1         # the segment's first rows
+    rows, lanes = x_ref.shape[1:]
+    taps = w_ref.shape[1]
+    columns = [pl.ds(col * _LANES, _LANES) for col in range(lanes // _LANES)]
+    _window_into(buf, x_ref, before_ref, tail_ref, first)
+
+    @pl.when(i == 0)
+    def _start():
+        after[...] = jnp.zeros_like(after)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def ahead(dpre, last=chunk):
+        """The projection's cotangent over the ``last`` rows of a strip;
+        the strip's ``dpre`` goes ahead of what ``after`` held of the strip
+        that follows."""
+        after[:, chunk:, :] = after[:, :_HALO, :]
+        after[:, :chunk, :] = dpre
+        return _transposed_taps(
+            _looked(after, chunk - last, last, taps, -1), w_ref[...])
+
+    def third(ct_ref, scale):
+        def strips(c, _):
+            at = pl.multiple_of((rows // chunk - 1 - c) * chunk, chunk)
+            dpre, partial = _strip_backward(
+                _looked(buf, at + _HALO, chunk, taps, 1), w_ref[...],
+                jnp.stack([ct_ref[0, pl.ds(at, chunk), cols].astype(f32)
+                           for cols in columns]),
+                dk // _LANES, scale, eps)
+            for tap, by in enumerate(partial):
+                dw_ref[0, :, pl.ds(_HALO * tap, _HALO), :] += by
+            dx = ahead(dpre).astype(dp_ref.dtype)
+            for col, cols in enumerate(columns):
+                dp_ref[0, pl.ds(at, chunk), cols] = dx[col]
+
+        jax.lax.fori_loop(0, rows // chunk, strips, None)
+
+    for n, (ct_ref, scale) in enumerate(
+            ((dq_ref, dk ** -0.5), (dk_ref, 1.0), (dv_ref, None))):
+        pl.when(j // per_third == n)(functools.partial(third, ct_ref, scale))
+
+    @pl.when(first)
+    def _the_tails_share():
+        # the tail's rows have no output of their own: what the segment's
+        # first rows hand back
+        dtail_ref[0] = ahead(jnp.zeros((len(columns), chunk, _LANES), f32),
+                             _HALO)
+
+
+def _prep_shapes(p, heads):
+    """-> (a head's size, the rows and lanes of a block, T padded to whole
+    blocks of rows)."""
+    B, T, ch = p.shape
+    dk = ch // (3 * heads)
+    rows = min(PREP_ROWS, -(-T // _PREP_CHUNK) * _PREP_CHUNK)
+    lanes = dk * max(n for n in range(1, max(1, PREP_LANES // dk) + 1)
+                     if heads % n == 0)
+    return dk, rows, lanes, -(-T // rows) * rows
+
+
+def _by_column(t):
+    """(.., n, channels) -> (.., channels / 128, n, 128): what a kernel
+    holds a block of as (columns, n, 128)."""
+    *lead, n, ch = t.shape
+    return jnp.moveaxis(t.reshape(*lead, n, ch // _LANES, _LANES), -2, -3)
+
+
+def _by_channel(t):
+    """``_by_column`` back."""
+    *lead, columns, n, _ = t.shape
+    return jnp.moveaxis(t, -3, -2).reshape(*lead, n, columns * _LANES)
+
+
+def _tail_rows(tail):
+    """(B, taps - 1, ch) -> (B, ch / 128, 8, 128) float32, the tail its
+    LAST rows."""
+    return _by_column(jnp.pad(tail.astype(jnp.float32), (
+        (0, 0), (_HALO - tail.shape[1], 0), (0, 0))))
+
+
+def _new_tail(p, tail):
+    """The last rows of ``tail`` then ``p``, as many as ``tail`` has."""
+    T, keep = p.shape[1], tail.shape[1]
+    return p[:, T - keep:] if T >= keep else jnp.concatenate(
+        [tail[:, T:], p], axis=1)
+
+
+def _prep_kernel(p, tail, conv_w, heads, eps):
+    """``kda_prep_fwd``: ``prepare_qkv`` as one kernel. The grid is (batch,
+    blocks of whole heads, blocks of rows), all parallel; a step reads its
+    block of each third of ``p`` IN PLACE (three block specs over the one
+    array) and the 16 rows before it (no window of T + taps - 1 rows is
+    written), and writes q, k, v as ``(B, T, H dk)`` rows, what
+    ``kda_operands_fwd`` reads in place. -> what ``prepare_qkv`` gives."""
+    return _prep_call(p, tail, conv_w, heads, eps, *_prep_shapes(p, heads))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "heads", "eps", "dk", "rows", "lanes", "Tp"))
+def _prep_call(p, tail, conv_w, heads, eps, dk, rows, lanes, Tp):
+    # jitted with ``inline``: one trace of the kernel's body a shape a
+    # process (Solar's five prefill programs hold the same segment)
+    B, T, ch = p.shape
+    per, cols, taps, f32 = ch // 3 // lanes, lanes // _LANES, \
+        conv_w.shape[0], jnp.float32
+    assert taps - 1 <= _HALO and rows % 16 == 0, (conv_w.shape, rows)
+    # a block of each third of the channels: the same rows, ``per`` blocks
+    # of lanes apart
+    thirds = lambda block, where: [
+        pl.BlockSpec(block, lambda b, j, i, n=n: where(b, i, n * per + j))
+        for n in range(3)]
+    padded = _padded(p, Tp)
+    q, k, v = pl.pallas_call(
+        functools.partial(_kda_prep_kernel, chunk=_PREP_CHUNK, dk=dk, eps=eps),
+        grid=(B, per, Tp // rows),
+        in_specs=thirds((1, rows, lanes), lambda b, i, c: (b, i, c))
+        + thirds((1, 16, lanes), lambda b, i, c: (
+            b, jnp.maximum(i * (rows // 16) - 1, 0), c))
+        + thirds((1, cols, _HALO, _LANES), lambda b, i, c: (b, c, 0, 0))
+        + thirds((cols, taps, _LANES), lambda b, i, c: (c, 0, 0)),
+        out_specs=[pl.BlockSpec((1, rows, lanes), lambda b, j, i: (b, i, j))
+                   ] * 3,
+        out_shape=[jax.ShapeDtypeStruct((B, Tp, ch // 3), p.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((cols, _HALO + rows, _LANES), f32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(B * Tp * ch * 16), transcendentals=int(B * Tp * ch),
+            bytes_accessed=int(B * Tp * ch * 2 * p.dtype.itemsize)),
+        name="kda_prep_fwd",
+    )(*[padded] * 6, *[_tail_rows(tail)] * 3,
+      *[_by_column(conv_w.astype(f32))] * 3)
+    return (*(t[:, :T].reshape(B, T, heads, dk) for t in (q, k, v)),
+            _new_tail(p, tail))
+
+
+def _prep_bwd_kernel(p, tail, conv_w, cts, heads, eps):
+    """``kda_prep_bwd``: the cotangents of ``p`` (in its type), ``tail`` and
+    ``conv_w`` (float32) from those of q, k, v (B, T, H, dk). The grid is
+    (batch, blocks of whole heads over ALL 3 H dk channels, blocks of rows
+    last to first), the last axis sequential: a step is of one third, reads
+    that third's cotangent (the two others' block specs stay where they
+    are, so nothing of them moves) and writes d``p`` in place as (B, T, 3 H
+    dk); the taps' cotangent comes out as eight partial sums a tap a batch
+    row, which the caller adds."""
+    return _prep_bwd_call(p, tail, conv_w, tuple(cts), heads, eps,
+                          *_prep_shapes(p, heads))
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "heads", "eps", "dk", "rows", "lanes", "Tp"))
+def _prep_bwd_call(p, tail, conv_w, cts, heads, eps, dk, rows, lanes, Tp):
+    B, T, ch = p.shape
+    per, steps, taps, cols, f32 = ch // 3 // lanes, Tp // rows, \
+        conv_w.shape[0], lanes // _LANES, jnp.float32
+    back = lambda i: steps - 1 - i
+    padded = _padded(p, Tp)
+
+    def of_third(n):
+        mine = lambda j: j // per == n
+        return pl.BlockSpec((1, rows, lanes), lambda b, j, i: (
+            b, jnp.where(mine(j), back(i), 0), jnp.where(mine(j), j % per, 0)))
+
+    by_column = lambda n: pl.BlockSpec((1, cols, n, _LANES),
+                                       lambda b, j, i: (b, j, 0, 0))
+    dp, dtail, dw = pl.pallas_call(
+        functools.partial(_kda_prep_bwd_kernel, chunk=_PREP_CHUNK, dk=dk,
+                          eps=eps, per_third=per),
+        grid=(B, 3 * per, steps),
+        in_specs=[
+            pl.BlockSpec((1, rows, lanes), lambda b, j, i: (b, back(i), j)),
+            pl.BlockSpec((1, 16, lanes), lambda b, j, i: (
+                b, jnp.maximum(back(i) * (rows // 16) - 1, 0), j)),
+            by_column(_HALO),
+            pl.BlockSpec((cols, taps, _LANES), lambda b, j, i: (j, 0, 0)),
+            of_third(0), of_third(1), of_third(2)],
+        out_specs=[
+            pl.BlockSpec((1, rows, lanes), lambda b, j, i: (b, back(i), j)),
+            by_column(_HALO), by_column(_HALO * taps)],
+        out_shape=[jax.ShapeDtypeStruct((B, Tp, ch), p.dtype),
+                   jax.ShapeDtypeStruct((B, ch // _LANES, _HALO, _LANES), f32),
+                   jax.ShapeDtypeStruct(
+                       (B, ch // _LANES, _HALO * taps, _LANES), f32)],
+        scratch_shapes=[
+            pltpu.VMEM((cols, _HALO + rows, _LANES), f32),
+            pltpu.VMEM((cols, _PREP_CHUNK + _HALO, _LANES), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(B * Tp * ch * 48), transcendentals=int(B * Tp * ch),
+            bytes_accessed=int(B * Tp * ch * 3 * p.dtype.itemsize)),
+        name="kda_prep_bwd",
+    )(padded, padded, _tail_rows(tail), _by_column(conv_w.astype(f32)),
+      *(_padded(t.reshape(B, T, -1), Tp) for t in cts))
+    dw = dw.reshape(B, ch // _LANES, taps, _HALO, _LANES).sum((0, 3))
+    return (dp[:, :T],
+            _by_channel(dtail)[:, _HALO - tail.shape[1]:].astype(tail.dtype),
+            _by_channel(dw).astype(conv_w.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def prepare(p, tail, conv_w, heads, eps):
+    """``prepare_qkv`` through ``_prep_kernel``, with a backward of its
+    own, ``kda_prep_bwd``: what the rule keeps is its three inputs."""
+    return _prep_kernel(p, tail, conv_w, heads, eps)
+
+
+def _prepare_bwd(heads, eps, res, cts):
+    p, tail, conv_w = res
+    *cts, dnew = cts
+    dp, dtail, dw = _prep_bwd_kernel(p, tail, conv_w, cts, heads, eps)
+    # the new tail's cotangent goes to the rows it was cut from, in place
+    T, keep = p.shape[1], tail.shape[1]
+    if T >= keep:
+        return dp.at[:, T - keep:].add(dnew.astype(dp.dtype)), dtail, dw
+    return (dp + dnew[:, keep - T:].astype(dp.dtype),
+            dtail.at[:, T:].add(dnew[:, :keep - T].astype(dtail.dtype)), dw)
+
+
+prepare.defvjp(
+    lambda p, tail, conv_w, heads, eps: (
+        prepare(p, tail, conv_w, heads, eps), (p, tail, conv_w)),
+    _prepare_bwd)

@@ -743,10 +743,12 @@ def test_trinity_step_moves_the_shares_rows_and_not_every_pair(trinity_record):
     assert trinity_record.memory()["total"] <= TRINITY_FULL_SIZE_STEP_BYTES
 
 
-# ----- kimi-linear-48b-a3b.train.z1.s16k: KDA's four kernels in a real step
+# ----- kimi-linear-48b-a3b.train.z1.s16k: KDA's six kernels in a real step
 # what ``memory()`` of the step summed to with ``chunk_operands`` in XLA and
-# differentiated by autodiff (PR 41: temporaries 5,861.7 MB)
+# differentiated by autodiff (PR 41: temporaries 5,861.7 MB), and with the
+# q | k | v preparation in XLA (PR 42: temporaries 5,278.5 MB)
 KIMI_XLA_OPERANDS_STEP_BYTES = 14_646_600_000
+KIMI_XLA_PREPARATION_STEP_BYTES = 13_974_200_000
 
 
 def test_kimi_step_makes_a_chunks_operands_in_kernels(v5e):
@@ -756,8 +758,13 @@ def test_kimi_step_makes_a_chunks_operands_in_kernels(v5e):
     ``mix``'s checkpoint, which hands the state pass's backward its
     operands) and ``kda_operands_bwd`` once, around ONE ``kda_chunk_fwd``
     (its outputs and group states are kept) and one ``kda_chunk_bwd``, all
-    under ``kda/core``; nothing of the ``jnp`` ``chunk_operands`` is left;
-    and it needs less of the chip than it did."""
+    under ``kda/core``; before them ``kda_prep_fwd`` twice and
+    ``kda_prep_bwd`` once under ``kda/qkv`` (the block's re-run under remat
+    'attn' needs the projection's last rows for the tail, not q, k, v: a
+    kernel call that nothing reads is dropped, where XLA's fusions had run
+    a third time); nothing of the ``jnp`` ``chunk_operands`` is left, no
+    float32 array the size of the projection's output and no norm factor
+    broadcast a channel; and it needs less of the chip than it did."""
     from deepspeed_tpu.telemetry.scopes import classify
 
     record = _compiled_train_step(v5e, "kimi-linear-48b-a3b.json",
@@ -768,13 +775,21 @@ def test_kimi_step_makes_a_chunks_operands_in_kernels(v5e):
         re.sub(r"[.\d]+$", "", n) for n, _, line in own
         if "tpu_custom_call" in line)
     assert [calls[k] for k in ("kda_operands_fwd", "kda_chunk_fwd",
-                               "kda_chunk_bwd", "kda_operands_bwd")] \
-        == [8, 4, 4, 4], calls
+                               "kda_chunk_bwd", "kda_operands_bwd",
+                               "kda_prep_fwd", "kda_prep_bwd")] \
+        == [8, 4, 4, 4, 8, 4], calls
     assert not re.search(r"\[(?:1,)?32,32,(?:64|128),(?:64|128)\]", text)
+    assert not re.search(r"f32\[(?:\d+,)*20(?:48|51),12288\]", text)
     table = record.instruction_scopes()
-    assert {classify(table[n], n)[0] for n, _, line in own
-            if n.startswith("kda_")} == {"kda/core"}
-    assert record.memory()["total"] < KIMI_XLA_OPERANDS_STEP_BYTES
+    scopes_of = lambda prefix: {classify(table[n], n)[0] for n, _, line in own
+                                if n.startswith(prefix)}
+    assert scopes_of("kda_prep") == {"kda/qkv"}
+    assert scopes_of("kda_operands") == scopes_of("kda_chunk") == {"kda/core"}
+    assert not [n for n, line in re.findall(
+        r"%([\w.\-]+) = (f32\[2048,32,128\]\S* broadcast\()", text)
+        if classify(table.get(n, ""), n)[0] == "kda/qkv"]
+    assert record.memory()["total"] < KIMI_XLA_PREPARATION_STEP_BYTES \
+        < KIMI_XLA_OPERANDS_STEP_BYTES
 
 
 # ------------- the real steps under the program's own names (scopes, PR 35)
@@ -1172,6 +1187,38 @@ def test_kda_state_pass_compiles_for_v5e_forward_and_backward(v5e):
         r"%[\w.]*kda_operands_bwd[\w.]* = ([^\n]*?) custom-call\(", text)
     assert back.count("bf16[1,2048,4096]") == 3 and "f32[1,2048,4096]" in back
     assert "f32[32,32,64]" in back
+
+
+@pytest.mark.parametrize("heads", [32, 64])
+def test_kda_prep_kernels_compile_for_v5e_uninterpreted(v5e, heads):
+    """The q | k | v preparation at Kimi-Linear's 32 and Solar's 64 heads x
+    128 over a segment of 2,048 positions, forward and backward: one Mosaic
+    call each, uninterpreted; the forward writes q, k, v as the (1, T, H
+    dk) rows ``kda_operands_fwd`` reads, the backward the projection's
+    cotangent in ONE piece beside the tail's and the taps' float32
+    partials, a 128-lane column at a time."""
+    from deepspeed_tpu.ops.pallas import kda
+
+    mesh = _mesh(v5e)
+    ch = 3 * heads * 128
+    p = _abstract((1, 2048, ch), jnp.bfloat16, mesh)
+    tail = _abstract((1, 3, ch), jnp.bfloat16, mesh)
+    taps = _abstract((4, ch), jnp.float32, mesh)
+
+    def loss(*args):
+        made = kda.prepare(*args, heads, 1e-6)
+        return sum(jnp.sum(t.astype(jnp.float32)) for t in made), made
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
+        p, tail, taps).compile().as_text()
+    fwd, = re.findall(r"%[\w.]*kda_prep_fwd[\w.]* = ([^\n]*?) custom-call\(",
+                      text)
+    bwd, = re.findall(r"%[\w.]*kda_prep_bwd[\w.]* = ([^\n]*?) custom-call\(",
+                      text)
+    assert fwd.count(f"bf16[1,2048,{ch // 3}]") == 3
+    assert f"bf16[1,2048,{ch}]" in bwd and f"f32[1,{ch // 128},8,128]" in bwd \
+        and f"f32[1,{ch // 128},32,128]" in bwd
+    assert not re.search(rf"f32\[(?:\d+,)*20(?:48|51),{ch}\]", text)
 
 
 def test_kda_kernels_compile_for_v5e_at_heads_narrower_than_a_lane_tile(v5e):

@@ -403,6 +403,21 @@ def _args_segment(line: str, open_pos: int) -> str:
     return line[open_pos + 1:]
 
 
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
+
+
+def _typed_operands(args: str, shape_of: Dict[str, str]) -> str:
+    """``args`` with every operand's shape in front of its name. Older
+    printers write ``dot(f32[32,64]{1,0} %a, ...)``; jax 0.9's writes
+    ``dot(%a, %b)`` and leaves the shapes to the operands' own lines, so
+    a dot's contraction, a reduce's input and every operand's bytes come
+    from ``shape_of`` (name -> the shape its defining line printed)."""
+    if _SHAPE_RE.search(args):
+        return args
+    return _OPERAND_NAME_RE.sub(
+        lambda m: f"{shape_of.get(m.group(1), '')} %{m.group(1)}", args)
+
+
 def _instr_cost(opcode: str, shape_text: str, args: str, attrs: str):
     """(flops, transcendentals, bytes, result_bytes) of one instruction
     under the HloCostAnalysis conventions (module docstring). Fusions
@@ -496,6 +511,7 @@ def parse_hlo_module(text: str) -> HloModel:
     comp_order: List[str] = []
     # per computation: [(ComputeOp, calls_target_or_None), ...]
     comp_records: Dict[str, list] = {}
+    shape_of: Dict[str, str] = {}      # instruction name -> its result shape
     for line in lines[1:]:
         im = _INSTR_RE.match(line)
         if im is None:
@@ -507,10 +523,12 @@ def parse_hlo_module(text: str) -> HloModel:
                     comp_records[current_comp] = []
             continue
         name, shape_text, opcode = im.group(1), im.group(2), im.group(3)
+        shape_of[name] = shape_text
 
         # ---- compute region (roofline) --------------------------------
         args = _args_segment(line, im.end() - 1)
         attrs = line[im.end() - 1 + len(args) + 2:]
+        args = _typed_operands(args, shape_of)
         flops, trans, nbytes, rbytes = _instr_cost(
             opcode, shape_text, args, attrs)
         calls = None

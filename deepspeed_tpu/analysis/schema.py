@@ -55,7 +55,7 @@ def _block_models() -> Dict[str, type]:
         "telemetry": C.TelemetryConfig, "analysis": C.AnalysisConfig,
         "profiling": C.ProfilingConfig, "perf": C.PerfConfig,
         "serving": C.ServingConfig, "goodput": C.GoodputConfig,
-        "overlap": C.OverlapConfig, "wire": C.WireConfig,
+        "overlap": C.OverlapConfig,
         "roofline": C.RooflineConfig, "blackbox": C.BlackboxConfig,
         "compression_training": CompressionConfig,
     }
@@ -218,14 +218,6 @@ def _cross_field(cfg, pd: dict, findings: List[Finding]) -> None:
                 "serving.default_deadline_s vs serving.decode_tick_timeout_s")
     ov = cfg.overlap
     if "overlap" in pd and ov.enabled:
-        if stage < 3 and ov.param_prefetch > 0:
-            add("warning",
-                f"overlap.param_prefetch={ov.param_prefetch} with ZeRO stage "
-                f"{stage}: params are only dp-sharded at stage 3, so there "
-                "is no per-layer gather to prefetch — the layer scan stays "
-                "unrestructured (set zero_optimization.stage: 3, or "
-                "param_prefetch: 0 to silence this)",
-                "overlap.param_prefetch vs zero_optimization.stage")
         if ov.schedule == "serial" and not (tel.enabled and tel.trace):
             add("warning",
                 "overlap.schedule='serial' is the MEASURED un-overlapped "
@@ -237,78 +229,11 @@ def _cross_field(cfg, pd: dict, findings: List[Finding]) -> None:
                 "overlap.schedule vs telemetry.trace")
         if zc.offload_param is not None:
             add("warning",
-                "overlap with zero_optimization.offload_param: the step "
-                "restructuring is disabled for host-offloaded params "
-                "(their stream-in IS the gather); scheduler flags and the "
-                "async checkpoint snapshot still apply",
+                "overlap with zero_optimization.offload_param: the serial "
+                "schedule is off for host-offloaded params (their "
+                "stream-in IS the gather); scheduler flags and the async "
+                "checkpoint snapshot still apply",
                 "overlap vs zero_optimization.offload_param")
-        if ov.param_prefetch > 2:
-            add("info",
-                f"overlap.param_prefetch={ov.param_prefetch}: each "
-                "prefetched layer keeps one more gathered slice resident; "
-                "past 1-2 layers ahead the scheduler rarely finds more "
-                "latency to hide and the engine clamps the depth below the "
-                "model's layer count — validate the trade with the ds_prof "
-                "memory census",
-                "overlap.param_prefetch")
-    wire = cfg.wire
-    if "wire" in pd and wire.enabled:
-        if (wire.weight_quant_bits > 0 or wire.secondary_partition) \
-                and stage < 3:
-            add("warning",
-                f"wire with ZeRO stage {stage}: the qwZ quantized weight "
-                "all-gather and the hpZ secondary partition rewrite the "
-                "per-layer ZeRO-3 param gathers — below stage 3 params are "
-                "not dp-sharded, there is no gather to shrink, and the "
-                "wire block changes nothing (set zero_optimization.stage: "
-                "3, or drop the block)",
-                "wire vs zero_optimization.stage")
-        if (wire.weight_quant_bits > 0 or wire.secondary_partition) \
-                and stage >= 3 and "overlap" not in pd:
-            add("warning",
-                "wire without the overlap block: the quantized gather is a "
-                "drop-in for the overlap engine's prefetched layer scan — "
-                "without `overlap` the scan is never restructured and "
-                "qwZ/hpZ are inactive (add \"overlap\": {})",
-                "wire vs overlap")
-        if wire.grad_quant_bits > 0 and onebit:
-            add("error",
-                f"wire.grad_quant_bits={wire.grad_quant_bits} with the "
-                f"1-bit optimizer {cfg.optimizer_name!r}: both want to own "
-                "the gradient exchange (the 1-bit family already "
-                "compresses its momentum sync) — engine init will refuse; "
-                "drop one",
-                "wire.grad_quant_bits vs optimizer.type")
-        if wire.grad_quant_bits > 0 and stage >= 1 and not onebit:
-            add("info",
-                f"wire.grad_quant_bits={wire.grad_quant_bits} at ZeRO "
-                f"stage {stage}: the qgZ shard-mapped grad sync applies at "
-                "stage 0 on a pure-DP mesh (GSPMD owns the stage>=1 grad "
-                "reduce and resolves the cotangent's pending sum at full "
-                "width on this jax) — the knob is inert here, logged at "
-                "engine init",
-                "wire.grad_quant_bits vs zero_optimization.stage")
-        if wire.secondary_partition and wire.weight_quant_bits == 0:
-            add("warning",
-                "wire.secondary_partition with weight_quant_bits: 0 — the "
-                "hpZ secondary replica rides the quantized gather plan, so "
-                "with qwZ off it is never built and every gather stays "
-                "full width; set weight_quant_bits to 8 (or 4), or drop "
-                "secondary_partition",
-                "wire.secondary_partition vs wire.weight_quant_bits")
-        if wire.secondary_partition and cfg.mesh_config.ici <= 1 \
-                and wire.secondary_size <= 1:
-            # INFO, not an error: on a single-host (simulated) mesh the
-            # auto-factored host split is synthetic — correct for drills
-            # and static-comm accounting, just not a real DCN boundary
-            add("info",
-                "wire.secondary_partition on a mesh with no explicit "
-                "intra-host factoring (tpu.ici / wire.secondary_size "
-                "unset): engine init auto-factors the data axis — on a "
-                "single-host simulated mesh the host split is synthetic "
-                "(fine for drills and the static_comm_bytes accounting; "
-                "the wall-clock win shows on multi-host fleets)",
-                "wire.secondary_partition vs tpu.ici")
     roof = cfg.roofline
     if "roofline" in pd and roof.enabled:
         chip = (roof.chip or "").strip()

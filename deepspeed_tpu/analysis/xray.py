@@ -357,14 +357,13 @@ def _op_intra_host(op, device_order, host_groups) -> bool:
 
 
 def comm_by_kind_hostaware(xr: "ProgramXray") -> Dict[str, int]:
-    """Per-kind wire bytes with the host split the wire rewrites are judged
-    on: on a mesh that encodes host structure (the ``ici`` sub-axis, or a
-    real multi-process run — :func:`~deepspeed_tpu.sharding.mesh.
-    host_device_groups`), collectives confined to one host group land
-    under ``<kind>/intra`` while everything crossing hosts keeps the plain
-    kind — so "all-gather + reduce-scatter" reads as INTER-host wire bytes
-    (what hpZ removes), and meshes without host structure keep the flat
-    accounting byte-compatible with pre-wire ledgers."""
+    """Per-kind wire bytes split by host: on a mesh that encodes host
+    structure (the ``ici`` sub-axis, or a real multi-process run —
+    :func:`~deepspeed_tpu.sharding.mesh.host_device_groups`), collectives
+    confined to one host group land under ``<kind>/intra`` while
+    everything crossing hosts keeps the plain kind — so "all-gather +
+    reduce-scatter" reads as INTER-host wire bytes, and meshes without
+    host structure keep the flat accounting."""
     from deepspeed_tpu.analysis.hlo_model import collective_wire_bytes
     from deepspeed_tpu.sharding.mesh import host_device_groups
 
@@ -389,7 +388,7 @@ def comm_by_kind_hostaware(xr: "ProgramXray") -> Dict[str, int]:
 def inter_host_bytes(by_kind: Dict[str, int],
                      kinds=("all-gather", "reduce-scatter")) -> int:
     """Sum of the named kinds' INTER-host wire bytes (the ``/intra``
-    entries excluded) — the acceptance number of the wire rewrites."""
+    entries excluded)."""
     return sum(v for k, v in by_kind.items() if k in kinds)
 
 

@@ -162,8 +162,8 @@ def _group_desc(group) -> str:
 def record_engine_collective(op: str, shape, dtype, axes) -> None:
     """Register an ENGINE-ISSUED collective with the ds_doctor recorder
     (analysis/collectives.py record mode): GSPMD-inserted collectives —
-    the overlap engine's per-layer ZeRO-3 gathers and its serial gather
-    phase — never pass through the eager ``dist.*`` wrappers, so they
+    the layer stack's ZeRO-3 gathers (zero/partition.py::LayerGathers) —
+    never pass through the eager ``dist.*`` wrappers, so they
     would be invisible to the cross-rank sequence fingerprint without
     this hook. Called at TRACE time from the step builder; one `is None`
     check when no recorder is installed."""

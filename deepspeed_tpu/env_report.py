@@ -314,84 +314,8 @@ def mesh_section(args):
     return 0
 
 
-def wire_section(args):
-    """``ds_report wire --config ds_config.json [--model family]
-    [--devices N]`` — the ds_wire view of a config: which collective
-    rewrites (qwZ/hpZ/qgZ) are armed at what bits, and the per-program
-    static comm table from the sharded_jit program table with the
-    intra-/inter-host split the rewrites are judged on."""
-    import json
-
-    config_path = model = None
-    devices = 0
-    it = iter(args)
-    for a in it:
-        if a == "--config":
-            config_path = next(it, None)
-        elif a == "--model":
-            model = next(it, None)
-        elif a == "--devices":
-            devices = int(next(it, "0"))
-        elif a in ("-h", "--help"):
-            print("usage: ds_report wire --config ds_config.json "
-                  "[--model gpt2|llama|moe|bert] [--devices N]")
-            return 0
-    if config_path is None:
-        print("ds_report wire: --config is required (the wire block lives "
-              "in the ds_config)", file=sys.stderr)
-        return 2
-    if devices > 1:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count="
-                f"{devices}").strip()
-    with open(config_path) as f:
-        pd = json.load(f)
-    line = "-" * 72
-    print(line)
-    wire = pd.get("wire") or {}
-    armed = bool(wire) and wire.get("enabled", True)
-    wb = int(wire.get("weight_quant_bits", 8)) if armed else 0
-    gb = int(wire.get("grad_quant_bits", 0)) if armed else 0
-    sec = armed and bool(wire.get("secondary_partition", False))
-    print("wire (wire-speed ZeRO collectives):")
-    if not armed:
-        print("  no armed `wire` block: full-width collectives "
-              "(strict no-op)")
-    else:
-        gs = wire.get("group_size", 64)
-        print(f"  weight all-gather   "
-              + (f"qwZ int{wb} codes + f32/{gs} scales" if wb else
-                 "full width"))
-        print(f"  backward regather   "
-              + ("hpZ secondary intra-host partition" if sec else
-                 ("quantized replay" if wb else "full width")))
-        print(f"  grad exchange       "
-              + (f"qgZ int{gb} hierarchical (stage-0 shard-mapped step)"
-                 if gb else "full width"))
-    from deepspeed_tpu.analysis.xray import xray_for_config
-
-    result = xray_for_config(pd, model or "gpt2")
-    print(line)
-    print("per-program static comm (ring model; '/intra' = confined to one "
-          "host group):")
-    for x in sorted(result.xrays, key=lambda x: x.label):
-        c = result.comm.get(x.label, {})
-        print(f"  {x.label}  [{x.record.mesh_axes}]  "
-              f"collectives={c.get('collectives', 0)}  "
-              f"total={c.get('total_bytes', 0) / 2**20:.2f} MiB/dev/step")
-        for kind, b in sorted((c.get("by_kind") or {}).items()):
-            print(f"      {kind:<24} {b / 2**20:9.2f} MiB")
-    return 0
-
-
 def main(args=None):
     args = list(sys.argv[1:] if args is None else args)
-    if args and args[0] == "wire":
-        # `ds_report wire --config X` — the ds_wire mode/bits view + the
-        # per-program intra/inter static comm table
-        return wire_section(args[1:])
     if args and args[0] == "mesh":
         # `ds_report mesh` — the unified mesh + per-program spec table
         return mesh_section(args[1:])

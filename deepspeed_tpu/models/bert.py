@@ -278,8 +278,8 @@ class BertModel:
                 x = carry + gate * (x - carry)
             return x, None
 
-        # overridable layer scan (overlap engine's ZeRO-3 gather prefetch;
-        # a plain lax.scan when nothing is installed)
+        # the blocks' walk: a lax.scan (models/common.py::layer_scan); under
+        # ZeRO-3 each block gathers its own weights inside remat_wrap
         x, _ = layer_scan(scan_body, x, (params["blocks"], keep_p, pld_rngs),
                           unroll=c.scan_unroll)
         return x

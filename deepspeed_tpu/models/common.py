@@ -28,39 +28,14 @@ _CHUNK_ELEMS = 64 * 1024 * 1024
 
 NEG_INF_ATTN = -1e30
 
-# ---------------------------------------------------------------------------
-# layer-scan indirection (overlap engine hook)
-# ---------------------------------------------------------------------------
-# The trunk of every layer-stacked model scans its blocks through
-# `layer_scan` instead of calling `jax.lax.scan` directly. With nothing
-# installed it IS a plain lax.scan (identical trace, asserted in tests —
-# the overlap strict-no-op contract); the overlap engine
-# (runtime/overlap.py) installs a double-buffered implementation around
-# step TRACING so ZeRO-3 per-layer param gathers are issued one layer
-# ahead of the forward. Trace-time only: compiled programs never read
-# this global.
-_LAYER_SCAN_IMPL = None
-
-
-def set_layer_scan_impl(impl):
-    """Install (or clear, with None) the layer-scan override; returns the
-    previous implementation so context managers can restore it."""
-    global _LAYER_SCAN_IMPL
-    prev = _LAYER_SCAN_IMPL
-    _LAYER_SCAN_IMPL = impl
-    return prev
-
 
 def layer_scan(body, init, xs, unroll: int = 1):
-    """``jax.lax.scan`` over layer-stacked ``xs``, overridable by the
-    overlap engine (see :func:`set_layer_scan_impl`)."""
-    impl = _LAYER_SCAN_IMPL
-    if impl is None:
-        return jax.lax.scan(body, init, xs, unroll=max(1, int(unroll)))
-    return impl(body, init, xs, unroll)
+    """``jax.lax.scan`` over layer-stacked ``xs``: the one call every
+    layer-stacked trunk walks its blocks with."""
+    return jax.lax.scan(body, init, xs, unroll=max(1, int(unroll)))
 
 
-# The same kind of indirection for what a block is HANDED: under ZeRO-3 on
+# The file's one trace-time hook, over what a block is HANDED: under ZeRO-3 on
 # more than one chip the engine installs the placement layer's gather-on-use
 # rule (runtime/zero/partition.py::LayerGathers) around the trace of the
 # loss's gradient, and `remat_wrap` applies it to the block's arguments

@@ -415,9 +415,9 @@ class GPT2Model:
                 x = carry + gate * (x - carry)
             return x, None
 
-        # layer_scan = lax.scan unless the overlap engine installed its
-        # double-buffered ZeRO-3 gather-prefetch implementation (trace-time
-        # indirection; identical trace when nothing is installed)
+        # the blocks' walk: a lax.scan (models/common.py::layer_scan); under
+        # ZeRO-3 each block gathers its own weights inside remat_wrap
+        # (runtime/zero/partition.py::LayerGathers)
         with scope("layers"):
             x, _ = layer_scan(scan_body, x,
                               (params["blocks"], layer_rngs, windows,

@@ -1072,8 +1072,8 @@ class LlamaModel:
             functools.partial(self._block, kind=kind), c.remat)
             for kind in set(c.kinds)}
 
-        # overridable layer scan (overlap engine's ZeRO-3 gather prefetch;
-        # a plain lax.scan when nothing is installed)
+        # the blocks' walk: a lax.scan (models/common.py::layer_scan); under
+        # ZeRO-3 each block gathers its own weights inside remat_wrap
         from deepspeed_tpu.models.common import layer_scan
 
         for xs, _, _, view, pattern in self._stacks(params,

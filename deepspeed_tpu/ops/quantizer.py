@@ -71,7 +71,7 @@ def quant_group_layout(n_in: int, group_size: int):
     old behavior): the padded rows are what actually crosses the wire in a
     quantized gather, so ``QuantizedTensor.nbytes`` — the number
     ``static_comm_bytes`` bills — must account them (pinned by
-    tests/unit/test_wire.py). ``group_size`` ≥ the dim still means one
+    tests/unit/test_quantization.py). ``group_size`` ≥ the dim still means one
     group (nothing to pad against)."""
     if group_size <= 0 or group_size >= n_in:
         return n_in, 1, n_in

@@ -35,14 +35,12 @@ DATA_AXIS = "data"
 # immediately inside 'data' puts each shard group on contiguous ICI
 # neighbors — the hierarchical intra-node gather MiCS hand-codes.
 MICS_AXIS = "mics"
-# Intra-host sub-axis of the data-parallel world (ds_wire hpZ, ZeRO++ §4):
-# when wire.secondary_partition is set, the data axis is factored into
-# (DATA_AXIS = inter-host groups, ICI_AXIS = devices within a host), so a
-# SECONDARY replica of the ZeRO-3 shards can be held partitioned over the
-# fast intra-host links only — the backward regather then never crosses
-# hosts. Placed immediately inside 'data' (like 'mics') so each host group
-# lands on contiguous ICI neighbors. Size 1 (absent) on every topology
-# that does not opt in, so existing meshes are unchanged.
+# Intra-host sub-axis of the data-parallel world: (DATA_AXIS = inter-host
+# groups, ICI_AXIS = devices within a host), placed immediately inside
+# 'data' (like 'mics') so each host group lands on contiguous ICI
+# neighbors. Size 1 unless ``tpu.ici`` sets it by hand: the hpZ secondary
+# partition that factored it went at PR 44, and the axis itself is
+# ROADMAP D15 (removing it changes every mesh's axis names).
 ICI_AXIS = "ici"
 EXPERT_AXIS = "expert"
 SEQ_AXIS = "seq"

@@ -101,11 +101,6 @@ def _world_tag(r):
     if mo is not None and mn is not None and mo != mn:
         return (f"  [mesh changed {mo} -> {mn}: same device count, "
                 "different layout — not two views of one experiment]")
-    wiro = r.get("old_wire_mode") or "off"
-    wirn = r.get("new_wire_mode") or "off"
-    if wiro != wirn:
-        return (f"  [wire changed {wiro} -> {wirn}: quantized vs "
-                "full-width collectives — not two views of one experiment]")
     return "  [world resized mid-run: not two views of one experiment]"
 
 

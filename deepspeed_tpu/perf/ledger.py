@@ -323,14 +323,9 @@ def compare(old: Dict[str, Any], new: Dict[str, Any],
     # different experiment too: the mesh_axes string the recorder stamps
     # participates in the world identity
     mo, mn = old.get("mesh_axes"), new.get("mesh_axes")
-    # the wire mode (quantized vs full-width collectives) is experiment
-    # identity too: entries that predate the key read as "off"
-    wiro = old.get("wire_mode") or "off"
-    wirn = new.get("wire_mode") or "off"
     world_changed = bool(
         (wo is not None and wn is not None and wo != wn)
         or (mo is not None and mn is not None and mo != mn)
-        or wiro != wirn
         or old.get("world_resized") or new.get("world_resized"))
     out = {
         "series": series_key(new),
@@ -341,7 +336,6 @@ def compare(old: Dict[str, Any], new: Dict[str, Any],
         "new_fingerprint": new.get("fingerprint"),
         "old_world": wo, "new_world": wn,
         "old_mesh_axes": mo, "new_mesh_axes": mn,
-        "old_wire_mode": wiro, "new_wire_mode": wirn,
         "world_changed": world_changed,
         "fingerprint_changed": world_changed or (
             bool(old.get("fingerprint")) and bool(new.get("fingerprint"))
@@ -363,7 +357,7 @@ def compare(old: Dict[str, Any], new: Dict[str, Any],
     # power floor and fingerprint-change escape hatch.
     # exposed_comm_us_per_step rides along the same way (entries recorded
     # under a telemetry session carry it in `attribution`): LOWER is
-    # better — the overlap engine's whole point is shrinking it — so the
+    # better — exposed communication is time the chip waits — so the
     # regression direction flips vs the headline. Judged relative with an
     # absolute floor (EXPOSED_COMM_FLOOR_US): a 0 → 40µs blip on a step
     # that exposes nothing must not fail CI, a 0 → 20ms un-overlap must.

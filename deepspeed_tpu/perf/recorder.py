@@ -110,13 +110,6 @@ class PerfRecorder:
             entry["mesh_axes"] = mesh_axes_string(self.engine.mesh)
         except Exception:
             pass
-        # the wire mode ("off" / "qwz" / "qwz+hpz+qgz", …) is part of the
-        # entry's experiment identity: a quantized-collective run is not
-        # two views of one experiment with a full-width one, so compare()
-        # treats a mode change like a mesh-layout change (never a silent
-        # diff — `ds_perf` prints `[wire changed a -> b]`)
-        wire = getattr(self.engine, "_wire", None)
-        entry["wire_mode"] = wire.mode if wire is not None else "off"
         resized = (getattr(self.engine, "_last_recovery", None)
                    or {}).get("resize")
         if resized:

@@ -487,9 +487,9 @@ class FleetTrace:
         of the step's comm leaf intervals minus the union of its non-comm
         leaf intervals, fleet-wide once clocks are aligned.
 
-        This is the ROADMAP Item 3 before/after number: overlap work
-        (gather prefetch, reduce-scatter under backward) shrinks exactly
-        this quantity while the per-op comm histograms stay the same.
+        This is the before/after number of any work that hides
+        communication behind compute: it shrinks exactly this quantity
+        while the per-op comm histograms stay the same.
         Returns None when the step has no leaf spans at all, 0.0 when it
         has spans but no comm (nothing exposed).
         """

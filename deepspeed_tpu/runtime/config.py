@@ -103,6 +103,9 @@ ADVISORY_NOOP_KEYS = {
 REJECTED_KEYS = {
     "amp": "apex automatic mixed precision is CUDA-only; use bf16 "
            "(recommended on TPU) or fp16 with dynamic loss scaling",
+    "wire": "the quantized gathers rode the overlap block's prefetch ring, "
+            "which measured 5% slower than ZeRO-3's default gather on a v5e "
+            "and was removed with them at PR 44; delete the block",
 }
 
 # Raw-dict blocks whose subsystems consume them permissively (no pydantic
@@ -270,9 +273,9 @@ class TPUMeshConfig(DeepSpeedConfigModel):
     # the data axis into (data=replica groups, mics=shard) from
     # zero_optimization.mics_shard_size (reference zero/mics.py:31)
     mics: int = Field(1, ge=1)
-    # ds_wire intra-host sub-axis (ZeRO++ hpZ); normally not set by hand —
-    # engine init factors the data axis into (data=inter-host groups,
-    # ici=devices per host) from wire.secondary_partition/secondary_size
+    # intra-host sub-axis of the data-parallel world: (data=inter-host
+    # groups, ici=devices per host). Nothing factors it since PR 44 removed
+    # its one producer (ROADMAP D15); set by hand it is one more DP axis
     ici: int = Field(1, ge=1)
     expert: int = Field(1, ge=1)
     seq: int = Field(1, ge=1)
@@ -666,31 +669,23 @@ class RooflineConfig(DeepSpeedConfigModel):
 
 
 class OverlapConfig(DeepSpeedConfigModel):
-    """Overlap engine (deepspeed_tpu/runtime/overlap.py): hide the ZeRO
-    collectives behind compute. Restructures the fused train step so the
-    XLA scheduler can overlap communication with computation: per-block
-    ZeRO-3 param gathers prefetched ``param_prefetch`` layers ahead of
-    the forward (double-buffered layer scan over the model's stacked
-    blocks, specs from the ShardingPlan), per-block gradient
-    reduce-scatter issued inside the backward scan (the gather's
-    custom-vjp transpose) instead of one fused post-backward reduction,
-    the XLA latency-hiding-scheduler flag preset applied once at engine
-    init (reported by ``ds_report``), and checkpoint snapshots taken as
-    a device-side copy with the device→host transfer + verified write on
-    a background thread. ``schedule: "serial"`` runs the measured
-    UN-overlapped baseline instead — a blocking, span-timed all-gather
-    phase before the compute program — so ``ds_prof merge`` /
-    ``ds_perf gate --metric exposed_comm`` can price exactly what the
-    overlapped schedule removes. STRICT no-op when the block is absent:
-    the overlap module is never imported, the step builder and models'
-    layer scan are byte-identical, and the checkpoint path is untouched
-    (asserted in tests — same bar as ``telemetry``/``profiling``/
-    ``goodput``). See docs/CONFIG.md 'overlap' section."""
+    """The ``overlap`` block (deepspeed_tpu/runtime/overlap.py): the XLA
+    latency-hiding-scheduler flag preset applied once at engine init
+    (reported by ``ds_report``), checkpoint snapshots taken as a
+    device-side copy with the device→host transfer + verified write on a
+    background thread, and ``schedule: "serial"``, a measuring tool: a
+    blocking, span-timed all-gather phase of the whole parameter tree
+    before the compute program, so ``ds_prof merge``, ``ds_gray`` and
+    ``ds_perf gate --metric exposed_comm`` can read as a host span what
+    the fused step keeps inside one program. ZeRO-3's per-layer gather is
+    not this block's: it is part of every stage-3 step
+    (runtime/zero/partition.py::LayerGathers), and with ``schedule:
+    "overlapped"`` the train step is the step without the block. STRICT
+    no-op when the block is absent: the overlap module is never imported
+    and the checkpoint path is untouched (asserted in tests). See
+    docs/CONFIG.md 'overlap' section."""
     enabled: bool = Field(True, description="arm the overlap engine (the block being present opts in; set false to keep the block but skip the work)")
-    schedule: str = Field("overlapped", description="'overlapped' = restructured step (prefetched gathers, in-scan reduce-scatter); 'serial' = the measured un-overlapped ZeRO-3 baseline: a blocking span-timed gather phase, then compute — the before side of the exposed-comm delta")
-    param_prefetch: int = Field(1, ge=0, le=8, description="layers of ZeRO-3 param gather issued ahead of the forward (double-buffered at 1; 0 disables the layer-scan restructure; clamped below the model's layer count)")
-    grad_reduce: str = Field("scan", description="'scan' = per-block gradient reduce-scatter inside the backward scan (overlapped with backward remat); 'post' = one fused post-backward reduction (the pre-overlap layout)")
-    remat_gather: bool = Field(True, description="recompute (re-gather) the prefetched params in the backward pass instead of saving L gathered layer slices — bounded memory, one extra gather per layer in backward")
+    schedule: str = Field("overlapped", description="'overlapped' = the engine's one fused step, as without the block; 'serial' = the measured un-overlapped ZeRO-3 baseline: a blocking span-timed gather phase of the whole parameter tree, then compute — the before side of the exposed-comm delta, and the span ds_gray and `ds_prof merge` read")
     scheduler_flags: bool = Field(True, description="append the XLA latency-hiding scheduler / async-collective-fusion flag preset to XLA_FLAGS at engine init (TPU scheduler flags; ds_report shows the live set — a backend initialized before engine init only hands them to launcher children)")
     async_checkpoint: bool = Field(True, description="save_checkpoint takes a device-side snapshot copy and runs the device→host transfer + verified orbax/manifest write on a background thread — checkpoint badput stops charging the step, at the cost of one extra state copy resident until the write drains")
 
@@ -700,45 +695,6 @@ class OverlapConfig(DeepSpeedConfigModel):
         if v not in ("overlapped", "serial"):
             raise ValueError(f"overlap.schedule must be 'overlapped' or "
                              f"'serial', got {v!r}")
-        return v
-
-    @field_validator("grad_reduce")
-    @classmethod
-    def _grad_reduce_known(cls, v):
-        if v not in ("scan", "post"):
-            raise ValueError(f"overlap.grad_reduce must be 'scan' or 'post', "
-                             f"got {v!r}")
-        return v
-
-
-class WireConfig(DeepSpeedConfigModel):
-    """ds_wire — wire-speed ZeRO collectives (runtime/wire.py): the three
-    ZeRO++-style rewrites (qwZ quantized weight all-gather, hpZ secondary
-    intra-host partition, qgZ hierarchical quantized gradient exchange —
-    PAPERS.md: ZeRO++, EQuARX) expressed as sharding-spec-level transforms
-    the overlap engine's prefetched layer scan schedules. Every knob is a
-    per-collective accuracy-vs-bandwidth trade; the delta is provable
-    hardware-free — each on/off pair lands as two perf-ledger entries whose
-    ``static_comm_bytes`` (by collective kind, intra-/inter-host split on
-    ``ici``-factored meshes) ``ds_perf gate --metric static_comm_bytes``
-    enforces. STRICT no-op when the block is absent: the wire module is
-    never imported, the overlap scan and the lowered HLO are byte-identical
-    (asserted in tests/unit/test_wire.py — same contract as ``overlap``/
-    ``goodput``/``rewind``). See docs/CONFIG.md 'wire' section and the
-    README "Shrinking the wire" walkthrough."""
-    enabled: bool = Field(True, description="arm the wire engine (the block being present opts in; set false to keep the block but skip the work)")
-    weight_quant_bits: int = Field(8, description="qwZ: bits of the block-quantized ZeRO-3 weight all-gather (8 = int8 codes, 4 = packed int4, 0 = full-width bf16 gather); active at ZeRO stage 3 with the overlap block armed — the gather moves codes + per-group f32 scales instead of bf16")
-    grad_quant_bits: int = Field(0, description="qgZ: bits of the hierarchical quantized gradient exchange (4/8; 0 = off). Owns the grad sync on the stage-0 pure-DP shard-mapped step (adam/adamw) with error-feedback residuals riding the optimizer state; at ZeRO stage >= 1 the grad reduce is GSPMD-inserted and this knob is loudly inert (a 1-bit optimizer alongside it is refused — both would own the exchange)")
-    secondary_partition: bool = Field(False, description="hpZ: hold a secondary QUANTIZED replica of the ZeRO-3 shards partitioned over the intra-host 'ici' sub-axis only, so every per-layer gather (and the backward regather walk) stays on the fast intra-host links — one small inter-host code gather per step rebuilds the replica; costs its resident codes (params/ici bytes per device)")
-    secondary_size: int = Field(0, ge=0, description="devices per host group for the hpZ factoring (the 'ici' sub-axis size); 0 = auto: the real per-host device count on multi-process runs, half the data axis on a single-process simulated mesh; must divide the data axis")
-    group_size: int = Field(64, gt=0, description="quantization group length (rows sharing one f32 scale) for qwZ codes and qgZ chunks; smaller = tighter error, more scale overhead on the wire (f32/group)")
-
-    @field_validator("weight_quant_bits", "grad_quant_bits")
-    @classmethod
-    def _bits_known(cls, v):
-        if v not in (0, 4, 8):
-            raise ValueError(f"wire quant bits must be 0 (off), 4 or 8, "
-                             f"got {v}")
         return v
 
 
@@ -977,14 +933,9 @@ class DeepSpeedConfig:
         self.goodput = GoodputConfig(**pd.get("goodput", {}))
         self.goodput_present = "goodput" in pd
         # presence matters, same contract again: no block, no overlap
-        # module (never imported; step builder + models' layer scan stay
-        # byte-identical, checkpoint path untouched)
+        # module (never imported; checkpoint path untouched)
         self.overlap = OverlapConfig(**pd.get("overlap", {}))
         self.overlap_present = "overlap" in pd
-        # presence matters, same contract again: no block, no wire module
-        # (never imported; the overlap scan and lowered HLO byte-identical)
-        self.wire = WireConfig(**pd.get("wire", {}))
-        self.wire_present = "wire" in pd
         # presence matters, same contract again: no block, no sdc module
         # (never imported; the step metrics carry no checksum and the
         # lowered step HLO is byte-identical)
@@ -1070,7 +1021,7 @@ class DeepSpeedConfig:
         "elasticity", "hybrid_engine", "gradient_compression",
         "compression_training", "sparse_attention", "data_efficiency",
         "autotuning", "optimizer", "scheduler", "gradient_clipping", "resilience", "rewind", "watchdog", "analysis",
-        "steps_per_print", "telemetry", "profiling", "perf", "serving", "goodput", "overlap", "wire", "sdc", "roofline", "gray", "blackbox", "wall_clock_breakdown", "memory_breakdown",
+        "steps_per_print", "telemetry", "profiling", "perf", "serving", "goodput", "overlap", "sdc", "roofline", "gray", "blackbox", "wall_clock_breakdown", "memory_breakdown",
         "dump_state", "seed", "eigenvalue", "progressive_layer_drop",
         "train_batch_size", "train_micro_batch_size_per_gpu",
         "train_micro_batch_size_per_chip", "gradient_accumulation_steps",

@@ -166,49 +166,6 @@ class DeepSpeedEngine:
                         update={"data": mesh_cfg.data // mics, "mics": mics})
                 else:
                     mesh_cfg = mesh_cfg.model_copy(update={"mics": mics})
-            wire_cfg = self._config.wire if self._config.wire_present else None
-            if wire_cfg is not None and wire_cfg.enabled and \
-                    wire_cfg.secondary_partition and mesh_cfg.ici == 1:
-                # ds_wire hpZ (ZeRO++ §4): factor the data axis into
-                # (data = inter-host groups, ici = devices per host) so the
-                # secondary replica of the ZeRO-3 shards can live on the
-                # fast intra-host axis only
-                from deepspeed_tpu.parallel.topology import (DATA_AXIS as _DA,
-                                                             _resolve_mesh_dims)
-                try:
-                    resolved = _resolve_mesh_dims(mesh_cfg,
-                                                  len(jax.devices()))
-                    data_size = resolved[_DA]
-                except ValueError:
-                    resolved, data_size = {}, 0
-                want = int(wire_cfg.secondary_size)
-                if want == 0 and data_size:
-                    if jax.process_count() > 1:
-                        # devices-per-host ON THE DATA AXIS: the inner
-                        # (expert/seq/tensor) axes sit inside a host, so
-                        # they use up part of its device budget — an ici
-                        # group of local_device_count would span hosts
-                        inner = int(np.prod(
-                            [resolved.get(a, 1)
-                             for a in ("expert", "seq", "tensor")])) or 1
-                        want = max(1, jax.local_device_count() // inner)
-                    else:
-                        want = max(1, data_size // 2)
-                if want > 1 and data_size and data_size % want == 0 \
-                        and data_size // want > 1:
-                    mesh_cfg = mesh_cfg.model_copy(
-                        update={"data": data_size // want, "ici": want})
-                elif int(wire_cfg.secondary_size) > 0:
-                    raise ValueError(
-                        f"wire.secondary_size={want} does not factor the "
-                        f"data axis ({data_size}) into >1 host groups of "
-                        f"{want}; pick a divisor smaller than the data size")
-                else:
-                    log_dist(
-                        f"wire.secondary_partition: cannot auto-factor the "
-                        f"data axis ({data_size}) into host groups — hpZ "
-                        "inactive (set wire.secondary_size explicitly)",
-                        ranks=[0])
             backend = dist.init_distributed(mesh_config=mesh_cfg, verbose=False)
             mesh = backend.mesh
         self.mesh = mesh
@@ -325,20 +282,6 @@ class DeepSpeedEngine:
         self.sharding = self.plan.registry
         log_dist(partition_report(self.plan, param_shapes), ranks=[0])
 
-        # ---- wire engine (wire-speed ZeRO collectives) -------------------
-        # runtime/wire.py: qwZ block-quantized weight all-gather (rides the
-        # overlap engine's prefetched scan), hpZ secondary intra-host
-        # partition (registry `secondary` family over the ici sub-axis),
-        # qgZ hierarchical quantized grad exchange (wraps the optimizer on
-        # the stage-0 shard-mapped path). STRICT no-op when the block is
-        # absent: the module is never imported, the overlap scan and the
-        # lowered HLO are byte-identical (asserted in tests).
-        self._wire = None
-        if self._config.wire_present and self._config.wire.enabled:
-            from deepspeed_tpu.runtime.wire import WireEngine
-
-            self._wire = WireEngine(self, self._config.wire)
-
         # ---- static analysis (ds_doctor) ---------------------------------
         # STRICT no-op when the ``analysis`` block is absent: the analysis
         # package is never imported and no pass runs (asserted in tests).
@@ -440,13 +383,6 @@ class DeepSpeedEngine:
 
         # ---- optimizer ---------------------------------------------------
         self.optimizer = self._configure_optimizer()
-        if self._wire is not None:
-            # qgZ: swap in the hierarchical-quantized-grad-sync optimizer
-            # where the wire can own the exchange (stage 0 pure-DP
-            # adam/adamw); loudly inert otherwise, refused next to a 1-bit
-            # optimizer (both would own the gradient exchange)
-            self.optimizer = self._wire.wrap_grad_sync(self.optimizer,
-                                                       self._config)
         self._lr_supports_override = _supports_lr_override(self.optimizer)
 
         # 1-bit optimizer family: the update runs inside a shard_map over the
@@ -460,9 +396,8 @@ class DeepSpeedEngine:
             if self.zero_stage != 0:
                 raise ValueError("1-bit optimizers require ZeRO stage 0 (parity with "
                                  "the reference: compressed comm replaces ZeRO's)")
-            comm_axes = getattr(self.optimizer, "comm_axes", (DATA_AXIS,))
             for ax, n in dict(mesh.shape).items():
-                if ax not in comm_axes and n > 1:
+                if ax != DATA_AXIS and n > 1:
                     raise ValueError(f"1-bit optimizers need a pure-DP mesh; axis "
                                      f"{ax!r} has size {n}")
             if self._config.gradient_clipping:
@@ -494,15 +429,13 @@ class DeepSpeedEngine:
             raise ValueError("NVMe optimizer offload supports bf16/fp32 only "
                              "(fp16 dynamic loss scaling is a device-side loop)")
 
-        # ---- overlap engine (hide ZeRO collectives behind compute) -------
-        # runtime/overlap.py: prefetched per-block ZeRO-3 gathers
-        # (double-buffered layer scan), per-block grad reduce-scatter in
-        # the backward scan, the XLA latency-hiding scheduler preset, async
-        # checkpoint snapshots — or the measured un-overlapped "serial"
+        # ---- overlap engine ----------------------------------------------
+        # runtime/overlap.py: the XLA latency-hiding scheduler preset, async
+        # checkpoint snapshots, and the measured un-overlapped "serial"
         # schedule whose gather phase lands as a comm span. STRICT no-op
-        # when the block is absent: the module is never imported, the step
-        # builder and the models' layer scan trace byte-identically, and
-        # the checkpoint path is untouched (asserted in tests).
+        # when the block is absent: the module is never imported and the
+        # checkpoint path is untouched; present or absent, the fused step
+        # traces the same program (asserted in tests).
         self._overlap = None
         if self._config.overlap_present and self._config.overlap.enabled:
             from deepspeed_tpu.runtime.overlap import OverlapEngine
@@ -523,11 +456,11 @@ class DeepSpeedEngine:
         # zero/partition.py: the plan's rule (None on one chip, at stage
         # 0-2, where no stacked leaf is sharded), applied to what a block is
         # handed while the loss's gradient is traced. Not where the step is
-        # manual over the data axes (1-bit) or the overlap engine gathers
-        # the layers itself (its ring, its serial phase): no leaf twice.
+        # manual over the data axes (1-bit) or the serial schedule's phase
+        # has gathered the whole tree (runtime/overlap.py): no leaf twice.
         self._layer_gathers = self.plan.layer_gathers
         if self._onebit or (self._overlap is not None
-                            and self._overlap.gathers_layers):
+                            and self._overlap.schedule == "serial"):
             self._layer_gathers = None
 
         # ---- materialize state sharded ----------------------------------
@@ -1411,15 +1344,7 @@ class DeepSpeedEngine:
                 if jnp.issubdtype(a.dtype, jnp.integer) else a[-1], auxes)
 
     def _build_train_batch_fn(self, gas: int):
-        """Fused train step: scan over gradient-accumulation microbatches.
-        With the overlap engine armed, the loss/grad trace runs under its
-        layer-scan override (runtime/overlap.py): per-block ZeRO-3 gathers
-        double-buffered one layer ahead, per-block reduce-scatter in the
-        backward scan. The override is trace-time only — installed around
-        the body's execution during jit tracing (and the ds_doctor
-        abstract re-trace, so the collective fingerprints see the same
-        schedule the engine compiles)."""
-        overlap = self._overlap
+        """Fused train step: scan over gradient-accumulation microbatches."""
         # ds_sentry online checksum: one extra fused reduction riding the
         # step (like the grad norm). Resolved at BUILD time so the
         # absent-block trace is byte-identical (the sdc module is never
@@ -1431,13 +1356,8 @@ class DeepSpeedEngine:
 
         def step_fn(state: TrainState, batch):
             scale = state.scaler.scale if state.scaler is not None else jnp.float32(1.0)
-            if overlap is None:
-                mean_loss, grads, aux = self._accumulated_loss_grads(
-                    state, batch, gas, scale, with_aux=True)
-            else:
-                with overlap.scan_context():
-                    mean_loss, grads, aux = self._accumulated_loss_grads(
-                        state, batch, gas, scale, with_aux=True)
+            mean_loss, grads, aux = self._accumulated_loss_grads(
+                state, batch, gas, scale, with_aux=True)
             new_state, metrics = self._apply_grads(state, grads, mean_loss, aux)
             if sdc_fold is not None:
                 metrics = metrics._replace(checksum=sdc_fold(
@@ -1496,11 +1416,6 @@ class DeepSpeedEngine:
         mesh = self.mesh
         spec_of = lambda tree: jax.tree.map(lambda s: s.spec, tree)
         state_specs = spec_of(self.state_shardings)
-        # the data-parallel axes the optimizer's exchange spans: (data,) for
-        # the 1-bit family, (data, ici) for the wire's qgZ sync on an
-        # hpZ-factored mesh
-        comm_axes = tuple(getattr(opt, "comm_axes", (DATA_AXIS,)))
-        batch_axis = comm_axes if len(comm_axes) > 1 else comm_axes[0]
 
         def local_step(state: TrainState, batch):
             masters0 = state.master if state.master is not None else state.params
@@ -1546,12 +1461,12 @@ class DeepSpeedEngine:
             else:
                 new_params, master_out = new_masters, None
 
-            loss_avg = jax.lax.pmean(loss.astype(jnp.float32), comm_axes)
+            loss_avg = jax.lax.pmean(loss.astype(jnp.float32), DATA_AXIS)
             # ||g||-proxy: sqrt(E_w ||g_local||²) — the dense global-mean grad
             # never exists in the compressed stage, so report the RMS of the
             # local-grad norms instead (documented deviation).
             sq = sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in jax.tree.leaves(grads))
-            gnorm = jnp.sqrt(jax.lax.pmean(sq, comm_axes))
+            gnorm = jnp.sqrt(jax.lax.pmean(sq, DATA_AXIS))
             new_state = TrainState(step=state.step + 1, params=new_params,
                                    master=master_out, opt_state=new_opt,
                                    scaler=None,
@@ -1562,7 +1477,7 @@ class DeepSpeedEngine:
             return new_state, metrics
 
         def step_fn(state, batch):
-            batch_specs = jax.tree.map(lambda x: P(batch_axis, *([None] * (x.ndim - 1))), batch)
+            batch_specs = jax.tree.map(lambda x: P(DATA_AXIS, *([None] * (x.ndim - 1))), batch)
             repl = jax.tree.map(lambda _: P(), jax.eval_shape(lambda: StepMetrics(
                 jnp.float32(0), jnp.float32(0), jnp.float32(0), jnp.float32(0), jnp.bool_(False))))
             return jax.shard_map(local_step, mesh=mesh,

@@ -1,75 +1,63 @@
-"""Overlap engine — hide the ZeRO collectives behind compute.
+"""The ``overlap`` block: a scheduler preset, a measuring schedule, a snapshot.
 
-The reference hides ZeRO-3 communication with hand-scheduled CUDA streams:
-``PartitionedParameterCoordinator`` prefetches the next submodule's
-allgather while the current one computes (stage3.py fetch/prefetch/release
-state machine) and ``overlap_comm`` launches the gradient reduce-scatter on
-a side stream during backward. On TPU the schedule belongs to XLA, so the
-same wins are expressed as *program structure* the compiler can overlap:
+ZeRO-3's gather of a layer's weights is NOT here. It is stated where a block
+uses its weights (``runtime/zero/partition.py::LayerGathers``, seated in
+``models/common.py::remat_wrap``) and is part of every stage-3 step with or
+without this block. Until PR 44 this file also held a prefetch ring (a
+double-buffered layer scan that gathered layer *i+depth* while layer *i*
+computed). PR 36 ran both on a v5e host's four chips on gpt2-xl ZeRO-3
+over data=4: the ring was busy 1,919.3 ms a step against the stated
+gather's 1,821.3, ``train.mfu`` 41.69 against 43.86,
+``train.coll_exposed_frac`` 5.76 against 1.65 (one traced run each, my chip
+run, PR 36), and it walked one stack where four of the benchmark's models
+hold several. It lost its only measurement and was removed. What is left:
 
-* **param-gather prefetch** (:func:`prefetched_layer_scan`) — the fused
-  train step's layer loop is rebuilt as a double-buffered scan: the
-  ZeRO-3 gather of layer *i+1*'s (dp-sharded) stacked params is issued as
-  an independent op while layer *i* computes, so the latency-hiding
-  scheduler can overlap gather and matmul instead of serializing
-  slice → gather → compute inside one iteration. Specs come straight from
-  the existing :class:`~deepspeed_tpu.runtime.zero.partition.ShardingPlan`.
-* **per-block grad reduce-scatter** — the gather is a ``custom_vjp`` whose
-  backward constrains the cotangent back to the *sharded* layout, so the
-  reduce-scatter of layer *i*'s grads is issued inside the backward scan
-  (while layer *i-1*'s backward computes) instead of one fused
-  post-backward reduction (``grad_reduce: "scan"`` vs ``"post"``).
 * **latency-hiding scheduler preset** (:func:`apply_scheduler_flags`) —
-  the XLA flags that let the TPU scheduler actually move async collectives
-  behind compute, applied once at engine init and reported by
-  ``ds_report``.
+  the XLA flags that let the TPU scheduler move async collectives behind
+  compute, applied once at engine init and reported by ``ds_report``.
+* **the serial schedule** (``schedule: "serial"``,
+  :meth:`OverlapEngine.serial_step`) — a measuring tool, not a way to
+  train fast. One fused XLA program is opaque to host-side spans: its
+  collectives never appear as ``cat="comm"`` trace events. The serial
+  schedule is the classic blocking ZeRO-3 schedule instead: a separately
+  dispatched all-gather program of the whole parameter tree (timed to
+  completion, emitted as a rank-matchable comm span with the same
+  ``(op, seq, group)`` identity ``ds_prof merge`` aligns on) followed by
+  the compute program over the gathered copy. ``ds_prof merge``, the
+  perf ledger's goodput block and ``ds_gray``'s evidence chain read that
+  ``zero3_gather`` span; the ``collective`` chaos target inflates it
+  deterministically for drills. ``schedule: "overlapped"`` (the default)
+  names the engine's one fused step, unchanged by this block.
 * **async checkpoint snapshot** (:class:`AsyncSnapshotter`) — a device-side
   copy of the state is taken on the step path (HBM-bandwidth fast) and the
-  device→host transfer plus the PR 1 verified orbax/manifest write run on
-  a background thread, so the ``checkpoint`` badput bucket stops charging
+  device→host transfer plus the verified orbax/manifest write run on a
+  background thread, so the ``checkpoint`` badput bucket stops charging
   the step.
 
-**Measuring the win.** One fused XLA program is opaque to host-side
-spans: its internal collectives never appear as ``cat="comm"`` trace
-events, so a fused step's ``exposed_comm_us_per_step`` reads ~0 whether
-or not the schedule overlaps. ``schedule: "serial"`` is the *measured
-un-overlapped baseline*: the classic blocking ZeRO-3 schedule the
-reference runs without prefetch — a separately dispatched all-gather
-program (timed to completion, emitted as a rank-matchable comm span with
-the same ``(op, seq, group)`` identity ``ds_prof merge`` aligns on)
-followed by the compute program. ``ds_prof merge`` / the perf-ledger
-goodput block then price exactly what the overlapped schedule removes
-from the host timeline; the ``collective`` chaos target can inflate it
-deterministically for drills.
-
 STRICT no-op contract: this module is imported only when the ``overlap``
-ds_config block is present and enabled; without it the engine's step
-builder, the models' ``layer_scan`` and the checkpoint path are untouched
-(asserted byte-identical in tests/unit/test_overlap.py).
+ds_config block is present and enabled, and with ``schedule:
+"overlapped"`` the lowered train step is the text of the step without the
+block (asserted in tests/unit/test_overlap.py).
 """
 
 from __future__ import annotations
 
 import os
-import threading
 import time
-from contextlib import contextmanager, nullcontext
-from typing import Any, List, Optional, Tuple
+from typing import Any, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.runtime.zero.partition import (GATHERED_NAME, ShardingPlan,
-                                                  _axes_of, _spec_tuple,
-                                                  drop_dp_axes, gather_on_use,
-                                                  stacked_param_keys)
+from deepspeed_tpu.runtime.zero.partition import (ShardingPlan, _axes_of,
+                                                  _spec_tuple, drop_dp_axes)
 from deepspeed_tpu.utils import locks as _locks
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 # ---------------------------------------------------------------------------
-# XLA latency-hiding scheduler preset (component 3)
+# XLA latency-hiding scheduler preset
 # ---------------------------------------------------------------------------
 # The flags that make "the compiler overlaps it" true on TPU: async
 # collectives + the latency-hiding scheduler that moves their waits behind
@@ -151,261 +139,11 @@ def _leaf_nbytes(shape_struct) -> int:
 
 
 # ---------------------------------------------------------------------------
-# stacked-subtree matching (the model's layer-scanned params)
-# ---------------------------------------------------------------------------
-class StackedGatherPlan:
-    """Gather/reduce specs for the model's layer-stacked param subtree
-    (``params["blocks"]`` by convention; ``model.stacked_params_key``
-    overrides). Built once at engine init from the ShardingPlan; matched
-    against scan ``xs`` elements at trace time by treedef + leaf shapes."""
-
-    def __init__(self, plan: ShardingPlan, shapes_subtree: Any,
-                 specs_subtree: Any, grad_reduce: str, remat_gather: bool,
-                 wire=None):
-        self.mesh = plan.mesh
-        self.dp_axes = tuple(plan.dp_axes)
-        self.grad_reduce = grad_reduce
-        self.remat_gather = remat_gather
-        leaves, self.treedef = jax.tree_util.tree_flatten(shapes_subtree)
-        self.stacked_shapes = [tuple(l.shape) for l in leaves]
-        self.n_layers = int(leaves[0].shape[0]) if leaves else 0
-        spec_leaves = self.treedef.flatten_up_to(specs_subtree)
-        # per leaf: (gathered slice spec, sharded slice spec) or None when
-        # the leaf carries no dp sharding (persistence-threshold smalls)
-        self.slice_specs: List[Optional[Tuple[P, P]]] = []
-        for sh, sp in zip(leaves, spec_leaves):
-            entries = _spec_tuple(sp, len(sh.shape))[1:]   # drop the L dim
-            sharded = P(*entries)
-            gathered = drop_dp_axes(sharded, len(entries), self.dp_axes)
-            if tuple(gathered) == tuple(_spec_tuple(sharded, len(entries))):
-                self.slice_specs.append(None)
-            else:
-                self.slice_specs.append((gathered, sharded))
-        # ds_wire (runtime/wire.py): per-leaf quantized-gather plans — the
-        # qwZ/hpZ drop-in for the gather below. None entries (or no wire
-        # engine at all) keep the full-width path byte-identical.
-        self.wire = wire if wire is not None and \
-            getattr(wire, "weight_active", False) else None
-        self.wire_leaves = (self.wire.plan_stacked(leaves, self.slice_specs)
-                            if self.wire is not None else None)
-        self.secondary = bool(self.wire is not None and self.wire.secondary
-                              and any(lw is not None and lw.sec_q is not None
-                                      for lw in self.wire_leaves))
-
-    @property
-    def active(self) -> bool:
-        return any(s is not None for s in self.slice_specs)
-
-    def matches(self, element: Any) -> bool:
-        """Does a scan ``xs`` element look like a per-layer slice source of
-        this stacked subtree (same treedef, same stacked leaf shapes)?"""
-        try:
-            leaves, treedef = jax.tree_util.tree_flatten(element)
-        except Exception:
-            return False
-        if treedef != self.treedef or len(leaves) != len(self.stacked_shapes):
-            return False
-        return all(tuple(getattr(l, "shape", ())) == s
-                   for l, s in zip(leaves, self.stacked_shapes))
-
-    def _gather_leaf(self, x, gathered: P, sharded: P):
-        """The placement layer's gather-on-use (zero/partition.py), whose
-        BACKWARD issues the per-block reduce-scatter — grad_reduce="scan".
-        "post" keeps the plain constraint: cotangents stay gathered through
-        the backward scan and the engine's final grad constraint does one
-        fused reduction."""
-        return gather_on_use(
-            x, NamedSharding(self.mesh, gathered),
-            NamedSharding(self.mesh, sharded)
-            if self.grad_reduce == "scan" else None)
-
-    def gather_slice(self, sliced_element: Any, sec_slices=None) -> Any:
-        """Gather one layer's slice of the stacked subtree (leaves without
-        dp sharding pass through untouched). With a wire plan, eligible
-        leaves gather QUANTIZED (codes + scales on the wire; from the hpZ
-        secondary replica's slice when one is held) — the quantized op
-        identity is recorded distinctly so the PR 4 collective fingerprints
-        hash it stably."""
-        from jax.ad_checkpoint import checkpoint_name
-
-        from deepspeed_tpu.comm import comm as _comm
-
-        leaves = self.treedef.flatten_up_to(sliced_element)
-        out = []
-        for i, (leaf, specs, stacked) in enumerate(
-                zip(leaves, self.slice_specs, self.stacked_shapes)):
-            if specs is None:
-                out.append(leaf)
-                continue
-            gathered, sharded = specs
-            lw = self.wire_leaves[i] if self.wire_leaves is not None else None
-            if lw is not None:
-                sec_qt = sec_slices[i] if sec_slices is not None else None
-                op = (f"zero3_gather[q{lw.bits}"
-                      + ("/sec]" if sec_qt is not None else "]"))
-                axes = (("ici",) if sec_qt is not None else self.dp_axes)
-                _comm.record_engine_collective(
-                    op, stacked[1:], getattr(leaf, "dtype", "?"), axes)
-                g = lw.gather(leaf, sec_qt, self.grad_reduce)
-            else:
-                _comm.record_engine_collective(
-                    "zero3_gather", stacked[1:], getattr(leaf, "dtype", "?"),
-                    self.dp_axes)
-                g = self._gather_leaf(leaf, gathered, sharded)
-            out.append(checkpoint_name(g, GATHERED_NAME))
-        return jax.tree_util.tree_unflatten(self.treedef, out)
-
-    # ------------------------------------------------- hpZ secondary replica
-    def build_secondary(self, element: Any):
-        """The per-step secondary replica of one matched stacked element:
-        a list (aligned with the flattened leaves) of stacked
-        QuantizedTensors constrained to the intra-host `secondary` specs —
-        ONE inter-host code gather for the whole stack — or None entries
-        for leaves that keep the full-width path."""
-        from deepspeed_tpu.comm import comm as _comm
-
-        leaves = self.treedef.flatten_up_to(element)
-        out = []
-        for leaf, lw, stacked in zip(leaves, self.wire_leaves,
-                                     self.stacked_shapes):
-            if lw is None or lw.sec_q is None:
-                out.append(None)
-                continue
-            _comm.record_engine_collective(
-                f"hpz_secondary[q{lw.bits}]", stacked,
-                getattr(leaf, "dtype", "?"), self.dp_axes)
-            out.append(lw.quantize_stacked(leaf))
-        return out
-
-    def slice_secondary(self, sec, i):
-        """Layer ``i``'s slices of a build_secondary() result."""
-        if sec is None:
-            return None
-        return [lw.slice_qt(qt, i) if qt is not None else None
-                for lw, qt in zip(self.wire_leaves, sec)]
-
-    def constrain_gathered(self, element: Any) -> Any:
-        """Re-pin a gathered slice's wired leaves at the GATHERED placement
-        (applied to the ring-carry slot right before the body consumes it):
-        without the anchor at the use site, GSPMD may store the carry/
-        residuals sharded and re-gather the weight at the matmul — at full
-        width, unwinding the quantized gather's entire wire win."""
-        if self.wire_leaves is None:
-            return element
-        import jax.lax as lax
-
-        leaves = self.treedef.flatten_up_to(element)
-        out = [lax.with_sharding_constraint(leaf, lw.gathered_leaf)
-               if lw is not None else leaf
-               for leaf, lw in zip(leaves, self.wire_leaves)]
-        return jax.tree_util.tree_unflatten(self.treedef, out)
-
-
-def find_stacked_plan(engine, cfg) -> Optional[StackedGatherPlan]:
-    """The model's layer-stacked param subtree, as a gather plan — None
-    when there is nothing to prefetch (no stacked key, stage < 3, or no
-    leaf actually dp-sharded)."""
-    key = stacked_param_keys(engine.module)[0]   # the ring walks one stack
-    shapes = getattr(engine.plan, "_master_shapes", None)
-    specs = engine.plan.param_specs
-    if not (isinstance(shapes, dict) and key in shapes
-            and isinstance(specs, dict) and key in specs):
-        return None
-    sp = StackedGatherPlan(engine.plan, shapes[key], specs[key],
-                           grad_reduce=cfg.grad_reduce,
-                           remat_gather=cfg.remat_gather,
-                           wire=getattr(engine, "_wire", None))
-    return sp if sp.active else None
-
-
-# ---------------------------------------------------------------------------
-# the double-buffered prefetch scan (components 1 + 2)
-# ---------------------------------------------------------------------------
-def prefetched_layer_scan(body, init, xs, unroll: int,
-                          stacked: StackedGatherPlan, depth: int):
-    """A ``lax.scan`` over layer-stacked ``xs`` where the ZeRO-3 gather of
-    layer ``i+depth``'s params is issued while layer ``i`` computes.
-
-    The gathered slices ride the carry as a ``depth``-deep ring buffer, so
-    the gather for a future layer has NO data dependency on the current
-    layer's compute — which is precisely what lets the latency-hiding
-    scheduler overlap the two (inside one scan iteration the naive
-    slice → gather → matmul chain is serial by construction). The gather's
-    backward re-shards the cotangent per layer (see
-    :meth:`StackedGatherPlan._gather_leaf`), and ``remat_gather`` wraps
-    the gather in ``jax.checkpoint(..., nothing_saveable)`` so the
-    backward REGATHERS instead of saving L gathered slices.
-    """
-    elements = xs if isinstance(xs, tuple) else (xs,)
-    matched = [stacked.matches(e) for e in elements]
-    length = stacked.n_layers
-    if not any(matched) or length <= 0:
-        return jax.lax.scan(body, init, xs, unroll=max(1, int(unroll)))
-    depth = max(1, min(int(depth), max(1, length - 1)))
-
-    # ds_wire hpZ: the secondary quantized replica of each matched stacked
-    # element, built ONCE per step (one inter-host code gather); per-layer
-    # gathers — forward and the remat-replayed backward regather, whose
-    # inputs these slices become — then stay on the intra-host axis.
-    secondary = None
-    if getattr(stacked, "secondary", False):
-        secondary = [stacked.build_secondary(e) if m else None
-                     for e, m in zip(elements, matched)]
-
-    def slice_prim(i):
-        return tuple(jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), e)
-            for e in elements)
-
-    if secondary is None:
-        slice_at = slice_prim
-        raw_gather = lambda sl: tuple(
-            stacked.gather_slice(e) if m else e for e, m in zip(sl, matched))
-    else:
-        def slice_at(i):
-            return (slice_prim(i),
-                    tuple(stacked.slice_secondary(s, i) if s is not None
-                          else None for s in secondary))
-
-        def raw_gather(sl):
-            prim, secs = sl
-            return tuple(
-                stacked.gather_slice(e, sec_slices=secs[j]) if m else e
-                for j, (e, m) in enumerate(zip(prim, matched)))
-
-    if stacked.remat_gather:
-        gather = jax.checkpoint(
-            raw_gather, policy=jax.checkpoint_policies.nothing_saveable)
-    else:
-        gather = raw_gather
-
-    def rewrap(sliced_tuple):
-        return sliced_tuple if isinstance(xs, tuple) else sliced_tuple[0]
-
-    buf = tuple(gather(slice_at(min(j, length - 1))) for j in range(depth))
-
-    def loop(carry, i):
-        c, ring = carry
-        nxt = gather(slice_at(jnp.minimum(i + depth, length - 1)))
-        head = ring[0]
-        if secondary is not None or stacked.wire_leaves is not None:
-            head = tuple(stacked.constrain_gathered(e) if m else e
-                         for e, m in zip(head, matched))
-        new_c, y = body(c, rewrap(head))
-        return (new_c, ring[1:] + (nxt,)), y
-
-    (final, _), ys = jax.lax.scan(loop, (init, buf), jnp.arange(length),
-                                  unroll=max(1, int(unroll)))
-    return final, ys
-
-
-# ---------------------------------------------------------------------------
 # the engine-side driver
 # ---------------------------------------------------------------------------
 class OverlapEngine:
-    """Per-engine overlap state: the stacked gather plan, the serial
-    (measured) schedule's compiled phases, the async snapshotter, and the
-    trace-time layer-scan override."""
+    """Per-engine overlap state: the serial (measured) schedule's compiled
+    phases and the async snapshotter."""
 
     def __init__(self, engine, cfg):
         self.engine = engine
@@ -414,8 +152,6 @@ class OverlapEngine:
         self._gather_compiled = None
         self._serial_compute = {}
         self._snapshotter = None
-        self._stacked: Optional[StackedGatherPlan] = None
-        self._warned_inactive = False
 
         unsupported = []
         if engine._onebit:
@@ -437,25 +173,9 @@ class OverlapEngine:
                 f"{engine.plan.dp_axes}); running the fused step instead "
                 "of dispatching an empty gather phase", ranks=[0])
         if self.unsupported:
-            log_dist(f"overlap: step restructuring disabled for this engine "
+            log_dist(f"overlap: the serial schedule is off for this engine "
                      f"({self.unsupported}); scheduler flags / async "
                      "checkpoint still apply", ranks=[0])
-        else:
-            if engine.plan.zero_stage < 3 and cfg.param_prefetch > 0:
-                log_dist(
-                    f"overlap.param_prefetch: ZeRO stage is "
-                    f"{engine.plan.zero_stage} — params are not dp-sharded, "
-                    "so there is no per-layer gather to prefetch (stage 3 "
-                    "activates it); grad placement is unchanged", ranks=[0])
-            self._stacked = find_stacked_plan(engine, cfg)
-            if self._stacked is not None and \
-                    cfg.param_prefetch >= self._stacked.n_layers > 0:
-                log_dist(
-                    f"overlap.param_prefetch={cfg.param_prefetch} >= the "
-                    f"model's layer count ({self._stacked.n_layers}): the "
-                    "whole stack would be gathered up front (no memory win "
-                    f"over replication); clamping to "
-                    f"{self._stacked.n_layers - 1}", ranks=[0])
         if cfg.scheduler_flags:
             self.scheduler_flags_added = apply_scheduler_flags()
         if cfg.async_checkpoint:
@@ -470,54 +190,9 @@ class OverlapEngine:
             return "overlapped"
         return self.cfg.schedule
 
-    @property
-    def gathers_layers(self) -> bool:
-        """This engine gathers the layer stack's ZeRO-3 leaves itself (the
-        serial schedule's phase, the prefetch ring), so the engine's default
-        gather-on-use (zero/partition.py::LayerGathers) stays off."""
-        return self.schedule == "serial" or (
-            self.schedule == "overlapped" and self.cfg.param_prefetch > 0
-            and self._stacked is not None)
-
     def invalidate_compiled(self):
         self._gather_compiled = None
         self._serial_compute = {}
-
-    def scan_context(self):
-        """Context manager installing the prefetched layer scan for the
-        duration of a TRACE of the step function (jit tracing or the
-        ds_doctor abstract re-trace). No-op outside the overlapped
-        schedule or when the model exposes no stacked subtree."""
-        if self.schedule != "overlapped" or self.cfg.param_prefetch <= 0:
-            return nullcontext()
-        stacked = self._stacked
-        if stacked is None:
-            if not self._warned_inactive:
-                self._warned_inactive = True
-                log_dist(
-                    "overlap: param-gather prefetch inactive — the model "
-                    "exposes no dp-sharded layer-stacked param subtree "
-                    "(key "
-                    f"{stacked_param_keys(self.engine.module)[0]!r}"
-                    "); the step compiles unrestructured", ranks=[0])
-            return nullcontext()
-        depth = self.cfg.param_prefetch
-
-        @contextmanager
-        def ctx():
-            from deepspeed_tpu.models import common as _mcommon
-
-            def impl(body, init, xs, unroll):
-                return prefetched_layer_scan(body, init, xs, unroll,
-                                             stacked, depth)
-
-            prev = _mcommon.set_layer_scan_impl(impl)
-            try:
-                yield
-            finally:
-                _mcommon.set_layer_scan_impl(prev)
-
-        return ctx()
 
     # --------------------------------------------------- the serial schedule
     def _gathered_shardings(self):
@@ -544,8 +219,9 @@ class OverlapEngine:
     def serial_step(self, state, batch, gas: int):
         """The measured un-overlapped ZeRO-3 schedule: a blocking,
         span-timed all-gather program, then the compute program over the
-        gathered params. This is what ``schedule: "overlapped"`` removes
-        from the host timeline — the before side of the ledger delta."""
+        gathered params. This is what the fused step (``schedule:
+        "overlapped"``) keeps off the host timeline — the before side of
+        the ledger delta."""
         from deepspeed_tpu.comm import comm as _comm
         from deepspeed_tpu.resilience import chaos as _chaos
 
@@ -553,28 +229,9 @@ class OverlapEngine:
         if self._gather_compiled is None:
             from deepspeed_tpu.sharding import sharded_jit
 
-            wire = getattr(eng, "_wire", None)
-            if wire is not None and wire.weight_active:
-                # ds_wire qwZ on the measured serial schedule: the explicit
-                # gather phase moves codes + scales, and the timed comm
-                # span bills the actual (padded) wire bytes — the chaos
-                # `collective` delay drill inflates the same span
-                leaf_fn, self._gather_bytes = wire.serial_gather(
-                    eng.plan._master_shapes, eng.plan.param_specs,
-                    eng.plan.dp_axes)
-
-                def gather_fn(p):
-                    leaves, tdef = jax.tree_util.tree_flatten(p)
-                    return tdef.unflatten(
-                        [leaf_fn(i, x) for i, x in enumerate(leaves)])
-
-                label = "overlap/zero3_gather_q"
-            else:
-                gather_fn = lambda p: p
-                label = "overlap/zero3_gather"
-                self._gather_bytes = self._gather_phase_bytes()
+            self._gather_bytes = self._gather_phase_bytes()
             self._gather_compiled = sharded_jit(
-                gather_fn, label=label,
+                lambda p: p, label="overlap/zero3_gather",
                 donate_argnums=(), mesh=eng.mesh,
                 in_shardings=(eng.state_shardings.params,),
                 out_shardings=self._gathered_shardings())
@@ -630,7 +287,7 @@ class OverlapEngine:
 
 
 class AsyncSnapshotter:
-    """Checkpoint snapshots off the step path (component 4).
+    """Checkpoint snapshots off the step path.
 
     On the step path only a DEVICE-side copy of the state is taken (a few
     ms of HBM bandwidth — and mandatory for correctness: the next step

@@ -29,10 +29,10 @@ in two places, and the partitioner keeps the rest (embeddings, final norm):
   so what a layer keeps for its backward is the sharded slice and the
   backward gathers again (a gather outside the checkpoint makes the scan
   stack L gathered layers). The engine installs the rule around the trace
-  of the loss's gradient when the plan is stage 3 over more than one chip;
-  with the ``overlap`` block its prefetch ring gathers instead
-  (``runtime/overlap.py``, the same pair). One chip, stage 0-2, a leaf
-  without a DP axis, a region that is already manual: nothing is traced.
+  of the loss's gradient when the plan is stage 3 over more than one chip
+  (not under ``overlap.schedule: "serial"``, whose gather phase hands the
+  step the whole tree gathered). One chip, stage 0-2, a leaf without a DP
+  axis, a region that is already manual: nothing is traced.
 * **the loss head**: ``models/common.py::chunked_lm_loss`` (a ``shard_map``
   over the batch axes that the head enters whole, once a step).
 
@@ -94,16 +94,13 @@ def drop_dp_axes(spec: Optional[P], ndim: int, dp_axes: Sequence[str],
     return P(*out)
 
 
-def gather_on_use(x, gathered: NamedSharding,
-                  sharded: Optional[NamedSharding] = None):
-    """``x`` constrained to its GATHERED placement; with ``sharded``, under
-    a ``custom_vjp`` whose backward constrains the cotangent straight back
-    to the SHARDED placement, so a weight's gradient leaves the pass that
-    made it as a reduce-scatter. The plain constraint's transpose pins the
-    cotangent GATHERED instead: inside a loop that is an all-reduce of the
-    whole gradient an iteration (measured at the loss head, PR 28)."""
-    if sharded is None:
-        return jax.lax.with_sharding_constraint(x, gathered)
+def gather_on_use(x, gathered: NamedSharding, sharded: NamedSharding):
+    """``x`` constrained to its GATHERED placement under a ``custom_vjp``
+    whose backward constrains the cotangent straight back to the SHARDED
+    placement, so a weight's gradient leaves the pass that made it as a
+    reduce-scatter. A plain constraint's transpose pins the cotangent
+    GATHERED instead: inside a loop that is an all-reduce of the whole
+    gradient an iteration (measured at the loss head, PR 28)."""
 
     @jax.custom_vjp
     def gather(v):
@@ -252,7 +249,7 @@ class ShardingPlan:
     for params / master / grads / batch / optimizer state / KV cache), and
     the plan keeps its historical attribute surface (``param_specs``,
     ``master_shardings()``, …) as reads of the registry, so ZeRO consumers
-    and the overlap engine did not have to move."""
+    and the serial schedule (``runtime/overlap.py``) did not have to move."""
 
     def __init__(self, mesh: Optional[Mesh] = None, param_specs: Any = None,
                  master_specs: Any = None, grad_specs: Any = None,

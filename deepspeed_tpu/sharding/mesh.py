@@ -152,19 +152,18 @@ def _nontrivial(dims: Dict[str, int]) -> Dict[str, int]:
 
 
 def host_device_groups(mesh: Optional[Mesh]):
-    """Device-id groups per *host* — the boundary ds_wire's hpZ keeps the
-    backward regather inside and the xray comm model splits wire bytes on
-    (``all-gather`` vs ``all-gather/intra``). Three sources, in order:
+    """Device-id groups per *host* — the boundary the xray comm model
+    splits a collective's bytes on (``all-gather`` vs
+    ``all-gather/intra``). Three sources, in order:
 
     * a real multi-process run: group by ``device.process_index`` — the
       actual host boundary;
-    * a single-process mesh carrying the wire's ``ici`` sub-axis (size
+    * a single-process mesh carrying the ``ici`` sub-axis (``tpu.ici``
       > 1): the DCN-ish axes (pipe, data, mics) index the host groups and
       everything inside (ici, expert, seq, tensor) is one host — the
       simulated-fleet host model the 8-dev drills run on;
     * neither: ``None`` — the mesh encodes no host structure, and the
-      comm model keeps its flat (un-split) accounting, so ledgers from
-      pre-wire topologies stay byte-comparable.
+      comm model keeps its flat (un-split) accounting.
     """
     if mesh is None:
         return None

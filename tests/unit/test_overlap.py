@@ -1,22 +1,21 @@
 """Overlap-engine tests (runtime/overlap.py + the ``overlap`` ds_config
-block): the prefetched layer scan must not change the math, the serial
-(measured un-overlapped) schedule must expose the ZeRO-3 gather as comm
-spans the overlapped schedule removes, promise-vs-actual sharding must
-hold on the simulated 8-way mesh for every ZeRO stage, the collective
-fingerprints must cover the restructured step, the async checkpoint
-snapshot must survive the next step's donation — and the block being
-absent must be a provable strict no-op."""
+block): the serial (measured un-overlapped) schedule must not change the
+math and must expose the ZeRO-3 gather as comm spans the fused step keeps
+inside one program, billed at the bytes of exactly the sharded leaves; the
+collective fingerprints must cover the step; the async checkpoint snapshot
+must survive the next step's donation; the keys of the prefetch ring that
+PR 44 removed must be refused by name — and the block, absent or present
+with ``schedule: "overlapped"``, must leave the train step's text alone."""
 
 import json
 import os
 import sys
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model, synthetic_lm_batch
@@ -56,94 +55,7 @@ def train_losses(engine, steps=3):
 
 
 # ---------------------------------------------------------------------------
-# the prefetched scan itself
-# ---------------------------------------------------------------------------
-@pytest.mark.overlap
-class TestPrefetchedScan:
-    def _toy(self):
-        mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
-        L, D = 4, 16
-        blocks = {
-            "w": jax.device_put(
-                jnp.arange(L * D * D, dtype=jnp.float32).reshape(L, D, D) / 997.0,
-                NamedSharding(mesh, P(None, None, "data"))),
-            "b": jax.device_put(jnp.ones((L, D), jnp.float32),
-                                NamedSharding(mesh, P(None, "data")))}
-        shapes = jax.eval_shape(lambda: blocks)
-        specs = {"w": P(None, None, "data"), "b": P(None, "data")}
-
-        def body(c, xs):
-            blk, extra = xs
-            y = jnp.tanh(c @ blk["w"] + blk["b"])
-            return y + (0.0 if extra is None else extra), None
-
-        x0 = jnp.ones((2, D))
-        return mesh, blocks, shapes, specs, body, x0
-
-    @pytest.mark.parametrize("depth,grad_reduce,remat_gather",
-                             [(1, "scan", True), (1, "post", False),
-                              (2, "scan", True), (3, "scan", False)])
-    def test_matches_lax_scan(self, depth, grad_reduce, remat_gather):
-        from deepspeed_tpu.runtime.overlap import (StackedGatherPlan,
-                                                   prefetched_layer_scan)
-        from deepspeed_tpu.runtime.zero.partition import ShardingPlan
-
-        mesh, blocks, shapes, specs, body, x0 = self._toy()
-        plan = ShardingPlan(mesh=mesh, param_specs=specs, master_specs=specs,
-                            grad_specs=specs, batch_spec=P("data"),
-                            zero_stage=3, dp_axes=("data",))
-        stacked = StackedGatherPlan(plan, shapes, specs,
-                                    grad_reduce=grad_reduce,
-                                    remat_gather=remat_gather)
-        assert stacked.active and stacked.n_layers == 4
-
-        def ref(x0, blocks):
-            c, _ = jax.lax.scan(body, x0, (blocks, None))
-            return c.sum()
-
-        def pre(x0, blocks):
-            c, _ = prefetched_layer_scan(body, x0, (blocks, None), 1,
-                                         stacked, depth)
-            return c.sum()
-
-        with mesh:
-            l_ref = jax.jit(ref)(x0, blocks)
-            l_pre = jax.jit(pre)(x0, blocks)
-            g_ref = jax.jit(jax.grad(ref, argnums=1))(x0, blocks)
-            g_pre = jax.jit(jax.grad(pre, argnums=1))(x0, blocks)
-        np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_pre),
-                                   rtol=1e-6)
-        for k in ("w", "b"):
-            np.testing.assert_allclose(np.asarray(g_ref[k]),
-                                       np.asarray(g_pre[k]), rtol=1e-5)
-            if grad_reduce == "scan":
-                # the custom-vjp transpose must land the cotangent back in
-                # the SHARDED layout (the per-block reduce-scatter target)
-                assert "data" in str(g_pre[k].sharding.spec)
-
-    def test_unmatched_xs_falls_back_to_lax_scan(self):
-        from deepspeed_tpu.runtime.overlap import (StackedGatherPlan,
-                                                   prefetched_layer_scan)
-        from deepspeed_tpu.runtime.zero.partition import ShardingPlan
-
-        mesh, blocks, shapes, specs, body, x0 = self._toy()
-        plan = ShardingPlan(mesh=mesh, param_specs=specs, master_specs=specs,
-                            grad_specs=specs, batch_spec=P("data"),
-                            zero_stage=3, dp_axes=("data",))
-        stacked = StackedGatherPlan(plan, shapes, specs, "scan", True)
-        other = jnp.ones((6, 3))     # wrong treedef/shape: no match
-
-        def body2(c, x):
-            return c + x.sum(), None
-
-        with mesh:
-            out, _ = prefetched_layer_scan(body2, jnp.float32(0.0), other,
-                                           1, stacked, 1)
-        assert float(out) == pytest.approx(18.0)
-
-
-# ---------------------------------------------------------------------------
-# engine schedules: numerics + sharding promises
+# engine schedules: numerics, the gather phase's bill
 # ---------------------------------------------------------------------------
 @pytest.mark.overlap
 class TestEngineSchedules:
@@ -151,47 +63,60 @@ class TestEngineSchedules:
         l_base = train_losses(make_engine())
         l_over = train_losses(make_engine(overlap={}))
         l_serial = train_losses(make_engine(overlap={"schedule": "serial"}))
-        # same math, different program structure: only float reassociation
-        # (gathered vs sharded reduction order) may differ
+        # the block alone changes no program; the serial schedule is the
+        # same math in two programs: only float reassociation (gathered vs
+        # sharded reduction order) may differ
+        assert l_base == l_over
         np.testing.assert_allclose(l_base, l_over, rtol=2e-3)
         np.testing.assert_allclose(l_base, l_serial, rtol=2e-3)
 
-    def test_grad_reduce_post_matches(self):
-        l_scan = train_losses(make_engine(overlap={}))
-        l_post = train_losses(make_engine(overlap={"grad_reduce": "post"}))
-        np.testing.assert_allclose(l_scan, l_post, rtol=2e-3)
+    @pytest.mark.parametrize("family", ["gpt2", "llama-routed"])
+    def test_serial_gather_phase_bills_the_sharded_leaves(self, family):
+        """The ``zero3_gather`` span's bytes: every leaf the plan's rule
+        (``LayerGathers``) names, over all its layers, plus the sharded
+        leaves outside the stacks — and no other leaf."""
+        from deepspeed_tpu.runtime.zero.partition import stacked_param_keys
 
-    @pytest.mark.parametrize("stage", [1, 2, 3])
-    def test_promise_vs_actual_sharding(self, stage):
-        """8-way promise-vs-actual: every materialized leaf must sit at
-        the plan's placement — params (stage 3), fp32 master (stage>=1) —
-        and stay there after an overlapped step."""
-        engine = make_engine(
-            bf16={"enabled": True},
-            zero_optimization={"stage": stage,
-                               "stage3_param_persistence_threshold": 0},
-            overlap={})
+        if family == "gpt2":
+            model = GPT2Model(MCFG)
+        else:
+            from deepspeed_tpu.models.llama import LlamaConfig, LlamaModel
+
+            model = LlamaModel(LlamaConfig(
+                vocab_size=256, n_positions=32, n_embd=32, n_layer=3,
+                n_head=2, intermediate_size=64, n_experts=4,
+                n_experts_per_tok=2, n_dense_layers=1, remat=False,
+                use_flash_attention=False))
+        engine, *_ = deepspeed_tpu.initialize(model=model, config=base_config(
+            zero_optimization={"stage": 3,
+                               "stage3_param_persistence_threshold": 200},
+            overlap={"schedule": "serial", "scheduler_flags": False,
+                     "async_checkpoint": False}))
         engine.train_batch(lm_batch())
-        plan = engine.plan
-        assert plan.dp_axes == ("data",)
-
-        def check(tree, specs):
-            leaves = jax.tree.leaves(tree)
-            spec_leaves = jax.tree.leaves(specs,
-                                          is_leaf=lambda x: isinstance(x, P))
-            assert len(leaves) == len(spec_leaves)
-            for leaf, spec in zip(leaves, spec_leaves):
-                assert leaf.sharding.spec == spec, \
-                    f"promised {spec}, actual {leaf.sharding.spec}"
-
-        check(engine.state.params, plan.param_specs)
-        assert engine.state.master is not None
-        check(engine.state.master, plan.master_specs)
-        if stage >= 1:
-            # the ZeRO promise is real: at least one master leaf is
-            # actually dp-sharded (not silently replicated)
-            assert any("data" in str(l.sharding.spec)
-                       for l in jax.tree.leaves(engine.state.master))
+        rule = engine.plan.layer_gathers
+        assert rule is not None and engine._layer_gathers is None
+        nbytes = lambda x: int(np.prod(x.shape)) * x.dtype.itemsize
+        keys = stacked_param_keys(model)
+        assert len(keys) == (1 if family == "gpt2" else 2)
+        want = named = 0
+        for key, sub in engine.state.params.items():
+            flat = jax.tree_util.tree_flatten_with_path(sub)[0]
+            specs = jax.tree.leaves(engine.plan.param_specs[key],
+                                    is_leaf=lambda x: isinstance(x, P))
+            for (path, leaf), spec in zip(flat, specs):
+                if key in keys:
+                    forms = rule.leaves.get(path[-1].key, ())
+                    hit = any(tuple(leaf.shape[1:]) == f[0] for f in forms)
+                    named += hit
+                else:
+                    hit = "data" in str(spec)
+                want += nbytes(leaf) if hit else 0
+        # the threshold splits a stack: weights named, norms and biases not
+        assert 0 < named < sum(
+            len(jax.tree.leaves(engine.state.params[k])) for k in keys)
+        assert engine._overlap._gather_bytes == want
+        total = sum(nbytes(x) for x in jax.tree.leaves(engine.state.params))
+        assert want < total
 
     def test_serial_degrades_when_nothing_sharded(self, tmp_path):
         """schedule='serial' below stage 3 has no gather to expose: the
@@ -213,10 +138,10 @@ class TestEngineSchedules:
             telemetry.deconfigure()
 
     def test_serial_gather_registers_with_doctor(self):
-        """PR 4 collective fingerprints cover the overlapped schedule:
-        deterministic across engines of the same config, different from
-        a step's that states no gather (ZeRO-2; the unrestructured ZeRO-3
-        step states its own: tests/unit/test_zero3_gather.py)."""
+        """PR 4 collective fingerprints cover the fused step with the
+        block present: deterministic across engines of the same config,
+        different from a step's that states no gather (ZeRO-2; what the
+        ZeRO-3 step states: tests/unit/test_zero3_gather.py)."""
         fps = []
         for _ in range(2):
             e = make_engine(overlap={}, analysis={"fail_on": "error"})
@@ -231,20 +156,27 @@ class TestEngineSchedules:
 
     def test_collective_mismatch_chaos_drills_overlapped_schedule(self):
         """The deadlock detector still names a divergent rank when the
-        sequence is the overlap engine's gather records."""
+        sequence is the fused step's gather records (the layer stack's
+        rule: the block being present changes nothing of them)."""
         from deepspeed_tpu.analysis.collectives import (diff_sequences,
                                                         record_collectives)
         from deepspeed_tpu.resilience.chaos import ChaosInjector
 
-        engine = make_engine(overlap={})
-        fn = engine._build_train_batch_fn(1)
-        abstract = lambda tree: jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
-        batch = engine._shard_batch(lm_batch())
-        with engine.mesh:
-            with record_collectives(apply_chaos=False) as rec:
-                jax.make_jaxpr(fn)(abstract(engine.state), abstract(batch))
+        def records(engine):
+            fn = engine._build_train_batch_fn(1)
+            abstract = lambda tree: jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+            batch = engine._shard_batch(lm_batch())
+            with engine.mesh:
+                with record_collectives(apply_chaos=False) as rec:
+                    jax.make_jaxpr(fn)(abstract(engine.state),
+                                       abstract(batch))
+            return rec
+
+        rec = records(make_engine(overlap={}))
         assert any(r.op == "zero3_gather" for r in rec.records)
+        assert [(r.op, r.shape, r.axes) for r in rec.records] == \
+            [(r.op, r.shape, r.axes) for r in records(make_engine()).records]
         inj = ChaosInjector(seed=3, collective_mismatch=True)
         perturbed = inj.perturb_collectives(rec.records, rank=1)
         findings = diff_sequences({0: list(rec.records), 1: perturbed})
@@ -252,7 +184,8 @@ class TestEngineSchedules:
 
 
 # ---------------------------------------------------------------------------
-# THE acceptance: exposed comm measurably lower with overlap on than off
+# THE acceptance: the serial schedule exposes as comm spans what the default
+# fused step keeps inside one program
 # ---------------------------------------------------------------------------
 @pytest.mark.overlap
 class TestExposedCommDelta:
@@ -501,15 +434,12 @@ class TestStrictNoOp:
             assert "deepspeed_tpu.runtime.overlap" not in sys.modules
         finally:
             sys.modules.update(saved)
-        from deepspeed_tpu.models import common as mcommon
-
-        assert mcommon._LAYER_SCAN_IMPL is None
 
     def test_block_absent_step_is_byte_identical(self):
         """The compiled-step cache key contract: an engine without the
         block and one with ``enabled: false`` lower the EXACT same step
-        program (same HLO text), and ``layer_scan`` with nothing
-        installed traces identically to a direct ``lax.scan``."""
+        program (same HLO text), and ``layer_scan`` traces identically to
+        a direct ``lax.scan``."""
         import jax.numpy as jnp
 
         from deepspeed_tpu.models import common as mcommon
@@ -524,18 +454,33 @@ class TestStrictNoOp:
             lambda xs: jax.lax.scan(body, jnp.zeros(2), xs))(xs)
         assert str(j1) == str(j2)
 
-        def lowered(engine):
-            abstract = lambda tree: jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding), tree)
-            batch = engine._shard_batch(lm_batch())
-            with engine.mesh:
-                return engine._get_compiled_train_batch(1).lower(
-                    abstract(engine.state), abstract(batch)).as_text()
-
-        t_absent = lowered(make_engine())
-        t_disabled = lowered(make_engine(overlap={"enabled": False}))
+        t_absent = self._lowered(make_engine())
+        t_disabled = self._lowered(make_engine(overlap={"enabled": False}))
         assert t_absent == t_disabled
+
+    @staticmethod
+    def _lowered(engine):
+        abstract = lambda tree: jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), tree)
+        batch = engine._shard_batch(lm_batch())
+        with engine.mesh:
+            return engine._get_compiled_train_batch(1).lower(
+                abstract(engine.state), abstract(batch)).as_text()
+
+    @pytest.mark.parametrize("stage", [0, 2, 3])
+    def test_block_present_overlapped_step_is_the_step_without_it(self, stage):
+        """``schedule: "overlapped"`` names the engine's one fused step:
+        the block arms the scheduler preset and the async snapshot, and
+        the train step's text is the text without the block (at stage 3
+        the same stated gather; until PR 44 the block swapped it)."""
+        zero = {"stage": stage, "stage3_param_persistence_threshold": 0}
+        engine = make_engine(zero_optimization=zero, overlap={})
+        assert engine._overlap.schedule == "overlapped"
+        assert engine._layer_gathers is engine.plan.layer_gathers
+        assert (engine._layer_gathers is not None) == (stage == 3)
+        assert self._lowered(engine) == \
+            self._lowered(make_engine(zero_optimization=zero))
 
     def test_enabled_false_is_noop(self):
         engine = make_engine(overlap={"enabled": False})
@@ -543,21 +488,48 @@ class TestStrictNoOp:
         assert engine._overlap is None
 
     def test_unknown_key_rejected_with_hint(self):
-        with pytest.raises(ValueError, match="param_prefetch"):
-            make_engine(overlap={"param_prefetch_": 1})
+        with pytest.raises(ValueError, match="did you mean 'schedule'"):
+            make_engine(overlap={"schedul": "serial"})
+
+    @pytest.mark.parametrize("key,block", [
+        ("param_prefetch", {"overlap": {"param_prefetch": 1}}),
+        ("grad_reduce", {"overlap": {"grad_reduce": "post"}}),
+        ("remat_gather", {"overlap": {"remat_gather": False}}),
+        ("wire", {"wire": {"weight_quant_bits": 8}})])
+    def test_removed_keys_refused_at_parse(self, key, block):
+        """The prefetch ring's keys and the ``wire`` block that rode it
+        went at PR 44: a config that still sets one is refused when it is
+        parsed, by name, before any engine exists."""
+        from deepspeed_tpu.runtime.config import DeepSpeedConfig
+
+        with pytest.raises(ValueError, match=repr(key)):
+            DeepSpeedConfig(base_config(**block))
+        assert DeepSpeedConfig(base_config(overlap={})).overlap.model_dump() \
+            == {"enabled": True, "schedule": "overlapped",
+                "scheduler_flags": True, "async_checkpoint": True}
 
     def test_schema_cross_fields(self):
         from deepspeed_tpu.analysis.schema import walk_config
 
         findings, _ = walk_config(base_config(
-            zero_optimization={"stage": 1},
-            overlap={"param_prefetch": 2}), world_size=8)
-        assert any("param_prefetch" in f.message and f.severity == "warning"
-                   for f in findings)
-        findings, _ = walk_config(base_config(
             overlap={"schedule": "serial"}), world_size=8)
         assert any("telemetry" in f.citation and "overlap" in f.citation
                    for f in findings)
+        findings, _ = walk_config(base_config(
+            zero_optimization={"stage": 3, "offload_param": {"device": "cpu"}},
+            overlap={}), world_size=8)
+        assert any(f.citation == "overlap vs zero_optimization.offload_param"
+                   and "serial schedule" in f.message for f in findings)
+        # a removed key is an unknown key to the doctor too, and the two
+        # rules above are all the block has
+        findings, _ = walk_config(base_config(
+            zero_optimization={"stage": 1},
+            overlap={"param_prefetch": 2}), world_size=8)
+        assert any("param_prefetch" in f.message
+                   and f.rule == "config/unknown-key" for f in findings)
+        findings, _ = walk_config(base_config(
+            zero_optimization={"stage": 1}, overlap={}), world_size=8)
+        assert not [f for f in findings if "overlap" in f.citation]
         findings, _ = walk_config(base_config(
             overlap={"schedul": "serial"}), world_size=8)
         assert any("schedule" in f.message and f.rule == "config/unknown-key"
@@ -647,3 +619,18 @@ def test_serial_schedule_entry_prices_exposed_comm(tmp_path, tiny_ledger_run):
                                extra={"overlap": {"schedule": "serial"}})
     assert entry["mesh_axes"] == "data=4"
     assert entry["attribution"].get("exposed_comm_us_per_step", 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# what went with the ring
+# ---------------------------------------------------------------------------
+def test_the_wire_module_is_gone_and_nothing_names_it():
+    import importlib
+    import pathlib
+
+    with pytest.raises(ModuleNotFoundError):
+        importlib.import_module("deepspeed_tpu.runtime.wire")
+    root = pathlib.Path(deepspeed_tpu.__file__).parent
+    names = ("runtime.wire", "runtime/wire", "import wire")
+    assert not [str(p.relative_to(root)) for p in sorted(root.rglob("*.py"))
+                if any(n in p.read_text() for n in names)]

@@ -112,3 +112,57 @@ class TestInt8Serving:
         assert rel < 0.15, rel
         assert q_out.shape == ref_out.shape
         assert (q_out[:, :12] == ids).all()
+
+
+# ---------------------------------------------------------------------------
+# ops/quantizer.py — padded-group accounting (the satellite fix, pinned)
+# ---------------------------------------------------------------------------
+class TestQuantizerPadding:
+    def test_group_layout_pads_instead_of_collapsing(self):
+        from deepspeed_tpu.ops.quantizer import quant_group_layout
+
+        assert quant_group_layout(100, 64) == (64, 2, 128)
+        assert quant_group_layout(128, 64) == (64, 2, 128)
+        assert quant_group_layout(37, 16) == (16, 3, 48)
+        # group >= dim: one whole-dim group, nothing padded
+        assert quant_group_layout(48, 64) == (48, 1, 48)
+        assert quant_group_layout(48, 0) == (48, 1, 48)
+
+    def test_nbytes_bills_padded_wire_bytes(self):
+        """static_comm_bytes bills what actually crosses the wire: the
+        PADDED codes (+ scales), not the logical element count."""
+        from deepspeed_tpu.ops.quantizer import quantize_tensor
+
+        w = jnp.asarray(np.random.RandomState(0).randn(100, 8),
+                        jnp.float32)
+        qt = quantize_tensor(w, num_bits=8, group_size=64)
+        assert qt.q.shape == (2, 64, 8)          # 2 groups of 64, padded
+        assert qt.scale.shape == (2, 8)
+        assert qt.nbytes == 2 * 64 * 8 + 2 * 8 * 4
+        assert qt.nbytes > 100 * 8               # > logical int8 bytes
+
+    @pytest.mark.parametrize("shape,gs", [((100, 8), 64), ((37,), 16),
+                                          ((3, 100, 8), 32)])
+    def test_roundtrip_exact_shape_and_bounded_error(self, shape, gs):
+        from deepspeed_tpu.ops.quantizer import (dequantize_tensor,
+                                                 quantize_tensor)
+
+        w = jnp.asarray(np.random.RandomState(1).randn(*shape), jnp.float32)
+        qt = quantize_tensor(w, num_bits=8, group_size=gs)
+        back = dequantize_tensor(qt)
+        assert back.shape == w.shape
+        # per-group symmetric int8: |err| <= group absmax / 127 / 2 + round
+        bound = float(jnp.max(jnp.abs(w))) / 127.0 * 0.51 * 2
+        assert float(jnp.max(jnp.abs(back - w))) <= max(bound, 2e-2)
+
+    def test_int4_roundtrip_padded(self):
+        from deepspeed_tpu.ops.quantizer import (dequantize_tensor,
+                                                 quantize_tensor)
+
+        w = jnp.asarray(np.random.RandomState(2).randn(100, 4), jnp.float32)
+        qt = quantize_tensor(w, num_bits=4, group_size=64)
+        assert qt.q.shape == (2, 32, 4)          # nibble-packed, padded
+        back = dequantize_tensor(qt)
+        assert back.shape == w.shape
+        assert float(jnp.max(jnp.abs(back - w))) <= \
+            float(jnp.max(jnp.abs(w))) / 7.0 * 0.51 * 2 + 1e-3

@@ -2,8 +2,8 @@
 ``LayerGathers``, seated in models/common.py ``remat_wrap``): where a block
 uses a leaf that ZeRO sharded, the leaf is constrained to its spec without
 the DP axes and its cotangent back to the sharded spec. Placement must not
-change the mathematics; one chip, stage 0-2 and the overlap engine's ring
-must not meet the rule at all."""
+change the mathematics; one chip, stage 0-2 and the serial schedule's
+compute program must not meet the rule at all."""
 
 import jax
 import jax.numpy as jnp
@@ -65,10 +65,10 @@ def _gpt2_moe(**kw):
 def _llama(**kw):
     from deepspeed_tpu.models.llama import LlamaConfig, LlamaModel
 
-    return LlamaModel(LlamaConfig(
+    return LlamaModel(LlamaConfig(**{**dict(
         vocab_size=VOCAB, n_positions=32, n_embd=32, n_layer=2, n_head=4,
         intermediate_size=64, dtype=jnp.float32, remat="attn",
-        use_flash_attention=False, **kw))
+        use_flash_attention=False), **kw}))
 
 
 def _bert(**kw):
@@ -119,6 +119,45 @@ CASES = {
                                {"qkv_w", "proj_w", "fc_w", "fc2_w"},
                                {"wi", "wo"}),
     "bert-data4": (_bert, {}, {"data": 4}, None, set()),
+    # the stacks PR 31-41 added. A layer pattern: what every layer has in
+    # ``blocks``, each mixer's leaves in a stack of its own
+    "llama-kda-pattern-data4": (
+        _llama, {"gqa_layers": (1,), "kda_heads": 2,
+                 "kda_head_dim": 16, "use_rope": False, "attn_gate": True},
+        {"data": 4},
+        {"q_w", "attn_gate_w", "kda_qkv_w", "gate_w", "down_w"},
+        {"attn_norm_g", "kda_conv_w"}),
+    # window and full softmax layers behind a leading dense layer
+    "llama-window-dense-lead-data4": (
+        _llama, {"n_layer": 3, "n_experts": 4, "n_experts_per_tok": 2,
+                 "n_dense_layers": 1, "dense_intermediate_size": 96,
+                 "layer_types": ("sliding_attention", "full_attention",
+                                 "sliding_attention"),
+                 "sliding_window": 4, "global_rope": False,
+                 "qk_norm": "head"},
+        {"data": 4},
+        {"q_w", "o_w", "gate_w", "expert_gate_w", "expert_down_w"},
+        {"q_norm_g"}),
+    # latent attention whose queries come straight from q_w
+    "llama-latent-direct-q-data4": (
+        _llama, {"kv_lora_rank": 32, "qk_nope_head_dim": 8,
+                 "qk_rope_head_dim": 8, "v_head_dim": 8},
+        {"data": 4}, {"q_w", "kv_a_w", "kv_b_k_w", "kv_b_v_w", "o_w"},
+        {"kv_a_norm_g"}),
+    "llama-shared-expert-data2-expert2": (
+        _llama, {"n_experts": 4, "n_experts_per_tok": 2,
+                 "n_shared_experts": 1, "router_scoring": "sigmoid",
+                 "norm_topk_prob": True},
+        {"data": 2, "expert": 2},
+        {"shared_gate_w", "shared_up_w", "shared_down_w", "expert_up_w"},
+        {"router_w"}),
+    # a chip's share of the experts: the held leaves are (count, ...) wide
+    "llama-experts-held-data4": (
+        _llama, {"n_experts": 8, "n_experts_per_tok": 2,
+                 "experts_held": (2, 4), "router_scoring": "sigmoid"},
+        {"data": 4},
+        {"expert_gate_w", "expert_up_w", "expert_down_w", "q_w"},
+        {"router_w"}),
 }
 
 
@@ -161,7 +200,10 @@ def test_loss_and_gradients_under_the_rule_equal_one_device(case):
             out_shardings=(NamedSharding(mesh, P()), plan.grad_shardings()))(
                 params, batch)
     # the forward names each sharded leaf of the block once a block traced
-    assert n >= len(rule.leaves) and n % len(rule.leaves) == 0, n
+    # (a model of several stacks traces a block a kind of layer, each with
+    # the leaves every layer has and its own)
+    assert n >= len(rule.leaves), n
+    assert len(stacked_param_keys(model)) > 1 or n % len(rule.leaves) == 0, n
 
     np.testing.assert_allclose(got_loss, want_loss, rtol=2e-4, atol=2e-5)
     flat, _ = jax.tree_util.tree_flatten_with_path(want)
@@ -214,12 +256,12 @@ def test_a_region_that_is_already_manual_is_left_alone():
 
 
 # ------------------------------------------------------------- the engine
-def _engine(stage=3, **over):
+def _engine(stage=3, model=None, **over):
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 
     cfg = GPT2Config(vocab_size=256, n_positions=32, n_embd=32, n_layer=2,
                      n_head=2, remat="attn", use_flash_attention=False)
-    engine, *_ = deepspeed_tpu.initialize(model=GPT2Model(cfg), config={
+    engine, *_ = deepspeed_tpu.initialize(model=model or GPT2Model(cfg), config={
         "train_batch_size": 8,
         "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
         "zero_optimization": {"stage": stage,
@@ -270,20 +312,73 @@ def test_stages_under_3_trace_no_gather(stage):
 
 @pytest.mark.parametrize("overlap", [{}, {"schedule": "serial"}])
 def test_with_the_overlap_block_no_leaf_is_gathered_twice(overlap):
-    """The prefetch ring (and the serial schedule's phase) gather the layer
-    stack themselves, through the same pair: the default rule stays off, so
-    every gather of the trace is the ring's."""
+    """There is one place a layer's sharded weight is gathered. With the
+    block and the fused step it is the rule's, as without the block; the
+    serial schedule's phase has gathered the whole tree before its compute
+    program runs, so there the rule is off and the trace states no gather."""
     engine = _engine(overlap=dict(overlap, scheduler_flags=False,
                                   async_checkpoint=False))
     jaxpr, records = _step_trace(engine)
-    assert engine._layer_gathers is None
-    assert not any(r.site.startswith("partition.py") for r in records)
-    if not overlap:
-        # the ring's gather (one checkpointed function, traced once): each
-        # sharded leaf of the stack, and nothing else
-        assert sorted(r.shape for r in records) == [
-            (32, 32), (32, 96), (32, 128), (128, 32)]
-        assert all(r.site.startswith("overlap.py") for r in records)
+    if overlap:
+        assert engine._layer_gathers is None
+        assert not records and not _gathered(jaxpr)
+        return
+    assert engine._layer_gathers is engine.plan.layer_gathers
+    assert sorted(r.shape for r in records) == [
+        (32, 32), (32, 96), (32, 128), (128, 32)]
+    assert all(r.site.startswith("partition.py") for r in records)
+
+
+def _routed_llama():
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaModel
+
+    return LlamaModel(LlamaConfig(
+        vocab_size=256, n_positions=32, n_embd=32, n_layer=3, n_head=2,
+        intermediate_size=64, n_experts=4, n_experts_per_tok=2,
+        n_dense_layers=1, remat="attn", use_flash_attention=False))
+
+
+@pytest.mark.parametrize("gas", [1, 4])
+@pytest.mark.parametrize("family", ["gpt2", "llama-routed"])
+def test_promise_vs_actual_sharding_of_the_default_step(family, gas):
+    """8-way promise-vs-actual of the step every stage-3 job runs: each
+    materialized leaf sits at the plan's placement (params, fp32 master,
+    optimizer moments) and is still there after a step that gathers the
+    layers' weights on use, with and without an accumulation scan, over
+    one stack (gpt2) and two (``dense_blocks`` ahead of ``blocks``)."""
+    from deepspeed_tpu.models.gpt2 import synthetic_lm_batch
+
+    model = _routed_llama() if family == "llama-routed" else None
+    engine = _engine(bf16={"enabled": True}, train_batch_size=8 * gas,
+                     gradient_accumulation_steps=gas,
+                     **({"model": model} if model else {}))
+    assert engine._layer_gathers is engine.plan.layer_gathers is not None
+    plan = engine.plan
+    assert plan.dp_axes == ("data",)
+
+    def check(tree, specs):
+        leaves = jax.tree.leaves(tree)
+        spec_leaves = jax.tree.leaves(specs,
+                                      is_leaf=lambda x: isinstance(x, P))
+        assert len(leaves) == len(spec_leaves)
+        for leaf, spec in zip(leaves, spec_leaves):
+            assert leaf.sharding.spec == spec, \
+                f"promised {spec}, actual {leaf.sharding.spec}"
+
+    for stepped in (False, True):   # as materialized, as a step hands it back
+        if stepped:
+            assert np.isfinite(float(engine.train_batch(
+                synthetic_lm_batch(8 * gas, 32, 256))))
+        check(engine.state.params, plan.param_specs)
+        check(engine.state.master, plan.master_specs)
+        moments = [x for x in engine.state.opt_state if isinstance(x, dict)]
+        assert len(moments) == 2                        # AdamW's mu and nu
+        for tree in moments:
+            check(tree, plan.master_specs)
+    # the promise is real: the stacks' weights are dp-sharded as params
+    sharded = [l for l in jax.tree.leaves(engine.state.params)
+               if "data" in str(l.sharding.spec)]
+    assert len(sharded) >= len(plan.layer_gathers.leaves)
 
 
 def test_one_chip_traces_the_parents_step():

@@ -1,0 +1,2 @@
+"""``tpot.pass_device_p50_s``: read by ``benchmark/sdar_metrics.py``."""
+from benchmark.sdar_metrics import pass_device as read  # noqa: F401

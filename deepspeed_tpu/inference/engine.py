@@ -17,6 +17,20 @@ _generate :619). TPU-native:
 Model protocol: init_params(rng), init_cache(B, max_len), prefill(params,
 ids, cache) → (logits, cache), decode_step(params, token, cache) →
 (logits, cache), param_partition_specs(), cache_partition_specs().
+
+A STEP of the decode loop emits ``n >= 1`` tokens a row. The autoregressive
+step (``_decode_scan_step``) emits one: ``decode_step`` on the last token,
+then the next. A model that generates by diffusion over blocks says so
+(``block_decoding``: a ``models/common.py::BlockDecoding``) and has
+``block_step(params, tokens, masked, cache, commit=)``; its step
+(``_block_scan_step``) emits a BLOCK: up to ``steps`` forward passes over
+the same ``length`` positions, each unmasking some of them, then one pass
+that commits the finished block's keys and values (one loop, the model in
+its body once). Everything around the
+step is one code: the prefill program ends in the FIRST emission (one
+token; or the first block, which opens with what the prompt left over of a
+block), ``generate()`` and the serving chunk scan steps and count tokens by
+what the steps return.
 """
 
 from __future__ import annotations
@@ -72,8 +86,7 @@ def build_generate_fn(module, max_new_tokens: int, do_sample: bool,
     def gen(params, ids, rng):
         if param_transform is not None:
             params = param_transform(params)
-        tok, cache, done, rng = prefill(params, ids, rng)
-        return decode(params, ids, tok, cache, done, rng)
+        return decode(params, ids, *prefill(params, ids, rng))
 
     return gen
 
@@ -136,12 +149,160 @@ def _decode_scan_step(module, params, sampling):
     return step
 
 
+def step_tokens(module) -> int:
+    """Tokens a row one step of ``module``'s decode loop emits: 1, or the
+    block length of a model that generates by diffusion over blocks."""
+    dec = getattr(module, "block_decoding", None)
+    return 1 if dec is None else int(dec.length)
+
+
+def _transfer_counts(dec) -> tuple:
+    """Positions each of a block's denoising passes unmasks at least:
+    ``length // steps``, one more in the first ``length % steps``."""
+    base, more = divmod(dec.length, dec.steps)
+    return tuple(base + (s < more) for s in range(dec.steps))
+
+
+def block_passes(dec, given: int = 0) -> int:
+    """Denoising passes a block needs when every pass unmasks exactly its
+    count (the two static rules), ``given`` of its positions not masked."""
+    left, passes = dec.length - given, 0
+    for n in _transfer_counts(dec):
+        if left <= 0:
+            break
+        left, passes = left - n, passes + 1
+    return passes
+
+
+def _unmask(conf, masked, n, dec):
+    """Which of a block's ``masked`` (B, Lb) positions a pass unmasks, by
+    ``dec.remasking`` from the confidences ``conf`` (B, Lb) of the tokens
+    chosen there and the pass's count ``n`` (traced): ``n`` of them (all
+    that are left if fewer), ties to the left; the dynamic rule every one
+    above the threshold where those are at least ``n``. Never a position
+    that is not masked."""
+    if dec.remasking == "sequential":
+        return masked & (jnp.cumsum(masked, axis=1) <= n)
+    conf = jnp.where(masked, conf, -jnp.inf)
+    # a position's rank by confidence: those above it, and its equals to
+    # the left
+    at = jnp.arange(conf.shape[1])
+    above = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None]) & (at[None, :] < at[:, None]))
+    top = masked & (jnp.sum(above, axis=2) < n)
+    if dec.remasking == "low_confidence_static":
+        return top
+    high = masked & (conf > dec.threshold)
+    return jnp.where(jnp.sum(high, axis=1, keepdims=True) >= n, high, top)
+
+
+def _denoise_and_commit(module, params, tokens, masked, cache, rng, sampling,
+                        dec, given: int):
+    """A block's passes, ONE loop whose body holds the model once: each pass
+    ``module.block_step`` over the block as it stands; while a mask is left
+    a token is chosen at every position (the largest logit, or ``do_sample``
+    the sampling head) with its confidence (the softmax's probability of
+    it) and :func:`_unmask` moves some in; the pass that finds no mask left
+    COMMITS the block (its keys and values stay, ``pos`` advances) and ends
+    the loop. The static rules run ``block_passes`` denoising passes and the
+    commit, a fixed trip; the dynamic one until the commit. -> (tokens,
+    cache, rng); nothing a denoising pass writes outlives the next pass
+    over the block, which writes the same slots."""
+    from deepspeed_tpu.telemetry.scopes import scope
+
+    do_sample, temperature, top_k, top_p, _ = sampling
+    counts = jnp.asarray(_transfer_counts(dec) + (0,), jnp.int32)
+
+    def one_pass(s, commit, tokens, masked, cache, rng):
+        logits, cache = module.block_step(params, tokens, masked, cache,
+                                          commit=commit)
+        with scope("head/unmask"):
+            if do_sample:
+                rng, sub = jax.random.split(rng)
+                x0 = _sample(logits, sub, temperature, top_k, top_p)
+            else:
+                x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            conf = jnp.exp(
+                jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
+                - jax.scipy.special.logsumexp(logits, axis=-1))
+            # (a committing pass finds nothing masked and moves nothing)
+            move = _unmask(conf, masked, counts[jnp.minimum(s, dec.steps)],
+                           dec)
+            return jnp.where(move, x0, tokens), masked & ~move, cache, rng
+
+    if dec.remasking == "low_confidence_dynamic":
+        def body(c):
+            s, committed, *state = c
+            commit = ~jnp.any(state[1])
+            return (s + 1, commit, *one_pass(s, commit, *state))
+
+        _, _, tokens, _, cache, rng = jax.lax.while_loop(
+            lambda c: ~c[1], body,
+            (jnp.int32(0), jnp.bool_(False), tokens, masked, cache, rng))
+    else:
+        n = block_passes(dec, given)
+        tokens, _, cache, rng = jax.lax.fori_loop(
+            0, n + 1, lambda s, c: one_pass(s, s == n, *c),
+            (tokens, masked, cache, rng))
+    return tokens, cache, rng
+
+
+def _block_scan_step(module, params, sampling, dec):
+    """One BLOCK of the decode loop, over the same carry ``(tok, cache,
+    done, rng)`` as :func:`_decode_scan_step`: the block all masked, but for
+    the ``opens`` (B, given) tokens it is handed (what a prompt left over of
+    a block: the prefill program's step alone), denoised and committed
+    (:func:`_denoise_and_commit`). Emits the block's NEW tokens (B, length -
+    given); a row holds its EOS token from the first one chosen on
+    (``done``), as the autoregressive step's rows do. ``tok``, the last
+    token emitted, is carried for the carry's sake: a block step reads
+    nothing of it."""
+    eos = sampling[-1]
+
+    def step(carry, opens=None):
+        _, cache, done, rng = carry
+        B = done.shape[0]
+        given = 0 if opens is None else opens.shape[1]
+        tokens = jnp.zeros((B, dec.length), jnp.int32)
+        if given:
+            tokens = tokens.at[:, :given].set(opens.astype(jnp.int32))
+        masked = jnp.broadcast_to(jnp.arange(dec.length) >= given,
+                                  tokens.shape)
+        tokens, cache, rng = _denoise_and_commit(
+            module, params, tokens, masked, cache, rng, sampling, dec, given)
+        new = tokens[:, given:]
+        ended = done[:, None] | (jnp.cumsum(new == eos, axis=1)
+                                 - (new == eos) > 0)
+        new = jnp.where(ended, jnp.int32(max(eos, 0)), new)
+        done = done | jnp.any(new == eos, axis=1)
+        return (new[:, -1], cache, done, rng), new
+
+    return step
+
+
+def _scan_step(module, params, sampling):
+    """The decode loop's scan body over ``(tok, cache, done, rng)``: the
+    model's kind of step (it emits :func:`step_tokens` tokens a row)."""
+    dec = getattr(module, "block_decoding", None)
+    if dec is None:
+        return _decode_scan_step(module, params, sampling)
+    return _block_scan_step(module, params, sampling, dec)
+
+
 def _prefill_program(module, cache_len, sampling, param_transform,
                      cache_shardings):
     """``prefill(params, ids, rng) -> (tok, cache, done, rng)`` over a cache
     of ``cache_len(T)`` slots: the prompt's pass and the FIRST token
     (:func:`_next_token`, as every decode step), so whoever runs it holds a
-    token when it returns. ``sampling``: :func:`_sampling`'s."""
+    token when it returns. ``sampling``: :func:`_sampling`'s.
+
+    For a model that generates by diffusion over blocks the prefill chooses
+    nothing: ``prefill(params, ids, rng) -> (tok, cache, done, rng, first)``
+    runs the prompt's WHOLE blocks through ``module.prefill`` (under the
+    model's block-causal mask), then the first block step, which opens with
+    the ``T % length`` tokens the prompt left over; ``first`` (B, length - T
+    % length) are the first tokens that exist."""
+    dec = getattr(module, "block_decoding", None)
 
     def prefill(params, ids, rng):
         if param_transform is not None:
@@ -151,12 +312,26 @@ def _prefill_program(module, cache_len, sampling, param_transform,
         cc = _resolve_cache_shardings(module, cache_shardings)
         if cc is not None:
             cache = jax.lax.with_sharding_constraint(cache, cc)
+        if dec is not None:
+            whole = T - T % dec.length
+            if whole:
+                _, cache = module.prefill(params, ids[:, :whole], cache)
+            carry, first = _block_scan_step(module, params, sampling, dec)(
+                (None, cache, jnp.zeros((B,), jnp.bool_), rng),
+                ids[:, whole:])
+            return (*carry, first)
         logits, cache = module.prefill(params, ids, cache)
         tok, done, rng = _next_token(logits, jnp.zeros((B,), jnp.bool_), rng,
                                      *sampling)
         return tok, cache, done, rng
 
     return prefill
+
+
+def _blocks_after_first(dec, prompt_len: int, max_new_tokens: int) -> int:
+    """Block steps ``max_new_tokens`` need after the prefill program's."""
+    owed = max_new_tokens - (dec.length - prompt_len % dec.length)
+    return max(0, -(-owed // dec.length))
 
 
 def build_generate_parts(module, max_new_tokens: int, do_sample: bool,
@@ -173,24 +348,44 @@ def build_generate_parts(module, max_new_tokens: int, do_sample: bool,
     ``max_new_tokens - 1`` steps the other tokens need and returns the ids
     with all of them appended. ``param_transform`` (dequant / offload
     stream-in) runs inside each program, so numerics match the fused path
-    exactly."""
+    exactly. A model that generates by diffusion over blocks
+    (``block_decoding``): the prefill hands over its first BLOCK as a fifth
+    value, ``decode`` takes it as a seventh argument, scans the block steps
+    the other tokens need and cuts the last block to ``max_new_tokens``."""
     if max_new_tokens < 1:
         raise ValueError(f"max_new_tokens {max_new_tokens}: the prefill "
                          "already chooses the first token")
     sampling = _sampling(do_sample, temperature, top_k, top_p, eos_token_id)
+    dec = getattr(module, "block_decoding", None)
 
-    def decode(params, ids, tok, cache, done, rng):
+    def decode(params, ids, tok, cache, done, rng, first=None):
         if param_transform is not None:
             params = param_transform(params)
+        step = _scan_step(module, params, sampling)
+        if dec is not None:
+            T = ids.shape[1]
+            _, toks = jax.lax.scan(
+                step, (tok, cache, done, rng), None,
+                length=_blocks_after_first(dec, T, max_new_tokens))
+            # (steps, B, length) -> (B, steps x length)
+            rest = toks.transpose(1, 0, 2).reshape(ids.shape[0], -1)
+            return jnp.concatenate(
+                [ids, first.astype(ids.dtype), rest.astype(ids.dtype)],
+                axis=1)[:, :T + max_new_tokens]
         _, toks = jax.lax.scan(
-            _decode_scan_step(module, params, sampling),
-            (tok, cache, done, rng), None, length=max_new_tokens - 1)
+            step, (tok, cache, done, rng), None, length=max_new_tokens - 1)
         return jnp.concatenate(
             [ids, tok[:, None].astype(ids.dtype), toks.T.astype(ids.dtype)],
             axis=1)
 
-    return _prefill_program(module, lambda T: T + max_new_tokens, sampling,
-                            param_transform, cache_shardings), decode
+    def cache_len(T):
+        if dec is None:
+            return T + max_new_tokens
+        return T - T % dec.length + dec.length * (
+            1 + _blocks_after_first(dec, T, max_new_tokens))
+
+    return _prefill_program(module, cache_len, sampling, param_transform,
+                            cache_shardings), decode
 
 
 def build_serving_programs(module, max_total_len: int, chunk_tokens: int,
@@ -209,21 +404,35 @@ def build_serving_programs(module, max_total_len: int, chunk_tokens: int,
     per request. Both end in :func:`_next_token`, in ``generate()``'s order
     of key splits (one for the first token, then one a step), so a request
     served through the front-end emits exactly the tokens ``generate()``
-    would."""
+    would. A model whose step emits a BLOCK of ``n`` tokens
+    (:func:`step_tokens`): ``chunk_tokens`` is a whole number of blocks, a
+    chunk scans ``chunk_tokens // n`` block steps, and the prefill returns a
+    fifth value, the tokens of the first block (what the front-end
+    delivers when the prefill tick returns: the first tokens that exist)."""
     sampling = _sampling(do_sample, temperature, top_k, top_p, eos_token_id)
+    n = step_tokens(module)
+    if chunk_tokens % n:
+        raise ValueError(
+            f"decode_tick_tokens {chunk_tokens}: a whole number of the "
+            f"{n}-token blocks a step of this model emits")
 
     def decode_chunk(params, tok, cache, done, rng):
         if param_transform is not None:
             params = param_transform(params)
         (tok, cache, done, rng), toks = jax.lax.scan(
-            _decode_scan_step(module, params, sampling),
-            (tok, cache, done, rng), None, length=chunk_tokens)
+            _scan_step(module, params, sampling), (tok, cache, done, rng),
+            None, length=chunk_tokens // n)
         # (B, chunk) int32 — rows past their EOS hold the EOS token, same
         # post-EOS convention as generate()
+        if n > 1:               # (steps, B, n) -> (B, steps x n)
+            return tok, cache, done, rng, toks.transpose(1, 0, 2).reshape(
+                toks.shape[1], -1)
         return tok, cache, done, rng, toks.T
 
-    return _prefill_program(module, lambda T: max_total_len, sampling,
-                            param_transform, cache_shardings), decode_chunk
+    # the last block of the longest request ends inside the allocation
+    return _prefill_program(module, lambda T: -(-max_total_len // n) * n,
+                            sampling, param_transform,
+                            cache_shardings), decode_chunk
 
 
 def _served_as_given(params, shardings, dtype) -> bool:
@@ -504,18 +713,21 @@ class InferenceEngine:
             params_in = self._params_in_shardings()
             cache_io = cache_sh if cache_sh is not None else INHERIT
             repl = self.sharding.replicated()
+            # a block-diffusion model's prefill hands over its first block too
+            first = (INHERIT,) * (step_tokens(self.module) > 1)
             self._compiled[key] = (
                 sharded_jit(pf, label=f"inference/prefill[new={max_new_tokens}]",
                             donate_argnums=(), mesh=self.mesh,
                             in_shardings=(params_in, ids_sh, repl),
-                            out_shardings=(INHERIT, cache_io, INHERIT, repl),
+                            out_shardings=(INHERIT, cache_io, INHERIT, repl)
+                            + first,
                             meta={"params_argnum": 0}),
                 sharded_jit(df, label=f"inference/decode[new={max_new_tokens}]",
                             # the cache is dead after the decode consumes it —
                             # donating it avoids a second live KV buffer
                             donate_argnums=(3,), mesh=self.mesh,
                             in_shardings=(params_in, ids_sh, INHERIT,
-                                          cache_io, INHERIT, repl),
+                                          cache_io, INHERIT, repl) + first,
                             out_shardings=ids_sh,
                             meta={"params_argnum": 0, "cache_argnum": 3}))
         pf, df = self._compiled[key]
@@ -524,14 +736,14 @@ class InferenceEngine:
         t0 = time.perf_counter()
         with self.mesh:
             with tracer.span("prefill", cat="inference", tokens=int(ids.shape[1])):
-                tok, cache, done, rng = pf(self.params, ids, rng)
-                jax.block_until_ready(tok)
+                carried = pf(self.params, ids, rng)
+                jax.block_until_ready(carried[0])
             ttft = time.perf_counter() - t0
             t1 = time.perf_counter()
             # the first token came with the prefill: the scan makes the rest
             with tracer.span("decode", cat="inference",
                              tokens=int(max_new_tokens) - 1):
-                out = df(self.params, ids, tok, cache, done, rng)
+                out = df(self.params, ids, *carried)
                 jax.block_until_ready(out)
             decode_s = time.perf_counter() - t1
         total = time.perf_counter() - t0

@@ -8,6 +8,7 @@ is never materialized — at V≈50k that is multiple GB per microbatch.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import math
@@ -27,6 +28,18 @@ from deepspeed_tpu.ops.pallas import SAVED_KDA_STATES, SAVED_LSE, SAVED_O
 _CHUNK_ELEMS = 64 * 1024 * 1024
 
 NEG_INF_ATTN = -1e30
+
+# How a model that generates by diffusion over blocks denoises a block (the
+# model protocol's ``block_decoding``; the inference engine runs it): the
+# block's ``length``, the most forward ``steps`` over it, which masked
+# positions a pass unmasks — ``sequential``: the leftmost;
+# ``low_confidence_static``: those whose chosen token is the most probable;
+# ``low_confidence_dynamic``: every one above ``threshold`` if they are at
+# least the step's count, else as the static rule — and the ``mask_token_id``
+# a position not yet chosen is read as.
+BlockDecoding = collections.namedtuple(
+    "BlockDecoding", "length steps remasking threshold mask_token_id")
+REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
 
 def layer_scan(body, init, xs, unroll: int = 1):
@@ -237,7 +250,7 @@ def check_flash_block(block):
 
 def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                            causal: bool = True, key_padding_mask=None,
-                           flash_block=None, window=None):
+                           flash_block=None, window=None, block=None):
     """Self-attention on local (unsharded-sequence) q, k, v with equal head
     counts (B, T, H, Dh) — v's head size may differ from q's and k's (latent
     attention: q.k at 192 columns, v at 128): the Pallas flash kernel on
@@ -262,9 +275,19 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     backward). It may also be a TRACED scalar so one scanned layer loop can
     mix global and local layers, <=0 meaning global: a kernel's plan is
     static, so that form takes the einsum path.
+    ``block``: optional Python int > 1, the BLOCK-causal mask of a model that
+    generates by diffusion over blocks: position i attends to j with ``j //
+    block <= i // block`` (its own block whole, every earlier one). Forward
+    only, no window beside it; the flash kernel carries it where the block
+    is a power of two that divides 128 and the length
+    (``flash_attention(block=)``), the einsum otherwise.
     """
     static_window = window is None or (
         isinstance(window, int) and window > 0 and causal)
+    if block is not None and block <= 1:
+        block = None
+    if block is not None and (window is not None or not causal):
+        raise ValueError("a block-causal mask takes no window beside it")
     if use_flash and alibi is None and key_padding_mask is None \
             and static_window:
         mesh, on_tpu = _kernel_target()
@@ -273,12 +296,16 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
 
             kw = ({"block_q": int(flash_block), "block_k": int(flash_block)}
                   if flash_block else {})
-            if fa.flash_supports(q.shape[1], k.shape[1], causal, **kw):
+            masked = {} if block is None else {"block": block}
+            if fa.flash_supports(q.shape[1], k.shape[1], causal, **kw) and (
+                    block is None or (fa.block_mask_supports(block)
+                                      and q.shape[1] % block == 0)):
                 batch, heads = _attn_axes(mesh, q.shape[0], q.shape[2])
                 spec = P(batch, None, heads, None)
                 return _kernel_on_mesh(
                     lambda q, k, v: fa.flash_attention(
-                        q, k, v, causal=causal, window=window, **kw),
+                        q, k, v, causal=causal, window=window, **kw,
+                        **masked),
                     mesh, (q, k, v), (spec, spec, spec), spec)
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
@@ -287,7 +314,9 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
         logits = logits + (alibi[None, :, None, None]
                            * jnp.arange(T, dtype=jnp.float32)[None, None, None, :])
     if causal:
-        mask = jnp.tril(jnp.ones((T, T), jnp.bool_))
+        at = jnp.arange(T)
+        mask = jnp.tril(jnp.ones((T, T), jnp.bool_)) if block is None \
+            else at[None, :] // block <= at[:, None] // block
         logits = jnp.where(mask[None, None], logits, NEG_INF_ATTN)
     if window is not None:
         assert causal, "windowed attention is causal-only"
@@ -415,6 +444,11 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     (GQA); ``alibi``: optional (H,) slopes (key-position bias); ``window``:
     optional traced sliding window (GPT-Neo). → (B, H, Dh).
 
+    q may also be (B, Lb, H, Dh): the queries of ``Lb`` positions that ALL
+    see slots ``0 .. pos`` and nothing else (a block-diffusion step's
+    block, which lies in the last ``Lb`` of those slots: no mask among its
+    positions); → (B, Lb, H, Dh). Neither a bias nor a window goes with it.
+
     The path is chosen the way ``local_causal_attention`` chooses flash:
     the Pallas streaming kernel (ops/pallas/decode_attention.py), which
     reads only slots ``0..pos``, where the program is for a TPU and the
@@ -423,23 +457,17 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     against. A kernel that does not lower is an error, not a reason to run
     something else.
     """
+    if q.ndim == 4:
+        if alibi is not None or window is not None:
+            raise ValueError("a block of query positions takes neither a "
+                             "bias nor a window")
+        return _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv)
     B, H, Dh = q.shape
     if alibi is None and window is None:
         mesh, on_tpu = _kernel_target()
         if on_tpu:
-            from deepspeed_tpu.ops.pallas.decode_attention import \
-                decode_attention
-
-            batch, heads = _attn_axes(mesh, B, n_kv)
-            if (n_kv * Dh) % KV_LANES:
-                heads = None        # padded rows stay whole (see the specs)
-            local_kv = n_kv // (mesh.shape[heads] if heads else 1)
-            cache_spec = P(None, batch, None, heads)
-            return _kernel_on_mesh(
-                functools.partial(decode_attention, n_kv=local_kv), mesh,
-                (q, k_cache, v_cache, layer, pos),
-                (P(batch, heads, None), cache_spec, cache_spec, P(), P()),
-                P(batch, heads, None))
+            return _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer,
+                                          pos, n_kv)
     S = k_cache.shape[2]
     layer_of = lambda c: jax.lax.dynamic_index_in_dim(
         c, layer, 0, keepdims=False)[..., :n_kv * Dh].reshape(B, S, n_kv, Dh)
@@ -459,6 +487,45 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     s = jnp.where(valid, s, NEG_INF_ATTN)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bgrk,bkgd->bgrd", p, v_l).reshape(B, H, Dh)
+
+
+def _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos, n_kv: int):
+    """``decode_attn`` (ops/pallas/decode_attention.py) on q (B, H, Dh) or
+    (B, Lb, H, Dh), from a program compiled over ``mesh``."""
+    from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
+
+    batch, heads = _attn_axes(mesh, q.shape[0], n_kv)
+    if (n_kv * q.shape[-1]) % KV_LANES:
+        heads = None        # padded rows stay whole (see the specs)
+    local_kv = n_kv // (mesh.shape[heads] if heads else 1)
+    cache_spec = P(None, batch, None, heads)
+    q_spec = P(batch, *([None] * (q.ndim - 3)), heads, None)
+    return _kernel_on_mesh(
+        functools.partial(decode_attention, n_kv=local_kv), mesh,
+        (q, k_cache, v_cache, layer, pos),
+        (q_spec, cache_spec, cache_spec, P(), P()), q_spec)
+
+
+def _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv: int):
+    """``cached_decode_attention`` for q (B, Lb, H, Dh): the kernel where
+    the program is for a TPU, else its einsum twin (and test reference):
+    every one of the ``Lb`` positions over slots ``0 .. pos``."""
+    B, Lb, H, Dh = q.shape
+    mesh, on_tpu = _kernel_target()
+    if on_tpu:
+        return _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos,
+                                      n_kv)
+    S = k_cache.shape[2]
+    layer_of = lambda c: jax.lax.dynamic_index_in_dim(
+        c, layer, 0, keepdims=False)[..., :n_kv * Dh].reshape(B, S, n_kv, Dh)
+    k_l, v_l = layer_of(k_cache), layer_of(v_cache)
+    qg = q.reshape(B, Lb, n_kv, H // n_kv, Dh)
+    s = jnp.einsum("blgrd,bkgd->bglrk", qg, k_l).astype(jnp.float32) \
+        / math.sqrt(Dh)
+    s = jnp.where((jnp.arange(S) <= pos)[None, None, None, None], s,
+                  NEG_INF_ATTN)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bglrk,bkgd->blgrd", p, v_l).reshape(B, Lb, H, Dh)
 
 
 def latent_decode_attention(q, cache, layer, pos, v_width: int, scale: float):

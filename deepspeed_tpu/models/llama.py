@@ -100,7 +100,21 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
     (a block that holds ``router_bias``: ``route_topk(bias=)``), which no
     gradient and no optimizer moves: the model names it to the engine
     (``ruled_leaves``) with the rule that does (``apply_rule``: aux-loss-free
-    balancing from the step's routing counts, ``loss_and_aux``).
+    balancing from the step's routing counts, ``loss_and_aux``);
+  - **generation by diffusion over blocks** (``block_length`` > 1; SDAR):
+    the attention mask is BLOCK-causal (position i sees j where ``j //
+    block_length <= i // block_length``: ``local_causal_attention(block=)``,
+    the flash forward's ``block=`` on a TPU) in the trunk and in
+    ``prefill``, and beside ``decode_step`` the model has ``block_step``:
+    ``block_length`` positions at once, their rows written into slots ``pos
+    .. pos + Lb - 1`` and attended, all of them, over slots ``0 .. pos + Lb
+    - 1`` (``cached_decode_attention`` with a block of query positions: the
+    decode kernel's multi-row form), the logits row of position i
+    predicting the token AT i. ``pos`` advances only when a step COMMITS
+    its block. How a block is denoised (``denoising_steps``, ``remasking``,
+    ``confidence_threshold``, ``mask_token_id``) is the configuration's and
+    the inference engine's (``inference/engine.py``: ``block_decoding``).
+    Softmax GQA layers only: a KDA, window or latent layer is refused.
 
 Implements the same model protocol as GPT2Model (init_params, loss, apply,
 prefill/decode_step, partition specs), so ``initialize()``,
@@ -204,6 +218,18 @@ class LlamaConfig:
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
+    # generation by diffusion over blocks (0: autoregressive): a step yields
+    # ``block_length`` tokens after up to ``denoising_steps`` (0: as many as
+    # the block is long) forward passes over the block, every position not
+    # yet chosen the ``mask_token_id`` (None: the vocabulary's last id); a
+    # pass unmasks by ``remasking`` (common.REMASKING: the leftmost, the most
+    # confident, or every one above ``confidence_threshold``), at least
+    # ``block_length // denoising_steps`` positions
+    block_length: int = 0
+    denoising_steps: int = 0
+    remasking: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: Optional[int] = None
     dtype: Any = jnp.bfloat16
     # what init_params draws in: a server that holds bf16 weights asks for
     # them as such, so no float32 copy of a 8.6 GB expert leaf ever exists
@@ -312,6 +338,30 @@ class LlamaConfig:
                 (self.router_bias and not self.n_experts):
             raise ValueError(f"qk_norm={self.qk_norm!r} (False | True | "
                              "'head'); router_bias is a routed model's")
+        if self.block_length:
+            from deepspeed_tpu.models.common import REMASKING
+
+            self.denoising_steps = self.denoising_steps or self.block_length
+            if self.mask_token_id is None:
+                self.mask_token_id = self.vocab_size - 1
+            if self.block_length < 2 \
+                    or not 1 <= self.denoising_steps <= self.block_length \
+                    or self.remasking not in REMASKING \
+                    or not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(
+                    f"block_length={self.block_length}: at least 2 positions "
+                    f"a block, denoised in 1 .. block_length passes "
+                    f"(denoising_steps={self.denoising_steps}) by one of "
+                    f"{REMASKING} (remasking={self.remasking!r}), "
+                    f"mask_token_id={self.mask_token_id} inside the "
+                    "vocabulary")
+            if self.gqa_layers is not None or self.layer_types is not None \
+                    or self.mla or self.sequence_parallel:
+                raise ValueError(
+                    "block_length (generation by diffusion over blocks) with "
+                    "a KDA, window or latent layer or sequence parallelism "
+                    "is not built: the block step attends through softmax "
+                    "GQA layers' K/V rows")
 
     @property
     def kv_dim(self) -> int:
@@ -417,6 +467,24 @@ class LlamaConfig:
             total += (c.n_layer - c.n_attn_layers) * (kda.num_params(c) - attn)
         return total
 
+    @property
+    def passes_per_token(self) -> float:
+        """Forward passes over a position for one generated token: 1
+        autoregressively; of a block-diffusion model the block's
+        ``denoising_steps`` passes (the most: a confident block needs fewer)
+        and the one that commits it."""
+        return self.denoising_steps + 1 if self.block_length else 1
+
+    def generate_flops_per_token(self, context: int = 0) -> float:
+        """FLOPs of ONE generated token at ``context`` cached positions: 2 a
+        parameter the token meets and 4 a cached position a head column, x
+        ``passes_per_token``. What a profile or a service estimate divides a
+        generation's time by, where ``flops_per_token`` is a TRAINED
+        token's."""
+        return self.passes_per_token * (
+            2 * self.num_params(active=True)
+            + 4 * self.n_attn_layers * self.n_head * self.head_dim * context)
+
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Megatron accounting (6N + 12·l·d·s), as in GPT2Config: GQA does not
         change the attention score/value FLOPs, only the KV projection (already
@@ -478,6 +546,21 @@ class LlamaModel:
 
     def __init__(self, config: LlamaConfig):
         self.config = config
+
+    @property
+    def block_decoding(self):
+        """The model protocol's word to the inference engine on HOW it
+        generates: None, one token a step from ``decode_step``; or, for a
+        model that generates by diffusion over blocks, the block's length
+        and how it is denoised (``common.BlockDecoding``), through
+        ``block_step``."""
+        c = self.config
+        if not c.block_length:
+            return None
+        from deepspeed_tpu.models.common import BlockDecoding
+
+        return BlockDecoding(c.block_length, c.denoising_steps, c.remasking,
+                             float(c.confidence_threshold), c.mask_token_id)
 
     # ---------------------------------------------------------------- params
     def _init_mixer(self, keys, l: int) -> Dict[str, Any]:
@@ -714,6 +797,11 @@ class LlamaModel:
         from deepspeed_tpu.models.common import causal_attention
 
         c = self.config
+        if c.block_length:
+            from deepspeed_tpu.models.common import local_causal_attention
+
+            return local_causal_attention(q, k, v, c.use_flash_attention,
+                                          block=c.block_length)
         return causal_attention(q, k, v, use_flash=c.use_flash_attention,
                                 sequence_parallel=c.sequence_parallel,
                                 window=window)
@@ -920,7 +1008,10 @@ class LlamaModel:
         — under a ``window`` with the last ``window`` slots (the cache holds
         the whole context for every layer; ``cached_decode_attention`` takes
         its einsum for a window: the decode kernel carries none yet).
-        -> (attn (B, 1, H, Dv), the caches)."""
+        -> (attn (B, 1, H, Dv), the caches). x may hold a BLOCK of T
+        positions (``block_step``): their rows go into slots ``pos .. pos +
+        T - 1`` and every one of them attends over slots ``0 .. pos + T -
+        1``; -> attn (B, T, H, Dv)."""
         from deepspeed_tpu.models.common import (cached_decode_attention,
                                                  kv_cache_write,
                                                  latent_decode_attention)
@@ -934,6 +1025,11 @@ class LlamaModel:
                 # GQA decode against the KV-head cache — repeated K/V are
                 # never materialized (grouped einsum or the Pallas streaming
                 # kernel)
+                if x.shape[1] > 1:
+                    attn = cached_decode_attention(
+                        q, cache_k, cache_v, layer, pos + x.shape[1] - 1,
+                        c.n_kv_head)
+                    return self._gated(attn, x, blk), (cache_k, cache_v)
                 attn = cached_decode_attention(q[:, 0], cache_k, cache_v,
                                                layer, pos, c.n_kv_head,
                                                window=window)
@@ -1124,6 +1220,11 @@ class LlamaModel:
         from deepspeed_tpu.models.common import chunked_lm_loss, parse_lm_batch
 
         c = self.config
+        if c.block_length:
+            raise NotImplementedError(
+                "a model that generates by diffusion over blocks is trained "
+                "on its noise schedule, which this repository has no source "
+                "for: the next-token loss is not its loss")
         ids, labels, mask = parse_lm_batch(batch)
         x, stats = self._trunk(params, ids, rng, with_router_stats=True)
         with scope("head"):
@@ -1235,7 +1336,9 @@ class LlamaModel:
         (L_routed, E held) int32: the (token, expert) pairs each held expert
         has been given since the prompt's first token — summed by the
         compiled programs themselves (the front-end reads them back when a
-        request resolves)."""
+        request resolves). A block-diffusion model's carries
+        ``block_passes`` (2,) int32, summed the same way: the denoising
+        passes and the commits its block steps have run."""
         from deepspeed_tpu.models.common import init_kv_cache
 
         c = self.config
@@ -1250,6 +1353,8 @@ class LlamaModel:
         if c.n_experts:
             cache["expert_tokens"] = jnp.zeros((c.n_moe_layers, c.n_held),
                                                jnp.int32)
+        if c.block_length:
+            cache["block_passes"] = jnp.zeros((2,), jnp.int32)
         return cache
 
     def cache_partition_specs(self):
@@ -1263,6 +1368,8 @@ class LlamaModel:
             specs.update(kda.state_specs())
         if self.config.n_experts:
             specs["expert_tokens"] = P()
+        if self.config.block_length:
+            specs["block_passes"] = P()
         return specs
 
     def _mix_cached(self, x, blk, cos_sin, caches, at, pos, attention=None,
@@ -1382,8 +1489,9 @@ class LlamaModel:
         c = self.config
         B, T = input_ids.shape
         x = self._embed(params, input_ids)
+        masked = {"block": c.block_length} if c.block_length else {}
         attention = lambda q, k, v, window=None: local_causal_attention(
-            q, k, v, c.use_flash_attention, window=window)
+            q, k, v, c.use_flash_attention, window=window, **masked)
         x, out, routed = self._run_cached(
             params, x, cache, self._rope(jnp.arange(T)), 0, attention)
         with scope("head"):
@@ -1393,6 +1501,8 @@ class LlamaModel:
         out["pos"] = jnp.int32(T)
         if routed is not None:
             out["expert_tokens"] = routed
+        if "block_passes" in cache:
+            out["block_passes"] = cache["block_passes"]
         return logits, out
 
     def decode_step(self, params, token, cache):
@@ -1409,4 +1519,48 @@ class LlamaModel:
         out["pos"] = pos + 1
         if routed is not None:
             out["expert_tokens"] = cache["expert_tokens"] + routed
+        return logits, out
+
+    def block_step(self, params, tokens, masked, cache, commit: bool = False):
+        """One forward pass over a BLOCK of a model that generates by
+        diffusion over blocks: ``tokens`` (B, Lb) int32 at positions ``pos ..
+        pos + Lb - 1``, read as the mask token where ``masked`` (B, Lb) bool
+        says so (masked-ness is carried beside the tokens: a model may
+        CHOOSE the mask id). The block's K/V rows are written into its own
+        slots and every position attends over slots ``0 .. pos + Lb - 1``,
+        the block itself whole. -> (logits (B, Lb, V) float32, row i the
+        prediction of the token AT position i; the cache, ``pos`` where it
+        was: the next pass over the block overwrites the rows). ``commit``:
+        the pass over a FINISHED block that leaves its rows for good: ->
+        (None, the cache with ``pos`` advanced by Lb). It may be a TRACED
+        bool, so that a loop over a block's passes holds the layers once:
+        the head then sits under a ``lax.cond`` and a committing pass
+        returns zeros for logits. One walk with ``prefill`` and
+        ``decode_step`` (``_run_cached``)."""
+        c = self.config
+        if not c.block_length:
+            raise ValueError("block_step: the model's configuration has no "
+                             "block_length (it generates a token a step)")
+        pos, Lb = cache["pos"], tokens.shape[1]
+        ids = jnp.where(masked, jnp.int32(c.mask_token_id), tokens)
+        x = self._embed(params, ids)                            # (B, Lb, D)
+        x, out, routed = self._run_cached(
+            params, x, cache, self._rope(pos + jnp.arange(Lb)), pos)
+        def head():
+            with scope("head"):
+                h = self._rms_norm(x, params["norm_g"])
+                return (h @ self._head(params, h.dtype)).astype(jnp.float32)
+
+        if isinstance(commit, bool):
+            logits = None if commit else head()
+        else:
+            logits = jax.lax.cond(
+                commit, lambda: jnp.zeros(
+                    tokens.shape + (c.vocab_size,), jnp.float32), head)
+        commit = jnp.asarray(commit, jnp.int32)
+        out["pos"] = pos + Lb * commit
+        if routed is not None:
+            out["expert_tokens"] = cache["expert_tokens"] + routed
+        out["block_passes"] = cache["block_passes"] + jnp.stack(
+            [1 - commit, commit])
         return logits, out

@@ -24,7 +24,18 @@ the K/V of the positions attended, once, and nothing else:
   ``g * rep + r`` in the columns of KV head ``g``, zeros elsewhere), so
   ``Q @ K^T`` gives every head's scores against its own columns and
   ``P @ V`` every head's output in its own columns; the block diagonal is
-  read off at the end. GQA-native: the cache is read at KV (not H) heads.
+  read off at the end. GQA-native: the cache is read at KV (not H) heads;
+* a step may bring MORE than one query position (``q`` (B, Lb, H, Dh): a
+  block-diffusion step's block, all of whose positions see slots ``0 ..
+  pos`` alike, no mask among them): the ``Lb x rep`` queries of a KV group
+  are that group's rows. Where that is a run of whole sublane tiles the
+  matrix is laid out GROUP-major instead (rows ``g * R .. g * R + R - 1``
+  hold KV head ``g``'s queries, R = ``Lb x rep`` rounded up to 16) and no
+  KV head is padded: 4 positions x 8 heads a group x 4 KV heads are 128
+  rows, one MXU tile, where the row-major form would pad the 4 KV heads to
+  16 and run 512. ``query_plan`` takes whichever has fewer rows, the
+  row-major one on a tie: one position at (25, 1), (16, 1), (8, 8) heads a
+  group keeps the plan it had.
 
 ``latent_decode_attention`` (``latent_decode_attn``) is the same stream for
 latent attention (MLA) in its ABSORBED form: the cache holds one row a
@@ -91,29 +102,52 @@ def _softmax_block(q, k, v, j, pos, block_k: int, edge: bool, acc_sc, m_sc,
     m_sc[:] = jnp.broadcast_to(m_new, m_sc.shape)
 
 
+def query_plan(n_kv: int, per_group: int):
+    """How the block-diagonal query matrix is laid out for ``per_group``
+    queries a KV head (positions x heads of a group) -> (group_major, rows a
+    unit, units): row-major, a unit is one query of every KV head (``n_kv``
+    rounded up to a sublane tile) and there are ``per_group`` of them;
+    group-major, a unit is one KV head's queries (``per_group`` rounded up)
+    and there are ``n_kv``. The form with fewer rows, row-major on a tie."""
+    by_row = per_group * _round_up(n_kv, ROW_TILE)
+    by_group = n_kv * _round_up(per_group, ROW_TILE)
+    if by_group < by_row:
+        return True, _round_up(per_group, ROW_TILE), n_kv
+    return False, _round_up(n_kv, ROW_TILE), per_group
+
+
 def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
                    l_sc, *, block_k: int, num_k: int, rep: int, kvp: int,
-                   head_dim: int):
+                   head_dim: int, group_major: bool = False):
+    """``rep`` units of ``kvp`` rows (``query_plan``). Row-major: unit r is
+    row r of ``q_ref`` spread over the KV heads' rows, each in its own
+    columns. Group-major: unit g is ALL of ``q_ref``'s rows masked to KV
+    head g's columns."""
     j = pl.program_id(1)
     pos = sc_ref[0]
     boundary = pos // block_k               # last block with valid entries
     width = qb_sc.shape[1]
 
-    def head_columns():
-        # (kvp, W): True where column c belongs to KV head g (= the row)
-        g = jax.lax.broadcasted_iota(jnp.int32, (kvp, width), 0)
+    def head_columns(g=None):
+        # (kvp, W): True where column c belongs to KV head g (None: the row)
+        if g is None:
+            g = jax.lax.broadcasted_iota(jnp.int32, (kvp, width), 0)
         c = jax.lax.broadcasted_iota(jnp.int32, (kvp, width), 1)
         return (c >= g * head_dim) & (c < (g + 1) * head_dim)
 
     @pl.when(j == 0)
     def _init():
-        own = head_columns()
-        for r in range(rep):                # static, small (H // KV)
-            # in float32: the 32-bit mask cannot be laid over packed rows
-            row = jnp.broadcast_to(q_ref[0, r:r + 1, :].astype(jnp.float32),
-                                   (kvp, width))
-            qb_sc[r * kvp:(r + 1) * kvp, :] = jnp.where(
-                own, row, 0.0).astype(qb_sc.dtype)
+        own = None if group_major else head_columns()
+        # in float32: the 32-bit mask cannot be laid over packed rows
+        whole = q_ref[0].astype(jnp.float32) if group_major else None
+        for r in range(rep):                # static, small
+            if group_major:
+                unit = jnp.where(head_columns(r), whole, 0.0)
+            else:
+                unit = jnp.where(own, jnp.broadcast_to(
+                    q_ref[0, r:r + 1, :].astype(jnp.float32),
+                    (kvp, width)), 0.0)
+            qb_sc[r * kvp:(r + 1) * kvp, :] = unit.astype(qb_sc.dtype)
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
@@ -132,9 +166,14 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
 
     @pl.when(j == num_k - 1)
     def _finalize():
-        own = head_columns()
         l = l_sc[:, :1]
         out = acc_sc[:] / jnp.where(l == 0.0, 1.0, l)
+        if group_major:
+            o_ref[0] = sum(
+                jnp.where(head_columns(g), out[g * kvp:(g + 1) * kvp], 0.0)
+                for g in range(rep)).astype(o_ref.dtype)
+            return
+        own = head_columns()
         for r in range(rep):
             mine = jnp.where(own, out[r * kvp:(r + 1) * kvp], 0.0)
             o_ref[0, r:r + 1, :] = jnp.sum(
@@ -143,33 +182,44 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
 
 def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
                      block_k: int = DEFAULT_BLOCK_K):
-    """q: (B, H, Dh) — the new token's queries; k_cache/v_cache: the stacked
-    ``(L, B, S, W)`` cache, ``W >= n_kv * Dh`` with KV head ``g`` in columns
-    ``[g * Dh, (g + 1) * Dh)`` and finite values everywhere; ``layer`` and
-    ``pos``: traced int32 scalars — the layer attended and the last valid
-    slot (valid length = pos + 1). Returns (B, H, Dh).
+    """q: (B, H, Dh) — the new token's queries — or (B, Lb, H, Dh): ``Lb``
+    positions' queries, each of which sees EVERY valid slot (a
+    block-diffusion step: its block lies in the last ``Lb`` of them);
+    k_cache/v_cache: the stacked ``(L, B, S, W)`` cache, ``W >= n_kv * Dh``
+    with KV head ``g`` in columns ``[g * Dh, (g + 1) * Dh)`` and finite
+    values everywhere; ``layer`` and ``pos``: traced int32 scalars — the
+    layer attended and the last valid slot (valid length = pos + 1).
+    Returns q's shape.
 
     ``H % n_kv == 0`` (grouped-query attention; H == n_kv is plain MHA).
     """
-    B, H, Dh = q.shape
+    one = q.ndim == 3
+    if one:
+        q = q[:, None]
+    B, Lb, H, Dh = q.shape
     S, W = k_cache.shape[2], k_cache.shape[3]
     if H % n_kv:
         raise ValueError(f"query heads {H} not divisible by KV heads {n_kv}")
     C = n_kv * Dh
     if W < C:
         raise ValueError(f"cache rows hold {W} values, {n_kv} x {Dh} asked")
-    rep = H // n_kv
-    kvp = _round_up(n_kv, ROW_TILE)
+    per_group = Lb * (H // n_kv)
+    group_major, kvp, rep = query_plan(n_kv, per_group)
+    # rows of the kernel's query input: a unit's rows group-major (padded to
+    # whole tiles: zero queries, dropped below), else one a query of a group
+    q_rows = kvp if group_major else per_group
     # a block is a multiple of the bf16 sublane tile, or the whole of S; the
     # last block of an S that does not tile is partial and always an edge
     bk = S if S <= block_k else _round_up(block_k, ROW_TILE)
     nk = pl.cdiv(S, bk)
 
-    # row r of the kernel's query input: the r-th query head of every KV
-    # group, each in its own group's columns; scale folded in
+    # row (l, r) of the kernel's query input: position l's r-th query head of
+    # every KV group, each in its own group's columns; scale folded in
     qf = (q * jnp.asarray(1.0 / math.sqrt(Dh), q.dtype)).reshape(
-        B, n_kv, rep, Dh).transpose(0, 2, 1, 3).reshape(B, rep, C)
-    qf = jnp.pad(qf.astype(k_cache.dtype), ((0, 0), (0, 0), (0, W - C)))
+        B, Lb, n_kv, H // n_kv, Dh).transpose(0, 1, 3, 2, 4).reshape(
+            B, per_group, C)
+    qf = jnp.pad(qf.astype(k_cache.dtype),
+                 ((0, 0), (0, q_rows - per_group), (0, W - C)))
 
     scalars = jnp.stack([jnp.asarray(pos, jnp.int32).reshape(()),
                          jnp.asarray(layer, jnp.int32).reshape(())])
@@ -182,22 +232,22 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=bk, num_k=nk, rep=rep,
-                          kvp=kvp, head_dim=Dh),
+                          kvp=kvp, head_dim=Dh, group_major=group_major),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, nk),
             in_specs=[
-                pl.BlockSpec((1, rep, W), qmap),
+                pl.BlockSpec((1, q_rows, W), qmap),
                 pl.BlockSpec((1, 1, bk, W), kmap),
                 pl.BlockSpec((1, 1, bk, W), kmap),
             ],
-            out_specs=pl.BlockSpec((1, rep, W), qmap),
+            out_specs=pl.BlockSpec((1, q_rows, W), qmap),
             scratch_shapes=[pltpu.VMEM((rep * kvp, W), k_cache.dtype),
                             pltpu.VMEM((rep * kvp, W), jnp.float32),
                             pltpu.VMEM((rep * kvp, LANES), jnp.float32),
                             pltpu.VMEM((rep * kvp, LANES), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, rep, W), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, q_rows, W), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         # what a full cache costs; the scheduler has no better number for a
@@ -208,8 +258,9 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
             transcendentals=int(B * rep * kvp * S)),
         name="decode_attn",
     )(scalars, qf, k_cache, v_cache)
-    return out[:, :, :C].reshape(B, rep, n_kv, Dh).transpose(
-        0, 2, 1, 3).reshape(B, H, Dh)
+    out = out[:, :per_group, :C].reshape(B, Lb, H // n_kv, n_kv, Dh).transpose(
+        0, 1, 3, 2, 4).reshape(B, Lb, H, Dh)
+    return out[:, 0] if one else out
 
 
 # rows of the latent cache a grid step streams: the row is narrow (640 lanes

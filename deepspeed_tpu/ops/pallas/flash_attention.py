@@ -60,6 +60,14 @@ step takes). ONE forward kernel for every length, its shapes from
   scratch, not yet the wide walk above. Its calls are ``flash_fwd_win`` /
   ``flash_bwd_dq_win`` / ``flash_bwd_dkv_win``. Without a window every
   plan, list and traced program is what it was.
+* **A BLOCK-causal mask** (``flash_attention(block=)``, forward only: the
+  prefill of a model that generates by diffusion over blocks): row i sees
+  the keys j with ``j // block <= i // block``, blocks counted from
+  position 0. ``block`` divides 128 and a sub-block is whole 128s (or the
+  whole length, which begins at 0), so a block never straddles two
+  sub-blocks: the span lists and the walks are the causal ones and only
+  the diagonal's sub-block differs, its mask ``col <= row | (block - 1)``
+  where the causal one is ``col <= row``. The backward refuses it.
 
 Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
 (B·H, T, D). v may have a head size of its own (latent attention: q.k at 192
@@ -390,14 +398,16 @@ def flash_forward_plan(t: int, d: int, dv: int, dtype,
 
 def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
                        acc_sc, m_sc, l_sc, *, scale: float, sub: int,
-                       n_sub: int):
+                       n_sub: int, block: int = 1):
     """A q block (one row of square sub-blocks) against a span of ``n_sub``
     of them. Every walk is unrolled, two sub-blocks a softmax update (one
     q.k matmul 2 x sub columns wide: the max, the rescale and the
     accumulator's pass are paid half as often), and one basic block, so the
     scheduler lays the next update's matmul beside this one's softmax. The
     running max, sum and accumulator are values inside a walk and touch
-    their scratch between walks."""
+    their scratch between walks. ``block`` > 1: the diagonal's sub-block
+    under the block-causal mask (a power of two that divides the sub-block:
+    a row's block ends at ``row | (block - 1)``)."""
     f = pl.program_id(1)
     qi, si = qi_arr[f], si_arr[f]
     q = q_ref[0]
@@ -418,6 +428,8 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = _scores(q, k_ref[0, pl.ds(at, width * sub), :], scale)
         if diagonal:
             rows, cols = _block_iotas(sub, sub, 0, 0)
+            if block > 1:
+                rows = rows | (block - 1)
             last = jnp.where(rows >= cols, s[:, -sub:], NEG_INF)
             s = last if width == 1 else jnp.concatenate(
                 [s[:, :-sub], last], axis=1)
@@ -462,7 +474,8 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
                     diagonal=True))
 
 
-def _causal_forward(q, k, v, scale, block_q, block_k, window=None):
+def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
+                    block=None):
     bh, t, d = q.shape
     dv = v.shape[2]
     plan = flash_forward_plan(t, d, dv, q.dtype, block_q, block_k, window)
@@ -470,7 +483,8 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None):
     qi_arr, si_arr = _causal_spans(t // sub, n_sub, sub, window)
     if window is None:
         kernel, name = functools.partial(
-            _fwd_causal_kernel, scale=scale, sub=sub, n_sub=n_sub), "flash_fwd"
+            _fwd_causal_kernel, scale=scale, sub=sub, n_sub=n_sub,
+            **({"block": block} if block else {})), "flash_fwd"
         pairs = t * t // 2                      # the causal half
     else:
         kernel, name = functools.partial(
@@ -572,9 +586,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
         _write_out(o_ref, lse_ref, m_sc[:], l_sc[:], acc_sc[:])
 
 
-def _flash_forward(q, k, v, scale, causal, block_q, block_k, window=None):
+def _flash_forward(q, k, v, scale, causal, block_q, block_k, window=None,
+                   block=None):
     if causal:              # self-attention: ``flash_attention`` saw to that
-        return _causal_forward(q, k, v, scale, block_q, block_k, window)
+        return _causal_forward(q, k, v, scale, block_q, block_k, window,
+                               block)
     bh, t_q, d = q.shape
     t_k, dv = k.shape[1], v.shape[2]
     bq = _pick_block(t_q, block_q)
@@ -771,7 +787,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(res, g, scale, causal, block_q, block_k, window=None):
+def _flash_backward(res, g, scale, causal, block_q, block_k, window=None,
+                    block=None):
+    if block:
+        raise NotImplementedError(
+            "flash_attention(block=): the block-causal mask is the forward's "
+            "(a prefill's); no backward kernel carries it")
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
     t_k = k.shape[1]
@@ -957,9 +978,15 @@ def _attention_vjp(forward, backward):
 _flash_bthd = _attention_vjp(_flash_forward, _flash_backward)
 
 
+def block_mask_supports(block) -> bool:
+    """Whether the forward carries a block-causal mask of ``block``
+    positions: a power of two that divides a sub-block's 128s."""
+    return isinstance(block, int) and block > 1 and _LANES % block == 0
+
+
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, block: Optional[int] = None):
     """q, k: (B, T, H, D), v: (B, T, H, Dv) → (B, T, H, Dv); Dv <= D, the
     softmax scale is D's. Differentiable; bf16-friendly.
 
@@ -969,6 +996,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     ``flash_fwd_win`` / ``flash_bwd_dq_win`` / ``flash_bwd_dkv_win``. A
     window that reaches the whole length is no window: that call's plans,
     lists and programs are those of a call without one.
+
+    ``block`` (a Python int, causal, no window, FORWARD only): the
+    block-causal mask, row i sees the keys j with ``j // block <= i //
+    block`` (``block_mask_supports``); 1 or None: the causal mask.
 
     Causal self-attention at a length the kernels cannot tile is padded at
     the END of the sequence and the pad rows sliced off the output: under
@@ -982,6 +1013,16 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
             raise ValueError(f"flash_attention: window={window} is a causal "
                              "window of at least the row's own key")
         window = int(window) if window < t else None
+    if block is not None and int(block) > 1:
+        if not causal or window is not None or t % int(block) \
+                or not block_mask_supports(block):
+            raise ValueError(
+                f"flash_attention: block={block} is a block-causal mask of a "
+                "power of two that divides 128 and the length, with no "
+                "window")
+        block = int(block)
+    else:
+        block = None
     if not flash_supports(t, k.shape[1], causal, block_q, block_k):
         raise ValueError(
             f"flash_attention: lengths ({t}, {k.shape[1]}) with causal="
@@ -1000,8 +1041,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     # (T, D) instead of a VPU pass over every (T², causal-half) score element
     # in the forward and in both backward kernels; autodiff scales dq back
     q = q * jnp.asarray(scale, q.dtype)
+    # (a pad key lies in a LATER block than every kept row: under the block
+    # mask too it is visible to pad queries alone)
     o = _flash_bthd(q, k, v, (1.0, bool(causal), int(block_q), int(block_k),
-                              window))
+                              window) + ((block,) if block else ()))
     return o[:, :t]
 
 

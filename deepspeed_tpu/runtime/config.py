@@ -721,7 +721,7 @@ class ServingConfig(DeepSpeedConfigModel):
     kv_budget_fraction: float = Field(0.6, gt=0.0, le=1.0, description="fraction of post-params HBM granted to request KV caches when sizing the admission bound")
     hbm_bytes: int = Field(0, ge=0, description="device HBM to budget against; 0 = probe the device (memory_stats), else the peak table's capacity for its device_kind (CPU mesh: the nominal cpu-sim row)")
     default_deadline_s: float = Field(30.0, gt=0.0, description="per-request deadline when the request carries none; enforced at admission (estimated TTFT must fit) and at every decode tick")
-    decode_tick_tokens: int = Field(16, gt=0, description="tokens decoded per decode tick, and the most a stream callback carries after the first (which is the prefill tick's one token) — the cancellation/deadline granularity; smaller = faster aborts, more dispatch gaps")
+    decode_tick_tokens: int = Field(16, gt=0, description="tokens decoded per decode tick, and the most a stream callback carries after the first (which is the prefill tick's one token; of a model that generates by diffusion over blocks its first block, and the tick is then a whole number of blocks) — the cancellation/deadline granularity; smaller = faster aborts, more dispatch gaps")
     decode_tick_timeout_s: float = Field(10.0, gt=0.0, description="hard deadline per warm decode tick (run_with_deadline); a tick exceeding it resolves the request as a partial timeout — keep it at or below watchdog.min_step_timeout so the per-request timeout fires before the engine watchdog")
     startup_tick_timeout_s: float = Field(300.0, gt=0.0, description="tick deadline before a program shape has run (first prefill/decode compiles)")
     breaker_threshold: int = Field(3, ge=1, description="consecutive tick failures that open the circuit (readiness → degraded, queued requests shed with retry-after)")

@@ -4,7 +4,12 @@ One worker thread pulls admitted requests off a bounded queue and drives
 each through ``prefill`` + chunked ``decode`` ticks (the programs come
 from :func:`~deepspeed_tpu.inference.engine.build_serving_programs`, the
 same scan body ``generate()`` compiles), delivering what a tick chose when
-it returns: the prefill tick the first token, a decode tick its chunk.
+it returns: the prefill tick the first token, a decode tick its chunk. A
+step of the programs may emit MORE than one token a row (a model that
+generates by diffusion over blocks: the prefill tick then delivers the
+first block, a decode tick ``decode_tick_tokens // block`` of them), so
+tokens, cache positions and the service estimate are counted from what
+the programs return, never from "one token a step".
 Every tick runs under the watchdog's ``run_with_deadline``, so a hung
 device step — or an injected chaos ``decode_step`` hang — surfaces as a
 clean per-request timeout instead of a wedged server, and the host checks
@@ -99,6 +104,11 @@ class ServingFrontEnd:
         self._stop = threading.Event()
         self._worker: Optional[threading.Thread] = None
         self._programs: Dict[tuple, tuple] = {}
+        # tokens a row one step of the engine's programs emits: 1, or the
+        # block of a model that generates by diffusion over blocks
+        from deepspeed_tpu.inference.engine import step_tokens
+
+        self._step_tokens = step_tokens(engine.module)
         self._warm: Dict[tuple, int] = {}    # tick key -> successful runs
         self._service_ema: Optional[float] = None
         self.counts: Dict[str, float] = collections.defaultdict(float)
@@ -199,7 +209,10 @@ class ServingFrontEnd:
         ``stream`` is called with each new list of tokens as a tick
         delivers it: the first call carries the first token alone (the
         prefill tick chose it), later calls up to ``decode_tick_tokens``,
-        the last one cut to what is owed (or the EOS padding)."""
+        the last one cut to what is owed (or the EOS padding). Of a model
+        that generates by diffusion over blocks the first call carries the
+        first BLOCK's new tokens (the prefill tick denoised it): the first
+        tokens that exist."""
         ids = np.asarray(prompt, dtype=np.int32)
         if ids.ndim == 1:
             ids = ids[None, :]
@@ -453,8 +466,10 @@ class ServingFrontEnd:
                 sharded_jit(pf, label="serving/prefill", donate_argnums=(),
                             mesh=eng.mesh,
                             in_shardings=(params_in, INHERIT, INHERIT),
-                            out_shardings=(INHERIT, cache_io, INHERIT,
-                                           INHERIT)),
+                            # a block step's prefill hands over its first
+                            # block beside the carry
+                            out_shardings=(INHERIT, cache_io, INHERIT, INHERIT)
+                            + (INHERIT,) * (self._step_tokens > 1)),
                 sharded_jit(dc, label="serving/decode_chunk",
                             # NO donation: a tick that dies on its deadline
                             # leaves the request's last-good cache intact for
@@ -491,8 +506,10 @@ class ServingFrontEnd:
         with tracer.span(
                 phase, cat="serving", request=req.id,
                 # positions in the cache when the tick starts: the prompt
-                # and every token but the last, which this tick steps on
-                context=int(req.prompt.shape[1]) + max(len(req.tokens) - 1, 0),
+                # and every token but the last, which this tick steps on (a
+                # block step has committed every token it delivered)
+                context=int(req.prompt.shape[1]) + max(
+                    len(req.tokens) - (self._step_tokens == 1), 0),
                 index=req.decode_ticks) as tick:
             now = tick.t0
             remaining = req.deadline_at - now
@@ -575,6 +592,15 @@ class ServingFrontEnd:
                 self.breaker.release_probe()
                 # whoever reads the spans keeps no handle to the Request
                 positions = self._cache_positions(req)
+                if req.block_passes is not None:
+                    # a model that generates by diffusion over blocks: what
+                    # its block steps ran, as the programs counted it
+                    passes, commits = req.block_passes
+                    dec = self.engine.module.block_decoding
+                    span.args.update(
+                        blocks=commits, passes=passes, commits=commits,
+                        block_length=int(dec.length),
+                        denoising_steps=int(dec.steps))
                 span.args.update(
                     prompt_len=int(req.prompt.shape[1]),
                     new_tokens=len(req.tokens), status=req.status,
@@ -593,12 +619,24 @@ class ServingFrontEnd:
                     * req.cache_state_bytes)
 
     def _cache_positions(self, req: Request) -> int:
-        """Positions of a sequence the request's programs have run: the
-        prompt and every step of every decode chunk."""
+        """Positions of a sequence the request's programs have COMMITTED to
+        the cache: the prompt and every step of every decode chunk; of a
+        model whose step is a block, the prompt's whole blocks, the block
+        the prefill tick committed and those of every decode chunk."""
         if req.prefill_done_at is None:
             return 0
-        return int(req.prompt.shape[1]) + req.decode_ticks * int(
-            self.cfg.decode_tick_tokens)
+        n, prompt = self._step_tokens, int(req.prompt.shape[1])
+        chunk = req.decode_ticks * int(self.cfg.decode_tick_tokens)
+        return prompt + chunk if n == 1 else prompt - prompt % n + n + chunk
+
+    def _positions_run(self, req: Request) -> int:
+        """Positions the request's programs have put through the layers:
+        the committed ones, and of a block step every denoising pass over
+        its block besides."""
+        if req.block_passes is None:
+            return self._cache_positions(req)
+        return self._cache_positions(req) \
+            + req.block_passes[0] * self._step_tokens
 
     def _serve(self, req: Request, tracer) -> None:
         import jax
@@ -622,15 +660,18 @@ class ServingFrontEnd:
             # output and would compile the decode chunk a second time
             rng = jax.device_put(jax.random.PRNGKey(req.seed),
                                  self.engine.sharding.replicated())
-            tok, cache, done, rng = self._tick(
+            # (tok, cache, done, rng) and, of a block step, its first block
+            tok, cache, done, rng, *first = self._tick(
                 req, lambda: prefill(self.engine.params, ids, rng),
                 warm_key=("prefill", pkey, ids.shape[1]))
             # told apart by what the model's cache says of itself (the
             # names of its leaves), not by the number of dimensions
             req.cache_position_bytes, req.cache_state_bytes = \
                 cache_footprint(cache)
-            # prefill chose the first token: it leaves now, alone
-            finished = self._deliver(req, tok, done, tracer)
+            # prefill chose the first token: it leaves now, alone (of a
+            # block step the first block's new tokens)
+            finished = self._deliver(req, first[0] if first else tok, done,
+                                     tracer)
             while not finished and len(req.tokens) < req.max_new_tokens:
                 self._poll_preempt()
                 tok, cache, done, rng, toks = self._tick(
@@ -639,6 +680,7 @@ class ServingFrontEnd:
                     warm_key=("decode", pkey, min(req.decode_ticks, 1)))
                 req.decode_ticks += 1
                 finished = self._deliver(req, toks, done, tracer)
+            self._count_block_passes(req, cache)
             self._count_expert_tokens(req, cache, tracer)
             self._observe_service(req)
             self._count("completed")
@@ -679,7 +721,8 @@ class ServingFrontEnd:
     def _deliver(self, req: Request, toks, done, tracer) -> bool:
         """A tick's new tokens come to the host and go to the client, cut
         to what the request is still owed: the one token of the prefill
-        tick, up to ``decode_tick_tokens`` of a decode tick. -> whether
+        tick (a block step's first block), up to ``decode_tick_tokens`` of
+        a decode tick. -> whether
         every row has passed its EOS (the rest is then padded with it and
         no further tick runs)."""
         with tracer.span("deliver", cat="serving", request=req.id):
@@ -704,6 +747,21 @@ class ServingFrontEnd:
                 self._flush_stream(req, pad)
         return finished
 
+    def _count_block_passes(self, req: Request, cache) -> None:
+        """A block-diffusion model's programs sum, in the cache they hand
+        from tick to tick, the denoising passes and the commits their block
+        steps ran (``block_passes``). One read when the request has its
+        tokens, as ``expert_tokens``: onto the request (its span's
+        ``passes`` / ``commits`` / ``blocks``) and into the counters
+        ``serving/passes`` and ``serving/blocks``."""
+        ran = cache.get("block_passes") if isinstance(cache, dict) else None
+        if ran is None:
+            return
+        passes, commits = (int(v) for v in np.asarray(ran))
+        req.block_passes = (passes, commits)
+        self._count("passes", n=passes + commits)
+        self._count("blocks", n=commits)
+
     def _count_expert_tokens(self, req: Request, cache, tracer) -> None:
         """A routed (MoE) model's programs sum, in the cache they hand from
         tick to tick, the (token, expert) pairs every expert HELD here was
@@ -720,7 +778,7 @@ class ServingFrontEnd:
         config = getattr(self.engine.module, "config", None)
         held = getattr(config, "experts_held", None) or (0, counts.shape[-1])
         pairs = counts.shape[0] * int(req.prompt.shape[0]) \
-            * self._cache_positions(req) * getattr(config, "n_experts_per_tok", 0)
+            * self._positions_run(req) * getattr(config, "n_experts_per_tok", 0)
         self._reg().counter("moe/expert_tokens").inc(float(counts.sum()))
         tracer.instant("moe/expert_tokens", cat="moe", trace=req.id,
                        request=req.id, counts=counts.tolist(),

@@ -16,7 +16,9 @@ ONE vocabulary (:data:`SCOPES`), innermost name wins:
 ``kda``         a KDA (gated delta rule) mixer, the same extent
 ``mlp``         the norm before the dense MLP, the MLP, its residual add
 ``moe``         router, sort / gather, grouped matmuls, shared expert, combine
-``head``        final norm, the loss head / logits, the next token
+``head``        final norm, the loss head / logits, the next token; of a
+                block-diffusion step the confidences, the choice of
+                positions and their transfer (``/unmask``)
 ``layers``      around ``layer_scan``: what the scan itself adds (weight
                 slices, residual stacking, carries) is ``layers``, a
                 block's ops inside it are the block's
@@ -44,7 +46,7 @@ __all__ = ["SCOPES", "FINER", "PASSES", "scope", "classify"]
 SCOPES = ("embed", "attn", "kda", "mlp", "moe", "head", "layers",
           "accumulate", "optimizer")
 FINER = ("qkv", "core", "out", "up", "down", "router", "experts", "shared",
-         "gnorm", "update", "cast", "router_bias")
+         "gnorm", "update", "cast", "router_bias", "unmask")
 PASSES = ("fwd", "bwd", "recompute", "none")
 
 # jax writes a transform around the scope entered outside it:

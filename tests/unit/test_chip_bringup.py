@@ -1127,6 +1127,72 @@ def test_pangu_prefill_for_v5e_keeps_no_square_of_scores(pangu):
     assert total < 15.75 * GIB, total / GIB
 
 
+# ------ SDAR-30B-A3B: one chip's share of 8, all 48 layers, block diffusion
+@pytest.fixture(scope="module")
+def sdar(v5e):
+    """(mesh, model, abstract bf16 params, abstract cache, the two serving
+    programs) of the benchmark's configuration on ONE chip: 48 layers, 16
+    of 128 experts, blocks of 4 denoised in 2 passes, an 8,192-slot cache."""
+    from benchmark import manifest as mf
+    from benchmark.families import sdar_moe as family
+    from deepspeed_tpu.inference.engine import build_serving_programs
+
+    mesh = _mesh(v5e)
+    model = family.build_model(mf.load_json(
+        mf.BENCH_DIR / "configs" / "sdar-30b-a3b-chat.json"), "serve")
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh), shapes)
+    cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
+                         jax.eval_shape(lambda: model.init_cache(1, 8192)))
+    return (mesh, model, params, cache) + build_serving_programs(
+        model, 8192, 16, False, 1.0, 0, 1.0, None)
+
+
+def test_sdar_decode_chunk_for_v5e_holds_the_layers_once(sdar):
+    """A chunk of 4 block steps: ONE multi-row ``decode_attn`` (4 positions
+    x 8 heads a group x 4 KV heads = 128 query rows, group-major) and one
+    pair of thin grouped matmuls under the share's conditional in the
+    program, the denoising passes and the commit being one loop; the head
+    under a conditional of its own (a committing pass skips it); it fits."""
+    mesh, model, params, cache, _, chunk = sdar
+    with mesh:
+        compiled = jax.jit(chunk).lower(
+            params, *_chunk_carry(cache, mesh)).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin", "decode_attn"):
+        assert len(re.findall(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call",
+                              text)) == 1, kernel
+    call, = re.findall(r"%decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
+    assert "bf16[1,32,512]" in call         # 4 x 8 query rows a KV head
+    assert text.count(" conditional(") >= 2
+    assert "head/unmask" in text
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 1.0 * GIB
+    assert total < 12 * GIB, total / GIB
+
+
+def test_sdar_prefill_ends_in_the_first_block_under_the_block_causal_mask(
+        sdar, v5e):
+    """The prefill program hands over the carry and the first block's NEW
+    tokens (what the prompt left over of a block opens it), the chunk takes
+    the carry; the flash forward lowers under the block-causal mask at the
+    cell's longest prompt and head shape, one Mosaic call, no (T, T) array.
+    (The whole 4,096-token prefill compiles in ~18 s: benchmark's to run.)"""
+    mesh, model, params, cache, prefill, chunk = sdar
+    with mesh:
+        ids = _abstract((1, 1030), jnp.int32, mesh)
+        key = _abstract((2,), jnp.uint32, mesh)
+        out = jax.eval_shape(prefill, params, ids, key)
+        assert out[0].shape == (1,) and out[4].shape == (1, 2)  # 4 - 1030 % 4
+        assert jax.eval_shape(chunk, params, *out[:4])[4].shape == (1, 16)
+        qkv = _abstract((1, 4096, 32, 128), jnp.bfloat16, mesh)
+        text = jax.jit(functools.partial(fa.flash_attention, block=4)).lower(
+            qkv, qkv, qkv).compile().as_text()
+    assert len(re.findall(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call",
+                          text)) == 1
+    assert not re.search(r"\[[\d,]*4096,4096\]", text)
+
+
 # ------ Solar-Open2-250B: one chip's share of 8, at the published widths
 def test_kda_chunk_kernel_compiles_for_v5e_uninterpreted(v5e):
     """The state pass of the chunked form at 64 heads x 128, a segment of

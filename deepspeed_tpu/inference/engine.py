@@ -22,15 +22,18 @@ A STEP of the decode loop emits ``n >= 1`` tokens a row. The autoregressive
 step (``_decode_scan_step``) emits one: ``decode_step`` on the last token,
 then the next. A model that generates by diffusion over blocks says so
 (``block_decoding``: a ``models/common.py::BlockDecoding``) and has
-``block_step(params, tokens, masked, cache, commit=)``; its step
+``block_step(params, tokens, masked, cache, pending=)``; its step
 (``_block_scan_step``) emits a BLOCK: up to ``steps`` forward passes over
-the same ``length`` positions, each unmasking some of them, then one pass
-that commits the finished block's keys and values (one loop, the model in
-its body once). Everything around the
+the same ``length`` positions, each unmasking some of them, and NO pass of
+its own to commit the finished block's keys and values: the block is left
+PENDING (its final tokens, a leaf of the cache) and its commit rides in
+the next block's first pass, which runs over both (``2 x length`` rows, the
+weights streamed once). The last block of a request is never committed:
+nothing reads its rows. Everything around the
 step is one code: the prefill program ends in the FIRST emission (one
 token; or the first block, which opens with what the prompt left over of a
-block), ``generate()`` and the serving chunk scan steps and count tokens by
-what the steps return.
+block and has nothing pending before it), ``generate()`` and the serving
+chunk scan steps and count tokens by what the steps return.
 """
 
 from __future__ import annotations
@@ -196,26 +199,28 @@ def _unmask(conf, masked, n, dec):
     return jnp.where(jnp.sum(high, axis=1, keepdims=True) >= n, high, top)
 
 
-def _denoise_and_commit(module, params, tokens, masked, cache, rng, sampling,
-                        dec, given: int):
-    """A block's passes, ONE loop whose body holds the model once: each pass
-    ``module.block_step`` over the block as it stands; while a mask is left
-    a token is chosen at every position (the largest logit, or ``do_sample``
-    the sampling head) with its confidence (the softmax's probability of
-    it) and :func:`_unmask` moves some in; the pass that finds no mask left
-    COMMITS the block (its keys and values stay, ``pos`` advances) and ends
-    the loop. The static rules run ``block_passes`` denoising passes and the
-    commit, a fixed trip; the dynamic one until the commit. -> (tokens,
-    cache, rng); nothing a denoising pass writes outlives the next pass
-    over the block, which writes the same slots."""
+def _denoise(module, params, tokens, masked, cache, rng, sampling, dec,
+             given: int, carrying: bool):
+    """A block's denoising passes: each ``module.block_step`` over the block
+    as it stands; a token is chosen at every position (the largest logit, or
+    ``do_sample`` the sampling head) with its confidence (the softmax's
+    probability of it) and :func:`_unmask` moves some in, until no mask is
+    left: the static rules run ``block_passes`` passes, a fixed trip; the
+    dynamic one while a mask is left. ``carrying``: the FIRST pass carries
+    the cache's pending block (the one finished before this: its rows are
+    committed by the pass that starts the next, ``block_step(pending=)``),
+    so a step whose passes are more than one holds the model twice, the
+    first pass and the loop's. No pass commits THIS block: nothing a pass
+    writes of it outlives the next pass over it, which writes the same
+    slots. -> (tokens, cache, rng)."""
     from deepspeed_tpu.telemetry.scopes import scope
 
     do_sample, temperature, top_k, top_p, _ = sampling
-    counts = jnp.asarray(_transfer_counts(dec) + (0,), jnp.int32)
+    counts = jnp.asarray(_transfer_counts(dec), jnp.int32)
 
-    def one_pass(s, commit, tokens, masked, cache, rng):
+    def one_pass(s, tokens, masked, cache, rng, pending=None):
         logits, cache = module.block_step(params, tokens, masked, cache,
-                                          commit=commit)
+                                          pending=pending)
         with scope("head/unmask"):
             if do_sample:
                 rng, sub = jax.random.split(rng)
@@ -225,25 +230,20 @@ def _denoise_and_commit(module, params, tokens, masked, cache, rng, sampling,
             conf = jnp.exp(
                 jnp.take_along_axis(logits, x0[..., None], axis=-1)[..., 0]
                 - jax.scipy.special.logsumexp(logits, axis=-1))
-            # (a committing pass finds nothing masked and moves nothing)
-            move = _unmask(conf, masked, counts[jnp.minimum(s, dec.steps)],
-                           dec)
+            move = _unmask(conf, masked, counts[s], dec)
             return jnp.where(move, x0, tokens), masked & ~move, cache, rng
 
+    state, start, n = (tokens, masked, cache, rng), 0, block_passes(dec, given)
+    if carrying:
+        state, start = one_pass(0, *state, pending=cache["pending"]), 1
     if dec.remasking == "low_confidence_dynamic":
-        def body(c):
-            s, committed, *state = c
-            commit = ~jnp.any(state[1])
-            return (s + 1, commit, *one_pass(s, commit, *state))
-
-        _, _, tokens, _, cache, rng = jax.lax.while_loop(
-            lambda c: ~c[1], body,
-            (jnp.int32(0), jnp.bool_(False), tokens, masked, cache, rng))
-    else:
-        n = block_passes(dec, given)
-        tokens, _, cache, rng = jax.lax.fori_loop(
-            0, n + 1, lambda s, c: one_pass(s, s == n, *c),
-            (tokens, masked, cache, rng))
+        _, *state = jax.lax.while_loop(
+            lambda c: jnp.any(c[2]), lambda c: (c[0] + 1, *one_pass(*c)),
+            (jnp.int32(start), *state))
+    elif start < n:
+        state = jax.lax.fori_loop(start, n, lambda s, c: one_pass(s, *c),
+                                  state)
+    tokens, _, cache, rng = state
     return tokens, cache, rng
 
 
@@ -251,12 +251,15 @@ def _block_scan_step(module, params, sampling, dec):
     """One BLOCK of the decode loop, over the same carry ``(tok, cache,
     done, rng)`` as :func:`_decode_scan_step`: the block all masked, but for
     the ``opens`` (B, given) tokens it is handed (what a prompt left over of
-    a block: the prefill program's step alone), denoised and committed
-    (:func:`_denoise_and_commit`). Emits the block's NEW tokens (B, length -
-    given); a row holds its EOS token from the first one chosen on
-    (``done``), as the autoregressive step's rows do. ``tok``, the last
-    token emitted, is carried for the carry's sake: a block step reads
-    nothing of it."""
+    a block: the prefill program's step alone, the FIRST block of a request,
+    before which nothing is pending), denoised (:func:`_denoise`) and left
+    in the cache as the PENDING block, for the next step's first pass to
+    commit. Emits the block's NEW tokens (B, length - given); a row holds
+    its EOS token from the first one chosen on (``done``), as the
+    autoregressive step's rows do. ``tok``, the last token emitted, is
+    carried for the carry's sake: a block step reads nothing of it."""
+    from deepspeed_tpu.models.common import BLOCK_COUNTS
+
     eos = sampling[-1]
 
     def step(carry, opens=None):
@@ -268,8 +271,12 @@ def _block_scan_step(module, params, sampling, dec):
             tokens = tokens.at[:, :given].set(opens.astype(jnp.int32))
         masked = jnp.broadcast_to(jnp.arange(dec.length) >= given,
                                   tokens.shape)
-        tokens, cache, rng = _denoise_and_commit(
-            module, params, tokens, masked, cache, rng, sampling, dec, given)
+        tokens, cache, rng = _denoise(
+            module, params, tokens, masked, cache, rng, sampling, dec, given,
+            carrying=opens is None)
+        cache = {**cache, "pending": tokens,
+                 "block_passes": cache["block_passes"].at[
+                     BLOCK_COUNTS.index("blocks")].add(1)}
         new = tokens[:, given:]
         ended = done[:, None] | (jnp.cumsum(new == eos, axis=1)
                                  - (new == eos) > 0)

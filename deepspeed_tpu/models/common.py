@@ -40,6 +40,10 @@ NEG_INF_ATTN = -1e30
 BlockDecoding = collections.namedtuple(
     "BlockDecoding", "length steps remasking threshold mask_token_id")
 REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+# What such a model's programs sum in their cache's ``block_passes`` leaf, in
+# its order: forward passes that denoise, forward passes that ONLY commit,
+# blocks whose commit rode in a later block's first pass, blocks finished.
+BLOCK_COUNTS = ("passes", "commits", "carried", "blocks")
 
 
 def layer_scan(body, init, xs, unroll: int = 1):
@@ -436,7 +440,7 @@ def read_as_stored(w):
 
 
 def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
-                            alibi=None, window=None):
+                            alibi=None, window=None, early=None):
     """Single-token decode attention over the stacked KV cache
     (``init_kv_cache``), shared by the model families. q: (B, H, Dh) — the
     new token's queries; caches (L, B, S, W), ``layer`` of them valid
@@ -444,10 +448,14 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     (GQA); ``alibi``: optional (H,) slopes (key-position bias); ``window``:
     optional traced sliding window (GPT-Neo). → (B, H, Dh).
 
-    q may also be (B, Lb, H, Dh): the queries of ``Lb`` positions that ALL
-    see slots ``0 .. pos`` and nothing else (a block-diffusion step's
-    block, which lies in the last ``Lb`` of those slots: no mask among its
+    q may also be (B, Lb, H, Dh): the queries of ``Lb`` positions that see
+    slots ``0 .. pos`` and nothing else (a block-diffusion step's block,
+    which lies in the last ``Lb`` of those slots: no mask among its
     positions); → (B, Lb, H, Dh). Neither a bias nor a window goes with it.
+    ``early`` (with such a q alone): ``(n, last)``, the first ``n`` (static)
+    of the positions see slots ``0 .. last`` (traced, <= pos) only: the
+    block BEFORE the step's, carried in the same pass and blind to the new
+    block's slots.
 
     The path is chosen the way ``local_causal_attention`` chooses flash:
     the Pallas streaming kernel (ops/pallas/decode_attention.py), which
@@ -461,7 +469,10 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
         if alibi is not None or window is not None:
             raise ValueError("a block of query positions takes neither a "
                              "bias nor a window")
-        return _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv)
+        return _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv,
+                                       early)
+    if early is not None:
+        raise ValueError("early: of a block of query positions")
     B, H, Dh = q.shape
     if alibi is None and window is None:
         mesh, on_tpu = _kernel_target()
@@ -489,7 +500,8 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     return jnp.einsum("bgrk,bkgd->bgrd", p, v_l).reshape(B, H, Dh)
 
 
-def _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos, n_kv: int):
+def _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos, n_kv: int,
+                           early=None):
     """``decode_attn`` (ops/pallas/decode_attention.py) on q (B, H, Dh) or
     (B, Lb, H, Dh), from a program compiled over ``mesh``."""
     from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
@@ -500,21 +512,28 @@ def _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos, n_kv: int):
     local_kv = n_kv // (mesh.shape[heads] if heads else 1)
     cache_spec = P(None, batch, None, heads)
     q_spec = P(batch, *([None] * (q.ndim - 3)), heads, None)
+    kernel, scalars = functools.partial(decode_attention, n_kv=local_kv), \
+        (layer, pos)
+    if early is not None:   # the early positions' last slot: traced, as pos
+        kernel = lambda q, k, v, layer, pos, last: decode_attention(
+            q, k, v, layer, pos, n_kv=local_kv, early=(early[0], last))
+        scalars += (early[1],)
     return _kernel_on_mesh(
-        functools.partial(decode_attention, n_kv=local_kv), mesh,
-        (q, k_cache, v_cache, layer, pos),
-        (q_spec, cache_spec, cache_spec, P(), P()), q_spec)
+        kernel, mesh, (q, k_cache, v_cache) + scalars,
+        (q_spec, cache_spec, cache_spec) + (P(),) * len(scalars), q_spec)
 
 
-def _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv: int):
+def _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
+                            early=None):
     """``cached_decode_attention`` for q (B, Lb, H, Dh): the kernel where
     the program is for a TPU, else its einsum twin (and test reference):
-    every one of the ``Lb`` positions over slots ``0 .. pos``."""
+    every one of the ``Lb`` positions over slots ``0 .. pos``, the first
+    ``early[0]`` of them over slots ``0 .. early[1]``."""
     B, Lb, H, Dh = q.shape
     mesh, on_tpu = _kernel_target()
     if on_tpu:
         return _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos,
-                                      n_kv)
+                                      n_kv, early)
     S = k_cache.shape[2]
     layer_of = lambda c: jax.lax.dynamic_index_in_dim(
         c, layer, 0, keepdims=False)[..., :n_kv * Dh].reshape(B, S, n_kv, Dh)
@@ -522,8 +541,9 @@ def _cached_block_attention(q, k_cache, v_cache, layer, pos, n_kv: int):
     qg = q.reshape(B, Lb, n_kv, H // n_kv, Dh)
     s = jnp.einsum("blgrd,bkgd->bglrk", qg, k_l).astype(jnp.float32) \
         / math.sqrt(Dh)
-    s = jnp.where((jnp.arange(S) <= pos)[None, None, None, None], s,
-                  NEG_INF_ATTN)
+    last = pos if early is None else jnp.where(
+        jnp.arange(Lb) < early[0], early[1], pos)[:, None, None]
+    s = jnp.where(jnp.arange(S) <= last, s, NEG_INF_ATTN)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bglrk,bkgd->blgrd", p, v_l).reshape(B, Lb, H, Dh)
 

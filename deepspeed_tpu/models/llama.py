@@ -111,8 +111,12 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
     - 1`` (``cached_decode_attention`` with a block of query positions: the
     decode kernel's multi-row form), the logits row of position i
     predicting the token AT i. ``pos`` advances only when a step COMMITS
-    its block. How a block is denoised (``denoising_steps``, ``remasking``,
-    ``confidence_threshold``, ``mask_token_id``) is the configuration's and
+    a block: in a pass of its own (``commit=True``, the definition) or, as
+    the engine runs it, CARRIED in the next block's first pass (``pending=``:
+    ``2 Lb`` positions, the finished block's blind to the new block's slots:
+    ``cached_decode_attention(early=)``). How a block is denoised
+    (``denoising_steps``, ``remasking``, ``confidence_threshold``,
+    ``mask_token_id``) is the configuration's and
     the inference engine's (``inference/engine.py``: ``block_decoding``).
     Softmax GQA layers only: a KDA, window or latent layer is refused.
 
@@ -472,7 +476,8 @@ class LlamaConfig:
         """Forward passes over a position for one generated token: 1
         autoregressively; of a block-diffusion model the block's
         ``denoising_steps`` passes (the most: a confident block needs fewer)
-        and the one that commits it."""
+        and the one that commits it (as rows of the next block's first pass:
+        the FLOPs of a pass, not a stream of the weights)."""
         return self.denoising_steps + 1 if self.block_length else 1
 
     def generate_flops_per_token(self, context: int = 0) -> float:
@@ -1002,7 +1007,7 @@ class LlamaModel:
             return attention(q, k, v), (latent,)
 
     def _attend_cached(self, x, blk, cos_sin, caches, layer, pos,
-                       window=None):
+                       window=None, early: int = 0):
         """The new token's attention over the cache (decode): its rows are
         written into slot ``pos`` of ``layer``, then attended with the rest
         — under a ``window`` with the last ``window`` slots (the cache holds
@@ -1011,7 +1016,9 @@ class LlamaModel:
         -> (attn (B, 1, H, Dv), the caches). x may hold a BLOCK of T
         positions (``block_step``): their rows go into slots ``pos .. pos +
         T - 1`` and every one of them attends over slots ``0 .. pos + T -
-        1``; -> attn (B, T, H, Dv)."""
+        1``, but the first ``early`` of them (a block carried before the
+        step's own) over slots ``0 .. pos + early - 1``; -> attn (B, T, H,
+        Dv)."""
         from deepspeed_tpu.models.common import (cached_decode_attention,
                                                  kv_cache_write,
                                                  latent_decode_attention)
@@ -1028,7 +1035,8 @@ class LlamaModel:
                 if x.shape[1] > 1:
                     attn = cached_decode_attention(
                         q, cache_k, cache_v, layer, pos + x.shape[1] - 1,
-                        c.n_kv_head)
+                        c.n_kv_head,
+                        early=(early, pos + early - 1) if early else None)
                     return self._gated(attn, x, blk), (cache_k, cache_v)
                 attn = cached_decode_attention(q[:, 0], cache_k, cache_v,
                                                layer, pos, c.n_kv_head,
@@ -1337,8 +1345,11 @@ class LlamaModel:
         has been given since the prompt's first token — summed by the
         compiled programs themselves (the front-end reads them back when a
         request resolves). A block-diffusion model's carries
-        ``block_passes`` (2,) int32, summed the same way: the denoising
-        passes and the commits its block steps have run."""
+        ``block_passes`` (4,) int32, summed the same way (what its block
+        steps have run: ``common.BLOCK_COUNTS``), and ``pending`` (B, Lb)
+        int32: the final tokens of the block at ``pos .. pos + Lb - 1`` that
+        is finished but not committed, for the next block's first pass to
+        carry (``block_step(pending=)``); zeros until a block is."""
         from deepspeed_tpu.models.common import init_kv_cache
 
         c = self.config
@@ -1354,7 +1365,9 @@ class LlamaModel:
             cache["expert_tokens"] = jnp.zeros((c.n_moe_layers, c.n_held),
                                                jnp.int32)
         if c.block_length:
-            cache["block_passes"] = jnp.zeros((2,), jnp.int32)
+            cache["block_passes"] = jnp.zeros((4,), jnp.int32)
+            cache["pending"] = jnp.zeros((batch_size, c.block_length),
+                                         jnp.int32)
         return cache
 
     def cache_partition_specs(self):
@@ -1369,11 +1382,11 @@ class LlamaModel:
         if self.config.n_experts:
             specs["expert_tokens"] = P()
         if self.config.block_length:
-            specs["block_passes"] = P()
+            specs["block_passes"] = specs["pending"] = P()
         return specs
 
     def _mix_cached(self, x, blk, cos_sin, caches, at, pos, attention=None,
-                    kind="attn"):
+                    kind="attn", early: int = 0):
         """A layer's mixer on x against the cache, by the leaves the block
         holds -> (what ``_block_finish`` takes, the caches). ``caches``: the
         arrays of ``_cache_names`` (the rows a position first); ``at``: the
@@ -1383,13 +1396,13 @@ class LlamaModel:
         the cache holds for it — zeros for a new sequence — and puts back
         what the last position left. ``kind``: the layer's, where its leaves
         cannot say it (a window layer: the same rows in the cache, a window
-        over them)."""
+        over them). ``early``: ``_attend_cached``'s."""
         n_rows = len(self._cache_layout()[2])
         if "kda_qkv_w" not in blk:
             cos_sin, window = self._rope_of(kind, cos_sin), self._window(kind)
             if attention is None:
                 attn, rows = self._attend_cached(
-                    x, blk, cos_sin, caches[:n_rows], at, pos, window)
+                    x, blk, cos_sin, caches[:n_rows], at, pos, window, early)
             else:
                 from deepspeed_tpu.models.common import kv_cache_write
 
@@ -1414,7 +1427,8 @@ class LlamaModel:
             return attn, caches[:n_rows] + (put(states, state),
                                             put(tails, tail))
 
-    def _run_cached(self, params, x, cache, cos_sin, pos, attention=None):
+    def _run_cached(self, params, x, cache, cos_sin, pos, attention=None,
+                    early: int = 0):
         """x through every layer against the cache (``_mix_cached``) -> (x,
         the cache's carried arrays by name, the pairs each held expert was
         given (L_routed, E held) or None). As in gpt2.decode_step the
@@ -1447,7 +1461,7 @@ class LlamaModel:
                 at, mine = self._layer_at(pattern, n, j, i)
                 attn, caches = self._mix_cached(
                     x, blk, cos_sin, caches, before[pattern[j]] + mine, pos,
-                    attention, pattern[j])
+                    attention, pattern[j], early)
                 x, stats = self._block_finish(x, blk, attn, experts, at)
                 return x, caches, None if stats is None else stats[0]
 
@@ -1501,8 +1515,9 @@ class LlamaModel:
         out["pos"] = jnp.int32(T)
         if routed is not None:
             out["expert_tokens"] = routed
-        if "block_passes" in cache:
-            out["block_passes"] = cache["block_passes"]
+        for kept in ("block_passes", "pending"):
+            if kept in cache:
+                out[kept] = cache[kept]
         return logits, out
 
     def decode_step(self, params, token, cache):
@@ -1521,7 +1536,8 @@ class LlamaModel:
             out["expert_tokens"] = cache["expert_tokens"] + routed
         return logits, out
 
-    def block_step(self, params, tokens, masked, cache, commit: bool = False):
+    def block_step(self, params, tokens, masked, cache, commit: bool = False,
+                   pending=None):
         """One forward pass over a BLOCK of a model that generates by
         diffusion over blocks: ``tokens`` (B, Lb) int32 at positions ``pos ..
         pos + Lb - 1``, read as the mask token where ``masked`` (B, Lb) bool
@@ -1532,35 +1548,42 @@ class LlamaModel:
         prediction of the token AT position i; the cache, ``pos`` where it
         was: the next pass over the block overwrites the rows). ``commit``:
         the pass over a FINISHED block that leaves its rows for good: ->
-        (None, the cache with ``pos`` advanced by Lb). It may be a TRACED
-        bool, so that a loop over a block's passes holds the layers once:
-        the head then sits under a ``lax.cond`` and a committing pass
-        returns zeros for logits. One walk with ``prefill`` and
-        ``decode_step`` (``_run_cached``)."""
+        (None, the cache with ``pos`` advanced by Lb). That pair is the
+        DEFINITION; what the engine runs is ``pending`` (B, Lb) int32: the
+        final tokens of the finished block BEFORE this one, which lies at
+        ``pos .. pos + Lb - 1`` uncommitted, carried in this pass: ``2 Lb``
+        positions from ``pos`` on, the pending block's then this block's,
+        all their rows written; the pending positions attend over slots ``0
+        .. pos + Lb - 1`` (every layer computes for them what the
+        committing pass would), this block's over ``0 .. pos + 2 Lb - 1``.
+        -> (logits of THIS block's positions, the cache with ``pos`` advanced
+        by Lb: the pending block is committed). One walk with ``prefill``
+        and ``decode_step`` (``_run_cached``)."""
         c = self.config
         if not c.block_length:
             raise ValueError("block_step: the model's configuration has no "
                              "block_length (it generates a token a step)")
+        if commit and pending is not None:
+            raise ValueError("block_step: a pass commits its own block or "
+                             "carries the one before it")
         pos, Lb = cache["pos"], tokens.shape[1]
         ids = jnp.where(masked, jnp.int32(c.mask_token_id), tokens)
-        x = self._embed(params, ids)                            # (B, Lb, D)
+        carried = 0 if pending is None else pending.shape[1]
+        if carried:
+            ids = jnp.concatenate([pending.astype(ids.dtype), ids], axis=1)
+        x = self._embed(params, ids)                    # (B, [Lb +] Lb, D)
         x, out, routed = self._run_cached(
-            params, x, cache, self._rope(pos + jnp.arange(Lb)), pos)
-        def head():
+            params, x, cache, self._rope(pos + jnp.arange(carried + Lb)), pos,
+            early=carried)
+        logits = None
+        if not commit:
             with scope("head"):
-                h = self._rms_norm(x, params["norm_g"])
-                return (h @ self._head(params, h.dtype)).astype(jnp.float32)
-
-        if isinstance(commit, bool):
-            logits = None if commit else head()
-        else:
-            logits = jax.lax.cond(
-                commit, lambda: jnp.zeros(
-                    tokens.shape + (c.vocab_size,), jnp.float32), head)
-        commit = jnp.asarray(commit, jnp.int32)
-        out["pos"] = pos + Lb * commit
+                h = self._rms_norm(x[:, carried:], params["norm_g"])
+                logits = (h @ self._head(params, h.dtype)).astype(jnp.float32)
+        out["pos"] = pos + (Lb if commit else carried)
         if routed is not None:
             out["expert_tokens"] = cache["expert_tokens"] + routed
-        out["block_passes"] = cache["block_passes"] + jnp.stack(
-            [1 - commit, commit])
+        out["block_passes"] = cache["block_passes"] + jnp.asarray(
+            [not commit, bool(commit), bool(carried), 0], jnp.int32)
+        out["pending"] = cache["pending"]
         return logits, out

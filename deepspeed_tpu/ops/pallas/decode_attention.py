@@ -35,7 +35,15 @@ the K/V of the positions attended, once, and nothing else:
   rows, one MXU tile, where the row-major form would pad the 4 KV heads to
   16 and run 512. ``query_plan`` takes whichever has fewer rows, the
   row-major one on a tie: one position at (25, 1), (16, 1), (8, 8) heads a
-  group keeps the plan it had.
+  group keeps the plan it had;
+* such a call may give its FIRST positions an earlier last slot (``early``:
+  a block-diffusion step whose pass carries the block before it: that
+  block's positions are blind to the new block's slots). Only the edge
+  blocks change: their mask compares a column with its ROW's limit (from a
+  row iota and the plan: which rows of the matrix are those positions'),
+  every block between the two limits is an edge, and the k-block index is
+  clamped by the later one. A call without it traces none of this (the
+  standing cells' jaxprs are pinned: tests/unit/test_decode_attention.py).
 
 ``latent_decode_attention`` (``latent_decode_attn``) is the same stream for
 latent attention (MLA) in its ABSORBED form: the cache holds one row a
@@ -76,18 +84,22 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _softmax_block(q, k, v, j, pos, block_k: int, edge: bool, acc_sc, m_sc,
-                   l_sc):
+                   l_sc, row_pos=None):
     """One block of the streaming softmax: the query rows q (rows, W)
     against block ``j``'s keys k (block_k, W) and values v (block_k, Wv),
     into the running max, sum and (rows, Wv) accumulator. ``edge``: the
     block that crosses the valid length — slots past ``pos`` (stale
     entries, or the rows a partial last block reads past the array) weigh
-    nothing and add nothing."""
+    nothing and add nothing. ``row_pos``: (rows, block_k) int32, each
+    row's OWN last slot where some rows stop before ``pos`` (a row has a
+    valid slot in an earlier block or this one: its running max is a score's
+    by the time a block it sees nothing of comes)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if edge:
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_k
-        s = jnp.where(cols <= pos, s, NEG_INF)
+        s = jnp.where(cols <= (pos if row_pos is None else row_pos), s,
+                      NEG_INF)
         rows = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) + j * block_k
         v = jnp.where(rows <= pos, v, jnp.zeros_like(v))
     m_prev = m_sc[:, :1]
@@ -118,15 +130,31 @@ def query_plan(n_kv: int, per_group: int):
 
 def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
                    l_sc, *, block_k: int, num_k: int, rep: int, kvp: int,
-                   head_dim: int, group_major: bool = False):
+                   head_dim: int, group_major: bool = False,
+                   early_queries: int = 0):
     """``rep`` units of ``kvp`` rows (``query_plan``). Row-major: unit r is
     row r of ``q_ref`` spread over the KV heads' rows, each in its own
     columns. Group-major: unit g is ALL of ``q_ref``'s rows masked to KV
-    head g's columns."""
+    head g's columns. ``early_queries``: the first that many queries of
+    every KV group (whole positions) see slots through ``sc_ref[2]`` only."""
     j = pl.program_id(1)
     pos = sc_ref[0]
     boundary = pos // block_k               # last block with valid entries
     width = qb_sc.shape[1]
+    # the first block that is an edge for SOME row
+    first_edge = sc_ref[2] // block_k if early_queries else boundary
+
+    def row_pos():
+        # (rows, block_k): the early queries' rows stop at their own slot:
+        # the head of every unit group-major, the first units whole row-major
+        row = jax.lax.broadcasted_iota(jnp.int32, (rep * kvp, block_k), 0)
+        if group_major:
+            early = functools.reduce(jnp.logical_or, (
+                (row >= g * kvp) & (row < g * kvp + early_queries)
+                for g in range(rep)))
+        else:
+            early = row < early_queries * kvp
+        return jnp.where(early, sc_ref[2], pos)
 
     def head_columns(g=None):
         # (kvp, W): True where column c belongs to KV head g (None: the row)
@@ -154,13 +182,15 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
 
     def block_update(edge: bool):
         _softmax_block(qb_sc[:], k_ref[0, 0], v_ref[0, 0], j, pos, block_k,
-                       edge, acc_sc, m_sc, l_sc)
+                       edge, acc_sc, m_sc, l_sc,
+                       row_pos() if edge and early_queries else None)
 
-    @pl.when(j < boundary)
+    @pl.when(j < first_edge)
     def _interior():                        # fully inside the valid prefix
         block_update(edge=False)
 
-    @pl.when(j == boundary)
+    @pl.when((j >= first_edge) & (j <= boundary) if early_queries
+             else j == boundary)
     def _edge():
         block_update(edge=True)
 
@@ -181,14 +211,17 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
 
 
 def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
-                     block_k: int = DEFAULT_BLOCK_K):
+                     block_k: int = DEFAULT_BLOCK_K, early=None):
     """q: (B, H, Dh) — the new token's queries — or (B, Lb, H, Dh): ``Lb``
-    positions' queries, each of which sees EVERY valid slot (a
+    positions' queries, each of which sees every valid slot (a
     block-diffusion step: its block lies in the last ``Lb`` of them);
     k_cache/v_cache: the stacked ``(L, B, S, W)`` cache, ``W >= n_kv * Dh``
     with KV head ``g`` in columns ``[g * Dh, (g + 1) * Dh)`` and finite
     values everywhere; ``layer`` and ``pos``: traced int32 scalars — the
     layer attended and the last valid slot (valid length = pos + 1).
+    ``early``: ``(n, last)``, a last valid slot PER POSITION in two values:
+    the first ``n`` (static, 0 < n < Lb) of the ``Lb`` positions see slots
+    ``0 .. last`` (traced, 0 <= last <= pos), the others ``0 .. pos``.
     Returns q's shape.
 
     ``H % n_kv == 0`` (grouped-query attention; H == n_kv is plain MHA).
@@ -197,6 +230,9 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
     if one:
         q = q[:, None]
     B, Lb, H, Dh = q.shape
+    if early is not None and not 0 < early[0] < Lb:
+        raise ValueError(f"early {early[0]}: some, not all, of the {Lb} "
+                         "query positions")
     S, W = k_cache.shape[2], k_cache.shape[3]
     if H % n_kv:
         raise ValueError(f"query heads {H} not divisible by KV heads {n_kv}")
@@ -221,8 +257,9 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
     qf = jnp.pad(qf.astype(k_cache.dtype),
                  ((0, 0), (0, q_rows - per_group), (0, W - C)))
 
-    scalars = jnp.stack([jnp.asarray(pos, jnp.int32).reshape(()),
-                         jnp.asarray(layer, jnp.int32).reshape(())])
+    # (the last valid slot, the layer[, the early positions' last slot])
+    scalars = jnp.stack([jnp.asarray(x, jnp.int32).reshape(()) for x in
+                         (pos, layer) + (() if early is None else early[1:])])
     # blocks past the valid boundary present the boundary block's index again
     # → the pipeline skips their DMA entirely
     # (index-map signature: grid indices first, then the scalar-prefetch refs)
@@ -232,7 +269,9 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_k=bk, num_k=nk, rep=rep,
-                          kvp=kvp, head_dim=Dh, group_major=group_major),
+                          kvp=kvp, head_dim=Dh, group_major=group_major,
+                          early_queries=early[0] * (H // n_kv) if early
+                          else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, nk),

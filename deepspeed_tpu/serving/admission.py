@@ -74,10 +74,11 @@ class Request:
     prefill_done_at: Optional[float] = None   # the prefill tick returned
     first_tokens_at: Optional[float] = None   # the first token is on the host
     decode_ticks: int = 0             # decode chunks that ran to their end
-    # (denoising passes, commits) a block-diffusion model's block steps ran
-    # for the request, read back from the cache when it completes; None: a
-    # model that emits a token a step
-    block_passes: Optional[Tuple[int, int]] = None
+    # what a block-diffusion model's block steps ran for the request
+    # (``models/common.py::BLOCK_COUNTS``: passes, commits, carried, blocks),
+    # read back from the cache when it completes; None: a model that emits
+    # a token a step
+    block_passes: Optional[Tuple[int, int, int, int]] = None
     compile_s: float = 0.0            # spent in ticks that compiled their program
     # bytes one position of one sequence holds in the cache the prefill
     # handed back, all layers, pad lanes and all (its (L, B, S, W) arrays),

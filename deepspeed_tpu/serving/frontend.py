@@ -7,7 +7,9 @@ same scan body ``generate()`` compiles), delivering what a tick chose when
 it returns: the prefill tick the first token, a decode tick its chunk. A
 step of the programs may emit MORE than one token a row (a model that
 generates by diffusion over blocks: the prefill tick then delivers the
-first block, a decode tick ``decode_tick_tokens // block`` of them), so
+first block, a decode tick ``decode_tick_tokens // block`` of them; a
+block's keys and values are committed by the first pass of the NEXT block,
+so the last block a request is delivered is never committed), so
 tokens, cache positions and the service estimate are counted from what
 the programs return, never from "one token a step".
 Every tick runs under the watchdog's ``run_with_deadline``, so a hung
@@ -507,7 +509,8 @@ class ServingFrontEnd:
                 phase, cat="serving", request=req.id,
                 # positions in the cache when the tick starts: the prompt
                 # and every token but the last, which this tick steps on (a
-                # block step has committed every token it delivered)
+                # block step has written every token it delivered; the last
+                # block's are committed by this tick's first pass)
                 context=int(req.prompt.shape[1]) + max(
                     len(req.tokens) - (self._step_tokens == 1), 0),
                 index=req.decode_ticks) as tick:
@@ -595,10 +598,11 @@ class ServingFrontEnd:
                 if req.block_passes is not None:
                     # a model that generates by diffusion over blocks: what
                     # its block steps ran, as the programs counted it
-                    passes, commits = req.block_passes
+                    from deepspeed_tpu.models.common import BLOCK_COUNTS
+
                     dec = self.engine.module.block_decoding
                     span.args.update(
-                        blocks=commits, passes=passes, commits=commits,
+                        zip(BLOCK_COUNTS, req.block_passes),
                         block_length=int(dec.length),
                         denoising_steps=int(dec.steps))
                 span.args.update(
@@ -619,10 +623,11 @@ class ServingFrontEnd:
                     * req.cache_state_bytes)
 
     def _cache_positions(self, req: Request) -> int:
-        """Positions of a sequence the request's programs have COMMITTED to
+        """Positions of a sequence the request's programs have written in
         the cache: the prompt and every step of every decode chunk; of a
         model whose step is a block, the prompt's whole blocks, the block
-        the prefill tick committed and those of every decode chunk."""
+        of the prefill tick and those of every decode chunk (the last of
+        them written by its passes, committed by none)."""
         if req.prefill_done_at is None:
             return 0
         n, prompt = self._step_tokens, int(req.prompt.shape[1])
@@ -631,12 +636,14 @@ class ServingFrontEnd:
 
     def _positions_run(self, req: Request) -> int:
         """Positions the request's programs have put through the layers:
-        the committed ones, and of a block step every denoising pass over
-        its block besides."""
+        those in the cache; of a block model the prompt's whole blocks and a
+        block's length for every pass, denoising or committing, and for
+        every block a pass carried beside its own."""
         if req.block_passes is None:
             return self._cache_positions(req)
-        return self._cache_positions(req) \
-            + req.block_passes[0] * self._step_tokens
+        n, prompt = self._step_tokens, int(req.prompt.shape[1])
+        passes, commits, carried, _ = req.block_passes
+        return prompt - prompt % n + n * (passes + commits + carried)
 
     def _serve(self, req: Request, tracer) -> None:
         import jax
@@ -749,18 +756,23 @@ class ServingFrontEnd:
 
     def _count_block_passes(self, req: Request, cache) -> None:
         """A block-diffusion model's programs sum, in the cache they hand
-        from tick to tick, the denoising passes and the commits their block
-        steps ran (``block_passes``). One read when the request has its
-        tokens, as ``expert_tokens``: onto the request (its span's
-        ``passes`` / ``commits`` / ``blocks``) and into the counters
-        ``serving/passes`` and ``serving/blocks``."""
+        from tick to tick, what their block steps ran (``block_passes``, in
+        ``BLOCK_COUNTS``' order): forward passes that denoise, forward
+        passes that ONLY commit (none: a served block's commit is carried),
+        blocks whose commit rode in a later block's first pass, blocks
+        finished. One read when the request has its tokens, as
+        ``expert_tokens``: onto the request (its span's ``passes`` /
+        ``commits`` / ``carried`` / ``blocks``) and into the counters
+        ``serving/passes`` (forward passes of either kind),
+        ``serving/carried`` and ``serving/blocks``."""
         ran = cache.get("block_passes") if isinstance(cache, dict) else None
         if ran is None:
             return
-        passes, commits = (int(v) for v in np.asarray(ran))
-        req.block_passes = (passes, commits)
+        req.block_passes = tuple(int(v) for v in np.asarray(ran))
+        passes, commits, carried, blocks = req.block_passes
         self._count("passes", n=passes + commits)
-        self._count("blocks", n=commits)
+        self._count("carried", n=carried)
+        self._count("blocks", n=blocks)
 
     def _count_expert_tokens(self, req: Request, cache, tracer) -> None:
         """A routed (MoE) model's programs sum, in the cache they hand from

@@ -36,18 +36,22 @@ def _slow_nodeids():
 
 
 # The files whose tests take longest, longest first (the sums of their
-# tests' times in a whole six-worker tier-1 run, PR 45: 930 s down to 200 s
-# of 7,500). `--dist loadfile` hands whole files to workers in the order of
+# tests' times in PR 46's whole six-worker tier-1 run in the sandbox under
+# the driver's command, 2 Oct 2026, on a loaded machine: 856 s down to 252 s
+# of 7,923, the run 1,373 s of 1,470 allowed with PR 45's list, which had
+# `test_kimi_cell.py` (395 s) last of its twelve; the driver's own run of
+# PR 46's tree, 5,937 s of tests in 1,174 s, ranks the same first five).
+# `--dist loadfile` hands whole files to workers in the order of
 # its queue, which xdist sorts by a file's NUMBER of tests: a file of three
 # tests that take 200-370 s (`test_kimi_cell.py`) then starts last and one
 # worker ends alone with it (~100 s of a run). With the long files at the
 # head and the others behind them in xdist's own order, a run ends 70-110 s
 # sooner. Regenerate from a run's junit file when the suite's shape changes.
 _LONG_FILES = (
-    "test_kda.py", "test_chip_bringup.py", "test_solar_family.py",
-    "test_pangu_family.py", "test_afmoe.py", "test_flash_attention.py",
-    "test_sdar_family.py", "test_pangu.py", "test_afmoe_family.py",
-    "test_first_token.py", "test_zero3_gather.py", "test_kimi_cell.py")
+    "test_chip_bringup.py", "test_kda.py", "test_afmoe_family.py",
+    "test_solar_family.py", "test_kimi_cell.py", "test_zero3_gather.py",
+    "test_pangu.py", "test_flash_attention.py", "test_afmoe.py",
+    "test_pangu_family.py", "test_olmoe_family.py", "test_first_token.py")
 
 
 def pytest_configure(config):

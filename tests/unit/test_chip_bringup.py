@@ -1148,12 +1148,13 @@ def sdar(v5e):
         model, 8192, 16, False, 1.0, 0, 1.0, None)
 
 
-def test_sdar_decode_chunk_for_v5e_holds_the_layers_once(sdar):
-    """A chunk of 4 block steps: ONE multi-row ``decode_attn`` (4 positions
-    x 8 heads a group x 4 KV heads = 128 query rows, group-major) and one
-    pair of thin grouped matmuls under the share's conditional in the
-    program, the denoising passes and the commit being one loop; the head
-    under a conditional of its own (a committing pass skips it); it fits."""
+def test_sdar_decode_chunk_for_v5e_holds_the_layers_twice(sdar):
+    """A chunk of 4 block steps holds the model TWICE: a step's first pass,
+    which carries the block before it (8 positions x 8 heads a group x 4 KV
+    heads = 256 query rows, group-major), and the loop of its other passes
+    over its own 4 (128 rows); a pair of thin grouped matmuls under the
+    share's conditional in each; no pass only commits, so no head under a
+    conditional; it fits."""
     mesh, model, params, cache, _, chunk = sdar
     with mesh:
         compiled = jax.jit(chunk).lower(
@@ -1161,10 +1162,12 @@ def test_sdar_decode_chunk_for_v5e_holds_the_layers_once(sdar):
     text = compiled.as_text()
     for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin", "decode_attn"):
         assert len(re.findall(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call",
-                              text)) == 1, kernel
-    call, = re.findall(r"%decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
-    assert "bf16[1,32,512]" in call         # 4 x 8 query rows a KV head
-    assert text.count(" conditional(") >= 2
+                              text)) == 2, kernel
+    carrying, own = re.findall(
+        r"%decode_attn[\w.]* = [^\n]*tpu_custom_call", text)
+    assert "bf16[1,64,512]" in carrying     # 8 x 8 query rows a KV head
+    assert "bf16[1,32,512]" in own          # 4 x 8
+    assert text.count(" conditional(") == 2
     assert "head/unmask" in text
     args, total = _footprint(compiled)
     assert args < 2 * model.config.num_params() + 1.0 * GIB

@@ -231,3 +231,106 @@ def test_front_end_on_the_kernel_path_emits_generates_tokens(as_tpu_program):
         front.close()
     ref = np.asarray(engine.generate(prompt, max_new_tokens=20))
     assert req.tokens == ref[0, 250:].tolist()
+
+
+# ------------------------------------- a last valid slot PER POSITION (early)
+def _block(B, S, Lb, H, KV, Dh, seed):
+    """q (B, Lb, H, Dh) and a two-layer cache, as ``_rand``."""
+    q, k, v = _rand(B, S, Lb * H, KV, Dh, seed=seed, layers=2)
+    return q.reshape(B, Lb, H, Dh), k, v
+
+
+# (positions, early, heads, KV heads): SDAR's carried pass (8 x 32 on 4:
+# group-major, 4 units of 64 rows) and a row-major plan, each with the later
+# limit ``pos`` (the early one is ``Lb - early`` slots before it) so that both
+# lie in one 128-slot block, in neighbouring blocks, the early one a block's
+# last slot, and in a fresh sequence (slots 0 .. Lb - 1 alone); a group-major
+# plan whose unit is padded (6 x 4 heads a group = 24 rows in 32) and a
+# second row-major one across a block's edge
+@pytest.mark.parametrize("Lb,n,heads,kv,pos", [
+    *((8, 4, 32, 4, pos) for pos in ("fresh", 100, 129, 131, 255)),
+    *((4, 2, 16, 16, pos) for pos in ("fresh", 100, 129, 255)),
+    (6, 2, 8, 2, 130), (2, 1, 4, 4, 128)])
+def test_a_limit_per_position_matches_the_einsum(Lb, n, heads, kv, pos):
+    B, S, Dh = 2, 256, 32
+    pos = Lb - 1 if pos == "fresh" else pos
+    last = pos - (Lb - n)
+    q, k, v = _block(B, S, Lb, heads, kv, Dh, seed=pos + Lb)
+    got = da.decode_attention(q, k, v, jnp.int32(1), jnp.int32(pos), n_kv=kv,
+                              early=(n, jnp.int32(last)))
+    want = common.cached_decode_attention(
+        q, k, v, jnp.int32(1), jnp.int32(pos), kv, early=(n, jnp.int32(last)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    # the twin's early rows are a call of their own through ``last``, the
+    # others the call without a limit of their own: two limits, no more
+    for rows, upto in ((slice(0, n), last), (slice(n, Lb), pos)):
+        alone = common.cached_decode_attention(
+            q[:, rows], k, v, jnp.int32(1), jnp.int32(upto), kv)
+        np.testing.assert_allclose(np.asarray(want[:, rows]),
+                                   np.asarray(alone), atol=2e-6, rtol=2e-6)
+    assert np.abs(np.asarray(want[:, :n]) - np.asarray(
+        common.cached_decode_attention(q, k, v, jnp.int32(1), jnp.int32(pos),
+                                       kv)[:, :n])).max() > 1e-3
+
+
+def test_a_limit_per_position_far_apart_and_refused(as_tpu_program):
+    """Limits more than a block apart (every block between them is an
+    edge), through ``cached_decode_attention`` as a TPU program calls it;
+    what the argument refuses."""
+    q, k, v = _block(1, 512, 8, 32, 4, 32, seed=9)
+    call = lambda **kw: common.cached_decode_attention(
+        q, k, v, jnp.int32(0), jnp.int32(400), 4, **kw)
+    assert "decode_attn" in str(jax.make_jaxpr(
+        lambda: call(early=(4, jnp.int32(90))))())
+    got = call(early=(4, jnp.int32(90)))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(common, "_kernel_target", lambda: (None, False))
+        want = call(early=(4, jnp.int32(90)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    for bad in (0, 8):
+        with pytest.raises(ValueError, match="early"):
+            da.decode_attention(q, k, v, jnp.int32(0), jnp.int32(400), n_kv=4,
+                                early=(bad, jnp.int32(90)))
+    with pytest.raises(ValueError, match="early"):
+        common.cached_decode_attention(q[:, 0], k, v, jnp.int32(0),
+                                       jnp.int32(400), 4,
+                                       early=(1, jnp.int32(90)))
+
+
+# sha256 of the jaxpr (kernel body and all) each call traced to at the commit
+# BEFORE ``early`` existed (bbeb614; jax 0.9.0): gpt2-xl's and a GQA model's
+# one-position step, and SDAR's 4-row block step. To refresh after a change
+# that is MEANT to move them: print ``_digest`` from a checkout of the parent
+PARENTS = {((1, 25, 64), 25): "409c2c0e3e7806ca",
+           ((1, 32, 64), 8): "b2668f1db1ae068c",
+           ((1, 4, 32, 128), 4): "a5b9fa50b57a6c0a"}
+
+
+def _digest(q_shape, kv, **kw):
+    import hashlib
+
+    q = jnp.zeros(q_shape, jnp.float32)
+    k = jnp.zeros((2, q_shape[0], 1024, common.kv_cache_width(
+        kv, q_shape[-1])), jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda q, k, v, layer, pos: da.decode_attention(
+            q, k, v, layer, pos, n_kv=kv, **kw))(
+                q, k, k, jnp.int32(0), jnp.int32(5)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the digests are of jax 0.9.0's jaxprs")
+@pytest.mark.parametrize("q_shape,kv", list(PARENTS))
+def test_a_call_without_the_limit_is_the_call_it_was(q_shape, kv, monkeypatch):
+    """The standing cells' decode steps trace to the program they traced to
+    before a call could carry a limit per position; a call that carries one
+    does not."""
+    monkeypatch.undo()          # traced as a program for the chip traces it
+    assert _digest(q_shape, kv) == PARENTS[q_shape, kv]
+    assert _digest(q_shape, kv, early=None) == PARENTS[q_shape, kv]
+    if len(q_shape) == 4:
+        assert _digest(q_shape, kv, early=(2, jnp.int32(3))) != \
+            PARENTS[q_shape, kv]

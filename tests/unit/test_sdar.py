@@ -200,12 +200,12 @@ def test_block_steps_through_the_cache_are_the_full_pass(held, prompt):
         got, passed = step(ids[None, at], masked[None, at], cache)
         np.testing.assert_allclose(np.asarray(got[0]), want[at], atol=2e-5)
         assert int(passed["pos"]) == prompt
-        assert passed["block_passes"].tolist() == [1, 0]
+        assert passed["block_passes"].tolist() == [1, 0, 0, 0]
         none, kept = model.block_step(params, ids[None, at],
                                       np.zeros((1, 4), bool), passed,
                                       commit=True)
         assert none is None and int(kept["pos"]) == prompt + 4
-        assert kept["block_passes"].tolist() == [1, 1]
+        assert kept["block_passes"].tolist() == [1, 1, 0, 0]
         # (e) the committed rows are the final tokens': one prefill of all of
         # them writes the same cache
         whole = prefill(ids[None, :prompt + 4])
@@ -221,6 +221,53 @@ def test_block_steps_through_the_cache_are_the_full_pass(held, prompt):
         np.testing.assert_allclose(np.asarray(got[0]), want[nxt], atol=2e-5)
     assert int(kept["expert_tokens"].sum()) > int(
         cache["expert_tokens"].sum())
+
+
+# (prompt, which of the NEW block's positions are masked): the engine's
+# carrying pass (all masked), a block that opens with two given tokens, and a
+# pending block that is the sequence's first (``pos`` 0)
+@pytest.mark.parametrize("prompt,masked", [(12, (1, 1, 1, 1)),
+                                           (8, (0, 0, 1, 1)),
+                                           (0, (1, 1, 1, 1))])
+def test_a_carrying_pass_is_the_commit_and_the_next_pass(held, prompt, masked):
+    """``block_step(pending=)`` over [the finished block | the next] leaves
+    the cache rows, ``pos`` and the next block's logits that the DEFINITION
+    leaves: ``block_step(commit=True)`` over the finished block, then a plain
+    pass over the next (float32, the einsum path: the same rows from the
+    same inputs, layer by layer)."""
+    model, params = held
+    ids = np.random.default_rng(prompt + 1).integers(
+        0, 127, size=(2, prompt + 8), dtype=np.int32)
+    masked = np.broadcast_to(np.asarray(masked, bool), (2, 4))
+    done, nxt = ids[:, prompt:prompt + 4], ids[:, prompt + 4:]
+    with jax.default_matmul_precision("highest"):
+        cache = model.init_cache(2, 32)
+        if prompt:
+            _, cache = jax.jit(model.prefill)(params, ids[:, :prompt], cache)
+        # the finished block's own denoising pass came before either
+        _, cache = model.block_step(params, done, np.zeros((2, 4), bool),
+                                    cache)
+        _, kept = model.block_step(params, done, np.zeros((2, 4), bool),
+                                   cache, commit=True)
+        want, plain = model.block_step(params, nxt, masked, kept)
+        got, carried = model.block_step(params, nxt, masked, cache,
+                                        pending=done)
+    assert got.shape == want.shape == (2, 4, 128)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5,
+                               rtol=0)
+    assert int(carried["pos"]) == int(plain["pos"]) == prompt + 4
+    for name in ("k", "v"):
+        np.testing.assert_allclose(
+            np.asarray(carried[name][:, :, :prompt + 8]),
+            np.asarray(plain[name][:, :, :prompt + 8]), atol=1e-6, rtol=0)
+    # the same rows met the routers; one forward pass where there were two
+    np.testing.assert_array_equal(np.asarray(carried["expert_tokens"]),
+                                  np.asarray(plain["expert_tokens"]))
+    assert plain["block_passes"].tolist() == [2, 1, 0, 0]
+    assert carried["block_passes"].tolist() == [2, 0, 1, 0]
+    with pytest.raises(ValueError, match="commits its own block or carries"):
+        model.block_step(params, nxt, masked, cache, commit=True,
+                         pending=done)
 
 
 def plain_generate(model, params, prompt, new, eos=None):
@@ -422,20 +469,30 @@ def test_the_front_end_counts_what_a_block_step_returns(held):
             ticks = -(-(new - first) // 8) if new > first else 0
             assert req.decode_ticks == ticks
             blocks = 1 + 2 * ticks
-            # 2 passes a block; the first block as many as its masks need
+            # 2 passes a block; the first block as many as its masks need.
+            # No pass only commits: every block but the last rode in the
+            # first pass of the next
             passes = 2 * (blocks - 1) + ie.block_passes(
                 model.block_decoding, prompt % 4)
-            assert req.block_passes == (passes, blocks)
+            assert req.block_passes == (passes, 0, blocks - 1, blocks)
             span = [s for s in telemetry.get_tracer().snapshot()
                     if s.name == "request" and s.args.get("request") == req.id]
             args = span[-1].args
-            assert (args["blocks"], args["passes"], args["commits"]) == (
-                blocks, passes, blocks)
+            assert (args["blocks"], args["passes"], args["commits"],
+                    args["carried"]) == (blocks, passes, 0, blocks - 1)
             assert (args["block_length"], args["denoising_steps"]) == (4, 2)
             assert args["cache_positions"] == prompt - prompt % 4 + 4 * blocks
             assert args["new_tokens"] == new
+            # every pass and every carried block is 4 positions the routers
+            # saw: (token, expert) pairs in 2 routed layers, 4 a token
+            routed = [s for s in telemetry.get_tracer().snapshot()
+                      if s.name == "moe/expert_tokens"
+                      and s.args.get("request") == req.id]
+            assert routed[-1].args["routed_pairs"] == 2 * 4 * (
+                prompt - prompt % 4 + 4 * (passes + blocks - 1))
         assert front.counts["blocks"] == sum(
             1 + 2 * n for n in (2, 1))
+        assert front.counts["carried"] == front.counts["blocks"] - 2
         assert front.counts["passes"] > front.counts["blocks"]
         # a tick that is no whole number of blocks is refused when the
         # programs are built, not served wrong
@@ -449,3 +506,47 @@ def test_the_front_end_counts_what_a_block_step_returns(held):
             bad.close()
     finally:
         front.close()
+
+
+def test_a_request_of_33_blocks_reads_66_0_32_33(held, tmp_path):
+    """The cell's request in small (a prompt of whole blocks, 132 new
+    tokens, ticks of 16): the first block in the prefill tick and 8 ticks of
+    4; every block but the last rode in the next block's first pass, no pass
+    only committed; the span, the request and the registry's counters say
+    so, and the tokens are the definition's."""
+    from deepspeed_tpu import serving, telemetry
+    from deepspeed_tpu.runtime.config import DeepSpeedConfig, TelemetryConfig
+
+    _, params = held
+    model = tiny(n_positions=160)
+    tel = telemetry.configure(TelemetryConfig(
+        enabled=True, output_dir=str(tmp_path / "t"), flush_interval=1000,
+        prometheus=False))
+    engine = deepspeed_tpu.init_inference(model, dtype="fp32", params=params,
+                                          max_out_tokens=160)
+    front = serving.from_ds_config(engine, DeepSpeedConfig({"serving": {
+        "decode_tick_tokens": 16, "max_queue_depth": 4}}))
+    try:
+        ids = np.random.default_rng(7).integers(0, 127, size=12,
+                                                dtype=np.int32)
+        with jax.default_matmul_precision("highest"):
+            req = front.submit(ids, max_new_tokens=132)
+            req.result(timeout=300.0)
+        assert req.status == "completed", req.reason
+        want, passes = plain_generate(model, params, ids, 132)
+        np.testing.assert_array_equal(req.tokens, want)
+        assert passes == 66 and req.decode_ticks == 8
+        assert req.block_passes == (66, 0, 32, 33)
+        args = [s for s in telemetry.get_tracer().snapshot()
+                if s.name == "request" and s.args.get("request") == req.id
+                ][-1].args
+        assert [args[n] for n in common.BLOCK_COUNTS] == [66, 0, 32, 33]
+        assert args["cache_positions"] == 12 + 4 * 33
+        assert (front.counts["passes"], front.counts["carried"],
+                front.counts["blocks"]) == (66, 32, 33)
+        assert {name: tel.registry.counter(f"serving/{name}").value
+                for name in ("passes", "carried", "blocks")} == {
+                    "passes": 66, "carried": 32, "blocks": 33}
+    finally:
+        front.close()
+        telemetry.deconfigure()

@@ -12,7 +12,7 @@ Algorithm: standard flash attention v2 online softmax —
   l = l * exp(m - m_new) + rowsum(P);  acc = acc * exp(m - m_new) + P @ V
 Backward recomputes P from the saved logsumexp:
   P = exp(S - lse); dV = Pᵀ dO; dS = P ∘ (dO Vᵀ - Δ); dQ = dS K; dK = dSᵀ Q
-with Δ = rowsum(dO ∘ O) computed outside the kernel.
+with Δ = rowsum(dO ∘ O) computed outside the kernel (five matmuls a tile).
 
 Causal execution (self-attention; the path every prefill and every train
 step takes). ONE forward kernel for every length, its shapes from
@@ -43,23 +43,38 @@ step takes). ONE forward kernel for every length, its shapes from
   equal, and meet a (rows, n) operand tiled along the lanes (``_lanes``):
   a (rows, 1) column pays an XLU broadcast a row group at every use, which
   measured 2 x the whole kernel's time at T = 16,384.
-* The backward kernels keep their own two grids (``_use_tri``: triangular
-  from four row blocks, else rectangular with a double-width k block under
-  the mask); they read the log-sum-exp by row, whatever block wrote it.
+* **ONE backward kernel** for every length (``_bwd_causal_kernel``, its
+  shapes from ``flash_backward_plan(t, d, dtype, window)``): P and dS are
+  recomputed ONCE a tile and all three gradients taken from them, five
+  matmuls and one exp where a dq kernel beside a dkv kernel ran seven and
+  two. The grid is the k-major list of the (k block, q block) pairs at or
+  under the diagonal (``_causal_pairs_colmajor``: T = 1,024 in 512s is 3
+  pairs a head, not the square's 4; of a diagonal pair's four quarters the
+  one above the diagonal is not run either). A step works on the
+  TRANSPOSED scores, S^T = K Q^T (keys, q rows), so the log-sum-exp and
+  delta meet it as the lane-dense (1, block) rows they arrive in,
+  broadcast along sublanes: no column, no cross-lane move; dV += P^T dO
+  and dK += dS^T Q are plain products, dQ += (dS^T)^T K the one that
+  contracts the leading dimension. dk and dv accumulate in (block, d)
+  float32 scratch over a k block's pairs; dq accumulates in a float32
+  scratch of the WHOLE head (T x d x 4 bytes: 0.39 MB at 1,024 x 96, 12.6
+  MB at 16,384 x 192), and q block i is written out at k block i's first
+  pair, its last product. It reads the log-sum-exp by row, whatever block
+  wrote it.
 * **A causal WINDOW** (``flash_attention(window=)``: row i sees the keys j
   with ``0 <= i - j < window``; a model's window layers) is a parameter of
   the same plans, not another family: a q block's span list starts at the
   span that holds the first sub-block with a key inside the window
   (``_causal_spans``: a second bound on the same list), the backward's
-  pair lists keep the pairs of the band alone (``_causal_pairs`` /
-  ``_causal_pairs_colmajor``: a window always takes the triangular grids),
-  and a sub-block pays the mask where the diagonal OR the window's trailing
+  pair list keeps the pairs of the band alone (``_causal_pairs_colmajor``:
+  a k block's q blocks end at the last that sees it), and a sub-block
+  pays the mask where the diagonal OR the window's trailing
   edge crosses it — that edge is a diagonal too (``row - col = window``),
   which is why no block-sparse layout can express it. The windowed forward
   (``_fwd_window_kernel``) runs one softmax update a sub-block, scratch to
   scratch, not yet the wide walk above. Its calls are ``flash_fwd_win`` /
-  ``flash_bwd_dq_win`` / ``flash_bwd_dkv_win``. Without a window every
-  plan, list and traced program is what it was.
+  ``flash_bwd_dkv_win``. Without a window every plan, list and traced
+  program is what it was.
 * **A BLOCK-causal mask** (``flash_attention(block=)``, forward only: the
   prefill of a model that generates by diffusion over blocks): row i sees
   the keys j with ``j // block <= i // block``, blocks counted from
@@ -73,12 +88,16 @@ Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
 (B·H, T, D). v may have a head size of its own (latent attention: q.k at 192
 columns, v at 128): the FORWARD kernels take the value width from v — the
 accumulator, the output and the P @ V pass are that wide, nothing is padded.
-The backward kernels take one width and refuse another for v: no model
-trains through latent attention here yet. The per-row statistics that pass
-between the kernels, the log-sum-exp and the backward's delta, are (B·H, 1,
-T) float32, lane-dense: a kernel transposes a query block's column in VMEM
-(``_row``, ``_col``), since a (B·H, T, 1) array pads the 1 to 128 lanes in
+The backward kernels take one width: a narrower v goes in with zero columns
+(``_attention_vjp``). The per-row statistics that pass between the
+kernels, the log-sum-exp and the backward's delta, are (B·H, 1, T)
+float32, lane-dense, since a (B·H, T, 1) array pads the 1 to 128 lanes in
 HBM — 128 x the bytes for the kernel to write, for XLA to copy and keep.
+A forward kernel turns its query block's column into that row in VMEM
+(``_row``); the causal backward reads the rows as they are, against the
+transposed scores; the non-causal and the block-sparse backward pairs,
+which keep q rows down the sublanes, turn them back into columns
+(``_col``, in ``_bwd_p_ds``).
 So a q block is a multiple of 128 or the whole length (``flash_supports``;
 a caller takes its einsum path for another, as ``local_causal_attention``
 does). The block-sparse kernels, whose tile is the layout's, keep a row a
@@ -86,6 +105,12 @@ block instead: (B·H·n, 1, block).
 
 Every ``pallas_call`` carries a ``name`` (``flash_fwd``, ``flash_bwd_dq``,
 ``flash_bwd_dkv``; ``sparse_`` before each for the block-sparse kernels).
+The causal backward's ONE call is ``flash_bwd_dkv`` (``flash_bwd_dkv_win``
+under a window): it is the dkv kernel, grid and all, which also returns
+dq. So ``flash_bwd_dq`` names the NON-causal dq kernel alone, and no train
+step of a causal model holds a call of that name since PR 47 (it left the
+device-op breakdowns of the train cells; a reader that matches
+``flash_bwd_dq|flash_bwd_dkv`` reads the whole backward as before).
 That name is the last scope of the Mosaic call's ``op_name`` and so the name
 of its HLO instruction (``%flash_fwd.3 = ... custom-call(...)``), which is
 what an op event in a device profile is called — whatever wraps the call
@@ -206,16 +231,10 @@ def _causal_spans(rows: int, n_sub: int, sub: int = 0, window=None):
     return qi, si
 
 
-def _causal_pairs(nq: int, block: int = 0, window=None):
-    """Lower-triangle block pairs, row-major (ki ascending within each qi);
-    with a window, those of the band."""
-    return _causal_spans(nq, 1, block, window)
-
-
 def _causal_pairs_colmajor(nq: int, block: int = 0, window=None):
     """Lower-triangle block pairs, column-major (qi ascending within each ki)
-    — the dkv iteration order: each ki row accumulates over qi = ki..nq-1,
-    with a window up to the last q block that sees it."""
+    — the causal backward's iteration order: each ki row accumulates over
+    qi = ki..nq-1, with a window up to the last q block that sees it."""
     last = [_last_block(i, block, window, nq) for i in range(nq)]
     ki = np.concatenate([np.full(last[i] + 1 - i, i, np.int32)
                          for i in range(nq)])
@@ -253,9 +272,11 @@ def _softmax_update(s, v, m, l, acc):
 
 
 def _scores(q, k, scale, mask_rc=None, window=None):
-    """q . k^T in float32; ``mask_rc`` = (rows, cols) index iotas where the
-    block crosses the diagonal or, with a ``window``, its trailing edge, else
-    None (an interior block pays none of the mask's VPU passes)."""
+    """q . k^T in float32; ``mask_rc`` = (rows, cols), each score's q and
+    key POSITION, where the block crosses the diagonal or, with a
+    ``window``, its trailing edge, else None (an interior block pays none
+    of the mask's VPU passes). The causal backward hands k for q and q for
+    k, and the positions of that transposed block."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if scale != 1.0:
@@ -304,7 +325,9 @@ def _row(x):
 
 def _col(row):
     """(1, n) lane-dense -> (n, 1): a per-row statistic (log-sum-exp, delta)
-    as the column a (n, block_k) score block subtracts."""
+    as the column a (n, block_k) score block subtracts. The non-causal and
+    the block-sparse backward pairs' (``_bwd_p_ds``); the causal backward
+    keeps the row."""
     return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
 
 
@@ -314,23 +337,6 @@ def _write_out(o_ref, lse_ref, m, l, acc):
     l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / _lanes(l, acc.shape[1])).astype(o_ref.dtype)
     lse_ref[0] = _row(m + jnp.log(l))
-
-
-def _causal_dispatch(qi, ki, block_q, block_k, compute):
-    """Rectangular-grid causal dispatch shared by fwd/dq/dkv kernels:
-    run ``compute(mask_rc)`` mask-free on blocks fully below the diagonal,
-    with the iota mask on blocks the diagonal crosses, and not at all on
-    blocks fully above it."""
-    interior = ki * block_k + block_k - 1 <= qi * block_q
-    crosses = (ki * block_k < (qi + 1) * block_q) & jnp.logical_not(interior)
-
-    @pl.when(interior)
-    def _interior():
-        compute(None)
-
-    @pl.when(crosses)
-    def _diag():
-        compute(_block_iotas(block_q, block_k, qi, ki))
 
 
 # ------------------------------------------------------ forward (causal)
@@ -623,24 +629,14 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, window=None,
     )(q, k, v)
 
 
-# the backward's triangular grid skips (nq-1)/2nq of the blocks: worth its
-# bookkeeping from this many row blocks; below, a rectangular grid with a
-# double-width k block
-_BWD_TRI_MIN_BLOCKS = 4
-
-
-def _use_tri(causal, t_q, t_k, bq, bk, window=None) -> bool:
-    """A window always takes the pair lists: only they can drop the pairs
-    outside the band."""
-    return (causal and t_q == t_k and bq == bk
-            and (window is not None or t_q // bq >= _BWD_TRI_MIN_BLOCKS))
-
-
 # -------------------------------------------------------------------- backward
-def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None, window=None):
-    """Recompute P and dS for one block (shared by dq and dkv kernels).
-    ``lse`` and ``delta`` arrive as the query block's lane-dense rows."""
-    p = jnp.exp(_scores(q, k, scale, mask_rc, window) - _col(lse))
+def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None):
+    """Recompute P and dS for one block, q rows down and keys across: the
+    non-causal and the block-sparse dq / dkv pairs, each kernel of which
+    calls it (so a pair recomputes P twice). ``lse`` and ``delta`` arrive as
+    the query block's lane-dense rows and are turned into columns
+    (``_col``). The causal backward has neither (``_bwd_causal_kernel``)."""
+    p = jnp.exp(_scores(q, k, scale, mask_rc) - _col(lse))
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     ds = p * (dp - _col(delta))
@@ -650,81 +646,140 @@ def _bwd_p_ds(q, k, v, do, lse, delta, scale, mask_rc=None, window=None):
     return p, ds
 
 
-def _bwd_dq_tri_kernel(qi_arr, ki_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dq_ref, dq_sc, *, scale, block, window=None):
-    """A q block's dq over its listed k blocks: those under the diagonal,
-    with a ``window`` those of the band. The mask is paid on the diagonal's
-    block and on the block(s) the window's trailing edge crosses."""
-    f = pl.program_id(1)
-    qi = qi_arr[f]
-    ki = ki_arr[f]
+# ----------------------------------------------------- backward (causal)
+class BackwardPlan(NamedTuple):
+    """The shapes of the causal backward for one (T, D, dtype, window), and
+    what its static grid will run; the counts are of ONE (batch x head)
+    row."""
+    block: int                  # rows of a q block = keys of a k block
+    pairs_run: int              # (k block, q block) pairs: the grid's steps
+    pairs_masked: int           # those the diagonal or the window's edge crosses
+    matmuls_per_pair: int
+    dq_accumulator_bytes: int   # the head's float32 dq, resident in VMEM
+    diagonal_quarters: int      # of the diagonal's pair, the quarters run
 
-    @pl.when(ki == _first_block(qi, block, window))
-    def _init():
+
+# the causal backward keeps a head's whole float32 dq in VMEM (128 MiB on a
+# v5e): up to this many bytes of it, T = 131,072 at d = 128, eight times the
+# longest cell's; past it ``_causal_backward`` refuses the call (the pair of
+# triangular kernels that needed no such buffer went with PR 47: no test, no
+# cell and no model here is within a factor of four of the bound)
+_BWD_DQ_BYTES = 64 << 20
+_BWD_VMEM_BYTES = 32 << 20     # the blocks and the score tiles beside it
+
+
+def flash_backward_plan(t: int, d: int, dtype, window=None,
+                        block_q: int = DEFAULT_BLOCK_Q,
+                        block_k: int = DEFAULT_BLOCK_K) -> BackwardPlan:
+    """What the causal backward runs for a call of length ``t`` (padded as
+    ``flash_attention`` pads it) at head width ``d``: the one place
+    ``_causal_backward`` takes its shapes from, a pure function of what the
+    call can see. The block is the forward's sub-block (``_pick_block``),
+    square; the pairs are ``_causal_pairs_colmajor``'s, a k block against
+    the q blocks from its diagonal down and, with a ``window``, as far as
+    the last that sees it. T = 1,024 in 512s: 3 pairs, 2 of them masked,
+    where a rectangular grid ran the square's 4; and of a diagonal pair's
+    four quarters the one above the diagonal is not run either, where half
+    a block is whole 128s (measured on the chip against blocks of 256,
+    which walk the same 10 quarters in 10 grid steps: 1.38 against 1.79 ms
+    at 128 x 1,024 x 96). ``dtype`` is there as ``flash_forward_plan`` has
+    it; nothing depends on it yet: every accumulator is float32."""
+    t = _padded_len(t, min(block_q, block_k))
+    block = _pick_block(t, min(block_q, block_k))
+    ki, qi = _causal_pairs_colmajor(t // block, block, window)
+    return BackwardPlan(
+        block=block, pairs_run=len(ki),
+        pairs_masked=sum(
+            1 for k, q in zip(ki, qi)
+            if k == q or (window is not None
+                          and not _inside_window(q, k, block, window))),
+        matmuls_per_pair=5,
+        dq_accumulator_bytes=t * d * 4,
+        # half a block has to be whole 128-lane groups of the statistics' row
+        diagonal_quarters=3 if block % (2 * _LANES) == 0 else 4)
+
+
+def _bwd_causal_kernel(ki_arr, qi_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                       delta_ref, dk_ref, dv_ref, dq_ref, dk_sc, dv_sc, dq_sc,
+                       *, scale, block, num_q, split, window=None):
+    """A k block against one of the q blocks that see it, all three
+    gradients from ONE recompute of P, on the TRANSPOSED scores: S^T = K Q^T
+    is (keys, q rows), so the log-sum-exp and delta meet it as the
+    lane-dense (1, block) rows they arrive in, broadcast along sublanes: no
+    column, no cross-lane move. P^T = exp(S^T - lse), dP^T = V dO^T, dS^T =
+    P^T (dP^T - delta); dV += P^T dO and dK += dS^T Q are plain products,
+    dQ[qi] += (dS^T)^T K the one that contracts the leading dimension. Five
+    matmuls and one exp a pair.
+
+    dk and dv accumulate over the k block's pairs in (block, d) scratch; dq
+    accumulates in the float32 scratch of the WHOLE head, in the rows of the
+    pair's q block. The pairs are k-major, so q block i has taken its last
+    product when k block i's first pair (the diagonal's) is done: that step
+    writes dq's block i out, which is why dq's output block follows ``ki``
+    as dk's and dv's do."""
+    f = pl.program_id(1)
+    ki, qi = ki_arr[f], qi_arr[f]
+    rows = pl.ds(pl.multiple_of(qi * block, block), block)
+
+    @pl.when(f == 0)
+    def _head():
         dq_sc[:] = jnp.zeros_like(dq_sc)
-
-    def _acc(mask_rc):
-        _, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                          delta_ref[0], scale, mask_rc, window)
-        dq_sc[:] += jax.lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    interior = ki < qi
-    if window is not None:
-        interior = interior & _inside_window(qi, ki, block, window)
-
-        @pl.when(jnp.logical_not(interior) & (ki < qi))
-        def _trailing():
-            _acc(_block_iotas(block, block, qi, ki))
-
-    @pl.when(interior)
-    def _interior():
-        _acc(None)
-
-    @pl.when(ki == qi)
-    def _diagonal():
-        _acc(_block_iotas(block, block, qi, ki))
-        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_tri_kernel(ki_arr, qi_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                        delta_ref, dk_ref, dv_ref, dk_sc, dv_sc,
-                        *, scale, block, num_q, window=None):
-    """A k block's dk and dv over its listed q blocks: from the diagonal's
-    down, with a ``window`` as far as the last that sees it."""
-    f = pl.program_id(1)
-    ki = ki_arr[f]
-    qi = qi_arr[f]
 
     @pl.when(qi == ki)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    def _acc(mask_rc):
-        p, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                          delta_ref[0], scale, mask_rc, window)
-        dv_sc[:] += jax.lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
-                                        (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-        dk_sc[:] += jax.lax.dot_general(ds, q_ref[0], (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
+    def _tile(masked, keys=(0, block), cols=(0, block)):
+        """The pair's keys ``keys`` against its q rows ``cols`` (static
+        halves of the block, or all of it)."""
+        kk, qq = pl.ds(*keys), pl.ds(*cols)
+        q, do = q_ref[0, qq, :], do_ref[0, qq, :]
+        k, v = k_ref[0, kk, :], v_ref[0, kk, :]
+        mask = None
+        if masked:
+            # transposed: keys down the sublanes, q rows along the lanes
+            shape = (keys[1], cols[1])
+            kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+                + (ki * block + keys[0])
+            qpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1) \
+                + (qi * block + cols[0])
+            mask = qpos, kpos
+        p = jnp.exp(_scores(k, q, scale, mask, window) - lse_ref[0, :, qq])
+        dp = jax.lax.dot_general(v, do, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, :, qq])
+        if scale != 1.0:
+            ds = ds * scale
+        ds = ds.astype(k.dtype)
+        dv_sc[kk, :] += jax.lax.dot_general(p.astype(do.dtype), do,
+                                            (((1,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32)
+        dk_sc[kk, :] += jax.lax.dot_general(ds, q, (((1,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32)
+        at = pl.ds(pl.multiple_of(qi * block + cols[0], cols[1]), cols[1])
+        dq_sc[at, :] += jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(qi == ki)
     def _diagonal():
-        _acc(_block_iotas(block, block, qi, ki))
+        if split:
+            # the quarter above the diagonal (the later keys against the
+            # earlier rows) is never run: 3 of the tile's 4 quarters
+            half = block // 2
+            _tile(True, keys=(0, half))
+            _tile(True, keys=(half, half), cols=(half, half))
+        else:
+            _tile(True)
+        dq_ref[0] = dq_sc[rows, :].astype(dq_ref.dtype)
 
     interior = qi > ki
     if window is not None:
         interior = interior & _inside_window(qi, ki, block, window)
-
-        @pl.when(jnp.logical_not(interior) & (qi > ki))
-        def _trailing():
-            _acc(_block_iotas(block, block, qi, ki))
-
-    @pl.when(interior)
-    def _interior():
-        _acc(None)
+        pl.when(jnp.logical_not(interior) & (qi > ki))(
+            functools.partial(_tile, True))
+    pl.when(interior)(functools.partial(_tile, False))
 
     @pl.when(qi == _last_block(ki, block, window, num_q))
     def _finalize():
@@ -732,25 +787,77 @@ def _bwd_dkv_tri_kernel(ki_arr, qi_arr, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
 
+def _causal_backward(q, k, v, do, lse, delta, scale, block_q, block_k,
+                     window=None):
+    """dq, dk, dv of causal self-attention in ONE call, named
+    ``flash_bwd_dkv`` (``flash_bwd_dkv_win`` under a window): it is the dkv
+    kernel, grid and all, which now returns dq too. No call is named
+    ``flash_bwd_dq`` in a causal backward any more."""
+    bh, t, d = q.shape
+    plan = flash_backward_plan(t, d, q.dtype, window, block_q, block_k)
+    if plan.dq_accumulator_bytes > _BWD_DQ_BYTES:
+        raise NotImplementedError(
+            f"flash_attention backward: a head's float32 dq at length {t}, "
+            f"width {d} is {plan.dq_accumulator_bytes} bytes, over the "
+            f"{_BWD_DQ_BYTES} the causal backward keeps in VMEM")
+    block, nq = plan.block, t // plan.block
+    ki_arr, qi_arr = _causal_pairs_colmajor(nq, block, window)
+    # the kernel is traced with the window only where there is one
+    win = {} if window is None else {"window": window}
+    at_q = pl.BlockSpec((1, block, d), lambda b, f, ka, qa: (b, qa[f], 0))
+    at_k = pl.BlockSpec((1, block, d), lambda b, f, ka, qa: (b, ka[f], 0))
+    stat = pl.BlockSpec((1, 1, block), lambda b, f, ka, qa: (b, 0, qa[f]))
+    tiles = plan.pairs_run * block * block
+    dk, dv, dq = pl.pallas_call(
+        functools.partial(_bwd_causal_kernel, scale=scale, block=block,
+                          num_q=nq, split=plan.diagonal_quarters == 3,
+                          **win),
+        name="flash_bwd_dkv" + ("" if window is None else "_win"),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, plan.pairs_run),
+            in_specs=[at_q, at_k, at_k, at_q, stat, stat],
+            out_specs=(at_k, at_k, at_k),
+            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
+                            pltpu.VMEM((block, d), jnp.float32),
+                            pltpu.VMEM((t, d), jnp.float32)],
+        ),
+        out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
+                   jax.ShapeDtypeStruct((bh, t, d), v.dtype),
+                   jax.ShapeDtypeStruct((bh, t, d), q.dtype)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            # beside the blocks and the score tiles, the head's dq as VMEM
+            # holds it: d padded to whole 128 lanes
+            vmem_limit_bytes=_BWD_VMEM_BYTES + t * -(-d // _LANES) * _LANES * 4),
+        cost_estimate=pl.CostEstimate(
+            flops=int(2 * bh * tiles * d * plan.matmuls_per_pair),
+            bytes_accessed=int(7 * q.size * q.dtype.itemsize),
+            transcendentals=int(bh * tiles)),
+        # dk, dv and dq take k's, v's and q's buffers (operands 3, 4, 2 after
+        # the two pair lists): k and v block i are read by k block i's pairs
+        # alone and dk, dv block i written after them; q block i is read last
+        # by k block i's FIRST pair and dq block i written after its last.
+        # All three gradients at once would else stand beside all four
+        # inputs: +183 MB on the step of 32 x 16,384 x 192 (AOT, PR 47)
+        input_output_aliases={3: 0, 4: 1, 2: 2},
+    )(jnp.asarray(ki_arr), jnp.asarray(qi_arr), q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+# ------------------------------------ backward (non-causal, t_q and t_k apart)
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc,
-                   *, scale, causal, block_q, block_k, num_k):
-    qi = pl.program_id(1)
+                   *, scale, num_k):
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    def _acc(mask_rc):
-        _, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                          delta_ref[0], scale, mask_rc)
-        dq_sc[:] += jax.lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    if causal:
-        _causal_dispatch(qi, ki, block_q, block_k, _acc)
-    else:
-        _acc(None)
+    _, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+                      delta_ref[0], scale)
+    dq_sc[:] += jax.lax.dot_general(ds, k_ref[0], (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
 
     @pl.when(ki == num_k - 1)
     def _finalize():
@@ -758,8 +865,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_s
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                    dk_sc, dv_sc, *, scale, causal, block_q, block_k, num_q):
-    ki = pl.program_id(1)
+                    dk_sc, dv_sc, *, scale, num_q):
     qi = pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -767,19 +873,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    def _acc(mask_rc):
-        p, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
-                          delta_ref[0], scale, mask_rc)
-        dv_sc[:] += jax.lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
-                                        (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-        dk_sc[:] += jax.lax.dot_general(ds, q_ref[0], (((0,), (0,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-
-    if causal:
-        _causal_dispatch(qi, ki, block_q, block_k, _acc)
-    else:
-        _acc(None)
+    p, ds = _bwd_p_ds(q_ref[0], k_ref[0], v_ref[0], do_ref[0], lse_ref[0],
+                      delta_ref[0], scale)
+    dv_sc[:] += jax.lax.dot_general(p.astype(do_ref.dtype), do_ref[0],
+                                    (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    dk_sc[:] += jax.lax.dot_general(ds, q_ref[0], (((0,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -796,82 +896,18 @@ def _flash_backward(res, g, scale, causal, block_q, block_k, window=None,
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
     t_k = k.shape[1]
-    bq = _pick_block(t_q, block_q)
-    bk = _pick_block(t_k, block_k)
-    nq, nk = t_q // bq, t_k // bk
     do = g
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None]        # (bh, 1, t_q), as lse
-
-    if causal and t_q == t_k and bq == bk and window is None \
-            and t_q // bq < _BWD_TRI_MIN_BLOCKS:
-        bk = _pick_block(t_k, 2 * bq)       # short sequences: wider k blocks
-        nk = t_k // bk
-    tri = _use_tri(causal, t_q, t_k, bq, bk, window)
-    if tri:
-        # the kernels are traced with the window only where there is one:
-        # without it their programs are what they were
-        win = {} if window is None else {"window": window}
-        suffix = "" if window is None else "_win"
-        qi_arr, ki_arr = _causal_pairs(nq, bq, window)
-        # dq: iterate (qi, ki≤qi) row-major; first prefetch array indexes q/dq
-        dq = pl.pallas_call(
-            functools.partial(_bwd_dq_tri_kernel, scale=scale, block=bq,
-                              **win),
-            name="flash_bwd_dq" + suffix,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(bh, len(qi_arr)),
-                in_specs=[
-                    pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, qa, ka: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
-                    pl.BlockSpec((1, 1, bq), lambda b, f, qa, ka: (b, 0, qa[f])),
-                    pl.BlockSpec((1, 1, bq), lambda b, f, qa, ka: (b, 0, qa[f])),
-                ],
-                out_specs=pl.BlockSpec((1, bq, d), lambda b, f, qa, ka: (b, qa[f], 0)),
-                scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-            ),
-            out_shape=jax.ShapeDtypeStruct((bh, t_q, d), q.dtype),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-        )(jnp.asarray(qi_arr), jnp.asarray(ki_arr), q, k, v, do, lse, delta)
-
-        # dkv: iterate (ki, qi≥ki) — the transposed triangle
-        ki2, qi2 = _causal_pairs_colmajor(nq, bq, window)
-        dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_tri_kernel, scale=scale, block=bq,
-                              num_q=nq, **win),
-            name="flash_bwd_dkv" + suffix,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(bh, len(ki2)),
-                in_specs=[
-                    pl.BlockSpec((1, bq, d), lambda b, f, ka, qa: (b, qa[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bq, d), lambda b, f, ka, qa: (b, qa[f], 0)),
-                    pl.BlockSpec((1, 1, bq), lambda b, f, ka, qa: (b, 0, qa[f])),
-                    pl.BlockSpec((1, 1, bq), lambda b, f, ka, qa: (b, 0, qa[f])),
-                ],
-                out_specs=(
-                    pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
-                    pl.BlockSpec((1, bk, d), lambda b, f, ka, qa: (b, ka[f], 0)),
-                ),
-                scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                                pltpu.VMEM((bk, d), jnp.float32)],
-            ),
-            out_shape=(jax.ShapeDtypeStruct((bh, t_k, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, t_k, d), v.dtype)),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-        )(jnp.asarray(ki2), jnp.asarray(qi2), q, k, v, do, lse, delta)
-        return dq, dk, dv
+    if causal:              # self-attention: ``flash_attention`` saw to that
+        return _causal_backward(q, k, v, do, lse, delta, scale, block_q,
+                                block_k, window)
+    bq = _pick_block(t_q, block_q)
+    bk = _pick_block(t_k, block_k)
+    nq, nk = t_q // bq, t_k // bk
 
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, num_k=nk),
+        functools.partial(_bwd_dq_kernel, scale=scale, num_k=nk),
         name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
@@ -890,8 +926,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k, window=None,
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, num_q=nq),
+        functools.partial(_bwd_dkv_kernel, scale=scale, num_q=nq),
         name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
@@ -993,7 +1028,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     ``window`` (a Python int, causal only): row i sees the keys j with ``0 <=
     i - j < window``. The same kernel family with the window in its plan
     and pair lists (``flash_forward_plan``); its calls are named
-    ``flash_fwd_win`` / ``flash_bwd_dq_win`` / ``flash_bwd_dkv_win``. A
+    ``flash_fwd_win`` / ``flash_bwd_dkv_win``. A
     window that reaches the whole length is no window: that call's plans,
     lists and programs are those of a call without one.
 

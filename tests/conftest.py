@@ -47,11 +47,21 @@ def _slow_nodeids():
 # worker ends alone with it (~100 s of a run). With the long files at the
 # head and the others behind them in xdist's own order, a run ends 70-110 s
 # sooner. Regenerate from a run's junit file when the suite's shape changes.
+# PR 47: TWO files stand among the first six, which xdist hands to six FRESH
+# workers, for what they need and not for their length (the two they
+# displaced follow at once): `test_profiling.py`'s census counts every live
+# array of its process (the engines `test_zero3_gather.py` leaves behind
+# fail it) and `test_first_token.py` aborts inside XLA:CPU when it follows
+# `test_solar_family.py` + `test_pangu_family.py` in one process. Both
+# reproduce at PR 46's commit with that order in ONE process; which files a
+# worker meets in a row moves with every file's length (here
+# `test_flash_attention.py`, 52 -> 73 tests): three whole runs of three.
 _LONG_FILES = (
     "test_chip_bringup.py", "test_kda.py", "test_afmoe_family.py",
-    "test_solar_family.py", "test_kimi_cell.py", "test_zero3_gather.py",
-    "test_pangu.py", "test_flash_attention.py", "test_afmoe.py",
-    "test_pangu_family.py", "test_olmoe_family.py", "test_first_token.py")
+    "test_solar_family.py", "test_first_token.py", "test_profiling.py",
+    "test_kimi_cell.py", "test_zero3_gather.py", "test_pangu.py",
+    "test_flash_attention.py", "test_afmoe.py", "test_pangu_family.py",
+    "test_olmoe_family.py")
 
 
 def pytest_configure(config):

@@ -70,14 +70,16 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, heads, dim):
         fa.flash_attention(q, k, v).astype(jnp.float32))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 3      # fwd, dq, dkv
+    # fwd, and the ONE backward call that gives dq, dk and dv (PR 47)
+    assert text.count("tpu_custom_call") == 2
     # each Mosaic call is an HLO instruction named after the kernel's own
     # ``name`` (here inside the transform's: %jvp_flash_fwd_.1): that name
     # is what an op event of a device profile carries
-    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for kernel in ("flash_fwd", "flash_bwd_dkv"):
         assert len(re.findall(
             rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", text)) == 1, \
             kernel
+    assert "flash_bwd_dq" not in text
 
 
 # every length a cell runs the causal forward at, heads cut to a few: the
@@ -105,8 +107,8 @@ def test_causal_flash_forward_compiles_for_v5e_at_the_cells_shapes(v5e, t, d,
 
 def test_windowed_flash_fwd_bwd_compiles_for_v5e_at_the_cells_shape(v5e):
     """``trinity-mini.train.z1.s8k``'s window layers: 32 heads x 8,192 x 128
-    under a window of 2,048, the forward and BOTH backward kernels, each an
-    HLO instruction under the name a trace reads (``flash_*_win``: the
+    under a window of 2,048, the forward and the ONE backward kernel, each
+    an HLO instruction under the name a trace reads (``flash_*_win``: the
     standing readers' ``flash_fwd`` / ``flash_bwd`` still match)."""
     mesh = _mesh(v5e)
     x = _abstract((1, 8192, 32, 128), jnp.bfloat16, mesh)
@@ -114,8 +116,8 @@ def test_windowed_flash_fwd_bwd_compiles_for_v5e_at_the_cells_shape(v5e):
         fa.flash_attention(q, k, v, window=2048).astype(jnp.float32))
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") == 3
-    for kernel in ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win"):
+    assert text.count("tpu_custom_call") == 2
+    for kernel in ("flash_fwd_win", "flash_bwd_dkv_win"):
         assert len(re.findall(
             rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", text)) == 1, \
             kernel
@@ -174,7 +176,7 @@ def test_a_flash_block_under_128_is_refused_before_mosaic_sees_it(v5e):
             functools.partial(common.local_causal_attention,
                               flash_block=block),
             _mesh(v5e), *[(1, 1024, 8, 64)] * 3)
-        assert text.count("tpu_custom_call") == 3
+        assert text.count("tpu_custom_call") == 2    # fwd, the one bwd
 
 
 @pytest.mark.parametrize("block", [16, 64, 128, 512])
@@ -329,8 +331,8 @@ def test_prefill_at_untileable_prompt_length_compiles(v5e, t):
 def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
     """Bare GSPMD cannot partition a Mosaic call (jax raises at lowering on
     more than one device): the kernel must sit in a shard_map manual over
-    every mesh axis. 16x96 heads, 24 layers, batch over data=4. THREE Mosaic
-    calls: forward, dq, dkv. Remat 'attn' saves the forward's ``o`` and
+    every mesh axis. 16x96 heads, 24 layers, batch over data=4. TWO Mosaic
+    calls: the forward and the one backward (PR 47). Remat 'attn' saves the forward's ``o`` and
     log-sum-exp, named inside that shard_map by the custom VJP's forward
     rule, so the recompute holds no second forward (four until PR 32: the
     name sat on the VJP's output, the residuals were thrown away)."""
@@ -343,7 +345,7 @@ def test_760m_grad_sharded_over_four_chips_keeps_the_kernel(v5e):
     with mesh:
         lowered = jax.jit(jax.value_and_grad(
             lambda p, b: model.loss(p, {"input_ids": b}))).lower(params, ids)
-    assert lowered.as_text().count("tpu_custom_call") == 3
+    assert lowered.as_text().count("tpu_custom_call") == 2
 
 
 # ---------------------- gpt2-xl's ZeRO-3 step over four chips (train.z3x4)
@@ -467,7 +469,7 @@ def _flash_kernels_by_loop(text):
         n = {k: sum(bool(re.search(
             rf"%[\w.]*{k}[\w.]* = [^\n]*tpu_custom_call", l))
             for c in inside for l in comps[c])
-            for k in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+            for k in ("flash_fwd", "flash_bwd_dkv")}
         if any(n.values()):
             found.append((len(inside), n))
     return [n for _, n in sorted(found, key=lambda x: x[0])]
@@ -475,14 +477,16 @@ def _flash_kernels_by_loop(text):
 
 def _assert_the_forward_kernel_runs_once_a_layer(text):
     """The layer scan of the forward holds ``flash_fwd``; the backward's
-    holds ``flash_bwd_dq`` and ``flash_bwd_dkv`` and NO forward kernel: what
-    remat 'attn' saved (``o``, the log-sum-exp) is what the backward reads."""
+    holds ``flash_bwd_dkv``, ONE backward call a layer (PR 47: it gives dq
+    too), and NO forward kernel: what remat 'attn' saved (``o``, the
+    log-sum-exp) is what the backward reads."""
     loops = _flash_kernels_by_loop(text)
     assert loops[:2] in (
-        [{"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkv": 0},
-         {"flash_fwd": 0, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}],
-        [{"flash_fwd": 0, "flash_bwd_dq": 1, "flash_bwd_dkv": 1},
-         {"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkv": 0}]), loops
+        [{"flash_fwd": 1, "flash_bwd_dkv": 0},
+         {"flash_fwd": 0, "flash_bwd_dkv": 1}],
+        [{"flash_fwd": 0, "flash_bwd_dkv": 1},
+         {"flash_fwd": 1, "flash_bwd_dkv": 0}]), loops
+    assert "flash_bwd_dq" not in text
     assert len(re.findall(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call",
                           text)) == 1
 
@@ -525,14 +529,14 @@ def test_xl_z3_step_gathers_the_head_once_and_reduces_its_gradient_once(xl_z3_st
 def _layer_scan_collectives(text):
     """{"fwd" | "bwd": [(op, result type)]} of the collectives inside the
     layer scan of the forward (the smallest ``while`` body that holds
-    ``flash_fwd``) and of the backward (``flash_bwd_dq``): what runs once a
+    ``flash_fwd``) and of the backward (``flash_bwd_dkv``): what runs once a
     layer. A reduce-scatter that XLA:TPU writes as an ``all-reduce-scatter``
     fusion is listed as ``reduce-scatter`` with the FUSION's result."""
     comps, called, reach = _computations(text)
     bodies = sorted({c for lines in comps.values() for c in called("body", lines)},
                     key=lambda b: len(reach(b)))
     found = {}
-    for key, kernel in (("fwd", "flash_fwd"), ("bwd", "flash_bwd_dq")):
+    for key, kernel in (("fwd", "flash_fwd"), ("bwd", "flash_bwd_dkv")):
         body = next(b for b in bodies if any(
             re.search(rf"%[\w.]*{kernel}[\w.]* = [^\n]*tpu_custom_call", l)
             for c in reach(b) for l in comps[c]))
@@ -674,9 +678,9 @@ def trinity_record(v5e):
 
 def test_trinity_step_runs_the_windowed_kernels_and_keeps_no_square(
         trinity_record):
-    """The cell's real step for one v5e chip: the window layers' three
-    kernels and the full layer's three (the forward ONCE a layer under remat
-    'attn'), XLA:TPU's own grouped-matmul kernels for the routed experts'
+    """The cell's real step for one v5e chip: the window layers' two
+    kernels and the full layer's two (the forward ONCE a layer under remat
+    'attn', ONE backward call a layer), XLA:TPU's own grouped-matmul kernels for the routed experts'
     ``ragged_dot`` (forward, re-run and both transposes), no (T, T) array of
     scores anywhere, and it fits the chip beside the 9.88 GB of state."""
     from deepspeed_tpu.telemetry.scopes import classify
@@ -688,10 +692,9 @@ def test_trinity_step_runs_the_windowed_kernels_and_keeps_no_square(
         if "tpu_custom_call" in line)
     # one dense window layer + a period of [win, attn, win, win]: the
     # period's layers are unrolled in the scan's body
-    assert calls["flash_fwd_win"] == calls["flash_bwd_dq_win"] == \
-        calls["flash_bwd_dkv_win"] == 4
-    assert calls["flash_fwd"] == calls["flash_bwd_dq"] == \
-        calls["flash_bwd_dkv"] == 1
+    assert calls["flash_fwd_win"] == calls["flash_bwd_dkv_win"] == 4
+    assert calls["flash_fwd"] == calls["flash_bwd_dkv"] == 1
+    assert calls["flash_bwd_dq_win"] == calls["flash_bwd_dq"] == 0
     # a routed layer's three products over a chunk of the share's rows:
     # forward, re-run, and in the backward once more before both transposes
     # (the loop over chunks keeps its inputs, PR 39)
@@ -824,7 +827,6 @@ def test_the_flash_kernels_keep_their_instruction_names(step_record):
         step_record.compiled().as_text()) if "tpu_custom_call" in line}
     by_kernel = {re.sub(r"[.\d]+$", "", n): v for n, v in kernels.items()}
     assert by_kernel == {"flash_fwd": ("attn/core", "fwd"),
-                         "flash_bwd_dq": ("attn/core", "bwd"),
                          "flash_bwd_dkv": ("attn/core", "bwd")}, kernels
 
 

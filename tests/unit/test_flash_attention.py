@@ -97,7 +97,7 @@ def _walk(rows, n_sub):
     ids=lambda x: str(x))
 def test_causal_forward_in_wide_steps_matches_reference(T, D, Dv):
     """Output and log-sum-exp of the one causal forward against the plain
-    softmax, gradients through it and the unchanged backward, and the
+    softmax, gradients through it and the one causal backward, and the
     plan's counts against a walk of the grid it makes."""
     B, H = 1, (1 if T > 2048 else 2)
     keys = jax.random.split(jax.random.PRNGKey(T + D), 4)
@@ -167,6 +167,116 @@ def test_flash_forward_plan_at_the_cells_shapes(t, d, dv, plan):
     rows = t // got.sub_block
     assert len(run) + len(masked) == rows * (rows + 1) // 2 == got.sub_blocks_run
     assert all(j < i for i, j in run) and all(j == i for i, j in masked)
+
+
+# ------------------------- the causal backward: one kernel, P recomputed once
+@pytest.mark.parametrize("t,d,window,plan", [
+    # (block, pairs_run, pairs_masked, matmuls a pair, dq bytes, quarters)
+    (1024, 96, None, (512, 3, 2, 5, 393216, 3)),       # gpt2-760m.train.*
+    (1024, 64, None, (512, 3, 2, 5, 262144, 3)),       # gpt2-xl.train.z3x4
+    (8192, 128, None, (512, 136, 16, 5, 4194304, 3)),  # Trinity's full layers
+    (8192, 128, 2048, (512, 70, 28, 5, 4194304, 3)),   # Trinity's window layers
+    (16384, 192, None, (512, 528, 32, 5, 12582912, 3)),  # Kimi's latent layers
+    (1024, 96, 300, (512, 3, 3, 5, 393216, 3)),        # a window inside a block
+    (1000, 32, None, (512, 3, 2, 5, 131072, 3)),       # padded to 1,024
+    (896, 64, None, (128, 28, 7, 5, 229376, 4)),       # 128s: no half of whole 128s
+], ids=lambda x: str(x))
+def test_flash_backward_plan_at_the_cells_shapes(t, d, window, plan):
+    """The one causal backward's static grid at the real shapes: every
+    block pair at or under the diagonal (inside the band) once, five
+    matmuls a pair, none above; T = 1,024 in 512s is 3 pairs where the
+    rectangular grid this replaced ran the square's 4."""
+    got = fa.flash_backward_plan(t, d, jnp.bfloat16, window)
+    assert tuple(got) == plan
+    n = -(-t // got.block)
+    ki, qi = fa._causal_pairs_colmajor(n, got.block, window)
+    assert len(ki) == got.pairs_run and (qi >= ki).all()
+    if window is None:
+        assert got.pairs_run == n * (n + 1) // 2 and got.pairs_masked == n
+    else:
+        # the window drops the pairs outside the band, and only those
+        assert {(int(k), int(q)) for k, q in zip(ki, qi)} == {
+            (k, q) for q in range(n) for k in range(q + 1)
+            if q * got.block - (k * got.block + got.block - 1) < window}
+    fwd = fa.flash_forward_plan(t, d, d, jnp.bfloat16, window=window)
+    assert (got.block, got.pairs_run, got.pairs_masked) == (
+        fwd.sub_block, fwd.sub_blocks_run, fwd.sub_blocks_masked)
+
+
+# the cells' shapes scaled down, their structure kept: T = 1,024 in two
+# blocks at d 96 and 64 (the diagonal in quarters), four and more blocks at
+# d 128 with and without a window that ends inside a block, q.k 192 with v
+# 128 (v, o and dO go in zero-padded), lengths that pad or halve the block
+@pytest.mark.parametrize("T,D,Dv,window,block,dtype", [
+    (1024, 96, 96, None, 512, "float32"), (1024, 64, 64, None, 512, "bfloat16"),
+    (1024, 128, 128, None, 256, "float32"), (1024, 128, 128, 300, 256, "float32"),
+    (1280, 128, 128, 700, 256, "bfloat16"), (512, 192, 128, None, 128, "float32"),
+    (1024, 192, 128, None, 512, "bfloat16"), (640, 32, 32, None, 512, "float32"),
+    (768, 32, 32, None, 512, "float32"), (896, 32, 32, 200, 512, "float32")],
+    ids=lambda x: str(x))
+def test_the_one_causal_backward_matches_the_reference(T, D, Dv, window,
+                                                       block, dtype):
+    """dq, dk, dv of the one causal backward kernel against the plain
+    softmax's gradients, at the tolerances the pair of kernels was held to
+    (float32: the windowed tests' 5e-5 absolute; bf16: 5e-2)."""
+    keys = jax.random.split(jax.random.PRNGKey(T + D), 4)
+    q, k, v, g = (jax.random.normal(key, (1, T, 2, w), jnp.float32)
+                  .astype(dtype) for key, w in zip(keys, (D, D, Dv, Dv)))
+    attend = functools.partial(fa.flash_attention, block_q=block,
+                               block_k=block, window=window)
+    want = functools.partial(_windowed_reference, window=window or T)
+    loss = lambda f: lambda *a: jnp.sum((f(*a) * g).astype(jnp.float32))
+    tol = dict(atol=5e-5, rtol=0) if dtype == "float32" \
+        else dict(atol=5e-2, rtol=5e-2)
+    for a, b in zip(jax.grad(loss(attend), argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(loss(want), argnums=(0, 1, 2))(q, k, v)):
+        assert a.dtype == b.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **tol)
+
+
+# sha256 of the jaxpr (kernel body and all) ``flash_attention(q, q, q)`` and
+# ``(..., window=300)`` traced to for bf16 (1, 1024, 2, 96) at the commit
+# BEFORE the one causal backward (4d2390d; jax 0.9.0). To refresh after a
+# change that is MEANT to move the forward: print them from a checkout of
+# the parent
+FORWARD_DIGESTS = ("4da99ca45b964efd", "61ca224caa6746f3")
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the digests are of jax 0.9.0's jaxprs")
+@pytest.mark.parametrize("window,name", [(None, "flash_bwd_dkv"),
+                                         (300, "flash_bwd_dkv_win")])
+def test_the_causal_backward_is_one_call_and_the_forward_is_the_parents(
+        window, name, monkeypatch):
+    """The gradient of causal self-attention holds TWO ``pallas_call``s: the
+    forward, whose jaxpr is the one the parent commit traced, and ONE
+    backward call, named for the dkv kernel it is; nothing is named
+    ``flash_bwd_dq``."""
+    import hashlib
+    import re
+
+    monkeypatch.undo()          # traced as a program for the chip traces it
+    q = jnp.zeros((1, 1024, 2, 96), jnp.bfloat16)
+    attend = functools.partial(fa.flash_attention, window=window)
+    grad = str(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, q, q))
+    assert grad.count("pallas_call[") == 2
+    names = re.findall(r"name=(\w*flash\w+)", grad)
+    assert sorted(names) == sorted([
+        "flash_fwd" + ("_win" if window else ""), name]), names
+    forward = str(jax.make_jaxpr(attend)(q, q, q))
+    assert hashlib.sha256(forward.encode()).hexdigest()[:16] == {
+        None: FORWARD_DIGESTS[0], 300: FORWARD_DIGESTS[1]}[window]
+
+
+def test_the_backward_refuses_a_head_whose_dq_cannot_stay_in_vmem():
+    q = jax.ShapeDtypeStruct((1, 262144, 128), jnp.bfloat16)
+    stat = jax.ShapeDtypeStruct((1, 1, 262144), jnp.float32)
+    with pytest.raises(NotImplementedError, match="keeps in VMEM"):
+        jax.eval_shape(lambda q, lse: fa._flash_backward(
+            (q, q, q, q, lse), q, 1.0, True, 512, 512), q, stat)
 
 
 def test_causal_means_one_length():
@@ -350,10 +460,12 @@ def test_windowed_kernels_match_the_masked_reference(T, window, block):
     assert plan.grid_steps == len(qi)
     assert {(i, s) for i, s in zip(qi, si)} == \
         {(i, j // n_sub) for i, j in touched}
-    # the backward's lists: the band's pairs, each once, either order
-    assert sorted(zip(*fa._causal_pairs(rows, sub, window))) == touched
+    # the backward's list: the band's pairs, each once, and its plan's counts
     assert sorted((i, j) for j, i in
                   zip(*fa._causal_pairs_colmajor(rows, sub, window))) == touched
+    back = fa.flash_backward_plan(T, 32, q.dtype, window, block, block)
+    assert (back.block, back.pairs_run, back.pairs_masked) == (
+        sub, len(touched), plan.sub_blocks_masked)
 
 
 def test_the_cells_windowed_plan():
@@ -380,8 +492,6 @@ def test_without_a_window_every_plan_list_and_program_is_the_parents():
     ki, qi = fa._causal_pairs_colmajor(5)
     assert ki.tolist() == [0] * 5 + [1] * 4 + [2] * 3 + [3] * 2 + [4]
     assert qi.tolist() == [0, 1, 2, 3, 4, 1, 2, 3, 4, 2, 3, 4, 3, 4, 4]
-    assert fa._use_tri(True, 1024, 1024, 512, 512) is False
-    assert fa._use_tri(True, 1024, 1024, 512, 512, window=300) is True
     q = jnp.zeros((1, 1024, 2, 32), jnp.float32)
     grad = lambda **kw: jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
         fa.flash_attention(q, k, v, block_q=128, block_k=128, **kw)),
@@ -389,9 +499,9 @@ def test_without_a_window_every_plan_list_and_program_is_the_parents():
     plain = str(grad())
     assert str(grad(window=None)) == plain == str(grad(window=1024)) \
         == str(grad(window=5000))
-    assert "flash_fwd_win" not in plain and "flash_bwd_dq_win" not in plain
+    assert "flash_fwd_win" not in plain and "flash_bwd_dkv_win" not in plain
     windowed = str(grad(window=300))
-    for name in ("flash_fwd_win", "flash_bwd_dq_win", "flash_bwd_dkv_win"):
+    for name in ("flash_fwd_win", "flash_bwd_dkv_win"):
         assert name in windowed
     with pytest.raises(ValueError, match="causal window"):
         fa.flash_attention(q, q, q, causal=False, window=8)

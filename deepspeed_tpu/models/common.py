@@ -252,9 +252,21 @@ def check_flash_block(block):
             "q block is a lane dimension)")
 
 
+def _softmax_beside_sink(logits, sink):
+    """The softmax over the last axis of ``logits`` (float32) with a SINK
+    logit beside each row's scores (``sink`` broadcasts against ``logits``
+    with a last axis of 1): the concatenated-column definition. The sink
+    takes its share of the mass and has no value, so its column is dropped:
+    the rows sum to less than 1."""
+    both = jnp.concatenate(
+        [logits, jnp.broadcast_to(sink, logits.shape[:-1] + (1,))], axis=-1)
+    return jax.nn.softmax(both, axis=-1)[..., :-1]
+
+
 def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                            causal: bool = True, key_padding_mask=None,
-                           flash_block=None, window=None, block=None):
+                           flash_block=None, window=None, block=None,
+                           sink=None):
     """Self-attention on local (unsharded-sequence) q, k, v with equal head
     counts (B, T, H, Dh) — v's head size may differ from q's and k's (latent
     attention: q.k at 192 columns, v at 128): the Pallas flash kernel on
@@ -285,9 +297,16 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     only, no window beside it; the flash kernel carries it where the block
     is a power of two that divides 128 and the length
     (``flash_attention(block=)``), the einsum otherwise.
+    ``sink``: optional (H,) learned sink logits of a WINDOW layer: beside
+    each row's scaled scores in the softmax's sum, with no value
+    (``flash_attention(sink=)``: the windowed forward's initial state; the
+    einsum here is the concatenated-column definition, and the one a
+    gradient runs through).
     """
     static_window = window is None or (
         isinstance(window, int) and window > 0 and causal)
+    if sink is not None and not (static_window and window is not None):
+        raise ValueError("a sink goes with a static causal window")
     if block is not None and block <= 1:
         block = None
     if block is not None and (window is not None or not causal):
@@ -306,6 +325,12 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
                                       and q.shape[1] % block == 0)):
                 batch, heads = _attn_axes(mesh, q.shape[0], q.shape[2])
                 spec = P(batch, None, heads, None)
+                if sink is not None:
+                    return _kernel_on_mesh(
+                        lambda q, k, v, sink: fa.flash_attention(
+                            q, k, v, window=window, sink=sink, **kw),
+                        mesh, (q, k, v, sink), (spec, spec, spec, P(heads)),
+                        spec)
                 return _kernel_on_mesh(
                     lambda q, k, v: fa.flash_attention(
                         q, k, v, causal=causal, window=window, **kw,
@@ -331,7 +356,12 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     if key_padding_mask is not None:
         keep = jnp.asarray(key_padding_mask).astype(jnp.bool_)
         logits = jnp.where(keep[:, None, None, :], logits, NEG_INF_ATTN)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    if sink is not None:
+        probs = _softmax_beside_sink(
+            logits, sink.astype(jnp.float32)[None, :, None, None]
+        ).astype(q.dtype)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return checkpoint_name(jnp.einsum("bhqk,bkhd->bqhd", probs, v), SAVED_O)
 
 
@@ -352,27 +382,51 @@ def kv_cache_width(n_kv: int, head_dim: int) -> int:
     return -(-n_kv * head_dim // KV_LANES) * KV_LANES
 
 
+def _row_dims(head_dim, rows):
+    """A head's columns in each row array: ``head_dim`` an int (every array
+    alike) or one a row array (K rows at the q.k width, V rows at v's)."""
+    return (head_dim,) * len(rows) if isinstance(head_dim, int) else head_dim
+
+
 def init_kv_cache(n_layer: int, batch_size: int, max_len: int, n_kv: int,
-                  head_dim: int, dtype, rows=("k", "v")):
-    """``rows``: the row arrays the cache holds. A latent-attention model
+                  head_dim, dtype, rows=("k", "v")):
+    """``rows``: the row arrays the cache holds, ``head_dim`` a head's
+    columns in them (an int, or one a row array where K rows and V rows
+    differ in width). A latent-attention model
     holds ONE, a position's ``[c_kv | k_rope]`` row for all heads (``n_kv``
     1, ``head_dim`` its width: 576 values in 640 lanes where K/V of 128
     heads would be 40,960): the same layout, writes and in-place reads.
     ``n_layer``: the layers that HAVE such rows — of a hybrid model its
     softmax layers only; what its other layers keep of a sequence is not a
     row a position and lies beside these arrays in the same dict
-    (``models/kda.py::STATE_LEAVES``, ``cache_footprint``)."""
-    shape = (n_layer, batch_size, max_len, kv_cache_width(n_kv, head_dim))
-    return {**{name: jnp.zeros(shape, dtype) for name in rows},
+    (``models/kda.py::STATE_LEAVES``, ``cache_footprint``). A WINDOW
+    layer's rows are a RING (``init_kv_ring``)."""
+    return {**{name: jnp.zeros((n_layer, batch_size, max_len, width), dtype)
+               for name, width in zip(rows, (
+                   kv_cache_width(n_kv, d)
+                   for d in _row_dims(head_dim, rows)))},
             "pos": jnp.zeros((), jnp.int32)}
 
 
-def kv_cache_partition_specs(n_kv: int, head_dim: int, rows=("k", "v")):
+def init_kv_ring(n_layer: int, batch_size: int, window: int, n_kv: int,
+                 head_dim, dtype):
+    """What the WINDOW layers of a model that gives them a cache of their
+    own keep of a sequence: the last ``window`` positions' rows, ``win_k`` /
+    ``win_v`` (L_win, B, window, W) in ``init_kv_cache``'s layout, a RING:
+    position p lies in slot ``p % window`` (``kv_ring_write``), and K is
+    rotated before it is written, so the order of the slots means nothing
+    to the softmax. Bytes a SEQUENCE, whatever its length (``cache_ring``)."""
+    held = init_kv_cache(n_layer, batch_size, window, n_kv, head_dim, dtype)
+    return dict(zip(CACHE_RING_ROWS, (held["k"], held["v"])))
+
+
+def kv_cache_partition_specs(n_kv: int, head_dim, rows=("k", "v")):
     """Heads over 'tensor' where the rows carry no pad columns (a padded row
     cut into equal shards would cut through heads); replicated otherwise."""
     from deepspeed_tpu.parallel.topology import TENSOR_AXIS
 
-    heads = TENSOR_AXIS if (n_kv * head_dim) % KV_LANES == 0 else None
+    heads = TENSOR_AXIS if all((n_kv * d) % KV_LANES == 0
+                               for d in _row_dims(head_dim, rows)) else None
     return {**{name: P(None, None, None, heads) for name in rows},
             "pos": P()}
 
@@ -382,6 +436,9 @@ def kv_cache_partition_specs(n_kv: int, head_dim: int, rows=("k", "v")):
 # is ``models/kda.py::STATE_LEAVES``, (L, B, ...). Anything else in a cache
 # dict (``pos``, a counter) is neither.
 CACHE_POSITION_ROWS = ("k", "v", "kv")
+# ... and the rings of ``init_kv_ring``: rows a position too, but only the
+# last ``window`` of them, so bytes a SEQUENCE
+CACHE_RING_ROWS = ("win_k", "win_v")
 
 
 def cache_footprint(cache):
@@ -395,6 +452,15 @@ def cache_footprint(cache):
                 for x in held(CACHE_POSITION_ROWS)),
             sum(x.size // x.shape[1] * x.dtype.itemsize
                 for x in held(STATE_LEAVES)))
+
+
+def cache_ring(cache):
+    """(bytes ONE sequence's rings hold across the window layers, pad lanes
+    and all, whatever its length; the slots of a ring) of a cache dict;
+    (0, 0) where it holds none."""
+    held = [cache[n] for n in CACHE_RING_ROWS if n in cache]
+    return (sum(x.size // x.shape[1] * x.dtype.itemsize for x in held),
+            held[0].shape[2] if held else 0)
 
 
 def kv_cache_rows(t, max_len: int):
@@ -414,6 +480,21 @@ def kv_cache_write(cache, t, layer, pos):
     return jax.lax.dynamic_update_slice(
         cache, t.reshape(1, B, T, KV * Dh).astype(cache.dtype),
         (layer, 0, pos, 0))
+
+
+def kv_ring_write(ring, t, layer, pos):
+    """k or v (B, T, KV, Dh) at positions ``pos .. pos + T - 1`` into
+    ``layer`` of a ring (``init_kv_ring``): position p into slot ``p %
+    window``. One position (a decode step; ``pos`` traced) is one slot; a
+    prompt (``pos`` 0, T static) leaves its last ``window`` positions, each
+    in its slot."""
+    B, T, KV, Dh = t.shape
+    window = ring.shape[2]
+    if T == 1:
+        return kv_cache_write(ring, t, layer, pos % window)
+    if T > window:      # position T - window + i into slot (T - window + i) % window
+        t = jnp.roll(t[:, T - window:], (T - window) % window, axis=1)
+    return kv_cache_write(ring, t, layer, 0)
 
 
 def read_as_stored(w):
@@ -440,7 +521,8 @@ def read_as_stored(w):
 
 
 def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
-                            alibi=None, window=None, early=None):
+                            alibi=None, window=None, early=None, v_dim=None,
+                            sink=None):
     """Single-token decode attention over the stacked KV cache
     (``init_kv_cache``), shared by the model families. q: (B, H, Dh) — the
     new token's queries; caches (L, B, S, W), ``layer`` of them valid
@@ -456,6 +538,13 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     of the positions see slots ``0 .. last`` (traced, <= pos) only: the
     block BEFORE the step's, carried in the same pass and blind to the new
     block's slots.
+
+    ``v_dim``: a head's columns in ``v_cache`` where they are not ``Dh``
+    (q.k at 192, v at 128: the two caches differ in width) -> (B, H, v_dim).
+    ``sink``: (H,) a head's learned sink logit, beside the scaled scores in
+    the softmax's sum, with no value. A window layer's RING
+    (``init_kv_ring``) is attended whole: the caller hands ``pos`` as its
+    last valid slot, ``min(pos, window - 1)``, and no ``window``.
 
     The path is chosen the way ``local_causal_attention`` chooses flash:
     the Pallas streaming kernel (ops/pallas/decode_attention.py), which
@@ -474,15 +563,17 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
     if early is not None:
         raise ValueError("early: of a block of query positions")
     B, H, Dh = q.shape
+    extra = {**({} if v_dim is None else {"v_dim": v_dim}),
+             **({} if sink is None else {"sink": sink})}
     if alibi is None and window is None:
         mesh, on_tpu = _kernel_target()
         if on_tpu:
             return _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer,
-                                          pos, n_kv)
+                                          pos, n_kv, **extra)
     S = k_cache.shape[2]
-    layer_of = lambda c: jax.lax.dynamic_index_in_dim(
-        c, layer, 0, keepdims=False)[..., :n_kv * Dh].reshape(B, S, n_kv, Dh)
-    k_l, v_l = layer_of(k_cache), layer_of(v_cache)
+    layer_of = lambda c, d=Dh: jax.lax.dynamic_index_in_dim(
+        c, layer, 0, keepdims=False)[..., :n_kv * d].reshape(B, S, n_kv, d)
+    k_l, v_l = layer_of(k_cache), layer_of(v_cache, v_dim or Dh)
     qg = q.reshape(B, n_kv, H // n_kv, Dh)
     scale = 1.0 / math.sqrt(Dh)
     s = jnp.einsum("bgrd,bkgd->bgrk", qg, k_l).astype(jnp.float32) * scale
@@ -496,18 +587,22 @@ def cached_decode_attention(q, k_cache, v_cache, layer, pos, n_kv: int,
         w = jnp.asarray(window, jnp.int32)
         valid = valid & (((jnp.arange(S) > pos - w) | (w <= 0))[None, None, None])
     s = jnp.where(valid, s, NEG_INF_ATTN)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bgrk,bkgd->bgrd", p, v_l).reshape(B, H, Dh)
+    if sink is not None:
+        p = _softmax_beside_sink(s, sink.astype(jnp.float32).reshape(
+            1, n_kv, H // n_kv, 1)).astype(q.dtype)
+    else:
+        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bgrk,bkgd->bgrd", p, v_l).reshape(B, H, -1)
 
 
 def _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos, n_kv: int,
-                           early=None):
+                           early=None, v_dim=None, sink=None):
     """``decode_attn`` (ops/pallas/decode_attention.py) on q (B, H, Dh) or
     (B, Lb, H, Dh), from a program compiled over ``mesh``."""
     from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
 
     batch, heads = _attn_axes(mesh, q.shape[0], n_kv)
-    if (n_kv * q.shape[-1]) % KV_LANES:
+    if (n_kv * q.shape[-1]) % KV_LANES or (n_kv * (v_dim or 0)) % KV_LANES:
         heads = None        # padded rows stay whole (see the specs)
     local_kv = n_kv // (mesh.shape[heads] if heads else 1)
     cache_spec = P(None, batch, None, heads)
@@ -518,6 +613,15 @@ def _decode_kernel_on_mesh(mesh, q, k_cache, v_cache, layer, pos, n_kv: int,
         kernel = lambda q, k, v, layer, pos, last: decode_attention(
             q, k, v, layer, pos, n_kv=local_kv, early=(early[0], last))
         scalars += (early[1],)
+    if v_dim is not None or sink is not None:   # a window model's two kinds
+        sinks = () if sink is None else (sink,)
+        kernel = lambda q, k, v, layer, pos, *b: decode_attention(
+            q, k, v, layer, pos, n_kv=local_kv, v_dim=v_dim,
+            **({"sink": b[0]} if b else {}))
+        return _kernel_on_mesh(
+            kernel, mesh, (q, k_cache, v_cache) + scalars + sinks,
+            (q_spec, cache_spec, cache_spec, P(), P())
+            + (P(heads),) * len(sinks), q_spec)
     return _kernel_on_mesh(
         kernel, mesh, (q, k_cache, v_cache) + scalars,
         (q_spec, cache_spec, cache_spec) + (P(),) * len(scalars), q_spec)
@@ -639,7 +743,7 @@ def kda_qkv(p, tail, conv_w, heads: int, eps: float,
 
 
 def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,
-                     alibi=None, flash_block=None, window=None):
+                     alibi=None, flash_block=None, window=None, sink=None):
     """The full causal-attention dispatch shared by the model families:
     sequence-parallel (ring / Ulysses over the 'seq' mesh axis) when enabled
     and the mesh has a seq axis, else ``local_causal_attention``."""
@@ -663,7 +767,8 @@ def causal_attention(q, k, v, use_flash: bool = True, sequence_parallel=False,
             return checkpoint_name(
                 seq_par.ring_attention(q, k, v, mesh, causal=True), SAVED_O)
     return local_causal_attention(q, k, v, use_flash, alibi=alibi,
-                                  flash_block=flash_block, window=window)
+                                  flash_block=flash_block, window=window,
+                                  **({} if sink is None else {"sink": sink}))
 
 
 def parse_lm_batch(batch):

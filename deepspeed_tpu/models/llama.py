@@ -93,7 +93,26 @@ LLaMA-specific pieces the GPT-2 trunk lacks:
     (``LlamaConfig.stack_pattern``). The cached walk keeps the WHOLE context
     for every layer and gives a window layer's decode step the window's
     slots (``cached_decode_attention(window=)``: the einsum, the decode
-    kernel carries no window yet; a window-sized cache is ROADMAP R4);
+    kernel carries no window);
+  - **window layers with a mixer of their OWN** (``window_kv_head``: another
+    number of KV heads than the full layers'; ``window_sink``: a learned
+    sink logit a head, which takes mass in the softmax and carries no value;
+    MiMo-V2-Flash): the two softmax kinds' LEAVES differ, so each lies in a
+    stack of its own over the layers that have it (``attn_blocks``,
+    ``win_blocks``) beside ``blocks``, as KDA layers do, and a block's KV
+    head count is read off its ``k_w``. The cache holds TWO kinds of rows in
+    the one dict: ``k`` / ``v`` over the FULL layers, the whole context; and
+    ``win_k`` / ``win_v`` over the window layers, a RING of ``sliding_window``
+    slots (``common.init_kv_ring``: a decode step writes slot ``pos %
+    window``, a prefill the prompt's last ``window`` positions; K is rotated
+    before it is written, so slot order means nothing to the softmax), which
+    a decode step attends whole through the decode kernel (valid length
+    ``min(pos + 1, window)``) and a prefill never reads: its window layers
+    run the flash kernels' band. With them GQA's other widths: a value head
+    size of its own (``v_head_dim``: q.k at 192, v at 128; K rows and V rows
+    then differ in width), a rotary embedding over a head's first
+    ``rotary_dim`` columns, a rotary base by kind (``window_rope_theta``),
+    ``value_scale`` x v;
   - **per-head q/k norm** (``qk_norm="head"``: gains ``(head_dim,)``, told
     from OLMoE's whole-projection form by the leaf's shape), **an embedding
     multiplier** (``embed_scale``), and **a selection bias** of the router
@@ -211,6 +230,19 @@ class LlamaConfig:
     layer_types: Optional[tuple] = None
     sliding_window: int = 0
     global_rope: bool = True
+    # window layers with a mixer of their OWN leaves: ``window_kv_head`` KV
+    # heads (None: ``n_kv_head``), a learned sink logit a head
+    # (``window_sink``); either gives them a stack (``win_blocks``) and a
+    # cache (a ring of ``sliding_window`` slots) of their own. Their rotary
+    # base where it is not ``rope_theta``
+    window_kv_head: Optional[int] = None
+    window_sink: bool = False
+    window_rope_theta: Optional[float] = None
+    # GQA's other widths: the columns of a head the rotary embedding turns
+    # (None: all of them), x v before it is attended; v's head size is
+    # ``v_head_dim`` (0: ``head_dim``), which latent attention has too
+    rotary_dim: Optional[int] = None
+    value_scale: float = 1.0
     # x the embedding's output (muP: n_embd ** 0.5)
     embed_scale: float = 1.0
     # a per-expert bias of the router's SELECTION (leaf ``router_bias``,
@@ -338,6 +370,23 @@ class LlamaConfig:
                     "layer_types (window and full softmax layers) with "
                     "gqa_layers, latent attention or sequence parallelism "
                     "is not built")
+        if self.window_kv_head is not None or self.window_sink \
+                or self.window_rope_theta is not None:
+            kv = self.window_kv_head or self.n_kv_head
+            if self.layer_types is None or self.n_head % kv \
+                    or self.sequence_parallel \
+                    or len(set(self.kinds[:self.n_dense_layers])) > 1:
+                raise ValueError(
+                    "window_kv_head / window_sink / window_rope_theta: of "
+                    "the window layers of a layer_types pattern, "
+                    f"window_kv_head={kv} dividing n_head={self.n_head}, "
+                    "leading dense layers of ONE kind, no sequence "
+                    "parallelism")
+        if self.rotary_dim is not None and not (
+                0 < self.rotary_dim <= self.head_dim
+                and self.rotary_dim % 2 == 0 and not self.mla):
+            raise ValueError(f"rotary_dim={self.rotary_dim}: an even number "
+                             f"of a GQA head's {self.head_dim} columns")
         if self.qk_norm not in (False, True, "head") or \
                 (self.router_bias and not self.n_experts):
             raise ValueError(f"qk_norm={self.qk_norm!r} (False | True | "
@@ -378,7 +427,23 @@ class LlamaConfig:
     @property
     def rope_dim(self) -> int:
         """Columns of a head the rotary embedding turns."""
-        return self.qk_rope_head_dim if self.mla else self.head_dim
+        return self.qk_rope_head_dim if self.mla \
+            else self.rotary_dim or self.head_dim
+
+    @property
+    def v_dim(self) -> int:
+        """A GQA head's columns of v (and of the attention's output)."""
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def own_window(self) -> bool:
+        """Whether the window layers hold leaves a full layer does not: a
+        stack and a cache of their own."""
+        return self.window_kv_head is not None or self.window_sink
+
+    def kv_heads(self, kind="attn") -> int:
+        return self.window_kv_head if kind == "win" and self.window_kv_head \
+            else self.n_kv_head
 
     @property
     def latent_dim(self) -> int:
@@ -419,11 +484,14 @@ class LlamaConfig:
         """What the leading dense layers' stack holds of a mixer: true, the
         model's one kind of softmax mixer; with a layer pattern the ONE kind
         of those layers (``"attn"`` | ``"kda"``)."""
-        return True if self.gqa_layers is None else self.kinds[0]
+        return self.kinds[0] if self.gqa_layers is not None \
+            or self.own_window else True
 
     @property
     def n_attn_layers(self) -> int:
         """Layers that keep rows a position in the cache."""
+        if self.own_window:
+            return self.kinds.count("attn")
         return self.n_layer if self.gqa_layers is None \
             else len(self.gqa_layers)
 
@@ -452,7 +520,9 @@ class LlamaConfig:
                 + c.n_head * c.v_head_dim * d
         else:
             heads = c.n_head * c.head_dim
-            attn = (2 + c.attn_gate) * d * heads + 2 * d * c.kv_dim
+            gqa = lambda kv: (1 + c.attn_gate) * d * heads \
+                + c.n_head * c.v_dim * d + d * kv * (c.head_dim + c.v_dim)
+            attn = gqa(c.n_kv_head)
             if c.qk_norm:
                 attn += 2 * c.head_dim if c.qk_norm == "head" \
                     else heads + c.kv_dim
@@ -469,6 +539,10 @@ class LlamaConfig:
             from deepspeed_tpu.models import kda
 
             total += (c.n_layer - c.n_attn_layers) * (kda.num_params(c) - attn)
+        if c.own_window:                # the window layers' mixer for a full one's
+            total += c.kinds.count("win") * (
+                gqa(c.kv_heads("win")) - gqa(c.n_kv_head)
+                + c.n_head * c.window_sink)
         return total
 
     @property
@@ -486,9 +560,13 @@ class LlamaConfig:
         ``passes_per_token``. What a profile or a service estimate divides a
         generation's time by, where ``flops_per_token`` is a TRAINED
         token's."""
+        attended = self.n_attn_layers * context
+        if self.own_window:             # a ring holds the window and no more
+            attended += self.kinds.count("win") * min(context,
+                                                      self.sliding_window)
         return self.passes_per_token * (
             2 * self.num_params(active=True)
-            + 4 * self.n_attn_layers * self.n_head * self.head_dim * context)
+            + 2 * self.n_head * (self.head_dim + self.v_dim) * attended)
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Megatron accounting (6N + 12·l·d·s), as in GPT2Config: GQA does not
@@ -568,9 +646,11 @@ class LlamaModel:
                              float(c.confidence_threshold), c.mask_token_id)
 
     # ---------------------------------------------------------------- params
-    def _init_mixer(self, keys, l: int) -> Dict[str, Any]:
+    def _init_mixer(self, keys, l: int, kind="attn") -> Dict[str, Any]:
         """``l`` softmax mixers, stacked: GQA (with its q/k norm and output
-        gate where the configuration has them) or latent attention."""
+        gate where the configuration has them) or latent attention.
+        ``kind``: ``"win"`` for window layers that hold leaves of their own
+        (their KV head count, a ``sink`` logit a head)."""
         c = self.config
         d, s = c.n_embd, 0.02
         fold = jax.random.fold_in
@@ -594,11 +674,16 @@ class LlamaModel:
                 kv_b_v_w=norm(fold(keys[3], 2),
                               (l, h, c.kv_lora_rank, c.v_head_dim), s),
                 o_w=norm(keys[4], (l, h * c.v_head_dim, d), proj_scale))
-        heads = c.n_head * c.head_dim
+        heads, kv = c.n_head * c.head_dim, c.kv_heads(kind)
         mixer = dict(q_w=norm(keys[1], (l, d, heads), s),
-                     k_w=norm(keys[2], (l, d, c.kv_dim), s),
-                     v_w=norm(keys[3], (l, d, c.kv_dim), s),
-                     o_w=norm(keys[4], (l, heads, d), proj_scale))
+                     k_w=norm(keys[2], (l, d, kv * c.head_dim), s),
+                     v_w=norm(keys[3], (l, d, kv * c.v_dim), s),
+                     o_w=norm(keys[4], (l, c.n_head * c.v_dim, d),
+                              proj_scale))
+        if kind == "win" and c.window_sink:
+            # N(0, 1): beside scores of about that spread it takes a share
+            # of the mass one can see (a trained sink does)
+            mixer.update(sink=norm(fold(keys[4], 1), (l, c.n_head), 1.0))
         if c.qk_norm == "head":
             mixer.update(q_norm_g=ones(l, c.head_dim),
                          k_norm_g=ones(l, c.head_dim))
@@ -631,7 +716,8 @@ class LlamaModel:
 
             blocks.update(kda.init_leaves(c, fold(keys[1], 7), l, proj_scale))
         elif mixer:
-            blocks.update(self._init_mixer(keys, l))
+            blocks.update(self._init_mixer(
+                keys, l, "win" if mixer == "win" else "attn"))
         if c.sandwich_norm:
             blocks.update(post_attn_norm_g=ones(l, d),
                           post_mlp_norm_g=ones(l, d))
@@ -673,6 +759,7 @@ class LlamaModel:
         c = self.config
         return ("blocks",) \
             + (("attn_blocks", "kda_blocks") if c.gqa_layers is not None else ()) \
+            + (("attn_blocks", "win_blocks") if c.own_window else ()) \
             + (("dense_blocks",) if c.n_dense_layers else ())
 
     def init_params(self, rng) -> Dict[str, Any]:
@@ -682,7 +769,9 @@ class LlamaModel:
         (norms, router, experts), over ALL layers, and each kind of mixer in
         a stack of its own over the layers that have it: ``attn_blocks``
         (softmax) and ``kda_blocks`` (``models/kda.py``); its leading dense
-        layers, all of one kind, hold that kind's leaves themselves."""
+        layers, all of one kind, hold that kind's leaves themselves. So
+        does a model whose window layers hold leaves of their own:
+        ``attn_blocks`` (full) and ``win_blocks``."""
         c = self.config
         keys = jax.random.split(rng, 8)
         norm = lambda key, shape: \
@@ -691,8 +780,15 @@ class LlamaModel:
         params = {"wte": norm(keys[0], (c.vocab_size, c.n_embd)),
                   "blocks": self._init_stack(
                       keys, c.n_layer - c.n_dense_layers,
-                      routed=bool(c.n_experts), mixer=not hybrid),
+                      routed=bool(c.n_experts),
+                      mixer=not (hybrid or c.own_window)),
                   "norm_g": jnp.ones((c.n_embd,), c.param_dtype)}
+        if c.own_window:
+            own = c.kinds[c.n_dense_layers:]
+            params["attn_blocks"] = self._init_mixer(keys, own.count("attn"))
+            params["win_blocks"] = self._init_mixer(
+                jax.random.split(jax.random.fold_in(rng, 3), 8),
+                own.count("win"), "win")
         if hybrid:
             from deepspeed_tpu.models import kda
 
@@ -710,7 +806,7 @@ class LlamaModel:
                                      (c.n_embd, c.vocab_size))
         return params
 
-    def _mixer_specs(self) -> Dict[str, Any]:
+    def _mixer_specs(self, kind="attn") -> Dict[str, Any]:
         c = self.config
         rep = lambda rank: P(*([None] * rank))
         if c.mla:
@@ -726,6 +822,8 @@ class LlamaModel:
             specs.update(q_norm_g=rep(2), k_norm_g=rep(2))
         if c.attn_gate:
             specs.update(attn_gate_w=P(None, None, "tensor"))
+        if kind == "win" and c.window_sink:
+            specs.update(sink=P(None, "tensor"))
         return specs
 
     def _stack_specs(self, routed: bool, mixer: bool = True) -> Dict[str, Any]:
@@ -737,7 +835,8 @@ class LlamaModel:
 
             blocks.update(kda.leaf_specs())
         elif mixer:
-            blocks.update(self._mixer_specs())
+            blocks.update(self._mixer_specs(
+                "win" if mixer == "win" else "attn"))
         if c.sandwich_norm:
             blocks.update(post_attn_norm_g=rep(2), post_mlp_norm_g=rep(2))
         if not routed:
@@ -765,9 +864,12 @@ class LlamaModel:
         c = self.config
         hybrid = c.gqa_layers is not None
         specs = {"wte": P("tensor", None),
-                 "blocks": self._stack_specs(bool(c.n_experts),
-                                             mixer=not hybrid),
+                 "blocks": self._stack_specs(
+                     bool(c.n_experts), mixer=not (hybrid or c.own_window)),
                  "norm_g": P(None)}
+        if c.own_window:
+            specs["attn_blocks"] = self._mixer_specs()
+            specs["win_blocks"] = self._mixer_specs("win")
         if hybrid:
             from deepspeed_tpu.models import kda
 
@@ -793,12 +895,13 @@ class LlamaModel:
 
     def _repeat_kv(self, t):
         """(B, T, KV, Dh) → (B, T, H, Dh) for the attention kernel."""
-        rep = self.config.n_head // self.config.n_kv_head
+        rep = self.config.n_head // t.shape[2]
         return t if rep == 1 else jnp.repeat(t, rep, axis=2)
 
-    def _causal(self, q, k, v, window=None):
+    def _causal(self, q, k, v, window=None, **sink):
         """The trunk's attention on full-head q, k, v: the shared dispatch
-        (models/common.py: sequence-parallel → flash → einsum)."""
+        (models/common.py: sequence-parallel → flash → einsum). ``sink``: a
+        window layer's sink logits, where it holds them."""
         from deepspeed_tpu.models.common import causal_attention
 
         c = self.config
@@ -809,7 +912,7 @@ class LlamaModel:
                                           block=c.block_length)
         return causal_attention(q, k, v, use_flash=c.use_flash_attention,
                                 sequence_parallel=c.sequence_parallel,
-                                window=window)
+                                window=window, **sink)
 
     def _window(self, kind):
         """A layer kind's causal window: ``sliding_window`` keys for a
@@ -817,10 +920,24 @@ class LlamaModel:
         return self.config.sliding_window if kind == "win" else None
 
     def _rope_of(self, kind, cos_sin):
-        """A layer kind's rotary tables: the model's, or none for the full
-        layers of a pattern whose window layers alone are rotated."""
+        """A layer kind's rotary tables: the model's (its own kind's, where
+        the kinds have a base each), or none for the full layers of a
+        pattern whose window layers alone are rotated."""
+        if isinstance(cos_sin, dict):
+            return cos_sin[kind]
         return (None, None) if kind == "attn" and not self.config.global_rope \
             else cos_sin
+
+    def _cache_group(self, kind):
+        """Which of the cache's arrays a layer of ``kind`` keeps its part of
+        a sequence in: KDA's state, a window layer's ring where the model
+        gives it one, else the rows a position."""
+        return kind if kind == "kda" or (
+            kind == "win" and self.config.own_window) else "attn"
+
+    @staticmethod
+    def _sink_of(blk):
+        return {"sink": blk["sink"]} if "sink" in blk else {}
 
     def _embed(self, params, ids):
         c = self.config
@@ -830,7 +947,7 @@ class LlamaModel:
                 x = (x.astype(jnp.float32) * c.embed_scale).astype(c.dtype)
             return x
 
-    def _stacks(self, params, split_experts=True):
+    def _stacks(self, params, split_experts=True, by_index=False):
         """The trunk's stacks in order, each as ``(xs, experts, first, view,
         pattern)`` (``pattern``: one period of the stack's kinds of layer):
         ``xs`` is what a scan over the stack's PERIODS of the layer pattern
@@ -848,7 +965,16 @@ class LlamaModel:
         model are of one kind and hold its leaves themselves: a period is a
         layer). Window and full softmax layers hold
         the same leaves: they stay in ``blocks`` / ``dense_blocks``, and
-        each of the two stacks walks its own phase of the pattern."""
+        each of the two stacks walks its own phase of the pattern.
+        ``by_index`` (the cached walk): where window layers hold leaves of
+        their own, each softmax kind's stack stays WHOLE outside ``xs`` and
+        ``view(per, j, i, n)`` indexes it by the layer's place in it, from
+        the period ``n``: a slice of a period's mixers taken by the outer
+        scan and indexed again by a run's loop is a COPY of them a period a
+        decode step (5 x (4096, 12288) + 5 x (8192, 4096) bf16 = 0.84 GB,
+        twice a token: the first chip run's 10.8 ms a token against 3.9 of
+        weights, PERF.md PR 49); one dynamic slice of one layer is read in
+        place by the matmul that takes it."""
         c = self.config
         blocks, experts = self._split_experts(params["blocks"]) \
             if split_experts else (params["blocks"], None)
@@ -856,21 +982,35 @@ class LlamaModel:
             lambda a: a.reshape(a.shape[0] // each, each, *a.shape[1:]), tree)
         stacks = []
         # (leaves, expert leaves, first layer, layers, mixers in stacks of
-        # their own: the routed stack of a KDA pattern)
+        # their own: the routed stack of a KDA pattern, or of one whose
+        # window layers hold leaves of their own)
         for held, exp, first, count, own in (
                 (params.get("dense_blocks"), None, 0, c.n_dense_layers, False),
                 (blocks, experts, c.n_dense_layers,
-                 c.n_layer - c.n_dense_layers, c.gqa_layers is not None)):
+                 c.n_layer - c.n_dense_layers,
+                 c.gqa_layers is not None or c.own_window)):
             if not count:
                 continue
             pattern = c.stack_pattern(first, count)
-            if own:
+            if own and by_index and c.own_window:
+                whole = {kind: params[f"{kind}_blocks"]
+                         for kind in set(pattern)}
+
+                def view(per, j, i=0, n=0, pattern=pattern, whole=whole):
+                    kind = pattern[j]
+                    at = n * pattern.count(kind) + pattern[:j].count(kind) + i
+                    return {**jax.tree.map(lambda a: a[j + i], per["all"]),
+                            **jax.tree.map(lambda a: a[at], whole[kind])}
+
+                stacks.append(({"all": group(held, len(pattern))}, exp, first,
+                               view, pattern))
+            elif own:
                 xs = {"all": group(held, len(pattern)),
                       **{kind: group(params[f"{kind}_blocks"],
                                      pattern.count(kind))
                          for kind in sorted(set(pattern))}}
 
-                def view(per, j, i=0, pattern=pattern):
+                def view(per, j, i=0, n=None, pattern=pattern):
                     kind = pattern[j]
                     mine = pattern[:j].count(kind)
                     return {**jax.tree.map(lambda a: a[j + i], per["all"]),
@@ -879,24 +1019,25 @@ class LlamaModel:
                 stacks.append((xs, exp, first, view, pattern))
             elif len(pattern) == 1:
                 stacks.append((held, exp, first,
-                               lambda per, j, i=0: per, pattern))
+                               lambda per, j, i=0, n=None: per, pattern))
             else:
                 stacks.append((
                     group(held, len(pattern)), exp, first,
-                    lambda per, j, i=0: jax.tree.map(
+                    lambda per, j, i=0, n=None: jax.tree.map(
                         lambda a: a[j + i], per), pattern))
         return stacks
 
-    @staticmethod
-    def _layer_at(pattern, n, j, i=0):
+    def _layer_at(self, pattern, n, j, i=0):
         """For layer j + i of period ``n`` (n, i traced) of a stack that
         walks ``pattern``: (its index in the stack, its index among the
         stack's layers that keep what it keeps of a sequence — the layer
-        axis of those cache arrays: rows a position for the softmax kinds,
-        full or window, a state for KDA)."""
+        axis of those cache arrays (``_cache_group``): rows a position for
+        the softmax kinds, full or window, a state for KDA, a ring for
+        window layers that have one)."""
         if len(pattern) == 1:
             return n, n
-        same = [(k == "kda") == (pattern[j] == "kda") for k in pattern]
+        group = self._cache_group
+        same = [group(k) == group(pattern[j]) for k in pattern]
         return n * len(pattern) + j + i, \
             n * sum(same) + sum(same[:j]) + i
 
@@ -907,14 +1048,32 @@ class LlamaModel:
         if not c.use_rope:
             return None, None
         with scope("attn/qkv"):
+            if c.window_rope_theta is not None:     # a base a kind
+                return {kind: _rope_cos_sin(positions, c.rope_dim, theta,
+                                            c.rope_scaling)
+                        for kind, theta in (("attn", c.rope_theta),
+                                            ("win", c.window_rope_theta))}
             return _rope_cos_sin(positions, c.rope_dim, c.rope_theta,
                                  c.rope_scaling)
 
+    @staticmethod
+    def _rotate(t, cos, sin):
+        """The rotary embedding on the first ``cos.shape[-1]`` columns of
+        every head of t; the others pass as they are."""
+        r = cos.shape[-1]
+        if r == t.shape[-1]:
+            return apply_rope(t, cos, sin)
+        return jnp.concatenate([apply_rope(t[..., :r], cos, sin),
+                                t[..., r:]], axis=-1)
+
     def _block_qkv(self, x, blk, cos, sin):
         """One GQA block's q, k, v for the current x, rotated where the
-        model has a rotary embedding."""
+        model has a rotary embedding. The KV heads are as many as the
+        block's ``k_w`` makes (a window layer may hold another number than a
+        full one), v's columns a head what its ``v_w`` makes."""
         c = self.config
         B, T, D = x.shape
+        n_kv = blk["k_w"].shape[-1] // c.head_dim
         with scope("attn/qkv"):
             h = self._rms_norm(x, blk["attn_norm_g"])
             hd = h.astype(c.dtype)
@@ -927,16 +1086,18 @@ class LlamaModel:
                 q = self._rms_norm(q, blk["q_norm_g"])
                 k = self._rms_norm(k, blk["k_norm_g"])
             q = q.reshape(B, T, c.n_head, c.head_dim)
-            k = k.reshape(B, T, c.n_kv_head, c.head_dim)
+            k = k.reshape(B, T, n_kv, c.head_dim)
             if per_head:
                 # gains (head_dim,): each head's own 128 columns (with ONE
                 # head the two forms are one)
                 q = self._rms_norm(q, blk["q_norm_g"])
                 k = self._rms_norm(k, blk["k_norm_g"])
-            v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, c.n_kv_head, c.head_dim)
+            v = (hd @ blk["v_w"].astype(hd.dtype)).reshape(B, T, n_kv, -1)
+            if c.value_scale != 1.0:
+                v = v * jnp.asarray(c.value_scale, v.dtype)
             if cos is None:
                 return q, k, v
-            return apply_rope(q, cos, sin), apply_rope(k, cos, sin), v
+            return self._rotate(q, cos, sin), self._rotate(k, cos, sin), v
 
     def _gated(self, attn, x, blk):
         """The softmax output (B, T, H, Dh) times ``sigmoid(h attn_gate_w)``,
@@ -1007,12 +1168,15 @@ class LlamaModel:
             return attention(q, k, v), (latent,)
 
     def _attend_cached(self, x, blk, cos_sin, caches, layer, pos,
-                       window=None, early: int = 0):
+                       window=None, early: int = 0, ring: bool = False):
         """The new token's attention over the cache (decode): its rows are
         written into slot ``pos`` of ``layer``, then attended with the rest
         — under a ``window`` with the last ``window`` slots (the cache holds
         the whole context for every layer; ``cached_decode_attention`` takes
-        its einsum for a window: the decode kernel carries none yet).
+        its einsum for a window: the decode kernel carries none) or, where
+        the caches are a ``ring`` of ``window`` slots, written into slot
+        ``pos % window`` and attended with all that is valid of the ring
+        (the decode kernel, K and V at their own widths, the block's sink).
         -> (attn (B, 1, H, Dv), the caches). x may hold a BLOCK of T
         positions (``block_step``): their rows go into slots ``pos .. pos +
         T - 1`` and every one of them attends over slots ``0 .. pos + T -
@@ -1021,14 +1185,16 @@ class LlamaModel:
         Dv)."""
         from deepspeed_tpu.models.common import (cached_decode_attention,
                                                  kv_cache_write,
+                                                 kv_ring_write,
                                                  latent_decode_attention)
 
         c = self.config
         if "kv_a_w" not in blk:
             q, k, v = self._block_qkv(x, blk, *cos_sin)     # q (B,1,H,Dh)
+            write = kv_ring_write if ring else kv_cache_write
             with scope("attn/core"):
-                cache_k = kv_cache_write(caches[0], k, layer, pos)
-                cache_v = kv_cache_write(caches[1], v, layer, pos)
+                cache_k = write(caches[0], k, layer, pos)
+                cache_v = write(caches[1], v, layer, pos)
                 # GQA decode against the KV-head cache — repeated K/V are
                 # never materialized (grouped einsum or the Pallas streaming
                 # kernel)
@@ -1038,9 +1204,15 @@ class LlamaModel:
                         c.n_kv_head,
                         early=(early, pos + early - 1) if early else None)
                     return self._gated(attn, x, blk), (cache_k, cache_v)
-                attn = cached_decode_attention(q[:, 0], cache_k, cache_v,
-                                               layer, pos, c.n_kv_head,
-                                               window=window)
+                # the KV heads the block's k_w has; a ring is attended whole,
+                # at what is valid of it; v's own width and the block's sink
+                # where the model has them
+                wide = {} if c.v_dim == c.head_dim else {"v_dim": c.v_dim}
+                attn = cached_decode_attention(
+                    q[:, 0], cache_k, cache_v, layer,
+                    jnp.minimum(pos, window - 1) if ring else pos,
+                    k.shape[2], window=None if ring else window, **wide,
+                    **self._sink_of(blk))
             return self._gated(attn[:, None], x, blk), (cache_k, cache_v)
         # absorbed: q.k_nope = (q_nope W_UK^T).c_kv and p.v = (p.c_kv) W_UV,
         # so the scores and the weighted sum are over the latent rows
@@ -1164,7 +1336,8 @@ class LlamaModel:
         else:
             attn, _ = self._attend(
                 x, blk, self._rope_of(kind, cos_sin),
-                functools.partial(self._causal, window=self._window(kind)))
+                functools.partial(self._causal, window=self._window(kind),
+                                  **self._sink_of(blk)))
         return self._block_finish(x, blk, attn)
 
     def _trunk(self, params, input_ids, rng=None, with_router_stats=False):
@@ -1317,12 +1490,25 @@ class LlamaModel:
         ``_attend``'s order): K and V at the KV heads, or ONE latent row a
         position, ``[c_kv | k_rope]``, for all heads."""
         c = self.config
-        return (1, c.latent_dim, ("kv",)) if c.mla \
-            else (c.n_kv_head, c.head_dim, ("k", "v"))
+        if c.mla:
+            return 1, c.latent_dim, ("kv",)
+        return (c.n_kv_head, c.head_dim if c.v_dim == c.head_dim
+                else (c.head_dim, c.v_dim), ("k", "v"))
+
+    def _ring_layout(self):
+        """(window layers, slots, KV heads, a head's columns in K and in V
+        rows) of the rings a model keeps for window layers of their own."""
+        c = self.config
+        return (c.kinds.count("win"), c.sliding_window, c.kv_heads("win"),
+                (c.head_dim, c.v_dim))
 
     def _cache_names(self):
         """The cache's arrays that ride the layer scan's carry."""
         rows = self._cache_layout()[2]
+        if self.config.own_window:
+            from deepspeed_tpu.models.common import CACHE_RING_ROWS
+
+            return rows + CACHE_RING_ROWS
         if self.config.gqa_layers is None:
             return rows
         from deepspeed_tpu.models import kda
@@ -1349,13 +1535,21 @@ class LlamaModel:
         steps have run: ``common.BLOCK_COUNTS``), and ``pending`` (B, Lb)
         int32: the final tokens of the block at ``pos .. pos + Lb - 1`` that
         is finished but not committed, for the next block's first pass to
-        carry (``block_step(pending=)``); zeros until a block is."""
-        from deepspeed_tpu.models.common import init_kv_cache
+        carry (``block_step(pending=)``); zeros until a block is. A model
+        whose window layers hold leaves of their own keeps ``k`` / ``v`` over
+        its FULL layers only and, for the window layers, ``win_k`` /
+        ``win_v`` (L_win, B, sliding_window, W): a ring, the same bytes
+        whatever ``max_len`` (``common.init_kv_ring``)."""
+        from deepspeed_tpu.models.common import init_kv_cache, init_kv_ring
 
         c = self.config
         n_kv, dim, rows = self._cache_layout()
         cache = init_kv_cache(c.n_attn_layers, batch_size, max_len, n_kv,
                               dim, c.dtype, rows=rows)
+        if c.own_window:
+            layers, slots, kv, dims = self._ring_layout()
+            cache.update(init_kv_ring(layers, batch_size, slots, kv, dims,
+                                      c.dtype))
         if c.gqa_layers is not None:
             from deepspeed_tpu.models import kda
 
@@ -1375,6 +1569,12 @@ class LlamaModel:
 
         n_kv, dim, rows = self._cache_layout()
         specs = kv_cache_partition_specs(n_kv, dim, rows=rows)
+        if self.config.own_window:
+            from deepspeed_tpu.models.common import CACHE_RING_ROWS
+
+            _, _, kv, dims = self._ring_layout()
+            specs.update(kv_cache_partition_specs(kv, dims,
+                                                  rows=CACHE_RING_ROWS))
         if self.config.gqa_layers is not None:
             from deepspeed_tpu.models import kda
 
@@ -1396,23 +1596,30 @@ class LlamaModel:
         the cache holds for it — zeros for a new sequence — and puts back
         what the last position left. ``kind``: the layer's, where its leaves
         cannot say it (a window layer: the same rows in the cache, a window
-        over them). ``early``: ``_attend_cached``'s."""
+        over them; or, in a model that gives it one, a ring of its own: the
+        two arrays after the rows). ``early``: ``_attend_cached``'s."""
         n_rows = len(self._cache_layout()[2])
         if "kda_qkv_w" not in blk:
             cos_sin, window = self._rope_of(kind, cos_sin), self._window(kind)
+            ring = self._cache_group(kind) == "win"
+            lo = n_rows if ring else 0
             if attention is None:
                 attn, rows = self._attend_cached(
-                    x, blk, cos_sin, caches[:n_rows], at, pos, window, early)
+                    x, blk, cos_sin, caches[lo:lo + n_rows], at, pos, window,
+                    early, ring)
             else:
-                from deepspeed_tpu.models.common import kv_cache_write
+                from deepspeed_tpu.models.common import (kv_cache_write,
+                                                         kv_ring_write)
 
                 attn, kept = self._attend(
                     x, blk, cos_sin, attention if window is None
-                    else functools.partial(attention, window=window))
+                    else functools.partial(attention, window=window,
+                                           **self._sink_of(blk)))
+                write = kv_ring_write if ring else kv_cache_write
                 with scope("attn/core"):
-                    rows = tuple(kv_cache_write(held, t, at, 0)
-                                 for held, t in zip(caches, kept))
-            return attn, rows + caches[n_rows:]
+                    rows = tuple(write(held, t, at, 0)
+                                 for held, t in zip(caches[lo:], kept))
+            return attn, caches[:lo] + rows + caches[lo + n_rows:]
         from deepspeed_tpu.models import kda
 
         states, tails = caches[n_rows:]
@@ -1445,19 +1652,22 @@ class LlamaModel:
         run as a loop, 42.5 ms unrolled; PERF.md, PR 33)."""
         names = self._cache_names()
         caches, routed = tuple(cache[n] for n in names), None
-        for xs, experts, first, view, pattern in self._stacks(params):
+        for xs, experts, first, view, pattern in self._stacks(
+                params, by_index=True):
             # (first layer, layers) of each run of one kind in a period
             sizes = [len(list(same)) for _, same in itertools.groupby(pattern)]
             runs = list(zip(itertools.accumulate([0] + sizes), sizes))
 
             # the stack's first layer in each kind of cache array: the layers
-            # before it that keep rows a position, those that keep a state
-            kda_before = self.config.kinds[:first].count("kda")
-            before = {kind: kda_before if kind == "kda"
-                      else first - kda_before for kind in pattern}
+            # before it that keep rows a position, those that keep a state,
+            # those that keep a ring
+            group = self._cache_group
+            before = {kind: sum(group(k) == group(kind)
+                                for k in self.config.kinds[:first])
+                      for kind in pattern}
 
             def layer(x, caches, per, n, j, i=0):
-                blk = view(per, j, i)
+                blk = view(per, j, i, n)
                 at, mine = self._layer_at(pattern, n, j, i)
                 attn, caches = self._mix_cached(
                     x, blk, cos_sin, caches, before[pattern[j]] + mine, pos,
@@ -1504,8 +1714,9 @@ class LlamaModel:
         B, T = input_ids.shape
         x = self._embed(params, input_ids)
         masked = {"block": c.block_length} if c.block_length else {}
-        attention = lambda q, k, v, window=None: local_causal_attention(
-            q, k, v, c.use_flash_attention, window=window, **masked)
+        attention = lambda q, k, v, window=None, **sink: \
+            local_causal_attention(q, k, v, c.use_flash_attention,
+                                   window=window, **masked, **sink)
         x, out, routed = self._run_cached(
             params, x, cache, self._rope(jnp.arange(T)), 0, attention)
         with scope("head"):

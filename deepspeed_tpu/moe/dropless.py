@@ -28,11 +28,12 @@ padding — so nothing is dropped and no slot is wasted:
   grouped matmul (``ops/pallas/grouped_matmul.py``) reading the stacked
   ``(L, E, ...)`` leaves in place; otherwise — the CPU, a multi-device mesh,
   training — ``jax.lax.ragged_dot`` over the sorted rows, which is also the
-  reference the kernel is tested against; there a share moves only the rows
-  of the pairs it holds, in a buffer of ``share_capacity`` rows, and what a
-  call holds beyond that goes through the same code in further chunks of
-  that size. The path is chosen by what the code observes, never by an
-  option and never by a failure;
+  reference the kernel is tested against. On either path a share moves only
+  the rows of the pairs it holds, in a buffer of ``share_capacity`` rows
+  (the kernel's: wherever a call holds more pairs than that, a prefill, and
+  a float32 row is at most ``_SCATTER_ROW_BYTES``), and what a call holds
+  beyond that goes through the same code in further chunks of that size. The path is chosen by what the code observes, never
+  by an option and never by a failure;
 * ``load_balancing_loss``: the Switch / Hugging Face auxiliary loss from
   per-layer sums, so a scanned trunk can carry them.
 """
@@ -122,6 +123,12 @@ def _layer_of(w, layer):
 
 # rows: a share's compact buffer comes in whole tiles of the products' rows
 _ROW_TILE = 128
+# the widest float32 row the kernel path's compact form scatter-adds: measured
+# on a v5e at 2,048 and 4,096 columns (sdar gen132.c1 unmoved, solar doc32k.c1
+# TTFT -7%, mimo doc24k.c1 -18%); at 7,680 the same form LOST (openpangu
+# doc8k.c1: TTFT +19%, ~9 ms a routed layer at 4,096 tokens; PERF.md section
+# 6, PR 49), so wider rows keep the full-size buffers until that is profiled
+_SCATTER_ROW_BYTES = 16 * 1024
 
 
 def share_capacity(pairs: int, held: int, n_experts: int) -> int:
@@ -169,6 +176,14 @@ def _routed_mlp(x, weights, experts, gate_w, up_w, down_w, layer, first,
     if layer is not None and _use_kernel(x, gate_w):
         from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
 
+        capacity = T * k if first is None else share_capacity(
+            T * k, E, n_experts or E)
+        # a prefill: the held pairs are a part of it
+        if capacity < T * k and D * 4 <= _SCATTER_ROW_BYTES:
+            sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+            return _share_kernel_mlp(
+                x, weights, jnp.argsort(flat, stable=True), sizes,
+                (gate_w, up_w, down_w), layer, capacity).astype(x.dtype), sizes
         tm = gmm.row_tile(T * k)
         sizes, tile_group, n_active, src, pos = gmm.group_layout(flat, E, tm)
         call = lambda rows, w, **kw: gmm.grouped_matmul(
@@ -218,6 +233,41 @@ def _share_mlp(x, weights, order, sizes, leaves, capacity):
     chunks = -(-order.shape[0] // capacity)
     order = jnp.pad(order, (0, chunks * capacity - order.shape[0]))
     return _chunks_mlp(capacity, x, weights, order, sizes, *leaves)
+
+
+def _share_kernel_mlp(x, weights, order, sizes, leaves, layer, capacity):
+    """``_share_mlp`` for the grouped-matmul kernel (a served prefill on one
+    TPU device): the pairs sorted by held expert go through the kernel a
+    chunk of ``capacity`` rows at a time, as many chunks as hold a grouped
+    row (none where the share holds no pair of the call: no kernel runs), so
+    the row buffers around the two calls take ``capacity`` rows and not T*k
+    (at 24,576 tokens x 8 the gather of every pair's row and the gather back
+    were 1.6 GB each a layer). -> (T, D) float32."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul as gmm
+
+    gate_w, up_w, down_w = leaves
+    k, E = weights.shape[1], sizes.shape[0]
+    tm = gmm.row_tile(capacity)
+    chunks = -(-order.shape[0] // capacity)
+    order = jnp.pad(order, (0, chunks * capacity - order.shape[0]))
+
+    def step(i, out):
+        pairs, part = _chunk(order, sizes, capacity, i)
+        # the chunk's rows are sorted by group; past the groups: no group
+        group = jnp.searchsorted(jnp.cumsum(part), jnp.arange(
+            capacity, dtype=jnp.int32), side="right").astype(jnp.int32)
+        _, tile_group, n_active, src, pos = gmm.group_layout(group, E, tm)
+        call = lambda rows, w, **kw: gmm.grouped_matmul(
+            rows, w, layer, tile_group, n_active, tm=tm, **kw)
+        token = pairs // k
+        h = call(x[token[src]], (gate_w, up_w), swiglu=True)
+        # whatever row a pair of no group was handed: zeros
+        y = jnp.where((group < E)[:, None], call(h, down_w)[pos], 0)
+        return out.at[token].add(
+            y.astype(jnp.float32) * weights.reshape(-1)[pairs][:, None])
+
+    return jax.lax.fori_loop(0, -(-jnp.sum(sizes) // capacity), step,
+                             jnp.zeros(x.shape, jnp.float32))
 
 
 def _chunk(order, sizes, capacity, i):

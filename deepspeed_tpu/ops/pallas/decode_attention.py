@@ -43,7 +43,19 @@ the K/V of the positions attended, once, and nothing else:
   row iota and the plan: which rows of the matrix are those positions'),
   every block between the two limits is an edge, and the k-block index is
   clamped by the later one. A call without it traces none of this (the
-  standing cells' jaxprs are pinned: tests/unit/test_decode_attention.py).
+  standing cells' jaxprs are pinned: tests/unit/test_decode_attention.py);
+* K rows and V rows may differ in width (``v_dim``: q.k at 192 columns a
+  head, v at 128): the block-diagonal query is over the K row's lanes, the
+  accumulator and the output over the V row's, each head's block in its own
+  columns of each;
+* a learned SINK logit a head (``sink``: it takes mass and carries no
+  value) is the INITIAL state of the online softmax, ``m = b_h, l = 1, acc
+  = 0``, where a call without one starts from ``m = -inf, l = 0``: one more
+  operand, the sinks by row of the query matrix, and no work a block;
+* the cache may be a RING (a window layer's: ``window`` slots, written at
+  ``pos % window``, K rotated before it is written so slot order means
+  nothing to the softmax): nothing here changes, the caller hands ``pos``
+  as the last valid slot, ``min(pos, window - 1)``.
 
 ``latent_decode_attention`` (``latent_decode_attn``) is the same stream for
 latent attention (MLA) in its ABSORBED form: the cache holds one row a
@@ -131,16 +143,18 @@ def query_plan(n_kv: int, per_group: int):
 def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
                    l_sc, *, block_k: int, num_k: int, rep: int, kvp: int,
                    head_dim: int, group_major: bool = False,
-                   early_queries: int = 0):
+                   early_queries: int = 0, v_dim=None, sink_ref=None):
     """``rep`` units of ``kvp`` rows (``query_plan``). Row-major: unit r is
     row r of ``q_ref`` spread over the KV heads' rows, each in its own
     columns. Group-major: unit g is ALL of ``q_ref``'s rows masked to KV
     head g's columns. ``early_queries``: the first that many queries of
-    every KV group (whole positions) see slots through ``sc_ref[2]`` only."""
+    every KV group (whole positions) see slots through ``sc_ref[2]`` only.
+    ``v_dim``: a head's columns of the V rows and of the output (None:
+    ``head_dim``, the K rows'). ``sink_ref`` (rows, 128): each row's sink
+    logit, lane-broadcast: the softmax's initial state."""
     j = pl.program_id(1)
     pos = sc_ref[0]
     boundary = pos // block_k               # last block with valid entries
-    width = qb_sc.shape[1]
     # the first block that is an edge for SOME row
     first_edge = sc_ref[2] // block_k if early_queries else boundary
 
@@ -156,12 +170,17 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
             early = row < early_queries * kvp
         return jnp.where(early, sc_ref[2], pos)
 
-    def head_columns(g=None):
+    def head_columns(g=None, of=qb_sc, dim=head_dim):
         # (kvp, W): True where column c belongs to KV head g (None: the row)
+        width = of.shape[1]
         if g is None:
             g = jax.lax.broadcasted_iota(jnp.int32, (kvp, width), 0)
         c = jax.lax.broadcasted_iota(jnp.int32, (kvp, width), 1)
-        return (c >= g * head_dim) & (c < (g + 1) * head_dim)
+        return (c >= g * dim) & (c < (g + 1) * dim)
+
+    # the output's columns: the V rows', a head ``v_dim`` of them
+    out_columns = head_columns if v_dim is None else functools.partial(
+        head_columns, of=acc_sc, dim=v_dim)
 
     @pl.when(j == 0)
     def _init():
@@ -174,11 +193,15 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
             else:
                 unit = jnp.where(own, jnp.broadcast_to(
                     q_ref[0, r:r + 1, :].astype(jnp.float32),
-                    (kvp, width)), 0.0)
+                    (kvp, qb_sc.shape[1])), 0.0)
             qb_sc[r * kvp:(r + 1) * kvp, :] = unit.astype(qb_sc.dtype)
         acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+        if sink_ref is None:
+            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[:] = jnp.zeros_like(l_sc)
+        else:                   # the sink: mass exp(b_h), no value
+            m_sc[:] = sink_ref[:]
+            l_sc[:] = jnp.ones_like(l_sc)
 
     def block_update(edge: bool):
         _softmax_block(qb_sc[:], k_ref[0, 0], v_ref[0, 0], j, pos, block_k,
@@ -200,18 +223,30 @@ def _decode_kernel(sc_ref, q_ref, k_ref, v_ref, o_ref, qb_sc, acc_sc, m_sc,
         out = acc_sc[:] / jnp.where(l == 0.0, 1.0, l)
         if group_major:
             o_ref[0] = sum(
-                jnp.where(head_columns(g), out[g * kvp:(g + 1) * kvp], 0.0)
+                jnp.where(out_columns(g), out[g * kvp:(g + 1) * kvp], 0.0)
                 for g in range(rep)).astype(o_ref.dtype)
             return
-        own = head_columns()
+        own = out_columns()
         for r in range(rep):
             mine = jnp.where(own, out[r * kvp:(r + 1) * kvp], 0.0)
             o_ref[0, r:r + 1, :] = jnp.sum(
                 mine, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
+def _sink_rows(sink, n_kv: int, positions: int, group_major: bool, kvp: int):
+    """A head's sink logit (H,) by ROW of the kernel's block-diagonal query
+    matrix, (rows, 128) float32 with every lane of a row equal; 0 in the
+    rows no query has (they hold zeros and are dropped)."""
+    per_group = jnp.tile(sink.astype(jnp.float32).reshape(n_kv, -1),
+                         (1, positions))          # (KV, positions x heads)
+    rows = jnp.pad(per_group, ((0, 0), (0, kvp - per_group.shape[1]))) \
+        if group_major else jnp.pad(per_group.T, ((0, 0), (0, kvp - n_kv)))
+    return jnp.broadcast_to(rows.reshape(-1, 1), (rows.size, LANES))
+
+
 def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
-                     block_k: int = DEFAULT_BLOCK_K, early=None):
+                     block_k: int = DEFAULT_BLOCK_K, early=None,
+                     v_dim=None, sink=None):
     """q: (B, H, Dh) — the new token's queries — or (B, Lb, H, Dh): ``Lb``
     positions' queries, each of which sees every valid slot (a
     block-diffusion step: its block lies in the last ``Lb`` of them);
@@ -222,7 +257,10 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
     ``early``: ``(n, last)``, a last valid slot PER POSITION in two values:
     the first ``n`` (static, 0 < n < Lb) of the ``Lb`` positions see slots
     ``0 .. last`` (traced, 0 <= last <= pos), the others ``0 .. pos``.
-    Returns q's shape.
+    ``v_dim``: a head's columns in ``v_cache``'s rows where they are not
+    ``Dh`` (the caches then differ in width). ``sink``: (H,) a head's sink
+    logit, beside the SCALED scores in the softmax's sum, with no value.
+    Returns q's shape, ``v_dim`` columns a head.
 
     ``H % n_kv == 0`` (grouped-query attention; H == n_kv is plain MHA).
     """
@@ -233,12 +271,14 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
     if early is not None and not 0 < early[0] < Lb:
         raise ValueError(f"early {early[0]}: some, not all, of the {Lb} "
                          "query positions")
-    S, W = k_cache.shape[2], k_cache.shape[3]
+    S, W, Wv = k_cache.shape[2], k_cache.shape[3], v_cache.shape[3]
     if H % n_kv:
         raise ValueError(f"query heads {H} not divisible by KV heads {n_kv}")
-    C = n_kv * Dh
-    if W < C:
-        raise ValueError(f"cache rows hold {W} values, {n_kv} x {Dh} asked")
+    Dv = Dh if v_dim is None else int(v_dim)
+    C, Cv = n_kv * Dh, n_kv * Dv
+    if W < C or Wv < Cv:
+        raise ValueError(f"cache rows hold {W} / {Wv} values, {n_kv} x "
+                         f"{Dh} / {Dv} asked")
     per_group = Lb * (H // n_kv)
     group_major, kvp, rep = query_plan(n_kv, per_group)
     # rows of the kernel's query input: a unit's rows group-major (padded to
@@ -267,38 +307,51 @@ def decode_attention(q, k_cache, v_cache, layer, pos, *, n_kv: int,
     qmap = lambda b, j, sc: (b, 0, 0)
     item = k_cache.dtype.itemsize
 
+    kernel = functools.partial(
+        _decode_kernel, block_k=bk, num_k=nk, rep=rep, kvp=kvp, head_dim=Dh,
+        group_major=group_major,
+        early_queries=early[0] * (H // n_kv) if early else 0,
+        **({} if v_dim is None else {"v_dim": Dv}))
+    operands, sink_specs = (scalars, qf, k_cache, v_cache), []
+    if sink is not None:
+        # one more input, handed to the body by name: it sits between the
+        # inputs and the output in the call's order
+        body = kernel
+        kernel = lambda sc, q, k, v, s, o, *scratch: body(
+            sc, q, k, v, o, *scratch, sink_ref=s)
+        operands += (_sink_rows(sink, n_kv, Lb, group_major, kvp),)
+        sink_specs = [pl.BlockSpec((rep * kvp, LANES),
+                                   lambda b, j, sc: (0, 0))]
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_k=bk, num_k=nk, rep=rep,
-                          kvp=kvp, head_dim=Dh, group_major=group_major,
-                          early_queries=early[0] * (H // n_kv) if early
-                          else 0),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, nk),
             in_specs=[
                 pl.BlockSpec((1, q_rows, W), qmap),
                 pl.BlockSpec((1, 1, bk, W), kmap),
-                pl.BlockSpec((1, 1, bk, W), kmap),
-            ],
-            out_specs=pl.BlockSpec((1, q_rows, W), qmap),
+                pl.BlockSpec((1, 1, bk, Wv), kmap),
+            ] + sink_specs,
+            out_specs=pl.BlockSpec((1, q_rows, Wv), qmap),
             scratch_shapes=[pltpu.VMEM((rep * kvp, W), k_cache.dtype),
-                            pltpu.VMEM((rep * kvp, W), jnp.float32),
+                            pltpu.VMEM((rep * kvp, Wv), jnp.float32),
                             pltpu.VMEM((rep * kvp, LANES), jnp.float32),
                             pltpu.VMEM((rep * kvp, LANES), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, q_rows, W), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, q_rows, Wv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         # what a full cache costs; the scheduler has no better number for a
         # length that is data
         cost_estimate=pl.CostEstimate(
-            flops=int(4 * B * rep * kvp * S * W),
-            bytes_accessed=int(2 * B * S * W * item),
+            flops=int(2 * B * rep * kvp * S * (W + Wv)),
+            bytes_accessed=int(B * S * (W + Wv) * item),
             transcendentals=int(B * rep * kvp * S)),
         name="decode_attn",
-    )(scalars, qf, k_cache, v_cache)
-    out = out[:, :per_group, :C].reshape(B, Lb, H // n_kv, n_kv, Dh).transpose(
-        0, 1, 3, 2, 4).reshape(B, Lb, H, Dh)
+    )(*operands)
+    out = out[:, :per_group, :Cv].reshape(
+        B, Lb, H // n_kv, n_kv, Dv).transpose(0, 1, 3, 2, 4).reshape(
+            B, Lb, H, Dv)
     return out[:, 0] if one else out
 
 

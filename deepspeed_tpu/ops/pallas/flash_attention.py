@@ -74,7 +74,13 @@ step takes). ONE forward kernel for every length, its shapes from
   (``_fwd_window_kernel``) runs one softmax update a sub-block, scratch to
   scratch, not yet the wide walk above. Its calls are ``flash_fwd_win`` /
   ``flash_bwd_dkv_win``. Without a window every plan, list and traced
-  program is what it was.
+  program is what it was. A window NARROWER than half a sub-block (128
+  keys under the default 512) takes a smaller sub-block
+  (``_window_sub_block``): at 512 a q block runs two 512 x 512 sub-blocks
+  for a band of 128 columns a row, eight times the band. A learned SINK
+  logit a head (``flash_attention(sink=)``, with a window, forward only: it
+  takes mass and carries no value) is the online softmax's INITIAL state,
+  ``m = b_h, l = 1, acc = 0``: one small operand and no work a sub-block.
 * **A BLOCK-causal mask** (``flash_attention(block=)``, forward only: the
   prefill of a model that generates by diffusion over blocks): row i sees
   the keys j with ``j // block <= i // block``, blocks counted from
@@ -362,6 +368,19 @@ _SPAN_BYTES = 4 << 20
 _FWD_VMEM_BYTES = 32 << 20
 
 
+# a window's sub-block is the smallest halving of the caller's block that
+# still holds this many windows (and whole 128s): the band of a q block is
+# ``sub + window`` columns wide and the kernel runs whole sub-blocks
+_WINDOWS_A_SUB_BLOCK = 2
+
+
+def _window_sub_block(t: int, sub: int, window: int) -> int:
+    while sub % 2 == 0 and sub // 2 >= max(
+            _LANES, _WINDOWS_A_SUB_BLOCK * window) and t % (sub // 2) == 0:
+        sub //= 2
+    return sub
+
+
 def flash_forward_plan(t: int, d: int, dv: int, dtype,
                        block_q: int = DEFAULT_BLOCK_Q,
                        block_k: int = DEFAULT_BLOCK_K,
@@ -384,6 +403,8 @@ def flash_forward_plan(t: int, d: int, dv: int, dtype,
     OR the window's trailing edge crosses it."""
     t = _padded_len(t, min(block_q, block_k))
     sub = _pick_block(t, min(block_q, block_k))
+    if window is not None:
+        sub = _window_sub_block(t, sub, window)
     rows = t // sub
     widest = max(1, min(_SPAN_SUB_BLOCKS, _SPAN_BYTES // (
         sub * (d + dv) * jnp.dtype(dtype).itemsize)))
@@ -481,7 +502,9 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
-                    block=None):
+                    block=None, sink=None):
+    """``sink`` (with a window): (B*H, 1, 128) float32, each row's sink
+    logit lane-broadcast."""
     bh, t, d = q.shape
     dv = v.shape[2]
     plan = flash_forward_plan(t, d, dv, q.dtype, block_q, block_k, window)
@@ -497,6 +520,16 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
             _fwd_window_kernel, scale=scale, sub=sub, n_sub=n_sub,
             window=window), "flash_fwd_win"
         pairs = plan.sub_blocks_run * sub * sub
+    operands, sink_specs = (q, k, v), []
+    if sink is not None:
+        # one more input, handed to the body by name: it sits between the
+        # inputs and the outputs in the call's order
+        body = kernel
+        kernel = lambda qa, sa, q, k, v, s, *rest: body(
+            qa, sa, q, k, v, *rest, sink_ref=s)
+        operands += (sink,)
+        sink_specs = [pl.BlockSpec((1, 1, _LANES),
+                                   lambda b, f, qa, sa: (b, 0, 0))]
     return pl.pallas_call(
         kernel,
         name=name,
@@ -509,7 +542,7 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
                              lambda b, f, qa, sa: (b, sa[f], 0)),
                 pl.BlockSpec((1, plan.span, dv),
                              lambda b, f, qa, sa: (b, sa[f], 0)),
-            ],
+            ] + sink_specs,
             out_specs=(
                 pl.BlockSpec((1, sub, dv), lambda b, f, qa, sa: (b, qa[f], 0)),
                 pl.BlockSpec((1, 1, sub), lambda b, f, qa, sa: (b, 0, qa[f])),
@@ -527,12 +560,12 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
             flops=int(2 * bh * pairs * (d + dv)),
             bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
             transcendentals=int(bh * pairs)),
-    )(jnp.asarray(qi_arr), jnp.asarray(si_arr), q, k, v)
+    )(jnp.asarray(qi_arr), jnp.asarray(si_arr), *operands)
 
 
 def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
                        acc_sc, m_sc, l_sc, *, scale: float, sub: int,
-                       n_sub: int, window: int):
+                       n_sub: int, window: int, sink_ref=None):
     """The causal forward under a window: a q block against one of the spans
     that hold a key it sees. Of the span's sub-blocks those from the first
     with a key inside the window up to the diagonal's are run, one softmax
@@ -541,7 +574,9 @@ def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
     pay the mask, the ones between them none. A row may see nothing of the
     trailing sub-block: what its update leaves in the running sum is wiped
     by the next update's rescale (``exp(NEG_INF - m)`` is 0), and the
-    diagonal's sub-block always holds the row's own key."""
+    diagonal's sub-block always holds the row's own key. ``sink_ref`` (1, 1,
+    128): the head's sink logit, the softmax's initial state (mass ``exp(b)``
+    and no value; the log-sum-exp written counts it)."""
     f = pl.program_id(1)
     qi, si = qi_arr[f], si_arr[f]
     q = q_ref[0]
@@ -550,8 +585,12 @@ def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(si == first // n_sub)
     def _init():
         acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
+        if sink_ref is None:
+            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[:] = jnp.zeros_like(l_sc)
+        else:
+            m_sc[:] = jnp.broadcast_to(sink_ref[0], m_sc.shape)
+            l_sc[:] = jnp.ones_like(l_sc)
 
     def update(j, g, masked):
         mask_rc = _block_iotas(sub, sub, qi, g) if masked else None
@@ -593,10 +632,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc,
 
 
 def _flash_forward(q, k, v, scale, causal, block_q, block_k, window=None,
-                   block=None):
+                   block=None, sink=None):
     if causal:              # self-attention: ``flash_attention`` saw to that
         return _causal_forward(q, k, v, scale, block_q, block_k, window,
-                               block)
+                               block, sink)
     bh, t_q, d = q.shape
     t_k, dv = k.shape[1], v.shape[2]
     bq = _pick_block(t_q, block_q)
@@ -1021,7 +1060,8 @@ def block_mask_supports(block) -> bool:
 
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-                    window: Optional[int] = None, block: Optional[int] = None):
+                    window: Optional[int] = None, block: Optional[int] = None,
+                    sink=None):
     """q, k: (B, T, H, D), v: (B, T, H, Dv) → (B, T, H, Dv); Dv <= D, the
     softmax scale is D's. Differentiable; bf16-friendly.
 
@@ -1036,6 +1076,11 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     block-causal mask, row i sees the keys j with ``j // block <= i //
     block`` (``block_mask_supports``); 1 or None: the causal mask.
 
+    ``sink`` ((H,), with a window, FORWARD only): a head's learned sink
+    logit, which stands beside the row's scaled scores in the softmax's sum
+    and carries no value: ``p_ij = exp(s_ij) / (exp(b_h) + sum_j' exp(s_ij'))``.
+    The call is the windowed kernel whatever the length.
+
     Causal self-attention at a length the kernels cannot tile is padded at
     the END of the sequence and the pad rows sliced off the output: under
     the causal mask a pad key is visible only to pad queries, so no kept
@@ -1047,7 +1092,10 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
         if not causal or int(window) < 1:
             raise ValueError(f"flash_attention: window={window} is a causal "
                              "window of at least the row's own key")
-        window = int(window) if window < t else None
+        window = int(window) if window < t or sink is not None else None
+    if sink is not None and window is None:
+        raise ValueError("flash_attention: a sink is a window layer's (the "
+                         "windowed forward carries it)")
     if block is not None and int(block) > 1:
         if not causal or window is not None or t % int(block) \
                 or not block_mask_supports(block):
@@ -1076,6 +1124,14 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     # (T, D) instead of a VPU pass over every (T², causal-half) score element
     # in the forward and in both backward kernels; autodiff scales dq back
     q = q * jnp.asarray(scale, q.dtype)
+    if sink is not None:        # the forward alone: no rule for a gradient
+        b, h = q.shape[0], q.shape[2]
+        rows = jnp.broadcast_to(jnp.tile(sink.astype(jnp.float32), b)[
+            :, None, None], (b * h, 1, _LANES))
+        o, _ = _flash_forward(_to_bhtd(q), _to_bhtd(k), _to_bhtd(v), 1.0,
+                              True, int(block_q), int(block_k), window,
+                              sink=rows)
+        return _to_bthd(o, b)[:, :t]
     # (a pad key lies in a LATER block than every kept row: under the block
     # mask too it is visible to pad queries alone)
     o = _flash_bthd(q, k, v, (1.0, bool(causal), int(block_q), int(block_k),

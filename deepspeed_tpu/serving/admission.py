@@ -86,6 +86,11 @@ class Request:
     # state): ``models/common.py::cache_footprint``
     cache_position_bytes: int = 0
     cache_state_bytes: int = 0
+    # ... and what its window layers' rings hold, bytes a sequence whatever
+    # its length, with a ring's slots (0: the model keeps none):
+    # ``models/common.py::cache_ring``
+    cache_window_bytes: int = 0
+    cache_ring_slots: int = 0
     finished_at: Optional[float] = None
     ttft_s: Optional[float] = None    # first_tokens_at - submitted_at
     _done: threading.Event = dataclasses.field(default_factory=threading.Event,
@@ -139,6 +144,21 @@ def kv_bytes_per_request(module, max_total_len: int) -> int:
     return total
 
 
+def kv_bytes_by_kind(module, max_total_len: int) -> Dict[str, int]:
+    """What of ``kv_bytes_per_request`` grows with the context and what does
+    not: ``per_position`` (the rows a position of the layers that keep the
+    whole context), ``per_sequence`` (a KDA layer's state, a window layer's
+    ring: a sequence holds them at any length)."""
+    import jax
+
+    from deepspeed_tpu.models.common import cache_footprint, cache_ring
+
+    shapes = jax.eval_shape(lambda: module.init_cache(1, int(max_total_len)))
+    per_position, state = cache_footprint(shapes)
+    return {"per_position": int(per_position),
+            "per_sequence": int(state + cache_ring(shapes)[0])}
+
+
 def resolve_capacity(engine, cfg) -> Tuple[int, Dict[str, Any]]:
     """The admission bound (queued + in-flight requests) and how it was
     derived. An explicit ``max_queue_depth`` wins; otherwise the bound is
@@ -168,6 +188,7 @@ def resolve_capacity(engine, cfg) -> Tuple[int, Dict[str, Any]]:
     detail.update({"source": f"kv_budget({src})", "capacity": cap,
                    "hbm_bytes": hbm, "params_bytes": params_bytes,
                    "kv_bytes_per_request": per_req,
+                   "kv_bytes": kv_bytes_by_kind(engine.module, max_len),
                    "kv_budget_fraction": float(cfg.kv_budget_fraction),
                    "max_total_len": max_len})
     logger.info(f"serving admission: capacity={cap} requests "

@@ -621,6 +621,16 @@ class ServingFrontEnd:
                     * req.cache_position_bytes,
                     state_bytes=int(req.prompt.shape[0])
                     * req.cache_state_bytes)
+                if req.cache_ring_slots:
+                    # window layers that keep a ring: its bytes a sequence,
+                    # whatever the length (``cache_bytes`` counts the FULL
+                    # layers' rows alone), and the decode steps that
+                    # overwrote a slot still inside the window
+                    wraps = self._ring_wraps(req, positions)
+                    self._count("ring_wraps", n=wraps)
+                    span.args.update(
+                        window_bytes=int(req.prompt.shape[0])
+                        * req.cache_window_bytes, ring_wraps=wraps)
 
     def _cache_positions(self, req: Request) -> int:
         """Positions of a sequence the request's programs have written in
@@ -633,6 +643,12 @@ class ServingFrontEnd:
         n, prompt = self._step_tokens, int(req.prompt.shape[1])
         chunk = req.decode_ticks * int(self.cfg.decode_tick_tokens)
         return prompt + chunk if n == 1 else prompt - prompt % n + n + chunk
+
+    def _ring_wraps(self, req: Request, positions: int) -> int:
+        """Decode steps of the request that wrote a ring slot which held a
+        position: those at positions past the ring's length."""
+        prompt = int(req.prompt.shape[1])
+        return max(0, positions - max(prompt, req.cache_ring_slots))
 
     def _positions_run(self, req: Request) -> int:
         """Positions the request's programs have put through the layers:
@@ -648,7 +664,7 @@ class ServingFrontEnd:
     def _serve(self, req: Request, tracer) -> None:
         import jax
 
-        from deepspeed_tpu.models.common import cache_footprint
+        from deepspeed_tpu.models.common import cache_footprint, cache_ring
 
         req.status = "running"
         reg = self._reg()
@@ -675,6 +691,7 @@ class ServingFrontEnd:
             # names of its leaves), not by the number of dimensions
             req.cache_position_bytes, req.cache_state_bytes = \
                 cache_footprint(cache)
+            req.cache_window_bytes, req.cache_ring_slots = cache_ring(cache)
             # prefill chose the first token: it leaves now, alone (of a
             # block step the first block's new tokens)
             finished = self._deliver(req, first[0] if first else tok, done,

@@ -1647,3 +1647,79 @@ print(dict(pool))
 """)
     assert r.returncode == 0, r.stderr[-2000:]
     assert r.stdout.strip() == "{'localhost': []}"
+
+
+# --- MiMo-V2-Flash: one chip's share of 32, window layers that keep a ring
+@pytest.fixture(scope="module")
+def mimo(v5e):
+    """(mesh, model, abstract bf16 params, abstract cache, the two serving
+    programs) of the benchmark's configuration on ONE chip: 13 layers, 8 of
+    256 experts, a 28,672-slot cache over the three full layers and a ring
+    of 128 slots a window layer."""
+    from benchmark import manifest as mf
+    from benchmark.families import mimo_v2_flash as family
+    from deepspeed_tpu.inference.engine import build_serving_programs
+
+    mesh = _mesh(v5e)
+    model = family.build_model(mf.load_json(
+        mf.BENCH_DIR / "configs" / "mimo-v2-flash.json"), "serve")
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh), shapes)
+    cache = jax.tree.map(lambda s: _abstract(s.shape, s.dtype, mesh),
+                         jax.eval_shape(lambda: model.init_cache(1, 28672)))
+    return (mesh, model, params, cache) + build_serving_programs(
+        model, 28672, 16, False, 1.0, 0, 1.0, None)
+
+
+def test_mimo_decode_chunk_for_v5e_reads_a_ring_and_a_context(mimo):
+    """A chunk of 16 steps holds FOUR ``decode_attn`` calls (the dense full
+    layer, the period's run of four window layers as one loop, its full
+    layer, its last window layer): the full layers' at 4 KV heads over rows
+    of 768 / 512 lanes (16 query heads a group: group-major, 16 rows a
+    unit), the rings' at 8 over 1,536 / 1,024 with the sinks as a fifth
+    operand; a pair of thin grouped matmuls under the share's conditional in
+    each routed body; it fits with room for the 24k prefill's successor."""
+    mesh, model, params, cache, _, chunk = mimo
+    with mesh:
+        compiled = jax.jit(chunk).lower(
+            params, *_chunk_carry(cache, mesh)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%decode_attn[\w.]* = [^\n]*tpu_custom_call[^\n]*",
+                       text)
+    assert len(calls) == 4, calls
+    full = [c for c in calls if "bf16[3,1,28672,768]" in c]
+    ring = [c for c in calls if "bf16[10,1,128,1536]" in c]
+    assert len(full) == 2 and len(ring) == 2
+    assert all("bf16[3,1,28672,512]" in c and "bf16[1,16,512]" in c
+               for c in full)
+    assert all("bf16[10,1,128,1024]" in c and "f32[128,128]" in c
+               for c in ring)
+    for kernel in ("moe_gmm_swiglu_thin", "moe_gmm_thin"):
+        assert len(re.findall(rf"%{kernel}[\w.]* = [^\n]*tpu_custom_call",
+                              text)) == 3, kernel
+    args, total = _footprint(compiled)
+    assert args < 2 * model.config.num_params() + 0.5 * GIB
+    assert total < 9 * GIB, total / GIB
+
+
+def test_mimo_prefill_kernels_lower_at_the_cells_widths(mimo):
+    """The two flash forwards of a 24,576-token prefill at 64 heads: the
+    causal one at 192 / 128 columns, and the windowed one with a sink at a
+    window of 128, planned in sub-blocks of 256 (one Mosaic call each, no
+    (T, T) array); the whole 24k prefill compiles in ~34 s here and is the
+    benchmark's to run."""
+    mesh, model, _, _, _, _ = mimo
+    with mesh:
+        q = _abstract((1, 24576, 64, 192), jnp.bfloat16, mesh)
+        v = _abstract((1, 24576, 64, 128), jnp.bfloat16, mesh)
+        sink = _abstract((64,), jnp.float32, mesh)
+        win = jax.jit(lambda q, k, v, s: fa.flash_attention(
+            q, k, v, window=128, sink=s)).lower(q, q, v, sink).compile()
+        full = jax.jit(fa.flash_attention).lower(q, q, v).compile()
+    for compiled, name in ((win, "flash_fwd_win"), (full, "flash_fwd")):
+        text = compiled.as_text()
+        assert len(re.findall(
+            rf"%[\w.]*{name}[\w.]* = [^\n]*tpu_custom_call", text)) == 1
+        assert not re.search(r"\[[\d,]*24576,24576\]", text)
+    assert fa.flash_forward_plan(24576, 192, 128, jnp.bfloat16,
+                                 window=128).sub_block == 256

@@ -443,29 +443,36 @@ def test_windowed_kernels_match_the_masked_reference(T, window, block):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
                                    rtol=0)
     plan = fa.flash_forward_plan(T, 32, 32, q.dtype, block, block, window)
-    sub, rows = plan.sub_block, -(-T // plan.sub_block)
-    # a sub-block pair is run iff some (row, col) of it is in the band, and
-    # masked iff not all of them are
-    band = lambda i, j, test: test(
-        0 <= r - c < window for r in (i * sub, i * sub + sub - 1)
-        for c in (j * sub, j * sub + sub - 1))
-    inside = lambda i, j: 0 <= i * sub - (j * sub + sub - 1) \
-        and i * sub + sub - 1 - j * sub < window
-    touched = [(i, j) for i in range(rows) for j in range(i + 1)
-               if i * sub - (j * sub + sub - 1) < window]
+
+    def band(sub):
+        """(the sub-block pairs of the band, how many of them are masked): a
+        pair is run iff some (row, col) of it is in the band, and masked iff
+        not all of them are."""
+        rows = -(-T // sub)
+        inside = lambda i, j: 0 <= i * sub - (j * sub + sub - 1) \
+            and i * sub + sub - 1 - j * sub < window
+        touched = [(i, j) for i in range(rows) for j in range(i + 1)
+                   if i * sub - (j * sub + sub - 1) < window]
+        return rows, touched, sum(not inside(i, j) for i, j in touched)
+
+    sub = plan.sub_block
+    rows, touched, masked = band(sub)
     assert plan.sub_blocks_run == len(touched)
-    assert plan.sub_blocks_masked == sum(not inside(i, j) for i, j in touched)
+    assert plan.sub_blocks_masked == masked
     n_sub = plan.span // sub
     qi, si = fa._causal_spans(rows, n_sub, sub, window)
     assert plan.grid_steps == len(qi)
     assert {(i, s) for i, s in zip(qi, si)} == \
         {(i, j // n_sub) for i, j in touched}
     # the backward's list: the band's pairs, each once, and its plan's counts
-    assert sorted((i, j) for j, i in
-                  zip(*fa._causal_pairs_colmajor(rows, sub, window))) == touched
+    # (at its OWN block: a window under half a sub-block narrows the
+    # forward's alone)
     back = fa.flash_backward_plan(T, 32, q.dtype, window, block, block)
-    assert (back.block, back.pairs_run, back.pairs_masked) == (
-        sub, len(touched), plan.sub_blocks_masked)
+    rows, touched, masked = band(back.block)
+    assert sorted((i, j) for j, i in zip(*fa._causal_pairs_colmajor(
+        rows, back.block, window))) == touched
+    assert (back.pairs_run, back.pairs_masked) == (len(touched), masked)
+    assert back.block == sub or window * fa._WINDOWS_A_SUB_BLOCK <= sub
 
 
 def test_the_cells_windowed_plan():

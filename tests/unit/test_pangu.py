@@ -225,6 +225,70 @@ def test_the_shares_kernel_path_matches_the_xla_form(as_tpu_program, T):
     np.testing.assert_array_equal(sizes, want_sizes)
 
 
+@pytest.mark.parametrize("held_choices", [4, 3, 0])
+def test_a_prefills_share_goes_through_the_kernel_in_chunks(as_tpu_program,
+                                                            held_choices):
+    """A call larger than ``share_capacity`` (64 tokens x 4 of a router 16
+    wide, 4 held: 128 rows of 256) sends only its held pairs' rows through
+    the kernel: every pair held = two chunks, three of four = a chunk and a
+    half, none = no trip at all (NaN weights are never read, exact zeros)."""
+    T, D, F, k = 64, 128, 128, 4
+    assert dropless.share_capacity(T * k, 4, 16) == 128
+    keys = jax.random.split(jax.random.PRNGKey(held_choices), 5)
+    x = jax.random.normal(keys[0], (T, D))
+    draw = lambda kk, *shape: jax.random.normal(kk, shape) * .1 \
+        if held_choices else jnp.full(shape, jnp.nan)
+    gate, up = (draw(kk, 2, 4, D, F) for kk in keys[1:3])
+    down = draw(keys[3], 2, 4, F, D)
+    # a token's first ``held_choices`` experts are held (8 .. 11), in an
+    # order of its own; the others are another chip's
+    held = 8 + jnp.argsort(jax.random.uniform(keys[4], (T, 4)))
+    experts = jnp.concatenate([held[:, :held_choices],
+                               jnp.arange(4 - held_choices)[None].repeat(T, 0)],
+                              axis=1).astype(jnp.int32)
+    weights = jax.random.uniform(keys[4], (T, k))
+    got, sizes = dropless.routed_mlp(x, weights, experts, gate, up, down,
+                                     layer=jnp.int32(1), first=8, n_experts=16)
+    assert int(sizes.sum()) == T * held_choices
+    if not held_choices:
+        np.testing.assert_array_equal(got, np.zeros((T, D), np.float32))
+        return
+    want, want_sizes = dropless.routed_mlp(
+        x, weights, experts, gate[1], up[1], down[1], first=8, n_experts=16)
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(got, dense_loop(
+        x, weights, experts, gate[1], up[1], down[1], first=8),
+        atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(sizes, want_sizes)
+
+
+def test_rows_wider_than_the_measured_scatter_keep_the_full_size_buffers(
+        as_tpu_program, monkeypatch):
+    """The compact form is taken where a float32 row is at most
+    ``_SCATTER_ROW_BYTES`` (what the chip runs of PR 49 showed to gain); one
+    lane tile wider and the call goes through the form it had, to the same
+    numbers."""
+    T, F, k = 40, 128, 4
+    D = dropless._SCATTER_ROW_BYTES // 4 + 128
+    monkeypatch.setattr(dropless, "_share_kernel_mlp", lambda *a: 1 / 0)
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(keys[0], (T, D))
+    gate, up = (jax.random.normal(kk, (1, 4, D, F)) * .02 for kk in keys[1:3])
+    down = jax.random.normal(keys[3], (1, 4, F, D)) * .1
+    experts = jnp.argsort(jax.random.uniform(keys[4], (T, 16)))[:, :k].astype(
+        jnp.int32)
+    weights = jax.random.uniform(keys[5], (T, k))
+    got, _ = dropless.routed_mlp(x, weights, experts, gate, up, down,
+                                 layer=jnp.int32(0), first=8, n_experts=16)
+    want, _ = dropless.routed_mlp(x, weights, experts, gate[0], up[0],
+                                  down[0], first=8, n_experts=16)
+    np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-3)
+    with pytest.raises(ZeroDivisionError):      # at 128 columns: compact
+        dropless.routed_mlp(x[:, :128], weights, experts, gate[:, :, :128],
+                            up[:, :, :128], down[..., :128],
+                            layer=jnp.int32(0), first=8, n_experts=16)
+
+
 def test_a_step_with_no_held_pair_is_exactly_zero_and_runs_no_kernel(
         as_tpu_program, monkeypatch):
     """``n_active`` 0: every tile skipped, in fact no expert kernel called

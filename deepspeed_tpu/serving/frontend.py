@@ -16,7 +16,9 @@ Every tick runs under the watchdog's ``run_with_deadline``, so a hung
 device step — or an injected chaos ``decode_step`` hang — surfaces as a
 clean per-request timeout instead of a wedged server, and the host checks
 the request deadline, the drain flag, and the elastic agent's preemption
-flag between ticks.
+flag between ticks. A request that cannot end early (no EOS id) has its
+next decode chunk dispatched BEHIND the one a tick waits for, so the host's
+work of a tick runs while the device computes (``_chunk_behind``).
 
 The invariant everything here serves: **an admitted request reaches
 exactly one terminal status** (completed / partial / shed / failed), and
@@ -112,6 +114,9 @@ class ServingFrontEnd:
 
         self._step_tokens = step_tokens(engine.module)
         self._warm: Dict[tuple, int] = {}    # tick key -> successful runs
+        # outputs of the decode chunk dispatched behind the last tick, the
+        # next tick's to wait for; written by the serving thread alone
+        self._ahead: Optional[tuple] = None
         self._service_ema: Optional[float] = None
         self.counts: Dict[str, float] = collections.defaultdict(float)
         self.exit_code = 0
@@ -491,15 +496,27 @@ class ServingFrontEnd:
         Raises WatchdogTimeout (tick cap / hung step) or
         _RequestDeadline (the request's own budget, drain cap).
 
-        The tick is one span (``prefill`` | ``decode``) from entry to
-        return, tiled by three children: ``tick_launch`` (entry until
-        ``fn()`` has returned in the deadline worker), ``tick_wait``
-        (``block_until_ready``) and ``tick_return`` (until this method
-        returns). The worker is a new thread per tick, so it only takes
-        the two inner stamps; the children are recorded from here."""
+        ``fn()`` dispatches the tick's program; where the tick before
+        dispatched this chunk behind its own (``self._ahead``), that is
+        what the tick waits for and ``fn`` is not called. Either way the
+        tick waits for ITS program's outputs alone, after dispatching the
+        chunk that follows where :meth:`_chunk_behind` allows one: the
+        first token leaves when the prefill returns, whatever is queued
+        behind it.
+
+        The tick is one span (``prefill`` | ``decode``; a decode tick says
+        whether its chunk was dispatched ``ahead``) from entry to return,
+        tiled by three children: ``tick_launch`` (entry until whatever
+        this call dispatches, its own program and the chunk behind it, has
+        been dispatched in the deadline worker), ``tick_wait``
+        (``block_until_ready`` of its own program) and ``tick_return``
+        (until this method returns). The worker is a new thread per tick,
+        so it only takes the two inner stamps; the children are recorded
+        from here."""
         import jax
 
         phase = str(warm_key[0])        # "prefill" | "decode"
+        ahead = self._ahead             # dropped by _serve if the tick dies
         tracer = _telemetry.get_tracer()
         stamps: List[float] = []        # worker: fn() returned, outputs ready
         # request-scoped span: with the admission_wait span this lets
@@ -514,6 +531,8 @@ class ServingFrontEnd:
                 context=int(req.prompt.shape[1]) + max(
                     len(req.tokens) - (self._step_tokens == 1), 0),
                 index=req.decode_ticks) as tick:
+            if phase == "decode":
+                tick.args["ahead"] = ahead is not None
             now = tick.t0
             remaining = req.deadline_at - now
             if self._draining and self._drain_deadline is not None:
@@ -539,19 +558,20 @@ class ServingFrontEnd:
                 if inj is not None and inj.targets("decode_step"):
                     inj.before("decode_step", req.id)
                 with self.engine.mesh:
-                    out = fn()
+                    out = fn() if ahead is None else ahead
+                    behind = self._chunk_behind(req, phase, out)
                     stamps.append(time.monotonic())
                     jax.block_until_ready(out)
                     stamps.append(time.monotonic())
-                return out
+                return out, behind
 
             try:
                 # a tick bound by the REQUEST's budget (budget < cap) that
                 # expires is a deadline over healthy compute, not a hang —
                 # it must not stamp a goodput watchdog_stall span
-                out = run_with_deadline(run, timeout=budget,
-                                        name=f"serve-tick[{req.id}]",
-                                        stall_span=budget >= cap)
+                out, self._ahead = run_with_deadline(
+                    run, timeout=budget, name=f"serve-tick[{req.id}]",
+                    stall_span=budget >= cap)
             except WatchdogTimeout:
                 if budget < cap:
                     # the request's own budget (or the drain cap) was the
@@ -564,6 +584,12 @@ class ServingFrontEnd:
             # full of good ticks is not evidence of a sick engine), and a
             # working tick is what closes a half-open circuit
             self.breaker.record_success()
+        # decode chunks by when they were DISPATCHED: behind a program still
+        # to be waited for, or by their own tick
+        if self._ahead is not None:
+            self._count("ticks_ahead")
+        if phase == "decode" and ahead is None:
+            self._count("ticks_serial")
         launched, ready = stamps
         for name, t0, t1 in (("tick_launch", tick.t0, launched),
                              ("tick_wait", launched, ready),
@@ -578,6 +604,36 @@ class ServingFrontEnd:
             f"serving/{'prefill' if phase == 'prefill' else 'decode_chunk'}"
             "_seconds").observe(tick.dur)
         return out
+
+    def _chunk_behind(self, req: Request, phase: str, out: tuple):
+        """Dispatch the decode chunk that FOLLOWS the tick whose program
+        returned ``out`` (device arrays, ready or not: the carry among them
+        is the chunk's input, so the device starts it when that program
+        ends) and return its outputs, or None: the loop is then tick by
+        tick. Decided a tick, from what the request says of itself:
+
+        - it has no EOS id, so nothing the tick in flight returns can make
+          the chunk needless (``decode_chunk`` scans all its steps whatever
+          ``done`` says: a chunk dispatched past an EOS would hold the
+          device in front of the next request's prefill);
+        - it is still owed tokens after those the tick in flight delivers;
+        - the chunk's specialization has run: a compile must never sit
+          inside another tick's deadline.
+
+        What is dispatched here and never waited for (the tick died, the
+        request ran out of its budget) costs at most one chunk of device
+        time, counted ``serving/ticks_dropped``."""
+        if req.eos_token_id is not None:
+            return None
+        fresh = out[4] if len(out) > 4 else out[0]      # what the tick delivers
+        if len(req.tokens) + fresh.size >= req.max_new_tokens:
+            return None
+        if not self._warm.get(("decode", self._program_key(req),
+                               int(phase == "decode"))):
+            return None
+        tok, cache, done, rng = out[:4]
+        return self._get_programs(req)[1](self.engine.params, tok, cache,
+                                          done, rng)
 
     def _process(self, req: Request) -> None:
         tracer = _telemetry.get_tracer()
@@ -683,27 +739,40 @@ class ServingFrontEnd:
             # output and would compile the decode chunk a second time
             rng = jax.device_put(jax.random.PRNGKey(req.seed),
                                  self.engine.sharding.replicated())
-            # (tok, cache, done, rng) and, of a block step, its first block
-            tok, cache, done, rng, *first = self._tick(
-                req, lambda: prefill(self.engine.params, ids, rng),
-                warm_key=("prefill", pkey, ids.shape[1]))
-            # told apart by what the model's cache says of itself (the
-            # names of its leaves), not by the number of dimensions
-            req.cache_position_bytes, req.cache_state_bytes = \
-                cache_footprint(cache)
-            req.cache_window_bytes, req.cache_ring_slots = cache_ring(cache)
-            # prefill chose the first token: it leaves now, alone (of a
-            # block step the first block's new tokens)
-            finished = self._deliver(req, first[0] if first else tok, done,
-                                     tracer)
-            while not finished and len(req.tokens) < req.max_new_tokens:
-                self._poll_preempt()
-                tok, cache, done, rng, toks = self._tick(
-                    req, lambda: decode_chunk(self.engine.params, tok, cache,
-                                              done, rng),
-                    warm_key=("decode", pkey, min(req.decode_ticks, 1)))
-                req.decode_ticks += 1
-                finished = self._deliver(req, toks, done, tracer)
+            try:
+                # (tok, cache, done, rng) and, of a block step, its first
+                # block
+                tok, cache, done, rng, *first = self._tick(
+                    req, lambda: prefill(self.engine.params, ids, rng),
+                    warm_key=("prefill", pkey, ids.shape[1]))
+                # told apart by what the model's cache says of itself (the
+                # names of its leaves), not by the number of dimensions
+                req.cache_position_bytes, req.cache_state_bytes = \
+                    cache_footprint(cache)
+                req.cache_window_bytes, req.cache_ring_slots = \
+                    cache_ring(cache)
+                # prefill chose the first token: it leaves now, alone (of a
+                # block step the first block's new tokens)
+                finished = self._deliver(req, first[0] if first else tok,
+                                         done, tracer)
+                # ONE loop: a tick waits for the chunk the tick before
+                # dispatched behind its own, or dispatches its own
+                # (``_tick``); ``cache`` is always the last DELIVERED
+                # chunk's
+                while not finished and len(req.tokens) < req.max_new_tokens:
+                    self._poll_preempt()
+                    tok, cache, done, rng, toks = self._tick(
+                        req, lambda: decode_chunk(self.engine.params, tok,
+                                                  cache, done, rng),
+                        warm_key=("decode", pkey, min(req.decode_ticks, 1)))
+                    req.decode_ticks += 1
+                    finished = self._deliver(req, toks, done, tracer)
+            finally:
+                if self._ahead is not None:
+                    # dispatched behind a tick that died or a deadline:
+                    # dropped unread, before the request resolves
+                    self._ahead = None
+                    self._count("ticks_dropped")
             self._count_block_passes(req, cache)
             self._count_expert_tokens(req, cache, tracer)
             self._observe_service(req)

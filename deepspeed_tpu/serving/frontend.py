@@ -18,7 +18,11 @@ clean per-request timeout instead of a wedged server, and the host checks
 the request deadline, the drain flag, and the elastic agent's preemption
 flag between ticks. A request that cannot end early (no EOS id) has its
 next decode chunk dispatched BEHIND the one a tick waits for, so the host's
-work of a tick runs while the device computes (``_chunk_behind``).
+work of a tick runs while the device computes (``_chunk_behind``). Every
+call that hands the device a program is a ``dispatch`` record with its
+place in the process's dispatch order (``seq``), and a ``tick_wait`` names
+the one it blocked on: a reader pairs the device's executions with them,
+one for one.
 
 The invariant everything here serves: **an admitted request reaches
 exactly one terminal status** (completed / partial / shed / failed), and
@@ -37,6 +41,7 @@ Health states: ``starting → ready ⇄ degraded → draining → dead``.
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import signal
@@ -58,6 +63,25 @@ from deepspeed_tpu.utils import locks as _locks
 from deepspeed_tpu.utils.logging import logger
 
 STATUS_FILE = "serving_status.json"
+
+# every call that hands the device a program, process-wide and in dispatch
+# order: the order the device runs them in (``next`` on it is atomic)
+_DISPATCHES = itertools.count(1)
+_Dispatch = collections.namedtuple(
+    "_Dispatch", "out program index behind seq t0 t1")
+
+
+def _dispatch(call, program: str, index: int, behind: bool) -> _Dispatch:
+    """``call()`` hands the device a program (``prefill`` |
+    ``decode_chunk``; ``index`` 0 for the prefill, k for a request's k-th
+    chunk; ``behind``: the request's program before it was still to be
+    waited for). -> its outputs with the ``dispatch`` record's fields:
+    ``t0`` just before the call, ``t1`` when it returned, what the dispatch
+    cost the host."""
+    seq = next(_DISPATCHES)
+    t0 = time.monotonic()
+    out = call()
+    return _Dispatch(out, program, index, behind, seq, t0, time.monotonic())
 
 
 class ServerState:
@@ -114,9 +138,9 @@ class ServingFrontEnd:
 
         self._step_tokens = step_tokens(engine.module)
         self._warm: Dict[tuple, int] = {}    # tick key -> successful runs
-        # outputs of the decode chunk dispatched behind the last tick, the
-        # next tick's to wait for; written by the serving thread alone
-        self._ahead: Optional[tuple] = None
+        # the decode chunk dispatched behind the last tick, the next tick's
+        # to wait for; written by the serving thread alone
+        self._ahead: Optional[_Dispatch] = None
         self._service_ema: Optional[float] = None
         self.counts: Dict[str, float] = collections.defaultdict(float)
         self.exit_code = 0
@@ -395,6 +419,7 @@ class ServingFrontEnd:
 
     # ---------------------------------------------------------------- worker
     def _serve_loop(self) -> None:
+        empty_since = None      # the first poll that found the queue empty
         try:
             while True:
                 self._poll_preempt()
@@ -404,11 +429,20 @@ class ServingFrontEnd:
                         req = self._queue.popleft()
                         self._in_flight = req
                         self._set_queue_gauge()
+                        popped = time.monotonic()
                     elif self._draining or self._stop.is_set():
                         break
                     else:
+                        if empty_since is None:
+                            empty_since = time.monotonic()
                         self._lock.wait(self.WORKER_POLL_S)
                         continue
+                if empty_since is not None:
+                    # ONE record a wait, however many polls it took: the
+                    # callers' time, not the program's
+                    _telemetry.get_tracer().record(
+                        "queue_empty", empty_since, popped, cat="serving")
+                    empty_since = None
                 try:
                     self._process(req)
                 finally:
@@ -509,108 +543,134 @@ class ServingFrontEnd:
         tiled by three children: ``tick_launch`` (entry until whatever
         this call dispatches, its own program and the chunk behind it, has
         been dispatched in the deadline worker), ``tick_wait``
-        (``block_until_ready`` of its own program) and ``tick_return``
-        (until this method returns). The worker is a new thread per tick,
-        so it only takes the two inner stamps; the children are recorded
-        from here."""
+        (``block_until_ready`` of its own program; ``seq`` names the
+        ``dispatch`` it blocked on) and ``tick_return`` (until this method
+        returns). Inside ``tick_launch``: ``worker_start`` (entry until the
+        deadline worker's first statement: the thread's spawn) and one
+        ``dispatch`` for every program this call hands the device (a chunk
+        the tick before sent behind its own is that tick's record). The
+        worker is a new thread per tick, so it only takes stamps; the
+        records are written from here, a dying tick's dispatches too."""
         import jax
 
         phase = str(warm_key[0])        # "prefill" | "decode"
+        program = "prefill" if phase == "prefill" else "decode_chunk"
         ahead = self._ahead             # dropped by _serve if the tick dies
         tracer = _telemetry.get_tracer()
-        stamps: List[float] = []        # worker: fn() returned, outputs ready
+        # the worker's: entered, all dispatched, outputs ready; and what it
+        # handed the device
+        stamps: List[float] = []
+        sent: List[_Dispatch] = []
         # request-scoped span: with the admission_wait span this lets
         # ds_metrics --serving decompose TTFT into queue-wait vs compute,
         # and a device profile show WHICH request a tick served
-        with tracer.span(
-                phase, cat="serving", request=req.id,
-                # positions in the cache when the tick starts: the prompt
-                # and every token but the last, which this tick steps on (a
-                # block step has written every token it delivered; the last
-                # block's are committed by this tick's first pass)
-                context=int(req.prompt.shape[1]) + max(
-                    len(req.tokens) - (self._step_tokens == 1), 0),
-                index=req.decode_ticks) as tick:
-            if phase == "decode":
-                tick.args["ahead"] = ahead is not None
-            now = tick.t0
-            remaining = req.deadline_at - now
-            if self._draining and self._drain_deadline is not None:
-                remaining = min(remaining, self._drain_deadline - now)
-            if remaining <= 0:
-                raise _RequestDeadline()
-            # a tick is "warm" only once its exact jit SPECIALIZATION has
-            # run: prefill specializes per prompt length; the decode
-            # chunk's call #1 takes prefill outputs, call #2+ its OWN
-            # outputs — XLA may hand those back in another layout and
-            # specialize again — so the two call positions carry distinct
-            # warm keys. Until a specialization has run, the startup cap
-            # applies; a compile must never read as a hang.
-            cold = not self._warm.get(warm_key)
-            cap = float(self.cfg.startup_tick_timeout_s) if cold \
-                else float(self.cfg.decode_tick_timeout_s)
-            budget = max(0.01, min(cap, remaining))
+        try:
+            with tracer.span(
+                    phase, cat="serving", request=req.id,
+                    # positions in the cache when the tick starts: the
+                    # prompt and every token but the last, which this tick
+                    # steps on (a block step has written every token it
+                    # delivered; the last block's are committed by this
+                    # tick's first pass)
+                    context=int(req.prompt.shape[1]) + max(
+                        len(req.tokens) - (self._step_tokens == 1), 0),
+                    index=req.decode_ticks) as tick:
+                if phase == "decode":
+                    tick.args["ahead"] = ahead is not None
+                now = tick.t0
+                remaining = req.deadline_at - now
+                if self._draining and self._drain_deadline is not None:
+                    remaining = min(remaining, self._drain_deadline - now)
+                if remaining <= 0:
+                    raise _RequestDeadline()
+                # a tick is "warm" only once its exact jit SPECIALIZATION
+                # has run: prefill specializes per prompt length; the decode
+                # chunk's call #1 takes prefill outputs, call #2+ its OWN
+                # outputs — XLA may hand those back in another layout and
+                # specialize again — so the two call positions carry
+                # distinct warm keys. Until a specialization has run, the
+                # startup cap applies; a compile must never read as a hang.
+                cold = not self._warm.get(warm_key)
+                cap = float(self.cfg.startup_tick_timeout_s) if cold \
+                    else float(self.cfg.decode_tick_timeout_s)
+                budget = max(0.01, min(cap, remaining))
 
-            def run():
-                from deepspeed_tpu.resilience.chaos import active_injector
-
-                inj = active_injector()
-                if inj is not None and inj.targets("decode_step"):
-                    inj.before("decode_step", req.id)
-                with self.engine.mesh:
-                    out = fn() if ahead is None else ahead
-                    behind = self._chunk_behind(req, phase, out)
+                def run():
                     stamps.append(time.monotonic())
-                    jax.block_until_ready(out)
-                    stamps.append(time.monotonic())
-                return out, behind
+                    from deepspeed_tpu.resilience.chaos import active_injector
 
-            try:
-                # a tick bound by the REQUEST's budget (budget < cap) that
-                # expires is a deadline over healthy compute, not a hang —
-                # it must not stamp a goodput watchdog_stall span
-                out, self._ahead = run_with_deadline(
-                    run, timeout=budget, name=f"serve-tick[{req.id}]",
-                    stall_span=budget >= cap)
-            except WatchdogTimeout:
-                if budget < cap:
-                    # the request's own budget (or the drain cap) was the
-                    # binding constraint — that is a deadline, not a hang
-                    raise _RequestDeadline() from None
-                raise
-            self._warm[warm_key] = self._warm.get(warm_key, 0) + 1
-            # "K consecutive decode-step failures" is TICK-granular: every
-            # healthy tick resets the streak (a deadline-partial request
-            # full of good ticks is not evidence of a sick engine), and a
-            # working tick is what closes a half-open circuit
-            self.breaker.record_success()
+                    inj = active_injector()
+                    if inj is not None and inj.targets("decode_step"):
+                        inj.before("decode_step", req.id)
+                    with self.engine.mesh:
+                        own = ahead
+                        if own is None:
+                            own = _dispatch(
+                                fn, program,
+                                req.decode_ticks + (phase == "decode"), False)
+                            sent.append(own)
+                        behind = self._chunk_behind(req, phase, own.out)
+                        if behind is not None:
+                            sent.append(behind)
+                        stamps.append(time.monotonic())
+                        jax.block_until_ready(own.out)
+                        stamps.append(time.monotonic())
+                    return own, behind
+
+                try:
+                    # a tick bound by the REQUEST's budget (budget < cap)
+                    # that expires is a deadline over healthy compute, not a
+                    # hang — it must not stamp a goodput watchdog_stall span
+                    own, self._ahead = run_with_deadline(
+                        run, timeout=budget, name=f"serve-tick[{req.id}]",
+                        stall_span=budget >= cap)
+                except WatchdogTimeout:
+                    if budget < cap:
+                        # the request's own budget (or the drain cap) was
+                        # the binding constraint: a deadline, not a hang
+                        raise _RequestDeadline() from None
+                    raise
+                self._warm[warm_key] = self._warm.get(warm_key, 0) + 1
+                # "K consecutive decode-step failures" is TICK-granular:
+                # every healthy tick resets the streak (a deadline-partial
+                # request full of good ticks is not evidence of a sick
+                # engine), and a working tick is what closes a half-open
+                # circuit
+                self.breaker.record_success()
+        finally:
+            # whatever the worker handed the device, of a tick that died too
+            for d in sent:
+                tracer.record("dispatch", d.t0, d.t1, cat="serving",
+                              parent=tick, request=req.id, program=d.program,
+                              index=d.index, behind=d.behind, seq=d.seq)
         # decode chunks by when they were DISPATCHED: behind a program still
         # to be waited for, or by their own tick
         if self._ahead is not None:
             self._count("ticks_ahead")
         if phase == "decode" and ahead is None:
             self._count("ticks_serial")
-        launched, ready = stamps
-        for name, t0, t1 in (("tick_launch", tick.t0, launched),
-                             ("tick_wait", launched, ready),
-                             ("tick_return", ready, tick.t1)):
+        entered, launched, ready = stamps
+        for name, t0, t1, args in (
+                ("worker_start", tick.t0, entered, {}),
+                ("tick_launch", tick.t0, launched, {}),
+                ("tick_wait", launched, ready, {"seq": own.seq}),
+                ("tick_return", ready, tick.t1, {})):
             tracer.record(name, t0, t1, cat="serving", parent=tick,
-                          request=req.id)
+                          request=req.id, **args)
         if cold:
             req.compile_s += tick.dur
         if phase == "prefill":
             req.prefill_done_at = tick.t1
-        self._reg().histogram(
-            f"serving/{'prefill' if phase == 'prefill' else 'decode_chunk'}"
-            "_seconds").observe(tick.dur)
-        return out
+        self._reg().histogram(f"serving/{program}_seconds").observe(tick.dur)
+        return own.out
 
     def _chunk_behind(self, req: Request, phase: str, out: tuple):
         """Dispatch the decode chunk that FOLLOWS the tick whose program
         returned ``out`` (device arrays, ready or not: the carry among them
         is the chunk's input, so the device starts it when that program
-        ends) and return its outputs, or None: the loop is then tick by
-        tick. Decided a tick, from what the request says of itself:
+        ends) and return the dispatch (its outputs among it), or None: the
+        loop is then tick by tick. Decided a tick, from what the request
+        says of itself:
 
         - it has no EOS id, so nothing the tick in flight returns can make
           the chunk needless (``decode_chunk`` scans all its steps whatever
@@ -632,8 +692,10 @@ class ServingFrontEnd:
                                int(phase == "decode"))):
             return None
         tok, cache, done, rng = out[:4]
-        return self._get_programs(req)[1](self.engine.params, tok, cache,
-                                          done, rng)
+        chunk = self._get_programs(req)[1]
+        return _dispatch(
+            lambda: chunk(self.engine.params, tok, cache, done, rng),
+            "decode_chunk", req.decode_ticks + (phase == "decode") + 1, True)
 
     def _process(self, req: Request) -> None:
         tracer = _telemetry.get_tracer()
@@ -753,8 +815,8 @@ class ServingFrontEnd:
                     cache_ring(cache)
                 # prefill chose the first token: it leaves now, alone (of a
                 # block step the first block's new tokens)
-                finished = self._deliver(req, first[0] if first else tok,
-                                         done, tracer)
+                finished, delivered = self._deliver(
+                    req, first[0] if first else tok, done, tracer)
                 # ONE loop: a tick waits for the chunk the tick before
                 # dispatched behind its own, or dispatches its own
                 # (``_tick``); ``cache`` is always the last DELIVERED
@@ -766,7 +828,8 @@ class ServingFrontEnd:
                                                   cache, done, rng),
                         warm_key=("decode", pkey, min(req.decode_ticks, 1)))
                     req.decode_ticks += 1
-                    finished = self._deliver(req, toks, done, tracer)
+                    finished, delivered = self._deliver(req, toks, done,
+                                                        tracer)
             finally:
                 if self._ahead is not None:
                     # dispatched behind a tick that died or a deadline:
@@ -778,6 +841,10 @@ class ServingFrontEnd:
             self._observe_service(req)
             self._count("completed")
             self._resolve(req, "completed", "")
+            # the last delivery -> the client has its answer: the counts
+            # read back from the device, the service estimate, the resolution
+            tracer.record("request_close", delivered, time.monotonic(),
+                          cat="serving", request=req.id)
         except _RequestDeadline:
             # the request ran out of ITS budget; every tick that ran was
             # healthy, so the breaker hears nothing. The ledger counts by
@@ -811,14 +878,14 @@ class ServingFrontEnd:
             self._resolve(req, "partial" if req.tokens else "failed",
                           f"error: {type(e).__name__}: {e}")
 
-    def _deliver(self, req: Request, toks, done, tracer) -> bool:
+    def _deliver(self, req: Request, toks, done, tracer) -> tuple:
         """A tick's new tokens come to the host and go to the client, cut
         to what the request is still owed: the one token of the prefill
         tick (a block step's first block), up to ``decode_tick_tokens`` of
-        a decode tick. -> whether
-        every row has passed its EOS (the rest is then padded with it and
-        no further tick runs)."""
-        with tracer.span("deliver", cat="serving", request=req.id):
+        a decode tick. -> (whether every row has passed its EOS: the rest is
+        then padded with it and no further tick runs; when the delivery
+        ended)."""
+        with tracer.span("deliver", cat="serving", request=req.id) as span:
             fresh = np.asarray(toks).reshape(-1).tolist()
             fresh = fresh[:req.max_new_tokens - len(req.tokens)]
             req.tokens.extend(fresh)
@@ -838,7 +905,7 @@ class ServingFrontEnd:
                 pad = [eos] * (req.max_new_tokens - len(req.tokens))
                 req.tokens.extend(pad)
                 self._flush_stream(req, pad)
-        return finished
+        return finished, span.t1
 
     def _count_block_passes(self, req: Request, cache) -> None:
         """A block-diffusion model's programs sum, in the cache they hand

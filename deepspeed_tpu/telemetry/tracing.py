@@ -7,9 +7,19 @@ id (the span that was open on this thread — or on the thread that started
 this one's work — when it began), a ``trace`` identifier shared by every
 span of one request (its id) or one train step (its number), and small
 ``args``. Spans mark the layer boundaries: data / train_batch / dispatch /
-wait / post_step in the train engine, request / prefill / decode /
-tick_launch / tick_wait / tick_return / deliver in the serving front-end,
-checkpoint save/load, ``door_compile`` at ``sharded_jit``.
+wait / post_step in the train engine; in the serving front-end request /
+admission_wait / prefill / decode / deliver / request_close, under a tick
+worker_start / dispatch / tick_launch / tick_wait / tick_return, and
+beside a request status_write / queue_empty; checkpoint save/load,
+``door_compile`` at ``sharded_jit``. Where the work leaves the host it is
+a record of its own: a serving ``dispatch`` is one call that hands the
+device a program (``program``, ``index``, ``behind``, ``seq`` = its place in
+the process's dispatch order) and a ``tick_wait`` names the ``seq`` it
+blocked on, so a reader of a device profile pairs executions with
+dispatches one for one. What an operator reads from them: a ``behind``
+share of the decode chunks under 100% is a loop gone serial, a rising
+``request_close`` a slow read-back of the counts, ``queue_empty`` the
+callers' time and not the program's.
 
 Two places hold them. With no ``telemetry`` session the process-wide
 :data:`RING` keeps the last :data:`RING_SPANS`; a session with ``trace:
@@ -40,9 +50,12 @@ from jax.profiler import TraceAnnotation
 
 from deepspeed_tpu.utils.logging import logger
 
-# The ring holds at least four times the spans of the longest benchmark
-# cell's whole run (gpt2-xl.serve.chat.c4: ~280 ticks x ~6 spans + set-up).
-RING_SPANS = 16_384
+# The ring holds four times the spans of the longest benchmark cell's whole
+# run (gpt2-xl.serve.chat.c4: ~750 ticks a 30 s window x ~8 spans a tick +
+# ~45 requests x ~7, and a sixth of that again in set-up and drain: ~7,500)
+# and twice those of a CPU rehearsal of a serve cell, whose toy model ticks
+# a thousand times a second (tests/benchmark: ~16,000 a run).
+RING_SPANS = 32_768
 
 _ids = itertools.count(1)
 _profiling = TraceAnnotation.is_enabled

@@ -714,8 +714,10 @@ class TestServingRequestSpans:
                      if e.get("cat") == "serving"]
             by_name = {e["name"] for e in spans}
             assert {"admission_wait", "prefill", "decode"} <= by_name
+            # every span of the request's service names it; the wait on an
+            # empty queue is no request's
             assert all((e.get("args") or {}).get("request") == r.id
-                       for e in spans)
+                       for e in spans if e["name"] != "queue_empty")
             # the SLO renderer decomposes TTFT from the new series
             from deepspeed_tpu.profiling.report import \
                 render_serving_summary

@@ -259,8 +259,9 @@ class TestSpans:
         assert telemetry.get_tracer() is tracing.RING
         _, spans = self._serve_one(engine, new_tokens=4)
         assert {s.name for s in spans} == {
-            "request", "admission_wait", "prefill", "decode", "tick_launch",
-            "tick_wait", "tick_return", "deliver", "status_write"}
+            "request", "admission_wait", "prefill", "decode", "worker_start",
+            "dispatch", "tick_launch", "tick_wait", "tick_return", "deliver",
+            "request_close", "status_write"}
 
     def test_stamps_are_ordered_and_in_the_request_span(self, engine):
         r, spans = self._serve_one(engine)
@@ -302,8 +303,8 @@ class TestSpans:
         for s in tree:
             if s is not req:
                 want = ("request",) if s.name in (
-                    "admission_wait", "prefill", "decode", "deliver") \
-                    else ("prefill", "decode")
+                    "admission_wait", "prefill", "decode", "deliver",
+                    "request_close") else ("prefill", "decode")
                 assert by_id[s.parent].name in want, (s.name, s.parent)
         decodes = [s for s in spans if s.name == "decode"]
         assert [s.args["index"] for s in decodes] == [0, 1, 2]
@@ -312,6 +313,11 @@ class TestSpans:
         assert [s.args["context"] for s in decodes] == [8, 12, 16]
         # one deliver after the prefill tick and one after every decode tick
         assert len([s for s in spans if s.name == "deliver"]) == 4
+        # from the last delivery to the resolution, the client's answer
+        (close,) = [s for s in spans if s.name == "request_close"]
+        last = max(s.t1 for s in spans if s.name == "deliver")
+        assert close.t0 == last and close.t1 >= r.finished_at
+        assert close.t1 <= req.t1
         # the status write follows the request and is not part of its tree
         (status,) = [s for s in spans if s.name == "status_write"]
         assert status.parent is None and status.trace is None
@@ -322,13 +328,23 @@ class TestSpans:
         ticks = [s for s in spans if s.name in ("prefill", "decode")]
         assert len(ticks) == 4
         for tick in ticks:
-            kids = sorted((s for s in spans if s.parent == tick.id),
-                          key=lambda s: s.t0)
+            under = sorted((s for s in spans if s.parent == tick.id),
+                           key=lambda s: s.t0)
+            kids = [k for k in under if k.name.startswith("tick_")]
             assert [k.name for k in kids] == [
                 "tick_launch", "tick_wait", "tick_return"]
             assert kids[0].t0 == tick.t0 and kids[2].t1 == tick.t1
             assert kids[0].t1 == kids[1].t0 and kids[1].t1 == kids[2].t0
             assert abs(sum(k.dur for k in kids) - tick.dur) < 50e-6
+            # what splits the launch: the worker thread's spawn, then every
+            # program this call handed the device
+            inside = [k for k in under if not k.name.startswith("tick_")]
+            assert inside[0].name == "worker_start"
+            assert {k.name for k in inside[1:]} <= {"dispatch"}
+            assert inside[0].t0 == tick.t0
+            for a, b in zip(inside, inside[1:]):
+                assert a.t1 <= b.t0
+            assert inside[-1].t1 <= kids[0].t1
         # a tick (the prefill too) and its deliver follow each other at once
         delivers = [s for s in spans if s.name == "deliver"]
         assert len(delivers) == len(ticks)

@@ -155,6 +155,110 @@ def test_the_tokens_are_the_serial_loops(served, sampling, n):
     assert span.args["decode_ticks"] == ticks
 
 
+# ------------------------------------------------------ the dispatch records
+def _counted(served):
+    """The served programs behind wrappers that note every call, in call
+    order; -> (the calls, what puts the programs back)."""
+    programs, calls, kept = served[2], [], dict(served[2])
+    note = lambda name, f: lambda *a: (calls.append(name), f(*a))[1]
+    for key, (prefill, chunk) in kept.items():
+        programs[key] = (note("prefill", prefill), note("decode_chunk", chunk))
+    return calls, lambda: programs.update(kept)
+
+
+def _dispatches(req):
+    return sorted(_spans_of(req, "dispatch"), key=lambda s: s.args["seq"])
+
+
+def _waited(req):
+    """{seq a ``tick_wait`` of the request names: (program, index) its tick
+    waits for}."""
+    ticks = {s.id: s for name in ("prefill", "decode")
+             for s in _spans_of(req, name)}
+    return {w.args["seq"]: ("prefill", 0) if ticks[w.parent].name == "prefill"
+            else ("decode_chunk", ticks[w.parent].args["index"] + 1)
+            for w in _spans_of(req, "tick_wait")}
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("serial", [False, True])
+def test_every_program_call_is_one_dispatch_record(served, serial):
+    """One ``dispatch`` a call of a served program, ``seq`` rising in call
+    order, ``behind`` exactly for the calls ``_chunk_behind`` makes, and a
+    ``tick_wait`` names the dispatch of ITS request and index; the tokens
+    are the serial loop's."""
+    _warm_up(served, **GREEDY)
+    n = 2 + 3 * CHUNK
+    ticks = -(-(n - _first(served[0])) // CHUNK)
+    ref, _ = _serve(served, n, serial=True, **GREEDY)
+    calls, restore = _counted(served)
+    try:
+        req, fe = _serve(served, n, serial=serial, **GREEDY)
+    finally:
+        restore()
+    assert req.tokens == ref.tokens
+    sent = _dispatches(req)
+    assert [s.args["program"] for s in sent] == calls \
+        == ["prefill"] + ["decode_chunk"] * ticks
+    seqs = [s.args["seq"] for s in sent]
+    assert all(a < b for a, b in zip(seqs, seqs[1:]))
+    assert [s.t0 for s in sent] == sorted(s.t0 for s in sent)
+    assert [s.args["index"] for s in sent] == list(range(ticks + 1))
+    assert [s.args["behind"] for s in sent] \
+        == [False] + [not serial] * ticks
+    assert all(s.trace == req.id and s.t0 <= s.t1 for s in sent)
+    # every dispatch was waited for, by the tick of its own index
+    by_seq = {s.args["seq"]: (s.args["program"], s.args["index"])
+              for s in sent}
+    assert _waited(req) == by_seq
+    by_id = {s.id: s for name in ("prefill", "decode")
+             for s in _spans_of(req, name)}
+    for s in sent:
+        tick = by_id[s.parent]
+        # its own tick's, or (behind) the tick's before it
+        assert s.args["index"] - bool(s.args["behind"]) == (
+            0 if tick.name == "prefill" else tick.args["index"] + 1)
+        assert tick.t0 <= s.t0 and s.t1 <= tick.t1
+    assert fe.counts["ticks_dropped"] == 0
+
+
+@pytest.mark.serving
+def test_the_loops_other_records(served):
+    """``worker_start`` opens every tick, ``request_close`` runs from the
+    last delivery to the resolution, and a server that waits for work writes
+    ONE ``queue_empty`` a wait, however many polls it took."""
+    from deepspeed_tpu import telemetry
+
+    _warm_up(served, **GREEDY)
+    fe = _frontend(served)
+    t_start = time.monotonic()
+    try:
+        time.sleep(6 * fe.WORKER_POLL_S)
+        reqs = [fe.submit(_prompt(), max_new_tokens=1 + CHUNK, **GREEDY)
+                for _ in range(2)]
+        for r in reqs:
+            assert r.result(timeout=600).status == "completed"
+    finally:
+        fe.close()
+    for r in reqs:
+        ticks = _spans_of(r, "prefill") + _spans_of(r, "decode")
+        starts = _spans_of(r, "worker_start")
+        assert [s.parent for s in starts] == [t.id for t in ticks]
+        for tick, start, wait in zip(ticks, starts, _spans_of(r, "tick_wait")):
+            assert start.t0 == tick.t0 and start.t1 <= wait.t0
+        (close,) = _spans_of(r, "request_close")
+        (span,) = _spans_of(r, "request")
+        assert close.parent == span.id
+        assert close.t0 == _spans_of(r, "deliver")[-1].t1
+        assert r.finished_at <= close.t1 <= span.t1
+    empty = [s for s in telemetry.get_tracer().snapshot()
+             if s.name == "queue_empty" and s.t0 >= t_start]
+    # the wait before the first request; the second was queued behind it
+    assert len(empty) == 1 and empty[0].parent is None
+    assert empty[0].dur >= 5 * fe.WORKER_POLL_S
+    assert empty[0].t1 <= _spans_of(reqs[0], "request")[0].t0
+
+
 # ------------------------------------------------------- when nothing is ahead
 @pytest.mark.serving
 @pytest.mark.parametrize("why", ["eos", "cold", "owed_nothing"])
@@ -179,6 +283,11 @@ def test_nothing_is_dispatched_ahead(served, why):
         assert r.result(timeout=600).status == "completed", r.reason
         assert fe.counts["ticks_ahead"] == fe.counts["ticks_dropped"] == 0
         assert fe.counts["ticks_serial"] == r.decode_ticks
+        sent = _dispatches(r)
+        assert [s.args["behind"] for s in sent] \
+            == [False] * (1 + r.decode_ticks)
+        assert [s.args["index"] for s in sent] \
+            == list(range(1 + r.decode_ticks))
         if why == "cold":
             assert r.decode_ticks == 2
             # both have run now: the next request's chunks all go ahead
@@ -258,6 +367,15 @@ def test_a_hung_tick_drops_the_chunk_ahead_and_the_server_serves_on(
         assert (fe.counts["ticks_ahead"], fe.counts["ticks_dropped"],
                 fe.counts["timed_out"]) == (2, 1, 1)
         assert fe._ahead is None
+        # a dying tick's dispatches are records too: the chunk its worker
+        # sent behind before it hung in the wait
+        sent = _dispatches(r)
+        assert [s.args["index"] for s in sent] \
+            == [0, 1, 2] + [3] * (where == "wait")
+        assert sorted(_waited(r).values()) == [("decode_chunk", 1),
+                                               ("prefill", 0)]
+        dead = _spans_of(r, "decode")[-1]
+        assert all(s.parent == dead.id for s in sent[3:])
         chaos.uninstall_chaos()
         monkeypatch.undo()
         r2 = fe.submit(_prompt(), max_new_tokens=6 * CHUNK, **GREEDY)
@@ -296,6 +414,13 @@ def test_a_request_deadline_with_a_chunk_ahead(served):
         assert (fe.counts["timed_out"], fe.counts["ticks_dropped"]) == (1, 1)
         assert fe.counts["ticks_ahead"] == r.decode_ticks + 1
         assert fe._ahead is None
+        # the dropped chunk: a dispatch that no tick_wait names, the last
+        sent, waited = _dispatches(r), _waited(r)
+        assert len(sent) == len(waited) + 1
+        assert [s.args["seq"] for s in sent[:-1]] == sorted(waited)
+        lost = sent[-1].args
+        assert lost["seq"] not in waited and lost["behind"]
+        assert lost["index"] == r.decode_ticks + 1
     finally:
         fe.close()
 

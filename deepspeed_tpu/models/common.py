@@ -142,6 +142,16 @@ def apply_rope(x, cos, sin, interleaved: bool = False):
     return out.astype(x.dtype)
 
 
+def apply_rope_leading(t, cos, sin):
+    """The rotary embedding on the first ``cos.shape[-1]`` columns of every
+    head of t (B, T, H, Dh); the others pass as they are."""
+    r = cos.shape[-1]
+    if r == t.shape[-1]:
+        return apply_rope(t, cos, sin)
+    return jnp.concatenate([apply_rope(t[..., :r], cos, sin), t[..., r:]],
+                           axis=-1)
+
+
 def _kernel_target():
     """``(mesh, on_tpu)`` for the program under trace: the ambient ``with
     mesh:`` every engine traces inside and whether its devices are TPUs —
@@ -363,6 +373,50 @@ def local_causal_attention(q, k, v, use_flash: bool = True, alibi=None,
     else:
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return checkpoint_name(jnp.einsum("bhqk,bkhd->bqhd", probs, v), SAVED_O)
+
+
+def prefill_attention_form(t: int, d: int, rotary_dim, use_flash: bool = True,
+                           window=None, block=None) -> str:
+    """The form a prefill's GQA layer takes at ``t`` positions, heads ``d``
+    wide of which the rotary embedding turns ``rotary_dim`` (None: none),
+    under the layer's static ``window`` (a Python int or None) and ``block``:
+    ``"fused"``, :func:`prefill_attention` on q, k and v as the projections
+    made them, where the program is for a TPU and the flash forward carries
+    the call's masks; ``"plain"``, q rotated and K / V repeated in passes
+    around :func:`local_causal_attention`, off it (the CPU's einsum) and
+    for what the kernel does not carry. By what the call can see, as
+    ``local_causal_attention`` chooses."""
+    if not (use_flash and _kernel_target()[1]):
+        return "plain"
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    masked = block is None or block <= 1 or (
+        fa.block_mask_supports(block) and t % block == 0
+        and window is None)
+    return "fused" if masked and fa.flash_supports(t, t, True) \
+        and fa.prefill_supports(d, rotary_dim) else "plain"
+
+
+def prefill_attention(q, k, v, cos, sin, window=None, block=None, sink=None):
+    """The ``"fused"`` form: ``flash_prefill`` (ops/pallas/
+    flash_attention.py: forward only) on q (B, T, H, D) NOT rotated, k (B,
+    T, KV, D) rotated, v (B, T, KV, Dv), the model's ``cos`` / ``sin`` (T,
+    r) or None, the layer's static ``window``, ``block`` and ``sink`` -> (B,
+    T, H, Dv). On a mesh the call is manual over the batch and KV-head axes
+    that divide them, as ``local_causal_attention``'s is."""
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+
+    mesh, _ = _kernel_target()
+    batch, heads = _attn_axes(mesh, q.shape[0], k.shape[2])
+    spec = P(batch, None, heads, None)
+    given = {name: (arg, at) for name, arg, at in (
+        ("cos", cos, P()), ("sin", sin, P()), ("sink", sink, P(heads)))
+        if arg is not None}
+    return _kernel_on_mesh(
+        lambda q, k, v, *rest: fa.flash_prefill(
+            q, k, v, window=window, block=block, **dict(zip(given, rest))),
+        mesh, (q, k, v, *(arg for arg, _ in given.values())),
+        (spec, spec, spec, *(at for _, at in given.values())), spec)
 
 
 # ------------------------------------------------------------------ KV cache

@@ -160,7 +160,7 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.telemetry.scopes import scope
 from deepspeed_tpu.models.common import (_rope_cos_sin, apply_rope,
-                                         remat_wrap)
+                                         apply_rope_leading, remat_wrap)
 
 
 @dataclasses.dataclass
@@ -1056,15 +1056,7 @@ class LlamaModel:
             return _rope_cos_sin(positions, c.rope_dim, c.rope_theta,
                                  c.rope_scaling)
 
-    @staticmethod
-    def _rotate(t, cos, sin):
-        """The rotary embedding on the first ``cos.shape[-1]`` columns of
-        every head of t; the others pass as they are."""
-        r = cos.shape[-1]
-        if r == t.shape[-1]:
-            return apply_rope(t, cos, sin)
-        return jnp.concatenate([apply_rope(t[..., :r], cos, sin),
-                                t[..., r:]], axis=-1)
+    _rotate = staticmethod(apply_rope_leading)
 
     def _block_qkv(self, x, blk, cos, sin):
         """One GQA block's q, k, v for the current x, rotated where the
@@ -1139,16 +1131,46 @@ class LlamaModel:
                  rotate(kv[..., None, C:])], axis=-1)
             return q[..., :n], rotate(q[..., n:]), latent
 
-    def _attend(self, x, blk, cos_sin, attention):
+    def _prefill_form(self, kind, t: int, cos):
+        """The form a prefill of ``t`` positions gives a softmax layer of
+        ``kind`` whose rotary table is ``cos`` (None: not rotated):
+        ``models/common.py::prefill_attention_form`` for a GQA layer;
+        latent attention expands a key a head and takes the plain form."""
+        from deepspeed_tpu.models.common import prefill_attention_form
+
+        c = self.config
+        if c.mla:
+            return "plain"
+        return prefill_attention_form(
+            t, c.head_dim, None if cos is None else cos.shape[-1],
+            c.use_flash_attention, self._window(kind), c.block_length or None)
+
+    def _attend(self, x, blk, cos_sin, attention, fused=None):
         """A block's causal self-attention over the whole of x (the trunk,
         prefill), by the mixer whose leaves it holds -> (attn (B, T, H,
         Dv), the rows a cache keeps of it: GQA (k, v) at the KV heads,
-        latent attention (latent,)). ``attention`` takes full-head q, k, v."""
+        latent attention (latent,)). ``attention`` takes full-head q, k, v.
+        ``fused`` (a prefill's GQA layer in the fused form: its window,
+        block and sink): q, k and v go to the kernel as the projections
+        made them — K rotated in its one small pass, since the cache keeps
+        it so; q's rotation, the scale and the KV heads' grouping are the
+        kernel's (``models/common.py::prefill_attention``)."""
         c = self.config
-        if "kv_a_w" not in blk:
+        if "kv_a_w" not in blk and fused is None:
             q, k, v = self._block_qkv(x, blk, *cos_sin)
             with scope("attn/core"):
                 attn = attention(q, self._repeat_kv(k), self._repeat_kv(v))
+            return self._gated(attn, x, blk), (k, v)
+        if "kv_a_w" not in blk:
+            from deepspeed_tpu.models.common import prefill_attention
+
+            cos, sin = cos_sin
+            q, k, v = self._block_qkv(x, blk, None, None)
+            if cos is not None:
+                with scope("attn/qkv"):
+                    k = self._rotate(k, cos, sin)
+            with scope("attn/core"):
+                attn = prefill_attention(q, k, v, cos, sin, **fused)
             return self._gated(attn, x, blk), (k, v)
         # un-absorbed: every head's key and value expanded from the latent
         # row (q.k at nope + rope columns, v at its own width); absorbing
@@ -1611,10 +1633,14 @@ class LlamaModel:
                 from deepspeed_tpu.models.common import (kv_cache_write,
                                                          kv_ring_write)
 
+                masks = {"window": window, **self._sink_of(blk)}
+                fused = self._prefill_form(
+                    kind, x.shape[1], cos_sin[0]) == "fused"
                 attn, kept = self._attend(
                     x, blk, cos_sin, attention if window is None
-                    else functools.partial(attention, window=window,
-                                           **self._sink_of(blk)))
+                    else functools.partial(attention, **masks),
+                    {**masks, "block": self.config.block_length or None}
+                    if fused else None)
                 write = kv_ring_write if ring else kv_cache_write
                 with scope("attn/core"):
                     rows = tuple(write(held, t, at, 0)
@@ -1707,7 +1733,11 @@ class LlamaModel:
         return x, dict(zip(names, caches)), routed
 
     def prefill(self, params, input_ids, cache):
-        """Process the prompt, fill the cache, return last-position logits."""
+        """Process the prompt, fill the cache, return last-position logits.
+        Counts, when the program is traced, its softmax layers by the form
+        their attention took (``_prefill_form``): registry counter
+        ``kernels/prefill_attn_calls{form=fused|plain}``."""
+        from deepspeed_tpu import telemetry
         from deepspeed_tpu.models.common import local_causal_attention
 
         c = self.config
@@ -1717,8 +1747,15 @@ class LlamaModel:
         attention = lambda q, k, v, window=None, **sink: \
             local_causal_attention(q, k, v, c.use_flash_attention,
                                    window=window, **masked, **sink)
-        x, out, routed = self._run_cached(
-            params, x, cache, self._rope(jnp.arange(T)), 0, attention)
+        cos_sin = self._rope(jnp.arange(T))
+        x, out, routed = self._run_cached(params, x, cache, cos_sin, 0,
+                                          attention)
+        # at trace time: the program's softmax layers by the form they took
+        for kind in set(c.kinds) - {"kda"}:
+            telemetry.get_registry().counter(
+                "kernels/prefill_attn_calls", {"form": self._prefill_form(
+                    kind, T, self._rope_of(kind, cos_sin)[0])}).inc(
+                        c.kinds.count(kind))
         with scope("head"):
             x = self._rms_norm(x, params["norm_g"])
             logits = (x[:, -1] @ self._head(params, x.dtype)

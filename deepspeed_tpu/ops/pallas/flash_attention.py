@@ -90,9 +90,34 @@ step takes). ONE forward kernel for every length, its shapes from
   the diagonal's sub-block differs, its mask ``col <= row | (block - 1)``
   where the causal one is ``col <= row``. The backward refuses it.
 
+* **A PREFILL's forward takes q, k and v as the projections made them**
+  (``flash_prefill``, FORWARD only; its one caller is
+  ``models/common.py::prefill_attention``, reached from
+  ``LlamaModel.prefill``; a caller that needs a gradient takes
+  ``flash_attention``). Operands: q (B, T, H, D) not rotated and not
+  scaled, k (B, T, KV, D) rotated, v (B, T, KV, Dv), the model's ``cos`` /
+  ``sin`` (T, r) float32 or none, the layer's ``window`` / ``sink`` /
+  ``block``. The same two kernels and plans as above
+  (``_causal_forward``), with three things done inside the call that
+  ``flash_attention``'s callers do in passes of q's size around it: (1) K
+  and V stay at their own KV heads, q's head b reading theirs at ``b //
+  (H / KV)`` in the k and v index maps: no repeated K or V is written; (2)
+  a q block is rotated and scaled in float32 in VMEM at the start of its
+  key walk and rounded once (``_turn_q``: the first ``r`` lanes by one
+  ``pltpu.roll`` against the sine with its first half negated, the others
+  passed through; scratch ``q_sc``, the tables' rows blocked by the q
+  block's index); (3) an output whose head is whole 128 lanes is written
+  in place as the (B, T, H * Dv) rows the out-projection reads, a head its
+  lane block (``o_rows``, ``_in_place``). q, k and v go in head-major: XLA
+  lays their producers' outputs out that way at no pass of its own, Mosaic
+  refuses a 192-lane block of a row (and a squeezed head of (B, T, H,
+  192)), and at 128 lanes the row-block read measured slower. With equal
+  heads, no tables and a scale of 1.0 ``_causal_forward`` traces to the
+  program it always traced to (the pinned digests).
+
 Layout: (B, T, H, D) in/out (matches deepspeed_tpu.models); internally
-(B·H, T, D). v may have a head size of its own (latent attention: q.k at 192
-columns, v at 128): the FORWARD kernels take the value width from v — the
+(B·H, T, D) (``flash_prefill``: above). v may have a head size of its own
+(latent attention: q.k at 192 columns, v at 128): the FORWARD kernels take the value width from v — the
 accumulator, the output and the P @ V pass are that wide, nothing is padded.
 The backward kernels take one width: a narrower v goes in with zero columns
 (``_attention_vjp``). The per-row statistics that pass between the
@@ -423,9 +448,32 @@ def flash_forward_plan(t: int, d: int, dv: int, dtype,
                           and not _inside_window(i, g, sub, window))))
 
 
+def _turn_q(q_ref, q_sc, cos_ref, sin_ref, scale: float):
+    """The q block as its whole key walk multiplies it, made ONCE a block
+    into ``q_sc``: in float32, the first ``r`` lanes of the head rotated
+    (rotate-half is ONE roll by ``r / 2`` inside those lanes against the
+    sine with its first half negated, ``_signed_sin``; ``cos_ref`` /
+    ``sin_ref`` are the block's (rows, r) float32 rows; None: no rotary
+    embedding), the others passed through, every lane times the softmax
+    scale, then ONE rounding to the operand's type."""
+    x = q_ref[0].astype(jnp.float32)
+    if scale != 1.0:
+        x = x * scale
+    if cos_ref is None:
+        q_sc[:] = x.astype(q_sc.dtype)
+        return
+    r = cos_ref.shape[-1]
+    head = x[:, :r]
+    head = head * cos_ref[:] + pltpu.roll(head, r // 2, 1) * sin_ref[:]
+    if r < x.shape[1]:
+        q_sc[:] = x.astype(q_sc.dtype)
+    q_sc[:, :r] = head.astype(q_sc.dtype)
+
+
 def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       acc_sc, m_sc, l_sc, *, scale: float, sub: int,
-                       n_sub: int, block: int = 1):
+                       acc_sc, m_sc, l_sc, q_sc=None, *, scale: float,
+                       sub: int, n_sub: int, block: int = 1,
+                       q_scale: float = 1.0, cos_ref=None, sin_ref=None):
     """A q block (one row of square sub-blocks) against a span of ``n_sub``
     of them. Every walk is unrolled, two sub-blocks a softmax update (one
     q.k matmul 2 x sub columns wide: the max, the rescale and the
@@ -437,7 +485,7 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
     a row's block ends at ``row | (block - 1)``)."""
     f = pl.program_id(1)
     qi, si = qi_arr[f], si_arr[f]
-    q = q_ref[0]
+    q = q_ref[0] if q_sc is None else None
     # sub-blocks of this span wholly under the diagonal: all of them, or, in
     # the q block's last span, those before the one the diagonal crosses
     under = jnp.minimum(qi - si * n_sub, n_sub)
@@ -447,6 +495,11 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_sc[:] = jnp.zeros_like(acc_sc)
         m_sc[:] = jnp.full_like(m_sc, NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
+        if q_sc is not None:
+            _turn_q(q_ref, q_sc, cos_ref, sin_ref, q_scale)
+
+    if q_sc is not None:
+        q = q_sc[:]
 
     def update(carry, j, width, diagonal=False):
         """``width`` sub-blocks from the span's j-th on, the last of them the
@@ -502,11 +555,20 @@ def _fwd_causal_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
-                    block=None, sink=None):
+                    block=None, sink=None, turn=None, o_rows=None):
     """``sink`` (with a window): (B*H, 1, 128) float32, each row's sink
-    logit lane-broadcast."""
+    logit lane-broadcast. k and v may hold FEWER heads than q (grouped
+    queries): q's head ``b`` reads theirs ``b // rep``, nothing is repeated.
+    ``o_rows`` (q's heads a batch row; the value width whole 128 lanes): the
+    output is written IN PLACE as the rows the out-projection reads, (B, T,
+    heads * Dv), a head its block of lanes, not head-major. ``turn`` =
+    (softmax scale, cos, sin): the q block is scaled and rotated in VMEM at
+    the start of its key walk (``_turn_q``; cos / sin (T, r) float32 or
+    None), where ``scale`` then is 1.0."""
     bh, t, d = q.shape
     dv = v.shape[2]
+    rep = bh // k.shape[0]
+    kv = (lambda b: b // rep) if rep > 1 else (lambda b: b)
     plan = flash_forward_plan(t, d, dv, q.dtype, block_q, block_k, window)
     sub, n_sub = plan.sub_block, plan.span // plan.sub_block
     qi_arr, si_arr = _causal_spans(t // sub, n_sub, sub, window)
@@ -520,16 +582,31 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
             _fwd_window_kernel, scale=scale, sub=sub, n_sub=n_sub,
             window=window), "flash_fwd_win"
         pairs = plan.sub_blocks_run * sub * sub
-    operands, sink_specs = (q, k, v), []
+    # further inputs, handed to the body by name: they sit between the
+    # three operands and the outputs in the call's order
+    extra, extra_specs, scratch = {}, [], []
+    if turn is not None:
+        q_scale, cos, sin = turn
+        kernel = functools.partial(kernel, q_scale=q_scale)
+        if cos is not None:
+            extra.update(cos_ref=cos, sin_ref=sin)
+            extra_specs += [pl.BlockSpec(
+                (sub, cos.shape[-1]), lambda b, f, qa, sa: (qa[f], 0))] * 2
+        scratch = [pltpu.VMEM((sub, d), q.dtype)]
     if sink is not None:
-        # one more input, handed to the body by name: it sits between the
-        # inputs and the outputs in the call's order
-        body = kernel
-        kernel = lambda qa, sa, q, k, v, s, *rest: body(
-            qa, sa, q, k, v, *rest, sink_ref=s)
-        operands += (sink,)
-        sink_specs = [pl.BlockSpec((1, 1, _LANES),
-                                   lambda b, f, qa, sa: (b, 0, 0))]
+        extra["sink_ref"] = sink
+        extra_specs.append(pl.BlockSpec((1, 1, _LANES),
+                                        lambda b, f, qa, sa: (b, 0, 0)))
+    if extra:
+        body, names = kernel, tuple(extra)
+        kernel = lambda qa, sa, q, k, v, *rest: body(
+            qa, sa, q, k, v, *rest[len(names):],
+            **dict(zip(names, rest[:len(names)])))
+    if o_rows:
+        o_shape = (bh // o_rows, t, o_rows * dv)
+        at_o = lambda g, i: (g // o_rows, i, g % o_rows)
+    else:
+        o_shape, at_o = (bh, t, dv), lambda g, i: (g, i, 0)
     return pl.pallas_call(
         kernel,
         name=name,
@@ -539,19 +616,20 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
             in_specs=[
                 pl.BlockSpec((1, sub, d), lambda b, f, qa, sa: (b, qa[f], 0)),
                 pl.BlockSpec((1, plan.span, d),
-                             lambda b, f, qa, sa: (b, sa[f], 0)),
+                             lambda b, f, qa, sa: (kv(b), sa[f], 0)),
                 pl.BlockSpec((1, plan.span, dv),
-                             lambda b, f, qa, sa: (b, sa[f], 0)),
-            ] + sink_specs,
+                             lambda b, f, qa, sa: (kv(b), sa[f], 0)),
+            ] + extra_specs,
             out_specs=(
-                pl.BlockSpec((1, sub, dv), lambda b, f, qa, sa: (b, qa[f], 0)),
+                pl.BlockSpec((1, sub, dv),
+                             lambda b, f, qa, sa: at_o(b, qa[f])),
                 pl.BlockSpec((1, 1, sub), lambda b, f, qa, sa: (b, 0, qa[f])),
             ),
             scratch_shapes=[pltpu.VMEM((sub, dv), jnp.float32),
                             pltpu.VMEM((sub, _LANES), jnp.float32),
-                            pltpu.VMEM((sub, _LANES), jnp.float32)],
+                            pltpu.VMEM((sub, _LANES), jnp.float32)] + scratch,
         ),
-        out_shape=(jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct(o_shape, q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -560,12 +638,13 @@ def _causal_forward(q, k, v, scale, block_q, block_k, window=None,
             flops=int(2 * bh * pairs * (d + dv)),
             bytes_accessed=int((q.size + k.size + 2 * v.size) * q.dtype.itemsize),
             transcendentals=int(bh * pairs)),
-    )(jnp.asarray(qi_arr), jnp.asarray(si_arr), *operands)
+    )(jnp.asarray(qi_arr), jnp.asarray(si_arr), q, k, v, *extra.values())
 
 
 def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       acc_sc, m_sc, l_sc, *, scale: float, sub: int,
-                       n_sub: int, window: int, sink_ref=None):
+                       acc_sc, m_sc, l_sc, q_sc=None, *, scale: float,
+                       sub: int, n_sub: int, window: int, sink_ref=None,
+                       q_scale: float = 1.0, cos_ref=None, sin_ref=None):
     """The causal forward under a window: a q block against one of the spans
     that hold a key it sees. Of the span's sub-blocks those from the first
     with a key inside the window up to the diagonal's are run, one softmax
@@ -579,7 +658,7 @@ def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
     and no value; the log-sum-exp written counts it)."""
     f = pl.program_id(1)
     qi, si = qi_arr[f], si_arr[f]
-    q = q_ref[0]
+    q = q_ref[0] if q_sc is None else None
     first = _first_block(qi, sub, window)
 
     @pl.when(si == first // n_sub)
@@ -591,6 +670,11 @@ def _fwd_window_kernel(qi_arr, si_arr, q_ref, k_ref, v_ref, o_ref, lse_ref,
         else:
             m_sc[:] = jnp.broadcast_to(sink_ref[0], m_sc.shape)
             l_sc[:] = jnp.ones_like(l_sc)
+        if q_sc is not None:
+            _turn_q(q_ref, q_sc, cos_ref, sin_ref, q_scale)
+
+    if q_sc is not None:
+        q = q_sc[:]
 
     def update(j, g, masked):
         mask_rc = _block_iotas(sub, sub, qi, g) if masked else None
@@ -641,14 +725,16 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k, window=None,
     bq = _pick_block(t_q, block_q)
     bk = _pick_block(t_k, block_k)
     nq, nk = t_q // bq, t_k // bk
+    rep = bh // k.shape[0]      # grouped queries: k, v at their own heads
+    kv = (lambda b: b // rep) if rep > 1 else (lambda b: b)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, num_k=nk),
         name="flash_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bk, dv), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, bk, dv), lambda b, i, j: (kv(b), j, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
@@ -1058,6 +1144,36 @@ def block_mask_supports(block) -> bool:
     return isinstance(block, int) and block > 1 and _LANES % block == 0
 
 
+def _static_masks(t: int, causal: bool, window, block, sink):
+    """A call's ``window`` and ``block`` as the plans take them (a window
+    that reaches the whole length is None unless a sink comes with it; a
+    block of 1 is None), or the reason the kernels do not carry them."""
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError(f"flash_attention: window={window} is a causal "
+                             "window of at least the row's own key")
+        window = int(window) if window < t or sink is not None else None
+    if sink is not None and window is None:
+        raise ValueError("flash_attention: a sink is a window layer's (the "
+                         "windowed forward carries it)")
+    if block is not None and int(block) > 1:
+        if not causal or window is not None or t % int(block) \
+                or not block_mask_supports(block):
+            raise ValueError(
+                f"flash_attention: block={block} is a block-causal mask of a "
+                "power of two that divides 128 and the length, with no "
+                "window")
+        return window, int(block)
+    return window, None
+
+
+def _sink_rows(sink, b: int, h: int):
+    """A head's sink logit (H,) as the windowed forward reads it: (B*H, 1,
+    128) float32, lane-broadcast."""
+    return jnp.broadcast_to(jnp.tile(sink.astype(jnp.float32), b)[
+        :, None, None], (b * h, 1, _LANES))
+
+
 def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
                     window: Optional[int] = None, block: Optional[int] = None,
@@ -1088,24 +1204,7 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     on an untileable length (``flash_supports`` tells callers beforehand).
     """
     t, d = q.shape[1], q.shape[-1]
-    if window is not None:
-        if not causal or int(window) < 1:
-            raise ValueError(f"flash_attention: window={window} is a causal "
-                             "window of at least the row's own key")
-        window = int(window) if window < t or sink is not None else None
-    if sink is not None and window is None:
-        raise ValueError("flash_attention: a sink is a window layer's (the "
-                         "windowed forward carries it)")
-    if block is not None and int(block) > 1:
-        if not causal or window is not None or t % int(block) \
-                or not block_mask_supports(block):
-            raise ValueError(
-                f"flash_attention: block={block} is a block-causal mask of a "
-                "power of two that divides 128 and the length, with no "
-                "window")
-        block = int(block)
-    else:
-        block = None
+    window, block = _static_masks(t, causal, window, block, sink)
     if not flash_supports(t, k.shape[1], causal, block_q, block_k):
         raise ValueError(
             f"flash_attention: lengths ({t}, {k.shape[1]}) with causal="
@@ -1126,16 +1225,88 @@ def flash_attention(q, k, v, causal: bool = True, scale: Optional[float] = None,
     q = q * jnp.asarray(scale, q.dtype)
     if sink is not None:        # the forward alone: no rule for a gradient
         b, h = q.shape[0], q.shape[2]
-        rows = jnp.broadcast_to(jnp.tile(sink.astype(jnp.float32), b)[
-            :, None, None], (b * h, 1, _LANES))
         o, _ = _flash_forward(_to_bhtd(q), _to_bhtd(k), _to_bhtd(v), 1.0,
                               True, int(block_q), int(block_k), window,
-                              sink=rows)
+                              sink=_sink_rows(sink, b, h))
         return _to_bthd(o, b)[:, :t]
     # (a pad key lies in a LATER block than every kept row: under the block
     # mask too it is visible to pad queries alone)
     o = _flash_bthd(q, k, v, (1.0, bool(causal), int(block_q), int(block_k),
                               window) + ((block,) if block else ()))
+    return o[:, :t]
+
+
+# ------------------------------------------ a prefill's forward, as projected
+def _signed_sin(sin):
+    """``sin`` (T, r) with its first half negated: rotate-half, ``[-x2, x1]
+    * sin``, is then ``roll(x, r / 2) * _signed_sin(sin)``, one roll."""
+    half = sin.shape[-1] // 2
+    return jnp.concatenate([-sin[..., :half], sin[..., half:]], axis=-1)
+
+
+def _in_place(width: int) -> bool:
+    """Whether the kernel writes an output of this head width where the
+    out-projection reads it, as a lane block of (B, T, H * width) rows:
+    Mosaic takes a block of whole 128-lane groups of a row (a 192-wide head
+    is none, nor is a squeezed head of (B, T, H, 192): the block's last two
+    dimensions would be (1, 192)). The INPUTS stay head-major whatever
+    their width: XLA lays the projection's (or the norm's, the rotation's)
+    output out by head at no pass of its own, and a row block's 256-byte
+    pieces read slower than a head's contiguous rows (ranked on the chip,
+    PERF.md section 6, PR 52)."""
+    return width % _LANES == 0
+
+
+def prefill_supports(d: int, rotary_dim: Optional[int]) -> bool:
+    """Whether ``flash_prefill`` rotates ``rotary_dim`` leading columns of a
+    ``d``-wide head (None: no rotary embedding): an even count of them, at
+    most the head's (the roll is by half of it, inside those lanes)."""
+    return rotary_dim is None or (
+        rotary_dim % 2 == 0 and 0 < rotary_dim <= d)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "scale", "window", "block", "block_q", "block_k"))
+def flash_prefill(q, k, v, cos=None, sin=None, sink=None, *,
+                  scale: Optional[float] = None,
+                  window: Optional[int] = None, block: Optional[int] = None,
+                  block_q: int = DEFAULT_BLOCK_Q,
+                  block_k: int = DEFAULT_BLOCK_K):
+    """Causal self-attention of a PREFILL on q, k and v as the projections
+    made them; FORWARD only (a caller that needs a gradient takes
+    :func:`flash_attention`). q: (B, T, H, D), NOT rotated and NOT scaled;
+    k: (B, T, KV, D), rotated (a cache keeps it so: 4 - 8 heads, one small
+    pass outside); v: (B, T, KV, Dv); KV divides H -> (B, T, H, Dv).
+
+    What ``flash_attention``'s callers do in passes around it is done
+    inside the one kernel call: q's head ``h`` reads K and V of head ``h //
+    (H / KV)`` by index map (nothing is repeated); a q block is rotated by
+    ``cos`` / ``sin`` ((T, r) float32, the model's tables: the first ``r``
+    columns of a head rotate-half, the others pass; None: no rotary
+    embedding) and scaled in float32 in VMEM at the start of its key walk,
+    rounded ONCE (``_turn_q``); an output whose head is whole 128 lanes is
+    written in place as the (B, T, H * Dv) rows the out-projection reads
+    (``_in_place``); q, k and v go in head-major, which XLA makes the layout
+    their producers write. ``window``, ``sink``, ``block``:
+    ``flash_attention``'s.
+    Under ``jax.jit(inline=True)``: the kernel's body is traced once a
+    shape a process, not once a call site."""
+    b, t, h, d = q.shape
+    dv = v.shape[-1]
+    window, block = _static_masks(t, True, window, block, sink)
+    pad = _padded_len(t, min(block_q, block_k)) - t
+    if pad:         # at the end: a pad key is visible to pad queries alone
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+    if cos is not None:
+        cos, sin = (jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
+                    for x in (cos, _signed_sin(sin)))
+    o, _ = _causal_forward(
+        _to_bhtd(q), _to_bhtd(k), _to_bhtd(v), 1.0, block_q, block_k, window,
+        block, None if sink is None else _sink_rows(sink, b, h),
+        turn=(1.0 / math.sqrt(d) if scale is None else scale, cos, sin),
+        o_rows=h if _in_place(dv) else None)
+    o = o.reshape(b, t + pad, h, dv) if _in_place(dv) else _to_bthd(o, b)
     return o[:, :t]
 
 

@@ -105,6 +105,37 @@ def test_causal_flash_forward_compiles_for_v5e_at_the_cells_shapes(v5e, t, d,
     assert f"(bf16[4,{t},{dv}]" in call and f"f32[4,1,{t}]" in call
 
 
+# heads / KV heads, q.k / v width, rotary columns, window (+ a sink), block,
+# length: MiMo's full and window layers at 16,384, SDAR's block mask at its
+# prompt (sub-blocks of 256), Solar's one attention layer, OLMoE
+@pytest.mark.parametrize("h,kv,d,dv,r,window,block,t", [
+    (64, 4, 192, 128, 64, None, None, 16384),
+    (64, 8, 192, 128, 64, 128, None, 16384),
+    (32, 4, 128, 128, 128, None, 4, 2304),
+    (64, 8, 128, 128, 128, None, None, 32768),
+    (16, 16, 128, 128, 128, None, None, 4096)], ids=lambda x: str(x))
+def test_flash_prefill_compiles_for_v5e_at_the_cells_shapes(
+        v5e, h, kv, d, dv, r, window, block, t):
+    """``flash_prefill`` on q, k, v as the projections made them: the roll
+    inside ``r`` of a head's lanes, the masked store beside the passed-
+    through lanes, the (rows, r) table blocks and the output's row blocks
+    of (B, T, H * 128) are what only Mosaic can refuse. The compiled call
+    takes K and V at their own heads and writes the output as rows."""
+    mesh = _mesh(v5e)
+    sink = () if window is None else (_abstract((h,), jnp.float32, mesh),)
+    text = jax.jit(lambda q, k, v, cos, sin, *sink: fa.flash_prefill(
+        q, k, v, cos, sin, *sink, window=window, block=block)).lower(
+            _abstract((1, t, h, d), jnp.bfloat16, mesh),
+            _abstract((1, t, kv, d), jnp.bfloat16, mesh),
+            _abstract((1, t, kv, dv), jnp.bfloat16, mesh),
+            *[_abstract((t, r), jnp.float32, mesh)] * 2, *sink
+    ).compile().as_text()
+    call, = re.findall(r"%[\w.]*flash_fwd[\w.]* = [^\n]*tpu_custom_call[^\n]*",
+                       text)
+    assert f"(bf16[1,{t},{h * dv}]" in call
+    assert f"bf16[{kv},{t},{d}]" in call and f"bf16[{kv},{t},{dv}]" in call
+
+
 def test_windowed_flash_fwd_bwd_compiles_for_v5e_at_the_cells_shape(v5e):
     """``trinity-mini.train.z1.s8k``'s window layers: 32 heads x 8,192 x 128
     under a window of 2,048, the forward and the ONE backward kernel, each
